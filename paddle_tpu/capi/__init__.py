@@ -10,8 +10,8 @@ CPython runtime and drives the jit-compiling Executor through
 reference demos' analogs and are built+run by ``tests/test_capi.py``.
 
 Build helpers here compile the library/demos on demand with g++
-(same pattern as recordio's compile-on-first-use; no pybind11 — the
-CPython C API is the binding layer).
+(``native_build``, shared with recordio: the binary is keyed on the
+sources' content; no pybind11 — the CPython C API is the binding layer).
 """
 
 import os
@@ -19,13 +19,14 @@ import subprocess
 import sysconfig
 import tempfile
 
+from ..native_build import build_shared
+
 __all__ = ["lib_path", "build_lib", "build_demo", "header_path",
            "native_available"]
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "paddle_capi.cpp")
 _HDR = os.path.join(_HERE, "paddle_capi.h")
-_LIB_PATH = os.path.join(_HERE, "_libpaddle_tpu_capi.so")
 
 
 def header_path():
@@ -43,24 +44,12 @@ def _python_link_flags():
                           "-Wl,-rpath," + libdir, "-ldl", "-lm"]
 
 
-def build_lib(force=False):
-    """Compile the shared library; returns its path."""
-    src_mtime = max(os.path.getmtime(_SRC), os.path.getmtime(_HDR))
-    if not force and os.path.exists(_LIB_PATH) and \
-            os.path.getmtime(_LIB_PATH) >= src_mtime:
-        return _LIB_PATH
+def build_lib():
+    """Compile the shared library (unless a build of exactly these
+    sources exists); returns its path."""
     cflags, ldflags = _python_link_flags()
-    fd, tmp = tempfile.mkstemp(dir=_HERE, prefix="_libcapi_", suffix=".so")
-    os.close(fd)
-    try:
-        cmd = (["g++", "-O2", "-shared", "-fPIC", "-std=c++17"] + cflags +
-               [_SRC, "-o", tmp] + ldflags)
-        subprocess.run(cmd, check=True, capture_output=True, text=True)
-        os.replace(tmp, _LIB_PATH)
-    finally:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
-    return _LIB_PATH
+    return build_shared(_HERE, "libpaddle_tpu_capi", [_SRC, _HDR],
+                        cflags=cflags, ldflags=ldflags)
 
 
 def lib_path():

@@ -4,10 +4,14 @@ Two layers, addressing the two costs a repeated step shape pays:
 
 * **Persistent XLA compilation cache** (``enable_persistent_cache``): the
   jax/XLA on-disk executable cache, keyed by HLO fingerprint.  Survives
-  process restarts — bench-ladder rungs, test runs, and training restarts
+  process restarts — bench-ladder rungs, chip runs, and training restarts
   with the same program+signature skip XLA's optimization pipeline and
-  deserialize the executable instead.  Wired to ``FLAGS_compile_cache_dir``
-  (env ``FLAGS_compile_cache_dir=/path`` enables it before the first jit).
+  deserialize the executable instead.  Where it lives is decided by ONE
+  resolver, ``persistent_cache_dir``: ``JAX_COMPILATION_CACHE_DIR`` when
+  the environment sets it (nothing in code overrides that), else
+  ``FLAGS_compile_cache_dir``, else — for chip entry points only — one
+  fixed directory inside the checkout.  A bare ``import paddle_tpu``
+  turns nothing on.
 * **Process-global trace cache** (``lookup``/``store``): re-tracing is a
   host-side cost the XLA cache cannot amortize (jaxpr building walks every
   op's compute function).  Executors cache their jitted step callables here
@@ -19,10 +23,14 @@ Two layers, addressing the two costs a repeated step shape pays:
 ``stats()`` exposes hit/miss/lowering counters; the executors emit
 ``compile_cache/hit`` / ``compile_cache/miss`` profiler marks at every
 lookup so cache behavior is visible in the chrome trace next to the
-``trace``/``compile``/``dispatch`` spans.
+``trace``/``compile``/``dispatch`` spans.  ``count_compiles()`` is the
+one "nothing new was lowered or compiled" counter (this module's
+lowerings next to jax's own compile events), shared by the tests and
+``chip_smoke.py``.
 """
 
 import collections
+import contextlib
 import hashlib
 import os
 import threading
@@ -31,8 +39,9 @@ from .profiler import mark_event
 
 __all__ = [
     "program_fingerprint", "trace_key", "trace_flag_values", "lookup",
-    "store", "stats", "reset_stats", "clear", "enable_persistent_cache",
-    "rescope_persistent_cache",
+    "store", "stats", "reset_stats", "clear", "note_kernel_body",
+    "count_compiles", "persistent_cache_dir", "enable_persistent_cache",
+    "rescope_persistent_cache", "CHECKOUT_CACHE_DIR",
 ]
 
 
@@ -71,8 +80,11 @@ _STATS = {"trace_hits": 0, "trace_misses": 0, "lowerings": 0}
 # lowering counts per short program fingerprint: a retrace storm in the
 # stats/StepStats names WHICH program is churning, not just that one is
 _LOWERINGS_BY_FP = {}
+# "<op type>:<body>" -> times traced: which compute body ("pallas",
+# "xla", "ring") an op with a hand-written alternative lowered to
+_KERNEL_BODIES = {}
 _persistent_dir = [None]
-_persistent_base = [None]     # user-given dir, before any world scoping
+_persistent_base = [None]     # resolved dir, before any world scoping
 
 
 # ---------------------------------------------------------------------------
@@ -166,6 +178,7 @@ def stats():
     with _mu:
         out = dict(_STATS)
         out["lowerings_by_program"] = dict(_LOWERINGS_BY_FP)
+        out["kernel_bodies"] = dict(_KERNEL_BODIES)
     lookups = out["trace_hits"] + out["trace_misses"]
     out["hit_ratio"] = round(out["trace_hits"] / lookups, 4) if lookups \
         else 0.0
@@ -179,6 +192,19 @@ def reset_stats():
         for k in _STATS:
             _STATS[k] = 0
         _LOWERINGS_BY_FP.clear()
+        _KERNEL_BODIES.clear()
+
+
+def note_kernel_body(op_type, body):
+    """Record, at trace time, which compute body an op with a Pallas (or
+    ring) alternative lowered to.  A requested kernel that its
+    ``supported()`` gate rejects gives way to the XLA reference; this
+    counter (``stats()["kernel_bodies"]``, plus a ``kernel_body/...``
+    profiler mark) is what tells the two apart afterwards."""
+    key = "%s:%s" % (op_type, body)
+    with _mu:
+        _KERNEL_BODIES[key] = _KERNEL_BODIES.get(key, 0) + 1
+    mark_event("kernel_body/%s/%s" % (op_type, body))
 
 
 def clear():
@@ -188,8 +214,118 @@ def clear():
 
 
 # ---------------------------------------------------------------------------
+# jax's own compile events
+# ---------------------------------------------------------------------------
+
+# jax.monitoring events -> counter names.  The duration events fire once
+# per jaxpr->MLIR lowering and once per backend compile request (a
+# persistent-cache hit still fires the latter, with the short retrieval
+# time); the plain events count persistent-cache traffic.
+_JAX_DURATION_EVENTS = {
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("jax_lowerings", "jax_lowering_seconds"),
+    "/jax/core/compile/backend_compile_duration":
+        ("jax_backend_compiles", "jax_backend_compile_seconds"),
+}
+_JAX_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "persistent_cache_hits",
+    "/jax/compilation_cache/cache_misses": "persistent_cache_misses",
+}
+_JAX_COUNTS = dict.fromkeys(
+    [n for pair in _JAX_DURATION_EVENTS.values() for n in pair]
+    + list(_JAX_EVENTS.values()), 0)
+_listening = [False]
+
+
+def _listen():
+    """Register the jax.monitoring listeners once per process (jax has
+    no public unregister, so they stay; they run only when jax lowers or
+    compiles something).  Process-global on purpose: the serving loop
+    compiles on its own thread, which thread-local counters miss."""
+    with _mu:
+        if _listening[0]:
+            return
+        _listening[0] = True
+    import jax.monitoring
+
+    def on_duration(event, duration, **_):
+        names = _JAX_DURATION_EVENTS.get(event)
+        if names is not None:
+            with _mu:
+                _JAX_COUNTS[names[0]] += 1
+                _JAX_COUNTS[names[1]] += duration
+
+    def on_event(event, **_):
+        name = _JAX_EVENTS.get(event)
+        if name is not None:
+            with _mu:
+                _JAX_COUNTS[name] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+
+
+def _compile_counts():
+    with _mu:
+        out = dict(_JAX_COUNTS)
+        out["lowerings"] = _STATS["lowerings"]
+    return out
+
+
+@contextlib.contextmanager
+def count_compiles():
+    """Count what was lowered and compiled inside the block, on any
+    thread.  Yields a callable returning the deltas since entry (live
+    inside the block, frozen at its exit): ``lowerings`` (this module's
+    trace-cache stores — a Program traced to a new step function),
+    ``jax_lowerings`` / ``jax_backend_compiles`` (jax's own jaxpr->MLIR
+    and backend-compile events, with their ``*_seconds``), and
+    ``persistent_cache_hits`` / ``_misses``.  A warm step shows zeros in
+    all of the first three."""
+    _listen()
+    before = _compile_counts()
+    frozen = {}
+
+    def delta():
+        return dict(frozen) or {
+            k: v - before[k] for k, v in _compile_counts().items()}
+    try:
+        yield delta
+    finally:
+        frozen.update(delta())
+
+
+# ---------------------------------------------------------------------------
 # persistent XLA compilation cache
 # ---------------------------------------------------------------------------
+
+# The one fixed location chip entry points (chip_smoke.py, bench.py and
+# its rung children) use when the environment names none: inside the
+# checkout, gitignored, never a temporary name, pid or time — the path
+# is part of jax's cache key, so a directory that moves never hits.
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_compile_cache")
+
+
+def persistent_cache_dir(requested=None, chip_entry=False):
+    """THE resolver for where the persistent compilation cache lives.
+
+    1. ``JAX_COMPILATION_CACHE_DIR`` set: there, and nowhere else — the
+       flag and any CLI option lose to it.
+    2. ``requested`` (``FLAGS_compile_cache_dir`` / ``--compile_cache_dir``).
+    3. ``chip_entry``: :data:`CHECKOUT_CACHE_DIR`.
+    4. None — off.  This is what a bare ``import paddle_tpu`` gets (the
+       test suite relies on a cold cache: warm multi-device CPU
+       executables were found nondeterministic).
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if requested:
+        return requested
+    return CHECKOUT_CACHE_DIR if chip_entry else None
+
 
 def _known_world_size():
     """The jax process count, WITHOUT initializing the backend: only
@@ -213,52 +349,44 @@ def rescope_persistent_cache():
     ``parallel.distributed.init_distributed`` AFTER the jax runtime
     joined the world (covering caches enabled BEFORE the join; caches
     enabled after it scope themselves in ``enable_persistent_cache``).
-    Single-process runs keep the base directory, so an elastic-resume
+    Single-process runs keep the directory itself, so an elastic-resume
     survivor restarts warm off the solo entries while never
     deserializing a multi-process executable: an N-process module
     embeds cross-process collective wiring and silently computes
     garbage in any other world shape (found by the cluster drill)."""
     base = _persistent_base[0]
     if base:
-        enable_persistent_cache(base)
+        _apply_persistent_dir(base)
 
 
-def enable_persistent_cache(cache_dir):
-    """Point jax's on-disk executable cache at ``cache_dir`` (empty/None
-    disables).  Thresholds are zeroed so even the CPU-backend test shapes
-    cache: the bench ladder's win case is many small-to-medium modules
-    recompiled across subprocess rungs and re-invocations.  In a
-    multi-process world (already joined at call time, or joined later
-    through ``init_distributed``) the cache lands in a ``world_<N>``
-    subdirectory — see ``rescope_persistent_cache``."""
+def enable_persistent_cache(cache_dir=None, chip_entry=False):
+    """Turn on jax's on-disk executable cache in the directory
+    :func:`persistent_cache_dir` resolves (None = off); returns it.
+    Thresholds are zeroed so even small modules cache: the win case is
+    many small-to-medium modules recompiled across rung subprocesses
+    and chip calls.  In a ``jax.distributed`` world the entries land in
+    a ``world_<N>`` subdirectory — see ``rescope_persistent_cache``."""
+    return _apply_persistent_dir(
+        persistent_cache_dir(cache_dir, chip_entry=chip_entry))
+
+
+def _apply_persistent_dir(cache_dir):
     import jax
+    from jax.experimental.compilation_cache import (
+        compilation_cache as jax_cc)
 
-    _persistent_base[0] = cache_dir or None
+    _persistent_base[0] = cache_dir
     if cache_dir:
         n = _known_world_size()
         if n > 1:
             cache_dir = os.path.join(cache_dir, "world_%d" % n)
-    _persistent_dir[0] = cache_dir or None
-    jax.config.update("jax_compilation_cache_dir", cache_dir or None)
-    if not cache_dir:
-        return
-    for name, val in (
-        ("jax_enable_compilation_cache", True),
-        ("jax_persistent_cache_min_compile_time_secs", 0),
-        ("jax_persistent_cache_min_entry_size_bytes", 0),
-    ):
-        try:
-            jax.config.update(name, val)
-        except AttributeError:
-            # older/newer jax spelling; the dir alone still enables it
-            pass
-    try:
-        # jax memoizes "cache disabled" on first compile: a process that
-        # already jitted before the flag was set would silently never
-        # cache.  reset_cache drops that memo so the new dir takes
-        # effect immediately.
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except (ImportError, AttributeError):
-        pass
+    _persistent_dir[0] = cache_dir
+    jax_cc.set_cache_dir(cache_dir)
+    if cache_dir:
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # jax binds the cache to the directory (or to "disabled") at the
+    # first compile; reset so a directory set after that takes effect
+    jax_cc.reset_cache()
+    return cache_dir

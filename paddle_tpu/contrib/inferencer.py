@@ -17,7 +17,7 @@ from .. import unique_name
 from ..executor import Executor
 from ..framework import Parameter, Program, program_guard
 from ..scope import Scope, scope_guard
-from .trainer import _default_place
+from ..executor import default_place
 
 __all__ = ["Inferencer"]
 
@@ -38,7 +38,7 @@ class Inferencer:
                 "parallel inference is served by the mesh ParallelExecutor "
                 "(paddle_tpu.parallel); pass the program to it directly")
         self.parallel = parallel
-        self.place = _default_place(place)
+        self.place = default_place(place)
 
         if not os.path.isdir(param_path):
             raise ValueError("param_path %r is not a directory" % param_path)

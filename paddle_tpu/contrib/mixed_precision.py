@@ -22,8 +22,6 @@ SURVEY.md §2.6 float16 demo row).
 
 import jax.numpy as jnp
 
-from ..core import bfloat16
-
 __all__ = ["AutoMixedPrecisionLists", "AMPPolicy", "decorate",
            "bf16_program_guard", "cast_parameters_to_bf16"]
 
@@ -72,8 +70,6 @@ class AMPPolicy:
         Grad ops follow their forward op's color (the generic auto-vjp
         grad re-runs the forward, so the same cast yields the same
         bf16 compute in the backward pass)."""
-        if bfloat16 is None:  # pragma: no cover - ml_dtypes always present
-            return ins
         base = op_type[:-5] if op_type.endswith("_grad") else op_type
         if base in self.lists.white_list:
             target, source = jnp.bfloat16, jnp.float32

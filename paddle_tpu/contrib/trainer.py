@@ -19,7 +19,7 @@ from .. import io as fluid_io
 from .. import monitor
 from .. import unique_name
 from ..data_feeder import DataFeeder
-from ..executor import CPUPlace, Executor, TPUPlace
+from ..executor import Executor, default_place
 from ..framework import Program, default_main_program, \
     default_startup_program, program_guard
 from ..optimizer import Optimizer
@@ -31,15 +31,6 @@ __all__ = [
     "Trainer", "CheckpointConfig",
     "BeginEpochEvent", "EndEpochEvent", "BeginStepEvent", "EndStepEvent",
 ]
-
-
-def _default_place(place=None):
-    """Pick TPU if one is attached, else CPU (shared by Trainer/Inferencer)."""
-    if place is not None:
-        return place
-    import jax
-    has_tpu = any(d.platform != "cpu" for d in jax.devices())
-    return TPUPlace(0) if has_tpu else CPUPlace()
 
 
 class BeginEpochEvent:
@@ -141,7 +132,7 @@ class Trainer:
         ``ClusterGuardian``."""
         self.__stop = False
         self.parallel = parallel
-        self.place = _default_place(place)
+        self.place = default_place(place)
         self._mesh = mesh
         self._guardian_config = guardian_config
         self._cluster_member = cluster_member

@@ -9,12 +9,9 @@ to a plain-dict format in ``framework.py``).
 
 import numpy as np
 
-try:  # jax's bfloat16 comes from ml_dtypes
-    import ml_dtypes
+import ml_dtypes  # jax's bfloat16 comes from ml_dtypes
 
-    bfloat16 = np.dtype(ml_dtypes.bfloat16)
-except ImportError:  # pragma: no cover
-    bfloat16 = None
+bfloat16 = np.dtype(ml_dtypes.bfloat16)
 
 
 class VarType:
@@ -41,9 +38,8 @@ _DTYPE_ALIASES = {
     "int8": np.int8,
     "uint8": np.uint8,
     "bool": np.bool_,
+    "bfloat16": bfloat16,
 }
-if bfloat16 is not None:
-    _DTYPE_ALIASES["bfloat16"] = bfloat16
 
 
 def convert_dtype(dtype):
@@ -90,7 +86,7 @@ def materialize_dtype(dtype):
 
 def dtype_is_floating(dtype):
     d = convert_dtype(dtype)
-    if bfloat16 is not None and d == bfloat16:
+    if d == bfloat16:
         return True
     return np.issubdtype(d, np.floating)
 

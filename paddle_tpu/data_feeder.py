@@ -135,12 +135,12 @@ class DataFeeder:
                               or isinstance(shardings, dict)):
             # no place anywhere would stage nothing (host arrays pass
             # through, h2d lands back on the critical path): default to
-            # the accelerator like layers.double_buffer (TPUPlace falls
-            # back to the first local device on CPU-only hosts).  A
-            # partial shardings dict still needs it for unlisted feeds.
-            from .executor import TPUPlace
+            # the accelerator (when there is one) like
+            # layers.double_buffer.  A partial shardings dict still
+            # needs it for unlisted feeds.
+            from .executor import default_place
 
-            place = TPUPlace(0)
+            place = default_place()
         return DevicePrefetcher(
             reader, feeder=self, place=place,
             shardings=shardings, capacity=capacity)

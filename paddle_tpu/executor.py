@@ -41,7 +41,7 @@ from .registry import ComputeContext
 from .scope import Scope, global_scope
 
 __all__ = ["Executor", "AsyncDispatchQueue", "CPUPlace", "TPUPlace",
-           "place_from_string"]
+           "default_place", "place_from_string"]
 
 
 class Place:
@@ -59,11 +59,10 @@ class Place:
 class CPUPlace(Place):
     def jax_device(self):
         # local_devices: under multi-host (jax.distributed) the first
-        # GLOBAL device may belong to another process
-        try:
-            return jax.local_devices(backend="cpu")[0]
-        except RuntimeError:
-            return jax.local_devices()[0]
+        # GLOBAL device may belong to another process.  No CPU backend
+        # (JAX_PLATFORMS names only the chip) raises: a host place never
+        # resolves to an accelerator
+        return jax.local_devices(backend="cpu")[0]
 
     def __eq__(self, other):
         return isinstance(other, CPUPlace)
@@ -79,10 +78,21 @@ class TPUPlace(Place):
         self.device_id = device_id
 
     def jax_device(self):
+        """The chip this place names.  Never another device: no
+        accelerator, or an id past the last local chip, is an error —
+        a step that was asked to run on a chip must not report from the
+        host CPU or from a different chip."""
         devs = [d for d in jax.local_devices() if d.platform != "cpu"]
         if not devs:
-            devs = jax.local_devices()
-        return devs[self.device_id % len(devs)]
+            raise RuntimeError(
+                "%r: this process has no accelerator (jax.local_devices() "
+                "= %s); use CPUPlace() to run on the host" %
+                (self, jax.local_devices()))
+        if not 0 <= self.device_id < len(devs):
+            raise RuntimeError(
+                "%r: device id out of range, this process has %d "
+                "accelerator device(s)" % (self, len(devs)))
+        return devs[self.device_id]
 
     def __eq__(self, other):
         return isinstance(other, TPUPlace) and other.device_id == self.device_id
@@ -110,6 +120,17 @@ class CUDAPinnedPlace(CPUPlace):
 
     def __hash__(self):
         return hash("CUDAPinnedPlace")
+
+
+def default_place(place=None):
+    """The library default where a caller names no place: chip 0 when
+    the process has an accelerator, else the host CPU.  Chip entry
+    points (chip_smoke.py, bench.py) do not go through this — they pass
+    an explicit ``TPUPlace``, which raises without a chip."""
+    if place is not None:
+        return place
+    accel = any(d.platform != "cpu" for d in jax.local_devices())
+    return TPUPlace(0) if accel else CPUPlace()
 
 
 def place_from_string(s):
@@ -399,7 +420,7 @@ class Executor:
         share one scope concurrently (inference predictor clones) —
         donation would delete the weight buffers under the other
         executors.  Training keeps the default in-place donation."""
-        self.place = place if place is not None else TPUPlace(0)
+        self.place = default_place(place)
         self.donate_state = donate_state
         self._cache = {}
         self._run_counter = 0
@@ -629,34 +650,24 @@ class Executor:
                         # signature: one compile total.  debug_nans
                         # keeps the jit path (its nan re-run machinery
                         # lives there).
-                        aotex = program_profile.capture(
-                            fp if fp is not None else
-                            compile_cache.program_fingerprint(program),
-                            feed_sig, compiled.fn,
-                            (feed_dev, state_vals, rng),
-                            device=dev, kind="executor",
-                            fetch_names=tuple(fetch_names))
-                        if aotex is not None:
-                            compiled.aot[
-                                (feed_sig, getattr(dev, "id", 0))] = aotex
+                        compiled.aot[(feed_sig, getattr(dev, "id", 0))] = \
+                            program_profile.capture(
+                                fp if fp is not None else
+                                compile_cache.program_fingerprint(program),
+                                feed_sig, compiled.fn,
+                                (feed_dev, state_vals, rng),
+                                device=dev, kind="executor",
+                                fetch_names=tuple(fetch_names))
                     # debug_nans checked at dispatch too: a previously
                     # captured executable must not bypass the jit
                     # path's op-level nan re-run machinery
                     if compiled.aot and not flags.flag("debug_nans"):
                         fn = compiled.aot.get(
                             (feed_sig, getattr(dev, "id", 0)), compiled.fn)
-                    try:
-                        fetches, new_state = fn(feed_dev, state_vals, rng)
-                    except (TypeError, ValueError):
-                        if fn is compiled.fn:
-                            raise
-                        # the AOT executable rejected the args (device/
-                        # layout drift a jit dispatch would absorb):
-                        # drop it and fall back to the jit path
-                        compiled.aot.pop(
-                            (feed_sig, getattr(dev, "id", 0)), None)
-                        fetches, new_state = compiled.fn(
-                            feed_dev, state_vals, rng)
+                    # an AOT executable that rejects its args raises
+                    # here: re-dispatching through jit would hide a
+                    # second compile of the step
+                    fetches, new_state = fn(feed_dev, state_vals, rng)
         compiled.seen_sigs.add(feed_sig)
 
         ok_flag = None
@@ -791,10 +802,7 @@ class Executor:
                                        device=dev, kind="executor",
                                        fetch_names=tuple(fetch_names))
         compiled.aot[(feed_sig, getattr(dev, "id", 0))] = cexec
-        ca = cexec.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0]
-        return dict(ca)
+        return dict(cexec.cost_analysis())
 
 
 def _check_finite(named_vals, context=None):
@@ -812,7 +820,7 @@ def _check_finite(named_vals, context=None):
     first_kind = None
     for name, v in named_vals:
         a = np.asarray(v)
-        if bfloat16 is not None and a.dtype == bfloat16:
+        if a.dtype == bfloat16:
             a = a.astype(np.float32)  # np.isfinite lacks a bf16 loop
         if np.issubdtype(a.dtype, np.floating) and not np.isfinite(a).all():
             bad_vars.append(name)

@@ -172,8 +172,8 @@ register_flag("fast_prng", False, bool)
 # activation read per BN (see ops/norm.py)
 register_flag("bn_two_pass", False, bool)
 # sequence-length gate for the flash-attention Pallas kernel: longer
-# sequences fall back to the XLA attention (see
-# ops/pallas/flash_attention.supported)
+# sequences take the XLA attention.  A selection default, not a compile
+# limit (see ops/pallas/flash_attention.supported)
 register_flag("pallas_attention_max_seq", 2048, int)
 def _on_compile_cache_dir(val):
     from . import compile_cache
@@ -183,10 +183,11 @@ def _on_compile_cache_dir(val):
 
 register_flag("debug_nans", False, bool, _on_debug_nans)
 register_flag("benchmark", False, bool)
-# persistent XLA compilation cache directory ("" = disabled): repeated
-# program+signature shapes across bench rungs, restarts, and tests
+# persistent XLA compilation cache directory ("" = none requested):
+# repeated program+signature shapes across bench rungs and restarts
 # deserialize the compiled executable instead of re-running the XLA
-# pipeline (see compile_cache.py)
+# pipeline.  JAX_COMPILATION_CACHE_DIR, when set, wins over this flag
+# (compile_cache.persistent_cache_dir is the one resolver)
 register_flag("compile_cache_dir", "", str, _on_compile_cache_dir)
 # async-dispatch window: how many steps the host may run ahead of the
 # device before blocking on the oldest in-flight step's fetches

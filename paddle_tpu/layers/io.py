@@ -228,10 +228,9 @@ def py_reader(capacity, shapes, dtypes, lod_levels=None, name=None,
         _declare_reader_vars(shapes, dtypes, lod_levels, name), name=name)
     handle._capacity = capacity
     if use_double_buffer:
-        # the reference stages to the device by default; TPUPlace falls
-        # back to the first local device on CPU-only hosts
-        from ..executor import TPUPlace
-        handle._place = TPUPlace(0)
+        # the reference stages to the device by default
+        from ..executor import default_place
+        handle._place = default_place()
     return handle
 
 
@@ -329,10 +328,10 @@ def double_buffer(reader, place=None, name=None, capacity=None):
     if isinstance(reader, Preprocessor):
         reader = reader()
     h = reader._replace(reader._source)
-    from ..executor import TPUPlace
-    # default: the accelerator (TPUPlace falls back to the first local
-    # device on CPU-only hosts) — staging to CPU would just add a copy
-    h._place = place or TPUPlace(0)
+    from ..executor import default_place
+    # default: the accelerator when there is one — staging to the CPU
+    # would just add a copy
+    h._place = default_place(place)
     if capacity is not None:
         h._capacity = capacity
     return h

@@ -37,7 +37,6 @@ instead of letting XLA OOM mid-run.
 """
 
 import contextlib
-import os
 import threading
 import time
 import warnings
@@ -47,12 +46,27 @@ __all__ = [
     "store_compiled", "get", "profiles", "note_step", "accounting",
     "probe_accounting", "probe_active", "probe_totals", "summary_for",
     "report_rows", "render_table", "reset", "reset_accounting",
-    "DEFAULT_PEAK_TFLOPS",
+    "DEVICE_PEAKS", "bf16_peak_tflops",
 ]
 
-# chip peak (bf16 matmul TFLOP/s) for the MFU column; same env knob as
-# bench.py so the two agree on the denominator.  v5e default.
-DEFAULT_PEAK_TFLOPS = float(os.environ.get("BENCH_PEAK_TFLOPS", "197"))
+# Published per-chip peaks keyed by jax's ``device_kind`` — the one table
+# behind every MFU / roofline denominator in the repo (program report,
+# bench.py, chip_smoke.py).  A device that is not listed yields NO MFU,
+# never a default: one assumed peak for any device is how a virtual CPU
+# mesh once printed an MFU against a TPU's peak.
+# Source: Google Cloud TPU documentation, "TPU v5e" (system architecture).
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_tflops": 197.0, "int8_tops": 393.0,
+                    "hbm_gbps": 819.0},
+}
+
+
+def bf16_peak_tflops(device_kind):
+    """bf16 matmul peak (TFLOP/s) for ``device_kind``; None = unknown
+    device, no MFU."""
+    row = DEVICE_PEAKS.get(device_kind)
+    return row["bf16_tflops"] if row else None
+
 
 _mu = threading.Lock()
 # (fingerprint, feed_sig, fetch_names, trace_flags, kind, partition) ->
@@ -86,13 +100,13 @@ class ProgramProfile:
                  "cost", "flops",
                  "bytes_accessed", "argument_bytes", "output_bytes",
                  "temp_bytes", "generated_code_bytes", "alias_bytes",
-                 "peak_hbm_bytes", "device", "partition")
+                 "peak_hbm_bytes", "device", "device_kind", "partition")
 
     def __init__(self, fingerprint, feed_sig, kind, cost=None, flops=0.0,
                  bytes_accessed=0.0, argument_bytes=0, output_bytes=0,
                  temp_bytes=0, generated_code_bytes=0, alias_bytes=0,
                  peak_hbm_bytes=0, device=None, fetch_names=(),
-                 partition=None):
+                 partition=None, device_kind=None):
         self.fingerprint = fingerprint
         self.feed_sig = tuple(feed_sig)
         self.fetch_names = tuple(fetch_names)
@@ -109,6 +123,7 @@ class ProgramProfile:
         self.alias_bytes = int(alias_bytes)
         self.peak_hbm_bytes = int(peak_hbm_bytes)
         self.device = device
+        self.device_kind = device_kind
 
     def breakdown(self):
         """Per-buffer-class bytes, the preflight diagnostic's currency."""
@@ -127,6 +142,7 @@ class ProgramProfile:
              "flops": self.flops,
              "bytes_accessed": self.bytes_accessed,
              "device": self.device,
+             "device_kind": self.device_kind,
              "partition": str(self.partition) if self.partition else None}
         d.update(self.breakdown())
         return d
@@ -180,16 +196,15 @@ def capture(fingerprint, feed_sig, jit_fn, args, device=None,
     microseconds over the C++ jit fast path, paid only while capture is
     enabled.
 
-    Returns the Compiled executable, or None if the backend refuses AOT
-    compilation (the executor then falls back to the plain jit call).
-    Raises :class:`PreflightOOMError` under ``FLAGS_preflight_oom=strict``
-    when the memory estimate exceeds capacity — analysis failures
-    themselves never break the step.
+    Returns the Compiled executable.  A compile failure (Mosaic, HBM,
+    an unsupported op) propagates from HERE, the step's one compile —
+    swallowing it would pay the failing compile a second time inside the
+    jit call and report it from the wrong place.  Raises
+    :class:`PreflightOOMError` under ``FLAGS_preflight_oom=strict`` when
+    the memory estimate exceeds capacity; failures of the cost/memory
+    *analyses* never break the step.
     """
-    try:
-        compiled = jit_fn.lower(*args).compile()
-    except Exception:  # noqa: BLE001 — observability must not break steps
-        return None
+    compiled = jit_fn.lower(*args).compile()
     prof = store_compiled(fingerprint, feed_sig, compiled, device=device,
                           kind=kind, fetch_names=fetch_names,
                           partition=partition)
@@ -205,10 +220,7 @@ def store_compiled(fingerprint, feed_sig, compiled, device=None,
     ``Executor.cost_analysis`` fallback path).  No preflight here."""
     cost = {}
     try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        cost = dict(ca or {})
+        cost = dict(compiled.cost_analysis() or {})
     except Exception:  # noqa: BLE001
         pass
     mem = {}
@@ -239,6 +251,7 @@ def store_compiled(fingerprint, feed_sig, compiled, device=None,
         alias_bytes=mem.get("alias", 0),
         peak_hbm_bytes=max(0, peak),
         device=str(getattr(device, "platform", device) or "") or None,
+        device_kind=getattr(device, "device_kind", None),
         fetch_names=fetch_names, partition=partition)
     with _mu:
         _profiles[(fingerprint, prof.feed_sig, prof.fetch_names,
@@ -452,8 +465,11 @@ def report_rows(peak_tflops=None, profiles_by_fp=None, acct_by_fp=None,
     as its OWN rows flagged ``probe=True`` — excluded from the
     wall-share denominator and the MFU column, so throwaway candidate
     dispatches never dilute the steady-state attribution the report
-    exists for (even when they share a fingerprint with steady rows)."""
-    peak = (peak_tflops if peak_tflops else DEFAULT_PEAK_TFLOPS) * 1e12
+    exists for (even when they share a fingerprint with steady rows).
+
+    The MFU denominator is the explicit ``peak_tflops`` when given, else
+    the :data:`DEVICE_PEAKS` row of the device that compiled the
+    program; a program from a device not in the table gets no MFU."""
     if acct_by_fp is None:
         acct_by_fp = accounting()
         if probe_acct_by_fp is None:
@@ -486,10 +502,11 @@ def report_rows(peak_tflops=None, profiles_by_fp=None, acct_by_fp=None,
         if probe:
             row["probe"] = True
             row["mfu"] = None
-        elif p is not None and wall > 0 and p.flops:
-            row["mfu"] = round(p.flops * steps / wall / peak, 4)
         else:
-            row["mfu"] = None
+            peak = peak_tflops or (
+                bf16_peak_tflops(p.device_kind) if p is not None else None)
+            row["mfu"] = round(p.flops * steps / wall / (peak * 1e12), 4) \
+                if peak and wall > 0 and p.flops else None
         return row
 
     rows = [_row(fp, acct_by_fp.get(fp) or {}, profiles_by_fp.get(fp),

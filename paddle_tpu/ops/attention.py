@@ -74,9 +74,12 @@ def _fused_attention_compute(ins, attrs, ctx, op_index):
     else:
         post = None
 
+    from ..compile_cache import note_kernel_body
+
     mesh = getattr(ctx, "mesh", None)
     if mesh is not None and getattr(ctx, "sequence_parallel", True) \
             and _ring_applicable(mesh, q.shape, k.shape, causal):
+        note_kernel_body("fused_attention", "ring")
         out = _ring_attention(mesh, q, k, v, k_len, seed, causal, rate,
                               scale)
     else:
@@ -93,12 +96,16 @@ def _fused_attention_compute(ins, attrs, ctx, op_index):
         # it lifts the flag's seq gate for this shape (the VMEM budget
         # inside supported() still applies)
         max_seq = max(q.shape[2], k.shape[2]) if choice else None
+        # a requested kernel that supported() rejects gives way to the
+        # XLA body; note_kernel_body records which one this trace took
         if use_pallas and fa.supported(q.shape, k.shape, q.dtype,
                                        max_seq=max_seq):
             from .pallas import interpret_mode
+            note_kernel_body("fused_attention", "pallas")
             out = fa.flash_attention(q, k, v, k_len, seed, causal, rate,
                                      scale, interpret_mode(ctx))
         else:
+            note_kernel_body("fused_attention", "xla")
             out = fa.reference_attention(q, k, v, k_len, seed, causal, rate,
                                          scale)
     if post is not None:
@@ -219,6 +226,7 @@ def _paged_attention_compute(ins, attrs, ctx, op_index):
     from .pallas import flash_attention as fa
     from .pallas import interpret_mode
     from .. import autotune
+    from ..compile_cache import note_kernel_body
     from ..flags import flag
 
     # kernel selection on the GATHERED shape (the shape the kernel
@@ -227,11 +235,14 @@ def _paged_attention_compute(ins, attrs, ctx, op_index):
     tmax = table.shape[1] * k_pool.shape[2]
     k_shape = (q.shape[0], q.shape[1], tmax, q.shape[3])
     choice = autotune.attention_choice(q.shape, k_shape, q.dtype)
-    use_pallas = flag("pallas_kernels") if choice is None else choice
+    use_pallas = (flag("pallas_kernels") if choice is None else choice) \
+        and fa.supported(q.shape, k_shape, q.dtype)
+    note_kernel_body("paged_attention", "pallas" if use_pallas else "xla")
     out = fa.paged_attention(
         q, k_pool, v_pool, table, k_len, k_scale, v_scale,
         causal=attrs.get("causal", True), scale=scale,
-        use_pallas=use_pallas, interpret=interpret_mode(ctx))
+        use_pallas=use_pallas,
+        interpret=interpret_mode(ctx) if use_pallas else False)
     return {"Out": out}
 
 

@@ -67,7 +67,9 @@ def _swce_compute(ins, attrs, ctx, op_index):
         # mask there (-100 sentinel = none, matching the sigmoid variant)
         from ..flags import flag
         if flag("pallas_kernels"):
+            from ..compile_cache import note_kernel_body
             from .pallas import interpret_mode, softmax_xent as px
+            note_kernel_body("softmax_with_cross_entropy", "pallas")
             flat = logits.reshape(-1, logits.shape[-1])
             lbl = label.reshape(-1)
             loss, softmax = px.softmax_xent(flat, lbl, interpret_mode(ctx),

@@ -232,7 +232,9 @@ def _ln_compute(ins, attrs, ctx, op_index):
         from ..flags import flag
         if flag("pallas_kernels"):
             # opt-in hand-tiled kernel (ops/pallas/layer_norm.py)
+            from ..compile_cache import note_kernel_body
             from .pallas import interpret_mode, layer_norm as pln
+            note_kernel_body("layer_norm", "pallas")
             d = int(np.prod(x.shape[axis:]))
             flat = x.reshape(-1, d)
             y = pln.layer_norm(flat, scale.reshape(d), bias.reshape(d),

@@ -11,34 +11,34 @@ of the executor compile-cache key); default off — measurements on v5e
 (see bench notes in each module) show XLA's fused code is already at
 parity for these shapes, so the Pallas path is an opt-in escape hatch
 and the reference implementation for writing further kernels (ring
-attention etc.).  On CPU the kernels run in interpreter mode, which the
-tests use for numerical parity checks.
+attention etc.).  Under a ``CPUPlace`` the kernels run in interpreter
+mode, which the tests use for numerical parity checks; every call site
+records the body it lowered to (``compile_cache.note_kernel_body``).
 """
-
-import jax
 
 from ... import flags  # flag "pallas_kernels" is declared in flags.py
 
 
-def on_tpu():
-    try:
-        return any(d.platform == "tpu" for d in jax.local_devices())
-    except RuntimeError:  # backend not initialized yet
+def interpret_mode(ctx):
+    """Whether a Pallas call traced under ``ctx`` runs interpreted.
+
+    Decided ONLY by the platform of the device the executor places the
+    step on (``ctx.platform``, threaded from the Place / mesh at trace
+    time): "tpu" compiles through Mosaic — interpret mode is impossible
+    there, not merely unlikely — and "cpu" interprets.  Anything else
+    (no platform threaded, a backend with no Pallas path here) is an
+    error: guessing from which devices happen to exist is how a chip run
+    could quietly execute the interpreter, or a CPU-placed step receive
+    a Mosaic kernel."""
+    platform = getattr(ctx, "platform", None)
+    if platform == "tpu":
         return False
-
-
-def interpret_mode(ctx=None):
-    """Interpreter fallback for non-TPU execution.
-
-    The decision must follow the device the *executor* places the step on
-    (``ctx.platform``, threaded from the Place at trace time), not global
-    device presence: a CPUPlace run on a machine whose TPU plugin is loaded
-    would otherwise emit Mosaic kernels into a CPU-lowered module and fail.
-    """
-    platform = getattr(ctx, "platform", None) if ctx is not None else None
-    if platform is not None:
-        return platform != "tpu"
-    return not on_tpu()
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        "a Pallas kernel was selected but the trace context carries "
+        "platform=%r: the executor must thread its device's platform "
+        "('tpu' compiles via Mosaic, 'cpu' interprets)" % (platform,))
 
 
 def block_rows(n, row_bytes, max_rows, vmem_budget=4 * 1024 * 1024):

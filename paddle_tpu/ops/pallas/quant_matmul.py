@@ -78,7 +78,7 @@ def _wo_kernel(x_ref, qw_ref, s_ref, o_ref):
     acc = jnp.dot(x_ref[...].astype(jnp.float32),
                   qw_ref[...].astype(jnp.float32),
                   preferred_element_type=jnp.float32)
-    o_ref[...] = acc * s_ref[...][None, :]
+    o_ref[...] = acc * s_ref[...]
 
 
 def _dyn_kernel(x_ref, qw_ref, s_ref, o_ref, *, rng):
@@ -90,7 +90,7 @@ def _dyn_kernel(x_ref, qw_ref, s_ref, o_ref, *, rng):
     qx = jnp.clip(jnp.round(x / sx), -rng, rng).astype(jnp.int8)
     acc = jax.lax.dot_general(qx, qw_ref[...], (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.int32)
-    o_ref[...] = acc.astype(jnp.float32) * sx * s_ref[...][None, :]
+    o_ref[...] = acc.astype(jnp.float32) * sx * s_ref[...]
 
 
 def dequant_matmul(x2, qw, scale, mode="weight_only", bit_length=8,
@@ -110,7 +110,9 @@ def dequant_matmul(x2, qw, scale, mode="weight_only", bit_length=8,
         qw = jnp.pad(qw, ((0, kp - k), (0, np_ - n)))
     if np_ != n:
         scale = jnp.pad(scale, (0, np_ - n))
-    scale = scale.astype(jnp.float32)
+    # the scale rides as [1, N]: a 1-D (128,) block over [N] meets XLA's
+    # T(1024) layout for 1-D f32 operands and Mosaic refuses it
+    scale = scale.astype(jnp.float32).reshape(1, np_)
     if mode == "weight_only":
         kernel = _wo_kernel
     elif mode == "dynamic":
@@ -123,7 +125,7 @@ def dequant_matmul(x2, qw, scale, mode="weight_only", bit_length=8,
         grid=(mp // bm, np_ // _BN),
         in_specs=[pl.BlockSpec((bm, kp), lambda i, j: (i, 0)),
                   pl.BlockSpec((kp, _BN), lambda i, j: (0, j)),
-                  pl.BlockSpec((_BN,), lambda i, j: (j,))],
+                  pl.BlockSpec((1, _BN), lambda i, j: (0, j))],
         out_specs=pl.BlockSpec((bm, _BN), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,

@@ -225,6 +225,7 @@ def _dequant_matmul_compute(ins, attrs, ctx, op_index):
     n = qw.shape[-1]
 
     from .. import autotune
+    from ..compile_cache import note_kernel_body
     from ..flags import flag
     from .pallas import interpret_mode
     from .pallas import quant_matmul as qm
@@ -236,10 +237,12 @@ def _dequant_matmul_compute(ins, attrs, ctx, op_index):
     choice = autotune.quant_kernel_choice(m, k, n, x.dtype, mode)
     use_pallas = flag("pallas_kernels") if choice is None else choice
     if use_pallas and xscale is None and qm.supported(m, k, n, x.dtype):
+        note_kernel_body("dequant_matmul", "pallas")
         acc = qm.dequant_matmul(x2, qw, scale, mode=mode,
                                 bit_length=bits,
                                 interpret=interpret_mode(ctx))
     else:
+        note_kernel_body("dequant_matmul", "xla")
         acc = xla_dequant_matmul(x2, qw, scale, mode=mode, xscale=xscale,
                                  bit_length=bits)
     out = acc.astype(x.dtype).reshape(tuple(x.shape[:xnc]) + (n,))

@@ -20,19 +20,11 @@ import numpy as np
 import jax
 from jax.sharding import Mesh
 
-try:
-    from jax import shard_map as _shard_map    # jax >= 0.8
-    _REP_KW = {"check_vma": False}
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _REP_KW = {"check_rep": False}
-
 
 def shard_map_norep(f, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across the jax >= 0.8
-    (check_vma) and older (check_rep) spellings of the flag."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **_REP_KW)
+    """shard_map with replication (varying-manual-axes) checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 __all__ = ["make_mesh", "shard_map_norep", "AXIS_DP", "AXIS_FSDP",
            "AXIS_TP", "AXIS_PP", "AXIS_SP", "AXIS_EP"]

@@ -591,16 +591,9 @@ class ParallelExecutor:
                 fn = compiled.aot_exec \
                     if compiled.aot_exec is not None \
                     and not flags.flag("debug_nans") else compiled.fn
-                try:
-                    fetches, new_state = fn(feed_dev, state_dev, rng)
-                except (TypeError, ValueError):
-                    if fn is compiled.fn:
-                        raise
-                    # AOT executable rejected the args: permanent
-                    # fallback to the jit path for this entry
-                    compiled.aot_exec = None
-                    fetches, new_state = compiled.fn(feed_dev, state_dev,
-                                                     rng)
+                # no jit re-dispatch when the AOT executable rejects
+                # its args (see Executor.run)
+                fetches, new_state = fn(feed_dev, state_dev, rng)
         compiled.warm = True
 
         ok_flag = None

@@ -6,10 +6,13 @@ and the ``paddle.reader.creator.recordio`` reader creator.
 
 The hot path is C++ (``librecordio.cpp``: chunked layout, zlib
 compression, crc32 integrity, chunk-skip for sharded scans), compiled
-on first import with g++ and bound via ctypes — no pybind11 needed;
-records cross the boundary as (ptr, len) views.  A pure-python codec of
-the SAME on-disk format (``_pyimpl``) is the fallback when no compiler
-is available, and doubles as the cross-check oracle in tests.
+on first use with g++ (``native_build``: the binary is keyed on the
+source's content) and bound via ctypes — no pybind11 needed; records
+cross the boundary as (ptr, len) views.  A pure-python codec of the
+SAME on-disk format (``_pyimpl``) is the fallback when no compiler is
+available — announced once with a warning, and visible as
+``native_available() == False`` — and doubles as the cross-check oracle
+in tests.
 
 Chunk granularity is the sharding unit: ``num_chunks`` + per-chunk
 skipping let the elastic master (paddle_tpu.cloud) lease chunk spans to
@@ -20,32 +23,17 @@ recordio files (go/master/service.go partition over chunks).
 import ctypes
 import os
 import subprocess
-import tempfile
+import warnings
+
+from ..native_build import build_shared
 
 __all__ = ["Writer", "Scanner", "num_chunks", "reader_creator",
            "convert_reader_to_recordio_file", "native_available"]
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "librecordio.cpp")
-_LIB_PATH = os.path.join(_HERE, "_librecordio.so")
 _lib = None
 _native_failed = False
-
-
-def _build_native():
-    # build to a unique temp name: concurrent first imports (pytest
-    # workers, multi-host trainers on a shared FS) must not collide
-    fd, tmp = tempfile.mkstemp(dir=_HERE, prefix="_librecordio_",
-                               suffix=".so")
-    os.close(fd)
-    try:
-        cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SRC,
-               "-o", tmp, "-lz"]
-        subprocess.run(cmd, check=True, capture_output=True)
-        os.replace(tmp, _LIB_PATH)
-    finally:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
 
 
 def _load():
@@ -53,10 +41,8 @@ def _load():
     if _lib is not None or _native_failed:
         return _lib
     try:
-        if (not os.path.exists(_LIB_PATH) or
-                os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)):
-            _build_native()
-        lib = ctypes.CDLL(_LIB_PATH)
+        lib = ctypes.CDLL(build_shared(_HERE, "librecordio", [_SRC],
+                                       ldflags=["-lz"]))
         lib.rio_writer_open.restype = ctypes.c_void_p
         lib.rio_writer_open.argtypes = [ctypes.c_char_p, ctypes.c_int,
                                         ctypes.c_uint64]
@@ -81,9 +67,13 @@ def _load():
         lib.rio_num_chunks.restype = ctypes.c_int64
         lib.rio_num_chunks.argtypes = [ctypes.c_char_p]
         _lib = lib
-    except (OSError, subprocess.CalledProcessError):
+    except (OSError, subprocess.CalledProcessError) as e:
         _native_failed = True
         _lib = None
+        warnings.warn(
+            "recordio: native codec unavailable (%s: %s); using the "
+            "pure-Python codec of the same format"
+            % (type(e).__name__, str(e)[:200]), stacklevel=2)
     return _lib
 
 

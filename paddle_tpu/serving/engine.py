@@ -30,7 +30,7 @@ import threading
 import numpy as np
 
 from .. import io as fluid_io
-from ..executor import CPUPlace, Executor, TPUPlace
+from ..executor import Executor, default_place
 from ..monitor import tracing
 from ..profiler import RecordEvent
 from ..scope import Scope, scope_guard
@@ -40,15 +40,6 @@ from .scheduler import (ContinuousBatchingScheduler, PoisonedRequestError,
                         RequestTimeoutError)
 
 __all__ = ["InferenceEngine", "GenerationEngine"]
-
-
-def _default_place(place):
-    if place is not None:
-        return place
-    import jax
-
-    accel = any(d.platform != "cpu" for d in jax.local_devices())
-    return TPUPlace(0) if accel else CPUPlace()
 
 
 def _load_tuned(tuned_config):
@@ -199,7 +190,7 @@ class InferenceEngine(_EngineBase):
                  quarantine_dir=None, name="serving", start=True,
                  quantize=None):
         super().__init__()
-        self.place = _default_place(place)
+        self.place = default_place(place)
         self._exe = Executor(self.place, donate_state=False)
         if model_dir is not None:
             scope = Scope()
@@ -427,7 +418,7 @@ class GenerationEngine(_EngineBase):
                  quantize=None, draft_spec=None):
         super().__init__()
         self.spec = spec
-        self.place = _default_place(place)
+        self.place = default_place(place)
         self.eos_id = eos_id
         self.max_new_tokens = int(max_new_tokens)
         self.record_logits = bool(record_logits)

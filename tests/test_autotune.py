@@ -341,8 +341,6 @@ def test_tune_batch_size_fake_hbm_limit_and_zero_extra_compiles():
     exactly the declared ladder (one per probed rung, trace-cache
     counted), and re-measuring the chosen rung afterwards performs zero
     further lowerings (the window dispatches the seeded executable)."""
-    from jax._src import test_util as jtu
-
     from paddle_tpu.executor import Executor
     from paddle_tpu.scope import Scope, scope_guard
 
@@ -365,7 +363,7 @@ def test_tune_batch_size_fake_hbm_limit_and_zero_extra_compiles():
     # noisy enough to fire the (pure-function-tested) regression stop
     # before the ladder reaches the ceiling — this test pins the MEMORY
     # path, so the ladder must climb until the estimate rejects
-    with jtu.count_jit_and_pmap_lowerings() as n:
+    with compile_cache.count_compiles() as n:
         d = autotune.tune_batch_size(
             fluid.default_main_program(),
             fluid.default_startup_program(), make_feed, loss,
@@ -385,7 +383,7 @@ def test_tune_batch_size_fake_hbm_limit_and_zero_extra_compiles():
     # NEW probed rung (the cost_analysis explicit compile, whose
     # executable the measured window then dispatches); the pre-warmed
     # b16 rung and the startup program re-lower nothing
-    assert n[0] == len(probed) - 1, (n[0], d)
+    assert n()["jax_lowerings"] == len(probed) - 1, (n(), d)
     # warm re-measure of the chosen batch in a fresh scope/executor:
     # the trace cache + seeded AOT slot serve it, zero new lowerings
     from paddle_tpu.executor import Executor
@@ -395,11 +393,11 @@ def test_tune_batch_size_fake_hbm_limit_and_zero_extra_compiles():
     with scope_guard(scope):
         exe = Executor(fluid.CPUPlace())
         exe.run(fluid.default_startup_program(), scope=scope)
-        with jtu.count_jit_and_pmap_lowerings() as n2:
+        with compile_cache.count_compiles() as n2:
             autotune.measure_step_window(
                 exe, fluid.default_main_program(),
                 make_feed(d["chosen"]), [loss], steps=2, scope=scope)
-    assert n2[0] == 0, n2[0]
+    assert n2()["jax_lowerings"] == 0, n2()
     # the decision landed in the config with provenance
     assert cfg.value("batch_size") == d["chosen"]
     assert cfg.get("batch_size")["fingerprint"]
@@ -445,8 +443,6 @@ def test_tune_attention_kernel_ab_and_warm_table(tmp_path):
     feed = {n: rng.rand(b, n_head, T, dh).astype("float32")
             for n in "qkv"}
     shape = ((b, n_head, T, dh), (b, n_head, T, dh), "float32")
-    from jax._src import test_util as jtu
-
     cfg = autotune.TunedConfig()
     d = autotune.tune_attention_kernel(
         fluid.default_main_program(), fluid.default_startup_program(),
@@ -464,13 +460,13 @@ def test_tune_attention_kernel_ab_and_warm_table(tmp_path):
     # warm process: fresh table object reads the persisted ruling and
     # the tuner pays nothing — zero lowerings, zero measurement
     autotune.reset_attention_table()
-    with jtu.count_jit_and_pmap_lowerings() as n:
+    with compile_cache.count_compiles() as n:
         d2 = autotune.tune_attention_kernel(
             fluid.default_main_program(),
             fluid.default_startup_program(), feed, loss,
             fluid.CPUPlace(), shape=shape, probe_steps=2)
     assert d2.get("cached") and d2["pallas"] == d["pallas"]
-    assert n[0] == 0
+    assert n()["jax_lowerings"] == 0
     # and the op-level chooser serves the tuned ruling
     assert autotune.attention_choice(*shape) == d["pallas"]
 

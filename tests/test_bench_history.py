@@ -1,5 +1,5 @@
-"""tools/bench_history.py: cross-run bench regression tracking over the
-committed driver wrappers (BENCH_r*.json) and fresh bench.py artifacts —
+"""tools/bench_history.py: cross-run bench regression tracking over
+driver wrappers (BENCH_r*.json) and fresh bench.py artifacts —
 legacy-methodology gating, noise-band verdicts, the +20% synthetic
 perturbation gate, and bare-artifact (schema v2) ingestion."""
 
@@ -47,13 +47,32 @@ def _write(tmp_path, name, payload):
     return str(p)
 
 
+def _history(tmp_path):
+    """The four wrapper shapes a driver history holds, as files: two
+    legacy runs (rungs without ``min_step_s``/``n_windows``), one
+    comparable run with extra rungs, and an rc=124 timeout with no
+    parsed line."""
+    legacy2 = _rung("resnet50_images_per_sec_bf16", 7903.64)
+    legacy2["extra_metrics"] = [
+        _rung("transformer_base_tokens_per_sec_bf16", 137013.68)]
+    ok = _rung("resnet50_images_per_sec_bf16", 2334.75, step_s=0.054824,
+               est_mfu=0.1458)
+    ok["extra_metrics"] = [
+        _rung("transformer_base_tokens_per_sec_bf16", 133219.73,
+              step_s=0.061492, est_mfu=0.2455)]
+    wrappers = [_wrapper(1, _rung("resnet50_images_per_sec", 7966.2)),
+                _wrapper(2, legacy2), _wrapper(3, ok),
+                _wrapper(4, None, rc=124)]
+    return [_write(tmp_path, "BENCH_r%02d.json" % w["n"], w)
+            for w in wrappers], ok
+
+
 def test_committed_artifact_evolution_passes(tmp_path):
-    """The r01->r04 history: r01/r02 predate the fetch-sync methodology
+    """A r01->r04 history: r01/r02 predate the fetch-sync methodology
     (legacy, never baselines), r04 is an rc=124 timeout with no parsed
     line (incomplete), r03 is the first comparable run — the evolution
     PASSes."""
-    paths = [os.path.join(ROOT, "BENCH_r%02d.json" % i)
-             for i in (1, 2, 3, 4)]
+    paths, _ = _history(tmp_path)
     runs = [bench_history.load_artifact(p, i) for i, p in
             enumerate(paths)]
     by = {r["run"]: r for r in runs}
@@ -68,20 +87,15 @@ def test_committed_artifact_evolution_passes(tmp_path):
 
 def test_synthetic_perturbation_regresses(tmp_path):
     """A +20% step-time copy of r03 (value scaled down accordingly)
-    must come back REGRESSED against the committed history — the CI
-    gate's self-check."""
-    with open(os.path.join(ROOT, "BENCH_r03.json")) as f:
-        r03 = json.load(f)
+    must come back REGRESSED against the history — the CI gate's
+    self-check."""
+    paths, r03 = _history(tmp_path)
     bad = copy.deepcopy(r03)
-    bad["n"] = 5
-    bad["parsed"]["min_step_s"] = round(
-        r03["parsed"]["min_step_s"] * 1.2, 6)
-    bad["parsed"]["value"] = round(r03["parsed"]["value"] / 1.2, 2)
-    p = _write(tmp_path, "BENCH_r05.json", bad)
+    bad["min_step_s"] = round(r03["min_step_s"] * 1.2, 6)
+    bad["value"] = round(r03["value"] / 1.2, 2)
+    p = _write(tmp_path, "BENCH_r05.json", _wrapper(5, bad))
     out = subprocess.run(
-        [sys.executable, TOOL] +
-        [os.path.join(ROOT, "BENCH_r%02d.json" % i)
-         for i in (1, 2, 3, 4)] + [p, "--json"],
+        [sys.executable, TOOL] + paths + [p, "--json"],
         stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
     assert out.returncode == 1, out.stderr
     report = json.loads(out.stdout)

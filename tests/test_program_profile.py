@@ -148,8 +148,6 @@ def test_profile_capture_costs_zero_extra_lowerings():
     own counters plus the trace cache's) are IDENTICAL between a
     profile-off and a profile-on run of the same fresh program — the
     capture is the one compile, not an extra one."""
-    from jax._src import test_util as jtu
-
     def arm():
         with fluid.program_guard(fluid.Program(), fluid.Program()):
             loss = _build_mlp()
@@ -163,23 +161,23 @@ def test_profile_capture_costs_zero_extra_lowerings():
     assert not program_profile.capture_enabled()
     compile_cache.clear()
     compile_cache.reset_stats()
-    with jtu.count_jit_and_pmap_lowerings() as off_n, \
-            jtu.count_jit_compilation_cache_miss() as off_c:
+    with compile_cache.count_compiles() as off_n:
         arm()
-    off_cc = compile_cache.stats()["lowerings"]
 
     monitor.enable()
     assert program_profile.capture_enabled()
     compile_cache.clear()
     compile_cache.reset_stats()
-    with jtu.count_jit_and_pmap_lowerings() as on_n, \
-            jtu.count_jit_compilation_cache_miss() as on_c:
+    with compile_cache.count_compiles() as on_n:
         arm()
-    on_cc = compile_cache.stats()["lowerings"]
 
-    assert on_n[0] == off_n[0], "profile capture added jax lowerings"
-    assert on_c[0] == off_c[0], "profile capture added backend compiles"
-    assert on_cc == off_cc, "profile capture added trace-cache lowerings"
+    on, off = on_n(), off_n()
+    assert on["jax_lowerings"] == off["jax_lowerings"], \
+        "profile capture added jax lowerings"
+    assert on["jax_backend_compiles"] == off["jax_backend_compiles"], \
+        "profile capture added backend compiles"
+    assert on["lowerings"] == off["lowerings"], \
+        "profile capture added trace-cache lowerings"
     assert program_profile.profiles(), "profile-on arm captured nothing"
 
 
@@ -259,15 +257,13 @@ def test_preflight_normal_run_unaffected():
 # ---------------------------------------------------------------------------
 
 def test_cost_analysis_free_on_warm_program():
-    from jax._src import test_util as jtu
-
     monitor.enable()
     loss = _build_mlp()
     exe = _run_steps(loss, steps=2)
     feed = {"x": np.zeros((8, 4), "float32")}
-    with jtu.count_jit_and_pmap_lowerings() as n:
+    with compile_cache.count_compiles() as n:
         ca = exe.cost_analysis(feed=feed, fetch_list=[loss])
-    assert n[0] == 0, "warm cost_analysis paid a lowering"
+    assert n()["jax_lowerings"] == 0, "warm cost_analysis paid a lowering"
     assert ca["flops"] > 0 and ca["bytes accessed"] > 0
     # compile_if_missing=False on a never-analyzed signature -> None
     cold = {"x": np.zeros((16, 4), "float32")}     # unseen batch size
@@ -302,8 +298,6 @@ def test_cost_analysis_distinguishes_fetch_sets():
 def test_cost_analysis_fallback_seeds_registry():
     """A never-run program pays one explicit compile, after which the
     registry serves repeats for free."""
-    from jax._src import test_util as jtu
-
     fluid.set_flags({"FLAGS_preflight_oom": "off"})    # no auto-capture
     loss = _build_mlp()
     exe = fluid.Executor(fluid.CPUPlace())
@@ -311,9 +305,9 @@ def test_cost_analysis_fallback_seeds_registry():
     feed = {"x": np.zeros((4, 4), "float32")}
     ca = exe.cost_analysis(feed=feed, fetch_list=[loss])
     assert ca["flops"] > 0
-    with jtu.count_jit_and_pmap_lowerings() as n:
+    with compile_cache.count_compiles() as n:
         ca2 = exe.cost_analysis(feed=feed, fetch_list=[loss])
-    assert n[0] == 0 and ca2["flops"] == ca["flops"]
+    assert n()["jax_lowerings"] == 0 and ca2["flops"] == ca["flops"]
 
 
 # ---------------------------------------------------------------------------

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import autotune
+from paddle_tpu import autotune, compile_cache
 from paddle_tpu.transpiler import quantize_inference
 from paddle_tpu.transpiler.quantize_pass import QUANT_SUFFIX, SCALE_SUFFIX
 
@@ -68,8 +68,6 @@ def test_pass_rewrites_weights_and_matches_fp(mode):
         assert delta < 0.02, delta
         # distinct fingerprint: the goodput/program-profile stack
         # attributes the quantized program separately for free
-        from paddle_tpu import compile_cache
-
         assert compile_cache.program_fingerprint(q) != \
             compile_cache.program_fingerprint(main)
 
@@ -308,8 +306,6 @@ def test_dynamic_mode_consumes_qat_activation_scale():
 # ---------------------------------------------------------------------------
 
 def test_save_load_round_trip_cold_and_zero_warm_lowerings(tmp_path):
-    from jax._src import test_util as jtu
-
     main, startup, pred = _fc_program()
     scope = fluid.Scope()
     exe = _init(startup, scope)
@@ -340,9 +336,9 @@ def test_save_load_round_trip_cold_and_zero_warm_lowerings(tmp_path):
         assert autotune.eval_delta([ref], [out]) < 0.02
         # warm serving path: a second dispatch of the same signature
         # performs ZERO lowerings
-        with jtu.count_jit_and_pmap_lowerings() as n:
+        with compile_cache.count_compiles() as n:
             (out2,) = exe.run(prog2, feed=feed, fetch_list=fetches)
-        assert n[0] == 0, n[0]
+        assert n()["jax_lowerings"] == 0, n()
         np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
 
 

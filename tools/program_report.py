@@ -82,7 +82,8 @@ def rows_from_records(records, peak_tflops=None, run_id=None):
                 generated_code_bytes=r.get("generated_code_bytes", 0),
                 alias_bytes=r.get("alias_bytes", 0),
                 peak_hbm_bytes=r.get("peak_hbm_bytes", 0),
-                device=r.get("device"))
+                device=r.get("device"),
+                device_kind=r.get("device_kind"))
         elif ev == "step_stats" and r.get("fingerprint"):
             # tuner-probe steps (tagged by probe_accounting at record
             # time) accumulate separately, mirroring note_step: probe
@@ -159,7 +160,9 @@ def main(argv=None):
                                "FLAGS_monitor_log_dir directory")
     p.add_argument("--peak_tflops", type=float, default=None,
                    help="chip peak TFLOP/s for the MFU column "
-                        "(default: BENCH_PEAK_TFLOPS env or 197)")
+                        "(default: the DEVICE_PEAKS row of each "
+                        "program's recorded device_kind; an unlisted "
+                        "device gets no MFU)")
     p.add_argument("--run_id", default=None,
                    help="only records of this run correlation id")
     p.add_argument("--top", type=int, default=0,

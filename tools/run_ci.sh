@@ -353,22 +353,33 @@ PY
 python tools/goodput_report.py "$GOOD_DIR/monitor" | tee "$GOOD_DIR/report.txt"
 grep -q "goodput ratio" "$GOOD_DIR/report.txt"
 grep -q "trace_compile" "$GOOD_DIR/report.txt"
-# (b) cross-run regression gate: the committed BENCH_r01-r04 evolution
-# PASSes, and a synthetically perturbed (+20% step time) copy of the
-# newest comparable artifact comes back REGRESSED
-python tools/bench_history.py BENCH_r0*.json --json \
-  | python -c "import json,sys; r=json.load(sys.stdin); \
-assert r['overall']=='PASS', r['overall']; print('bench_history: committed history PASS')"
+# (b) cross-run regression gate: a four-wrapper history (two legacy
+# runs, one comparable, one rc=124 with no parsed line) PASSes, and a
+# synthetically perturbed (+20% step time) copy of the comparable run
+# comes back REGRESSED
 python - "$GOOD_DIR" <<'PY'
 import copy, json, sys
-d = json.load(open("BENCH_r03.json"))
-p = copy.deepcopy(d); p["n"] = 99
-p["parsed"]["min_step_s"] = round(d["parsed"]["min_step_s"] * 1.2, 6)
-p["parsed"]["value"] = round(d["parsed"]["value"] / 1.2, 2)
-json.dump(p, open(sys.argv[1] + "/BENCH_r99_perturbed.json", "w"))
+out = sys.argv[1]
+def wrapper(n, parsed, rc=0):
+    json.dump({"n": n, "cmd": "python bench.py", "rc": rc, "tail": "",
+               "parsed": parsed}, open("%s/BENCH_r%02d.json" % (out, n), "w"))
+def rung(value, **kw):
+    return dict({"metric": "resnet50_images_per_sec_bf16", "value": value,
+                 "unit": "images/sec", "vs_baseline": 1.0}, **kw)
+ok = rung(2334.75, min_step_s=0.054824, n_windows=3, est_mfu=0.1458)
+wrapper(1, rung(7966.2)); wrapper(2, rung(7903.64)); wrapper(3, ok)
+wrapper(4, None, rc=124)
+bad = copy.deepcopy(ok)
+bad["min_step_s"] = round(ok["min_step_s"] * 1.2, 6)
+bad["value"] = round(ok["value"] / 1.2, 2)
+json.dump({"n": 99, "cmd": "python bench.py", "rc": 0, "tail": "",
+           "parsed": bad}, open(out + "/BENCH_r99_perturbed.json", "w"))
 PY
+python tools/bench_history.py "$GOOD_DIR"/BENCH_r0*.json --json \
+  | python -c "import json,sys; r=json.load(sys.stdin); \
+assert r['overall']=='PASS', r['overall']; print('bench_history: history PASS')"
 set +e
-python tools/bench_history.py BENCH_r0*.json "$GOOD_DIR/BENCH_r99_perturbed.json" \
+python tools/bench_history.py "$GOOD_DIR"/BENCH_r0*.json "$GOOD_DIR/BENCH_r99_perturbed.json" \
   --json > "$GOOD_DIR/history.json"
 rc=$?
 set -e

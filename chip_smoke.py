@@ -11,8 +11,17 @@ from seeds; nothing downloads.
 
 It never runs on the CPU: no TPU, or a device that is not in the peaks
 table, is an error (exit code 2, no result line).  A phase that fails
-raises — nothing is caught and carried on — so the last line of standard
-output is the JSON summary only when every phase passed.
+raises — nothing is caught and carried on — so standard output carries a
+result only when every phase passed.  That result is the last (and only)
+line of standard output, one JSON object of exactly this shape, the device
+as JAX reports it:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+Everything else goes to standard error: progress, and before the result a
+``[chip_smoke] report {...}`` line with the versions, the cache directory,
+which kernel bodies were lowered, and per phase ``seconds`` /
+``compile_seconds`` / persistent-cache hits, ending with ``"claim": null``.
 
 A CPU run of the *tests* yields correctness and counts; the step times this
 script prints are information labelled with the device, not a benchmark.
@@ -76,6 +85,16 @@ TOL_KERNEL = {"matmul": 2e-2, "float32": 1e-4, "bfloat16": 2e-2}
 def log(msg):
     # progress goes to stderr: standard output carries the result line only
     print("[chip_smoke] " + msg, file=sys.stderr, flush=True)
+
+
+def result_line(devices, ok=True):
+    """The one line standard output carries: exactly ``ok`` and ``device``
+    (``platform``, ``kind``, ``count``), the device as JAX reports it."""
+    return json.dumps({
+        "ok": bool(ok),
+        "device": {"platform": str(devices[0].platform),
+                   "kind": str(devices[0].device_kind),
+                   "count": len(devices)}})
 
 
 def die(msg):
@@ -650,11 +669,7 @@ def main():
     run("serve_1chip", phase_serve_1chip)
     run("train_4chip", phase_train_4chip, train["losses"][0])
 
-    devs = jax.devices()
-    summary = {
-        "ok": True,
-        "device": {"platform": devs[0].platform,
-                   "kind": devs[0].device_kind, "count": len(devs)},
+    report = {
         "versions": {p: importlib.metadata.version(p)
                      for p in ("jax", "jaxlib", "libtpu")},
         "compile_cache_dir": cache_dir,
@@ -663,7 +678,8 @@ def main():
         "phases": phases,
         "claim": None,
     }
-    print(json.dumps(summary), flush=True)
+    log("report " + json.dumps(report))
+    print(result_line(jax.devices()), flush=True)
 
 
 if __name__ == "__main__":

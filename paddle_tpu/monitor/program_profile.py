@@ -277,7 +277,7 @@ def _device_capacity(device):
         return None
     try:
         ms = device.memory_stats()
-    except Exception:  # noqa: BLE001 — CPU/older backends
+    except Exception:  # noqa: BLE001 — a backend without memory stats
         return None
     if not ms:
         return None

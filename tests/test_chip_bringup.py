@@ -97,6 +97,19 @@ def test_chip_smoke_refuses_to_run_without_a_tpu():
     assert r.stdout == ""                          # no result line
 
 
+def test_chip_smoke_result_line_has_exactly_the_contract_keys():
+    """The driver parses the last stdout line: exactly ``ok`` and
+    ``device`` = {platform, kind (text), count (a whole number)}; the
+    per-phase report goes to stderr, not into this object."""
+    r = _run("import types, chip_smoke\n"
+             "d = types.SimpleNamespace(platform='tpu',"
+             " device_kind='TPU v5 lite')\n"
+             "print(chip_smoke.result_line([d]))\n")
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout == ('{"ok": true, "device": {"platform": "tpu", '
+                        '"kind": "TPU v5 lite", "count": 1}}\n')
+
+
 def test_bench_ladder_parent_stays_off_the_jax_backend():
     """A chip belongs to one process: the ladder's parent resolves its
     device (auto = the chip, never the CPU) and walks the rung list

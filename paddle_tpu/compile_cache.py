@@ -6,8 +6,13 @@ Two layers, addressing the two costs a repeated step shape pays:
   jax/XLA on-disk executable cache, keyed by HLO fingerprint.  Survives
   process restarts — bench-ladder rungs, chip runs, and training restarts
   with the same program+signature skip XLA's optimization pipeline and
-  deserialize the executable instead.  Where it lives is decided by ONE
-  resolver, ``persistent_cache_dir``: ``JAX_COMPILATION_CACHE_DIR`` when
+  deserialize the executable instead.  Metadata (scope names, source
+  lines) is NOT part of jax's key; the module name is, and ``name_step``
+  derives it from the program's fingerprint, so a renamed Fluid variable
+  or op never reads another tree's names back (only a respelling of
+  ``registry.fluid_scope_name`` itself would: clear the directory then).
+  Where it lives is decided by
+  ONE resolver, ``persistent_cache_dir``: ``JAX_COMPILATION_CACHE_DIR`` when
   the environment sets it (nothing in code overrides that), else
   ``FLAGS_compile_cache_dir``, else — for chip entry points only — one
   fixed directory inside the checkout.  A bare ``import paddle_tpu``
@@ -38,7 +43,8 @@ import threading
 from .profiler import mark_event
 
 __all__ = [
-    "program_fingerprint", "trace_key", "trace_flag_values", "lookup",
+    "program_fingerprint", "program_label", "name_step", "trace_key",
+    "trace_flag_values", "lookup",
     "store", "stats", "reset_stats", "clear", "note_kernel_body",
     "count_compiles", "persistent_cache_dir", "enable_persistent_cache",
     "rescope_persistent_cache", "CHECKOUT_CACHE_DIR",
@@ -127,6 +133,25 @@ def program_fingerprint(program):
     fp = h.hexdigest()
     program._fp_cache = (memo_key, fp)
     return fp
+
+
+def program_label(program):
+    """What a compiled program is called in a profiler trace: the
+    program's own ``_label`` (``serving/decoder.py`` names its prefill
+    and decode-tick programs) or the first 8 hex digits of its
+    fingerprint.  A label is not structure: it enters neither the
+    fingerprint nor any cache key of this module."""
+    label = getattr(program, "_label", None)
+    return label or program_fingerprint(program)[:8]
+
+
+def name_step(fn, kind, program):
+    """Name the traced step function before it is jitted, so the compiled
+    module reads ``jit_pt_<kind>_<label>`` in the device trace's ``XLA
+    Modules`` line instead of ``jit_fn`` (``kind``: ``exe`` for Executor,
+    ``pe`` for ParallelExecutor).  Returns ``fn``."""
+    fn.__name__ = fn.__qualname__ = "pt_%s_%s" % (kind, program_label(program))
+    return fn
 
 
 def trace_key(program, feed_sig, state_sig, fetch_names, *extras):

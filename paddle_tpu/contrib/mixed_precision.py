@@ -65,6 +65,13 @@ class AMPPolicy:
     def __init__(self, amp_lists=None):
         self.lists = amp_lists or AutoMixedPrecisionLists()
 
+    def __repr__(self):
+        # by content, not by address: compile_cache.program_fingerprint
+        # hashes this, and a compiled program's name is taken from the
+        # fingerprint — it has to come out the same in the next process
+        return "AMPPolicy(white=%s, black=%s)" % (
+            sorted(self.lists.white_list), sorted(self.lists.black_list))
+
     def cast_inputs(self, op_type, ins):
         """Return ``ins`` with float32<->bf16 casts applied per the lists.
         Grad ops follow their forward op's color (the generic auto-vjp

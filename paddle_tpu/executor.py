@@ -537,7 +537,8 @@ class Executor:
                     fn, probe, len(fetch_names), guarded, state_in,
                     state_out)
             donate = (1,) if self.donate_state else ()
-            jitted = jax.jit(fn, donate_argnums=donate)
+            jitted = jax.jit(compile_cache.name_step(fn, "exe", program),
+                             donate_argnums=donate)
         return compile_cache.store(tkey, _CompiledProgram(
             jitted, feed_names, state_in, state_out, fetch_names,
             guarded=guarded, probe=probe))
@@ -553,6 +554,12 @@ class Executor:
     ):
         """Execute ``program``: feed dict name->array, fetch list of
         Variables/names; persistable results are committed back to scope."""
+        # the whole call is one span, so that a profiler trace with no
+        # span of the caller's in it still has the step
+        with RecordEvent("executor/step"):
+            return self._run(program, feed, fetch_list, scope, return_numpy)
+
+    def _run(self, program, feed, fetch_list, scope, return_numpy):
         if program is None:
             program = default_main_program()
         feed = dict(feed or {})

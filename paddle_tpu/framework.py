@@ -408,6 +408,9 @@ class Program:
         self._version = 0
         # trace-time mixed-precision policy (contrib.mixed_precision)
         self._amp_policy = None
+        # optional name of this program's compiled module in a profiler
+        # trace (compile_cache.program_label); not structure, not hashed
+        self._label = None
 
     # ---- block management --------------------------------------------------
     def global_block(self):

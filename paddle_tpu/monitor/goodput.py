@@ -103,6 +103,8 @@ SPAN_BUCKETS = {
 EXCLUDED_SPANS = {
     "executor/trace": "nested inside executor/compile",
     "parallel_executor/trace": "nested inside parallel_executor/compile",
+    "executor/step": "container (the whole run() call)",
+    "parallel_executor/step": "container (the whole run() call)",
     "executor/run": "container (whole step)",
     "parallel_executor/run": "container (whole step)",
     "executor/dispatch": "step remainder (compute)",

@@ -355,7 +355,7 @@ class ParallelExecutor:
         # jax.jit here is lazy (tracing deferred to the first call): no
         # span — the real jaxpr cost is the trace_program above
         jitted = jax.jit(
-            fn,
+            compile_cache.name_step(fn, "pe", program),
             in_shardings=(feed_shardings, state_shardings, None),
             out_shardings=(fetch_shardings, out_state_shardings),
             donate_argnums=donate,
@@ -458,6 +458,11 @@ class ParallelExecutor:
 
     # ------------------------------------------------------------------
     def run(self, fetch_list, feed=None, feed_dict=None, return_numpy=True):
+        # the whole call is one span (see Executor.run)
+        with RecordEvent("parallel_executor/step"):
+            return self._run(fetch_list, feed, feed_dict, return_numpy)
+
+    def _run(self, fetch_list, feed, feed_dict, return_numpy):
         program = self._program or default_main_program()
         scope = self._actual_scope()
         mon_t0 = time.perf_counter() if monitor.enabled() else None

@@ -6,6 +6,15 @@ every op run), ``platform/device_tracer`` (CUPTI kernel timestamps),
 managers ``fluid/profiler.py:221`` — TPU-native: device-side tracing
 delegates to ``jax.profiler`` (XPlane/TensorBoard), host-side named spans
 are collected here and exported as chrome-trace JSON directly.
+
+One span, three sinks.  Every ``RecordEvent`` / ``mark_event`` is also a
+``jax.profiler.TraceAnnotation("pt/<name>")``: whenever ANY jax profiler
+trace is being taken (``start_profiler(trace_dir=...)``, a bare
+``jax.profiler.start_trace``, the benchmark's traced run) the span lands on
+the calling thread's line of the ``/host:CPU`` plane of the ``.xplane.pb``,
+on the device trace's time base.  The ``pt/`` prefix is the annotation's
+only: the Python list (chrome export, ``tools/trace_summary.py``) and the
+monitor's ``span/<name>`` histograms keep the bare name.
 """
 
 import contextlib
@@ -13,6 +22,8 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 from . import monitor
 
@@ -32,6 +43,8 @@ _jax_trace_dir = [None]
 # the chrome-trace M-phase thread_name metadata (dispatch/prefetch
 # worker threads are labeled in the timeline instead of raw tids)
 _thread_names = {}
+# what a span or mark is called in a jax profiler trace: "pt/" + its name
+ANNOTATION_PREFIX = "pt/"
 
 
 def _now_us():
@@ -71,6 +84,11 @@ class RecordEvent:
     post-hoc.  Completed spans double-publish into the monitor's
     ``span/<name>`` histograms whenever the monitor is on, so the two
     observability layers agree with or without a profiler session.
+
+    Independently of both, the span is a ``TraceAnnotation("pt/<name>")``:
+    TraceMe decides by itself (one atomic load) whether a jax trace is
+    being taken.  With nothing on, a span costs one object and two C calls
+    (PERF.md has the nanoseconds) — keep it out of per-op loops.
     """
 
     def __init__(self, name, args=None):
@@ -83,15 +101,18 @@ class RecordEvent:
         self.t0 = None
         self._prof = False
         self._mon = False
+        self._annotation = TraceAnnotation(ANNOTATION_PREFIX + name)
 
     def __enter__(self):
         self._prof = _enabled[0]
         self._mon = monitor.enabled()
         if self._prof or self._mon:
             self.t0 = _now_us()
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._annotation.__exit__(*exc)
         if self.t0 is None:
             return False
         dur = _now_us() - self.t0
@@ -113,6 +134,8 @@ def mark_event(name):
     other point occurrences, countable in the summary and visible in the
     chrome trace next to the ``RecordEvent`` spans.  Double-publishes as
     a ``mark/<name>`` monitor counter when the monitor is on."""
+    with TraceAnnotation(ANNOTATION_PREFIX + name):
+        pass
     if monitor.enabled():
         monitor.mark(name)
     if not _enabled[0]:
@@ -122,7 +145,15 @@ def mark_event(name):
 
 def start_profiler(state="All", trace_dir=None):
     """state ∈ {CPU, GPU, All} for parity; device tracing uses
-    jax.profiler when a trace_dir is given."""
+    jax.profiler when a trace_dir is given.
+
+    With a ``trace_dir`` one session leaves TWO files, on two clocks:
+    ``stop_profiler``'s ``profile_path`` is the chrome-trace JSON of the
+    host spans alone, on ``time.perf_counter``; ``<trace_dir>/plugins/
+    profile/<time>/*.xplane.pb`` is jax's trace — device operations under
+    their Fluid scopes and module names AND the same host spans as
+    ``pt/<name>`` annotations, on the profiler's own clock.  The two clocks
+    share no origin: correlate host with device in the ``.xplane.pb``."""
     _enabled[0] = True
     if trace_dir and state in ("GPU", "All"):
         import jax

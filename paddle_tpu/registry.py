@@ -22,6 +22,8 @@ Capability parity with the reference's op registry stack
   dims.  This is the build-time half of the reference's InferShape.
 """
 
+import re
+
 import numpy as np
 
 import jax
@@ -134,6 +136,31 @@ def infer_op(op, block):
         d.infer(op, block)
 
 
+_SCOPE_UNSAFE = re.compile(r"[^\w.\-]")
+
+
+def fluid_scope_name(op):
+    """``fluid[<op type>]<first output variable>``: the ``jax.named_scope``
+    one lowered Fluid op runs under.  The ``fluid[`` marker is what readers
+    of a trace match (``benchmark/trace/scopes.py``): no entry of jax's own
+    name stack (``jit(..)``, ``jvp(..)``, ``transpose(..)``, ``checkpoint``,
+    ``shard_map``) is spelled with a bracket.  Of the output's name only
+    letters, digits, ``_``, ``.`` and ``-`` are kept, anything else becomes
+    ``.`` (``fc_0.tmp_0@GRAD`` -> ``fc_0.tmp_0.GRAD``): ``/`` separates the
+    stack's entries, ``:`` ends a name in the profiler's ``tf_op``, and XLA
+    reads a location ``<name>@<function>`` and keeps the name only — an
+    ``@`` cut the variable AND the rest of the stack (my chip run, PR 24).
+
+    Scope names are metadata, which jax leaves out of its persistent-cache
+    key: respell this function and a warm cache keeps serving the old
+    names under an unchanged module name (seen on the chip, PR 24) until
+    the directory is cleared.  A renamed variable or op is safe: it moves
+    the fingerprint, and with it ``compile_cache.name_step``'s module
+    name, which IS in the key."""
+    out = next((n for names in op.outputs.values() for n in names if n), "")
+    return "fluid[%s]%s" % (op.type, _SCOPE_UNSAFE.sub(".", out))
+
+
 def compute_op(op, env, ctx, op_index=0):
     """Execute one op inside a trace: read inputs from env, write outputs."""
     d = get_op_def(op.type)
@@ -157,13 +184,17 @@ def compute_op(op, env, ctx, op_index=0):
             else:
                 vals.append(env[n])
         ins[slot] = vals
-    if ctx.amp is not None:
-        ins = ctx.amp.cast_inputs(op.type, ins)
     # save/restore: region ops (pipeline_region, control flow) re-enter
     # compute_op for their body ops under the same ctx
     prev_op, ctx.op = ctx.op, op
     try:
-        outs = d.compute(ins, op.attrs, ctx, op_index)
+        # everything this op traces — the AMP casts, the kernel, the
+        # generic-gradient vjp — carries the op's Fluid name in the
+        # profiler's trace; region bodies nest, the innermost scope owns
+        with jax.named_scope(fluid_scope_name(op)):
+            if ctx.amp is not None:
+                ins = ctx.amp.cast_inputs(op.type, ins)
+            outs = d.compute(ins, op.attrs, ctx, op_index)
     finally:
         ctx.op = prev_op
     for slot, names in op.outputs.items():

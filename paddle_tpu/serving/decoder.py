@@ -369,6 +369,11 @@ def build_decoder_lm(vocab_size, max_len, slots, n_layer=2, n_head=2,
             logits = _fc(x, vocab_size, prefix + "_logits")
             programs["verify"] = (verify, logits)
 
+    # the names the compiled modules carry in a profiler trace
+    # (jit_pt_exe_<prefix>_prefill, .._decode, ..): a decode tick can be
+    # told from a prefill and from the transfers around it
+    for kind, (prog, _) in programs.items():
+        prog._label = "%s_%s" % (prefix, kind)
     return DecoderSpec(vocab_size, max_len, slots, n_layer, n_head,
                        d_model, d_inner, cache, programs, startup,
                        spec_k=spec_k)

@@ -26,9 +26,13 @@ def fresh_programs():
     (the reference achieves this with new Program() per test; we reset the
     singletons)."""
     import paddle_tpu as fluid
-    from paddle_tpu import framework, unique_name
+    from paddle_tpu import flags, framework, unique_name
     from paddle_tpu.scope import Scope
 
+    # the benchmark's builder sets FLAGS_fast_prng for the process (a
+    # benchmark run owns it); an xdist worker goes on to other files,
+    # where a leaked rbg PRNG changes every seeded initialisation
+    prng, prng_pinned = flags.flag("fast_prng"), flags.pinned("fast_prng")
     old_main = framework.switch_main_program(fluid.Program())
     old_startup = framework.switch_startup_program(fluid.Program())
     old_gen = unique_name.switch()
@@ -38,6 +42,8 @@ def fresh_programs():
     framework.switch_main_program(old_main)
     framework.switch_startup_program(old_startup)
     unique_name.switch(old_gen)
+    flags.set_flags({"FLAGS_fast_prng": prng}, pin=False)
+    flags._restore_pins({"fast_prng": prng_pinned})
     # the per-test unique_name reset makes structurally identical
     # programs from DIFFERENT tests fingerprint-collide in the
     # process-global trace cache; drop it so a monkeypatched op in one

@@ -47,6 +47,7 @@ from paddle_tpu.models import transformer as tfm
 from paddle_tpu.monitor import program_profile
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import layer_norm as pallas_ln
+from paddle_tpu.ops.pallas import packed_attention as pa
 from paddle_tpu.ops.pallas import quant_matmul as pallas_qm
 from paddle_tpu.ops.pallas import softmax_xent as pallas_xent
 from paddle_tpu.ops.quantize import xla_dequant_matmul
@@ -221,13 +222,34 @@ def phase_device():
     return {"peaks": peaks}
 
 
+def attention_bodies():
+    return {k: n for k, n in compile_cache.stats()["kernel_bodies"].items()
+            if k.startswith("fused_attention")}
+
+
+def assert_packed(before, attentions, what):
+    """Every attention of the program traced since ``before`` took the
+    packed short-sequence kernel, forward and gradient, and no other
+    body."""
+    got = {k: n - before.get(k, 0) for k, n in attention_bodies().items()
+           if n != before.get(k, 0)}
+    want = {"fused_attention:packed": attentions,
+            "fused_attention_grad:packed": attentions}
+    if got != want:
+        raise AssertionError("%s: attention bodies %s, expected %s"
+                             % (what, got, want))
+
+
 def phase_train_1chip():
     place = fluid.TPUPlace(0)
     dev = place.jax_device()
     main, startup, loss = build_transformer(SEQ, LAYERS, 0.1, amp=True)
+    bodies = attention_bodies()
     losses, secs, new, scope, init = run_train(
         place, main, startup, loss, TRAIN_STEPS, BATCH, SEQ)
     log("train_1chip losses: %s" % " ".join("%.4f" % v for v in losses))
+    # encoder self, decoder self, decoder cross: three a layer
+    assert_packed(bodies, 3 * LAYERS, "train_1chip")
     assert_on_device(scope, [dev])
     if not all(math.isfinite(v) for v in losses):
         raise AssertionError("non-finite loss: %s" % losses)
@@ -368,6 +390,37 @@ def phase_kernels():
     flash("flash_attention_nmt", BATCH, 8, SEQ, 64, jnp.bfloat16, 0.1, True)
     flash("flash_attention_t4096", 2, 8, RING_SEQ, 64, jnp.bfloat16, 0.0,
           False)
+
+    def packed(name, tk, causal):
+        """The short-sequence kernel over the projections' layout
+        [B, T, H*D] at the scored batch, bf16, padding + in-kernel
+        dropout, against the XLA body over split heads."""
+        h, d = WIDTH["n_head"], WIDTH["d_model"] // WIDTH["n_head"]
+        if not pa.supported((BATCH, h, SEQ, d), (BATCH, h, tk, d),
+                            jnp.bfloat16):
+            raise AssertionError("packed_attention.supported rejects "
+                                 + name)
+        klen = jnp.arange(BATCH, dtype=jnp.int32) % (tk // 2) + tk // 2
+        seed = jnp.uint32(1234)
+
+        def split(x):
+            return x.reshape(BATCH, -1, h, d).transpose(0, 2, 1, 3)
+
+        def reference(q, k, v):
+            o = fa.reference_attention(split(q), split(k), split(v), klen,
+                                       seed, causal, 0.1, None)
+            return o.transpose(0, 2, 1, 3).reshape(q.shape)
+        out[name] = check_kernel(
+            name,
+            lambda q, k, v: pa.packed_attention(q, k, v, klen, seed, None,
+                                                h, causal, 0.1, None, False),
+            reference,
+            [normal(i, (BATCH, t, h * d), jnp.bfloat16)
+             for i, t in enumerate((SEQ, tk, tk))], 3, TOL_KERNEL["matmul"])
+
+    packed("packed_attention_self", SEQ, False)
+    packed("packed_attention_causal", SEQ, True)
+    packed("packed_attention_cross", 2 * SEQ, False)
 
     rows, d_model = BATCH * SEQ, WIDTH["d_model"]
     gamma = jnp.linspace(0.5, 1.5, d_model, dtype=jnp.float32)
@@ -560,9 +613,12 @@ def phase_train_4chip(one_chip_first_loss):
     strategy = fluid.BuildStrategy()
     strategy.sharding_rules = True
     pe, scope = mesh_executor(mesh, main, startup, loss, strategy)
+    bodies = attention_bodies()
     with mesh:
         losses, _, new = seeded_steps(pe.run, loss, MESH_STEPS, BATCH, SEQ)
     log("train_4chip losses: %s" % " ".join("%.4f" % v for v in losses))
+    # per shard under shard_map: batch over dp, four whole heads over tp
+    assert_packed(bodies, 3 * LAYERS, "train_4chip")
     assert_no_new_compiles(new, "train_4chip")
     if not all(math.isfinite(v) for v in losses):
         raise AssertionError("non-finite loss: %s" % losses)

@@ -70,8 +70,11 @@ def trace_flag_values():
     # side publication only and deliberately not keyed.  The autotune
     # trace token carries the attention decision table's content: a
     # tuned kernel ruling is baked into the lowered step the same way
-    # the flags are, so a changed ruling must re-lower too.
-    return (flags.flag("pallas_kernels"), flags.flag("bn_two_pass"),
+    # the flags are, so a changed ruling must re-lower too.  Whether
+    # FLAGS_pallas_kernels is PINNED is part of it: a pinned False turns
+    # the shape-chosen packed attention kernel off (ops/attention.py).
+    return (flags.flag("pallas_kernels"), flags.pinned("pallas_kernels"),
+            flags.flag("bn_two_pass"),
             flags.flag("pallas_attention_max_seq"),
             guardian.skip_guard_enabled(), health.probe_enabled(),
             autotune.trace_token())

@@ -233,10 +233,15 @@ def fused_attention(q, k, v, k_len=None, causal=False, dropout_rate=0.0,
     """Flash attention over head-split tensors q/k/v [B, H, T, D].
 
     ``k_len`` [B] int masks padded key positions; ``causal`` adds the
-    autoregressive mask.  Never materializes the [B, H, Tq, Tk] score
-    matrix (reference ``nets.scaled_dot_product_attention`` does); runs
-    the Pallas kernel under FLAGS_pallas_kernels, an XLA fallback with
-    identical semantics otherwise."""
+    autoregressive mask.  One op, identical semantics in every body; the
+    body is chosen at trace time (``ops/attention.py``): ring attention on
+    a mesh with an ``sp`` axis; on a TPU, for sequences short enough that
+    a batch row's blocks fit VMEM (up to 384 at H*D = 512 in bf16), the
+    packed Pallas kernel over ``[B, T, H*D]``, against which a model's
+    own head split / merge around this layer cancels; the long-sequence
+    Pallas kernel under FLAGS_pallas_kernels or a tuned ruling; the XLA
+    body otherwise and on the CPU.  A pinned FLAGS_pallas_kernels=False
+    keeps every Pallas body off."""
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(dtype=q.dtype)
     inputs = {"Q": [q], "K": [k], "V": [v]}

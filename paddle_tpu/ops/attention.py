@@ -3,11 +3,32 @@
 Capability parity target: the reference's only attention implementation,
 ``nets.scaled_dot_product_attention`` (``python/paddle/fluid/nets.py:323``) —
 batched QK^T, softmax, optional dropout on the weights, PV.  TPU-first
-redesign: one op whose kernel never materializes the [B, H, Tq, Tk] score
-matrix.  Under ``FLAGS_pallas_kernels`` it runs the hand-tiled blockwise
-kernel (``ops/pallas/flash_attention.py``); otherwise an XLA fallback with
-identical semantics (same structural masks, same counter-hash dropout mask),
-so the flag changes schedule, not math.
+redesign: one op, one algorithm, and a body chosen at trace time from what
+the op can observe (platform, mesh, shapes), with identical semantics in
+every body (same structural masks, same counter-hash dropout mask), so the
+choice changes schedule, not math:
+
+* **ring** — the mesh has a populated ``sp`` axis the sequence dims divide:
+  sequence-parallel ring attention (``parallel/ring_attention.py``).
+* **packed** — on a TPU, when one batch row's blocks for the backward fit
+  the VMEM budget (``ops/pallas/packed_attention.supported``: sequences up
+  to 384 at H*D = 512 in bf16) and the shape is not the suffix-causal
+  decode shape: one Pallas kernel over ``[B, T, H*D]``, the layout the
+  q/k/v projections write.  The op merges the heads on entry and splits
+  them on exit; against the model's own ``transpose(reshape(fc(x)))`` /
+  ``reshape(transpose(.))`` these are a transpose of a transpose and a
+  reshape of a reshape, which XLA removes, forward and in the gradient ops.
+  Under a mesh the kernel runs per shard (``shard_map``: batch over the
+  data axes, whole heads over ``tp``).  No flag turns it on; a PINNED
+  ``FLAGS_pallas_kernels=False`` ("no Pallas") turns it off.
+* **pallas** — the long-sequence blockwise kernel
+  (``ops/pallas/flash_attention.py``) under ``FLAGS_pallas_kernels`` or a
+  tuned per-shape ruling (``autotune.attention_choice``), never
+  materializing the [B, H, Tq, Tk] scores.
+* **xla** — everything else, and every CPU trace: the XLA body.
+
+Which one a trace took is counted in
+``compile_cache.stats()["kernel_bodies"]`` (``fused_attention:<body>``).
 
 Masking is structural: an optional per-batch valid-key count ``KLen`` [B]
 (the ``<name>@LEN`` companion of the key sequence) and a ``causal`` attr —
@@ -22,7 +43,8 @@ commutes with the PV matmul into a single output scale.
 
 import jax.numpy as jnp
 
-from ..registry import register_op, set_output, in_var
+from ..registry import (register_op, set_output, in_var,
+                        _generic_grad_infer)
 
 
 def _fused_attention_infer(op, block):
@@ -52,36 +74,42 @@ def _fused_attention_infer(op, block):
     set_output(op, block, "Out", q.shape, q.dtype)
 
 
-def _fused_attention_compute(ins, attrs, ctx, op_index):
+def _attention_args(ins, attrs, ctx, op_index):
+    """What the forward and its gradient both read off the op: Q, K, V,
+    KLen, the masks' and dropout's attributes, the dropout hash's seed
+    (from the FORWARD op's trace index), and the eval-time output scale
+    (``downgrade_in_infer``: weights *= (1-p) == output *= (1-p))."""
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     k_len = ins.get("KLen", [None])[0]
     causal = attrs.get("causal", False)
     rate = float(attrs.get("dropout_rate", 0.0))
     is_test = attrs.get("is_test", False) or ctx.is_test
     scale = attrs.get("scale", None)
-    seed = None
+    seed = post = None
     if rate and not is_test:
         import jax
         kd = jax.random.key_data(ctx.rng_key(op_index)).astype(jnp.uint32)
         seed = kd.reshape(-1)[0] ^ kd.reshape(-1)[-1]
+    elif rate:
+        post, rate = 1.0 - rate, 0.0
+    return q, k, v, k_len, seed, causal, rate, scale, post
+
+
+def _fused_attention_compute(ins, attrs, ctx, op_index):
+    q, k, v, k_len, seed, causal, rate, scale, post = _attention_args(
+        ins, attrs, ctx, op_index)
 
     from .pallas import flash_attention as fa
-
-    if rate and is_test:
-        # downgrade_in_infer: weights *= (1-p) == output *= (1-p)
-        post = 1.0 - rate
-        rate = 0.0
-    else:
-        post = None
-
     from ..compile_cache import note_kernel_body
 
-    mesh = getattr(ctx, "mesh", None)
-    if mesh is not None and getattr(ctx, "sequence_parallel", True) \
-            and _ring_applicable(mesh, q.shape, k.shape, causal):
+    if _ring_selected(ctx, q.shape, k.shape, causal):
         note_kernel_body("fused_attention", "ring")
-        out = _ring_attention(mesh, q, k, v, k_len, seed, causal, rate,
+        out = _ring_attention(ctx.mesh, q, k, v, k_len, seed, causal, rate,
                               scale)
+    elif _packed_applicable(ctx, q.shape, k.shape, q.dtype, causal):
+        note_kernel_body("fused_attention", "packed")
+        (out,) = _packed_run(ctx, (q, k, v), k_len, seed, causal, rate,
+                             scale)
     else:
         from .. import autotune
         from ..flags import flag
@@ -111,6 +139,145 @@ def _fused_attention_compute(ins, attrs, ctx, op_index):
     if post is not None:
         out = out * jnp.asarray(post, out.dtype)
     return {"Out": out}
+
+
+def _fused_attention_grad_compute(ins, attrs, ctx, op_index):
+    """The op's gradient.  Every body but the packed one differentiates
+    the forward (``registry._generic_grad_compute``: ``jax.vjp`` over the
+    forward's compute).  On the packed body that would run the forward
+    kernel a second time per attention — the vjp's forward is a second
+    custom call with the same operands, and XLA does not merge custom
+    calls (3 kernels an attention in the compiled step) — so there the
+    gradient IS the one backward kernel, over the program's own Q, K, V
+    and dO."""
+    from ..registry import _generic_grad_compute
+
+    fwd_index = attrs.get("__fwd_op_index__", op_index)
+    q, k, v, k_len, seed, causal, rate, scale, post = _attention_args(
+        ins, attrs, ctx, fwd_index)
+    dout = (ins.get("GRAD::Out") or [None])[0]
+    if dout is None or _ring_selected(ctx, q.shape, k.shape, causal) \
+            or not _packed_applicable(ctx, q.shape, k.shape, q.dtype, causal):
+        return _generic_grad_compute(ins, attrs, ctx, op_index)
+
+    from ..compile_cache import note_kernel_body
+
+    note_kernel_body("fused_attention_grad", "packed")
+    dout = dout.astype(q.dtype)
+    if post is not None:
+        dout = dout * jnp.asarray(post, dout.dtype)
+    dq, dk, dv = _packed_run(ctx, (q, k, v, dout), k_len, seed, causal,
+                             rate, scale)
+    return {"GRAD::Q": [dq], "GRAD::K": [dk], "GRAD::V": [dv]}
+
+
+def _ring_selected(ctx, q_shape, k_shape, causal):
+    mesh = getattr(ctx, "mesh", None)
+    return mesh is not None and getattr(ctx, "sequence_parallel", True) \
+        and _ring_applicable(mesh, q_shape, k_shape, causal)
+
+
+# the platforms whose traces take the packed kernel: on the CPU the op
+# keeps the XLA body (the interpreter is for the kernel's own tests)
+_PACKED_PLATFORMS = ("tpu",)
+
+
+def _packed_axes(ctx, b, h, d):
+    """(batch axes, head axis) the packed kernel's shards split over: the
+    populated data axes whose extent divides the batch, and ``tp`` when it
+    leaves every shard whole heads in whole lane tiles."""
+    from ..parallel.embedding import _data_axes, _narrow_batch_axes
+    from ..parallel.mesh import AXIS_TP
+
+    mesh = getattr(ctx, "mesh", None)
+    if mesh is None:
+        return (), None
+    baxes = _narrow_batch_axes(ctx, _data_axes(ctx), b)
+    tp = mesh.shape[AXIS_TP] if AXIS_TP in mesh.axis_names else 1
+    haxis = AXIS_TP if tp > 1 and h % tp == 0 \
+        and (h // tp * d) % 128 == 0 else None
+    return baxes, haxis
+
+
+def _packed_applicable(ctx, q_shape, k_shape, dtype, causal):
+    """The packed short-sequence kernel's rule, from what the op can
+    observe: a TPU trace, no pinned ``FLAGS_pallas_kernels=False``, not
+    the suffix-causal decode shape (K and V come from a cache there, not
+    from a transpose: merging heads would ADD copies), and one shard's
+    row fits the kernel's VMEM budget."""
+    from ..flags import flag, pinned
+    from .pallas import packed_attention as pa
+
+    if getattr(ctx, "platform", None) not in _PACKED_PLATFORMS:
+        return False
+    if pinned("pallas_kernels") and not flag("pallas_kernels"):
+        return False
+    if causal and q_shape[2] < k_shape[2]:
+        return False
+    b, h, _, d = q_shape
+    _, haxis = _packed_axes(ctx, b, h, d)
+    if haxis is not None:
+        h //= ctx.mesh.shape[haxis]
+    return pa.supported((b, h) + tuple(q_shape[2:]),
+                        (b, h) + tuple(k_shape[2:]), dtype)
+
+
+def _packed_run(ctx, arrays, k_len, seed, causal, rate, scale):
+    """Merge the heads of ``arrays`` — (Q, K, V) for the forward, (Q, K,
+    V, dO) for the gradient, each ``[B, H, T, D]`` — into the projections'
+    ``[B, T, H*D]`` layout, run the packed kernel, split the results
+    again: (O,) or (dQ, dK, dV).  Under a mesh a Pallas call is a custom
+    call GSPMD cannot partition (left bare it would gather the global
+    batch onto every chip), so it runs per shard: batch over the data
+    axes, whole heads over ``tp``, and the dropout hash offset by the
+    shard's first global row and head as ``ring_attention_shard`` does."""
+    from .pallas import interpret_mode
+    from .pallas import packed_attention as pa
+
+    b, h, _, d = arrays[0].shape
+    interpret = interpret_mode(ctx)
+    kernel = pa.packed_attention if len(arrays) == 3 \
+        else pa.packed_attention_bwd
+
+    def run(arrays, klen, seed, offsets):
+        out = kernel(*arrays, klen, seed, offsets, arrays[0].shape[2] // d,
+                     causal, rate, scale, interpret)
+        return out if isinstance(out, tuple) else (out,)
+
+    packed = tuple(x.transpose(0, 2, 1, 3).reshape(b, x.shape[2], h * d)
+                   for x in arrays)
+    baxes, haxis = _packed_axes(ctx, b, h, d)
+    if not baxes and haxis is None:
+        outs = run(packed, k_len, seed, None)
+    else:
+        from jax import lax
+        from jax.sharding import PartitionSpec as P
+
+        from ..parallel.embedding import _shard_offset
+        from ..parallel.mesh import shard_map_norep
+
+        mesh = ctx.mesh
+        if k_len is None:
+            k_len = jnp.full((b,), arrays[1].shape[2], jnp.int32)
+        if seed is None:
+            seed = jnp.zeros((), jnp.uint32)
+
+        def shard_body(arrays, klen, seed):
+            heads = arrays[0].shape[2] // d
+            first = lax.axis_index(haxis) * heads if haxis else 0
+            return run(arrays, klen, seed,
+                       (_shard_offset(mesh, baxes, arrays[0].shape[0]),
+                        first, h))
+
+        bspec = baxes if len(baxes) > 1 else (baxes[0] if baxes else None)
+        spec = P(bspec, None, haxis)
+        fn = shard_map_norep(
+            shard_body, mesh,
+            in_specs=((spec,) * len(arrays), P(bspec), P()),
+            out_specs=(spec,) * (1 if len(arrays) == 3 else 3))
+        outs = fn(packed, k_len.astype(jnp.int32), seed.astype(jnp.uint32))
+    return tuple(o.reshape(b, o.shape[1], h, d).transpose(0, 2, 1, 3)
+                 for o in outs)
 
 
 def _ring_applicable(mesh, q_shape, k_shape, causal):
@@ -181,6 +348,15 @@ register_op(
     "fused_attention", ["Q", "K", "V", "KLen"], ["Out"],
     infer=_fused_attention_infer, compute=_fused_attention_compute,
     no_grad_inputs=("KLen",), stateful_random=True,
+)
+
+# the gradient op the default grad maker emits for fused_attention
+# (``fused_attention_grad``, the forward's slots + Out::Out + GRAD::Out):
+# the same op in the program, its compute chosen like the forward's
+register_op(
+    "fused_attention_grad", (), (),
+    infer=_generic_grad_infer, compute=_fused_attention_grad_compute,
+    grad=None, doc="gradient of fused_attention",
 )
 
 

@@ -5,15 +5,23 @@ here provide hand-tiled Pallas implementations for ops where explicit
 VMEM staging/fusion can beat XLA's automatic fusion (SURVEY.md §7 hot-op
 list: softmax_with_cross_entropy, layer_norm).
 
-Selection: gated at each call site by the ``pallas_kernels`` runtime
-flag (``flags.flag("pallas_kernels")`` / FLAGS_pallas_kernels env, part
-of the executor compile-cache key); default off — measurements on v5e
-(see bench notes in each module) show XLA's fused code is already at
-parity for these shapes, so the Pallas path is an opt-in escape hatch
-and the reference implementation for writing further kernels (ring
-attention etc.).  Under a ``CPUPlace`` the kernels run in interpreter
-mode, which the tests use for numerical parity checks; every call site
-records the body it lowered to (``compile_cache.note_kernel_body``).
+Selection: softmax_xent, layer_norm, quant_matmul, conv_bn and the
+long-sequence ``flash_attention`` are gated at each call site by the
+``pallas_kernels`` runtime flag (``flags.flag("pallas_kernels")`` /
+FLAGS_pallas_kernels env, part of the executor compile-cache key);
+default off — nothing on the v5e has yet shown them ahead of XLA's
+fused code at the scored shapes, so they are an opt-in escape hatch.
+``packed_attention`` — short-sequence attention over the projections'
+``[B, T, H*D]`` layout — is NOT behind the flag: the ``fused_attention``
+op takes it on a TPU whenever the shape is inside its VMEM bound
+(``packed_attention.supported``), because there it was measured 2.8x
+ahead of the XLA body (PERF.md 6.6); only a PINNED
+``FLAGS_pallas_kernels=False`` ("no Pallas") turns it off.  Under a
+``CPUPlace`` the flag-selected kernels run in interpreter mode, which
+the tests use for numerical parity checks (the packed kernel is not
+selected on the CPU at all; its tests call it interpreted); every call
+site records the body it lowered to
+(``compile_cache.note_kernel_body``).
 """
 
 from ... import flags  # flag "pallas_kernels" is declared in flags.py

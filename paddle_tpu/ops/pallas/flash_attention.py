@@ -37,6 +37,13 @@ probabilities from the saved log-sum-exp.
 Long-sequence scope: K/V live fully in VMEM per (batch, head) — fine up to
 Tk ~ 8-16k at D=64; beyond that sequence parallelism (ring attention over
 the ``sp`` mesh axis) is the intended scaling path, per SURVEY.md §5.
+
+Short-sequence sibling: ``packed_attention.py``.  This kernel's grid is
+(B*H, Tq/bq); at T=64 that is 2048 steps of 8 KB each, three calls an
+op.  There one batch row's Q, K, V for ALL heads fit VMEM in the
+projections' own ``[B, T, H*D]`` layout, so the sibling tiles batch rows
+instead and the ``fused_attention`` op picks it by shape; it shares this
+module's ``_keep_mask`` / ``_causal_valid``, so the masks are the same.
 """
 
 import functools

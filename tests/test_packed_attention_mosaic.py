@@ -1,0 +1,66 @@
+"""The packed attention kernel at the benchmark cells' real widths,
+compiled by the TPU's own compiler for a v5e that is described and not
+attached (no chip time, nothing runs): Mosaic has to accept the 64-lane
+head slices, the batched products and the VMEM the blocks take, forward
+and backward, or the step would fail on the chip at trace time.
+
+The topology is described inside a fixture, after this file's first test
+has started, and only here: one process at a time may load the TPU's
+library."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops.pallas import packed_attention as pa
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:           # no TPU compiler in this installation
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("tk,causal,rate", [
+    (64, False, 0.0),                # encoder self-attention, the cells'
+    (64, True, 0.1),                 # decoder self-attention with dropout
+    (128, False, 0.1),               # cross attention, Tq != Tk
+])
+def test_packed_kernel_compiles_for_v5e_at_the_cell_shape(one_chip, tk,
+                                                          causal, rate):
+    b, tq, h, d = 256, 64, 8, 64
+    assert pa.supported((b, h, tq, d), (b, h, tk, d), jnp.bfloat16)
+
+    def step(q, k, v, klen, seed, ct):
+        out, vjp = jax.vjp(
+            lambda q, k, v: pa.packed_attention(
+                q, k, v, klen, seed, None, h, causal, rate, None, False),
+            q, k, v)
+        return (out,) + vjp(ct)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(step).lower(
+            arg((b, tq, h * d), jnp.bfloat16),
+            arg((b, tk, h * d), jnp.bfloat16),
+            arg((b, tk, h * d), jnp.bfloat16), arg((b,), jnp.int32),
+            arg((), jnp.uint32),
+            arg((b, tq, h * d), jnp.bfloat16)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    # one forward and ONE backward kernel
+    assert text.count('custom_call_target="tpu_custom_call"') == 2

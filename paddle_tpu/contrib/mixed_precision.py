@@ -52,11 +52,44 @@ class AutoMixedPrecisionLists:
         "isfinite",
     }
 
+    # The sparse-attention mixture-of-experts ops (ops/sparse_select.py,
+    # ops/moe.py) and what they get unless a custom list says otherwise:
+    # the indexer's products and the experts' grouped products in bf16,
+    # the router's product and softmax — they decide which experts a
+    # token goes to — in float32.  rms_norm, rotary_embedding, swiglu,
+    # select_topk_keys and moe_dispatch are on no list, like layer_norm:
+    # float32 inside, the activations' dtype outside.  Kept apart from
+    # WHITE/BLACK because ``AMPPolicy.__repr__`` goes into every AMP
+    # program's fingerprint, and with it into its compiled module's name
+    # and compile-cache key: these sets decide nothing for a program that
+    # holds none of their ops, so they must not rename it.
+    WHITE_SPARSE_MOE = {"indexer_score", "moe_expert_ffn"}
+    BLACK_SPARSE_MOE = {"moe_router"}
+    # white ops that round their own operands: the grouped expert products
+    # take X and the expert matrices in bf16 but keep the routing weights,
+    # the incoming gradient and every sum in float32, which a cast of all
+    # their inputs would lose (ops/moe.py ``_ffn_args``)
+    SELF_CAST = {"moe_expert_ffn"}
+
     def __init__(self, custom_white_list=None, custom_black_list=None):
         self.white_list = (set(self.WHITE) | set(custom_white_list or ())) \
             - set(custom_black_list or ())
         self.black_list = (set(self.BLACK) | set(custom_black_list or ())) \
             - set(custom_white_list or ())
+
+    def colour(self, op_type):
+        """"white", "black" or None (follow the inputs) for a forward op
+        type: the lists first — a custom entry overrides a default — then
+        the sparse-attention mixture-of-experts defaults."""
+        if op_type in self.white_list:
+            return "white"
+        if op_type in self.black_list:
+            return "black"
+        if op_type in self.WHITE_SPARSE_MOE:
+            return "white"
+        if op_type in self.BLACK_SPARSE_MOE:
+            return "black"
+        return None
 
 
 class AMPPolicy:
@@ -78,12 +111,13 @@ class AMPPolicy:
         grad re-runs the forward, so the same cast yields the same
         bf16 compute in the backward pass)."""
         base = op_type[:-5] if op_type.endswith("_grad") else op_type
-        if base in self.lists.white_list:
-            target, source = jnp.bfloat16, jnp.float32
-        elif base in self.lists.black_list:
-            target, source = jnp.float32, jnp.bfloat16
-        else:
+        colour = self.lists.colour(base)
+        if colour is None or base in self.lists.SELF_CAST:
             return ins
+        if colour == "white":
+            target, source = jnp.bfloat16, jnp.float32
+        else:
+            target, source = jnp.float32, jnp.bfloat16
         out = {}
         for slot, vals in ins.items():
             out[slot] = [

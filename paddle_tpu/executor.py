@@ -217,6 +217,22 @@ def _sparse_step_extras(program, feed_names, feed_vals):
             "sparse_table_bytes": int(table_bytes)}
 
 
+def _step_extras(program, feed_names, feed_vals, fetch_names, fetches):
+    """The step record's producer-supplied fields: the sparse embedding
+    path's, and the values of a ``program.step_stats`` variable
+    (``framework.Program.step_stats``: a builder's per-step counters, e.g.
+    a routed-expert layer's pairs computed) WHEN the caller fetched it to
+    the host with this step — a counter never costs a sync of its own."""
+    extras = _sparse_step_extras(program, feed_names, feed_vals)
+    declared = getattr(program, "step_stats", None)
+    if declared and declared[0] in fetch_names:
+        val = fetches[fetch_names.index(declared[0])]
+        if isinstance(val, np.ndarray):
+            extras = dict(extras or {})
+            extras.update(zip(declared[1], val.reshape(-1).tolist()))
+    return extras
+
+
 def _batch_examples(block, feed_names, feed_vals):
     """Examples-per-step for StepStats: the leading dim of a feed whose
     program var declares a batch dim (shape[0] == -1/None); fallback is
@@ -735,8 +751,8 @@ class Executor:
                 _batch_examples(block, feed_names, feed_vals),
                 len(self._dispatch_queue), device=dev,
                 warm=not cold, fingerprint=fp,
-                extras=_sparse_step_extras(program, feed_names,
-                                           feed_vals))
+                extras=_step_extras(program, feed_names, feed_vals,
+                                    compiled.fetch_names, fetches))
         # guardian hook LAST (after telemetry): a ladder decision raises
         # out of run() with this step's record already published.  One
         # module-global read when no guardian is installed.

@@ -411,6 +411,11 @@ class Program:
         # optional name of this program's compiled module in a profiler
         # trace (compile_cache.program_label); not structure, not hashed
         self._label = None
+        # (variable name, field names): a builder's per-step counters, a
+        # small float vector a caller may fetch WITH the loss; the
+        # executor then writes them into that step's StepStats record
+        # (executor._step_extras).  Not structure, not hashed.
+        self.step_stats = None
 
     # ---- block management --------------------------------------------------
     def global_block(self):

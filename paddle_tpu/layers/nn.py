@@ -20,6 +20,11 @@ __all__ = [
     "softmax_with_cross_entropy",
     "fused_attention",
     "paged_attention",
+    "rms_norm",
+    "rotary_embedding",
+    "swiglu",
+    "select_keys",
+    "routed_experts",
     "one_hot",
     "topk",
     "matmul",
@@ -229,8 +234,14 @@ def softmax_with_cross_entropy(
 
 
 def fused_attention(q, k, v, k_len=None, causal=False, dropout_rate=0.0,
-                    is_test=False, scale=None, name=None):
+                    is_test=False, scale=None, selected=None, name=None):
     """Flash attention over head-split tensors q/k/v [B, H, T, D].
+
+    k and v may carry fewer heads than q (grouped-query attention: H a
+    whole multiple of theirs).  ``selected`` is ``select_keys``'s packed
+    mask of the keys each query may read, beside ``k_len`` and ``causal``;
+    with either, a TPU trace takes the kernel that streams K/V by blocks
+    (``ops/pallas/streamed_attention.py``) and the CPU the XLA body.
 
     ``k_len`` [B] int masks padded key positions; ``causal`` adds the
     autoregressive mask.  One op, identical semantics in every body; the
@@ -247,15 +258,150 @@ def fused_attention(q, k, v, k_len=None, causal=False, dropout_rate=0.0,
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if k_len is not None:
         inputs["KLen"] = [k_len]
+    if selected is not None:
+        inputs["Selected"] = [selected]
     attrs = {"causal": causal, "dropout_rate": float(dropout_rate),
              "is_test": is_test}
     if scale is not None:
         attrs["scale"] = float(scale)
+    outputs = {"Out": [out]}
+    if selected is not None or k.shape[1] != q.shape[1]:
+        # the bodies of grouped-query / selected-key attention keep the
+        # rows' log-sum-exp for their gradient op
+        lse = helper.create_variable_for_type_inference(dtype="float32")
+        lse.stop_gradient = True
+        outputs["LSE"] = [lse]
     helper.append_op(
-        type="fused_attention", inputs=inputs, outputs={"Out": [out]},
-        attrs=attrs,
+        type="fused_attention", inputs=inputs, outputs=outputs, attrs=attrs,
     )
     return out
+
+
+def rms_norm(x, epsilon=1e-6, param_attr=None, name=None):
+    """``x * rsqrt(mean(x^2) + epsilon) * gain`` over the LAST axis, with a
+    learned gain of that axis's size (initialised to 1) and no bias: the
+    pre-norm of a decoder block over ``[B, T, D]``, and its per-head
+    QK-norm over ``[B, T, H, Dh]``.  Statistics in float32 whatever the
+    activations' dtype."""
+    from ..initializer import ConstantInitializer
+
+    helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
+    gain = helper.create_parameter(
+        attr=helper.param_attr, shape=[x.shape[-1]], dtype="float32",
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(dtype=x.dtype)
+    helper.append_op(type="rms_norm", inputs={"X": [x], "Scale": [gain]},
+                     outputs={"Y": [out]}, attrs={"epsilon": float(epsilon)})
+    return out
+
+
+def rotary_embedding(x, theta=10000.0, name=None):
+    """Rotary position embedding of ``x`` ``[B, T, ..., D]``: position =
+    index along axis 1, all D dimensions rotated, rotate-half convention
+    (dimension i pairs with i + D/2), frequencies ``theta^(-2i/D)``."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    out = helper.create_variable_for_type_inference(dtype=x.dtype)
+    helper.append_op(type="rotary_embedding", inputs={"X": [x]},
+                     outputs={"Out": [out]}, attrs={"theta": float(theta)})
+    return out
+
+
+def swiglu(x, y, name=None):
+    """``silu(x) * y``: the gated product of a SiLU feed-forward."""
+    helper = LayerHelper("swiglu", name=name)
+    out = helper.create_variable_for_type_inference(dtype=x.dtype)
+    helper.append_op(type="swiglu", inputs={"X": [x], "Y": [y]},
+                     outputs={"Out": [out]})
+    return out
+
+
+def select_keys(index_q, index_k, index_w, top_k, scale=1.0, causal=True,
+                name=None):
+    """Learned sparse selection (a lightning indexer's): per query the
+    ``top_k`` keys with the highest ``scale * sum_j w[t, j] * relu(q[t, j] .
+    k[s])`` among the causal ones — all of them while ``t < top_k`` —
+    ties to the lower index.
+
+    ``index_q`` [B, T, Hi, Di], ``index_k`` [B, T, Di] (one shared key
+    head), ``index_w`` [B, T, Hi].  Returns ``(selected, share)``: the
+    packed bit mask ``fused_attention(selected=...)`` takes ([B, T, W]
+    int32; a mask, not indices, because a blockwise kernel reads it by
+    tiles and it is 1 bit a pair to keep for the backward) and the share
+    of the causal pairs selected ([1] float32).  Not differentiable:
+    nothing flows back into the indexer or its inputs."""
+    helper = LayerHelper("select_keys", name=name)
+    scores = helper.create_variable_for_type_inference(dtype="float32")
+    helper.append_op(
+        type="indexer_score",
+        inputs={"Q": [index_q], "K": [index_k], "W": [index_w]},
+        outputs={"Out": [scores]}, attrs={"scale": float(scale)})
+    selected = helper.create_variable_for_type_inference(dtype="int32")
+    share = helper.create_variable_for_type_inference(dtype="float32")
+    helper.append_op(
+        type="select_topk_keys", inputs={"X": [scores]},
+        outputs={"Out": [selected], "Share": [share]},
+        attrs={"k": int(top_k), "causal": causal})
+    for v in (scores, selected, share):
+        v.stop_gradient = True
+    return selected, share
+
+
+def routed_experts(x, num_experts, top_k, expert_width, held=None, first=0,
+                   tile=256, router_attr=None, gate_attr=None, up_attr=None,
+                   down_attr=None, name=None):
+    """A layer of routed SiLU-gated experts, or one chip's share of it.
+
+    ``x`` [N, D] tokens.  The router (``[D, num_experts]``, float32 product
+    and softmax) picks ``top_k`` experts a token and renormalises their
+    probabilities over the ``top_k``.  This program holds ``held`` experts,
+    numbers ``first .. first + held - 1`` (all of them by default), as
+    stacked parameters ``[held, D, expert_width]`` (gate, up) and ``[held,
+    expert_width, D]`` (down), and returns
+    ``sum_{e routed to t and held} c[t, e] * (silu(x Wg_e) * (x Wu_e)) Wd_e``
+    — what the experts held elsewhere would add is left out.  Dropless:
+    every routed pair of a held expert is computed, whatever the imbalance
+    (``ops/moe.py``: pairs sorted by expert into tiles of ``tile`` rows, a
+    loop over the live tiles).
+
+    Returns ``(out [N, D], counts [held] int32 tokens routed to each held
+    expert, pairs [1] float32 token-expert pairs computed)``."""
+    held = num_experts if held is None else held
+    helper = LayerHelper("routed_experts", name=name)
+    d = x.shape[-1]
+
+    def param(attr, shape):
+        return helper.create_parameter(attr=attr, shape=shape,
+                                       dtype="float32")
+    router = param(router_attr, [d, num_experts])
+    gate = param(gate_attr, [held, d, expert_width])
+    up = param(up_attr, [held, d, expert_width])
+    down = param(down_attr, [held, expert_width, d])
+
+    def tmp(dtype):
+        return helper.create_variable_for_type_inference(dtype=dtype)
+    idx, weight = tmp("int32"), tmp("float32")
+    helper.append_op(type="moe_router", inputs={"X": [x], "W": [router]},
+                     outputs={"TopkIdx": [idx], "TopkWeight": [weight]},
+                     attrs={"top_k": int(top_k)})
+    idx.stop_gradient = True
+    layout = {s: [tmp("int32")] for s in
+              ("RowToken", "RowSlot", "TileExpert", "NumTiles", "Counts")}
+    helper.append_op(type="moe_dispatch", inputs={"TopkIdx": [idx]},
+                     outputs=layout,
+                     attrs={"first": int(first), "held": int(held),
+                            "tile": int(tile)})
+    for v in layout.values():
+        v[0].stop_gradient = True
+    counts = layout.pop("Counts")[0]
+    out, pairs = tmp(x.dtype), tmp("float32")
+    pairs.stop_gradient = True
+    inputs = {"X": [x], "TopkWeight": [weight], "Gate": [gate], "Up": [up],
+              "Down": [down]}
+    inputs.update(layout)
+    helper.append_op(type="moe_expert_ffn", inputs=inputs,
+                     outputs={"Out": [out], "Pairs": [pairs]},
+                     attrs={"tile": int(tile)})
+    return out, counts, pairs
 
 
 def paged_attention(q, k_cache, v_cache, page_table, k_len=None,

@@ -6,5 +6,5 @@ layers DSL, TPU-first (bfloat16-friendly, MXU-sized matmuls/convs).
 
 from . import (alexnet, ctr_dnn, googlenet,  # noqa: F401
                machine_translation, mnist, resnet, se_resnext,
-               simnet_bow, smallnet,
+               simnet_bow, smallnet, sparse_moe_decoder,
                stacked_dynamic_lstm, transformer, vgg)

@@ -21,6 +21,7 @@ from . import (  # noqa: F401
     manipulation,
     math,
     metric,
+    moe,
     norm,
     optimizer_ops,
     pipeline_region,
@@ -32,4 +33,5 @@ from . import (  # noqa: F401
     rnn,
     selected_rows,
     sequence,
+    sparse_select,
 )

@@ -130,3 +130,45 @@ def _maxout_compute(ins, attrs, ctx, op_index):
 
 register_op("maxout", ["X"], ["Out"], infer=_maxout_infer,
             compute=_maxout_compute)
+
+
+# -- swiglu: the gated product of a SiLU feed-forward ------------------------
+
+def _swiglu_compute(ins, attrs, ctx, op_index):
+    """``silu(X) * Y`` in float32, returned in X's dtype."""
+    x, y = ins["X"][0], ins["Y"][0]
+    out = jax.nn.silu(x.astype(jnp.float32)) * y.astype(jnp.float32)
+    return {"Out": out.astype(x.dtype)}
+
+
+register_op("swiglu", ["X", "Y"], ["Out"], infer=same_shape_infer("X", "Out"),
+            compute=_swiglu_compute)
+
+
+# -- rotary position embedding ----------------------------------------------
+
+def rotary_tables(length, dim, theta):
+    """(cos, sin) ``[length, dim]`` of positions 0..length-1 in the
+    rotate-half convention: frequency i = theta^(-2i/dim) turns the pair
+    (x[i], x[i + dim/2])."""
+    inv = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    angle = jnp.arange(length, dtype=jnp.float32)[:, None] * inv[None, :]
+    angle = jnp.concatenate([angle, angle], -1)
+    return jnp.cos(angle), jnp.sin(angle)
+
+
+def _rotary_compute(ins, attrs, ctx, op_index):
+    """Rotate ``X`` ``[B, T, ..., D]`` by its position along axis 1 over all
+    D dimensions (rotate-half), in float32; the output keeps X's dtype."""
+    x = ins["X"][0]
+    d = x.shape[-1]
+    cos, sin = rotary_tables(x.shape[1], d, float(attrs.get("theta", 1e4)))
+    shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (d,)
+    xf = x.astype(jnp.float32)
+    half = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], -1)
+    return {"Out": (xf * cos.reshape(shape)
+                    + half * sin.reshape(shape)).astype(x.dtype)}
+
+
+register_op("rotary_embedding", ["X"], ["Out"],
+            infer=same_shape_infer("X", "Out"), compute=_rotary_compute)

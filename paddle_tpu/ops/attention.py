@@ -21,6 +21,12 @@ choice changes schedule, not math:
   Under a mesh the kernel runs per shard (``shard_map``: batch over the
   data axes, whole heads over ``tp``).  No flag turns it on; a PINNED
   ``FLAGS_pallas_kernels=False`` ("no Pallas") turns it off.
+* **streamed** — on a TPU, self-attention with grouped-query heads (K/V of
+  ``H / g`` heads) or a ``Selected`` key set: the blockwise kernel that
+  streams K/V by blocks and applies the selection per block
+  (``ops/pallas/streamed_attention.py``); the [B, H, T, T] scores of such
+  a model's long rows do not fit HBM, so there is nothing to weigh it
+  against.
 * **pallas** — the long-sequence blockwise kernel
   (``ops/pallas/flash_attention.py``) under ``FLAGS_pallas_kernels`` or a
   tuned per-shape ruling (``autotune.attention_choice``), never
@@ -32,7 +38,12 @@ Which one a trace took is counted in
 
 Masking is structural: an optional per-batch valid-key count ``KLen`` [B]
 (the ``<name>@LEN`` companion of the key sequence) and a ``causal`` attr —
-the two shapes every Transformer mask reduces to.  ``causal`` with
+the two shapes every Transformer mask reduces to — and, for learned sparse
+attention, an optional ``Selected`` input: per query the set of keys it
+may read, as the packed bit mask of ``ops/sparse_select.py`` ([B, Tq, W]
+int32); an unselected key contributes exactly nothing, in every body.
+K and V may carry fewer heads than Q (grouped-query attention): query head
+``h`` reads K/V head ``h // (H / Hkv)``.  ``causal`` with
 ``Tq == Tk`` is aligned self-attention (query i sees keys <= i); with
 ``Tq < Tk`` the queries are the *suffix* of the valid keys — query i sits
 at global position ``klen - Tq + i`` — which is the single-token /
@@ -64,6 +75,20 @@ def _fused_attention_infer(op, block):
             "fused_attention V must be [B, H, Tk, D] matching K's length "
             "and Q's head dim: got Q %s, K %s, V %s"
             % (q.shape, k.shape, v.shape))
+    if k.shape[1] != v.shape[1] or k.shape[1] < 1 \
+            or q.shape[1] % k.shape[1]:
+        raise ValueError(
+            "fused_attention: K and V carry the same number of heads, and "
+            "Q's heads are a whole multiple of it (grouped-query "
+            "attention): got Q %s, K %s, V %s" % (q.shape, k.shape, v.shape))
+    sel = in_var(op, block, "Selected")
+    if sel is not None:
+        from .sparse_select import packed_width
+        want = (q.shape[0], q.shape[2], packed_width(k.shape[2]))
+        if tuple(sel.shape) != want:
+            raise ValueError(
+                "fused_attention: Selected must be the packed key mask %s "
+                "(ops/sparse_select.py), got %s" % (want, sel.shape))
     if op.attrs.get("causal", False) and q.shape[2] > k.shape[2]:
         # a suffix query cannot be longer than the key sequence it is a
         # suffix of; Tq < Tk is the decode/chunked-decode shape (queries
@@ -72,6 +97,8 @@ def _fused_attention_infer(op, block):
             "fused_attention: causal=True requires Tq <= Tk (got %d vs "
             "%d)" % (q.shape[2], k.shape[2]))
     set_output(op, block, "Out", q.shape, q.dtype)
+    if op.outputs.get("LSE"):
+        set_output(op, block, "LSE", tuple(q.shape[:3]) + (1,), "float32")
 
 
 def _attention_args(ins, attrs, ctx, op_index):
@@ -98,10 +125,30 @@ def _attention_args(ins, attrs, ctx, op_index):
 def _fused_attention_compute(ins, attrs, ctx, op_index):
     q, k, v, k_len, seed, causal, rate, scale, post = _attention_args(
         ins, attrs, ctx, op_index)
+    selected = (ins.get("Selected") or [None])[0]
 
     from .pallas import flash_attention as fa
     from ..compile_cache import note_kernel_body
 
+    if selected is not None or k.shape[1] != q.shape[1]:
+        # grouped heads or a selected key set: the streamed kernel on a
+        # TPU where it takes the call, the XLA body otherwise; either
+        # hands the gradient op its rows' log-sum-exp
+        from .pallas import interpret_mode
+        from .pallas import streamed_attention as sa
+
+        if _streamed_applicable(ctx, q.shape, k.shape, q.dtype, causal,
+                                k_len is not None, rate):
+            note_kernel_body("fused_attention", "streamed")
+            out, lse = sa.forward(q, k, v, selected, causal, scale,
+                                  interpret_mode(ctx))
+        else:
+            note_kernel_body("fused_attention", "xla")
+            out, lse = fa.reference_attention(
+                q, k, v, k_len, seed, causal, rate, scale, selected, True)
+        if post is not None:
+            out = out * jnp.asarray(post, out.dtype)
+        return {"Out": out, "LSE": lse}
     if _ring_selected(ctx, q.shape, k.shape, causal):
         note_kernel_body("fused_attention", "ring")
         out = _ring_attention(ctx.mesh, q, k, v, k_len, seed, causal, rate,
@@ -149,13 +196,37 @@ def _fused_attention_grad_compute(ins, attrs, ctx, op_index):
     custom call with the same operands, and XLA does not merge custom
     calls (3 kernels an attention in the compiled step) — so there the
     gradient IS the one backward kernel, over the program's own Q, K, V
-    and dO."""
+    and dO.  Likewise the streamed body (grouped heads / selected keys):
+    its dQ and dK/dV kernels run on the forward op's own ``Out`` and
+    ``LSE``."""
     from ..registry import _generic_grad_compute
 
     fwd_index = attrs.get("__fwd_op_index__", op_index)
     q, k, v, k_len, seed, causal, rate, scale, post = _attention_args(
         ins, attrs, ctx, fwd_index)
     dout = (ins.get("GRAD::Out") or [None])[0]
+    selected = (ins.get("Selected") or [None])[0]
+    if selected is not None or k.shape[1] != q.shape[1]:
+        # the streamed body: its two backward kernels from the forward's
+        # own output and log-sum-exp (the generic rule would run the
+        # forward kernel a second time to get them); the XLA body
+        # differentiates itself
+        out = (ins.get("Out::Out") or [None])[0]
+        lse = (ins.get("Out::LSE") or [None])[0]
+        if dout is None or out is None or lse is None \
+                or not _streamed_applicable(ctx, q.shape, k.shape, q.dtype,
+                                            causal, k_len is not None, rate):
+            return _generic_grad_compute(ins, attrs, ctx, op_index)
+        from ..compile_cache import note_kernel_body
+        from .pallas import interpret_mode
+        from .pallas import streamed_attention as sa
+
+        note_kernel_body("fused_attention_grad", "streamed")
+        if post is not None:
+            dout = dout * jnp.asarray(post, dout.dtype)
+        dq, dk, dv = sa.backward(q, k, v, selected, out, lse, dout, causal,
+                                 scale, interpret_mode(ctx))
+        return {"GRAD::Q": [dq], "GRAD::K": [dk], "GRAD::V": [dv]}
     if dout is None or _ring_selected(ctx, q.shape, k.shape, causal) \
             or not _packed_applicable(ctx, q.shape, k.shape, q.dtype, causal):
         return _generic_grad_compute(ins, attrs, ctx, op_index)
@@ -180,6 +251,29 @@ def _ring_selected(ctx, q_shape, k_shape, causal):
 # the platforms whose traces take the packed kernel: on the CPU the op
 # keeps the XLA body (the interpreter is for the kernel's own tests)
 _PACKED_PLATFORMS = ("tpu",)
+# likewise for the streamed kernel
+_STREAMED_PLATFORMS = ("tpu",)
+
+
+def _kernel_allowed(ctx, platforms):
+    """What the shape-selected kernels share: a trace for one of
+    ``platforms`` and no pinned ``FLAGS_pallas_kernels=False``."""
+    from ..flags import flag, pinned
+
+    return getattr(ctx, "platform", None) in platforms \
+        and not (pinned("pallas_kernels") and not flag("pallas_kernels"))
+
+
+def _streamed_applicable(ctx, q_shape, k_shape, dtype, causal, has_klen,
+                         rate):
+    """The streamed kernel's rule: a TPU trace on one device (it has no
+    per-shard lowering yet), no pinned ``FLAGS_pallas_kernels=False``, and
+    a call its ``supported()`` takes."""
+    from .pallas import streamed_attention as sa
+
+    return _kernel_allowed(ctx, _STREAMED_PLATFORMS) \
+        and getattr(ctx, "mesh", None) is None \
+        and sa.supported(q_shape, k_shape, dtype, causal, has_klen, rate)
 
 
 def _packed_axes(ctx, b, h, d):
@@ -205,12 +299,9 @@ def _packed_applicable(ctx, q_shape, k_shape, dtype, causal):
     the suffix-causal decode shape (K and V come from a cache there, not
     from a transpose: merging heads would ADD copies), and one shard's
     row fits the kernel's VMEM budget."""
-    from ..flags import flag, pinned
     from .pallas import packed_attention as pa
 
-    if getattr(ctx, "platform", None) not in _PACKED_PLATFORMS:
-        return False
-    if pinned("pallas_kernels") and not flag("pallas_kernels"):
+    if not _kernel_allowed(ctx, _PACKED_PLATFORMS):
         return False
     if causal and q_shape[2] < k_shape[2]:
         return False
@@ -345,9 +436,9 @@ def _ring_attention(mesh, q, k, v, k_len, seed, causal, rate, scale):
 
 
 register_op(
-    "fused_attention", ["Q", "K", "V", "KLen"], ["Out"],
+    "fused_attention", ["Q", "K", "V", "KLen", "Selected"], ["Out", "LSE"],
     infer=_fused_attention_infer, compute=_fused_attention_compute,
-    no_grad_inputs=("KLen",), stateful_random=True,
+    no_grad_inputs=("KLen", "Selected"), stateful_random=True,
 )
 
 # the gradient op the default grad maker emits for fused_attention

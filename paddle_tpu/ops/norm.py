@@ -1,4 +1,5 @@
-"""Normalization ops: batch_norm, layer_norm, lrn, norm (L2), group_norm.
+"""Normalization ops: batch_norm, layer_norm, rms_norm, lrn, norm (L2),
+group_norm.
 
 Parity: reference ``paddle/fluid/operators/batch_norm_op.{cc,cu.cc}``
 (train/infer modes, momentum moving stats, NCHW/NHWC data_layout),
@@ -270,6 +271,30 @@ register_op(
     "layer_norm", ["X", "Scale", "Bias"], ["Y", "Mean", "Variance"],
     infer=_ln_infer, compute=_ln_compute,
 )
+
+
+# -- rms_norm ----------------------------------------------------------------
+
+def _rms_compute(ins, attrs, ctx, op_index):
+    """``x * rsqrt(mean(x^2) + eps) * scale`` over the LAST axis (a hidden
+    vector, or one head's slice of it: the per-head QK-norm of a decoder
+    block is this op over ``[B, T, H, D]`` with a ``[D]`` gain).  Like
+    layer_norm an AMP-gray op: the mean of squares is taken in float32
+    whatever the activations' dtype, and the output keeps that dtype."""
+    x = ins["X"][0]
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True)
+                       + attrs.get("epsilon", 1e-6))
+    return {"Y": (y * ins["Scale"][0].astype(jnp.float32)).astype(x.dtype)}
+
+
+def _rms_infer(op, block):
+    x = in_var(op, block, "X")
+    set_output(op, block, "Y", x.shape, x.dtype)
+
+
+register_op("rms_norm", ["X", "Scale"], ["Y"], infer=_rms_infer,
+            compute=_rms_compute)
 
 
 # -- group_norm (parity extension; reference gained it right after 0.15) ----

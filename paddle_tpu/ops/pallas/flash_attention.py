@@ -460,12 +460,30 @@ def paged_attention(q, k_pool, v_pool, table, k_len, k_scale=None,
 
 
 def reference_attention(q, k, v, k_len, seed, causal=False, dropout_rate=0.0,
-                        scale=None):
+                        scale=None, selected=None, with_lse=False):
     """XLA fallback with bit-identical semantics (same hash dropout mask):
-    used when the pallas flag is off or shapes exceed the VMEM budget."""
+    used when the pallas flag is off or shapes exceed the VMEM budget.
+    K/V of fewer heads than Q are read by whole groups of query heads;
+    ``selected`` is the packed per-query key mask of
+    ``ops/sparse_select.py``.  ``with_lse`` also returns the rows'
+    log-sum-exp ``[B, H, Tq, 1]`` (+1e30 for a row with no valid key, as
+    the kernels write it)."""
     b, h, tq, d = q.shape
     tk = k.shape[2]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
+    if k.shape[1] != h:
+        # grouped-query heads: one group of query heads at a time through
+        # the same body
+        if dropout_rate:
+            raise ValueError("grouped-query attention has no weight dropout")
+        g = h // k.shape[1]
+        q5 = q.reshape(b, k.shape[1], g, tq, d)
+        outs = [reference_attention(q5[:, :, i], k, v, k_len, seed, causal,
+                                    0.0, scale, selected, True)
+                for i in range(g)]
+        out = jnp.stack([o for o, _ in outs], 2).reshape(b, h, tq, d)
+        lse = jnp.stack([l for _, l in outs], 2).reshape(b, h, tq, 1)
+        return (out, lse) if with_lse else out
     # operands stay in the input dtype (bf16 under AMP -> bf16 MXU pass);
     # scores/softmax accumulate fp32 via preferred_element_type
     s = jnp.einsum("bhqd,bhkd->bhqk", q * jnp.asarray(scale, q.dtype), k,
@@ -480,6 +498,9 @@ def reference_attention(q, k, v, k_len, seed, causal=False, dropout_rate=0.0,
     if causal:
         valid = valid & _causal_valid(gq[None, None], gk[None, None],
                                       klen.reshape(b, 1, 1, 1), tq, tk)
+    if selected is not None:
+        from ..sparse_select import unpack_key_mask
+        valid = valid & unpack_key_mask(selected, tk)[:, None]
     s = jnp.where(valid, s, _NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.where(valid, jnp.exp(s - m), 0.0)
@@ -493,5 +514,9 @@ def reference_attention(q, k, v, k_len, seed, causal=False, dropout_rate=0.0,
                           bh, gq[None, None], gk[None, None], dropout_rate)
         # downgrade_in_infer: train-time mask without upscale
         y = jnp.where(keep, y, 0.0)
-    return jnp.einsum("bhqk,bhkd->bhqd", y.astype(q.dtype), v,
-                      preferred_element_type=jnp.float32).astype(q.dtype)
+    out = jnp.einsum("bhqk,bhkd->bhqd", y.astype(q.dtype), v,
+                     preferred_element_type=jnp.float32).astype(q.dtype)
+    if not with_lse:
+        return out
+    return out, jnp.where(l > 0.0, m + jnp.log(jnp.maximum(l, 1e-37)),
+                          _POS_BIG)

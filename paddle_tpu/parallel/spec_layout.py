@@ -72,7 +72,13 @@ def classify_params(program):
     that consume it:
 
     * ``lookup_table`` W                     -> ``("vocab", "embed")``
-    * ``layer_norm`` Scale/Bias              -> ``("norm",)``
+    * ``layer_norm`` / ``rms_norm`` Scale/Bias -> ``("norm",)``
+    * ``moe_router`` W [embed, experts]      -> ``("embed", "expert")``
+    * ``moe_expert_ffn`` stacks [held, ., .] -> ``("expert", "embed",
+      "mlp")`` (Gate, Up) and ``("expert", "mlp", "embed")`` (Down).  No
+      default rule maps ``expert`` to a mesh axis yet (the mesh has no
+      expert axis: the experts' exchange is not built), so that dim stays
+      whole and the other two shard like a feed-forward pair's.
     * ``mul``/``matmul`` weights [in, out]   -> ``("embed", "mlp")``
       (column-parallel), or ``("mlp", "embed")`` (row-parallel) when the
       op's data input descends from a column-parallel output — the
@@ -94,10 +100,19 @@ def classify_params(program):
             if op.type == "lookup_table":
                 for w in ins.get("W", ()):
                     classes[w] = ("vocab", "embed")
-            elif op.type == "layer_norm":
+            elif op.type in ("layer_norm", "rms_norm"):
                 for slot in ("Scale", "Bias"):
                     for nm in ins.get(slot, ()):
                         classes[nm] = ("norm",)
+            elif op.type == "moe_router":
+                for w in ins.get("W", ()):
+                    classes[w] = ("embed", "expert")
+            elif op.type == "moe_expert_ffn":
+                for slot, logical in (("Gate", ("expert", "embed", "mlp")),
+                                      ("Up", ("expert", "embed", "mlp")),
+                                      ("Down", ("expert", "mlp", "embed"))):
+                    for w in ins.get(slot, ()):
+                        classes[w] = logical
             elif op.type in ("mul", "matmul"):
                 xs = ins.get("X", ())
                 for w in ins.get("Y", ()):

@@ -330,7 +330,13 @@ def _generic_grad_compute(ins, attrs, ctx, op_index):
     diff_vals = {slot: primal_ins[slot] for slot in diff_slots}
     outs, vjp_fn = jax.vjp(fwd_fn, diff_vals)
 
-    # build cotangents: use provided GRAD:: slots, zeros elsewhere
+    # build cotangents: use provided GRAD:: slots, zeros elsewhere (an
+    # integer output, e.g. a router's expert ids, takes jax's float0)
+    def zero_ct(v):
+        if dtype_is_floating(v.dtype):
+            return jnp.zeros_like(v)
+        return np.zeros(v.shape, jax.dtypes.float0)
+
     cts = {}
     for slot, vals in outs.items():
         gslot = "GRAD::" + slot
@@ -340,11 +346,13 @@ def _generic_grad_compute(ins, attrs, ctx, op_index):
             # under the AMP policy a white-listed forward yields bf16 while
             # the incoming cotangent may be fp32 (or vice versa)
             cts[slot] = [
-                g.astype(v.dtype) if g is not None else jnp.zeros_like(v)
+                g.astype(v.dtype)
+                if g is not None and dtype_is_floating(v.dtype)
+                else zero_ct(v)
                 for g, v in zip(gvals, vals)
             ]
         else:
-            cts[slot] = [jnp.zeros_like(v) for v in vals]
+            cts[slot] = [zero_ct(v) for v in vals]
 
     (grads,) = vjp_fn(cts)
 

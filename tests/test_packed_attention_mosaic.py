@@ -64,3 +64,43 @@ def test_packed_kernel_compiles_for_v5e_at_the_cell_shape(one_chip, tk,
         jax.config.update("jax_enable_compilation_cache", cache)
     # one forward and ONE backward kernel
     assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_streamed_kernel_compiles_for_v5e_at_the_long_document_shape(
+        one_chip):
+    """The streamed kernel at ``keye_vl2_30b_a3b.train_longdoc_8k``'s shape
+    — 32 query heads over 4 key/value heads of 128, T = 8192, bf16, a
+    selection — forward, dQ and dK/dV: Mosaic has to accept the bit-plane
+    unpacking of the packed key mask, the clamped block index maps and
+    the VMEM the 512 x 512 blocks take."""
+    from paddle_tpu.ops import sparse_select as ss
+    from paddle_tpu.ops.pallas import streamed_attention as sa
+
+    b, h, hk, t, d = 1, 32, 4, 8192, 128
+    assert sa.supported((b, h, t, d), (b, hk, t, d), jnp.bfloat16, True,
+                        False, 0.0)
+
+    def step(q, k, v, sel, ct):
+        out, vjp = jax.vjp(
+            lambda q, k, v: sa.streamed_attention(q, k, v, sel, True, None,
+                                                  False), q, k, v)
+        return (out,) + vjp(ct)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(step).lower(
+            arg((b, h, t, d), jnp.bfloat16), arg((b, hk, t, d), jnp.bfloat16),
+            arg((b, hk, t, d), jnp.bfloat16),
+            arg((b, t, ss.packed_width(t)), jnp.int32),
+            arg((b, h, t, d), jnp.bfloat16)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3
+    # no [32, 8192, 8192] scores anywhere: the temporaries are the
+    # log-sum-exp and delta columns and the outputs' staging
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 * 1024 * 1024

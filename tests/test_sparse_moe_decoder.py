@@ -1,0 +1,561 @@
+"""The ops, layers and model of the sparse-attention mixture-of-experts
+decoder (ISSUE 26) on the CPU at small sizes: each new op against
+``jax.numpy`` forward and gradient, ``fused_attention`` with grouped heads
+and a selection against ``reference_attention`` and against the streamed
+kernel (interpreted), the expert layer and its shares against the plain
+reference, and the tiny model's training against it."""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as fluid
+from op_test import OpTest
+from paddle_tpu import compile_cache
+from paddle_tpu.ops import moe
+from paddle_tpu.ops import sparse_select as ss
+from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import streamed_attention as sa
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import harness, weights                        # noqa: E402
+
+CELL = "keye_vl2_30b_a3b.train_longdoc_8k"
+
+
+def _rand(shape, seed=0, scale=1.0):
+    return (np.random.RandomState(seed).randn(*shape) * scale).astype(
+        "float32")
+
+
+# ---- rms_norm, rotary_embedding, swiglu ------------------------------------
+
+def _np_rms(x, g, eps):
+    return x / np.sqrt(np.mean(x * x, -1, keepdims=True) + eps) * g
+
+
+def _np_rotary(x, theta):
+    t, d = x.shape[1], x.shape[-1]
+    inv = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    ang = np.arange(t)[:, None] * inv[None, :]
+    ang = np.concatenate([ang, ang], -1).reshape(
+        (1, t) + (1,) * (x.ndim - 3) + (d,))
+    half = np.concatenate([-x[..., d // 2:], x[..., :d // 2]], -1)
+    return x * np.cos(ang) + half * np.sin(ang)
+
+
+def _op_case(name):
+    t = OpTest()
+    if name == "rms_norm":
+        x, g = _rand((2, 3, 4, 8), 1), 1.0 + 0.1 * _rand((8,), 2)
+        t.op_type, t.attrs = "rms_norm", {"epsilon": 1e-6}
+        t.inputs = {"X": x, "Scale": g}
+        t.outputs = {"Y": _np_rms(x, g, 1e-6)}
+        return t, ["rms_norm__X", "rms_norm__Scale"], "rms_norm__Y"
+    if name == "rotary_embedding":
+        x = _rand((2, 5, 3, 8), 3)
+        t.op_type, t.attrs = "rotary_embedding", {"theta": 1e4}
+        t.inputs = {"X": x}
+        t.outputs = {"Out": _np_rotary(x, 1e4)}
+        return t, ["rotary_embedding__X"], "rotary_embedding__Out"
+    x, y = _rand((3, 7), 4), _rand((3, 7), 5)
+    t.op_type = "swiglu"
+    t.inputs = {"X": x, "Y": y}
+    t.outputs = {"Out": x / (1.0 + np.exp(-x)) * y}
+    return t, ["swiglu__X", "swiglu__Y"], "swiglu__Out"
+
+
+@pytest.mark.parametrize("name", ["rms_norm", "rotary_embedding", "swiglu"])
+def test_elementwise_op_forward(name):
+    _op_case(name)[0].check_output(atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("name", ["rms_norm", "rotary_embedding", "swiglu"])
+def test_elementwise_op_gradient(name):
+    t, ins, out = _op_case(name)
+    t.check_grad(ins, out, max_relative_error=0.01)
+
+
+# ---- indexer_score, select_topk_keys -----------------------------------------
+
+def test_indexer_score_matches_the_formula_blockwise_or_not(monkeypatch):
+    q, k, w = _rand((2, 32, 3, 8), 1), _rand((2, 32, 8), 2), _rand((2, 32, 3), 3)
+    want = np.einsum("btjs,btj->bts", np.maximum(
+        np.einsum("btjd,bsd->btjs", q, k), 0), w) * 0.25
+    np.testing.assert_allclose(ss.index_scores(q, k, w, 0.25), want,
+                               rtol=1e-5, atol=1e-5)
+    monkeypatch.setattr(ss, "_SCORE_BLOCK", 8)        # four query blocks
+    np.testing.assert_allclose(ss.index_scores(q, k, w, 0.25), want,
+                               rtol=1e-5, atol=1e-5)
+
+
+def _np_topk_mask(x, k):
+    out = np.zeros(x.shape, bool)
+    for b in range(x.shape[0]):
+        for t in range(x.shape[1]):
+            best = sorted(range(t + 1), key=lambda s: (-x[b, t, s], s))[:k]
+            out[b, t, best] = True
+    return out
+
+
+@pytest.mark.parametrize("levels", [5, 1000000])
+def test_select_topk_keys_ties_go_to_the_lower_index(levels):
+    """Few distinct scores: most rows are cut inside a run of ties."""
+    x = np.random.RandomState(0).randint(0, levels, (2, 40, 40)).astype(
+        "float32") - levels / 2
+    sel = ss.topk_key_mask(jnp.asarray(x), 8)
+    want = _np_topk_mask(x, 8)
+    assert np.array_equal(np.asarray(sel), want)
+    assert np.array_equal(want[:, :8], np.tril(np.ones((40, 40), bool))[:8]
+                          [None].repeat(2, 0))      # t < k: every causal key
+    packed = ss.pack_key_mask(sel)
+    assert packed.shape == (2, 40, 128) and packed.dtype == jnp.int32
+    assert np.array_equal(np.asarray(ss.unpack_key_mask(packed, 40)), want)
+
+
+def test_select_op_reports_the_share_and_has_no_gradient():
+    x = _rand((1, 24, 24), 7)
+    got = ss._select_compute({"X": [jnp.asarray(x)]},
+                             {"k": 6, "causal": True}, None, 0)
+    want = _np_topk_mask(x, 6)
+    assert np.array_equal(np.asarray(ss.unpack_key_mask(got["Out"], 24)),
+                          want)
+    assert float(got["Share"][0]) == pytest.approx(want.sum() / (24 * 25 / 2))
+    from paddle_tpu.registry import get_op_def
+    assert get_op_def("select_topk_keys").grad is None
+    assert get_op_def("indexer_score").grad is None
+
+
+def test_packed_layout_is_one_bit_plane_per_128_keys():
+    sel = np.zeros((1, 1, 5000), bool)
+    sel[0, 0, [0, 127, 128, 4095, 4096, 4999]] = True
+    words = np.asarray(ss.pack_key_mask(jnp.asarray(sel))).view(np.uint32)
+    assert words.shape == (1, 1, 256)
+    assert words[0, 0, 0] == 1 | (1 << 1)        # keys 0 and 128
+    assert words[0, 0, 127] == 1 | (1 << 31)     # keys 127 and 4095
+    assert words[0, 0, 128] == 1                 # key 4096
+    assert words[0, 0, 128 + 4999 % 128] == 1 << ((4999 - 4096) // 128)
+
+
+# ---- fused_attention: grouped heads, selected keys ---------------------------
+
+def _qkv(b=1, h=4, hk=2, t=256, d=128, seed=0):
+    r = np.random.RandomState(seed)
+    return (jnp.asarray(r.randn(b, h, t, d).astype("float32")),
+            jnp.asarray(r.randn(b, hk, t, d).astype("float32")),
+            jnp.asarray(r.randn(b, hk, t, d).astype("float32")))
+
+
+def _dense_attention(q, k, v, valid):
+    g = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, g, 1), jnp.repeat(v, g, 1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+    p = jax.nn.softmax(jnp.where(valid, s, -jnp.inf), -1)
+    return jnp.einsum("bhqk,bhkd->bhqd", jnp.where(valid, p, 0.0), v)
+
+
+def test_xla_body_with_grouped_heads_and_a_selection():
+    q, k, v = _qkv(t=64, d=16)
+    scores = jnp.asarray(_rand((1, 64, 64), 9))
+    sel = ss.topk_key_mask(scores, 8)
+    got = fa.reference_attention(q, k, v, None, None, True, 0.0, None,
+                                 ss.pack_key_mask(sel))
+    want = _dense_attention(q, k, v, sel[:, None])
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def test_selection_of_more_keys_than_the_row_holds_is_plain_causal():
+    q, k, v = _qkv(t=64, d=16)
+    sel = ss.topk_key_mask(jnp.asarray(_rand((1, 64, 64), 9)), 64)   # t < k
+    got = fa.reference_attention(q, k, v, None, None, True, 0.0, None,
+                                 ss.pack_key_mask(sel))
+    want = fa.reference_attention(q, k, v, None, None, True, 0.0, None)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("causal,selected", [(True, True), (True, False),
+                                             (False, False)])
+def test_streamed_kernel_interpreted_matches_the_xla_body(causal, selected):
+    """Four query blocks by four key blocks of 128 (T = 512), two query
+    heads a key/value head.  With a selection, keys 128..255 score so low
+    that from query 136 on none of them is selected: for the later query
+    blocks key block 1 holds no selected key at all, and must leave the
+    online softmax as it was."""
+    q, k, v = _qkv(t=512)
+    packed = None
+    if selected:
+        scores = jnp.asarray(_rand((1, 512, 512), 11)).at[:, :, 128:256].set(
+            -100.0)
+        sel = ss.topk_key_mask(scores, 64)
+        assert not bool(sel[0, 256:, 128:256].any())
+        packed = ss.pack_key_mask(sel)
+
+    def xla(q, k, v):
+        return fa.reference_attention(q, k, v, None, None, causal, 0.0, None,
+                                      packed)
+
+    def kernel(q, k, v):
+        return sa.streamed_attention(q, k, v, packed, causal, None, True)
+    np.testing.assert_allclose(kernel(q, k, v), xla(q, k, v), rtol=1e-5,
+                               atol=1e-5)
+    ct = jnp.asarray(_rand(q.shape, 12))
+    want = jax.grad(lambda *a: jnp.sum(xla(*a) * ct), (0, 1, 2))(q, k, v)
+    got = jax.grad(lambda *a: jnp.sum(kernel(*a) * ct), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+def test_streamed_supported_says_what_it_takes():
+    ok = ((1, 32, 8192, 128), (1, 4, 8192, 128), jnp.bfloat16, True, False, 0.0)
+    assert sa.supported(*ok)
+    assert not sa.supported((1, 32, 8192, 64), (1, 4, 8192, 64), *ok[2:])
+    assert not sa.supported((1, 32, 100, 128), (1, 4, 100, 128), *ok[2:])
+    assert not sa.supported(ok[0], (1, 5, 8192, 128), *ok[2:])
+    assert not sa.supported(*ok[:4], True, 0.0)       # a padding mask
+    assert not sa.supported(*ok[:5], 0.1)             # dropout
+
+
+def _attention_program(h, hk, t, d, selected):
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        q = fluid.layers.data("q", shape=[h, t, d], dtype="float32")
+        k = fluid.layers.data("k", shape=[hk, t, d], dtype="float32")
+        v = fluid.layers.data("v", shape=[hk, t, d], dtype="float32")
+        for x in (q, k, v):
+            x.stop_gradient = False
+        sel = fluid.layers.data(
+            "sel", shape=[t, ss.packed_width(t)], dtype="int32") \
+            if selected else None
+        out = fluid.layers.fused_attention(q, k, v, causal=True, selected=sel)
+        loss = fluid.layers.reduce_sum(fluid.layers.elementwise_mul(out, out))
+        fluid.append_backward(loss)
+    return main, out
+
+
+@pytest.mark.parametrize("body", ["xla", "streamed"])
+def test_fused_attention_op_takes_grouped_heads_and_a_selection(body,
+                                                                monkeypatch):
+    """Through the op and its gradient op: the XLA body on the CPU, and the
+    streamed kernel when the test lets the CPU trace take it."""
+    from paddle_tpu.ops import attention as att
+
+    if body == "streamed":
+        monkeypatch.setattr(att, "_STREAMED_PLATFORMS", ("tpu", "cpu"))
+    compile_cache.clear()
+    q, k, v = _qkv(t=256)
+    sel = ss.topk_key_mask(jnp.asarray(_rand((1, 256, 256), 5)), 32)
+    main, out = _attention_program(4, 2, 256, 128, True)
+    feed = {"q": np.asarray(q), "k": np.asarray(k), "v": np.asarray(v),
+            "sel": np.asarray(ss.pack_key_mask(sel))}
+    def bodies():
+        got = compile_cache.stats()["kernel_bodies"]
+        return (got.get("fused_attention:" + body, 0),
+                got.get("fused_attention_grad:streamed", 0))
+    before = bodies()
+    got = fluid.Executor(fluid.CPUPlace()).run(
+        main, feed=feed, fetch_list=[out, "q@GRAD", "k@GRAD", "v@GRAD"])
+    # the XLA body's gradient differentiates (and so re-traces) the
+    # forward; the streamed body's runs its two backward kernels on the
+    # forward op's own output and log-sum-exp, and no second forward
+    after = bodies()
+    assert (after[0] - before[0], after[1] - before[1]) == (
+        (1, 1) if body == "streamed" else (2, 0))
+    want = _dense_attention(q, k, v, sel[:, None])
+    np.testing.assert_allclose(got[0], want, rtol=1e-4, atol=1e-4)
+    grads = jax.grad(lambda *a: jnp.sum(_dense_attention(
+        *a, sel[:, None]) ** 2), (0, 1, 2))(q, k, v)
+    for a, b in zip(got[1:], grads):
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-3)
+    compile_cache.clear()
+
+
+def test_fused_attention_refuses_heads_that_do_not_group():
+    with pytest.raises(ValueError, match="whole multiple"):
+        _attention_program(4, 3, 16, 8, False)
+    with pytest.raises(ValueError, match="packed key mask"):
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            q = fluid.layers.data("q", shape=[2, 16, 8], dtype="float32")
+            bad = fluid.layers.data("sel", shape=[16, 16], dtype="int32")
+            fluid.layers.fused_attention(q, q, q, causal=True, selected=bad)
+
+
+# ---- routed experts ------------------------------------------------------------
+
+def _expert_setup(n=64, d=16, f=8, total=8, k=2, seed=0):
+    r = np.random.RandomState(seed)
+    x = jnp.asarray(r.randn(n, d).astype("float32"))
+    router = jnp.asarray(r.randn(d, total).astype("float32"))
+    mats = [jnp.asarray(r.randn(total, *s).astype("float32") * 0.3)
+            for s in ((d, f), (d, f), (f, d))]
+    return x, router, mats, k
+
+
+def _dense_experts(x, idx, c, mats, experts):
+    y = 0.0
+    for e in experts:
+        ce = jnp.sum(jnp.where(idx == e, c, 0.0), -1)
+        y = y + ce[:, None] * (
+            (jax.nn.silu(x @ mats[0][e]) * (x @ mats[1][e])) @ mats[2][e])
+    return y
+
+
+def _share(x, router, mats, k, held, first, tile):
+    routed = moe._router_compute({"X": [x], "W": [router]}, {"top_k": k},
+                                 None, 0)
+    lay = moe.dispatch_layout(routed["TopkIdx"], first, held, tile)
+    layout = tuple(lay[s] for s in moe._LAYOUT)
+    local = [m[first:first + held] for m in mats]
+    y, pairs = moe.expert_ffn(x, routed["TopkWeight"], *local, layout, tile)
+    return routed, lay, layout, local, y, pairs
+
+
+def test_router_renormalises_over_the_top_k():
+    x, router, _, k = _expert_setup()
+    got = moe._router_compute({"X": [x], "W": [router]}, {"top_k": k}, None, 0)
+    p = jax.nn.softmax(x @ router, -1)
+    top, idx = jax.lax.top_k(p, k)
+    assert np.array_equal(got["TopkIdx"], idx)
+    np.testing.assert_allclose(got["TopkWeight"],
+                               top / top.sum(-1, keepdims=True), rtol=1e-6)
+    np.testing.assert_allclose(got["TopkWeight"].sum(-1), 1.0, rtol=1e-6)
+
+
+def test_dispatch_puts_every_held_pair_in_its_experts_tiles():
+    x, router, mats, k = _expert_setup()
+    routed, lay, _, _, _, pairs = _share(x, router, mats, k, 3, 2, 8)
+    idx = np.asarray(routed["TopkIdx"])
+    counts = [(idx == e).sum() for e in (2, 3, 4)]
+    assert np.array_equal(lay["Counts"], counts)
+    assert int(lay["NumTiles"][0]) == sum(-(-c // 8) for c in counts)
+    rows, slots = np.asarray(lay["RowToken"]), np.asarray(lay["RowSlot"])
+    seen = set()
+    for t in range(int(lay["NumTiles"][0])):
+        e = int(lay["TileExpert"][t]) + 2
+        for r, s in zip(rows[t * 8:(t + 1) * 8], slots[t * 8:(t + 1) * 8]):
+            if r < 64:
+                assert idx[r, s] == e
+                seen.add((int(r), int(s)))
+    assert len(seen) == sum(counts) == int(pairs)        # dropless
+    assert lay["RowToken"].shape[0] == moe.dispatch_capacity(64 * k, 3, 8)
+
+
+def test_expert_ffn_and_its_gradient_against_dense_experts():
+    x, router, mats, k = _expert_setup()
+    routed, _, layout, local, y, _ = _share(x, router, mats, k, 3, 2, 8)
+    idx, c = routed["TopkIdx"], routed["TopkWeight"]
+    np.testing.assert_allclose(
+        y, _dense_experts(x, idx, c, mats, (2, 3, 4)), rtol=1e-5, atol=1e-5)
+    dy = jnp.asarray(_rand(x.shape, 3))
+
+    def dense(x, c, g, u, d):
+        return jnp.sum(dy * _dense_experts(
+            x, idx, c, [jnp.zeros_like(m).at[2:5].set(v)
+                        for m, v in zip(mats, (g, u, d))], (2, 3, 4)))
+    want = jax.grad(dense, (0, 1, 2, 3, 4))(x, c, *local)
+    got = moe.expert_ffn_grad(x, c, *local, layout, 8, dy)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+def test_one_expert_takes_every_token_and_nothing_is_dropped():
+    """The worst imbalance: a router that sends every token to expert 5
+    first.  Capacity is static and worst-case, so all 64 pairs run."""
+    x, router, mats, k = _expert_setup()
+    x = jnp.abs(x)
+    router = router.at[:, 5].set(10.0)
+    routed, lay, _, _, y, pairs = _share(x, router, mats, k, 2, 4, 8)
+    assert int(lay["Counts"][1]) == 64 and int(pairs) == int(
+        lay["Counts"].sum())
+    np.testing.assert_allclose(y, _dense_experts(
+        x, routed["TopkIdx"], routed["TopkWeight"], mats, (4, 5)),
+        rtol=1e-5, atol=1e-5)
+
+
+def _cfg(tiny=True):
+    _, cfg, traffic = harness.resolve_cell(harness.load_benchmark(ROOT), CELL,
+                                           tiny=tiny)
+    return cfg, traffic
+
+
+def test_the_shares_of_a_layer_add_up_to_the_uncut_reference():
+    """Four shares of a small layer (8 experts, two held each), each with
+    its own (held, first), through the program's ops; what every share
+    computes alike (the attention branch and the residual) counted once;
+    against the reference's layer holding all 8."""
+    cfg, _ = _cfg()
+    ref = harness.load_reference(cfg["reference"])
+    whole = dict(cfg, num_local_experts=8, first_local_expert=0,
+                 num_hidden_layers=1)
+    p = weights.make_weights(ref.param_spec(whole), 3)
+    x = jnp.asarray(_rand((64, cfg["hidden_size"]), 4))
+    uncut = ref.layer(p, "l0.", x, whole, (8, 0), 32)
+    # the part every share computes alike: x + attention
+    h = ref.rms_norm(x, p["l0.ln1.g"], cfg["rms_norm_eps"])
+    common = x + ref.f32_matmul(ref.attention(p, "l0.", h, whole, 32,
+                                              ref.f32_matmul),
+                                p["l0.attn.o"])
+    h2 = ref.rms_norm(common, p["l0.ln2.g"], cfg["rms_norm_eps"])
+    total, pairs = common, 0
+    for first in (0, 2, 4, 6):
+        mats = [jnp.stack([p["l0.moe.e%d.%s" % (e, m)]
+                           for e in (first, first + 1)])
+                for m in ("gate", "up", "down")]
+        routed = moe._router_compute(
+            {"X": [h2], "W": [p["l0.moe.router"]]},
+            {"top_k": cfg["num_experts_per_tok"]}, None, 0)
+        lay = moe.dispatch_layout(routed["TopkIdx"], first, 2, 8)
+        y, n = moe.expert_ffn(h2, routed["TopkWeight"], *mats,
+                              tuple(lay[s] for s in moe._LAYOUT), 8)
+        one = ref.layer(p, "l0.", x, whole, (2, first), 32)
+        np.testing.assert_allclose(common + y, one, rtol=1e-4, atol=1e-5)
+        total, pairs = total + y, pairs + int(n)
+    assert pairs == 64 * cfg["num_experts_per_tok"]       # every pair, once
+    np.testing.assert_allclose(total, uncut, rtol=1e-4, atol=1e-5)
+
+
+# ---- the model -------------------------------------------------------------------
+
+def _train(precision, seed=5):
+    from benchmark.generators import train_lm_steps as gen
+
+    cfg, traffic = _cfg()
+    cfg = dict(cfg, precision=precision)
+    ref = harness.load_reference(cfg["reference"])
+    batches = gen.make_batches(traffic, cfg["vocab_size"], seed)
+    w0 = gen.seeded_weights(ref.param_spec(cfg), cfg, seed)
+    want = gen.reference_readings(ref, cfg, batches, w0, ref.f32_matmul, 3)
+    model = harness.load_module("models", cfg["builder"]).build_train(
+        cfg, traffic, jax.devices()[:1])
+    model.set_weights(w0)
+    feeds = [model.make_feed(b) for b in batches]
+    prog = gen.program_readings(model, feeds, w0, cfg["adam_beta1"], 3,
+                                want["first_grad"], traffic["seq"])
+    return cfg, model, prog, want, gen
+
+
+def test_tiny_model_trains_like_the_plain_reference_in_float32():
+    """Three losses, the first gradient leaf by leaf, three Adam steps, the
+    first layer's selection and the dropless count: in float32 the program
+    and the reference are the same mathematics."""
+    cfg, model, prog, want, gen = _train("float32")
+    for a, b in zip(prog["losses"], want["losses"]):
+        assert abs(a - b) / b < 2e-6
+    assert gen.rel_error_rms(prog["grad_errors"], want["grad_norms"]) < 1e-5
+    assert gen.worst_leaf_gap(prog["grad_norms"], want["grad_norms"])[0] < 1e-5
+    assert gen.worst_leaf_gap(prog["update_norms"],
+                              want["update_norms"])[0] < 1e-4
+    assert gen.overlap(prog["selected"], want["selected"]) == 1.0
+    assert gen.dropped_pairs(prog["stats"]) == 0
+    assert all(s["pairs_computed"] > 0 for s in prog["stats"])
+    # the frozen indexer: no gradient, no Adam state, and it did not move
+    names = set(model.scope.local_var_names()) \
+        if hasattr(model.scope, "local_var_names") else None
+    for leaf in ("l0.idx.q", "l0.idx.k", "l0.idx.w", "l0.idx.k_g",
+                 "l1.idx.k_b"):
+        assert model.scope.find_var(leaf + "_moment1_0") is None
+        assert leaf not in want["grad_norms"]
+    assert model.scope.find_var("l0.attn.q_moment1_0") is not None
+    assert names is None or "l0.idx.q" in names
+    model.close()
+
+
+def test_program_lists_every_new_op_under_its_own_type():
+    cfg, traffic = _cfg()
+    model = harness.load_module("models", cfg["builder"]).build_train(
+        cfg, traffic, jax.devices()[:1])
+    types = [op.type for op in model.main.global_block().ops]
+    n = cfg["num_hidden_layers"]
+    for t in ("indexer_score", "select_topk_keys", "fused_attention",
+              "moe_router", "moe_dispatch", "moe_expert_ffn",
+              "fused_attention_grad", "moe_router_grad",
+              "moe_expert_ffn_grad"):
+        assert types.count(t) == n, t
+    assert types.count("rms_norm") == 4 * n + 1
+    assert types.count("rotary_embedding") == 4 * n
+    for t in ("indexer_score_grad", "select_topk_keys_grad",
+              "moe_dispatch_grad"):
+        assert t not in types
+    model.close()
+
+
+# ---- mixed precision, step counters ---------------------------------------------
+
+def test_amp_colours_of_the_new_ops_leave_the_fingerprinted_lists_alone():
+    """The indexer's and the experts' products are white, the router
+    black, by defaults that sit OUTSIDE the lists AMPPolicy's repr prints:
+    an AMP program without these ops keeps its fingerprint (and its
+    compiled module's name); a custom list still overrides a default."""
+    from paddle_tpu.contrib import mixed_precision as mp
+
+    lists = mp.AutoMixedPrecisionLists()
+    assert lists.colour("indexer_score") == "white"
+    assert lists.colour("moe_expert_ffn") == "white"
+    assert lists.colour("moe_router") == "black"
+    for gray in ("rms_norm", "rotary_embedding", "swiglu",
+                 "select_topk_keys", "moe_dispatch", "layer_norm"):
+        assert lists.colour(gray) is None
+    assert lists.colour("mul") == "white" and lists.colour("adam") == "black"
+    text = repr(mp.AMPPolicy())
+    assert "moe" not in text and "indexer" not in text
+    custom = mp.AutoMixedPrecisionLists(custom_black_list=["moe_expert_ffn"])
+    assert custom.colour("moe_expert_ffn") == "black"
+    assert "moe_expert_ffn" in repr(mp.AMPPolicy(custom))
+    # the expert op rounds its own operands: routing weights stay float32
+    ins = {"X": [jnp.ones((2, 2), jnp.float32)],
+           "TopkWeight": [jnp.ones((2, 1), jnp.float32)]}
+    out = mp.AMPPolicy().cast_inputs("moe_expert_ffn_grad", ins)
+    assert out["TopkWeight"][0].dtype == jnp.float32
+    assert mp.AMPPolicy().cast_inputs("indexer_score", ins)["X"][0].dtype \
+        == jnp.bfloat16
+    assert mp.AMPPolicy().cast_inputs(
+        "moe_router", {"X": [jnp.ones((2,), jnp.bfloat16)]})["X"][0].dtype \
+        == jnp.float32
+
+
+def test_step_counters_ride_the_loss_into_the_step_record(tmp_path):
+    """Fetched to the host with the loss, the builder's counters land in
+    that step's StepStats record; left on the device they cost nothing and
+    are left out."""
+    from paddle_tpu import monitor
+    from paddle_tpu.models import sparse_moe_decoder as smd
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        tok = fluid.layers.data("tok", shape=[32, 1], dtype="int64")
+        lbl = fluid.layers.data("lbl", shape=[32, 1], dtype="int64")
+        loss, stats, _ = smd.decoder_lm(tok, lbl, 64, 1, 32, 4, 2, 8,
+                                        (2, 4, 1), 16, 2, 2, 8, 8,
+                                        expert_tile=8)
+    assert main.step_stats == (stats.name, smd.STEP_STATS)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    t = np.random.RandomState(0).randint(0, 64, (2, 32, 1)).astype("int64")
+    monitor.enable(log_dir=str(tmp_path))
+    try:
+        _, st = exe.run(main, feed={"tok": t, "lbl": t},
+                        fetch_list=[loss, stats])
+        exe.run(main, feed={"tok": t, "lbl": t}, fetch_list=[loss, stats],
+                return_numpy=False)
+        exe.run(main, feed={"tok": t, "lbl": t}, fetch_list=[loss])
+    finally:
+        monitor.disable()
+    import glob
+    import json
+    recs = [json.loads(line) for f in glob.glob(str(tmp_path / "*.jsonl"))
+            for line in open(f)]
+    steps = [r for r in recs if r.get("event") == "step_stats"]
+    assert len(steps) == 3
+    assert [steps[0][n] for n in smd.STEP_STATS] == st.tolist()
+    assert steps[0]["moe_pairs_routed"] == steps[0]["moe_pairs_computed"] > 0
+    assert 0 < steps[0]["selected_key_share"] <= 1
+    for later in steps[1:]:
+        assert not set(smd.STEP_STATS) & set(later)

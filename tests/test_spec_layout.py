@@ -90,6 +90,35 @@ def test_spec_layout_axis_renaming():
     assert dict(lay.rules)["embed"] == "dp"
 
 
+def test_classify_routed_expert_leaves():
+    """The sparse mixture-of-experts decoder's leaves are all known: the
+    gains are norms, the router is [embed, expert], the stacked experts
+    [expert, embed, mlp] / [expert, mlp, embed]; no rule maps ``expert``
+    to a mesh axis yet, so on a tp mesh the expert dim stays whole and the
+    pair shards like a feed-forward pair."""
+    from paddle_tpu.models import sparse_moe_decoder as smd
+
+    tok = fluid.layers.data("tok", shape=[16, 1], dtype="int64")
+    lbl = fluid.layers.data("lbl", shape=[16, 1], dtype="int64")
+    smd.decoder_lm(tok, lbl, 64, 1, 32, 4, 2, 8, (2, 8, 0), 16, 2, 2, 8, 4,
+                   expert_tile=8)
+    program = fluid.default_main_program()
+    classes = sl.classify_params(program)
+    assert classes["l0.ln1.g"] == classes["l0.attn.q_g"] == ("norm",)
+    assert classes["l0.moe.router"] == ("embed", "expert")
+    assert classes["l0.moe.gate"] == classes["l0.moe.up"] == (
+        "expert", "embed", "mlp")
+    assert classes["l0.moe.down"] == ("expert", "mlp", "embed")
+    params = {p.name for p in program.global_block().all_parameters()}
+    assert params <= set(classes)          # no leaf is unknown
+    mesh = make_mesh((2, 4), ("fsdp", "tp"))
+    lay = SpecLayout()
+    assert lay.spec_for_logical(classes["l0.moe.gate"], (2, 32, 16),
+                                mesh) == P(None, "fsdp", "tp")
+    assert lay.spec_for_logical(classes["l0.moe.down"], (2, 16, 32),
+                                mesh) == P(None, "tp", "fsdp")
+
+
 def test_classify_transformer_params():
     _build_transformer()
     classes = sl.classify_params(fluid.default_main_program())

@@ -45,11 +45,13 @@ from paddle_tpu import compile_cache, recordio, serving
 from paddle_tpu.contrib import mixed_precision
 from paddle_tpu.models import transformer as tfm
 from paddle_tpu.monitor import program_profile
+from paddle_tpu.ops import sparse_select
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import layer_norm as pallas_ln
 from paddle_tpu.ops.pallas import packed_attention as pa
 from paddle_tpu.ops.pallas import quant_matmul as pallas_qm
 from paddle_tpu.ops.pallas import softmax_xent as pallas_xent
+from paddle_tpu.ops.pallas import topk_select
 from paddle_tpu.ops.quantize import xla_dequant_matmul
 from paddle_tpu.parallel import make_mesh
 
@@ -62,6 +64,9 @@ VOCAB, SEQ, BATCH, LAYERS = 32000, 64, 256, 6
 REF_LAYERS, REF_BATCH = 2, 8
 TRAIN_STEPS, MESH_STEPS = 12, 6
 RING_SEQ = 4096
+# the long-document cell's selection: 2048 of 8192 keys a query, scored by
+# 16 indexer heads of 64
+SELECT_SEQ, SELECT_K, INDEX_HEADS, INDEX_DIM = 8192, 2048, 16, 64
 SEED = 90
 # the parameter whose movement (with both of its Adam moments) is checked
 WATCHED_PARAM = "dec_logits.w_0"
@@ -222,17 +227,27 @@ def phase_device():
     return {"peaks": peaks}
 
 
-def attention_bodies():
+def kernel_bodies(op_prefix):
     return {k: n for k, n in compile_cache.stats()["kernel_bodies"].items()
-            if k.startswith("fused_attention")}
+            if k.startswith(op_prefix)}
+
+
+def attention_bodies():
+    return kernel_bodies("fused_attention")
+
+
+def bodies_since(before, op_prefix):
+    """The bodies ops of ``op_prefix`` lowered to since ``before``."""
+    return {k: n - before.get(k, 0)
+            for k, n in kernel_bodies(op_prefix).items()
+            if n != before.get(k, 0)}
 
 
 def assert_packed(before, attentions, what):
     """Every attention of the program traced since ``before`` took the
     packed short-sequence kernel, forward and gradient, and no other
     body."""
-    got = {k: n - before.get(k, 0) for k, n in attention_bodies().items()
-           if n != before.get(k, 0)}
+    got = bodies_since(before, "fused_attention")
     want = {"fused_attention:packed": attentions,
             "fused_attention_grad:packed": attentions}
     if got != want:
@@ -471,6 +486,83 @@ def phase_kernels():
                                   mode=mode),
                 [normal(7, (m, k), jnp.float32)], 0, TOL_KERNEL["matmul"])
     return {"max_errors": out}
+
+
+# -- learned sparse selection ---------------------------------------------------
+
+def phase_select_keys():
+    """``select_topk_keys`` at the long-document cell's shape — scores
+    [1, 8192, 8192] from an indexer, the 2048 highest causal keys a query —
+    through the executor: the op takes its Pallas body (one Mosaic kernel
+    that reads each score once) and its words EQUAL the XLA body's, which
+    is the definition.  Then the same scores coarsened until every row is
+    cut inside a run of ties, so that Mosaic also runs the index passes."""
+    t, k = SELECT_SEQ, SELECT_K
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        q = fluid.layers.data("index_q", shape=[t, INDEX_HEADS, INDEX_DIM],
+                              dtype="float32")
+        key = fluid.layers.data("index_k", shape=[t, INDEX_DIM],
+                                dtype="float32")
+        w = fluid.layers.data("index_w", shape=[t, INDEX_HEADS],
+                              dtype="float32")
+        selected, share = fluid.layers.select_keys(
+            q, key, w, k, scale=INDEX_DIM ** -0.5)
+    scores = main.global_block().ops[-1].input("X")[0]
+    feed = {name: np.asarray(normal(seed, (1, t) + shape, jnp.float32))
+            for seed, (name, shape) in enumerate((
+                ("index_q", (INDEX_HEADS, INDEX_DIM)),
+                ("index_k", (INDEX_DIM,)), ("index_w", (INDEX_HEADS,))), 11)}
+    before = kernel_bodies("select_topk_keys")
+    words, share, x = fluid.Executor(fluid.TPUPlace(0)).run(
+        main, feed=feed, fetch_list=[selected, share, scores],
+        return_numpy=False)
+    bodies = bodies_since(before, "select_topk_keys")
+    if bodies != {"select_topk_keys:pallas": 1}:
+        raise AssertionError("select_topk_keys bodies %s, expected the "
+                             "Pallas one" % bodies)
+    if not topk_select.supported(x.shape, x.dtype):
+        raise AssertionError("topk_select.supported rejects %s" % (x.shape,))
+
+    def xla_body(x):
+        sel = sparse_select.topk_key_mask(x, k, True)
+        return sparse_select.pack_key_mask(sel), jnp.sum(sel, axis=-1)
+    xla = jax.jit(xla_body)
+    kernel = mosaic_jit(
+        lambda x: topk_select.select_topk_words(x, k, True, False), x)
+
+    def same(got, want, what):
+        differ = int(np.sum(np.asarray(got) != np.asarray(want)))
+        if differ:
+            raise AssertionError("%s: %d of %d differ from the XLA body's"
+                                 % (what, differ, got.size))
+    want_words, want_count = xla(x)
+    same(words, want_words, "select_topk_keys words")
+    pairs = t * (t + 1) // 2
+    want_share = float(np.asarray(want_count, np.float64).sum()) / pairs
+    if abs(float(np.asarray(share)[0]) - want_share) > 1e-6:
+        raise AssertionError("share %r, the XLA body's %r"
+                             % (np.asarray(share), want_share))
+    coarse = jnp.round(x * 4.0) / 4.0
+    got = kernel(coarse)
+    want = xla(coarse)
+    same(got[0], want[0], "tied scores: words")
+    same(got[1][..., 0], want[1], "tied scores: counts")
+
+    def ms(fn, arg):
+        jax.block_until_ready(fn(arg))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            out = fn(arg)
+        jax.block_until_ready(out)
+        return round((time.perf_counter() - t0) / 5 * 1e3, 3)
+    timing = {"pallas": ms(kernel, x), "pallas_tied": ms(kernel, coarse),
+              "xla": ms(xla, x)}
+    log("select_topk_keys [1, %d, %d] k=%d: bodies %s, share %.4f, ms a call "
+        "%s (host clock over 5 calls; information, not a metric)"
+        % (t, t, k, bodies, want_share, timing))
+    return {"bodies": bodies, "selected_share": round(want_share, 6),
+            "ms_per_call": timing}
 
 
 # -- serving --------------------------------------------------------------------
@@ -722,6 +814,7 @@ def main():
     fluid.set_flags({"FLAGS_fast_prng": True})   # rbg, as the scored rung
     train = run("train_1chip", phase_train_1chip)
     run("kernels", phase_kernels)
+    run("select_keys", phase_select_keys)
     run("serve_1chip", phase_serve_1chip)
     run("train_4chip", phase_train_4chip, train["losses"][0])
 

@@ -328,7 +328,14 @@ def select_keys(index_q, index_k, index_w, top_k, scale=1.0, causal=True,
     int32; a mask, not indices, because a blockwise kernel reads it by
     tiles and it is 1 bit a pair to keep for the backward) and the share
     of the causal pairs selected ([1] float32).  Not differentiable:
-    nothing flows back into the indexer or its inputs."""
+    nothing flows back into the indexer or its inputs.
+
+    The selection's definition is the XLA body
+    (``ops.sparse_select.topk_key_mask``: counting passes over the score
+    matrix in HBM), which every CPU trace and every trace under a mesh
+    runs; a TPU trace on one device with ``T`` in whole 128-key slabs runs
+    ``ops/pallas/topk_select.py``, which makes the same decisions with a
+    block of queries' scores held in VMEM and returns the same words."""
     helper = LayerHelper("select_keys", name=name)
     scores = helper.create_variable_for_type_inference(dtype="float32")
     helper.append_op(

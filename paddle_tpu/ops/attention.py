@@ -255,23 +255,14 @@ _PACKED_PLATFORMS = ("tpu",)
 _STREAMED_PLATFORMS = ("tpu",)
 
 
-def _kernel_allowed(ctx, platforms):
-    """What the shape-selected kernels share: a trace for one of
-    ``platforms`` and no pinned ``FLAGS_pallas_kernels=False``."""
-    from ..flags import flag, pinned
-
-    return getattr(ctx, "platform", None) in platforms \
-        and not (pinned("pallas_kernels") and not flag("pallas_kernels"))
-
-
 def _streamed_applicable(ctx, q_shape, k_shape, dtype, causal, has_klen,
                          rate):
     """The streamed kernel's rule: a TPU trace on one device (it has no
     per-shard lowering yet), no pinned ``FLAGS_pallas_kernels=False``, and
     a call its ``supported()`` takes."""
-    from .pallas import streamed_attention as sa
+    from .pallas import kernel_allowed, streamed_attention as sa
 
-    return _kernel_allowed(ctx, _STREAMED_PLATFORMS) \
+    return kernel_allowed(ctx, _STREAMED_PLATFORMS) \
         and getattr(ctx, "mesh", None) is None \
         and sa.supported(q_shape, k_shape, dtype, causal, has_klen, rate)
 
@@ -299,9 +290,9 @@ def _packed_applicable(ctx, q_shape, k_shape, dtype, causal):
     the suffix-causal decode shape (K and V come from a cache there, not
     from a transpose: merging heads would ADD copies), and one shard's
     row fits the kernel's VMEM budget."""
-    from .pallas import packed_attention as pa
+    from .pallas import kernel_allowed, packed_attention as pa
 
-    if not _kernel_allowed(ctx, _PACKED_PLATFORMS):
+    if not kernel_allowed(ctx, _PACKED_PLATFORMS):
         return False
     if causal and q_shape[2] < k_shape[2]:
         return False

@@ -16,7 +16,11 @@ fused code at the scored shapes, so they are an opt-in escape hatch.
 op takes it on a TPU whenever the shape is inside its VMEM bound
 (``packed_attention.supported``), because there it was measured 2.8x
 ahead of the XLA body (PERF.md 6.6); only a PINNED
-``FLAGS_pallas_kernels=False`` ("no Pallas") turns it off.  Under a
+``FLAGS_pallas_kernels=False`` ("no Pallas") turns it off.  Likewise
+``streamed_attention`` (grouped heads / selected keys at any length) and
+``topk_select`` (``select_topk_keys`` with a query block's scores held in
+VMEM: one read of the scores where the XLA body makes 46; PERF.md 6.8);
+``kernel_allowed`` is the part of their rules they share.  Under a
 ``CPUPlace`` the flag-selected kernels run in interpreter mode, which
 the tests use for numerical parity checks (the packed kernel is not
 selected on the CPU at all; its tests call it interpreted); every call
@@ -47,6 +51,14 @@ def interpret_mode(ctx):
         "a Pallas kernel was selected but the trace context carries "
         "platform=%r: the executor must thread its device's platform "
         "('tpu' compiles via Mosaic, 'cpu' interprets)" % (platform,))
+
+
+def kernel_allowed(ctx, platforms):
+    """What the kernels an op picks by shape share: a trace for one of
+    ``platforms`` and no pinned ``FLAGS_pallas_kernels=False``."""
+    return getattr(ctx, "platform", None) in platforms \
+        and not (flags.pinned("pallas_kernels")
+                 and not flags.flag("pallas_kernels"))
 
 
 def block_rows(n, row_bytes, max_rows, vmem_budget=4 * 1024 * 1024):

@@ -16,6 +16,19 @@ from indices is a search per element and from a mask is a load; and the mask
 is what is saved for the backward — 1 bit a pair (8 MB a layer at T = 8192)
 against 4 bytes a selected key (67 MB).
 
+Which body runs where.  ``topk_key_mask`` below — plain ``jax.numpy``, 32
+counting passes over the value's bits and one per bit of the index for the
+ties, each pass a fusion over the whole ``[B, T, Tk]`` key matrix — is the
+**definition** of ``select_topk_keys``, and the body of every CPU trace, of
+a trace under a mesh and of scores the kernel does not take.  A TPU trace
+on one device whose float32 scores come in whole 128-key slabs
+(``_kernel_applicable``: nothing but what the op can observe, and a pinned
+``FLAGS_pallas_kernels=False`` says no) lowers to
+``ops/pallas/topk_select.py``: the same decisions with a block of rows held
+in VMEM, every score read once, the same words bit for bit.
+``compile_cache.stats()["kernel_bodies"]`` says which one a trace took
+(``select_topk_keys:pallas`` / ``:xla``).
+
 Layout (``pack_key_mask``): int32 words ``[B, T, W]``, ``W = ceil(Tk / 4096)
 * 128``; key ``s`` is bit ``(s % 4096) // 128`` of word ``(s // 4096) * 128 +
 s % 128``.  One bit plane of a 128-word tile is thus the mask of 128
@@ -76,7 +89,12 @@ def _score_infer(op, block):
                "float32")
 
 
-_SCORE_BLOCK = 512
+# Queries a block.  A block's scores ``[block, Tk]`` float32 are summed over
+# the heads in place, which is fast only while XLA keeps them in VMEM: at
+# Tk = 8192, 512 rows (16 MB) keep their place in one layer of four once
+# the selection is a kernel and 21 ms a step go to ``indexer_score``; 256
+# rows keep it in all four, 9.4 ms (PERF.md 6.8).
+_SCORE_BLOCK = 256
 
 
 def index_scores(q, k, w, scale):
@@ -163,14 +181,43 @@ def topk_key_mask(scores, k, causal=True):
     return cand & (above | (tie & (idx < cut)))
 
 
+# the platforms whose traces take the Pallas body: on the CPU the op keeps
+# the XLA body (the interpreter is for the kernel's own tests)
+_KERNEL_PLATFORMS = ("tpu",)
+
+
+def _kernel_applicable(ctx, x_shape, dtype):
+    """The Pallas body's rule, from what the op can observe: a TPU trace on
+    one device (the rows are independent, but a per-shard lowering is not
+    written), no pinned ``FLAGS_pallas_kernels=False``, and scores its
+    ``supported()`` takes."""
+    from .pallas import kernel_allowed, topk_select
+
+    return kernel_allowed(ctx, _KERNEL_PLATFORMS) \
+        and getattr(ctx, "mesh", None) is None \
+        and topk_select.supported(x_shape, dtype)
+
+
 def _select_compute(ins, attrs, ctx, op_index):
+    from ..compile_cache import note_kernel_body
+
     x = ins["X"][0]
-    causal = attrs.get("causal", True)
-    sel = topk_key_mask(x, int(attrs["k"]), causal)
+    k, causal = int(attrs["k"]), attrs.get("causal", True)
+    if _kernel_applicable(ctx, x.shape, x.dtype):
+        from .pallas import interpret_mode, topk_select
+
+        note_kernel_body("select_topk_keys", "pallas")
+        words, count = topk_select.select_topk_words(
+            x, k, causal, interpret_mode(ctx))
+        selected = jnp.sum(count, dtype=jnp.float32)
+    else:
+        note_kernel_body("select_topk_keys", "xla")
+        sel = topk_key_mask(x, k, causal)
+        words, selected = pack_key_mask(sel), jnp.sum(sel, dtype=jnp.float32)
     t, tk = x.shape[-2:]
     pairs = t * (t + 1) // 2 if causal and t == tk else t * tk
-    share = jnp.sum(sel, dtype=jnp.float32) / (x.shape[0] * pairs)
-    return {"Out": pack_key_mask(sel), "Share": share.reshape(1)}
+    share = selected / (x.shape[0] * pairs)
+    return {"Out": words, "Share": share.reshape(1)}
 
 
 register_op("select_topk_keys", ["X"], ["Out", "Share"], infer=_select_infer,

@@ -104,3 +104,35 @@ def test_streamed_kernel_compiles_for_v5e_at_the_long_document_shape(
     # no [32, 8192, 8192] scores anywhere: the temporaries are the
     # log-sum-exp and delta columns and the outputs' staging
     assert compiled.memory_analysis().temp_size_in_bytes < 512 * 1024 * 1024
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_topk_select_kernel_compiles_for_v5e_at_the_long_document_shape(
+        one_chip, causal):
+    """``select_topk_keys``'s Pallas body at the cell's scores — float32
+    [1, 8192, 8192], the 2048 highest keys a query: Mosaic has to accept the
+    signed keys, the slab loops with a bound read from the grid position,
+    the scalar test that skips the index passes, and 64-row blocks (2 MB of
+    scores, twice for the pipeline, and the keys' scratch)."""
+    from paddle_tpu.ops import sparse_select as ss
+    from paddle_tpu.ops.pallas import topk_select
+
+    shape = (1, 8192, 8192)
+    assert topk_select.supported(shape, jnp.float32)
+    assert topk_select._rows(*shape[1:]) == 64
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(
+            lambda x: topk_select.select_topk_words(x, 2048, causal, False)
+        ).lower(jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+                ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    # nothing of the scores' size beside the scores: no key matrix, no mask
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1024 * 1024
+    assert mem.output_size_in_bytes < 9 * 1024 * 1024
+    assert ss.packed_width(shape[2]) == 256

@@ -145,6 +145,155 @@ def test_packed_layout_is_one_bit_plane_per_128_keys():
     assert words[0, 0, 128 + 4999 % 128] == 1 << ((4999 - 4096) // 128)
 
 
+# ---- select_topk_keys: the Pallas body ----------------------------------------
+
+def _levels(shape, levels, seed=0):
+    return (np.random.RandomState(seed).randint(0, levels, shape)
+            - levels / 2).astype("float32")
+
+
+def _special(shape):
+    """Zeros of both signs, runs of equal negatives, infinities and NaNs of
+    both signs — among them the one whose sortable key is 0."""
+    r = np.random.RandomState(3)
+    pool = np.array([0.0, -0.0, -1.5, -1.5, 2.0, np.inf, -np.inf, np.nan,
+                     1e-40, -1e-40], "float32")
+    x = pool[r.randint(0, len(pool), shape)]
+    x.view(np.uint32)[..., ::7] = 0xFFFFFFFF
+    x[:, 8:16] = 0.0                                  # whole rows of ties
+    x[:, 16:24] = -3.0
+    return x
+
+
+_KERNEL_CASES = {
+    # many ties at the threshold: the index passes run (72 rows: two row
+    # blocks, the second a part of one; batch 2)
+    "ties_levels_5": (lambda: _levels((2, 72, 256), 5), 8, True),
+    "ties_levels_1000000": (lambda: _levels((2, 72, 256), 1000000), 8, True),
+    "negative_zero_equal_nan": (lambda: _special((2, 72, 256)), 8, True),
+    "not_causal": (lambda: _levels((2, 72, 256), 5), 8, False),
+    # one slab; rows 0..99 have fewer candidates than k
+    "fewer_candidates_than_k": (lambda: _rand((1, 136, 128), 1), 100, True),
+    "k_at_least_tk": (lambda: _rand((1, 136, 128), 2), 128, True),
+    "k_at_least_tk_not_causal": (lambda: _rand((1, 8, 384), 2), 500, False),
+    # two 4096-key tiles and one slab of a third
+    "several_tiles_and_a_part": (lambda: _levels((1, 8, 8320), 50, 4), 5000,
+                                 False),
+    # a part of one tile, slabs in groups of four, causal past T = Tk
+    "rows_past_the_keys": (lambda: _rand((1, 80, 512), 5), 40, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_KERNEL_CASES))
+def test_topk_kernel_interpreted_equals_the_xla_body_word_for_word(case):
+    from paddle_tpu.ops.pallas import topk_select
+
+    make, k, causal = _KERNEL_CASES[case]
+    x = jnp.asarray(make())
+    assert topk_select.supported(x.shape, x.dtype)
+    words, count = topk_select.select_topk_words(x, k, causal, True)
+    sel = ss.topk_key_mask(x, k, causal)
+    assert words.dtype == jnp.int32
+    assert np.array_equal(np.asarray(words), np.asarray(ss.pack_key_mask(sel)))
+    assert np.array_equal(np.asarray(count)[..., 0], np.asarray(sel).sum(-1))
+
+
+def test_topk_kernel_crosses_a_tile_under_causal():
+    """Rows past 4096 have candidates in the second 4096-key tile (the
+    kernel runs on the whole matrix; its last row blocks are compared)."""
+    from paddle_tpu.ops.pallas import topk_select
+
+    x = _levels((1, 4104, 4224), 3000, 6)
+    x[..., 4096:] += 5000.0                  # the second tile's keys win
+    x = jnp.asarray(x)
+    words, _ = topk_select.select_topk_words(x, 1024, True, True)
+    want = ss.pack_key_mask(ss.topk_key_mask(x, 1024, True))
+    assert words.shape == (1, 4104, 256)
+    assert np.array_equal(np.asarray(words[:, 4032:]),
+                          np.asarray(want[:, 4032:]))
+    assert np.asarray(words[0, 4100, 128:133] == 1).all()
+
+
+def _select_body(monkeypatch, x, k, causal, platforms, mesh=None):
+    """Trace the op's compute as the CPU executor would; return (kernel
+    bodies recorded, outputs)."""
+    from paddle_tpu.registry import ComputeContext
+
+    monkeypatch.setattr(ss, "_KERNEL_PLATFORMS", platforms)
+    ctx = ComputeContext(key=jax.random.key(0), platform="cpu", mesh=mesh)
+    before = dict(compile_cache.stats()["kernel_bodies"])
+    out = jax.jit(lambda x: ss._select_compute(
+        {"X": [x]}, {"k": k, "causal": causal}, ctx, 0))(x)
+    after = compile_cache.stats()["kernel_bodies"]
+    return {key: n - before.get(key, 0) for key, n in after.items()
+            if n != before.get(key, 0)}, out
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_select_op_takes_the_kernel_when_its_rule_says_so(causal,
+                                                          monkeypatch):
+    """A CPU trace keeps the XLA body and says so; let the CPU platform take
+    the kernel (interpreted; the program never does) and the op's words
+    and share are the XLA body's."""
+    from paddle_tpu import flags
+
+    monkeypatch.setattr(flags, "_PINNED", flags._PINNED - {"pallas_kernels"})
+    x = jnp.asarray(_levels((2, 72, 256), 7, 8))
+    bodies, want = _select_body(monkeypatch, x, 16, causal, ("tpu",))
+    assert bodies == {"select_topk_keys:xla": 1}
+    bodies, got = _select_body(monkeypatch, x, 16, causal, ("tpu", "cpu"))
+    assert bodies == {"select_topk_keys:pallas": 1}
+    assert np.array_equal(np.asarray(got["Out"]), np.asarray(want["Out"]))
+    assert np.asarray(got["Share"]) == np.asarray(want["Share"])
+    assert got["Share"].shape == (1,) and got["Share"].dtype == jnp.float32
+
+
+@pytest.mark.parametrize("case", ["cell_shape", "mesh", "pinned_flag",
+                                  "odd_tk", "odd_rows", "bf16", "cpu",
+                                  "row_over_budget"])
+def test_select_kernel_rule_reads_only_what_the_op_observes(case,
+                                                            monkeypatch):
+    import types
+
+    from paddle_tpu import flags
+    from paddle_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(flags, "_PINNED", flags._PINNED - {"pallas_kernels"})
+    tpu = types.SimpleNamespace(platform="tpu", mesh=None)
+    cell = (1, 8192, 8192)
+    if case == "cell_shape":
+        assert ss._kernel_applicable(tpu, cell, jnp.float32)
+        assert not flags.pinned("pallas_kernels")
+    elif case == "mesh":
+        meshed = types.SimpleNamespace(platform="tpu",
+                                       mesh=make_mesh((2, 4), ("dp", "tp")))
+        assert not ss._kernel_applicable(meshed, cell, jnp.float32)
+    elif case == "pinned_flag":
+        prev = flags.flag("pallas_kernels")
+        fluid.set_flags({"FLAGS_pallas_kernels": False})      # pins
+        try:
+            assert not ss._kernel_applicable(tpu, cell, jnp.float32)
+            fluid.set_flags({"FLAGS_pallas_kernels": True})
+            assert ss._kernel_applicable(tpu, cell, jnp.float32)
+        finally:
+            fluid.set_flags({"FLAGS_pallas_kernels": prev})
+    elif case == "odd_tk":
+        assert not ss._kernel_applicable(tpu, (1, 8192, 8200), jnp.float32)
+        assert not ss._kernel_applicable(tpu, (1, 40, 40), jnp.float32)
+    elif case == "odd_rows":
+        assert not ss._kernel_applicable(tpu, (1, 100, 128), jnp.float32)
+    elif case == "bf16":
+        assert not ss._kernel_applicable(tpu, cell, jnp.bfloat16)
+    elif case == "cpu":
+        cpu = types.SimpleNamespace(platform="cpu", mesh=None)
+        assert not ss._kernel_applicable(cpu, cell, jnp.float32)
+        assert not ss._kernel_applicable(None, cell, jnp.float32)
+    else:
+        # eight rows of 131072 keys are 4 MB: over the row block's budget
+        assert ss._kernel_applicable(tpu, (1, 8, 65536), jnp.float32)
+        assert not ss._kernel_applicable(tpu, (1, 8, 131072), jnp.float32)
+
+
 # ---- fused_attention: grouped heads, selected keys ---------------------------
 
 def _qkv(b=1, h=4, hk=2, t=256, d=128, seed=0):

@@ -686,9 +686,10 @@ def bytes_in_use(devs):
 
 def compiled_text(pe):
     (entry,) = pe._cache.values()
-    if entry.aot_exec is None:
+    if not entry.aot:
         raise AssertionError("no AOT executable captured (preflight off?)")
-    return entry.aot_exec.as_text()
+    (captured,) = entry.aot.values()
+    return captured.as_text()
 
 
 def phase_train_4chip(one_chip_first_loss):

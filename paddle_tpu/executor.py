@@ -158,13 +158,6 @@ def _coerce_feed(block, name, v):
     return v
 
 
-def _feed_signature(feed):
-    return tuple(
-        (name, tuple(np.shape(v)), str(np.asarray(v).dtype))
-        for name, v in sorted(feed.items())
-    )
-
-
 def _sparse_feed_info(program):
     """(ids feed names tuple, total sparse-table bytes) for telemetry:
     the is_sparse lookup tables' directly-fed Ids vars + total table
@@ -196,55 +189,55 @@ def _sparse_feed_info(program):
     return hit
 
 
-def _sparse_step_extras(program, feed_names, feed_vals):
-    """Step-record extras for the sparse embedding path: distinct rows
-    touched this step (summed over id feeds) + static table bytes.
-    Host feeds only — counting a device-resident id feed would force a
-    per-step sync on the async path.  Also bumps the
-    ``sparse/touched_rows`` registry counter.  None when the program
-    has no is_sparse tables."""
-    feeds, table_bytes = _sparse_feed_info(program)
-    if not feeds:
-        return None
-    touched = 0
-    by_name = dict(zip(feed_names, feed_vals))
-    for n in feeds:
-        v = by_name.get(n)
-        if isinstance(v, np.ndarray) and v.size:
-            touched += int(np.unique(v).size)
-    monitor.count("sparse/touched_rows", touched)
-    return {"sparse_touched_rows": touched,
-            "sparse_table_bytes": int(table_bytes)}
-
-
 def _step_extras(program, feed_names, feed_vals, fetch_names, fetches):
-    """The step record's producer-supplied fields: the sparse embedding
-    path's, and the values of a ``program.step_stats`` variable
+    """The step record's producer-supplied fields.
+
+    The sparse embedding path's: distinct rows touched this step (summed
+    over id feeds) + static table bytes.  Host feeds only — counting a
+    device-resident id feed would force a per-step sync on the async
+    path.  Also bumps the ``sparse/touched_rows`` registry counter.
+
+    And the values of a ``program.step_stats`` variable
     (``framework.Program.step_stats``: a builder's per-step counters, e.g.
     a routed-expert layer's pairs computed) WHEN the caller fetched it to
     the host with this step — a counter never costs a sync of its own."""
-    extras = _sparse_step_extras(program, feed_names, feed_vals)
+    extras = {}
+    feeds, table_bytes = _sparse_feed_info(program)
+    if feeds:
+        touched = 0
+        by_name = dict(zip(feed_names, feed_vals))
+        for n in feeds:
+            v = by_name.get(n)
+            if isinstance(v, np.ndarray) and v.size:
+                touched += int(np.unique(v).size)
+        monitor.count("sparse/touched_rows", touched)
+        extras = {"sparse_touched_rows": touched,
+                  "sparse_table_bytes": int(table_bytes)}
     declared = getattr(program, "step_stats", None)
     if declared and declared[0] in fetch_names:
         val = fetches[fetch_names.index(declared[0])]
         if isinstance(val, np.ndarray):
-            extras = dict(extras or {})
             extras.update(zip(declared[1], val.reshape(-1).tolist()))
-    return extras
+    return extras or None
+
+
+def _declares_batch(block, name):
+    """Whether the program var declares a batch dim (shape[0] == -1/None)."""
+    pv = block._find_var_recursive(name)
+    return (pv is not None and pv.shape is not None
+            and len(pv.shape) >= 1 and pv.shape[0] in (-1, None))
 
 
 def _batch_examples(block, feed_names, feed_vals):
     """Examples-per-step for StepStats: the leading dim of a feed whose
-    program var declares a batch dim (shape[0] == -1/None); fallback is
-    the max leading dim over array feeds (an alphabetically-first scalar
-    aux feed must not report examples=1)."""
+    program var declares a batch dim; fallback is the max leading dim
+    over array feeds (an alphabetically-first scalar aux feed must not
+    report examples=1)."""
     best = 0
     for n, v in zip(feed_names, feed_vals):
         if getattr(v, "ndim", 0) < 1:
             continue
-        pv = block._find_var_recursive(n)
-        if pv is not None and pv.shape is not None \
-                and len(pv.shape) >= 1 and pv.shape[0] in (-1, None):
+        if _declares_batch(block, n):
             return int(v.shape[0])
         best = max(best, int(v.shape[0]))
     return best
@@ -292,42 +285,6 @@ def trace_program(program, feed_names, state_names, writeback, fetch_names,
         return fetches, new_state
 
     return fn, state_in, state_out
-
-
-class _CompiledProgram:
-    """One lowered+jitted (program, feed-signature) entry."""
-
-    def __init__(self, fn, feed_names, state_in, state_out, fetch_names,
-                 guarded=False, probe=None):
-        self.fn = fn
-        self.feed_names = feed_names
-        self.state_in = state_in      # read from scope before the step
-        self.state_out = state_out    # written back to scope after
-        self.fetch_names = fetch_names
-        # lowered with the guardian's in-graph skip guard: the step
-        # returns a trailing `ok` fetch (stripped before user fetches)
-        # and suppresses its state update when a float fetch is
-        # non-finite
-        self.guarded = guarded
-        # lowered with the model-health probe (FLAGS_health): a HealthProbe
-        # whose (L, 4) per-layer stats array rides as one extra fetch
-        # between the user fetches and the guard's ok; None = every
-        # health call site in run() is skipped (disabled-is-free)
-        self.probe = probe
-        # feed signatures already dispatched through this entry.  jax.jit
-        # retraces+recompiles per feed shape, and the entry is shared
-        # process-globally (trace cache), so warmth is per-signature: an
-        # unseen shape's first call pays trace + XLA compile (or a
-        # persistent-cache deserialize) and is recorded as a "compile"
-        # span, seen shapes as "dispatch"
-        self.seen_sigs = set()
-        # AOT-captured executables keyed (feed_sig, device id): while
-        # profile capture is on, the cold dispatch compiles through
-        # program_profile.capture (so cost/memory analyses are readable)
-        # and every later step of that signature dispatches through the
-        # same executable — jax's AOT and jit call paths do not share a
-        # backend-compile cache, so mixing them would compile twice
-        self.aot = {}
 
 
 class AsyncDispatchQueue:
@@ -427,23 +384,128 @@ class AsyncDispatchQueue:
             self._sync_oldest()
 
 
-class Executor:
-    """Runs Programs on a Place (reference executor.py:256 / executor.cc:85)."""
+def analyze(program, feed_names, scope, fetch_names=()):
+    """Split program vars into feeds / state-from-scope / temporaries.
+    Returns ``(state_names, writeback)``."""
+    block = program.global_block()
+    produced = set(feed_names)
+    state = []
+    for op in block.ops:
+        for n in op.input_arg_names:
+            if n and n not in produced and n not in state:
+                if scope.has_var(n):
+                    state.append(n)
+                else:
+                    raise RuntimeError(
+                        "input var %r of op %r is neither fed, produced by "
+                        "an earlier op, nor present in the scope. Feed it "
+                        "or run the startup program first." % (n, op.type)
+                    )
+        for n in op.output_arg_names:
+            if n:
+                produced.add(n)
+    # fetch targets no op produces but the scope holds (evaluator
+    # state reads, plain var inspection) load like any other state
+    for n in fetch_names:
+        if n and n not in produced and n not in state \
+                and scope.has_var(n):
+            state.append(n)
+    # persistable outputs must be written back even if never read
+    writeback = []
+    for op in block.ops:
+        for n in op.output_arg_names:
+            v = block._find_var_recursive(n) if n else None
+            if v is not None and v.persistable and n not in writeback:
+                writeback.append(n)
+    return state, writeback
 
-    def __init__(self, place=None, donate_state=True):
-        """``donate_state=False`` keeps input state buffers alive after
-        the step (no XLA donation): required when several executors
-        share one scope concurrently (inference predictor clones) —
-        donation would delete the weight buffers under the other
-        executors.  Training keeps the default in-place donation."""
-        self.place = default_place(place)
-        self.donate_state = donate_state
+
+class CompiledStep:
+    """One lowered+jitted step: an entry of the process-global trace
+    cache, shared by every executor whose placement keys alike."""
+
+    def __init__(self, fn, feed_names, state_in, state_out, fetch_names,
+                 guarded=False, probe=None, feed_shardings=None,
+                 state_shardings=None, partition_key=None,
+                 pipeline_stats=None):
+        self.fn = fn
+        self.feed_names = feed_names
+        self.state_in = state_in      # read from scope before the step
+        self.state_out = state_out    # written back to scope after
+        self.fetch_names = fetch_names
+        # lowered with the guardian's in-graph skip guard: the step
+        # returns a trailing `ok` fetch (stripped before user fetches)
+        # and suppresses its state update when a float fetch is
+        # non-finite
+        self.guarded = guarded
+        # lowered with the model-health probe (FLAGS_health): a HealthProbe
+        # whose (L, 4) per-layer stats array rides as one extra fetch
+        # between the user fetches and the guard's ok; None = every
+        # health call site in the step is skipped (disabled-is-free)
+        self.probe = probe
+        # feed signatures already dispatched through this entry.  jax.jit
+        # retraces+recompiles per feed shape, and the entry is shared
+        # process-globally (trace cache), so warmth is per-signature: an
+        # unseen shape's first call pays trace + XLA compile (or a
+        # persistent-cache deserialize) and is recorded as a "compile"
+        # span, seen shapes as "dispatch"
+        self.seen_sigs = set()
+        # AOT-captured executables keyed (feed_sig, first device id):
+        # while profile capture is on, the cold dispatch compiles through
+        # program_profile.capture (so cost/memory analyses are readable)
+        # and every later step of that signature dispatches through the
+        # same executable — jax's AOT and jit call paths do not share a
+        # backend-compile cache, so mixing them would compile twice
+        self.aot = {}
+        # a mesh entry's placement of feeds and state, and its
+        # mesh/sharding identity for the program-profile registry: the
+        # same program compiled replicated vs fsdp-sharded has ~N-times
+        # different per-device memory analyses — separate profile slots
+        self.feed_shardings = feed_shardings
+        self.state_shardings = state_shardings
+        self.partition_key = partition_key
+        # schedule accounting for the program's pipeline regions on its
+        # mesh (None = nothing runs pipelined)
+        self.pipeline_stats = pipeline_stats
+
+
+def _names(fetch_list):
+    return [v.name if isinstance(v, Variable) else v
+            for v in fetch_list or []]
+
+
+def _feed_sig(feed_names, feed_vals):
+    return tuple((n, tuple(v.shape), str(v.dtype))
+                 for n, v in zip(feed_names, feed_vals))
+
+
+class StepPath:
+    """The one step path under both executors: resolve feeds and fetches,
+    find or lower the step (trace -> guard -> probe -> jit), put feeds
+    and state on the device(s), dispatch (capturing the executable at
+    the cold step), strip the guard's and the probe's fetches, write the
+    state back, check, sync or queue, record.
+
+    A subclass is a *placement* — what really differs between one device
+    and a mesh — and nothing here asks which one it is.  It sets
+    ``_name`` (spans, monitor and guardian records), ``_label`` (the
+    module reads ``jit_pt_<label>_<program>``) and ``donate_state``, and
+    answers the placement hooks below; the bodies here place the step on
+    the one device ``_first_device()`` names."""
+
+    _name = None
+    _label = None
+    # whether check_nan_inf may read the new state on the host, and
+    # whether FLAGS_benchmark times each step
+    _check_state = True
+    _times_steps = True
+
+    def __init__(self):
         self._cache = {}
         self._run_counter = 0
         self._warned_unobserved_guard = False
-        self._dispatch_queue = AsyncDispatchQueue(name="executor")
+        self._dispatch_queue = AsyncDispatchQueue(name=self._name)
 
-    # ------------------------------------------------------------------
     def sync(self):
         """Retire every in-flight async-dispatched step (the
         ``return_numpy=False`` fast path never syncs per step; call this
@@ -462,82 +524,121 @@ class Executor:
     def load_state_dict(self, state):
         self._run_counter = int(state["run_counter"])
 
-    def close(self):
-        self.sync()
-        self._cache.clear()
+    # -- the placement ---------------------------------------------------
+    def _first_device(self):
+        """The device whose platform the trace is told, whose id keys the
+        AOT slot, and which step records and profiles name."""
+        raise NotImplementedError
 
-    def _program_key(self, program, feed_sig, fetch_names, scope):
+    def _placement_key(self, dev):
+        """What this placement bakes into a lowering: joins the
+        per-executor key and the trace key."""
+        return ("jit", dev.platform, self.donate_state)
+
+    def _trace_sigs(self, feed_names, feed_sig, state_names, scope):
+        """The trace key's feed and state parts: names where one entry
+        serves every shape (jit retraces), signatures where shapes
+        decide the placement."""
+        return feed_names, tuple(state_names)
+
+    def _trace_kwargs(self, program, state_names, scope, dev):
+        """What ``trace_program`` is told beyond the names."""
+        return {"platform": dev.platform}
+
+    def _wrap_traced(self, fn):
+        """A wrap of the traced function inside guard and probe."""
+        return fn
+
+    def _jit_kwargs(self, program, traced, feed_names, feed_vals, state_in,
+                    state_out, n_fetches):
+        """``(jax.jit arguments beyond donation, CompiledStep's placement
+        attributes)``; ``traced`` is what ``_trace_kwargs`` returned."""
+        return {}, {}
+
+    # the context the step is captured and called under
+    _dispatch_scope = staticmethod(jax.default_device)
+
+    def _pad_uneven(self, feed_vals):
+        """The feeds the step runs on, given the feeds as fed; one that
+        returns others also has ``_trim_fetches`` to undo it on the
+        fetches."""
+        return feed_vals
+
+    def _put(self, compiled, feed_vals, scope, dev):
+        """``(feeds, state)`` on the device(s)."""
+        return ([jax.device_put(v, dev) for v in feed_vals],
+                [jax.device_put(scope.var(n), dev)
+                 for n in compiled.state_in])
+
+    def _auto_seed(self):
+        """The seed of a program that declares none."""
+        return np.random.randint(0, 2**31 - 1)
+
+    _fetch_to_np = staticmethod(np.asarray)
+
+    def _before_record(self, compiled, cold, mon_t0, fp):
+        """Spans the step record's goodput attribution should see."""
+
+    def _after_record(self):
+        """Gauges beyond the first device's."""
+
+    # -- lowering --------------------------------------------------------
+    def _entry(self, program, scope, feed_names, feed_vals, fetch_names,
+               dev):
+        """``(feed signature, CompiledStep)`` of this step, lowering it
+        when neither this executor nor the process has it."""
+        feed_sig = _feed_sig(feed_names, feed_vals)
         # program._version bumps on structural mutation (op append/insert,
         # rename_var) so stale compiled functions are not reused; direct
         # attr edits on existing ops are NOT tracked — clone() instead.
         # the policy object itself goes in the key (kept alive by the
         # cache) — id() could alias a recycled address after GC
-        return (id(program), program._version, program.random_seed, feed_sig,
-                tuple(fetch_names), id(scope),
-                getattr(program, '_amp_policy', None),
-                # trace-time flag choices are baked into the jaxpr
-                compile_cache.trace_flag_values())
+        key = (id(program), program._version, program.random_seed, feed_sig,
+               tuple(fetch_names), id(scope),
+               getattr(program, '_amp_policy', None),
+               # trace-time flag choices are baked into the jaxpr
+               compile_cache.trace_flag_values()) + self._placement_key(dev)
+        compiled = self._cache.get(key)
+        if compiled is None:
+            # the reference wraps op instantiation in RecordBlock
+            # (executor.cc Prepare); here the analog is the trace+jit
+            # (_lower consults the process-global trace cache first)
+            with RecordEvent(self._name + "/compile"):
+                compiled = self._cache[key] = self._lower(
+                    program, scope, feed_names, feed_vals, feed_sig,
+                    fetch_names, dev)
+        return feed_sig, compiled
 
-    def _analyze(self, program, feed_names, scope, fetch_names=()):
-        """Split program vars into feeds / state-from-scope / temporaries."""
-        block = program.global_block()
-        produced = set(feed_names)
-        state = []
-        for op in block.ops:
-            for n in op.input_arg_names:
-                if n and n not in produced and n not in state:
-                    if scope.has_var(n):
-                        state.append(n)
-                    else:
-                        raise RuntimeError(
-                            "input var %r of op %r is neither fed, produced by "
-                            "an earlier op, nor present in the scope. Feed it "
-                            "or run the startup program first." % (n, op.type)
-                        )
-            for n in op.output_arg_names:
-                if n:
-                    produced.add(n)
-        # fetch targets no op produces but the scope holds (evaluator
-        # state reads, plain var inspection) load like any other state
-        for n in fetch_names:
-            if n and n not in produced and n not in state \
-                    and scope.has_var(n):
-                state.append(n)
-        # persistable outputs must be written back even if never read
-        writeback = []
-        for op in block.ops:
-            for n in op.output_arg_names:
-                v = block._find_var_recursive(n) if n else None
-                if v is not None and v.persistable and n not in writeback:
-                    writeback.append(n)
-        return state, writeback
-
-    def _lower(self, program, feed_names, state_names, writeback, fetch_names):
-        platform = self.place.jax_device().platform
+    def _lower(self, program, scope, feed_names, feed_vals, feed_sig,
+               fetch_names, dev):
+        state_names, writeback = analyze(
+            program, feed_names, scope, fetch_names)
         # process-global trace cache: a second executor over the same
         # program structure + signature (bench reruns, evaluator clones)
         # reuses the jitted step — zero new lowerings
         tkey = compile_cache.trace_key(
-            program, feed_names, tuple(state_names), fetch_names,
-            "jit", platform, self.donate_state,
+            program,
+            *self._trace_sigs(feed_names, feed_sig, state_names, scope),
+            fetch_names, *self._placement_key(dev),
             compile_cache.trace_flag_values())
         cached = compile_cache.lookup(tkey)
         if cached is not None:
             return cached
+        traced = self._trace_kwargs(program, state_names, scope, dev)
         # FLAGS_health: per-layer grad/param/update stats ride the step as
         # one fused extra fetch.  The grad vars are added to the traced
         # fetch list (XLA sees them as outputs); enablement is part of
         # trace_flag_values so the probe-free trace is never served stale
         probe = monitor.health.build_probe(program, state_names) \
             if monitor.health.probe_enabled() else None
-        with RecordEvent("executor/trace"):
+        guarded = guardian.skip_guard_enabled()
+        with RecordEvent(self._name + "/trace"):
             traced_fetches = list(fetch_names) + \
                 (list(probe.grad_names) if probe is not None else [])
             fn, state_in, state_out = trace_program(
                 program, feed_names, state_names, writeback, traced_fetches,
-                platform=platform,
-            )
-            guarded = guardian.skip_guard_enabled()
+                **traced)
+            fn = self._wrap_traced(fn)
             if guarded:
                 # in-graph sentinel + skip: non-finite float fetches
                 # suppress the whole state update on-device (the
@@ -552,47 +653,41 @@ class Executor:
                 fn = monitor.health.wrap_step_probe(
                     fn, probe, len(fetch_names), guarded, state_in,
                     state_out)
-            donate = (1,) if self.donate_state else ()
-            jitted = jax.jit(compile_cache.name_step(fn, "exe", program),
-                             donate_argnums=donate)
-        return compile_cache.store(tkey, _CompiledProgram(
+        # the guard's trailing ok fetch and the probe's stats array are
+        # fetches to the placement too
+        jit_kwargs, placed = self._jit_kwargs(
+            program, traced, feed_names, feed_vals, state_in, state_out,
+            len(fetch_names) + (probe is not None) + guarded)
+        # jax.jit is lazy (tracing deferred to the first call): the real
+        # jaxpr cost is the trace_program above
+        jitted = jax.jit(
+            compile_cache.name_step(fn, self._label, program),
+            donate_argnums=(1,) if self.donate_state else (), **jit_kwargs)
+        return compile_cache.store(tkey, CompiledStep(
             jitted, feed_names, state_in, state_out, fetch_names,
-            guarded=guarded, probe=probe))
+            guarded=guarded, probe=probe, **placed))
 
-    # ------------------------------------------------------------------
-    def run(
-        self,
-        program=None,
-        feed=None,
-        fetch_list=None,
-        scope=None,
-        return_numpy=True,
-    ):
-        """Execute ``program``: feed dict name->array, fetch list of
-        Variables/names; persistable results are committed back to scope."""
+    # -- the step --------------------------------------------------------
+    def _run_step(self, program, scope, feed, fetch_list, return_numpy):
         # the whole call is one span, so that a profiler trace with no
         # span of the caller's in it still has the step
-        with RecordEvent("executor/step"):
-            return self._run(program, feed, fetch_list, scope, return_numpy)
+        with RecordEvent(self._name + "/step"):
+            return self._step(program, scope, feed, fetch_list,
+                              return_numpy)
 
-    def _run(self, program, feed, fetch_list, scope, return_numpy):
-        if program is None:
-            program = default_main_program()
-        feed = dict(feed or {})
-        fetch_list = fetch_list or []
-        scope = scope if scope is not None else global_scope()
+    def _step(self, program, scope, feed, fetch_list, return_numpy):
+        name = self._name
         # a single module-global bool read when telemetry is off — the
         # whole StepStats assembly is behind it
         mon_t0 = time.perf_counter() if monitor.enabled() else None
-
-        fetch_names = [
-            v.name if isinstance(v, Variable) else v for v in fetch_list
-        ]
+        feed = dict(feed or {})
+        fetch_names = _names(fetch_list)
         feed_names = sorted(feed.keys())
-        # cast feeds to declared var dtype when the program declares one;
-        # jax arrays already on device pass through untouched (the input-
-        # pipeline fast path: py_reader/double-buffer feeds stay device-
-        # resident instead of re-crossing the host link every step)
+        # cast feeds to the var's materialized dtype when the program
+        # declares one; jax arrays already on device pass through
+        # untouched (the input-pipeline fast path: py_reader/double-buffer
+        # feeds stay device-resident instead of re-crossing the host link
+        # every step)
         block = program.global_block()
         feed_vals = [_coerce_feed(block, n, feed[n]) for n in feed_names]
 
@@ -605,44 +700,30 @@ class Executor:
             fault.fire("executor/feed", step_idx,
                        feed_names=feed_names, feed_vals=feed_vals)
 
-        feed_sig = tuple(
-            (n, tuple(v.shape), str(v.dtype))
-            for n, v in zip(feed_names, feed_vals)
-        )
-        key = self._program_key(program, feed_sig, fetch_names, scope)
-        compiled = self._cache.get(key)
-        if compiled is None:
-            # the reference wraps op instantiation in RecordBlock
-            # (executor.cc Prepare); here the analog is the trace+jit
-            # (_lower consults the process-global trace cache first)
-            with RecordEvent("executor/compile"):
-                state_names, writeback = self._analyze(
-                    program, feed_names, scope, fetch_names)
-                compiled = self._lower(
-                    program, feed_names, state_names, writeback, fetch_names
-                )
-            self._cache[key] = compiled
+        # feed_vals stays the batch AS FED (post-drill, pre-pad): what
+        # the guardian quarantines and the probe's replay stashes must
+        # match what the reader yielded, not a mesh-padded copy
+        step_feeds = self._pad_uneven(feed_vals)
+        dev = self._first_device()
+        feed_sig, compiled = self._entry(
+            program, scope, feed_names, step_feeds, fetch_names, dev)
 
-        dev = self.place.jax_device()
-        with RecordEvent("executor/h2d_transfer"):
-            state_vals = [
-                jax.device_put(scope.var(n), dev) for n in compiled.state_in
-            ]
-            feed_dev = [jax.device_put(v, dev) for v in feed_vals]
+        with RecordEvent(name + "/h2d_transfer"):
+            feed_dev, state_dev = self._put(compiled, step_feeds, scope, dev)
         seed = program.random_seed or 0
         rng = jax.random.key(
-            np.uint32(seed) if seed else np.random.randint(0, 2**31 - 1),
+            np.uint32(seed) if seed else self._auto_seed(),
             impl="rbg" if flags.flag("fast_prng") else None,
         )
         rng = jax.random.fold_in(rng, self._run_counter)
         self._run_counter += 1
 
-        t0 = time.perf_counter() if flags.flag("benchmark") else None
+        t0 = time.perf_counter() \
+            if self._times_steps and flags.flag("benchmark") else None
         # an unseen feed signature's first call pays jaxpr trace + XLA
         # compile (or a persistent-cache deserialize) — recorded as a
         # compile span so cache hits are observable as its disappearance
         cold = feed_sig not in compiled.seen_sigs
-        step_span = "executor/compile" if cold else "executor/dispatch"
         # correlation tags: fingerprint is memoized per program version
         # (one attribute read when warm), computed only when some
         # observability layer is on — a dark process pays nothing here
@@ -653,44 +734,46 @@ class Executor:
         # the compute remainder — by the producer's own verdict, not by
         # name guessing
         span_args = {"run_id": monitor.run_id(), "fingerprint": fp[:12],
-                     "step": self._run_counter - 1,
+                     "step": step_idx,
                      "bucket": "trace_compile" if cold else "compute"} \
             if fp else None
         if fault.active():
             fault.fire("executor/dispatch", step_idx)
-        with RecordEvent("executor/run"):
-            with RecordEvent(step_span, args=span_args):
-                with jax.default_device(dev):
+        with RecordEvent(name + "/run"):
+            with RecordEvent(name + ("/compile" if cold else "/dispatch"),
+                             args=span_args):
+                with self._dispatch_scope(dev):
                     fn = compiled.fn
+                    slot = (feed_sig, getattr(dev, "id", 0))
                     if cold and program_profile.capture_enabled() \
-                            and (feed_sig, getattr(dev, "id", 0)) \
-                            not in compiled.aot \
+                            and slot not in compiled.aot \
                             and not flags.flag("debug_nans"):
                         # the step is AOT-compiled here — profiled
                         # (cost/memory analysis) and HBM-preflighted
                         # BEFORE its first dispatch — and the same
                         # executable serves every later step of this
-                        # signature: one compile total.  debug_nans
-                        # keeps the jit path (its nan re-run machinery
-                        # lives there).
-                        compiled.aot[(feed_sig, getattr(dev, "id", 0))] = \
-                            program_profile.capture(
-                                fp if fp is not None else
-                                compile_cache.program_fingerprint(program),
-                                feed_sig, compiled.fn,
-                                (feed_dev, state_vals, rng),
-                                device=dev, kind="executor",
-                                fetch_names=tuple(fetch_names))
+                        # signature: one compile total.  SPMD analyses
+                        # are per-device, which is the granularity the
+                        # preflight compares against.  debug_nans keeps
+                        # the jit path (its nan re-run machinery lives
+                        # there).
+                        compiled.aot[slot] = program_profile.capture(
+                            fp if fp is not None else
+                            compile_cache.program_fingerprint(program),
+                            feed_sig, compiled.fn,
+                            (feed_dev, state_dev, rng),
+                            device=dev, kind=name,
+                            fetch_names=tuple(fetch_names),
+                            partition=compiled.partition_key)
                     # debug_nans checked at dispatch too: a previously
                     # captured executable must not bypass the jit
                     # path's op-level nan re-run machinery
                     if compiled.aot and not flags.flag("debug_nans"):
-                        fn = compiled.aot.get(
-                            (feed_sig, getattr(dev, "id", 0)), compiled.fn)
+                        fn = compiled.aot.get(slot, fn)
                     # an AOT executable that rejects its args raises
                     # here: re-dispatching through jit would hide a
                     # second compile of the step
-                    fetches, new_state = fn(feed_dev, state_vals, rng)
+                    fetches, new_state = fn(feed_dev, state_dev, rng)
         compiled.seen_sigs.add(feed_sig)
 
         ok_flag = None
@@ -706,7 +789,7 @@ class Executor:
             health_stats = fetches[-1]
             fetches = fetches[:-1]
             monitor.health.note_step(
-                "executor", step_idx, compiled.probe, health_stats,
+                name, step_idx, compiled.probe, health_stats,
                 program=program, scope=scope, rng=rng,
                 feed_names=feed_names, feed_vals=feed_vals,
                 platform=dev.platform)
@@ -719,16 +802,25 @@ class Executor:
             fault.fire("executor/step_done", step_idx, scope=scope,
                        state_names=compiled.state_out,
                        fetch_names=compiled.fetch_names, fetches=fetches)
+        if step_feeds is not feed_vals:
+            fetches = self._trim_fetches(block, compiled.fetch_names,
+                                         fetches, feed_vals, step_feeds)
 
+        np_fetches = None
         if flags.flag("check_nan_inf"):
             ctx = lambda: "run_id=%s fp12=%s step=%d" % (  # noqa: E731
                 monitor.run_id(),
                 compile_cache.program_fingerprint(program)[:12], step_idx)
+            # a side copy, so return_numpy=False still hands back device
+            # arrays (the check implies a per-step sync, not a type
+            # change)
+            np_fetches = [self._fetch_to_np(f) for f in fetches]
             try:
-                _check_finite(zip(compiled.fetch_names, fetches),
+                _check_finite(zip(compiled.fetch_names, np_fetches),
                               context=ctx)
-                _check_finite(zip(compiled.state_out, new_state),
-                              context=ctx)
+                if self._check_state:
+                    _check_finite(zip(compiled.state_out, new_state),
+                                  context=ctx)
             except RuntimeError as e:
                 raise _with_provenance(e, compiled.probe, step_idx) \
                     from None
@@ -738,32 +830,76 @@ class Executor:
                   % ((time.perf_counter() - t0) * 1e3))
 
         if return_numpy:
-            with RecordEvent("executor/fetch_sync"):
-                fetches = [np.asarray(f) for f in fetches]
+            with RecordEvent(name + "/fetch_sync"):
+                fetches = np_fetches if np_fetches is not None else \
+                    [self._fetch_to_np(f) for f in fetches]
         else:
-            # async fast path: fetches stay device arrays; bound the
-            # host's run-ahead on the dispatch window (sync only at
-            # window edges, never per step)
+            # async fast path: fetches stay (possibly sharded) device
+            # arrays; bound the host's run-ahead on the dispatch window
+            # (sync only at window edges, never per step)
             self._dispatch_queue.push_step(fetches, new_state)
         if mon_t0 is not None:
+            self._before_record(compiled, cold, mon_t0, fp)
             monitor.record_step(
-                "executor", time.perf_counter() - mon_t0,
+                name, time.perf_counter() - mon_t0,
                 _batch_examples(block, feed_names, feed_vals),
                 len(self._dispatch_queue), device=dev,
                 warm=not cold, fingerprint=fp,
                 extras=_step_extras(program, feed_names, feed_vals,
                                     compiled.fetch_names, fetches))
+            self._after_record()
         # guardian hook LAST (after telemetry): a ladder decision raises
         # out of run() with this step's record already published.  One
         # module-global read when no guardian is installed.
         g = guardian.active()
         if g is not None:
-            g.note_step("executor", step_idx, ok=ok_flag,
+            g.note_step(name, step_idx, ok=ok_flag,
                         fetch_names=compiled.fetch_names, fetches=fetches,
                         feed=(feed_names, feed_vals), sync=return_numpy)
         elif ok_flag is not None:
             guardian.warn_unobserved_skip_guard(self)
         return fetches
+
+
+class Executor(StepPath):
+    """Runs Programs on a Place (reference executor.py:256 / executor.cc:85):
+    the step path placed on one device."""
+
+    _name = "executor"
+    _label = "exe"
+
+    def __init__(self, place=None, donate_state=True):
+        """``donate_state=False`` keeps input state buffers alive after
+        the step (no XLA donation): required when several executors
+        share one scope concurrently (inference predictor clones) —
+        donation would delete the weight buffers under the other
+        executors.  Training keeps the default in-place donation."""
+        super().__init__()
+        self.place = default_place(place)
+        self.donate_state = donate_state
+
+    def close(self):
+        self.sync()
+        self._cache.clear()
+
+    def _first_device(self):
+        return self.place.jax_device()
+
+    # ------------------------------------------------------------------
+    def run(
+        self,
+        program=None,
+        feed=None,
+        fetch_list=None,
+        scope=None,
+        return_numpy=True,
+    ):
+        """Execute ``program``: feed dict name->array, fetch list of
+        Variables/names; persistable results are committed back to scope."""
+        return self._run_step(
+            program if program is not None else default_main_program(),
+            scope if scope is not None else global_scope(),
+            feed, fetch_list, return_numpy)
 
     def cost_analysis(self, program=None, feed=None, fetch_list=None,
                       scope=None, compile_if_missing=True):
@@ -781,18 +917,12 @@ class Executor:
         if program is None:
             program = default_main_program()
         feed = dict(feed or {})
-        fetch_list = fetch_list or []
         scope = scope if scope is not None else global_scope()
-        fetch_names = [
-            v.name if isinstance(v, Variable) else v for v in fetch_list
-        ]
+        fetch_names = _names(fetch_list)
         feed_names = sorted(feed.keys())
         block = program.global_block()
         feed_vals = [_coerce_feed(block, n, feed[n]) for n in feed_names]
-        feed_sig = tuple(
-            (n, tuple(v.shape), str(v.dtype))
-            for n, v in zip(feed_names, feed_vals)
-        )
+        feed_sig = _feed_sig(feed_names, feed_vals)
         fp = compile_cache.program_fingerprint(program)
         prof = program_profile.get(fp, feed_sig, kind="executor",
                                    fetch_names=tuple(fetch_names))
@@ -800,21 +930,15 @@ class Executor:
             return dict(prof.cost)
         if not compile_if_missing:
             return None
-        key = self._program_key(program, feed_sig, fetch_names, scope)
-        compiled = self._cache.get(key)
-        if compiled is None:
-            state_names, writeback = self._analyze(
-                program, feed_names, scope, fetch_names)
-            compiled = self._lower(
-                program, feed_names, state_names, writeback, fetch_names)
-            self._cache[key] = compiled
+        dev = self._first_device()
+        _, compiled = self._entry(
+            program, scope, feed_names, feed_vals, fetch_names, dev)
         state_vals = [np.asarray(scope.var(n)) for n in compiled.state_in]
         rng = jax.random.key(
             0, impl="rbg" if flags.flag("fast_prng") else None)
-        dev = self.place.jax_device()
         # lower on the executor's device so the executable is the one a
         # run() of this signature would build
-        with jax.default_device(dev):
+        with self._dispatch_scope(dev):
             cexec = compiled.fn.lower(feed_vals, state_vals, rng).compile()
         # seed the profile registry AND the entry's AOT-dispatch slot:
         # repeated cost_analysis calls are free, and a later run() of

@@ -26,20 +26,19 @@ dataflow executor) — re-designed TPU-first:
   world, parallel_executor.cc:94-103).
 """
 
+import contextlib
+import math
 import time
+import warnings
 
 import numpy as np
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .. import compile_cache, fault, flags, guardian, monitor, registry  # noqa: F401  (op registry must be loaded)
-from ..executor import (AsyncDispatchQueue, trace_program, Executor,
-                        _batch_examples, _check_finite,
-                        _sparse_step_extras, _with_provenance)
-from ..monitor import program_profile
-from ..profiler import RecordEvent, is_profiling
-from ..framework import Variable, default_main_program
+from .. import monitor, registry  # noqa: F401  (op registry must be loaded)
+from ..executor import StepPath, _declares_batch
+from ..framework import default_main_program
 from ..scope import global_scope
 from .mesh import make_mesh, AXIS_DP, AXIS_FSDP
 from .spec_layout import SpecLayout
@@ -53,44 +52,21 @@ __all__ = ["ParallelExecutor"]
 _DEFAULT_SPEC_LAYOUT = SpecLayout()
 
 
-class _Compiled:
-    def __init__(self, fn, feed_names, state_in, state_out, fetch_names,
-                 feed_shardings, state_shardings, out_state_shardings,
-                 partition_key=None, guarded=False, probe=None):
-        self.fn = fn
-        self.feed_names = feed_names
-        self.state_in = state_in
-        self.state_out = state_out
-        self.fetch_names = fetch_names
-        self.feed_shardings = feed_shardings
-        self.state_shardings = state_shardings
-        self.out_state_shardings = out_state_shardings
-        # mesh/sharding identity for the program-profile registry: the
-        # same program compiled replicated vs fsdp-sharded has ~N-times
-        # different per-device memory analyses — separate profile slots
-        self.partition_key = partition_key
-        # lowered with the guardian's in-graph skip guard (trailing ok
-        # fetch; see executor._CompiledProgram)
-        self.guarded = guarded
-        # lowered with the model-health probe (FLAGS_health): the (L, 4)
-        # per-layer stats array rides between user fetches and ok; None
-        # means run() performs zero health calls
-        self.probe = probe
-        self.warm = False      # first dispatch = trace+compile (see Executor)
-        # schedule accounting for the program's pipeline regions on this
-        # mesh (set by PE._compile; None = nothing runs pipelined)
-        self.pipeline_stats = None
-        # AOT-captured executable (one per entry: the trace-cache key
-        # already pins the feed signature + mesh); set by profile
-        # capture at the cold dispatch and used for every later step
-        self.aot_exec = None
+class ParallelExecutor(StepPath):
+    """The step path placed on a mesh: this class is the mesh's side of
+    ``executor.StepPath``'s placement hooks and nothing of the step."""
 
+    _name = "parallel_executor"
+    _label = "pe"
+    # state may span hosts (not fully addressable): fetches only
+    _check_state = False
+    _times_steps = False
 
-class ParallelExecutor:
     def __init__(self, use_cuda=True, loss_name=None, main_program=None,
                  share_vars_from=None, exec_strategy=None,
                  build_strategy=None, num_trainers=1, trainer_id=0,
                  scope=None, mesh=None):
+        super().__init__()
         self._mesh = mesh if mesh is not None else make_mesh()
         if AXIS_DP not in self._mesh.axis_names and \
                 AXIS_FSDP not in self._mesh.axis_names:
@@ -104,11 +80,10 @@ class ParallelExecutor:
         self._loss_name = loss_name
         self._num_trainers = num_trainers
         self._trainer_id = trainer_id
-        self._cache = {}
-        self._run_counter = 0
-        self._warned_unobserved_guard = False
+        self._mesh_key = (tuple(self._mesh.axis_names),
+                          tuple(self._mesh.devices.shape),
+                          tuple(int(d.id) for d in self._mesh.devices.flat))
         self._auto_seed_val = None
-        self._dispatch_queue = AsyncDispatchQueue(name="parallel_executor")
         # observability: how many ragged batches were replication-padded
         # (the data_balance_op_handle capability — see _pad_uneven)
         self.uneven_batches_padded = 0
@@ -227,79 +202,74 @@ class ParallelExecutor:
                 return P(ax)
         return P()
 
-    def _compile(self, program, feed_names, fetch_names, scope, feed_vals,
-                 feed_sig):
-        exe = Executor.__new__(Executor)  # reuse its analyzer only
-        state_names, writeback = Executor._analyze(
-            exe, program, feed_names, scope)
+    # -- the placement: a mesh ---------------------------------------------
+    @property
+    def donate_state(self):
+        return self._build_strategy.donate_state
+
+    def _first_device(self):
+        return self._mesh.devices.flat[0]
+
+    def _placement_key(self, dev):
+        # mesh identity and the sharding policy knobs; policy fns go in
+        # as objects (kept alive by the cache, so no id()-reuse aliasing
+        # after GC)
         bs = self._build_strategy
-        # process-global trace cache: key everything this lowering bakes
-        # in — program structure + signatures (fingerprint/feed/state/
-        # fetch), mesh identity, and the sharding policy knobs
-        state_sig = tuple(
+        return ("pjit", self._mesh_key, bs.reduce_strategy,
+                bs.param_sharding_fn, bs.feed_sharding_fn,
+                self._sharding_layout(), bs.sequence_parallel, bs.remat,
+                bs.donate_state, jax.process_count(),
+                bs.pipeline_schedule, bs.pipeline_microbatches)
+
+    def _trace_sigs(self, feed_names, feed_sig, state_names, scope):
+        # shapes decide the shardings below, so they key the entry
+        return feed_sig, tuple(
             (n, tuple(getattr(scope.var(n), "shape", ())),
              str(getattr(scope.var(n), "dtype", "")))
             for n in state_names)
-        mesh_key = (tuple(self._mesh.axis_names),
-                    tuple(self._mesh.devices.shape),
-                    tuple(int(d.id) for d in self._mesh.devices.flat))
-        tkey = compile_cache.trace_key(
-            program, feed_sig, state_sig, fetch_names,
-            "pjit", mesh_key, bs.reduce_strategy, bs.param_sharding_fn,
-            bs.feed_sharding_fn, self._sharding_layout(),
-            bs.sequence_parallel, bs.remat,
-            bs.donate_state, jax.process_count(),
-            bs.pipeline_schedule, bs.pipeline_microbatches,
-            compile_cache.trace_flag_values())
-        cached = compile_cache.lookup(tkey)
-        if cached is not None:
-            return cached
 
-        mesh = self._mesh
-        # resolve the state placement BEFORE tracing: sharded-op
-        # lowerings (sparse embedding lookup/update over row-sharded
-        # tables) read their operands' specs from the trace context, so
-        # the placement is an input of the trace, not an afterthought.
-        # state_in below == state_names (trace_program's contract).
-        pre_state_vals = [scope.var(n) for n in state_names]
+    def _state_specs(self, program, state):
+        """``{name: PartitionSpec}`` for the persistables ``state``
+        (name -> value) under this executor's policy."""
         layout = self._sharding_layout()
         rule_specs = {}
         if layout is not None:
             rule_specs = layout.resolve(
-                program, mesh,
+                program, self._mesh,
                 [(n, tuple(getattr(v, "shape", ())))
-                 for n, v in zip(state_names, pre_state_vals)])
-        spec_by_name = {
-            n: self._state_spec(n, v, rule_specs)
-            for n, v in zip(state_names, pre_state_vals)
-        }
+                 for n, v in state.items()])
+        return {n: self._state_spec(n, v, rule_specs)
+                for n, v in state.items()}
 
-        # FLAGS_health: grad vars join the traced fetch list, the fused
-        # per-layer stats reduction rides as one extra fetch (see
-        # executor._lower); enablement re-keys via trace_flag_values
-        probe = monitor.health.build_probe(program, state_names) \
-            if monitor.health.probe_enabled() else None
-        traced_fetches = list(fetch_names) + \
-            (list(probe.grad_names) if probe is not None else [])
-        with RecordEvent("parallel_executor/trace"):
-            fn, state_in, state_out = trace_program(
-                program, feed_names, state_names, writeback, traced_fetches,
-                platform=self._mesh.devices.flat[0].platform,
-                mesh=self._mesh,
-                sequence_parallel=self._build_strategy.sequence_parallel,
-                pipeline_schedule=bs.pipeline_schedule,
-                pipeline_microbatches=bs.pipeline_microbatches,
-                state_specs=spec_by_name)
+    def _trace_kwargs(self, program, state_names, scope, dev):
+        bs = self._build_strategy
+        # the state placement is resolved BEFORE tracing: sharded-op
+        # lowerings (sparse embedding lookup/update over row-sharded
+        # tables) read their operands' specs from the trace context, so
+        # the placement is an input of the trace, not an afterthought
+        return {"platform": dev.platform, "mesh": self._mesh,
+                "sequence_parallel": bs.sequence_parallel,
+                "pipeline_schedule": bs.pipeline_schedule,
+                "pipeline_microbatches": bs.pipeline_microbatches,
+                "state_specs": self._state_specs(
+                    program, {n: scope.var(n) for n in state_names})}
+
+    def _wrap_traced(self, fn):
+        # INSIDE the guard, so the guard's select is not rematerialized
+        return jax.checkpoint(fn) if self._build_strategy.remat else fn
+
+    def _jit_kwargs(self, program, traced, feed_names, feed_vals, state_in,
+                    state_out, n_fetches):
+        mesh = self._mesh
+        spec_by_name = traced["state_specs"]
         data_axes = self._data_axes()
         batch_spec = P(data_axes if len(data_axes) > 1 else data_axes[0])
         feed_shardings = []
-        dp = self._dp_size()
         # multi-host: each process feeds its local slice, so the local
         # batch only needs to cover this process's share of the dp axis
-        dp = max(1, dp // jax.process_count())
+        dp = max(1, self._dp_size() // jax.process_count())
         custom_feed = self._build_strategy.feed_sharding_fn
-        for n, v in zip(feed_names, feed_vals):
-            arr = np.asarray(v) if not isinstance(v, jax.Array) else v
+        for n, arr in zip(feed_names, feed_vals):
             spec = None
             if custom_feed is not None:
                 spec = custom_feed(n, tuple(arr.shape))
@@ -319,7 +289,6 @@ class ParallelExecutor:
                     "data-parallel mesh extent %d (dp x fsdp)"
                     % (n, arr.shape[:1], dp)
                 )
-
         state_shardings = [
             NamedSharding(mesh, spec_by_name[n]) for n in state_in
         ]
@@ -327,49 +296,20 @@ class ParallelExecutor:
             NamedSharding(mesh, spec_by_name.get(n, P()))
             for n in state_out
         ]
-
-        if self._build_strategy.remat:
-            fn = jax.checkpoint(fn)
-
-        guarded = guardian.skip_guard_enabled()
-        if guarded:
-            # in-graph sentinel + skip (see executor._lower); wrapped
-            # OUTSIDE remat so the guard's select is not rematerialized.
-            # n_watch keeps the probe's grad fetches off the sentinel
-            fn = guardian.wrap_step_guard(fn, state_in, state_out,
-                                          n_watch=len(fetch_names))
-        if probe is not None:
-            fn = monitor.health.wrap_step_probe(
-                fn, probe, len(fetch_names), guarded, state_in, state_out)
-
-        donate = (1,) if self._build_strategy.donate_state else ()
         # multi-host: fetches are forced replicated so every process can
         # read them (np.asarray on a non-addressable array would throw)
-        fetch_shardings = None
-        if jax.process_count() > 1:
-            # +1s: the guard's trailing ok fetch and the probe's stats
-            # array are scalars/small every process must read too
-            fetch_shardings = [NamedSharding(mesh, P())] \
-                * (len(fetch_names) + (1 if probe is not None else 0)
-                   + (1 if guarded else 0))
-        # jax.jit here is lazy (tracing deferred to the first call): no
-        # span — the real jaxpr cost is the trace_program above
-        jitted = jax.jit(
-            compile_cache.name_step(fn, "pe", program),
-            in_shardings=(feed_shardings, state_shardings, None),
-            out_shardings=(fetch_shardings, out_state_shardings),
-            donate_argnums=donate,
-        )
-        partition_key = (mesh_key[0], mesh_key[1], tuple(
-            (n, str(spec_by_name[n])) for n in state_in
-            if spec_by_name[n] != P()))
-        compiled = _Compiled(
-            jitted, feed_names, state_in, state_out,
-            fetch_names, feed_shardings, state_shardings,
-            out_state_shardings, partition_key=partition_key,
-            guarded=guarded, probe=probe)
-        compiled.pipeline_stats = self._pipeline_stats(program)
-        return compile_cache.store(tkey, compiled)
+        fetch_shardings = [NamedSharding(mesh, P())] * n_fetches \
+            if jax.process_count() > 1 else None
+        return ({"in_shardings": (feed_shardings, state_shardings, None),
+                 "out_shardings": (fetch_shardings, out_state_shardings)},
+                {"feed_shardings": feed_shardings,
+                 "state_shardings": state_shardings,
+                 "partition_key": self._mesh_key[:2] + (tuple(
+                     (n, str(spec_by_name[n])) for n in state_in
+                     if spec_by_name[n] != P()),),
+                 "pipeline_stats": self._pipeline_stats(program)})
+
+    _dispatch_scope = staticmethod(contextlib.nullcontext)
 
     def _pipeline_stats(self, program):
         """Per-tick stage-idle accounting for the program's
@@ -409,7 +349,6 @@ class ParallelExecutor:
                 "bubble_fraction": idle / total if total else 0.0,
                 "regions": regions}
 
-    # ------------------------------------------------------------------
     @staticmethod
     def _global_state(val, sharding):
         """Lift a host-local state value (identical on every process, by
@@ -420,7 +359,6 @@ class ParallelExecutor:
         return jax.make_array_from_callback(
             host.shape, sharding, lambda idx: host[idx])
 
-    # ------------------------------------------------------------------
     def _pad_uneven(self, feed_vals):
         """Ragged-batch handling (reference
         ``details/data_balance_op_handle.cc:1`` redistributes uneven
@@ -433,299 +371,97 @@ class ParallelExecutor:
         trajectory matches the single-device run bit-for-bit; per-sample
         fetches are trimmed back to the true batch.  Costs r x compute
         for the one ragged batch per epoch."""
-        import math
-
+        if not self._build_strategy.pad_uneven_batches:
+            return feed_vals
         dp = max(1, self._dp_size() // jax.process_count())
         bs = {v.shape[0] for v in feed_vals if getattr(v, "ndim", 0) >= 1}
         if len(bs) != 1:
-            return feed_vals, 1
+            return feed_vals
         b = bs.pop()
         if b <= 0 or b % dp == 0:
-            return feed_vals, 1
+            return feed_vals
         r = dp // math.gcd(b, dp)
         if self.uneven_batches_padded == 0:
-            import warnings
             warnings.warn(
                 "ragged batch %d replicated x%d to fit the dp=%d mesh: "
                 "exact for mean-normalized losses and BN stats; a "
                 "sum-reduced objective would scale by the replication "
                 "factor — set BuildStrategy.pad_uneven_batches=False to "
                 "reject ragged batches instead" % (b, r, dp),
-                stacklevel=3)
+                stacklevel=5)
         self.uneven_batches_padded += 1
         return [np.concatenate([np.asarray(v)] * r, axis=0)
-                for v in feed_vals], r
+                for v in feed_vals]
+
+    def _trim_fetches(self, block, fetch_names, fetches, fed, padded):
+        """Trim per-sample fetches (e.g. predictions [B*r, ...]) back to
+        the true batch; scalars/means are replication-invariant.  Only
+        BATCH-dim vars trim (program shape[0] == -1): a parameter whose
+        leading dim coincidentally equals the padded batch must come
+        back whole."""
+        padded_b, true_b = (
+            next((v.shape[0] for v in vals if getattr(v, "ndim", 0) >= 1), 0)
+            for vals in (padded, fed))
+        return [
+            f[:true_b] if getattr(f, "ndim", 0) >= 1
+            and f.shape[0] == padded_b and _declares_batch(block, n) else f
+            for n, f in zip(fetch_names, fetches)
+        ]
+
+    def _put(self, compiled, feed_vals, scope, dev):
+        if jax.process_count() > 1:
+            # NCCL2-mode parity: each trainer process feeds its LOCAL
+            # shard of the global batch; the global array spans hosts
+            # (parallel_executor.cc:102 flat world of trainer ranks)
+            return ([v if isinstance(v, jax.Array)
+                     and len(v.sharding.device_set) > 1
+                     else jax.make_array_from_process_local_data(s, v)
+                     for v, s in zip(feed_vals, compiled.feed_shardings)],
+                    [self._global_state(scope.var(n), s)
+                     for n, s in zip(compiled.state_in,
+                                     compiled.state_shardings)])
+        return ([jax.device_put(v, s)
+                 for v, s in zip(feed_vals, compiled.feed_shardings)],
+                [jax.device_put(scope.var(n), s)
+                 for n, s in zip(compiled.state_in,
+                                 compiled.state_shardings)])
+
+    def _before_record(self, compiled, cold, mon_t0, fp):
+        ps = compiled.pipeline_stats
+        if ps is not None and not cold:
+            # measured bubble attribution: the executed schedule's
+            # per-tick stage-idle fraction (exact, from the lowering's
+            # own schedule tables) carved out of this step's measured
+            # wall clock.  Warm steps only — a cold step's wall is
+            # compile, already attributed.  The whole step is treated as
+            # pipelined time (the regions dominate deep models;
+            # documented in README).
+            monitor.observe_span(
+                "pipeline/bubble",
+                (time.perf_counter() - mon_t0) * ps["bubble_fraction"] * 1e6,
+                args={"bucket": "pipeline_bubble",
+                      "schedule": ps["schedule"],
+                      "fraction": round(ps["bubble_fraction"], 4),
+                      "run_id": monitor.run_id(),
+                      "fingerprint": fp[:12] if fp else None})
+
+    def _after_record(self):
+        # per-device memory/step gauges for the whole local mesh (the
+        # step record's sample covers only the first device)
+        monitor.sample_device_gauges(
+            [d for d in self._mesh.devices.flat
+             if d.process_index == jax.process_index()])
 
     # ------------------------------------------------------------------
     def run(self, fetch_list, feed=None, feed_dict=None, return_numpy=True):
-        # the whole call is one span (see Executor.run)
-        with RecordEvent("parallel_executor/step"):
-            return self._run(fetch_list, feed, feed_dict, return_numpy)
-
-    def _run(self, fetch_list, feed, feed_dict, return_numpy):
-        program = self._program or default_main_program()
-        scope = self._actual_scope()
-        mon_t0 = time.perf_counter() if monitor.enabled() else None
         feed = feed if feed is not None else feed_dict
         if isinstance(feed, (list, tuple)):
             # reference per-device feed list: concatenate along batch
-            merged = {}
-            for k in feed[0]:
-                merged[k] = np.concatenate(
-                    [np.asarray(d[k]) for d in feed], axis=0)
-            feed = merged
-        feed = dict(feed or {})
-
-        fetch_names = [
-            v.name if isinstance(v, Variable) else v for v in fetch_list
-        ]
-        feed_names = sorted(feed.keys())
-        block = program.global_block()
-        feed_vals = []
-        for n in feed_names:
-            v = feed[n]
-            if not isinstance(v, jax.Array):
-                v = np.asarray(v)
-            pv = block._find_var_recursive(n)
-            if pv is not None and pv.dtype is not None and \
-                    np.dtype(v.dtype) != np.dtype(pv.dtype):
-                v = v.astype(pv.dtype)
-            feed_vals.append(v)
-
-        # this run's step index (before the PRNG fold-in counter bumps):
-        # fault schedules and guardian records key on it
-        step_idx = self._run_counter
-        if fault.active():
-            fault.fire("executor/feed", step_idx,
-                       feed_names=feed_names, feed_vals=feed_vals)
-
-        # the guardian quarantines the batch AS FED (post-drill, pre-pad):
-        # a replayed quarantine artifact must match what the reader
-        # yielded, not the mesh-padded copy
-        user_feed_vals = feed_vals
-        pad_r = 1
-        if self._build_strategy.pad_uneven_batches:
-            feed_vals, pad_r = self._pad_uneven(feed_vals)
-
-        feed_sig = tuple(
-            (n, tuple(v.shape), str(v.dtype))
-            for n, v in zip(feed_names, feed_vals)
-        )
-        # policy fns go in the key as objects (kept alive by the cache, so
-        # no id()-reuse aliasing after GC)
-        key = (id(program), program._version, feed_sig, tuple(fetch_names),
-               id(scope), getattr(program, '_amp_policy', None),
-               # trace-time flag choices, matching _compile's trace_key
-               compile_cache.trace_flag_values(),
-               self._build_strategy.reduce_strategy,
-               self._build_strategy.param_sharding_fn,
-               self._build_strategy.feed_sharding_fn,
-               self._sharding_layout(),
-               self._build_strategy.pipeline_schedule,
-               self._build_strategy.pipeline_microbatches)
-        compiled = self._cache.get(key)
-        if compiled is None:
-            with RecordEvent("parallel_executor/compile"):
-                compiled = self._compile(program, feed_names, fetch_names,
-                                         scope, feed_vals, feed_sig)
-            self._cache[key] = compiled
-
-        multihost = jax.process_count() > 1
-        with RecordEvent("parallel_executor/h2d_transfer"):
-            if multihost:
-                # NCCL2-mode parity: each trainer process feeds its LOCAL
-                # shard of the global batch; the global array spans hosts
-                # (parallel_executor.cc:102 flat world of trainer ranks)
-                feed_dev = [
-                    v if isinstance(v, jax.Array)
-                    and len(v.sharding.device_set)
-                    > 1 else jax.make_array_from_process_local_data(s, v)
-                    for v, s in zip(feed_vals, compiled.feed_shardings)
-                ]
-                state_dev = [
-                    self._global_state(scope.var(n), s)
-                    for n, s in zip(compiled.state_in,
-                                    compiled.state_shardings)
-                ]
-            else:
-                feed_dev = [
-                    jax.device_put(v, s)
-                    for v, s in zip(feed_vals, compiled.feed_shardings)
-                ]
-                state_dev = [
-                    jax.device_put(scope.var(n), s)
-                    for n, s in zip(compiled.state_in,
-                                    compiled.state_shardings)
-                ]
-        seed = program.random_seed or 0
-        rng = jax.random.key(
-            np.uint32(seed) if seed else self._auto_seed(),
-            impl="rbg" if flags.flag("fast_prng") else None)
-        rng = jax.random.fold_in(rng, self._run_counter)
-        self._run_counter += 1
-
-        step_span = "parallel_executor/dispatch" if compiled.warm \
-            else "parallel_executor/compile"
-        fp = compile_cache.program_fingerprint(program) \
-            if (mon_t0 is not None or is_profiling()) else None
-        # bucket hint for the goodput ledger / offline trace_summary
-        # (same contract as the single-device Executor)
-        span_args = {"run_id": monitor.run_id(), "fingerprint": fp[:12],
-                     "step": self._run_counter - 1,
-                     "bucket": "compute" if compiled.warm
-                     else "trace_compile"} if fp else None
-        if fault.active():
-            fault.fire("executor/dispatch", step_idx)
-        with RecordEvent("parallel_executor/run"):
-            with RecordEvent(step_span, args=span_args):
-                if not compiled.warm and program_profile.capture_enabled() \
-                        and not flags.flag("debug_nans"):
-                    # AOT-compile + profile + HBM-preflight the pjit'd
-                    # module before its first dispatch; the captured
-                    # executable serves every later step (one compile
-                    # total).  SPMD analyses are per-device, which is
-                    # the granularity the preflight compares against.
-                    compiled.aot_exec = program_profile.capture(
-                        fp if fp is not None else
-                        compile_cache.program_fingerprint(program),
-                        feed_sig, compiled.fn, (feed_dev, state_dev, rng),
-                        device=self._mesh.devices.flat[0],
-                        kind="parallel_executor",
-                        fetch_names=tuple(fetch_names),
-                        partition=compiled.partition_key)
-                fn = compiled.aot_exec \
-                    if compiled.aot_exec is not None \
-                    and not flags.flag("debug_nans") else compiled.fn
-                # no jit re-dispatch when the AOT executable rejects
-                # its args (see Executor.run)
-                fetches, new_state = fn(feed_dev, state_dev, rng)
-        compiled.warm = True
-
-        ok_flag = None
-        if compiled.guarded:
-            # the in-graph sentinel's verdict rides as a trailing fetch
-            ok_flag = fetches[-1]
-            fetches = fetches[:-1]
-        if compiled.probe is not None:
-            # per-layer health stats ride second-to-last (before ok);
-            # the replay context stashes the batch AS FED (pre-pad), the
-            # same artifact the guardian quarantines — so provenance
-            # replays reproduce the quarantined step exactly
-            health_stats = fetches[-1]
-            fetches = fetches[:-1]
-            monitor.health.note_step(
-                "parallel_executor", step_idx, compiled.probe,
-                health_stats, program=program, scope=scope, rng=rng,
-                feed_names=feed_names, feed_vals=user_feed_vals,
-                platform=self._mesh.devices.flat[0].platform)
-
-        for n, v in zip(compiled.state_out, new_state):
-            scope.set_var(n, v)
-
-        if fault.active():
-            fetches = list(fetches)
-            fault.fire("executor/step_done", step_idx, scope=scope,
-                       state_names=compiled.state_out,
-                       fetch_names=compiled.fetch_names, fetches=fetches)
-        if pad_r > 1:
-            # trim per-sample fetches (e.g. predictions [B*r, ...]) back
-            # to the true batch; scalars/means are replication-invariant.
-            # Only BATCH-dim vars trim (program shape[0] == -1): a
-            # parameter whose leading dim coincidentally equals the
-            # padded batch must come back whole.
-            padded_b = next((v.shape[0] for v in feed_vals
-                             if getattr(v, "ndim", 0) >= 1), 0)
-            true_b = padded_b // pad_r
-
-            def _is_batch_var(name):
-                v = block._find_var_recursive(name)
-                return (v is not None and v.shape is not None
-                        and len(v.shape) >= 1 and v.shape[0] in (-1, None))
-
-            fetches = [
-                f[:true_b] if getattr(f, "ndim", 0) >= 1
-                and f.shape[0] == padded_b and _is_batch_var(n) else f
-                for n, f in zip(compiled.fetch_names, fetches)
-            ]
-        np_fetches = None
-        if flags.flag("check_nan_inf"):
-            # fetches only: state may span hosts (not fully addressable).
-            # Convert into a side copy so return_numpy=False still hands
-            # back device arrays (the check implies a per-step sync, not
-            # a type change).
-            np_fetches = [self._fetch_to_np(f) for f in fetches]
-            try:
-                _check_finite(
-                    zip(compiled.fetch_names, np_fetches),
-                    context=lambda: "run_id=%s fp12=%s step=%d" % (
-                        monitor.run_id(),
-                        compile_cache.program_fingerprint(program)[:12],
-                        step_idx))
-            except RuntimeError as e:
-                raise _with_provenance(e, compiled.probe, step_idx) \
-                    from None
-        if return_numpy:
-            with RecordEvent("parallel_executor/fetch_sync"):
-                fetches = np_fetches if np_fetches is not None else \
-                    [self._fetch_to_np(f) for f in fetches]
-        else:
-            # async fast path (matches single-device Executor semantics):
-            # fetches stay (possibly sharded) device arrays, no per-step
-            # sync — the dispatch window blocks only at its edge
-            self._dispatch_queue.push_step(fetches, new_state)
-        if mon_t0 is not None:
-            warm_step = step_span == "parallel_executor/dispatch"
-            ps = compiled.pipeline_stats
-            if ps is not None and warm_step:
-                # measured bubble attribution: the executed schedule's
-                # per-tick stage-idle fraction (exact, from the
-                # lowering's own schedule tables) carved out of this
-                # step's measured wall clock.  Warm steps only — a cold
-                # step's wall is compile, already attributed.  The
-                # whole step is treated as pipelined time (the regions
-                # dominate deep models; documented in README).
-                step_s = time.perf_counter() - mon_t0
-                monitor.observe_span(
-                    "pipeline/bubble",
-                    step_s * ps["bubble_fraction"] * 1e6,
-                    args={"bucket": "pipeline_bubble",
-                          "schedule": ps["schedule"],
-                          "fraction": round(ps["bubble_fraction"], 4),
-                          "run_id": monitor.run_id(),
-                          "fingerprint": fp[:12] if fp else None})
-            # // pad_r: a replication-padded ragged batch still trained
-            # on its true example count
-            examples = _batch_examples(block, feed_names,
-                                       feed_vals) // pad_r
-            monitor.record_step(
-                "parallel_executor", time.perf_counter() - mon_t0,
-                examples, len(self._dispatch_queue),
-                device=self._mesh.devices.flat[0],
-                warm=warm_step,
-                fingerprint=fp,
-                extras=_sparse_step_extras(program, feed_names,
-                                           user_feed_vals))
-            # per-device memory/step gauges for the whole local mesh
-            # (the single-device sample above covers only device 0)
-            monitor.sample_device_gauges(
-                [d for d in self._mesh.devices.flat
-                 if d.process_index == jax.process_index()])
-        # guardian hook LAST (after telemetry); one module-global read
-        # when no guardian is installed
-        g = guardian.active()
-        if g is not None:
-            g.note_step("parallel_executor", step_idx, ok=ok_flag,
-                        fetch_names=compiled.fetch_names, fetches=fetches,
-                        feed=(feed_names, user_feed_vals),
-                        sync=return_numpy)
-        elif ok_flag is not None:
-            guardian.warn_unobserved_skip_guard(self)
-        return fetches
-
-    def sync(self):
-        """Retire every in-flight async-dispatched step (see
-        ``Executor.sync``)."""
-        self._dispatch_queue.drain()
+            feed = {k: np.concatenate([np.asarray(d[k]) for d in feed],
+                                      axis=0) for k in feed[0]}
+        return self._run_step(
+            self._program or default_main_program(), self._actual_scope(),
+            feed, fetch_list, return_numpy)
 
     def state_shardings(self, program=None, scope=None):
         """``{name: NamedSharding}`` for every persistable var of
@@ -733,37 +469,27 @@ class ParallelExecutor:
         — the ``shardings=`` argument for TrainState/orbax restores, so
         a checkpoint written on any topology lands directly sharded on
         this one instead of replicating through host memory first."""
-        from jax.sharding import NamedSharding as NS
-
-        from ..framework import default_main_program
         from .checkpoint import _persistable_state
 
         program = program if program is not None else (
             self._program or default_main_program())
         scope = scope if scope is not None else self._actual_scope()
-        state = _persistable_state(scope, program)
-        layout = self._sharding_layout()
-        rule_specs = {}
-        if layout is not None:
-            rule_specs = layout.resolve(
-                program, self._mesh,
-                [(n, tuple(getattr(v, "shape", ())))
-                 for n, v in state.items()])
-        return {n: NS(self._mesh, self._state_spec(n, v, rule_specs))
-                for n, v in state.items()}
+        specs = self._state_specs(program, _persistable_state(scope, program))
+        return {n: NamedSharding(self._mesh, spec)
+                for n, spec in specs.items()}
 
     def state_dict(self):
         """Exact-resume host state (see ``Executor.state_dict``): the
         PRNG fold-in counter plus the once-per-executor auto seed for
         seedless programs (drawn at first run, broadcast across hosts —
         restoring it keeps the resumed random stream identical)."""
-        st = {"run_counter": int(self._run_counter)}
+        st = super().state_dict()
         if self._auto_seed_val is not None:
             st["auto_seed"] = int(self._auto_seed_val)
         return st
 
     def load_state_dict(self, state):
-        self._run_counter = int(state["run_counter"])
+        super().load_state_dict(state)
         if state.get("auto_seed") is not None:
             self._auto_seed_val = np.uint32(state["auto_seed"])
 

@@ -717,7 +717,7 @@ def test_packed_body_cancels_the_head_split_and_merge_copies(monkeypatch):
             scope = fluid.global_scope()
             vals = [ex._coerce_feed(main.global_block(), n, feed[n])
                     for n in names]
-            state, writeback = exe._analyze(main, names, scope, [cost.name])
+            state, writeback = ex.analyze(main, names, scope, [cost.name])
             fn, _, _ = ex.trace_program(main, names, state, writeback,
                                         [cost.name], platform="cpu")
             text = jax.jit(fn).lower(

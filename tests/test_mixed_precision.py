@@ -139,7 +139,7 @@ def test_amp_transformer_hlo_emits_bf16_dots(fresh_programs):
 
     import jax
 
-    from paddle_tpu.executor import trace_program
+    from paddle_tpu.executor import analyze, trace_program
     from paddle_tpu.models import transformer as tfm
 
     src = fluid.layers.data("src_word", shape=[1], dtype="int64",
@@ -166,7 +166,7 @@ def test_amp_transformer_hlo_emits_bf16_dots(fresh_programs):
             "tgt_word": ids, "tgt_word@LEN": lens,
             "lbl_word": ids, "lbl_word@LEN": lens}
     feed_names = sorted(feed)
-    state_names, writeback = exe._analyze(prog, feed_names, scope)
+    state_names, writeback = analyze(prog, feed_names, scope)
     fn, state_in, _ = trace_program(prog, feed_names, state_names,
                                     writeback, [cost.name])
     txt = jax.jit(fn).lower([feed[n] for n in feed_names],

@@ -66,19 +66,24 @@ def test_packed_kernel_compiles_for_v5e_at_the_cell_shape(one_chip, tk,
     assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
+@pytest.mark.parametrize("hk", [4, 32])
 def test_streamed_kernel_compiles_for_v5e_at_the_long_document_shape(
-        one_chip):
+        one_chip, hk):
     """The streamed kernel at ``keye_vl2_30b_a3b.train_longdoc_8k``'s shape
     — 32 query heads over 4 key/value heads of 128, T = 8192, bf16, a
     selection — forward, dQ and dK/dV: Mosaic has to accept the bit-plane
-    unpacking of the packed key mask, the clamped block index maps and
-    the VMEM the 512 x 512 blocks take."""
+    unpacking of the packed key mask, the clamped block index maps, the
+    rolled loop over the eight heads a grid step serves and the VMEM their
+    ``[8, 512, 128]`` blocks, padded columns and scratch take (more than
+    the default scoped limit: the kernels state their own).  And the same
+    over 32 key/value heads: plain heads, a loop of one."""
     from paddle_tpu.ops import sparse_select as ss
     from paddle_tpu.ops.pallas import streamed_attention as sa
 
-    b, h, hk, t, d = 1, 32, 4, 8192, 128
+    b, h, t, d = 1, 32, 8192, 128
     assert sa.supported((b, h, t, d), (b, hk, t, d), jnp.bfloat16, True,
                         False, 0.0)
+    assert sa._heads_per_step(h // hk, 512, 512, d, 2) == h // hk
 
     def step(q, k, v, sel, ct):
         out, vjp = jax.vjp(

@@ -330,22 +330,58 @@ def test_selection_of_more_keys_than_the_row_holds_is_plain_causal():
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.parametrize("causal,selected", [(True, True), (True, False),
-                                             (False, False)])
-def test_streamed_kernel_interpreted_matches_the_xla_body(causal, selected):
-    """Four query blocks by four key blocks of 128 (T = 512), two query
-    heads a key/value head.  With a selection, keys 128..255 score so low
-    that from query 136 on none of them is selected: for the later query
-    blocks key block 1 holds no selected key at all, and must leave the
-    online softmax as it was."""
-    q, k, v = _qkv(t=512)
+def _low_scoring_keys(t, first, last, seed=11):
+    """Indexer scores ``[1, t, t]`` in which keys first..last-1 score so low
+    that no query with enough other candidates selects any of them."""
+    return jnp.asarray(_rand((1, t, t), seed)).at[:, :, first:last].set(
+        -100.0)
+
+
+# (query heads, key/value heads, T, D, causal, selection): the selection is
+# None, or (low-scoring keys' range, keys a query selects)
+_STREAMED_CASES = {
+    # T = 512 is ONE 512 x 512 block pair, two heads a key/value head
+    "one_block_selected": (4, 2, 512, 128, True, ((128, 256), 64)),
+    "one_block_causal": (4, 2, 512, 128, True, None),
+    "one_block_dense": (4, 2, 512, 128, False, None),
+    # T = 384 is three by three blocks of 128: pairs the diagonal crosses,
+    # pairs below it and pairs above it (skipped) all occur
+    "g8_selected": (8, 1, 384, 128, True, ((128, 256), 64)),
+    "g8_causal": (8, 1, 384, 128, True, None),
+    "g1_selected": (2, 2, 384, 128, True, ((128, 256), 64)),
+    "g1_causal": (2, 2, 384, 128, True, None),
+    "g4_dense": (8, 2, 384, 128, False, None),
+    "noncausal_selected": (4, 2, 384, 128, False, ((128, 256), 64)),
+    # from query 192 on no key of the FIRST key block is selected: those
+    # rows pass it with no key yet, for every head of the loop in turn
+    "first_key_block_empty": (8, 2, 384, 128, True, ((0, 128), 64)),
+    # 16 heads a key/value head at D = 256 in float32 are more than the
+    # VMEM budget takes a step: 8 a step, two steps a block pair; T = 1024
+    # is two by two blocks of 512; T = 768 three by three of 256
+    "g16_split_selected": (16, 1, 1024, 256, True, ((512, 640), 256)),
+    "blocks_of_256": (4, 1, 768, 128, True, ((256, 512), 128)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_STREAMED_CASES))
+def test_streamed_kernel_interpreted_matches_the_xla_body(case):
+    """Forward and the three gradients against ``reference_attention``.
+    With a selection, a range of keys scores so low that queries with
+    enough other candidates select none of them: whole key blocks then hold
+    no selected key for the later query blocks, and must leave the online
+    softmax as it was."""
+    h, hk, t, d, causal, selection = _STREAMED_CASES[case]
+    q, k, v = _qkv(h=h, hk=hk, t=t, d=d)
     packed = None
-    if selected:
-        scores = jnp.asarray(_rand((1, 512, 512), 11)).at[:, :, 128:256].set(
-            -100.0)
-        sel = ss.topk_key_mask(scores, 64)
-        assert not bool(sel[0, 256:, 128:256].any())
+    if selection is not None:
+        (first, last), keep = selection
+        sel = ss.topk_key_mask(_low_scoring_keys(t, first, last), keep,
+                               causal)
+        assert not bool(sel[0, last + keep:, first:last].any())
         packed = ss.pack_key_mask(sel)
+    g, block = h // hk, sa._pick_blocks(t)
+    heads = sa._heads_per_step(g, block, block, d, 4)
+    assert heads == (8 if case == "g16_split_selected" else g)
 
     def xla(q, k, v):
         return fa.reference_attention(q, k, v, None, None, causal, 0.0, None,
@@ -360,6 +396,25 @@ def test_streamed_kernel_interpreted_matches_the_xla_body(causal, selected):
     got = jax.grad(lambda *a: jnp.sum(kernel(*a) * ct), (0, 1, 2))(q, k, v)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("g,block,d,itemsize", [
+    (8, 512, 128, 2),                # the long-document cell: all 8 a step
+    (1, 512, 128, 2),                # plain heads: a loop of one
+    (16, 512, 256, 4), (6, 512, 256, 4), (11, 512, 256, 4), (64, 512, 128, 2),
+    (32, 128, 128, 2),
+])
+def test_heads_per_step_is_a_divisor_inside_the_vmem_budget(g, block, d,
+                                                            itemsize):
+    heads = sa._heads_per_step(g, block, block, d, itemsize)
+    assert 1 <= heads <= g and g % heads == 0
+    fits = [n for n in range(1, g + 1) if g % n == 0 and sa._step_bytes(
+        n, block, block, d, itemsize) <= sa._VMEM_BUDGET]
+    assert fits and heads == max(fits)
+    if (g, block, d, itemsize) == (8, 512, 128, 2):
+        assert heads == 8
+    if (g, d, itemsize) == (11, 256, 4):
+        assert heads == 1            # 11 do not fit, and 11 is prime
 
 
 def test_streamed_supported_says_what_it_takes():

@@ -438,29 +438,36 @@ def phase_kernels():
     packed("packed_attention_causal", SEQ, True)
     packed("packed_attention_cross", 2 * SEQ, False)
 
-    def streamed(name, h, hk, t, d, keep):
+    def streamed(name, h, hk, t, d, keep, dv=None):
         """The long-document kernels (K/V streamed by blocks, a key/value
-        head's query group a grid step, the packed selection) at a causal
-        shape of several block pairs, bf16: forward, dQ, dK and dV against
-        the XLA body over the same selection."""
+        head's query group a grid step, the packed selection when ``keep``
+        keys a query are selected, values ``dv`` wide) at a causal shape of
+        several block pairs, bf16: forward, dQ, dK and dV against the XLA
+        body over the same selection."""
+        dv = d if dv is None else dv
         if not sa.supported((1, h, t, d), (1, hk, t, d), jnp.bfloat16, True,
-                            False, 0.0):
+                            False, 0.0, dv):
             raise AssertionError("streamed_attention.supported rejects "
                                  + name)
-        words = sparse_select.pack_key_mask(sparse_select.topk_key_mask(
-            normal(8, (1, t, t), jnp.float32), keep))
+        words = None if keep is None else sparse_select.pack_key_mask(
+            sparse_select.topk_key_mask(normal(8, (1, t, t), jnp.float32),
+                                        keep))
         out[name] = check_kernel(
             name,
             lambda q, k, v: sa.streamed_attention(q, k, v, words, True,
                                                   None, False),
             lambda q, k, v: fa.reference_attention(q, k, v, None, None, True,
                                                    0.0, None, words),
-            [normal(i, (1, n, t, d), jnp.bfloat16)
-             for i, n in enumerate((h, hk, hk))], 3, TOL_KERNEL["matmul"])
+            [normal(i, (1, n, t, w), jnp.bfloat16)
+             for i, (n, w) in enumerate(((h, d), (hk, d), (hk, dv)))], 3,
+            TOL_KERNEL["matmul"])
 
     # eight query heads a key/value head (one grid step serves all eight),
     # four by four blocks of 512, a quarter of the keys selected
     streamed("streamed_attention_grouped", 16, 2, 2048, 128, 512)
+    # latent attention as training computes it: plain heads, 192-wide keys
+    # over 128-wide values, no selection
+    streamed("streamed_attention_latent", 4, 4, 2048, 192, None, 128)
 
     rows, d_model = BATCH * SEQ, WIDTH["d_model"]
     gamma = jnp.linspace(0.5, 1.5, d_model, dtype=jnp.float32)

@@ -238,10 +238,14 @@ def fused_attention(q, k, v, k_len=None, causal=False, dropout_rate=0.0,
     """Flash attention over head-split tensors q/k/v [B, H, T, D].
 
     k and v may carry fewer heads than q (grouped-query attention: H a
-    whole multiple of theirs).  ``selected`` is ``select_keys``'s packed
-    mask of the keys each query may read, beside ``k_len`` and ``causal``;
-    with either, a TPU trace takes the kernel that streams K/V by blocks
-    (``ops/pallas/streamed_attention.py``) and the CPU the XLA body.
+    whole multiple of theirs), and v another width than q and k (``[B, H,
+    T, Dv]``: the result is ``[B, H, T, Dv]``).  ``selected`` is
+    ``select_keys``'s packed mask of the keys each query may read, beside
+    ``k_len`` and ``causal``.  With any of the three, or for plain-head
+    self-attention too long for the resident-K/V kernel
+    (``ops.attention.streams_plain_heads``), a TPU trace takes the kernel
+    that streams K/V by blocks (``ops/pallas/streamed_attention.py``) and
+    the CPU the XLA body.
 
     ``k_len`` [B] int masks padded key positions; ``causal`` adds the
     autoregressive mask.  One op, identical semantics in every body; the
@@ -265,9 +269,15 @@ def fused_attention(q, k, v, k_len=None, causal=False, dropout_rate=0.0,
     if scale is not None:
         attrs["scale"] = float(scale)
     outputs = {"Out": [out]}
-    if selected is not None or k.shape[1] != q.shape[1]:
-        # the bodies of grouped-query / selected-key attention keep the
-        # rows' log-sum-exp for their gradient op
+    from ..ops.attention import streams_plain_heads
+    marked = selected is None and k.shape[1] == q.shape[1] \
+        and streams_plain_heads(q.shape, k.shape, v.shape, k_len is not None,
+                                float(dropout_rate))
+    if marked:
+        attrs["keep_lse"] = True
+    if marked or selected is not None or k.shape[1] != q.shape[1]:
+        # the bodies of grouped-query / selected-key / long plain-head
+        # attention keep the rows' log-sum-exp for their gradient op
         lse = helper.create_variable_for_type_inference(dtype="float32")
         lse.stop_gradient = True
         outputs["LSE"] = [lse]
@@ -295,14 +305,19 @@ def rms_norm(x, epsilon=1e-6, param_attr=None, name=None):
     return out
 
 
-def rotary_embedding(x, theta=10000.0, name=None):
+def rotary_embedding(x, theta=10000.0, interleaved=False, name=None):
     """Rotary position embedding of ``x`` ``[B, T, ..., D]``: position =
-    index along axis 1, all D dimensions rotated, rotate-half convention
-    (dimension i pairs with i + D/2), frequencies ``theta^(-2i/D)``."""
+    index along axis 1, all D dimensions rotated, frequencies
+    ``theta^(-2i/D)``; rotate-half convention (dimension i pairs with i +
+    D/2) or, ``interleaved``, neighbouring pairs (2i, 2i + 1).  A model
+    that rotates part of a head slices that part out and joins it back."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_variable_for_type_inference(dtype=x.dtype)
+    attrs = {"theta": float(theta)}
+    if interleaved:
+        attrs["interleaved"] = True
     helper.append_op(type="rotary_embedding", inputs={"X": [x]},
-                     outputs={"Out": [out]}, attrs={"theta": float(theta)})
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
@@ -355,12 +370,21 @@ def select_keys(index_q, index_k, index_w, top_k, scale=1.0, causal=True,
 
 def routed_experts(x, num_experts, top_k, expert_width, held=None, first=0,
                    tile=256, router_attr=None, gate_attr=None, up_attr=None,
-                   down_attr=None, name=None):
+                   down_attr=None, score_func="softmax", bias_attr=None,
+                   weight_scale=1.0, shared_width=None, shared_attrs=None,
+                   name=None):
     """A layer of routed SiLU-gated experts, or one chip's share of it.
 
     ``x`` [N, D] tokens.  The router (``[D, num_experts]``, float32 product
-    and softmax) picks ``top_k`` experts a token and renormalises their
-    probabilities over the ``top_k``.  This program holds ``held`` experts,
+    and scores: ``score_func`` ``softmax`` or ``sigmoid``) picks ``top_k``
+    experts a token and renormalises their scores over the ``top_k``, times
+    ``weight_scale``.  With ``bias_attr`` it picks on the scores plus a
+    ``[num_experts]`` correction bias (zeros at start, never trained by the
+    loss: no gradient, no optimizer state) and weighs by the scores alone.
+    ``shared_width`` adds a shared expert of that width that EVERY token
+    takes with weight 1 — ``(silu(x Ws_g) * (x Ws_u)) Ws_d``, plain ``mul``
+    and ``swiglu`` ops under ``shared_attrs`` (gate, up, down); a layer cut
+    into shares has it in one of them.  This program holds ``held`` experts,
     numbers ``first .. first + held - 1`` (all of them by default), as
     stacked parameters ``[held, D, expert_width]`` (gate, up) and ``[held,
     expert_width, D]`` (down), and returns
@@ -380,6 +404,20 @@ def routed_experts(x, num_experts, top_k, expert_width, held=None, first=0,
         return helper.create_parameter(attr=attr, shape=shape,
                                        dtype="float32")
     router = param(router_attr, [d, num_experts])
+    router_in = {"X": [x], "W": [router]}
+    router_attrs = {"top_k": int(top_k)}
+    if score_func != "softmax":
+        router_attrs["score_func"] = score_func
+    if float(weight_scale) != 1.0:
+        router_attrs["scale"] = float(weight_scale)
+    if bias_attr is not None:
+        from ..initializer import ConstantInitializer
+        bias_attr.trainable = False
+        bias = helper.create_parameter(
+            attr=bias_attr, shape=[num_experts], dtype="float32",
+            default_initializer=ConstantInitializer(0.0))
+        bias.stop_gradient = True
+        router_in["Bias"] = [bias]
     gate = param(gate_attr, [held, d, expert_width])
     up = param(up_attr, [held, d, expert_width])
     down = param(down_attr, [held, expert_width, d])
@@ -387,9 +425,9 @@ def routed_experts(x, num_experts, top_k, expert_width, held=None, first=0,
     def tmp(dtype):
         return helper.create_variable_for_type_inference(dtype=dtype)
     idx, weight = tmp("int32"), tmp("float32")
-    helper.append_op(type="moe_router", inputs={"X": [x], "W": [router]},
+    helper.append_op(type="moe_router", inputs=router_in,
                      outputs={"TopkIdx": [idx], "TopkWeight": [weight]},
-                     attrs={"top_k": int(top_k)})
+                     attrs=router_attrs)
     idx.stop_gradient = True
     layout = {s: [tmp("int32")] for s in
               ("RowToken", "RowSlot", "TileExpert", "NumTiles", "Counts")}
@@ -408,6 +446,14 @@ def routed_experts(x, num_experts, top_k, expert_width, held=None, first=0,
     helper.append_op(type="moe_expert_ffn", inputs=inputs,
                      outputs={"Out": [out], "Pairs": [pairs]},
                      attrs={"tile": int(tile)})
+    if shared_width is not None:
+        g_attr, u_attr, d_attr = shared_attrs or (None, None, None)
+
+        def proj(v, size, attr):
+            return fc(v, size=size, bias_attr=False, param_attr=attr)
+        out = elementwise_add(out, proj(
+            swiglu(proj(x, shared_width, g_attr),
+                   proj(x, shared_width, u_attr)), d, d_attr))
     return out, counts, pairs
 
 

@@ -1,10 +1,12 @@
-"""A decoder-only language model of pre-norm blocks with grouped-query
-attention over learned-selected keys and routed SiLU-gated experts,
-parameterised by its sizes — the block of today's sparse-attention
-mixture-of-experts models, built from ``layers`` functions into a Fluid
-``Program``.
+"""Decoder-only language models of pre-norm blocks with routed SiLU-gated
+experts, parameterised by their sizes and built from ``layers`` functions
+into a Fluid ``Program``: one family, two kinds of block.  What the kinds
+share exists once here — the routed-expert half of a block
+(``_expert_half``), the head and its loss (``_head_loss``) and the step's
+counters (``_declare_step_stats``).
 
-One block, for ``x`` [B, T, D]:
+**Selected-key blocks** (``decoder_block`` / ``decoder_lm``), for ``x``
+[B, T, D]:
 
 1. ``h = rms_norm(x)``; ``q = h Wq`` -> ``n_head`` heads of ``head_dim``,
    ``k = h Wk``, ``v = h Wv`` -> ``n_kv_head`` heads; no biases.  ``q`` and
@@ -16,26 +18,58 @@ One block, for ``x`` [B, T, D]:
    WIw``; rotary on ``qI``, ``kI``; ``layers.select_keys`` keeps the
    ``index_topk`` best causal keys a query.
 3. ``x += concat_h(attention over the selected keys) Wo``.
-4. ``h2 = rms_norm(x)``; ``x += routed_experts(h2)`` — the router over all
-   ``num_experts``, of which this program holds the share
-   ``(held, total, first)``: ``held`` experts, numbers ``first .. first +
-   held - 1``, of ``total``.
+4. ``h2 = rms_norm(x)``; ``x += routed_experts(h2)`` — the router
+   (softmax) over all ``num_experts``, of which this program holds the
+   share ``(held, total, first)``: ``held`` experts, numbers ``first ..
+   first + held - 1``, of ``total``.
+
+**Latent-attention blocks** (``latent_block`` / ``latent_decoder_lm``; the
+sizes are a ``LatentSizes``):
+
+1. ``h = rms_norm(x)``; queries through a low-rank path, ``cq =
+   rms_norm(h Wqa)``, ``q = cq Wqb`` -> ``n_head`` heads of ``[nope |
+   rope]``; keys and values from one latent, ``[ckv | kr] = h Wkva``,
+   ``ckv = rms_norm(ckv)``, ``[k_nope_i | v_i] = ckv Wkvb`` a head.  Rotary
+   (interleaved pairs) on each head's ``rope`` part of the query and on
+   ``kr``, ONE rotary key all heads share; ``k_i = [k_nope_i | rope(kr)]``.
+   Causal attention with keys ``nope + rope`` wide over values ``v_dim``
+   wide; ``x += concat_i(o_i) Wo``.
+2. ``h2 = rms_norm(x)``; the first ``n_dense`` layers: ``x += (silu(h2 Wg)
+   * (h2 Wu)) Wd`` of ``dense_width``; the others: ``x +=
+   routed_experts(h2)`` with a sigmoid router that selects on ``score +
+   bias`` (the bias is never trained by the loss), weighs by the scores
+   renormalised over the ``k`` times ``route_scale``, plus a shared expert
+   every token takes.
+3. A **multi-token-prediction module**: ``h' = [rms_norm(Emb(tok[t+1])) |
+   rms_norm(x_last[t])] Weh``, one more expert block, a final RMSNorm of
+   its own, the SAME embedding and head, cross entropy against
+   ``tok[t+2]``; the step minimises ``L_main + mtp_weight * L_mtp``, so the
+   shared tables' gradients are the two uses' sum.
 
 After the last block a final RMSNorm, an untied head over ``vocab_size``
 rows and the mean next-token cross entropy in float32.
 """
 
+import collections
+
 from .. import layers
 from ..initializer import NormalInitializer
 from ..param_attr import ParamAttr
 
-__all__ = ["decoder_block", "decoder_lm"]
+__all__ = ["decoder_block", "decoder_lm", "LatentSizes", "latent_block",
+           "latent_decoder_lm"]
 
 _INIT_STD = 0.02
 # the fields of ``decoder_lm``'s step counters, as the executor names them in
 # a StepStats record when the caller fetches them to the host
 STEP_STATS = ("moe_pairs_routed", "moe_pairs_computed",
               "moe_max_expert_tokens", "selected_key_share")
+# ... and of ``latent_decoder_lm``'s
+LATENT_STEP_STATS = STEP_STATS[:3] + ("mtp_loss",)
+
+# the sizes of a latent-attention block's attention half
+LatentSizes = collections.namedtuple(
+    "LatentSizes", "n_head q_rank kv_rank nope_dim rope_dim v_dim")
 
 
 def _attr(name, trainable=True):
@@ -46,6 +80,61 @@ def _attr(name, trainable=True):
 def _proj(x, size, name, trainable=True):
     return layers.fc(x, size=size, num_flatten_dims=2, bias_attr=False,
                      param_attr=_attr(name, trainable))
+
+
+def _expert_half(x, prefix, expert_share, expert_width, experts_per_token,
+                 rms_eps, expert_tile, **router):
+    """The routed-expert half of a block of either kind: ``x +=
+    routed_experts(rms_norm(x))`` over the share ``(held, total, first)``;
+    ``router`` is what ``layers.routed_experts`` takes beyond the softmax
+    default (score function, bias, scale, shared expert).  Returns ``(x,
+    counters)``: ``pairs_routed``, ``pairs_computed``,
+    ``max_expert_tokens`` as [1] float32 variables."""
+    held, total, first = expert_share
+    d = x.shape[-1]
+    h2 = layers.rms_norm(x, rms_eps, ParamAttr(name=prefix + "ln2.g"))
+    y, counts, pairs = layers.routed_experts(
+        layers.reshape(h2, shape=[-1, d]), total, experts_per_token,
+        expert_width, held=held, first=first, tile=expert_tile,
+        router_attr=_attr(prefix + "moe.router"),
+        gate_attr=_attr(prefix + "moe.gate"),
+        up_attr=_attr(prefix + "moe.up"),
+        down_attr=_attr(prefix + "moe.down"), **router)
+    x = layers.elementwise_add(x, layers.reshape(y, shape=[-1] + list(
+        x.shape[1:])))
+    counts = layers.cast(counts, "float32")
+    return x, {"pairs_routed": layers.reduce_sum(counts, keep_dim=True),
+               "pairs_computed": pairs,
+               "max_expert_tokens": layers.reduce_max(counts, keep_dim=True)}
+
+
+def _head_loss(x, labels, vocab_size, rms_eps, norm_name):
+    """Final RMSNorm (``norm_name``), the untied head ``out_w`` (one
+    parameter, whoever calls) and the mean cross entropy in float32."""
+    x = layers.rms_norm(x, rms_eps, ParamAttr(name=norm_name))
+    logits = _proj(x, vocab_size, "out_w")
+    return layers.mean(layers.softmax_with_cross_entropy(logits, labels))
+
+
+def _over_layers(stats, key, reduce):
+    return reduce(layers.concat([st[key] for st in stats], axis=0),
+                  keep_dim=True)
+
+
+def _declare_step_stats(loss, stats, last, names):
+    """The step's counters as ONE float32 variable a caller fetches with
+    the loss: pairs routed and computed (the layers' sums), the fullest
+    held expert's tokens (the layers' largest) and what ``last()`` builds
+    after them; declared on
+    the program under ``names`` (``Program.step_stats``)."""
+    step_stats = layers.concat([
+        _over_layers(stats, "pairs_routed", layers.reduce_sum),
+        _over_layers(stats, "pairs_computed", layers.reduce_sum),
+        _over_layers(stats, "max_expert_tokens", layers.reduce_max),
+        last()], axis=0)
+    step_stats.stop_gradient = True
+    loss.block.program.step_stats = (step_stats.name, names)
+    return step_stats
 
 
 def decoder_block(x, prefix, n_head, n_kv_head, head_dim, expert_share,
@@ -59,7 +148,6 @@ def decoder_block(x, prefix, n_head, n_kv_head, head_dim, expert_share,
     fullest held expert's tokens) and ``selected_share`` (share of the
     causal pairs the indexer selected), and ``selected``, the packed key
     mask itself."""
-    held, total, first = expert_share
     d = x.shape[-1]
 
     def heads(v, n, width):
@@ -100,21 +188,10 @@ def decoder_block(x, prefix, n_head, n_kv_head, head_dim, expert_share,
     ctx = layers.reshape(to_bhtd(ctx), shape=[0, 0, n_head * head_dim])
     x = layers.elementwise_add(x, _proj(ctx, d, prefix + "attn.o"))
 
-    h2 = layers.rms_norm(x, rms_eps, ParamAttr(name=prefix + "ln2.g"))
-    y, counts, pairs = layers.routed_experts(
-        layers.reshape(h2, shape=[-1, d]), total, experts_per_token,
-        expert_width, held=held, first=first, tile=expert_tile,
-        router_attr=_attr(prefix + "moe.router"),
-        gate_attr=_attr(prefix + "moe.gate"),
-        up_attr=_attr(prefix + "moe.up"),
-        down_attr=_attr(prefix + "moe.down"))
-    x = layers.elementwise_add(x, layers.reshape(y, shape=[-1] + list(
-        x.shape[1:])))
-    counts = layers.cast(counts, "float32")
-    return x, {"pairs_routed": layers.reduce_sum(counts, keep_dim=True),
-               "pairs_computed": pairs,
-               "max_expert_tokens": layers.reduce_max(counts, keep_dim=True),
-               "selected_share": share, "selected": selected}
+    x, stats = _expert_half(x, prefix, expert_share, expert_width,
+                            experts_per_token, rms_eps, expert_tile)
+    stats.update(selected_share=share, selected=selected)
+    return x, stats
 
 
 def decoder_lm(tokens, labels, vocab_size, n_layer, d_model, n_head,
@@ -140,18 +217,122 @@ def decoder_lm(tokens, labels, vocab_size, n_layer, d_model, n_head,
             expert_width, experts_per_token, index_heads, index_dim,
             index_topk, rope_theta, rms_eps, expert_tile)
         stats.append(st)
-    x = layers.rms_norm(x, rms_eps, ParamAttr(name="ln_f.g"))
-    logits = _proj(x, vocab_size, "out_w")
-    loss = layers.mean(layers.softmax_with_cross_entropy(logits, labels))
-
-    def over_layers(key, reduce):
-        return reduce(layers.concat([st[key] for st in stats], axis=0),
-                      keep_dim=True)
-    step_stats = layers.concat([
-        over_layers("pairs_routed", layers.reduce_sum),
-        over_layers("pairs_computed", layers.reduce_sum),
-        over_layers("max_expert_tokens", layers.reduce_max),
-        over_layers("selected_share", layers.reduce_mean)], axis=0)
-    step_stats.stop_gradient = True
-    loss.block.program.step_stats = (step_stats.name, STEP_STATS)
+    loss = _head_loss(x, labels, vocab_size, rms_eps, "ln_f.g")
+    step_stats = _declare_step_stats(
+        loss, stats, lambda: _over_layers(stats, "selected_share",
+                                          layers.reduce_mean), STEP_STATS)
     return loss, step_stats, stats[0]["selected"]
+
+
+def _dense_half(x, prefix, width, rms_eps):
+    """``x += (silu(h2 Wg) * (h2 Wu)) Wd`` over ``h2 = rms_norm(x)``."""
+    h2 = layers.rms_norm(x, rms_eps, ParamAttr(name=prefix + "ln2.g"))
+    y = _proj(layers.swiglu(_proj(h2, width, prefix + "mlp.gate"),
+                            _proj(h2, width, prefix + "mlp.up")),
+              x.shape[-1], prefix + "mlp.down")
+    return layers.elementwise_add(x, y)
+
+
+def _latent_attention(x, prefix, sizes, rope_theta, rms_eps):
+    """``x += latent attention(rms_norm(x))``: see the module's text."""
+    n, nope, rope, dv = (sizes.n_head, sizes.nope_dim, sizes.rope_dim,
+                         sizes.v_dim)
+
+    def norm(v, name):
+        return layers.rms_norm(v, rms_eps, ParamAttr(name=prefix + name))
+
+    def rotate(v):
+        return layers.rotary_embedding(v, theta=rope_theta, interleaved=True)
+
+    def to_bhtd(t):
+        return layers.transpose(t, perm=[0, 2, 1, 3])
+    h = norm(x, "ln1.g")
+    cq = norm(_proj(h, sizes.q_rank, prefix + "attn.q_a"), "attn.q_a_g")
+    q_nope, q_rope = layers.split(layers.reshape(
+        _proj(cq, n * (nope + rope), prefix + "attn.q_b"),
+        shape=[0, 0, n, nope + rope]), [nope, rope], dim=-1)
+    ckv, kr = layers.split(_proj(h, sizes.kv_rank + rope,
+                                 prefix + "attn.kv_a"),
+                           [sizes.kv_rank, rope], dim=-1)
+    k_nope, v = layers.split(layers.reshape(
+        _proj(norm(ckv, "attn.kv_a_g"), n * (nope + dv),
+              prefix + "attn.kv_b"),
+        shape=[0, 0, n, nope + dv]), [nope, dv], dim=-1)
+    # ONE rotary key for all the heads, joined onto each head's own part
+    kr = layers.expand(rotate(layers.reshape(kr, shape=[0, 0, 1, rope])),
+                       [1, 1, n, 1])
+    q = layers.concat([q_nope, rotate(q_rope)], axis=3)
+    k = layers.concat([k_nope, kr], axis=3)
+    ctx = layers.fused_attention(to_bhtd(q), to_bhtd(k), to_bhtd(v),
+                                 causal=True, scale=(nope + rope) ** -0.5)
+    ctx = layers.reshape(to_bhtd(ctx), shape=[0, 0, n * dv])
+    return layers.elementwise_add(x, _proj(ctx, x.shape[-1],
+                                           prefix + "attn.o"))
+
+
+def latent_block(x, prefix, sizes, rope_theta, rms_eps, dense_width=None,
+                 experts=None):
+    """One latent-attention block over ``x`` [B, T, D]: the attention half
+    and a dense gated FFN of ``dense_width``, or the routed-expert half
+    with ``experts`` — ``_expert_half``'s arguments after the prefix, as a
+    dict.  Returns ``(x, counters or None)``."""
+    x = _latent_attention(x, prefix, sizes, rope_theta, rms_eps)
+    if experts is None:
+        return _dense_half(x, prefix, dense_width, rms_eps), None
+    return _expert_half(x, prefix, rms_eps=rms_eps, **experts)
+
+
+def latent_decoder_lm(tokens, labels, labels2, vocab_size, n_layer, n_dense,
+                      d_model, sizes, dense_width, expert_share,
+                      expert_width, experts_per_token, shared_width,
+                      route_scale=1.0, mtp_weight=0.3, rope_theta=1e4,
+                      rms_eps=1e-6, expert_tile=256):
+    """The training graph over ``tokens`` / ``labels`` (the next token) /
+    ``labels2`` (the next but one) [B, T, 1] int64, every position real:
+    ``n_dense`` dense and ``n_layer - n_dense`` expert blocks, and the
+    multi-token-prediction module.  Returns ``(loss, stats)``: ``L_main +
+    mtp_weight * L_mtp`` and a [4] float32 variable a caller fetches WITH
+    the loss — pairs routed to the held experts and pairs computed (summed
+    over the expert layers, the module's too), the fullest held expert's
+    tokens, and ``L_mtp`` — under ``LATENT_STEP_STATS``' names
+    (``Program.step_stats``)."""
+    def experts(prefix):
+        return dict(
+            expert_share=expert_share, expert_width=expert_width,
+            experts_per_token=experts_per_token, expert_tile=expert_tile,
+            score_func="sigmoid", weight_scale=route_scale,
+            bias_attr=ParamAttr(name=prefix + "moe.bias"),
+            shared_width=shared_width,
+            shared_attrs=tuple(_attr(prefix + "moe.shared." + m)
+                               for m in ("gate", "up", "down")))
+
+    def embed(ids):
+        return layers.embedding(ids, size=[vocab_size, d_model],
+                                param_attr=_attr("tok_emb"))
+    x = embed(tokens)
+    stats = []
+    for i in range(n_layer):
+        prefix = "l%d." % i
+        x, st = latent_block(
+            x, prefix, sizes, rope_theta, rms_eps, dense_width,
+            None if i < n_dense else experts(prefix))
+        if st is not None:
+            stats.append(st)
+    loss_main = _head_loss(x, labels, vocab_size, rms_eps, "ln_f.g")
+
+    # the module: the next token's embedding beside the trunk's last
+    # hidden state (before its final norm), one more expert block
+    joined = layers.concat([
+        layers.rms_norm(embed(labels), rms_eps,
+                        ParamAttr(name="mtp.enorm.g")),
+        layers.rms_norm(x, rms_eps, ParamAttr(name="mtp.hnorm.g"))], axis=2)
+    y, st = latent_block(_proj(joined, d_model, "mtp.eh_proj"), "mtp.",
+                         sizes, rope_theta, rms_eps,
+                         experts=experts("mtp."))
+    stats.append(st)
+    loss_mtp = _head_loss(y, labels2, vocab_size, rms_eps, "mtp.ln_f.g")
+    loss = layers.elementwise_add(loss_main,
+                                  layers.scale(loss_mtp, scale=mtp_weight))
+    return loss, _declare_step_stats(
+        loss, stats, lambda: layers.reshape(loss_mtp, shape=[1]),
+        LATENT_STEP_STATS)

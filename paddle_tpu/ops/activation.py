@@ -159,13 +159,22 @@ def rotary_tables(length, dim, theta):
 
 def _rotary_compute(ins, attrs, ctx, op_index):
     """Rotate ``X`` ``[B, T, ..., D]`` by its position along axis 1 over all
-    D dimensions (rotate-half), in float32; the output keeps X's dtype."""
+    D dimensions, in float32; the output keeps X's dtype.  Frequency i
+    turns the pair (x[i], x[i + D/2]) (rotate-half, the default) or, with
+    ``interleaved``, the neighbours (x[2i], x[2i + 1]), in place."""
     x = ins["X"][0]
     d = x.shape[-1]
     cos, sin = rotary_tables(x.shape[1], d, float(attrs.get("theta", 1e4)))
     shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (d,)
     xf = x.astype(jnp.float32)
-    half = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], -1)
+    if attrs.get("interleaved", False):
+        # the tables repeat each frequency as [f0..f(D/2-1), f0..]: the
+        # neighbours' order is [f0, f0, f1, f1, ..]
+        cos, sin = (jnp.repeat(t[:, :d // 2], 2, -1) for t in (cos, sin))
+        pair = xf.reshape(xf.shape[:-1] + (d // 2, 2))
+        half = jnp.stack([-pair[..., 1], pair[..., 0]], -1).reshape(xf.shape)
+    else:
+        half = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], -1)
     return {"Out": (xf * cos.reshape(shape)
                     + half * sin.reshape(shape)).astype(x.dtype)}
 

@@ -22,11 +22,13 @@ choice changes schedule, not math:
   data axes, whole heads over ``tp``).  No flag turns it on; a PINNED
   ``FLAGS_pallas_kernels=False`` ("no Pallas") turns it off.
 * **streamed** — on a TPU, self-attention with grouped-query heads (K/V of
-  ``H / g`` heads) or a ``Selected`` key set: the blockwise kernel that
-  streams K/V by blocks and applies the selection per block
-  (``ops/pallas/streamed_attention.py``); the [B, H, T, T] scores of such
-  a model's long rows do not fit HBM, so there is nothing to weigh it
-  against.
+  ``H / g`` heads), a ``Selected`` key set, values narrower than the keys,
+  or plain heads at a length whose K/V the resident kernel below cannot
+  hold (``streams_plain_heads``; the layer marks those ops ``keep_lse``):
+  the blockwise kernel that streams K/V by blocks and applies the
+  selection per block (``ops/pallas/streamed_attention.py``); the
+  [B, H, T, T] scores of such a model's long rows do not fit HBM, so there
+  is nothing to weigh it against.
 * **pallas** — the long-sequence blockwise kernel
   (``ops/pallas/flash_attention.py``) under ``FLAGS_pallas_kernels`` or a
   tuned per-shape ruling (``autotune.attention_choice``), never
@@ -43,7 +45,10 @@ attention, an optional ``Selected`` input: per query the set of keys it
 may read, as the packed bit mask of ``ops/sparse_select.py`` ([B, Tq, W]
 int32); an unselected key contributes exactly nothing, in every body.
 K and V may carry fewer heads than Q (grouped-query attention): query head
-``h`` reads K/V head ``h // (H / Hkv)``.  ``causal`` with
+``h`` reads K/V head ``h // (H / Hkv)``.  V may be narrower or wider than
+Q and K (``[B, Hkv, Tk, Dv]``; latent attention's 192-wide keys over
+128-wide values): ``Out`` is ``[B, H, Tq, Dv]``, and the bodies are the
+streamed kernel and the XLA one.  ``causal`` with
 ``Tq == Tk`` is aligned self-attention (query i sees keys <= i); with
 ``Tq < Tk`` the queries are the *suffix* of the valid keys — query i sits
 at global position ``klen - Tq + i`` — which is the single-token /
@@ -70,11 +75,15 @@ def _fused_attention_infer(op, block):
         raise ValueError(
             "fused_attention Q/K head dims disagree: %s vs %s"
             % (q.shape, k.shape))
-    if v.shape[2] != k.shape[2] or v.shape[3] != q.shape[3]:
+    if v.shape[2] != k.shape[2]:
         raise ValueError(
-            "fused_attention V must be [B, H, Tk, D] matching K's length "
-            "and Q's head dim: got Q %s, K %s, V %s"
-            % (q.shape, k.shape, v.shape))
+            "fused_attention V must be [B, H, Tk, Dv] matching K's length: "
+            "got Q %s, K %s, V %s" % (q.shape, k.shape, v.shape))
+    if v.shape[3] != q.shape[3] and not op.outputs.get("LSE"):
+        raise ValueError(
+            "fused_attention: values of another width than the keys (Q %s, "
+            "V %s) run on the bodies that keep the rows' log-sum-exp: "
+            "build the op with layers.fused_attention" % (q.shape, v.shape))
     if k.shape[1] != v.shape[1] or k.shape[1] < 1 \
             or q.shape[1] % k.shape[1]:
         raise ValueError(
@@ -96,9 +105,29 @@ def _fused_attention_infer(op, block):
         raise ValueError(
             "fused_attention: causal=True requires Tq <= Tk (got %d vs "
             "%d)" % (q.shape[2], k.shape[2]))
-    set_output(op, block, "Out", q.shape, q.dtype)
+    set_output(op, block, "Out", tuple(q.shape[:3]) + (v.shape[3],), q.dtype)
     if op.outputs.get("LSE"):
         set_output(op, block, "LSE", tuple(q.shape[:3]) + (1,), "float32")
+
+
+def streams_plain_heads(q_shape, k_shape, v_shape, has_klen, rate):
+    """Whether plain-head attention of these shapes belongs to the bodies
+    that keep the rows' log-sum-exp (streamed on a TPU): values of another
+    width than the keys, or self-attention the streamed kernel takes at a
+    length where the resident-K/V kernel's VMEM bound says no even in
+    bfloat16 (beyond T = 2048 at D = 128).  Shapes alone, so that the layer
+    can ask it when it builds the op (``keep_lse``) and the trace need
+    not."""
+    from .pallas import flash_attention as fa, streamed_attention as sa
+
+    if len(q_shape) != 4 or len(v_shape) != 4:
+        return False
+    if v_shape[3] != q_shape[3]:
+        return True
+    return sa.supported(q_shape, k_shape, jnp.bfloat16, True, has_klen,
+                        rate) \
+        and not fa.supported(q_shape, k_shape, jnp.bfloat16,
+                             max_seq=max(q_shape[2], k_shape[2]))
 
 
 def _attention_args(ins, attrs, ctx, op_index):
@@ -130,18 +159,27 @@ def _fused_attention_compute(ins, attrs, ctx, op_index):
     from .pallas import flash_attention as fa
     from ..compile_cache import note_kernel_body
 
-    if selected is not None or k.shape[1] != q.shape[1]:
-        # grouped heads or a selected key set: the streamed kernel on a
-        # TPU where it takes the call, the XLA body otherwise; either
-        # hands the gradient op its rows' log-sum-exp
+    if _keeps_lse(q, k, selected, attrs):
+        # grouped heads, a selected key set, or plain heads the layer
+        # marked (``streams_plain_heads``): the streamed kernel on a TPU
+        # where it takes the call, the XLA body otherwise; either hands the
+        # gradient op its rows' log-sum-exp
         from .pallas import interpret_mode
         from .pallas import streamed_attention as sa
 
         if _streamed_applicable(ctx, q.shape, k.shape, q.dtype, causal,
-                                k_len is not None, rate):
+                                k_len is not None, rate, v.shape[3]):
             note_kernel_body("fused_attention", "streamed")
             out, lse = sa.forward(q, k, v, selected, causal, scale,
                                   interpret_mode(ctx))
+        elif _plain(q, k, v, selected) \
+                and _ring_selected(ctx, q.shape, k.shape, causal):
+            # a sequence-parallel mesh keeps its ring, which keeps no
+            # log-sum-exp: its gradient differentiates the body
+            note_kernel_body("fused_attention", "ring")
+            out = _ring_attention(ctx.mesh, q, k, v, k_len, seed, causal,
+                                  rate, scale)
+            lse = jnp.zeros(q.shape[:3] + (1,), jnp.float32)
         else:
             note_kernel_body("fused_attention", "xla")
             out, lse = fa.reference_attention(
@@ -206,16 +244,17 @@ def _fused_attention_grad_compute(ins, attrs, ctx, op_index):
         ins, attrs, ctx, fwd_index)
     dout = (ins.get("GRAD::Out") or [None])[0]
     selected = (ins.get("Selected") or [None])[0]
-    if selected is not None or k.shape[1] != q.shape[1]:
+    if _keeps_lse(q, k, selected, attrs):
         # the streamed body: its two backward kernels from the forward's
         # own output and log-sum-exp (the generic rule would run the
-        # forward kernel a second time to get them); the XLA body
-        # differentiates itself
+        # forward kernel a second time to get them); the XLA body and the
+        # ring differentiate themselves
         out = (ins.get("Out::Out") or [None])[0]
         lse = (ins.get("Out::LSE") or [None])[0]
         if dout is None or out is None or lse is None \
                 or not _streamed_applicable(ctx, q.shape, k.shape, q.dtype,
-                                            causal, k_len is not None, rate):
+                                            causal, k_len is not None, rate,
+                                            v.shape[3]):
             return _generic_grad_compute(ins, attrs, ctx, op_index)
         from ..compile_cache import note_kernel_body
         from .pallas import interpret_mode
@@ -255,8 +294,21 @@ _PACKED_PLATFORMS = ("tpu",)
 _STREAMED_PLATFORMS = ("tpu",)
 
 
+def _keeps_lse(q, k, selected, attrs):
+    """Whether the op is one of those whose bodies keep the rows'
+    log-sum-exp for the gradient op (the layer gave it an ``LSE``
+    output)."""
+    return selected is not None or k.shape[1] != q.shape[1] \
+        or attrs.get("keep_lse", False)
+
+
+def _plain(q, k, v, selected):
+    return selected is None and k.shape[1] == q.shape[1] \
+        and v.shape[3] == q.shape[3]
+
+
 def _streamed_applicable(ctx, q_shape, k_shape, dtype, causal, has_klen,
-                         rate):
+                         rate, dv=None):
     """The streamed kernel's rule: a TPU trace on one device (it has no
     per-shard lowering yet), no pinned ``FLAGS_pallas_kernels=False``, and
     a call its ``supported()`` takes."""
@@ -264,7 +316,7 @@ def _streamed_applicable(ctx, q_shape, k_shape, dtype, causal, has_klen,
 
     return kernel_allowed(ctx, _STREAMED_PLATFORMS) \
         and getattr(ctx, "mesh", None) is None \
-        and sa.supported(q_shape, k_shape, dtype, causal, has_klen, rate)
+        and sa.supported(q_shape, k_shape, dtype, causal, has_klen, rate, dv)
 
 
 def _packed_axes(ctx, b, h, d):

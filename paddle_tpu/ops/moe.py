@@ -8,8 +8,12 @@ stands in for the other chips or for the exchange with them.
 
 Three ops:
 
-* ``moe_router`` — ``softmax(X W)`` over all experts in float32, the top
-  ``k`` a token and their probabilities renormalised over the ``k``.
+* ``moe_router`` — scores over all experts in float32, ``softmax(X W)``
+  (the default) or ``sigmoid(X W)``; the top ``k`` a token, chosen on the
+  scores plus an optional per-expert ``Bias`` that moves the SELECTION only
+  (bias-corrected, auxiliary-loss-free balancing: no gradient, no optimizer
+  state, set by a rule outside the loss); their weights are the UNBIASED
+  scores renormalised over the ``k``, times ``scale``.
 * ``moe_dispatch`` — from the routed ids alone, the layout of the work:
   token-expert pairs of held experts sorted by expert, every expert's group
   padded to whole tiles of ``tile`` rows, so that a tile belongs to ONE
@@ -40,22 +44,45 @@ def _router_infer(op, block):
     if len(x.shape) != 2 or len(w.shape) != 2 or x.shape[1] != w.shape[0]:
         raise ValueError("moe_router expects X [N, D] and W [D, E], got %s "
                          "/ %s" % (x.shape, w.shape))
+    if op.attrs.get("score_func", "softmax") not in _ROUTER_SCORES:
+        raise ValueError("moe_router: score_func is one of %s, got %r"
+                         % (sorted(_ROUTER_SCORES), op.attrs["score_func"]))
     k = int(op.attrs["top_k"])
     set_output(op, block, "TopkIdx", (x.shape[0], k), "int32")
     set_output(op, block, "TopkWeight", (x.shape[0], k), "float32")
 
 
+_ROUTER_SCORES = {"softmax": lambda z: jax.nn.softmax(z, -1),
+                  "sigmoid": jax.nn.sigmoid}
+
+
 def _router_compute(ins, attrs, ctx, op_index):
     x = ins["X"][0].astype(jnp.float32)
     w = ins["W"][0].astype(jnp.float32)
-    p = jax.nn.softmax(jnp.matmul(x, w, precision=lax.Precision.HIGHEST), -1)
-    top, idx = lax.top_k(p, int(attrs["top_k"]))
-    return {"TopkIdx": idx.astype(jnp.int32),
-            "TopkWeight": top / jnp.sum(top, -1, keepdims=True)}
+    score = attrs.get("score_func", "softmax")
+    p = _ROUTER_SCORES[score](
+        jnp.matmul(x, w, precision=lax.Precision.HIGHEST))
+    bias = (ins.get("Bias") or [None])[0]
+    k = int(attrs["top_k"])
+    if bias is None:
+        top, idx = lax.top_k(p, k)
+    else:
+        _, idx = lax.top_k(p + lax.stop_gradient(bias.astype(jnp.float32)),
+                           k)
+        top = jnp.take_along_axis(p, idx, -1)
+    total = jnp.sum(top, -1, keepdims=True)
+    if score == "sigmoid":
+        total = total + 1e-20     # independent scores can all be ~0
+    weight = top / total
+    scale = float(attrs.get("scale", 1.0))
+    if scale != 1.0:
+        weight = weight * scale
+    return {"TopkIdx": idx.astype(jnp.int32), "TopkWeight": weight}
 
 
-register_op("moe_router", ["X", "W"], ["TopkIdx", "TopkWeight"],
-            infer=_router_infer, compute=_router_compute)
+register_op("moe_router", ["X", "W", "Bias"], ["TopkIdx", "TopkWeight"],
+            infer=_router_infer, compute=_router_compute,
+            no_grad_inputs=("Bias",))
 
 
 # -- moe_dispatch --------------------------------------------------------------

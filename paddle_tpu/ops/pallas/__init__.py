@@ -17,7 +17,8 @@ op takes it on a TPU whenever the shape is inside its VMEM bound
 (``packed_attention.supported``), because there it was measured 2.8x
 ahead of the XLA body (PERF.md 6.6); only a PINNED
 ``FLAGS_pallas_kernels=False`` ("no Pallas") turns it off.  Likewise
-``streamed_attention`` (grouped heads / selected keys at any length) and
+``streamed_attention`` (grouped heads, selected keys, values narrower than
+the keys, or plain heads too long for the resident kernel; any length) and
 ``topk_select`` (``select_topk_keys`` with a query block's scores held in
 VMEM: one read of the scores where the XLA body makes 46; PERF.md 6.8);
 ``kernel_allowed`` is the part of their rules they share.  Under a

@@ -481,7 +481,8 @@ def reference_attention(q, k, v, k_len, seed, causal=False, dropout_rate=0.0,
         outs = [reference_attention(q5[:, :, i], k, v, k_len, seed, causal,
                                     0.0, scale, selected, True)
                 for i in range(g)]
-        out = jnp.stack([o for o, _ in outs], 2).reshape(b, h, tq, d)
+        out = jnp.stack([o for o, _ in outs], 2).reshape(b, h, tq,
+                                                         v.shape[3])
         lse = jnp.stack([l for _, l in outs], 2).reshape(b, h, tq, 1)
         return (out, lse) if with_lse else out
     # operands stay in the input dtype (bf16 under AMP -> bf16 MXU pass);

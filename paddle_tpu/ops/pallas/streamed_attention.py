@@ -8,6 +8,15 @@ grid axis (the innermost, ``arbitrary``), the online-softmax state lives in
 VMEM scratch across it, and only one ``[bk, D]`` block of K and of V is in
 flight: any sequence length the HBM holds fits.
 
+* **Keys wider than values.** Q and K are ``Dk`` wide, V, O and dO ``Dv``
+  (latent attention as training computes it: 192-wide keys — 128 from the
+  latent and a 64-wide rotary part the caller has already joined on — over
+  128-wide values); ``Dk == Dv`` is the common case.  The scores are ONE
+  contraction over ``Dk``: ``Dv`` is whole lane tiles, ``Dk`` whole
+  half-tiles (a 192-wide block is the array's full last axis, which Mosaic
+  lays out in two lane tiles; the MXU's 128-deep passes make the 64 odd
+  columns cost a pass either way, so a second product for the rotary part
+  would save no pass and add a kernel operand).
 * **One grid step serves a K/V head's query group.** K/V carry ``H / g``
   heads; the ``g`` query heads that read one of them are contiguous in
   ``[B, H, T, D]``, so a step's Q / O / dO / dQ block is ``[1, g', bq, D]``
@@ -21,7 +30,9 @@ flight: any sequence length the HBM holds fits.
   to its scores.  Forward and dQ run on the grid (B, H / g', query blocks,
   key blocks); dK/dV on (B, H / g, key blocks, (g / g') x query blocks),
   every head of every step adding into the one float32 dK and dV of the
-  key block.  ``g = 1`` (plain heads with a selection) is a loop of one.
+  key block.  ``g = 1`` (plain heads, with a selection or without: the
+  body of long plain-head self-attention whose K/V the resident kernel
+  cannot hold) is a loop of one.
 * **Selected keys.** ``selected`` is the packed bit mask of
   ``ops/sparse_select.py`` (``[B, Tq, W]`` int32; key ``s`` is bit ``(s %
   4096) // 128`` of word ``(s // 4096) * 128 + s % 128``).  One ``[bq, 128]``
@@ -82,37 +93,47 @@ def _pick_blocks(t):
     return None
 
 
-def supported(q_shape, k_shape, dtype, causal, has_klen, rate):
+def supported(q_shape, k_shape, dtype, causal, has_klen, rate, dv=None):
     """Whether the streamed kernels take this call: self-attention
-    (Tq == Tk) over whole 128-key slabs, heads in whole groups, a head size
-    of whole lane tiles, no dropout and no padding mask."""
+    (Tq == Tk) over whole 128-key slabs, heads in whole groups, values
+    (``dv`` wide; as wide as the keys by default) of whole lane tiles and
+    keys of whole half-tiles, no dropout and no padding mask."""
     if len(q_shape) != 4 or len(k_shape) != 4 or has_klen or rate:
         return False
     b, h, tq, d = q_shape
+    dv = d if dv is None else dv
     if k_shape[0] != b or k_shape[2] != tq or k_shape[3] != d:
         return False
-    if h % k_shape[1] or d % LANES or d > 256:
+    if h % k_shape[1] or d % (LANES // 2) or dv % LANES or max(d, dv) > 256:
         return False
     return _pick_blocks(tq) is not None
 
 
-def _step_bytes(gh, bq, bk, d, itemsize):
-    """VMEM bytes of a grid step that serves ``gh`` heads, by the hungriest
-    of the three kernels (dQ: three row blocks and two columns a head)."""
-    rows = bq * d * itemsize
+def _lanes(d):
+    """Lanes a ``d``-wide row takes in VMEM (whole tiles)."""
+    return -(-d // LANES) * LANES
+
+
+def _step_bytes(gh, bq, bk, dk, itemsize, dv=None):
+    """VMEM bytes of a grid step that serves ``gh`` heads of ``dk``-wide
+    keys and ``dv``-wide values (``dk`` by default), by the hungriest of
+    the three kernels (dQ: the Q, dQ and dO row blocks and two columns a
+    head)."""
+    dk, dv = _lanes(dk), _lanes(dk if dv is None else dv)
     column = bq * LANES * 4              # [bq, 1] float32 pads to 128 lanes
-    head = 2 * (3 * rows + 2 * column) + bq * d * 4
-    shared = 2 * (2 * bk * d * itemsize + bq * LANES * 4) + bq * bk * 4
+    head = 2 * (bq * (2 * dk + dv) * itemsize + 2 * column) \
+        + bq * max(dk, dv) * 4
+    shared = 2 * (bk * (dk + dv) * itemsize + bq * LANES * 4) + bq * bk * 4
     temporaries = 8 * bq * bk * 4        # scores, probabilities, their casts
     return gh * head + shared + temporaries
 
 
-def _heads_per_step(g, bq, bk, d, itemsize):
+def _heads_per_step(g, bq, bk, dk, itemsize, dv=None):
     """``g'``: the most heads of a group of ``g`` one grid step serves — the
     largest divisor of ``g`` whose blocks and scratch fit the budget."""
     return max(n for n in range(1, g + 1)
                if g % n == 0 and (n == 1 or _step_bytes(
-                   n, bq, bk, d, itemsize) <= _VMEM_BUDGET))
+                   n, bq, bk, dk, itemsize, dv) <= _VMEM_BUDGET))
 
 
 def _scores(q, k, scale, in_dtype):
@@ -293,22 +314,24 @@ def _params():
         vmem_limit_bytes=_VMEM_BUDGET)
 
 
-def _geometry(q, k):
-    """(B, H, T, D, heads a K/V head, heads a grid step, bq, bk, query
+def _geometry(q, k, v):
+    """(B, H, T, Dk, Dv, heads a K/V head, heads a grid step, bq, bk, query
     blocks, key blocks)."""
-    b, h, t, d = q.shape
+    b, h, t, dk = q.shape
+    dv = v.shape[3]
     bq = bk = _pick_blocks(t)
     g = h // k.shape[1]
-    gh = _heads_per_step(g, bq, bk, d, q.dtype.itemsize)
-    return b, h, t, d, g, gh, bq, bk, t // bq, t // bk
+    gh = _heads_per_step(g, bq, bk, dk, q.dtype.itemsize, dv)
+    return b, h, t, dk, dv, g, gh, bq, bk, t // bq, t // bk
 
 
-def _row_specs(g, gh, bq, bk, d, causal):
+def _row_specs(g, gh, bq, bk, causal):
     """Block specs of a grid (batch, block of ``gh`` query heads, query
-    block, key block): (the heads' query-row blocks ``[gh, bq, d]``, a
-    ``[gh, bq, 1]`` column of them, the K/V block of the heads' group, the
-    selection's word tile).  Under ``causal`` the key index clamps to the
-    last block the query block needs, so a skipped step fetches nothing."""
+    block, key block): (the heads' query-row blocks ``[gh, bq, d]`` for a
+    width ``d``, a ``[gh, bq, 1]`` column of them, the ``d``-wide K/V block
+    of the heads' group, the selection's word tile).  Under ``causal`` the
+    key index clamps to the last block the query block needs, so a skipped
+    step fetches nothing."""
     per_tile = KEYS_PER_TILE // bk
 
     def key_block(qi, ki):
@@ -322,28 +345,28 @@ def _row_specs(g, gh, bq, bk, d, causal):
 
     def sel_map(bi, hi, qi, ki):
         return (bi, qi, key_block(qi, ki) // per_tile)
-    return (pl.BlockSpec((1, gh, bq, d), q_map),
+    return (lambda d: pl.BlockSpec((1, gh, bq, d), q_map),
             pl.BlockSpec((1, gh, bq, 1), q_map),
-            pl.BlockSpec((1, 1, bk, d), kv_map),
+            lambda d: pl.BlockSpec((1, 1, bk, d), kv_map),
             pl.BlockSpec((1, bq, LANES), sel_map))
 
 
 def _forward(q, k, v, selected, causal, scale, interpret):
-    b, h, t, d, g, gh, bq, bk, nq, nk = _geometry(q, k)
-    row, col, kv, sel = _row_specs(g, gh, bq, bk, d, causal)
+    b, h, t, dk, dv, g, gh, bq, bk, nq, nk = _geometry(q, k, v)
+    row, col, kv, sel = _row_specs(g, gh, bq, bk, causal)
     has_sel = selected is not None
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           has_sel=has_sel, gh=gh, bq=bq, bk=bk, nk=nk,
                           in_dtype=q.dtype),
         grid=(b, h // gh, nq, nk),
-        in_specs=([sel] if has_sel else []) + [row, kv, kv],
-        out_specs=[row, col],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+        in_specs=([sel] if has_sel else []) + [row(dk), kv(dk), kv(dv)],
+        out_specs=[row(dv), col],
+        out_shape=[jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
                    jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((gh, bq, 1), jnp.float32),
                         pltpu.VMEM((gh, bq, 1), jnp.float32),
-                        pltpu.VMEM((gh, bq, d), jnp.float32),
+                        pltpu.VMEM((gh, bq, dv), jnp.float32),
                         pltpu.VMEM((bq, bk), jnp.float32)],
         compiler_params=_params(), interpret=interpret,
     )(*(((selected,) if has_sel else ()) + (q, k, v)))
@@ -351,7 +374,7 @@ def _forward(q, k, v, selected, causal, scale, interpret):
 
 
 def _backward(q, k, v, selected, out, lse, dout, causal, scale, interpret):
-    b, h, t, d, g, gh, bq, bk, nq, nk = _geometry(q, k)
+    b, h, t, dk, dv, g, gh, bq, bk, nq, nk = _geometry(q, k, v)
     per_tile = KEYS_PER_TILE // bk
     has_sel = selected is not None
     dout = dout.astype(q.dtype)
@@ -363,14 +386,15 @@ def _backward(q, k, v, selected, out, lse, dout, causal, scale, interpret):
     pairs = pltpu.VMEM((bq, bk), jnp.float32)
 
     # -- dQ: grid (B, blocks of gh heads, query blocks, key blocks) -----------
-    row, col, kv, sel = _row_specs(g, gh, bq, bk, d, causal)
+    row, col, kv, sel = _row_specs(g, gh, bq, bk, causal)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, nk=nk, **common),
         grid=(b, h // gh, nq, nk),
-        in_specs=([sel] if has_sel else []) + [row, kv, kv, row, col, col],
-        out_specs=row,
+        in_specs=([sel] if has_sel else [])
+        + [row(dk), kv(dk), kv(dv), row(dv), col, col],
+        out_specs=row(dk),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((gh, bq, d), jnp.float32), pairs],
+        scratch_shapes=[pltpu.VMEM((gh, bq, dk), jnp.float32), pairs],
         compiler_params=_params(), interpret=interpret,
     )(*(head + (q, k, v, dout, lse, delta)))
 
@@ -391,20 +415,23 @@ def _backward(q, k, v, selected, out, lse, dout, causal, scale, interpret):
 
     def sel_map2(bi, hk, ki, r):
         return (bi, clamp_q(ki, r), ki // per_tile)
-    row2 = pl.BlockSpec((1, gh, bq, d), q_map2)
+    def row2(d):
+        return pl.BlockSpec((1, gh, bq, d), q_map2)
+
+    def kv2(d):
+        return pl.BlockSpec((1, 1, bk, d), kv_map2)
     col2 = pl.BlockSpec((1, gh, bq, 1), q_map2)
-    kv2 = pl.BlockSpec((1, 1, bk, d), kv_map2)
     nr = g // gh * nq
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, nq=nq, nr=nr, **common),
         grid=(b, h // g, nk, nr),
         in_specs=([pl.BlockSpec((1, bq, LANES), sel_map2)] if has_sel else [])
-        + [row2, kv2, kv2, row2, col2, col2],
-        out_specs=[kv2, kv2],
+        + [row2(dk), kv2(dk), kv2(dv), row2(dv), col2, col2],
+        out_specs=[kv2(dk), kv2(dv)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32), pairs],
+        scratch_shapes=[pltpu.VMEM((bk, dk), jnp.float32),
+                        pltpu.VMEM((bk, dv), jnp.float32), pairs],
         compiler_params=_params(), interpret=interpret,
     )(*(head + (q, k, v, dout, lse, delta)))
     return dq, dk, dv
@@ -415,10 +442,10 @@ def _scale(q, scale):
 
 
 def forward(q, k, v, selected, causal=False, scale=None, interpret=False):
-    """q ``[B, H, T, D]``; k, v ``[B, H / g, T, D]``; ``selected`` the packed
-    key mask ``[B, T, W]`` int32 or None.  Returns the output ``[B, H, T,
-    D]`` in q's dtype and the rows' log-sum-exp ``[B, H, T, 1]`` float32,
-    which ``backward`` wants back."""
+    """q ``[B, H, T, Dk]``; k ``[B, H / g, T, Dk]``, v ``[B, H / g, T,
+    Dv]``; ``selected`` the packed key mask ``[B, T, W]`` int32 or None.
+    Returns the output ``[B, H, T, Dv]`` in q's dtype and the rows'
+    log-sum-exp ``[B, H, T, 1]`` float32, which ``backward`` wants back."""
     return _forward(q, k, v, selected, causal, _scale(q, scale), interpret)
 
 

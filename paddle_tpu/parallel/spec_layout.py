@@ -64,7 +64,30 @@ DEFAULT_RULES = (
 _PASSTHROUGH_OPS = {
     "relu", "gelu", "tanh", "sigmoid", "dropout", "scale", "reshape",
     "transpose", "fused_attention", "softmax", "cast",
+    # a gated FFN's product of its two column-parallel halves, and what a
+    # latent-attention block does between its up-projections and the
+    # attention: the head's parts split off, rotated, joined again
+    "swiglu", "split", "concat", "expand", "rotary_embedding",
 }
+
+
+def _normed_latents(program):
+    """Variables that go — whole, or split first — into an ``rms_norm``
+    and into no residual sum: the low-rank latents of a latent-attention
+    block (the query's, and the keys' and values' with the shared rotary
+    key beside it).  A residual stream is normed too, and added to."""
+    split_of, normed, added = {}, set(), set()
+    for blk in program.blocks:
+        for op in blk.ops:
+            if op.type == "split":
+                for out in op.outputs.get("Out", ()):
+                    split_of[out] = op.inputs["X"][0]
+            elif op.type == "rms_norm":
+                for x in op.inputs.get("X", ()):
+                    normed.add(split_of.get(x, x))
+            elif op.type == "elementwise_add":
+                added.update(op.inputs.get("X", ()), op.inputs.get("Y", ()))
+    return normed - added
 
 
 def classify_params(program):
@@ -73,7 +96,8 @@ def classify_params(program):
 
     * ``lookup_table`` W                     -> ``("vocab", "embed")``
     * ``layer_norm`` / ``rms_norm`` Scale/Bias -> ``("norm",)``
-    * ``moe_router`` W [embed, experts]      -> ``("embed", "expert")``
+    * ``moe_router`` W [embed, experts]      -> ``("embed", "expert")``,
+      its selection-only ``Bias`` [experts]  -> ``("expert",)``
     * ``moe_expert_ffn`` stacks [held, ., .] -> ``("expert", "embed",
       "mlp")`` (Gate, Up) and ``("expert", "mlp", "embed")`` (Down).  No
       default rule maps ``expert`` to a mesh axis yet (the mesh has no
@@ -84,6 +108,13 @@ def classify_params(program):
       op's data input descends from a column-parallel output — the
       Megatron pairing: qkv/ffn-up shard columns, attn-out/ffn-down
       shard rows, so the pair needs one all-reduce, not two.
+    * a down-projection into a normed latent (``_normed_latents``: a
+      latent-attention block's ``q_a`` / ``kv_a``) -> ``("embed",
+      "latent")``: no rule maps ``latent`` to a mesh axis, so the latent
+      stays whole on every chip (its RMSNorm reduces over it, and the
+      rotary key beside it is shared by all heads); the up-projections that
+      follow are column-parallel over the heads, the out-projection
+      row-parallel.
     * 1-D biases added onto a column-parallel output -> ``("mlp",)``;
       other 1-D biases -> ``("norm",)``.
 
@@ -94,6 +125,7 @@ def classify_params(program):
     # vars whose LAST dim is currently "mlp"-sharded (output of a
     # column-parallel projection, propagated through elementwise ops)
     mlp_vars = set()
+    latents = _normed_latents(program)
     for blk in program.blocks:
         for op in blk.ops:
             ins, outs = op.inputs, op.outputs
@@ -107,6 +139,8 @@ def classify_params(program):
             elif op.type == "moe_router":
                 for w in ins.get("W", ()):
                     classes[w] = ("embed", "expert")
+                for b in ins.get("Bias", ()):
+                    classes[b] = ("expert",)
             elif op.type == "moe_expert_ffn":
                 for slot, logical in (("Gate", ("expert", "embed", "mlp")),
                                       ("Up", ("expert", "embed", "mlp")),
@@ -118,6 +152,9 @@ def classify_params(program):
                 for w in ins.get("Y", ()):
                     v = blk._find_var_recursive(w)
                     if v is None or not getattr(v, "persistable", False):
+                        continue
+                    if any(o in latents for o in outs.get("Out", ())):
+                        classes.setdefault(w, ("embed", "latent"))
                         continue
                     row_par = any(x in mlp_vars for x in xs)
                     classes.setdefault(
@@ -136,7 +173,8 @@ def classify_params(program):
                     mlp_vars.update(outs.get("Out", ()))
             elif op.type in _PASSTHROUGH_OPS:
                 if any(x in mlp_vars for x in
-                       list(ins.get("X", ())) + list(ins.get("Q", ()))):
+                       list(ins.get("X", ())) + list(ins.get("Y", ()))
+                       + list(ins.get("Q", ()))):
                     for names in outs.values():
                         mlp_vars.update(names)
     return classes

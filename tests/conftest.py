@@ -53,11 +53,11 @@ def fresh_programs():
     compile_cache.clear()
 
 
-# Two tests of tests/benchmark_suite/test_bm_contract.py state what was true
-# of PR 23's benchmark and is not of one with a second configuration; the
+# Tests of tests/benchmark_suite/ that state what was true of the benchmark
+# when they were written and is not of one with a further configuration; the
 # benchmark's own files change in a benchmark PR only, so until one
-# restates them they are expected to fail here, and
-# tests/benchmark_suite/test_bm_keye_cell.py asserts what they meant of
+# restates them they are expected to fail here, and the newest cell's test
+# (tests/benchmark_suite/test_bm_joyai_cell.py) asserts what they meant of
 # the benchmark there is now.
 _RESTATED = {
     "test_bm_contract.py::test_benchmark_json_holds_the_training_cells_only":
@@ -67,6 +67,14 @@ _RESTATED = {
     "[keye_vl2_30b_a3b]":
         "reads 'hidden' in the reduced key num_hidden_layers (a depth, and "
         "the published config.json's own key) as a width",
+    "test_bm_contract.py::test_configuration_entry_and_file"
+    "[joyai_llm_flash]":
+        "the same reading of num_hidden_layers; "
+        "test_bm_joyai_cell.py::test_configuration_keeps_every_published_"
+        "size holds the file to the rest of that test",
+    "test_bm_keye_cell.py::test_benchmark_json_holds_the_training_cells":
+        "pins BENCHMARK.json to PR 26's three cells and two configurations; "
+        "ISSUE 30 adds joyai_llm_flash.train_mtp_8k",
 }
 
 

@@ -111,6 +111,44 @@ def test_streamed_kernel_compiles_for_v5e_at_the_long_document_shape(
     assert compiled.memory_analysis().temp_size_in_bytes < 512 * 1024 * 1024
 
 
+def test_streamed_kernel_compiles_for_v5e_at_the_latent_attention_shape(
+        one_chip):
+    """The streamed kernel at ``joyai_llm_flash.train_mtp_8k``'s shape — 32
+    plain heads, keys 192 wide over values 128 wide, T = 8192, bf16, no
+    selection — forward, dQ and dK/dV: Mosaic has to accept a block whose
+    last axis is the array's full 192 (two lane tiles, the second half
+    full) and the contraction over it, a ``[512, 192]`` float32 dQ
+    accumulator beside a ``[512, 128]`` one for dV, and nowhere the
+    ``[32, 8192, 8192]`` scores."""
+    from paddle_tpu.ops.pallas import streamed_attention as sa
+
+    b, h, t, dk, dv = 1, 32, 8192, 192, 128
+    assert sa.supported((b, h, t, dk), (b, h, t, dk), jnp.bfloat16, True,
+                        False, 0.0, dv)
+
+    def step(q, k, v, ct):
+        out, lse = sa.forward(q, k, v, None, True, dk ** -0.5, False)
+        return (out,) + sa.backward(q, k, v, None, out, lse, ct, True,
+                                    dk ** -0.5, False)
+
+    def arg(width):
+        return jax.ShapeDtypeStruct((b, h, t, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(step).lower(arg(dk), arg(dk), arg(dv),
+                                       arg(dv)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3
+    # (float32 scores would be 8.6 GB; the log-sum-exp and delta columns
+    # pad to 128 lanes, 134 MB each, and so do the backward's stagings)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024 * 1024 * 1024
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_topk_select_kernel_compiles_for_v5e_at_the_long_document_shape(
         one_chip, causal):

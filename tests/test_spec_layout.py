@@ -119,6 +119,52 @@ def test_classify_routed_expert_leaves():
                                 mesh) == P(None, "tp", "fsdp")
 
 
+def test_classify_latent_attention_and_shared_expert_leaves():
+    """The latent-attention decoder's leaves are all known: the two
+    down-projections into normed latents stay whole, the up-projections are
+    column-parallel over the heads and the out-projection row-parallel
+    (lineage through split / rotary / concat / expand / attention); the
+    dense gated FFN and the shared expert pair like any feed-forward (the
+    gate's lineage through ``swiglu``); the router's bias is [expert]."""
+    from paddle_tpu.models import sparse_moe_decoder as smd
+
+    tok, lbl, lbl2 = (fluid.layers.data(n, shape=[16, 1], dtype="int64")
+                      for n in ("tok", "lbl", "lbl2"))
+    smd.latent_decoder_lm(tok, lbl, lbl2, 64, 2, 1, 32,
+                          smd.LatentSizes(4, 24, 16, 16, 8, 16), 48,
+                          (2, 8, 0), 16, 2, 16, route_scale=2.5,
+                          expert_tile=8)
+    program = fluid.default_main_program()
+    classes = sl.classify_params(program)
+    for pre in ("l0.", "l1.", "mtp."):
+        assert classes[pre + "attn.q_a"] == classes[pre + "attn.kv_a"] == (
+            "embed", "latent")
+        assert classes[pre + "attn.q_b"] == classes[pre + "attn.kv_b"] == (
+            "embed", "mlp")
+        assert classes[pre + "attn.o"] == ("mlp", "embed")
+        assert classes[pre + "attn.q_a_g"] == classes[pre + "ln2.g"] == (
+            "norm",)
+    assert classes["l0.mlp.gate"] == classes["l0.mlp.up"] == ("embed", "mlp")
+    assert classes["l0.mlp.down"] == ("mlp", "embed")
+    for pre in ("l1.", "mtp."):
+        assert classes[pre + "moe.router"] == ("embed", "expert")
+        assert classes[pre + "moe.bias"] == ("expert",)
+        assert classes[pre + "moe.shared.gate"] == ("embed", "mlp")
+        assert classes[pre + "moe.shared.down"] == ("mlp", "embed")
+        assert classes[pre + "moe.down"] == ("expert", "mlp", "embed")
+    assert classes["mtp.eh_proj"] == ("embed", "mlp")
+    assert classes["tok_emb"] == ("vocab", "embed")
+    params = {p.name for p in program.global_block().all_parameters()}
+    assert params <= set(classes)          # no leaf is unknown
+    mesh = make_mesh((2, 4), ("fsdp", "tp"))
+    lay = SpecLayout()
+    assert lay.spec_for_logical(classes["l0.attn.q_a"], (32, 24),
+                                mesh) == P("fsdp")     # the latent whole
+    assert lay.spec_for_logical(classes["l0.attn.q_b"], (24, 96),
+                                mesh) == P("fsdp", "tp")
+    assert lay.spec_for_logical(classes["l1.moe.bias"], (8,), mesh) == P()
+
+
 def test_classify_transformer_params():
     _build_transformer()
     classes = sl.classify_params(fluid.default_main_program())

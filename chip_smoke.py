@@ -68,6 +68,8 @@ RING_SEQ = 4096
 # the long-document cell's selection: 2048 of 8192 keys a query, scored by
 # 16 indexer heads of 64
 SELECT_SEQ, SELECT_K, INDEX_HEADS, INDEX_DIM = 8192, 2048, 16, 64
+# plain heads the streamed kernels' check runs: more than a grid step serves
+PLAIN_HEADS = 16
 SEED = 90
 # the parameter whose movement (with both of its Adam moments) is checked
 WATCHED_PARAM = "dec_logits.w_0"
@@ -377,6 +379,75 @@ def check_kernel(name, kernel, reference, args, diff, tol):
     return errs
 
 
+def same_bits_as_one_head_a_step(name, args, words):
+    """Plain heads through the three kernels at the rule's own heads a grid
+    step and at ONE a step (the kernels before heads shared a step): out,
+    log-sum-exp, dQ, dK and dV bit for bit — a head's arithmetic does not
+    depend on which heads share its step."""
+    ct = normal(7, args[0].shape[:3] + args[2].shape[3:], jnp.bfloat16)
+
+    def kernels(q, k, v, ct):
+        out, lse = sa.forward(q, k, v, words, True, None, False)
+        return (out, lse) + sa.backward(q, k, v, words, out, lse, ct, True,
+                                        None, False)
+    rule = sa._heads_per_step
+    got = mosaic_jit(kernels, *args, ct)(*args, ct)
+    sa._heads_per_step = lambda *a: (1, 1)
+    try:
+        one = mosaic_jit(lambda *a: kernels(*a), *args, ct)(*args, ct)
+    finally:
+        sa._heads_per_step = rule
+    for what, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, one):
+        if not np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32)):
+            raise AssertionError("%s: %s differs from one head a step"
+                                 % (name, what))
+    return "bit-equal"
+
+
+def plain_heads_through_the_op(h, t, dk, dv):
+    """``h`` plain heads through ``fused_attention`` and its gradient op in
+    a program: the trace takes the streamed kernels, forward and backward,
+    and says how many heads a grid step serves (``streamed_step:<K/V
+    heads>x<query heads of each>``) — several, and not all ``h``.  Out and
+    the three gradients against the XLA body."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        q, k, v = (fluid.layers.data(n, shape=[h, t, w], dtype="float32")
+                   for n, w in (("q", dk), ("k", dk), ("v", dv)))
+        for x in (q, k, v):
+            x.stop_gradient = False
+        o = fluid.layers.fused_attention(q, k, v, causal=True,
+                                         scale=dk ** -0.5)
+        fluid.append_backward(fluid.layers.reduce_sum(
+            fluid.layers.elementwise_mul(o, o)))
+    args = [normal(i, (1, h, t, w), jnp.float32)
+            for i, w in enumerate((dk, dk, dv))]
+    before = {p: kernel_bodies(p) for p in ("fused_attention", "streamed_step")}
+    got = fluid.Executor(fluid.TPUPlace(0)).run(
+        main, feed=dict(zip("qkv", map(np.asarray, args))),
+        fetch_list=[o, "q@GRAD", "k@GRAD", "v@GRAD"])
+    bodies = bodies_since(before["fused_attention"], "fused_attention")
+    step = bodies_since(before["streamed_step"], "streamed_step")
+    kh, gh = sa.step_heads(*args)
+    if bodies != {"fused_attention:streamed": 1,
+                  "fused_attention_grad:streamed": 1} \
+            or step != {"streamed_step:%dx%d" % (kh, gh): 1} \
+            or not 1 < kh < h:
+        raise AssertionError("plain heads through the op: bodies %s, step %s"
+                             % (bodies, step))
+
+    def reference(q, k, v):
+        return fa.reference_attention(q, k, v, None, None, True, 0.0,
+                                      dk ** -0.5)
+    want = (jax.jit(reference)(*args),) + jax.jit(jax.grad(
+        lambda *a: jnp.sum(reference(*a) ** 2), (0, 1, 2)))(*args)
+    errs = [close(g, w, TOL_KERNEL["matmul"], "plain heads through the op")
+            for g, w in zip(got, want)]
+    log("plain heads through the op: %s, errors %s" % (step, errs))
+    return sorted(step)[0]
+
+
 def normal(seed, shape, dtype):
     return jax.random.normal(jax.random.key(seed), shape,
                              jnp.float32).astype(dtype)
@@ -439,11 +510,12 @@ def phase_kernels():
     packed("packed_attention_cross", 2 * SEQ, False)
 
     def streamed(name, h, hk, t, d, keep, dv=None):
-        """The long-document kernels (K/V streamed by blocks, a key/value
-        head's query group a grid step, the packed selection when ``keep``
-        keys a query are selected, values ``dv`` wide) at a causal shape of
-        several block pairs, bf16: forward, dQ, dK and dV against the XLA
-        body over the same selection."""
+        """The long-document kernels (K/V streamed by blocks, several heads
+        a grid step — a key/value head's query group, or some of the plain
+        heads and not all — the packed selection when ``keep`` keys a query
+        are selected, values ``dv`` wide) at a causal shape of several
+        block pairs, bf16: forward, dQ, dK and dV against the XLA body over
+        the same selection."""
         dv = d if dv is None else dv
         if not sa.supported((1, h, t, d), (1, hk, t, d), jnp.bfloat16, True,
                             False, 0.0, dv):
@@ -452,22 +524,34 @@ def phase_kernels():
         words = None if keep is None else sparse_select.pack_key_mask(
             sparse_select.topk_key_mask(normal(8, (1, t, t), jnp.float32),
                                         keep))
+        args = [normal(i, (1, n, t, w), jnp.bfloat16)
+                for i, (n, w) in enumerate(((h, d), (hk, d), (hk, dv)))]
+        kh, gh = sa.step_heads(*args)
+        if not (1 < gh if h > hk else 1 < kh < hk):
+            raise AssertionError("%s: a grid step serves %d x %d heads"
+                                 % (name, kh, gh))
         out[name] = check_kernel(
             name,
             lambda q, k, v: sa.streamed_attention(q, k, v, words, True,
                                                   None, False),
             lambda q, k, v: fa.reference_attention(q, k, v, None, None, True,
                                                    0.0, None, words),
-            [normal(i, (1, n, t, w), jnp.bfloat16)
-             for i, (n, w) in enumerate(((h, d), (hk, d), (hk, dv)))], 3,
-            TOL_KERNEL["matmul"])
+            args, 3, TOL_KERNEL["matmul"])
+        if h == hk:
+            out[name]["one_head_a_step"] = same_bits_as_one_head_a_step(
+                name, args, words)
 
     # eight query heads a key/value head (one grid step serves all eight),
     # four by four blocks of 512, a quarter of the keys selected
     streamed("streamed_attention_grouped", 16, 2, 2048, 128, 512)
     # latent attention as training computes it: plain heads, 192-wide keys
-    # over 128-wide values, no selection
-    streamed("streamed_attention_latent", 4, 4, 2048, 192, None, 128)
+    # over 128-wide values, no selection; the rule's own 8 heads a grid step,
+    # each with its own K/V block, dK and dV, and two blocks of heads; the
+    # same bits as one head a step
+    h = PLAIN_HEADS
+    streamed("streamed_attention_latent", h, h, 2048, 192, None, 128)
+    out["streamed_attention_latent"]["step"] = plain_heads_through_the_op(
+        h, 2048, 192, 128)
 
     rows, d_model = BATCH * SEQ, WIDTH["d_model"]
     gamma = jnp.linspace(0.5, 1.5, d_model, dtype=jnp.float32)
@@ -857,6 +941,7 @@ def main():
         "compile_cache_dir": cache_dir,
         "recordio_native": recordio.native_available(),
         "kernel_bodies": compile_cache.stats()["kernel_bodies"],
+        "kernel_traces": compile_cache.stats()["kernel_traces"],
         "phases": phases,
         "claim": None,
     }

@@ -46,6 +46,7 @@ __all__ = [
     "program_fingerprint", "program_label", "name_step", "trace_key",
     "trace_flag_values", "lookup",
     "store", "stats", "reset_stats", "clear", "note_kernel_body",
+    "note_kernel_trace",
     "count_compiles", "persistent_cache_dir", "enable_persistent_cache",
     "rescope_persistent_cache", "CHECKOUT_CACHE_DIR",
 ]
@@ -92,6 +93,8 @@ _LOWERINGS_BY_FP = {}
 # "<op type>:<body>" -> times traced: which compute body ("pallas",
 # "xla", "ring") an op with a hand-written alternative lowered to
 _KERNEL_BODIES = {}
+# kernel -> {"sites": calls that reached it, "traces": jaxprs it made}
+_KERNEL_TRACES = {}
 _persistent_dir = [None]
 _persistent_base = [None]     # resolved dir, before any world scoping
 
@@ -207,6 +210,7 @@ def stats():
         out = dict(_STATS)
         out["lowerings_by_program"] = dict(_LOWERINGS_BY_FP)
         out["kernel_bodies"] = dict(_KERNEL_BODIES)
+        out["kernel_traces"] = {k: dict(n) for k, n in _KERNEL_TRACES.items()}
     lookups = out["trace_hits"] + out["trace_misses"]
     out["hit_ratio"] = round(out["trace_hits"] / lookups, 4) if lookups \
         else 0.0
@@ -221,6 +225,7 @@ def reset_stats():
             _STATS[k] = 0
         _LOWERINGS_BY_FP.clear()
         _KERNEL_BODIES.clear()
+        _KERNEL_TRACES.clear()
 
 
 def note_kernel_body(op_type, body):
@@ -233,6 +238,18 @@ def note_kernel_body(op_type, body):
     with _mu:
         _KERNEL_BODIES[key] = _KERNEL_BODIES.get(key, 0) + 1
     mark_event("kernel_body/%s/%s" % (op_type, body))
+
+
+def note_kernel_trace(kernel, counter):
+    """Count, at trace time, what a kernel that traces its ``pallas_call``s
+    once a signature did (``ops/pallas/streamed_attention.py``):
+    ``"sites"`` — a call reached it, ``"traces"`` — the call's signature was
+    new and its jaxpr was made.  ``stats()["kernel_traces"][kernel]`` holds
+    both: a step program of six blocks reads 18 sites and 3 traces, and
+    sites == traces says the memo never engaged."""
+    with _mu:
+        counts = _KERNEL_TRACES.setdefault(kernel, {"sites": 0, "traces": 0})
+        counts[counter] += 1
 
 
 def clear():

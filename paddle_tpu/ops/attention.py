@@ -170,6 +170,8 @@ def _fused_attention_compute(ins, attrs, ctx, op_index):
         if _streamed_applicable(ctx, q.shape, k.shape, q.dtype, causal,
                                 k_len is not None, rate, v.shape[3]):
             note_kernel_body("fused_attention", "streamed")
+            # K/V heads x query heads of each that a grid step serves
+            note_kernel_body("streamed_step", "%dx%d" % sa.step_heads(q, k, v))
             out, lse = sa.forward(q, k, v, selected, causal, scale,
                                   interpret_mode(ctx))
         elif _plain(q, k, v, selected) \

@@ -17,22 +17,31 @@ flight: any sequence length the HBM holds fits.
   lays out in two lane tiles; the MXU's 128-deep passes make the 64 odd
   columns cost a pass either way, so a second product for the rotary part
   would save no pass and add a kernel operand).
-* **One grid step serves a K/V head's query group.** K/V carry ``H / g``
-  heads; the ``g`` query heads that read one of them are contiguous in
-  ``[B, H, T, D]``, so a step's Q / O / dO / dQ block is ``[1, g', bq, D]``
-  — ``g'`` heads, a divisor of ``g`` (``_heads_per_step``: all of them where
-  the blocks fit the VMEM budget) — and the step loops over them (a rolled
-  ``fori_loop`` of at most four heads' text a turn, whatever ``g'``).  What
-  the heads of a group share is done ONCE a step: the K/V block and the
-  selection's word tile are fetched once, and which (query, key) pairs
-  count is worked out once, into a float32 ``[bq, bk]`` scratch that holds
-  0 for a pair that counts and -1e30 for one that does not; a head adds it
-  to its scores.  Forward and dQ run on the grid (B, H / g', query blocks,
-  key blocks); dK/dV on (B, H / g, key blocks, (g / g') x query blocks),
-  every head of every step adding into the one float32 dK and dV of the
-  key block.  ``g = 1`` (plain heads, with a selection or without: the
-  body of long plain-head self-attention whose K/V the resident kernel
-  cannot hold) is a loop of one.
+* **One grid step serves several heads.** K/V carry ``H / g`` heads; the
+  ``g`` query heads that read one of them are contiguous in ``[B, H, T,
+  D]``.  A step serves ``kh`` K/V heads and ``g'`` query heads of each
+  (``_heads_per_step``, from the operands' shapes and the VMEM budget):
+  its Q / O / dO / dQ block is ``[1, kh * g', bq, D]``, its K/V block
+  ``[1, kh, bk, D]``, query head ``j`` of the step reads K/V head ``j //
+  g'`` of the step, and the step loops over its heads (a rolled
+  ``fori_loop`` of at most four heads' text a turn, however many).  Heads
+  that share a K/V head (``g`` > 1): ``kh`` = 1 and ``g'`` a divisor of
+  ``g``, all of them where the blocks fit.  Plain heads (``g`` = 1, with a
+  selection or without: the body of long plain-head self-attention whose
+  K/V the resident kernel cannot hold, and of latent attention): ``g'`` =
+  1 and ``kh`` a divisor of the heads, each head of the step with its own
+  K/V block and, in dK/dV, its own float32 dK and dV.  What the heads of a
+  step share is done ONCE a step: the selection's word tile is fetched
+  once (and a group's K/V block), and which (query, key) pairs count is
+  worked out once, into a float32 ``[bq, bk]`` scratch that holds 0 for a
+  pair that counts and -1e30 for one that does not; a head adds it to its
+  scores.  So is what a step costs whatever it holds: its prologue (the
+  blocks' DMAs issued and awaited, the ``pl.when`` tests, the
+  accumulators' first and last touch) and, under ``causal``, the empty
+  steps above the diagonal.  Forward and dQ run on the grid (B, H / (kh x
+  g'), query blocks, key blocks); dK/dV on (B, H / (g x kh), key blocks,
+  (g / g') x query blocks), head ``j`` of a step adding into the float32
+  dK and dV of K/V head ``j // g'``.
 * **Selected keys.** ``selected`` is the packed bit mask of
   ``ops/sparse_select.py`` (``[B, Tq, W]`` int32; key ``s`` is bit ``(s %
   4096) // 128`` of word ``(s // 4096) * 128 + s % 128``).  One ``[bq, 128]``
@@ -53,6 +62,21 @@ No dropout and no per-row key length: every position is real (the op falls
 back to the XLA body otherwise).  Backward is the standard flash
 decomposition (``delta = rowsum(dO * O)``, one dQ kernel, one dK/dV kernel,
 probabilities recomputed from the saved log-sum-exp).
+
+A step program calls these kernels once a block with the same shapes.  Each
+of the three ``pallas_call``s is traced ONCE a signature — the operands'
+shapes and dtypes, a selection or none, the heads a step, ``causal``,
+``scale``, ``interpret`` — into a jaxpr that ``_traced`` keeps, and every
+call evaluates that jaxpr on its own operands (``_run``).  All sites of a
+signature then bind the SAME ``pallas_call`` equation, so jax lowers it to
+Mosaic once (its per-equation lowering cache is keyed on the equation's
+params; a ``pallas_call`` built anew carries new index maps and a new
+partial of its kernel and never hits) and inlines the result at each site
+under that site's own name stack — the ``fluid[<op type>]`` scope by which
+the device trace is read.  Not a ``jax.jit`` around the kernels: that would
+lower once too, into shared functions under no block's scope.
+``compile_cache.stats()["kernel_traces"]["streamed_attention"]`` counts the
+sites and the traces (18 and 3 in a step of six plain-head blocks).
 """
 
 import functools
@@ -62,6 +86,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...compile_cache import note_kernel_trace
 from ..sparse_select import KEYS_PER_TILE, LANES
 
 _NEG_INF = -1e30
@@ -74,15 +99,31 @@ _POS_BIG = 1e30
 # are 128 KB each but its ``[bq, 1]`` float32 columns (log-sum-exp, delta)
 # pad to 128 lanes, 256 KB each, and all five are double-buffered (2 MB a
 # head with the float32 accumulator, 16 MB the group); the rest is the K/V
-# blocks, the word tile, the pairs' scratch and a head's temporaries.
+# blocks, the word tile, the pairs' scratch and a head's temporaries.  At
+# the latent-attention cell's — 32 plain heads, keys 192 (two lane tiles)
+# over values 128, bf16 — a head of the step is 4 MiB by dK/dV, the
+# hungrier there (Q and dO blocks and two columns 1.75, its own K and V
+# blocks 0.75, its float32 dK and dV and their output blocks 1.5): 25.5 MiB
+# for 4 heads, 41.5 for 8, which the rule takes and Mosaic compiles; 16 do
+# not fit.  A block's forward / dQ / dK/dV alone on a v5e, ms a call (dQ
+# and dK/dV with ``backward``'s delta), by plain heads a step:
+#   1: 11.56 / 12.10 / 13.95 (the kernels before plain heads shared a step,
+#   to 0.01)   2: 10.92 / 11.16 / 12.64   4: 8.81 / 10.71 / 11.94
+#   8: 8.38 / 10.56 / 11.87 — the same bits out of all of them.
 _VMEM_BUDGET = 48 * 1024 * 1024
 # Heads whose text one turn of the head loop holds: the scheduler runs a
 # head's products on the MXU under its neighbour's softmax on the VPU, which
-# a loop of single heads forbids.  A layer's three kernels at the cell's
-# shape read 23.6 ms with 1, 22.4 with 2, 21.7 with 4 and 21.4 with all 8
-# (28.2 before the grouping); the step's compile is the same to its own
-# noise (30-35 s) with any of them.
+# a loop of single heads forbids.  A layer's three kernels at the
+# long-document cell's shape read 23.6 ms with 1, 22.4 with 2, 21.7 with 4
+# and 21.4 with all 8 (28.2 before the grouping); the step's compile is the
+# same to its own noise (30-35 s) with any of them.  At the latent cell's,
+# 8 plain heads a step: 33.4 ms with 2, 30.8 with 4, 31.2 with all 8 (the
+# forward 10.50 / 8.38 / 9.05); 4 heads a step one at a time 34.9, by twos
+# 33.9, all four 31.5.
 _HEADS_UNROLLED = 4
+# Signatures whose three jaxprs ``_traced`` keeps (a step program has one or
+# two; a jaxpr is a few hundred equations and holds no array).
+_TRACES_KEPT = 3 * 32
 
 
 def _pick_blocks(t):
@@ -114,26 +155,40 @@ def _lanes(d):
     return -(-d // LANES) * LANES
 
 
-def _step_bytes(gh, bq, bk, dk, itemsize, dv=None):
-    """VMEM bytes of a grid step that serves ``gh`` heads of ``dk``-wide
-    keys and ``dv``-wide values (``dk`` by default), by the hungriest of
-    the three kernels (dQ: the Q, dQ and dO row blocks and two columns a
-    head)."""
+def _step_bytes(gh, bq, bk, dk, itemsize, dv=None, kh=1):
+    """VMEM bytes of a grid step that serves ``kh`` K/V heads of ``dk``-wide
+    keys and ``dv``-wide values (``dk`` by default), each with ``gh`` query
+    heads, by the hungrier of dQ (a query head's Q, dQ and dO row blocks,
+    two columns and its float32 accumulator; a K/V head's two blocks) and
+    dK/dV (a query head's Q and dO blocks and two columns; a K/V head's two
+    blocks, its float32 dK and dV and their two output blocks)."""
     dk, dv = _lanes(dk), _lanes(dk if dv is None else dv)
     column = bq * LANES * 4              # [bq, 1] float32 pads to 128 lanes
-    head = 2 * (bq * (2 * dk + dv) * itemsize + 2 * column) \
-        + bq * max(dk, dv) * 4
-    shared = 2 * (bk * (dk + dv) * itemsize + bq * LANES * 4) + bq * bk * 4
+    kv = 2 * bk * (dk + dv) * itemsize
+    dq = kh * gh * (2 * (bq * (2 * dk + dv) * itemsize + 2 * column)
+                    + bq * max(dk, dv) * 4) + kh * kv
+    dkv = kh * gh * 2 * (bq * (dk + dv) * itemsize + 2 * column) \
+        + kh * (2 * kv + bk * (dk + dv) * 4)
+    shared = 2 * bq * LANES * 4 + bq * bk * 4   # the word tile, the pairs
     temporaries = 8 * bq * bk * 4        # scores, probabilities, their casts
-    return gh * head + shared + temporaries
+    return max(dq, dkv) + shared + temporaries
 
 
-def _heads_per_step(g, bq, bk, dk, itemsize, dv=None):
-    """``g'``: the most heads of a group of ``g`` one grid step serves — the
-    largest divisor of ``g`` whose blocks and scratch fit the budget."""
-    return max(n for n in range(1, g + 1)
-               if g % n == 0 and (n == 1 or _step_bytes(
-                   n, bq, bk, dk, itemsize, dv) <= _VMEM_BUDGET))
+def _heads_per_step(g, hk, bq, bk, dk, itemsize, dv=None):
+    """(``kh``, ``g'``): the K/V heads one grid step serves, of ``hk``, and
+    the query heads of each, of its group of ``g`` — the most whose blocks
+    and scratch fit the budget.  Heads that share a K/V head: one K/V head a
+    step and the largest divisor of ``g`` that fits.  Plain heads (``g`` =
+    1): the largest divisor of ``hk`` that fits."""
+    def fits(kh, gh):
+        return _step_bytes(gh, bq, bk, dk, itemsize, dv, kh) <= _VMEM_BUDGET
+
+    def most(n, ok):
+        return max(m for m in range(1, n + 1)
+                   if n % m == 0 and (m == 1 or ok(m)))
+    if g > 1:
+        return 1, most(g, lambda m: fits(1, m))
+    return most(hk, lambda m: fits(m, 1)), 1
 
 
 def _scores(q, k, scale, in_dtype):
@@ -149,6 +204,14 @@ def _dot(a, b, contract, in_dtype):
                                preferred_element_type=jnp.float32)
 
 
+# Whole-number division of a traced index that is never negative (a grid
+# index, a head of the step's loop).  Not ``//`` and ``%``: they round to the
+# floor through ``sign`` and a select, a dozen scalar operations each in the
+# kernel's text — 480 of them took 6.6 s of a step's lowering when the head
+# loop used ``//``.
+_div, _rem = jax.lax.div, jax.lax.rem
+
+
 def _split(refs, has_sel, n_in):
     """(selected ref or None, the other inputs, outputs and scratch)."""
     if has_sel:
@@ -156,20 +219,24 @@ def _split(refs, has_sel, n_in):
     return None, refs[:n_in - 1], refs[n_in - 1:]
 
 
-def _each_head(head, sel_ref, bias_s, qi, ki, gh, bq, bk, causal):
-    """Block pair (qi, ki) for the step's ``gh`` heads: ``head(h, bias)``
-    for each, where ``bias`` is ``bias_s`` — float32 ``[bq, bk]``, 0.0 for a
-    (query, key) pair that counts and -1e30 for one that does not, written
-    here once for all the heads — or None when every pair counts.  Nothing
-    runs for a pair wholly above the diagonal."""
-    together = max(n for n in range(1, _HEADS_UNROLLED + 1) if gh % n == 0)
+def _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal):
+    """Block pair (qi, ki) for the step's ``kh * gh`` query heads:
+    ``head(h, kv, bias)`` for each, where ``kv`` is the one of the step's
+    ``kh`` K/V heads that query head ``h`` reads (``h // gh``) and ``bias``
+    is ``bias_s`` — float32 ``[bq, bk]``, 0.0 for a (query, key) pair that
+    counts and -1e30 for one that does not, written here once for all the
+    heads — or None when every pair counts.  Nothing runs for a pair wholly
+    above the diagonal."""
+    n = kh * gh
+    together = max(m for m in range(1, _HEADS_UNROLLED + 1) if n % m == 0)
 
     def heads(bias):
         def body(i, carry):
             for j in range(together):
-                head(i * together + j, bias)
+                h = i * together + j
+                head(h, _div(h, gh) if kh > 1 else 0, bias)
             return carry
-        jax.lax.fori_loop(0, gh // together, body, 0)
+        jax.lax.fori_loop(0, n // together, body, 0)
 
     def below_diagonal():
         rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
@@ -181,7 +248,7 @@ def _each_head(head, sel_ref, bias_s, qi, ki, gh, bq, bk, causal):
     if sel_ref is not None:
         def block():
             words = sel_ref[0]                                  # [bq, 128]
-            first_plane = (ki % (KEYS_PER_TILE // bk)) * (bk // LANES)
+            first_plane = _rem(ki, KEYS_PER_TILE // bk) * (bk // LANES)
             for p in range(bk // LANES):
                 bit = jax.lax.shift_right_logical(
                     words, jnp.full(words.shape, first_plane + p, jnp.int32))
@@ -210,7 +277,7 @@ def _each_head(head, sel_ref, bias_s, qi, ki, gh, bq, bk, causal):
         heads(None)
 
 
-def _fwd_kernel(*refs, scale, causal, has_sel, gh, bq, bk, nk, in_dtype):
+def _fwd_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nk, in_dtype):
     sel_ref, (q_ref, k_ref, v_ref), \
         (o_ref, lse_ref, m_s, l_s, acc_s, bias_s) = _split(refs, has_sel, 4)
     qi, ki = pl.program_id(2), pl.program_id(3)
@@ -221,8 +288,8 @@ def _fwd_kernel(*refs, scale, causal, has_sel, gh, bq, bk, nk, in_dtype):
         l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
         acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
 
-    def head(h, bias):
-        s = _scores(q_ref[0, h], k_ref[0, 0], scale, in_dtype)
+    def head(h, kv, bias):
+        s = _scores(q_ref[0, h], k_ref[0, kv], scale, in_dtype)
         if bias is not None:
             s = s + bias[...]
         m = m_s[h]
@@ -235,11 +302,11 @@ def _fwd_kernel(*refs, scale, causal, has_sel, gh, bq, bk, nk, in_dtype):
             p = jnp.exp(s - jnp.where(m_new > _NEG_INF, m_new, 0.0))
         corr = jnp.exp(m - m_new)
         l_s[h] = l_s[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_s[h] = acc_s[h] * corr + _dot(p, v_ref[0, 0], ((1,), (0,)),
+        acc_s[h] = acc_s[h] * corr + _dot(p, v_ref[0, kv], ((1,), (0,)),
                                           in_dtype)
         m_s[h] = m_new
 
-    _each_head(head, sel_ref, bias_s, qi, ki, gh, bq, bk, causal)
+    _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -257,7 +324,7 @@ def _probs(q, k, lse, bias, scale, in_dtype):
     return jnp.exp(s - lse)                          # empty rows: lse = +BIG
 
 
-def _dq_kernel(*refs, scale, causal, has_sel, gh, bq, bk, nk, in_dtype):
+def _dq_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nk, in_dtype):
     sel_ref, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), \
         (dq_ref, acc_s, bias_s) = _split(refs, has_sel, 7)
     qi, ki = pl.program_id(2), pl.program_id(3)
@@ -266,21 +333,22 @@ def _dq_kernel(*refs, scale, causal, has_sel, gh, bq, bk, nk, in_dtype):
     def _():
         acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
 
-    def head(h, bias):
-        p = _probs(q_ref[0, h], k_ref[0, 0], lse_ref[0, h], bias, scale,
+    def head(h, kv, bias):
+        p = _probs(q_ref[0, h], k_ref[0, kv], lse_ref[0, h], bias, scale,
                    in_dtype)
-        g = _dot(do_ref[0, h], v_ref[0, 0], ((1,), (1,)), in_dtype)
+        g = _dot(do_ref[0, h], v_ref[0, kv], ((1,), (1,)), in_dtype)
         ds = p * (g - delta_ref[0, h])
-        acc_s[h] += _dot(ds, k_ref[0, 0], ((1,), (0,)), in_dtype)
+        acc_s[h] += _dot(ds, k_ref[0, kv], ((1,), (0,)), in_dtype)
 
-    _each_head(head, sel_ref, bias_s, qi, ki, gh, bq, bk, causal)
+    _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal)
 
     @pl.when(ki == nk - 1)
     def _():
         dq_ref[0] = (acc_s[...] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(*refs, scale, causal, has_sel, gh, bq, bk, nq, nr, in_dtype):
+def _dkv_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nq, nr,
+                in_dtype):
     sel_ref, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), \
         (dk_ref, dv_ref, dk_s, dv_s, bias_s) = _split(refs, has_sel, 7)
     ki, r = pl.program_id(2), pl.program_id(3)
@@ -290,155 +358,209 @@ def _dkv_kernel(*refs, scale, causal, has_sel, gh, bq, bk, nq, nr, in_dtype):
         dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
         dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
 
-    def head(h, bias):
-        q = q_ref[0, h]
-        p = _probs(q, k_ref[0, 0], lse_ref[0, h], bias, scale, in_dtype)
-        do = do_ref[0, h]
-        dv_s[...] += _dot(p, do, ((0,), (0,)), in_dtype)
-        g = _dot(do, v_ref[0, 0], ((1,), (1,)), in_dtype)
-        ds = p * (g - delta_ref[0, h])
-        dk_s[...] += _dot(ds, q.astype(jnp.float32) * scale, ((0,), (0,)),
-                          in_dtype)
+    def rows(kv):                  # K/V head kv's rows of dk_s and dv_s
+        return pl.ds(kv * bk, bk)
 
-    _each_head(head, sel_ref, bias_s, r % nq, ki, gh, bq, bk, causal)
+    def head(h, kv, bias):
+        q = q_ref[0, h]
+        p = _probs(q, k_ref[0, kv], lse_ref[0, h], bias, scale, in_dtype)
+        do = do_ref[0, h]
+        dv_s[rows(kv)] += _dot(p, do, ((0,), (0,)), in_dtype)
+        g = _dot(do, v_ref[0, kv], ((1,), (1,)), in_dtype)
+        ds = p * (g - delta_ref[0, h])
+        dk_s[rows(kv)] += _dot(ds, q.astype(jnp.float32) * scale,
+                               ((0,), (0,)), in_dtype)
+
+    _each_head(head, sel_ref, bias_s, _rem(r, nq), ki, kh, gh, bq, bk,
+               causal)
 
     @pl.when(r == nr - 1)
     def _():
-        dk_ref[0, 0] = dk_s[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_s[...].astype(dv_ref.dtype)
+        for kv in range(kh):
+            dk_ref[0, kv] = dk_s[rows(kv)].astype(dk_ref.dtype)
+            dv_ref[0, kv] = dv_s[rows(kv)].astype(dv_ref.dtype)
 
 
-def _params():
+def _params(vmem):
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=_VMEM_BUDGET)
+        vmem_limit_bytes=vmem)
 
 
 def _geometry(q, k, v):
-    """(B, H, T, Dk, Dv, heads a K/V head, heads a grid step, bq, bk, query
-    blocks, key blocks)."""
+    """(B, H, T, Dk, Dv, query heads a K/V head, bq, bk, query blocks, key
+    blocks)."""
     b, h, t, dk = q.shape
-    dv = v.shape[3]
     bq = bk = _pick_blocks(t)
-    g = h // k.shape[1]
-    gh = _heads_per_step(g, bq, bk, dk, q.dtype.itemsize, dv)
-    return b, h, t, dk, dv, g, gh, bq, bk, t // bq, t // bk
+    return b, h, t, dk, v.shape[3], h // k.shape[1], bq, bk, t // bq, t // bk
 
 
-def _row_specs(g, gh, bq, bk, causal):
-    """Block specs of a grid (batch, block of ``gh`` query heads, query
-    block, key block): (the heads' query-row blocks ``[gh, bq, d]`` for a
-    width ``d``, a ``[gh, bq, 1]`` column of them, the ``d``-wide K/V block
-    of the heads' group, the selection's word tile).  Under ``causal`` the
-    key index clamps to the last block the query block needs, so a skipped
-    step fetches nothing."""
+def step_heads(q, k, v):
+    """(K/V heads, query heads of each) that one grid step of the three
+    kernels serves for these operands."""
+    b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v)
+    return _heads_per_step(g, k.shape[1], bq, bk, dk, q.dtype.itemsize, dv)
+
+
+def _row_specs(g, kh, gh, bq, bk, causal):
+    """Block specs of a grid (batch, block of ``kh * gh`` query heads, query
+    block, key block): (the heads' query-row blocks ``[kh * gh, bq, d]`` for
+    a width ``d``, a ``[kh * gh, bq, 1]`` column of them, the ``d``-wide K/V
+    blocks ``[kh, bk, d]`` of the heads' K/V heads, the selection's word
+    tile).  Under ``causal`` the key index clamps to the last block the
+    query block needs, so a skipped step fetches nothing."""
     per_tile = KEYS_PER_TILE // bk
 
     def key_block(qi, ki):
-        return jnp.minimum(ki, (qi * bq + bq - 1) // bk) if causal else ki
+        return jnp.minimum(ki, _div(qi * bq + bq - 1, bk)) if causal else ki
 
     def q_map(bi, hi, qi, ki):
         return (bi, hi, qi, 0)
 
-    def kv_map(bi, hi, qi, ki):
-        return (bi, hi * gh // g, key_block(qi, ki), 0)
+    def kv_map(bi, hi, qi, ki):    # in blocks of kh; kh > 1 only if gh == g
+        return (bi, _div(hi * gh, g), key_block(qi, ki), 0)
 
     def sel_map(bi, hi, qi, ki):
-        return (bi, qi, key_block(qi, ki) // per_tile)
-    return (lambda d: pl.BlockSpec((1, gh, bq, d), q_map),
-            pl.BlockSpec((1, gh, bq, 1), q_map),
-            lambda d: pl.BlockSpec((1, 1, bk, d), kv_map),
+        return (bi, qi, _div(key_block(qi, ki), per_tile))
+    return (lambda d: pl.BlockSpec((1, kh * gh, bq, d), q_map),
+            pl.BlockSpec((1, kh * gh, bq, 1), q_map),
+            lambda d: pl.BlockSpec((1, kh, bk, d), kv_map),
             pl.BlockSpec((1, bq, LANES), sel_map))
 
 
-def _forward(q, k, v, selected, causal, scale, interpret):
-    b, h, t, dk, dv, g, gh, bq, bk, nq, nk = _geometry(q, k, v)
-    row, col, kv, sel = _row_specs(g, gh, bq, bk, causal)
+def _given(*operands):
+    """The operands that are there (``selected`` is None without a
+    selection)."""
+    return [x for x in operands if x is not None]
+
+
+def _forward(selected, q, k, v, *, heads, vmem, causal, scale, interpret):
+    b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v)
+    kh, gh = heads
+    row, col, kv, sel = _row_specs(g, kh, gh, bq, bk, causal)
     has_sel = selected is not None
-    out, lse = pl.pallas_call(
+    n = kh * gh
+    return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          has_sel=has_sel, gh=gh, bq=bq, bk=bk, nk=nk,
+                          has_sel=has_sel, kh=kh, gh=gh, bq=bq, bk=bk, nk=nk,
                           in_dtype=q.dtype),
-        grid=(b, h // gh, nq, nk),
+        grid=(b, h // n, nq, nk),
         in_specs=([sel] if has_sel else []) + [row(dk), kv(dk), kv(dv)],
         out_specs=[row(dv), col],
         out_shape=[jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
                    jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((gh, bq, 1), jnp.float32),
-                        pltpu.VMEM((gh, bq, 1), jnp.float32),
-                        pltpu.VMEM((gh, bq, dv), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((n, bq, 1), jnp.float32),
+                        pltpu.VMEM((n, bq, 1), jnp.float32),
+                        pltpu.VMEM((n, bq, dv), jnp.float32),
                         pltpu.VMEM((bq, bk), jnp.float32)],
-        compiler_params=_params(), interpret=interpret,
-    )(*(((selected,) if has_sel else ()) + (q, k, v)))
-    return out, lse
+        compiler_params=_params(vmem), interpret=interpret,
+    )(*_given(selected, q, k, v))
 
 
-def _backward(q, k, v, selected, out, lse, dout, causal, scale, interpret):
-    b, h, t, dk, dv, g, gh, bq, bk, nq, nk = _geometry(q, k, v)
-    per_tile = KEYS_PER_TILE // bk
+def _dq(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
+        scale, interpret):
+    """dQ: grid (B, blocks of kh x gh heads, query blocks, key blocks)."""
+    b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v)
+    kh, gh = heads
+    n = kh * gh
     has_sel = selected is not None
-    dout = dout.astype(q.dtype)
-    delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), -1,
-                    keepdims=True)
-    common = dict(scale=scale, causal=causal, has_sel=has_sel, gh=gh, bq=bq,
-                  bk=bk, in_dtype=q.dtype)
-    head = (selected,) if has_sel else ()
-    pairs = pltpu.VMEM((bq, bk), jnp.float32)
-
-    # -- dQ: grid (B, blocks of gh heads, query blocks, key blocks) -----------
-    row, col, kv, sel = _row_specs(g, gh, bq, bk, causal)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, nk=nk, **common),
-        grid=(b, h // gh, nq, nk),
+    row, col, kv, sel = _row_specs(g, kh, gh, bq, bk, causal)
+    return pl.pallas_call(
+        functools.partial(_dq_kernel, scale=scale, causal=causal,
+                          has_sel=has_sel, kh=kh, gh=gh, bq=bq, bk=bk, nk=nk,
+                          in_dtype=q.dtype),
+        grid=(b, h // n, nq, nk),
         in_specs=([sel] if has_sel else [])
         + [row(dk), kv(dk), kv(dv), row(dv), col, col],
         out_specs=row(dk),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((gh, bq, dk), jnp.float32), pairs],
-        compiler_params=_params(), interpret=interpret,
-    )(*(head + (q, k, v, dout, lse, delta)))
+        scratch_shapes=[pltpu.VMEM((n, bq, dk), jnp.float32),
+                        pltpu.VMEM((bq, bk), jnp.float32)],
+        compiler_params=_params(vmem), interpret=interpret,
+    )(*_given(selected, q, k, v, dout, lse, delta))
 
-    # -- dK, dV: grid (B, KV heads, key blocks, the group's blocks of gh heads
-    # x query blocks) ----------------------------------------------------------
+
+def _dkv(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
+         scale, interpret):
+    """dK, dV: grid (B, blocks of kh K/V heads, key blocks, a group's blocks
+    of gh heads x query blocks); kh > 1 only if gh == g."""
+    b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v)
+    kh, gh = heads
+    n = kh * gh
+    per_tile = KEYS_PER_TILE // bk
+    has_sel = selected is not None
+
     def first_q(ki):               # the first query block key block ki reaches
-        return (ki * bk) // bq
+        return _div(ki * bk, bq)
 
     def clamp_q(ki, r):
-        qi = r % nq
+        qi = _rem(r, nq)
         return jnp.maximum(qi, first_q(ki)) if causal else qi
 
-    def q_map2(bi, hk, ki, r):
-        return (bi, hk * (g // gh) + r // nq, clamp_q(ki, r), 0)
+    def q_map(bi, hk, ki, r):
+        return (bi, hk * (g // gh) + _div(r, nq), clamp_q(ki, r), 0)
 
-    def kv_map2(bi, hk, ki, r):
+    def kv_map(bi, hk, ki, r):
         return (bi, hk, ki, 0)
 
-    def sel_map2(bi, hk, ki, r):
-        return (bi, clamp_q(ki, r), ki // per_tile)
-    def row2(d):
-        return pl.BlockSpec((1, gh, bq, d), q_map2)
+    def sel_map(bi, hk, ki, r):
+        return (bi, clamp_q(ki, r), _div(ki, per_tile))
 
-    def kv2(d):
-        return pl.BlockSpec((1, 1, bk, d), kv_map2)
-    col2 = pl.BlockSpec((1, gh, bq, 1), q_map2)
+    def row(d):
+        return pl.BlockSpec((1, n, bq, d), q_map)
+
+    def kv(d):
+        return pl.BlockSpec((1, kh, bk, d), kv_map)
+    col = pl.BlockSpec((1, n, bq, 1), q_map)
     nr = g // gh * nq
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, nq=nq, nr=nr, **common),
-        grid=(b, h // g, nk, nr),
-        in_specs=([pl.BlockSpec((1, bq, LANES), sel_map2)] if has_sel else [])
-        + [row2(dk), kv2(dk), kv2(dv), row2(dv), col2, col2],
-        out_specs=[kv2(dk), kv2(dv)],
+    return pl.pallas_call(
+        functools.partial(_dkv_kernel, scale=scale, causal=causal,
+                          has_sel=has_sel, kh=kh, gh=gh, bq=bq, bk=bk, nq=nq,
+                          nr=nr, in_dtype=q.dtype),
+        grid=(b, h // (g * kh), nk, nr),
+        in_specs=([pl.BlockSpec((1, bq, LANES), sel_map)] if has_sel else [])
+        + [row(dk), kv(dk), kv(dv), row(dv), col, col],
+        out_specs=[kv(dk), kv(dv)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, dk), jnp.float32),
-                        pltpu.VMEM((bk, dv), jnp.float32), pairs],
-        compiler_params=_params(), interpret=interpret,
-    )(*(head + (q, k, v, dout, lse, delta)))
-    return dq, dk, dv
+        scratch_shapes=[pltpu.VMEM((kh * bk, dk), jnp.float32),
+                        pltpu.VMEM((kh * bk, dv), jnp.float32),
+                        pltpu.VMEM((bq, bk), jnp.float32)],
+        compiler_params=_params(vmem), interpret=interpret,
+    )(*_given(selected, q, k, v, dout, lse, delta))
 
 
-def _scale(q, scale):
-    return scale if scale is not None else q.shape[-1] ** -0.5
+@functools.lru_cache(maxsize=_TRACES_KEPT)
+def _traced(call, operands, **statics):
+    """The jaxpr of ``call`` — one of the three ``pallas_call``s above — on
+    ``operands`` (a ``ShapeDtypeStruct`` each, None for no selection) under
+    ``statics``, traced the first time the signature is asked for."""
+    note_kernel_trace("streamed_attention", "traces")
+    closed = jax.make_jaxpr(functools.partial(call, **statics))(*operands)
+    assert not closed.consts, "a streamed kernel's trace holds no arrays"
+    return closed.jaxpr
+
+
+def _run(call, operands, **statics):
+    """``call(*operands, **statics)`` by the signature's one jaxpr: every
+    site of a step program binds the SAME ``pallas_call`` equation, under
+    its own name stack, so jax lowers a distinct kernel to Mosaic once
+    (``mlir._cached_lowering`` is keyed on the equation's params, and a
+    ``pallas_call`` built anew carries new index maps and a new partial of
+    its kernel: eighteen traces and eighteen lowerings for the three
+    kernels of a six-block step)."""
+    note_kernel_trace("streamed_attention", "sites")
+    jaxpr = _traced(call, tuple(
+        None if x is None else jax.ShapeDtypeStruct(x.shape, x.dtype)
+        for x in operands), **statics)
+    return jax.core.eval_jaxpr(jaxpr, (), *_given(*operands))
+
+
+def _statics(q, k, v, causal, scale, interpret):
+    return dict(heads=step_heads(q, k, v), vmem=_VMEM_BUDGET,
+                causal=bool(causal),
+                scale=float(q.shape[-1] ** -0.5 if scale is None else scale),
+                interpret=bool(interpret))
 
 
 def forward(q, k, v, selected, causal=False, scale=None, interpret=False):
@@ -446,14 +568,22 @@ def forward(q, k, v, selected, causal=False, scale=None, interpret=False):
     Dv]``; ``selected`` the packed key mask ``[B, T, W]`` int32 or None.
     Returns the output ``[B, H, T, Dv]`` in q's dtype and the rows'
     log-sum-exp ``[B, H, T, 1]`` float32, which ``backward`` wants back."""
-    return _forward(q, k, v, selected, causal, _scale(q, scale), interpret)
+    out, lse = _run(_forward, (selected, q, k, v),
+                    **_statics(q, k, v, causal, scale, interpret))
+    return out, lse
 
 
 def backward(q, k, v, selected, out, lse, dout, causal=False, scale=None,
              interpret=False):
     """(dQ, dK, dV) from the forward's operands and results."""
-    return _backward(q, k, v, selected, out, lse, dout, causal,
-                     _scale(q, scale), interpret)
+    dout = dout.astype(q.dtype)
+    delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), -1,
+                    keepdims=True)
+    operands = (selected, q, k, v, dout, lse, delta)
+    statics = _statics(q, k, v, causal, scale, interpret)
+    (dq,) = _run(_dq, operands, **statics)
+    dk, dv = _run(_dkv, operands, **statics)
+    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))

@@ -186,14 +186,24 @@ def _join(k_nope, kr):
         [k_nope, jnp.broadcast_to(kr, k_nope.shape[:3] + kr.shape[3:])], -1)
 
 
-@pytest.mark.parametrize("t,causal", [(256, True), (384, True), (384, False)])
-def test_streamed_kernel_with_192_wide_keys_matches_the_xla_body(t, causal):
+@pytest.mark.parametrize("t,causal,h,a_step", [
+    (256, True, 2, 2), (384, True, 2, 2), (384, False, 2, 2),
+    # more than one block of heads a grid: 8 heads by 4, 6 by 3 and by 2
+    (384, True, 8, 4), (384, False, 8, 4), (384, True, 6, 3),
+    (384, True, 6, 2)])
+def test_streamed_kernel_with_192_wide_keys_matches_the_xla_body(
+        t, causal, h, a_step, monkeypatch):
     """Forward and the gradients with respect to q, each head's own key
-    part, the SHARED rotary key part (the heads' sum) and v."""
-    q, k_nope, kr, v = _latent_qkv(t=t)
+    part, the SHARED rotary key part (the heads' sum) and v; ``a_step``
+    plain heads a grid step (the VMEM budget is what that many take)."""
+    q, k_nope, kr, v = _latent_qkv(h=h, t=t)
     scale = 192 ** -0.5
     assert sa.supported(q.shape, q.shape, jnp.float32, causal, False, 0.0,
                         128)
+    block = sa._pick_blocks(t)
+    monkeypatch.setattr(sa, "_VMEM_BUDGET", sa._step_bytes(
+        1, block, block, 192, 4, 128, a_step))
+    assert sa.step_heads(q, q, v) == (a_step, 1)
 
     def xla(q, k_nope, kr, v):
         return fa.reference_attention(q, _join(k_nope, kr), v, None, None,
@@ -203,7 +213,7 @@ def test_streamed_kernel_with_192_wide_keys_matches_the_xla_body(t, causal):
         return sa.streamed_attention(q, _join(k_nope, kr), v, None, causal,
                                      scale, True)
     want = xla(q, k_nope, kr, v)
-    assert want.shape == (1, 2, t, 128)
+    assert want.shape == (1, h, t, 128)
     np.testing.assert_allclose(kernel(q, k_nope, kr, v), want, rtol=1e-5,
                                atol=1e-5)
     ct = jnp.asarray(_rand(want.shape, 12))
@@ -212,6 +222,45 @@ def test_streamed_kernel_with_192_wide_keys_matches_the_xla_body(t, causal):
     got = jax.grad(lambda *a: jnp.sum(kernel(*a) * ct), (0, 1, 2, 3))(*args)
     for a, b in zip(got, want):
         assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("selected", [False, True])
+@pytest.mark.parametrize("dk,dv", [(128, 128), (192, 128)])
+@pytest.mark.parametrize("a_step", [1, 2, 4, 8])
+def test_plain_heads_a_step_give_the_bits_of_one_head_a_step(
+        a_step, dk, dv, selected, monkeypatch):
+    """8 plain heads, ``a_step`` of them a grid step (the rule answers what
+    the test tells it to), causal over two by two blocks of 128, with a
+    selection or without: forward, log-sum-exp, dQ, dK and dV are, bit for
+    bit, those of one head a step — a head's arithmetic does not depend on
+    which heads share its step — and within the standing tolerance of the
+    XLA body."""
+    from paddle_tpu.ops import sparse_select as ss
+
+    h, t, scale = 8, 256, dk ** -0.5
+    q, k, v, ct = (jnp.asarray(_rand((1, h, t, w), i, 0.5))
+                   for i, w in enumerate((dk, dk, dv, dv)))
+    packed = ss.pack_key_mask(ss.topk_key_mask(
+        jnp.asarray(_rand((1, t, t), 9)), 48, True)) if selected else None
+
+    def kernels(n):
+        monkeypatch.setattr(sa, "_heads_per_step", lambda *a: (n, 1))
+        assert sa.step_heads(q, k, v) == (n, 1)
+        out, lse = sa.forward(q, k, v, packed, True, scale, True)
+        return (out, lse) + sa.backward(q, k, v, packed, out, lse, ct, True,
+                                        scale, True)
+    got, one = kernels(a_step), kernels(1)
+    for a, b in zip(got, one):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def xla(q, k, v):
+        return fa.reference_attention(q, k, v, None, None, True, 0.0, scale,
+                                      packed, True)
+    (out, lse), vjp = jax.vjp(xla, q, k, v)
+    np.testing.assert_allclose(got[0], out, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(got[1], lse, rtol=1e-5, atol=1e-5)
+    for a, b in zip(got[2:], vjp((ct, jnp.zeros_like(lse)))):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
 
@@ -250,16 +299,21 @@ def test_fused_attention_op_takes_values_narrower_than_keys(body,
 
     def bodies():
         got = compile_cache.stats()["kernel_bodies"]
+        # the note: both plain heads in one grid step, each its own K/V
+        # head; the last: calls that reached the three streamed kernels
         return (got.get("fused_attention:" + body, 0),
-                got.get("fused_attention_grad:streamed", 0))
+                got.get("fused_attention_grad:streamed", 0),
+                got.get("streamed_step:2x1", 0),
+                compile_cache.stats()["kernel_traces"].get(
+                    "streamed_attention", {}).get("sites", 0))
     before = bodies()
     got = fluid.Executor(fluid.CPUPlace()).run(
         main, feed={"q": np.asarray(q), "k": np.asarray(k),
                     "v": np.asarray(v)},
         fetch_list=[out, "q@GRAD", "k@GRAD", "v@GRAD"])
     after = bodies()
-    assert (after[0] - before[0], after[1] - before[1]) == (
-        (1, 1) if body == "streamed" else (2, 0))
+    assert tuple(x - y for x, y in zip(after, before)) == (
+        (1, 1, 1, 3) if body == "streamed" else (2, 0, 0, 0))
 
     def dense(q, k, v):
         return fa.reference_attention(q, k, v, None, None, True, 0.0,

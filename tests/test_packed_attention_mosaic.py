@@ -76,14 +76,17 @@ def test_streamed_kernel_compiles_for_v5e_at_the_long_document_shape(
     rolled loop over the eight heads a grid step serves and the VMEM their
     ``[8, 512, 128]`` blocks, padded columns and scratch take (more than
     the default scoped limit: the kernels state their own).  And the same
-    over 32 key/value heads: plain heads, a loop of one."""
+    over 32 key/value heads: plain heads, several a grid step, each with
+    its own K/V block."""
     from paddle_tpu.ops import sparse_select as ss
     from paddle_tpu.ops.pallas import streamed_attention as sa
 
     b, h, t, d = 1, 32, 8192, 128
     assert sa.supported((b, h, t, d), (b, hk, t, d), jnp.bfloat16, True,
                         False, 0.0)
-    assert sa._heads_per_step(h // hk, 512, 512, d, 2) == h // hk
+    kh, gh = sa._heads_per_step(h // hk, hk, 512, 512, d, 2)
+    # 8 query heads a step over their one K/V head; plain heads by the block
+    assert (kh, gh) == (1, 8) if hk == 4 else (kh > 1 and gh == 1)
 
     def step(q, k, v, sel, ct):
         out, vjp = jax.vjp(
@@ -117,14 +120,17 @@ def test_streamed_kernel_compiles_for_v5e_at_the_latent_attention_shape(
     plain heads, keys 192 wide over values 128 wide, T = 8192, bf16, no
     selection — forward, dQ and dK/dV: Mosaic has to accept a block whose
     last axis is the array's full 192 (two lane tiles, the second half
-    full) and the contraction over it, a ``[512, 192]`` float32 dQ
-    accumulator beside a ``[512, 128]`` one for dV, and nowhere the
+    full) and the contraction over it, several heads a grid step with a
+    ``[512, 192]`` float32 dK accumulator beside a ``[512, 128]`` one for
+    dV each, all inside the VMEM limit the kernels state, and nowhere the
     ``[32, 8192, 8192]`` scores."""
     from paddle_tpu.ops.pallas import streamed_attention as sa
 
     b, h, t, dk, dv = 1, 32, 8192, 192, 128
     assert sa.supported((b, h, t, dk), (b, h, t, dk), jnp.bfloat16, True,
                         False, 0.0, dv)
+    kh, gh = sa._heads_per_step(1, h, 512, 512, dk, 2, dv)
+    assert kh > 1 and h % kh == 0 and gh == 1
 
     def step(q, k, v, ct):
         out, lse = sa.forward(q, k, v, None, True, dk ** -0.5, False)
@@ -147,6 +153,148 @@ def test_streamed_kernel_compiles_for_v5e_at_the_latent_attention_shape(
     # (float32 scores would be 8.6 GB; the log-sum-exp and delta columns
     # pad to 128 lanes, 134 MB each, and so do the backward's stagings)
     assert compiled.memory_analysis().temp_size_in_bytes < 1024 * 1024 * 1024
+
+
+def _blocks_of_attention(sa, n, selected, scale, interpret):
+    """A function of (q, k, v, dO) -> n x (out, dQ, dK, dV): ``n`` blocks'
+    forward and backward through the streamed kernels, each call under its
+    own Fluid scope as the step program's ops are."""
+    def step(q, k, v, ct):
+        got = []
+        for i in range(n):
+            with jax.named_scope("fluid[fused_attention]out_%d" % i):
+                out, lse = sa.forward(q, k, v, selected, True, scale,
+                                      interpret)
+            with jax.named_scope("fluid[fused_attention_grad]q_%d.GRAD" % i):
+                got.append((out,) + sa.backward(q, k, v, selected, out, lse,
+                                                ct, True, scale, interpret))
+            q = q + got[-1][1]
+        return got
+    return step
+
+
+def _kernel_traces():
+    from paddle_tpu import compile_cache
+
+    counts = compile_cache.stats()["kernel_traces"].get(
+        "streamed_attention", {"sites": 0, "traces": 0})
+    return counts["sites"], counts["traces"]
+
+
+def test_a_step_program_traces_and_lowers_each_streamed_kernel_once(one_chip):
+    """Six blocks of forward + backward at the latent cell's shape in one
+    jitted function, lowered for the described v5e: 18 call sites reach the
+    kernels and 3 jaxprs are made (``compile_cache.stats()``); the lowered
+    text holds 18 custom calls, each under ITS OWN block's Fluid scope —
+    the device trace's readers find the kernels by that scope, which is why
+    the kernels may not move into shared jitted functions.  A second
+    signature adds exactly three traces."""
+    import re
+
+    from paddle_tpu.ops.pallas import streamed_attention as sa
+
+    b, h, t, dk, dv, blocks = 1, 32, 8192, 192, 128, 6
+    sa._traced.cache_clear()
+
+    def arg(t, width):
+        return jax.ShapeDtypeStruct((b, h, t, width), jnp.bfloat16,
+                                    sharding=one_chip)
+    step = _blocks_of_attention(sa, blocks, None, dk ** -0.5, False)
+    sites, traces = _kernel_traces()
+    text = jax.jit(step).lower(arg(t, dk), arg(t, dk), arg(t, dv),
+                               arg(t, dv)).as_text(debug_info=True)
+    assert _kernel_traces() == (sites + 3 * blocks, traces + 3)
+    calls = re.findall(
+        r"stablehlo\.custom_call @tpu_custom_call.*?loc\((#loc\d+)\)\s*$",
+        text, re.M)
+    locs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, re.M))
+    assert len(calls) == 3 * blocks
+    scopes = [re.search(r"fluid\[\w+\][\w.]+", locs[c]).group(0)
+              for c in calls]
+    assert scopes == [s for i in range(blocks) for s in
+                      ["fluid[fused_attention]out_%d" % i]
+                      + 2 * ["fluid[fused_attention_grad]q_%d.GRAD" % i]]
+    # another length: three more jaxprs; the first length again: none
+    jax.jit(step).lower(arg(t // 2, dk), arg(t // 2, dk), arg(t // 2, dv),
+                        arg(t // 2, dv))
+    assert _kernel_traces() == (sites + 6 * blocks, traces + 6)
+    jax.jit(lambda *a: step(*a)).lower(arg(t, dk), arg(t, dk), arg(t, dv),
+                                       arg(t, dv))
+    assert _kernel_traces() == (sites + 9 * blocks, traces + 6)
+
+
+def test_a_program_traced_again_reuses_the_streamed_kernels_jaxprs():
+    """The same function traced a second time (a new ``jax.jit`` of it, as
+    a program lowered again is) makes no new jaxpr and, interpreted, gives
+    the first trace's results; a selection is another signature: three
+    more."""
+    import numpy as np
+
+    from paddle_tpu.ops import sparse_select as ss
+    from paddle_tpu.ops.pallas import streamed_attention as sa
+
+    h, t, dk, dv = 4, 256, 192, 128
+    args = [jax.random.normal(jax.random.key(i), (1, h, t, w), jnp.float32)
+            for i, w in enumerate((dk, dk, dv, dv))]
+    sa._traced.cache_clear()
+    step = _blocks_of_attention(sa, 2, None, dk ** -0.5, True)
+    sites, traces = _kernel_traces()
+    first = jax.jit(step)(*args)
+    assert _kernel_traces() == (sites + 6, traces + 3)
+    again = jax.jit(lambda *a: step(*a))(*args)
+    assert _kernel_traces() == (sites + 12, traces + 3)
+    for a, b in zip(jax.tree.leaves(first), jax.tree.leaves(again)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    words = ss.pack_key_mask(ss.topk_key_mask(
+        jax.random.normal(jax.random.key(9), (1, t, t)), 64, True))
+    jax.jit(_blocks_of_attention(sa, 2, words, dk ** -0.5, True))(*args)
+    assert _kernel_traces() == (sites + 18, traces + 6)
+
+
+def _primitives(jaxpr):
+    """Every primitive's name in ``jaxpr``, in the jaxprs its equations
+    hold (a kernel's body, its loops and branches) and in a
+    ``pallas_call``'s index maps."""
+    def inside(value):
+        if hasattr(value, "block_mappings"):                # a grid mapping
+            for m in value.block_mappings:
+                yield from _primitives(m.index_map_jaxpr.jaxpr)
+        elif hasattr(value, "eqns"):
+            yield from _primitives(value)
+        elif hasattr(value, "jaxpr"):
+            yield from _primitives(value.jaxpr)
+        elif isinstance(value, (tuple, list)):
+            for x in value:
+                yield from inside(x)
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for value in eqn.params.values():
+            yield from inside(value)
+
+
+@pytest.mark.parametrize("hk,selected", [(32, False), (4, True), (32, True)])
+def test_no_index_of_the_streamed_kernels_divides_through_sign(hk, selected):
+    """``//`` and ``%`` on a traced index round to the floor through
+    ``sign`` and a select — a dozen scalar operations each in the kernel's
+    text, 480 of them once in a head loop (6.6 s of a step's lowering).
+    The kernels' bodies and index maps divide with ``lax.div`` /
+    ``lax.rem``: no ``sign``, no ``floor`` in any of the three."""
+    from paddle_tpu.ops import sparse_select as ss
+    from paddle_tpu.ops.pallas import streamed_attention as sa
+
+    h, t, d = 32, 8192, 128
+    q, ct = (jax.ShapeDtypeStruct((1, h, t, d), jnp.bfloat16),) * 2
+    k = v = jax.ShapeDtypeStruct((1, hk, t, d), jnp.bfloat16)
+    words = jax.ShapeDtypeStruct((1, t, ss.packed_width(t)), jnp.int32)
+
+    def step(q, k, v, ct, words=None):
+        out, lse = sa.forward(q, k, v, words, True, None, False)
+        return sa.backward(q, k, v, words, out, lse, ct, True, None, False)
+    jaxpr = jax.make_jaxpr(step)(q, k, v, ct,
+                                 *([words] if selected else [])).jaxpr
+    assert [e.primitive.name for e in jaxpr.eqns].count("pallas_call") == 3
+    inside = set(_primitives(jaxpr))
+    assert {"div", "rem"} & inside and not {"sign", "floor"} & inside
 
 
 @pytest.mark.parametrize("causal", [True, False])

@@ -337,8 +337,10 @@ def _low_scoring_keys(t, first, last, seed=11):
         -100.0)
 
 
-# (query heads, key/value heads, T, D, causal, selection): the selection is
-# None, or (low-scoring keys' range, keys a query selects)
+# (query heads, key/value heads, T, D, causal, selection[, plain heads a grid
+# step]): the selection is None, or (low-scoring keys' range, keys a query
+# selects); with a number of heads a step, the VMEM budget is what that many
+# plain heads take, and the rule has to find it
 _STREAMED_CASES = {
     # T = 512 is ONE 512 x 512 block pair, two heads a key/value head
     "one_block_selected": (4, 2, 512, 128, True, ((128, 256), 64)),
@@ -360,17 +362,27 @@ _STREAMED_CASES = {
     # is two by two blocks of 512; T = 768 three by three of 256
     "g16_split_selected": (16, 1, 1024, 256, True, ((512, 640), 256)),
     "blocks_of_256": (4, 1, 768, 128, True, ((256, 512), 128)),
+    # plain heads, several a grid step and more than one block of heads a
+    # grid (each head its own K/V block, dK and dV of the step): 8 heads by
+    # 4, 6 by 3 and by 2; three by three blocks of 128
+    "plain8_by4_causal": (8, 8, 384, 128, True, None, 4),
+    "plain8_by4_selected": (8, 8, 384, 128, True, ((128, 256), 64), 4),
+    "plain8_by4_dense": (8, 8, 384, 128, False, None, 4),
+    "plain6_by3_selected": (6, 6, 384, 128, True, ((0, 128), 64), 3),
+    "plain6_by3_noncausal_selected": (6, 6, 384, 128, False,
+                                      ((128, 256), 64), 3),
+    "plain6_by2_causal": (6, 6, 384, 128, True, None, 2),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_STREAMED_CASES))
-def test_streamed_kernel_interpreted_matches_the_xla_body(case):
+def test_streamed_kernel_interpreted_matches_the_xla_body(case, monkeypatch):
     """Forward and the three gradients against ``reference_attention``.
     With a selection, a range of keys scores so low that queries with
     enough other candidates select none of them: whole key blocks then hold
     no selected key for the later query blocks, and must leave the online
     softmax as it was."""
-    h, hk, t, d, causal, selection = _STREAMED_CASES[case]
+    h, hk, t, d, causal, selection, *a_step = _STREAMED_CASES[case]
     q, k, v = _qkv(h=h, hk=hk, t=t, d=d)
     packed = None
     if selection is not None:
@@ -380,8 +392,12 @@ def test_streamed_kernel_interpreted_matches_the_xla_body(case):
         assert not bool(sel[0, last + keep:, first:last].any())
         packed = ss.pack_key_mask(sel)
     g, block = h // hk, sa._pick_blocks(t)
-    heads = sa._heads_per_step(g, block, block, d, 4)
-    assert heads == (8 if case == "g16_split_selected" else g)
+    if a_step:
+        monkeypatch.setattr(sa, "_VMEM_BUDGET", sa._step_bytes(
+            1, block, block, d, 4, kh=a_step[0]))
+    assert sa._heads_per_step(g, hk, block, block, d, 4) == (
+        (1, 8 if case == "g16_split_selected" else g) if g > 1
+        else (a_step[0] if a_step else hk, 1))
 
     def xla(q, k, v):
         return fa.reference_attention(q, k, v, None, None, causal, 0.0, None,
@@ -398,23 +414,53 @@ def test_streamed_kernel_interpreted_matches_the_xla_body(case):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("g,block,d,itemsize", [
-    (8, 512, 128, 2),                # the long-document cell: all 8 a step
-    (1, 512, 128, 2),                # plain heads: a loop of one
-    (16, 512, 256, 4), (6, 512, 256, 4), (11, 512, 256, 4), (64, 512, 128, 2),
-    (32, 128, 128, 2),
+@pytest.mark.parametrize("g,hk,block,dk,dv,itemsize", [
+    (8, 4, 512, 128, 128, 2),        # the long-document cell: all 8 a step
+    (16, 1, 512, 256, 256, 4), (6, 2, 512, 256, 256, 4),
+    (11, 3, 512, 256, 256, 4), (64, 1, 512, 128, 128, 2),
+    (32, 2, 128, 128, 128, 2),
+    # plain heads: several K/V heads a step, each with its own blocks
+    (1, 32, 512, 192, 128, 2),       # the latent-attention cell
+    (1, 32, 512, 128, 128, 2), (1, 6, 512, 256, 256, 4),
+    (1, 7, 512, 256, 256, 4), (1, 3, 128, 128, 128, 2), (1, 1, 512, 128, 128, 2),
 ])
-def test_heads_per_step_is_a_divisor_inside_the_vmem_budget(g, block, d,
-                                                            itemsize):
-    heads = sa._heads_per_step(g, block, block, d, itemsize)
-    assert 1 <= heads <= g and g % heads == 0
-    fits = [n for n in range(1, g + 1) if g % n == 0 and sa._step_bytes(
-        n, block, block, d, itemsize) <= sa._VMEM_BUDGET]
-    assert fits and heads == max(fits)
-    if (g, block, d, itemsize) == (8, 512, 128, 2):
-        assert heads == 8
-    if (g, d, itemsize) == (11, 256, 4):
-        assert heads == 1            # 11 do not fit, and 11 is prime
+def test_heads_per_step_is_a_divisor_inside_the_vmem_budget(g, hk, block, dk,
+                                                            dv, itemsize):
+    """Heads that share a K/V head: one K/V head a step and the most of its
+    group that fit (the answers PR 29's rule gave).  Plain heads: the most
+    K/V heads that fit, each counted with its own K and V blocks and, by
+    dK/dV, its own accumulators and output blocks."""
+    def fits(kh, gh):
+        return sa._step_bytes(gh, block, block, dk, itemsize, dv,
+                              kh) <= sa._VMEM_BUDGET
+    kh, gh = sa._heads_per_step(g, hk, block, block, dk, itemsize, dv)
+    assert g % gh == 0 and hk % kh == 0 and 1 in (kh, gh)
+    if g > 1:
+        assert kh == 1 and gh == max(
+            n for n in range(1, g + 1) if g % n == 0 and (n == 1 or
+                                                          fits(1, n)))
+    else:
+        assert kh == max(n for n in range(1, hk + 1)
+                         if hk % n == 0 and (n == 1 or fits(n, 1)))
+    expect = {(8, 4, 128): (1, 8),                   # Keye's, as it was
+              (16, 1, 256): (1, 8), (6, 2, 256): (1, 6),
+              (11, 3, 256): (1, 1),  # 11 do not fit, and 11 is prime
+              (64, 1, 128): (1, 16), (32, 2, 128): (1, 32),
+              (1, 7, 256): (1, 1),   # nor do 7 plain heads in float32
+              (1, 3, 128): (3, 1), (1, 1, 128): (1, 1),
+              # the latent cell's 32 plain heads at 192 / 128 bf16, 512
+              # blocks: 8 a step fit (41.5 MiB of the 48), 16 do not
+              (1, 32, 192): (8, 1), (1, 32, 128): (8, 1)}
+    if (g, hk, dk) in expect:
+        assert (kh, gh) == expect[(g, hk, dk)]
+    if (g, hk, dk, dv) == (1, 32, 192, 128):
+        assert fits(8, 1) and not fits(16, 1)
+    # a K/V head of the step counts: its K and V blocks twice buffered,
+    # float32 dK and dV and their output blocks twice buffered
+    k_and_v = block * (sa._lanes(dk) + sa._lanes(dv))
+    one, two = (sa._step_bytes(1, block, block, dk, itemsize, dv, n)
+                for n in (8, 9))
+    assert two - one >= k_and_v * (2 * itemsize + 4 + 2 * itemsize)
 
 
 def test_streamed_supported_says_what_it_takes():

@@ -46,6 +46,7 @@ from paddle_tpu.contrib import mixed_precision
 from paddle_tpu.models import transformer as tfm
 from paddle_tpu.monitor import program_profile
 from paddle_tpu.ops import sparse_select
+from paddle_tpu.ops.activation import rotary_tables
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import layer_norm as pallas_ln
 from paddle_tpu.ops.pallas import packed_attention as pa
@@ -405,19 +406,27 @@ def same_bits_as_one_head_a_step(name, args, words):
     return "bit-equal"
 
 
-def plain_heads_through_the_op(h, t, dk, dv):
+def plain_heads_through_the_op(h, t, dk, dv, rope_theta=None):
     """``h`` plain heads through ``fused_attention`` and its gradient op in
     a program: the trace takes the streamed kernels, forward and backward,
     and says how many heads a grid step serves (``streamed_step:<K/V
-    heads>x<query heads of each>``) — several, and not all ``h``.  Out and
-    the three gradients against the XLA body."""
+    heads>x<query heads of each>``) — several, and not all ``h``.  With
+    ``rope_theta`` the program turns q and k by ``rotary_embedding``
+    (rotate-half, the whole head) before the kernels, as a decoder block
+    does.  Out and the three gradients against the XLA body."""
+    def rotate(x):
+        return x if rope_theta is None else to_bhtd(
+            fluid.layers.rotary_embedding(to_bhtd(x), theta=rope_theta))
+
+    def to_bhtd(x):
+        return fluid.layers.transpose(x, perm=[0, 2, 1, 3])
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
         q, k, v = (fluid.layers.data(n, shape=[h, t, w], dtype="float32")
                    for n, w in (("q", dk), ("k", dk), ("v", dv)))
         for x in (q, k, v):
             x.stop_gradient = False
-        o = fluid.layers.fused_attention(q, k, v, causal=True,
+        o = fluid.layers.fused_attention(rotate(q), rotate(k), v, causal=True,
                                          scale=dk ** -0.5)
         fluid.append_backward(fluid.layers.reduce_sum(
             fluid.layers.elementwise_mul(o, o)))
@@ -437,14 +446,22 @@ def plain_heads_through_the_op(h, t, dk, dv):
         raise AssertionError("plain heads through the op: bodies %s, step %s"
                              % (bodies, step))
 
+    def turned(x):
+        if rope_theta is None:
+            return x
+        cos, sin = rotary_tables(t, dk, rope_theta)
+        half = jnp.concatenate([-x[..., dk // 2:], x[..., :dk // 2]], -1)
+        return x * cos + half * sin
+
     def reference(q, k, v):
-        return fa.reference_attention(q, k, v, None, None, True, 0.0,
-                                      dk ** -0.5)
+        return fa.reference_attention(turned(q), turned(k), v, None, None,
+                                      True, 0.0, dk ** -0.5)
     want = (jax.jit(reference)(*args),) + jax.jit(jax.grad(
         lambda *a: jnp.sum(reference(*a) ** 2), (0, 1, 2)))(*args)
     errs = [close(g, w, TOL_KERNEL["matmul"], "plain heads through the op")
             for g, w in zip(got, want)]
-    log("plain heads through the op: %s, errors %s" % (step, errs))
+    log("plain heads through the op (%d x %d/%d, T %d, rotary %s): %s, "
+        "errors %s" % (h, dk, dv, t, rope_theta, step, errs))
     return sorted(step)[0]
 
 
@@ -552,6 +569,10 @@ def phase_kernels():
     streamed("streamed_attention_latent", h, h, 2048, 192, None, 128)
     out["streamed_attention_latent"]["step"] = plain_heads_through_the_op(
         h, 2048, 192, 128)
+    # the looped decoder's geometry: 16 plain heads, keys and values both
+    # 128 wide, rotary on the whole head, T = 4096
+    out["streamed_attention_plain_128"] = {"step": plain_heads_through_the_op(
+        16, 4096, 128, 128, rope_theta=1e6)}
 
     rows, d_model = BATCH * SEQ, WIDTH["d_model"]
     gamma = jnp.linspace(0.5, 1.5, d_model, dtype=jnp.float32)

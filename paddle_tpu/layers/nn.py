@@ -18,6 +18,7 @@ __all__ = [
     "cross_entropy",
     "square_error_cost",
     "softmax_with_cross_entropy",
+    "exit_gate_loss",
     "fused_attention",
     "paged_attention",
     "rms_norm",
@@ -231,6 +232,39 @@ def softmax_with_cross_entropy(
     if return_softmax:
         return loss, softmax_out
     return loss
+
+
+def exit_gate_loss(hiddens, token_losses, beta=0.0, param_attr=None,
+                   bias_attr=None, name=None):
+    """The expected loss of a model that runs its layers ``P`` times and
+    may leave after any pass: ``token_losses`` are the passes' per-token
+    cross entropies ``[B, T, 1]`` (NOT their means), ``hiddens`` the
+    passes' states ``[B, T, D]``.  A learned gate ``lam_t = sigmoid(h_t w
+    + b)`` (``w`` [D, 1], ``b`` [1], one gate for all passes) gives each
+    token its exit distribution ``p_1 = lam_1, p_t = lam_t prod_{j<t} (1 -
+    lam_j)``, the last pass taking the rest (so the last state's gate
+    enters no loss, and the op is not handed that state); the loss is the
+    mean over tokens of ``sum_t p_t CE_t - beta H(p)``, in float32.
+    Returns ``(loss [1], stats [2P])``: ``stats``
+    holds the passes' mean cross entropy and then their mean exit mass,
+    and carries no gradient."""
+    helper = LayerHelper("exit_gate_loss", param_attr=param_attr,
+                         bias_attr=bias_attr, name=name)
+    w = helper.create_parameter(attr=helper.param_attr,
+                                shape=[hiddens[0].shape[-1], 1],
+                                dtype="float32")
+    b = helper.create_parameter(attr=helper.bias_attr, shape=[1],
+                                dtype="float32", is_bias=True)
+    loss = helper.create_variable_for_type_inference(dtype="float32")
+    stats = helper.create_variable_for_type_inference(dtype="float32")
+    stats.stop_gradient = True
+    helper.append_op(
+        type="exit_gate_loss",
+        inputs={"H": list(hiddens[:-1]), "CE": list(token_losses),
+                "W": [w], "B": [b]},
+        outputs={"Loss": [loss], "Stats": [stats]},
+        attrs={"beta": float(beta)})
+    return loss, stats
 
 
 def fused_attention(q, k, v, k_len=None, causal=False, dropout_rate=0.0,

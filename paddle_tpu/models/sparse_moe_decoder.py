@@ -1,9 +1,16 @@
-"""Decoder-only language models of pre-norm blocks with routed SiLU-gated
-experts, parameterised by their sizes and built from ``layers`` functions
-into a Fluid ``Program``: one family, two kinds of block.  What the kinds
-share exists once here — the routed-expert half of a block
-(``_expert_half``), the head and its loss (``_head_loss``) and the step's
-counters (``_declare_step_stats``).
+"""Decoder-only language models parameterised by their sizes and built
+from ``layers`` functions into a Fluid ``Program``: one family, three kinds
+of block.  Two are here, pre-norm blocks with routed SiLU-gated experts;
+the third, the sandwich-norm block of a looped dense decoder, is in
+:mod:`.looped_decoder`.  What the kinds share exists once, here: the
+bias-free projection (``_proj``), the SiLU-gated products of a dense FFN
+(``_gated_ffn``: the latent kind's leading dense layer and every block of
+the looped kind), the untied head's per-token cross entropy
+(``_head_token_loss``: its mean is the two expert kinds' loss, the looped
+kind weighs the passes' by an exit gate) and the declaration of the step's
+counters (``_declare_step_stats``).  The two expert kinds also share the
+routed-expert half of a block (``_expert_half``) and its counters
+(``_moe_step_stats``).
 
 **Selected-key blocks** (``decoder_block`` / ``decoder_lm``), for ``x``
 [B, T, D]:
@@ -108,12 +115,19 @@ def _expert_half(x, prefix, expert_share, expert_width, experts_per_token,
                "max_expert_tokens": layers.reduce_max(counts, keep_dim=True)}
 
 
+def _head_token_loss(x, labels, vocab_size):
+    """The untied head ``out_w`` (one parameter, whoever calls) over the
+    normed state ``x`` and each token's cross entropy [B, T, 1] in
+    float32."""
+    return layers.softmax_with_cross_entropy(
+        _proj(x, vocab_size, "out_w"), labels)
+
+
 def _head_loss(x, labels, vocab_size, rms_eps, norm_name):
-    """Final RMSNorm (``norm_name``), the untied head ``out_w`` (one
-    parameter, whoever calls) and the mean cross entropy in float32."""
+    """Final RMSNorm (``norm_name``), the head and the mean cross
+    entropy."""
     x = layers.rms_norm(x, rms_eps, ParamAttr(name=norm_name))
-    logits = _proj(x, vocab_size, "out_w")
-    return layers.mean(layers.softmax_with_cross_entropy(logits, labels))
+    return layers.mean(_head_token_loss(x, labels, vocab_size))
 
 
 def _over_layers(stats, key, reduce):
@@ -121,20 +135,25 @@ def _over_layers(stats, key, reduce):
                   keep_dim=True)
 
 
-def _declare_step_stats(loss, stats, last, names):
-    """The step's counters as ONE float32 variable a caller fetches with
-    the loss: pairs routed and computed (the layers' sums), the fullest
+def _declare_step_stats(loss, step_stats, names):
+    """Declare ``step_stats``, ONE float32 variable a caller fetches with
+    the loss, as the step's counters on the loss's program, under
+    ``names`` (``Program.step_stats``)."""
+    step_stats.stop_gradient = True
+    loss.block.program.step_stats = (step_stats.name, names)
+    return step_stats
+
+
+def _moe_step_stats(stats, last):
+    """The expert blocks' counters and one more [1] variable as one [4]
+    variable: pairs routed and computed (the layers' sums), the fullest
     held expert's tokens (the layers' largest) and what ``last()`` builds
-    after them; declared on
-    the program under ``names`` (``Program.step_stats``)."""
-    step_stats = layers.concat([
+    after them."""
+    return layers.concat([
         _over_layers(stats, "pairs_routed", layers.reduce_sum),
         _over_layers(stats, "pairs_computed", layers.reduce_sum),
         _over_layers(stats, "max_expert_tokens", layers.reduce_max),
         last()], axis=0)
-    step_stats.stop_gradient = True
-    loss.block.program.step_stats = (step_stats.name, names)
-    return step_stats
 
 
 def decoder_block(x, prefix, n_head, n_kv_head, head_dim, expert_share,
@@ -218,19 +237,23 @@ def decoder_lm(tokens, labels, vocab_size, n_layer, d_model, n_head,
             index_topk, rope_theta, rms_eps, expert_tile)
         stats.append(st)
     loss = _head_loss(x, labels, vocab_size, rms_eps, "ln_f.g")
-    step_stats = _declare_step_stats(
-        loss, stats, lambda: _over_layers(stats, "selected_share",
-                                          layers.reduce_mean), STEP_STATS)
+    step_stats = _declare_step_stats(loss, _moe_step_stats(
+        stats, lambda: _over_layers(stats, "selected_share",
+                                    layers.reduce_mean)), STEP_STATS)
     return loss, step_stats, stats[0]["selected"]
 
 
+def _gated_ffn(h, prefix, width):
+    """``(silu(h Wg) * (h Wu)) Wd``: a dense SiLU-gated FFN's products."""
+    return _proj(layers.swiglu(_proj(h, width, prefix + "mlp.gate"),
+                               _proj(h, width, prefix + "mlp.up")),
+                 h.shape[-1], prefix + "mlp.down")
+
+
 def _dense_half(x, prefix, width, rms_eps):
-    """``x += (silu(h2 Wg) * (h2 Wu)) Wd`` over ``h2 = rms_norm(x)``."""
+    """``x += gated_ffn(rms_norm(x))``."""
     h2 = layers.rms_norm(x, rms_eps, ParamAttr(name=prefix + "ln2.g"))
-    y = _proj(layers.swiglu(_proj(h2, width, prefix + "mlp.gate"),
-                            _proj(h2, width, prefix + "mlp.up")),
-              x.shape[-1], prefix + "mlp.down")
-    return layers.elementwise_add(x, y)
+    return layers.elementwise_add(x, _gated_ffn(h2, prefix, width))
 
 
 def _latent_attention(x, prefix, sizes, rope_theta, rms_eps):
@@ -333,6 +356,6 @@ def latent_decoder_lm(tokens, labels, labels2, vocab_size, n_layer, n_dense,
     loss_mtp = _head_loss(y, labels2, vocab_size, rms_eps, "mtp.ln_f.g")
     loss = layers.elementwise_add(loss_main,
                                   layers.scale(loss_mtp, scale=mtp_weight))
-    return loss, _declare_step_stats(
-        loss, stats, lambda: layers.reshape(loss_mtp, shape=[1]),
+    return loss, _declare_step_stats(loss, _moe_step_stats(
+        stats, lambda: layers.reshape(loss_mtp, shape=[1])),
         LATENT_STEP_STATS)

@@ -115,6 +115,52 @@ register_op(
 )
 
 
+# -- exit_gate_loss: a looped model's expected loss over its exit gate -------
+
+def _exit_gate_infer(op, block):
+    passes = len(op.inputs["CE"])
+    if len(op.inputs.get("H", ())) != passes - 1:
+        raise ValueError(
+            "exit_gate_loss: %d passes' losses take the hidden states of "
+            "the %d passes before the last (the last pass takes the rest "
+            "of the exit mass, its own gate enters no loss), got %d"
+            % (passes, passes - 1, len(op.inputs.get("H", ()))))
+    set_output(op, block, "Loss", (1,), "float32")
+    set_output(op, block, "Stats", (2 * passes,), "float32")
+
+
+def _exit_gate_compute(ins, attrs, ctx, op_index):
+    """``Loss`` = mean over tokens of ``sum_t p_t CE_t - beta H(p)``, where
+    ``lam_t = sigmoid(H_t W + B)`` is pass t's exit gate, ``p_t = lam_t
+    prod_{j<t} (1 - lam_j)`` for t < P and ``p_P = prod_{j<P} (1 -
+    lam_j)`` the exit distribution of a token over the P passes, and
+    ``H(p) = -sum_t p_t log p_t``.  All in float32 and through the logs
+    of the gates (``log p_t = logsigmoid(z_t) + sum_{j<t} logsigmoid(-z_j)``:
+    a gate that saturates gives no ``0 log 0``); the gate's product is a
+    float32 one at the highest precision.  ``Stats`` = the P passes' mean
+    cross entropy, then their mean exit mass."""
+    ce = [c.astype(jnp.float32).reshape(-1) for c in ins["CE"]]
+    w = ins["W"][0].astype(jnp.float32)
+    b = ins["B"][0].astype(jnp.float32)
+    stay = jnp.zeros_like(ce[0])        # log prod_{j<t} (1 - lam_j)
+    logp = []
+    for h in ins["H"]:
+        z = jnp.matmul(h.astype(jnp.float32).reshape(-1, h.shape[-1]), w,
+                       precision=jax.lax.Precision.HIGHEST).reshape(-1) + b
+        logp.append(jax.nn.log_sigmoid(z) + stay)
+        stay = stay + jax.nn.log_sigmoid(-z)
+    logp = jnp.stack(logp + [stay])                          # [P, N]
+    p, ce = jnp.exp(logp), jnp.stack(ce)
+    beta = float(attrs.get("beta", 0.0))
+    loss = jnp.mean(jnp.sum(p * (ce + beta * logp), 0))
+    return {"Loss": loss.reshape(1),
+            "Stats": jnp.concatenate([jnp.mean(ce, 1), jnp.mean(p, 1)])}
+
+
+register_op("exit_gate_loss", ["H", "CE", "W", "B"], ["Loss", "Stats"],
+            infer=_exit_gate_infer, compute=_exit_gate_compute)
+
+
 # -- sigmoid_cross_entropy_with_logits --------------------------------------
 
 def _scewl_compute(ins, attrs, ctx, op_index):

@@ -57,7 +57,7 @@ def fresh_programs():
 # when they were written and is not of one with a further configuration; the
 # benchmark's own files change in a benchmark PR only, so until one
 # restates them they are expected to fail here, and the newest cell's test
-# (tests/benchmark_suite/test_bm_joyai_cell.py) asserts what they meant of
+# (tests/benchmark_suite/test_bm_ouro_cell.py) asserts what they meant of
 # the benchmark there is now.
 _RESTATED = {
     "test_bm_contract.py::test_benchmark_json_holds_the_training_cells_only":
@@ -75,6 +75,15 @@ _RESTATED = {
     "test_bm_keye_cell.py::test_benchmark_json_holds_the_training_cells":
         "pins BENCHMARK.json to PR 26's three cells and two configurations; "
         "ISSUE 30 adds joyai_llm_flash.train_mtp_8k",
+    "test_bm_joyai_cell.py::test_benchmark_json_holds_the_four_training_"
+    "cells":
+        "pins BENCHMARK.json to PR 30's four cells and three configurations "
+        "and wants JoyAI's cell last in every list; ISSUE 33 appends "
+        "ouro_2_6b.train_loop_4k after it",
+    "test_bm_contract.py::test_configuration_entry_and_file[ouro_2_6b]":
+        "the same reading of num_hidden_layers as a width; "
+        "test_bm_ouro_cell.py::test_configuration_keeps_every_published_"
+        "size holds the file to the rest of that test",
 }
 
 

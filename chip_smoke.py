@@ -45,7 +45,7 @@ from paddle_tpu import compile_cache, recordio, serving
 from paddle_tpu.contrib import mixed_precision
 from paddle_tpu.models import transformer as tfm
 from paddle_tpu.monitor import program_profile
-from paddle_tpu.ops import sparse_select
+from paddle_tpu.ops import moe, sparse_select
 from paddle_tpu.ops.activation import rotary_tables
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import layer_norm as pallas_ln
@@ -470,6 +470,64 @@ def normal(seed, shape, dtype):
                              jnp.float32).astype(dtype)
 
 
+def grouped_experts_through_the_op(name, total, chunks):
+    """``moe_expert_ffn`` and its gradient at the long-document cell's shape
+    — 8192 tokens of 2048, eight of ``total`` experts a token, the 16 held
+    ones of width 768 at tiles of 640 rows, bf16 — through the op's compute
+    as a TPU trace lowers it (the grouped kernels: the note says so) against
+    the loop body on the same chip: ``Out``, ``Pairs`` and the five
+    gradients.  Of 128 experts ~8.2 k pairs are held, the cell's own load,
+    ONE chunk of tiles; of 32, ~32.8 k pairs, which the kernels walk in
+    ``chunks`` chunks, each after the first continuing what the chunks
+    before it left of ``Out``, ``dX`` and an expert's weight gradients."""
+    from paddle_tpu.ops.pallas import grouped_experts
+    from paddle_tpu.registry import ComputeContext
+
+    n, d, f, held, k, tile = 8192, 2048, 768, 16, 8, 640
+    idx = jnp.argsort(jax.random.uniform(jax.random.key(20), (n, total)),
+                      axis=-1)[:, :k].astype(jnp.int32)
+    layout = moe.dispatch_layout(idx, 0, held, tile)
+    ins = {"X": normal(21, (n, d), jnp.bfloat16),
+           "TopkWeight": jax.random.uniform(jax.random.key(22), (n, k)),
+           "GRAD::Out": normal(23, (n, d), jnp.bfloat16)}
+    for i, (slot, shape) in enumerate((("Gate", (d, f)), ("Up", (d, f)),
+                                       ("Down", (f, d)))):
+        ins[slot] = (0.02 * normal(24 + i, (held,) + shape, jnp.float32)
+                     ).astype(jnp.bfloat16)
+    ins = {slot: [v] for slot, v in ins.items()}
+    ins.update({slot: [layout[slot]] for slot in moe._LAYOUT})
+
+    def both(platform):
+        ctx = ComputeContext(key=jax.random.key(0), platform=platform)
+        return lambda ins: (moe._ffn_compute(ins, {"tile": tile}, ctx, 0),
+                            moe._ffn_grad_compute(ins, {"tile": tile}, ctx,
+                                                  0))
+    before = kernel_bodies("moe_expert_ffn")
+    got, got_grad = mosaic_jit(both("tpu"), ins)(ins)
+    bodies = bodies_since(before, "moe_expert_ffn")
+    if set(bodies) != {"moe_expert_ffn:grouped",
+                       "moe_expert_ffn_grad:grouped"}:      # no "loop"
+        raise AssertionError("the expert ops lowered to %s" % bodies)
+    want, want_grad = jax.jit(both("cpu"))(ins)      # the loop: no Pallas
+    pairs, live = float(got["Pairs"][0]), int(layout["NumTiles"][0])
+    if pairs != float(want["Pairs"][0]) or pairs != float(
+            jnp.sum(layout["Counts"])) \
+            or abs(pairs / (n * k * held / total) - 1) > 0.15:
+        raise AssertionError("%s computed %s pairs" % (name, pairs))
+    if -(-live // grouped_experts._chunk_tiles(held)) != chunks:
+        raise AssertionError("%s: %d live tiles are not %d chunks"
+                             % (name, live, chunks))
+    tol = TOL_KERNEL["matmul"]
+    errs = {"fwd": close(got["Out"], want["Out"], tol, name + " fwd"),
+            "bwd": [close(got_grad[s][0], want_grad[s][0], tol,
+                          "%s %s" % (name, s))
+                    for s in ("GRAD::X", "GRAD::TopkWeight", "GRAD::Gate",
+                              "GRAD::Up", "GRAD::Down")],
+            "pairs": pairs, "tiles": live, "bodies": bodies}
+    log("kernel %s: %s" % (name, errs))
+    return errs
+
+
 def phase_kernels():
     out = {}
 
@@ -573,6 +631,10 @@ def phase_kernels():
     # 128 wide, rotary on the whole head, T = 4096
     out["streamed_attention_plain_128"] = {"step": plain_heads_through_the_op(
         16, 4096, 128, 128, rope_theta=1e6)}
+    out["grouped_experts"] = grouped_experts_through_the_op(
+        "grouped_experts", 128, 1)
+    out["grouped_experts_chunks"] = grouped_experts_through_the_op(
+        "grouped_experts_chunks", 32, 4)
 
     rows, d_model = BATCH * SEQ, WIDTH["d_model"]
     gamma = jnp.linspace(0.5, 1.5, d_model, dtype=jnp.float32)

@@ -21,12 +21,28 @@ Three ops:
   many tiles are live is a device scalar.
 * ``moe_expert_ffn`` — dropless grouped products: for each live tile gather
   its tokens, ``(silu(x Wg_e) * (x Wu_e)) Wd_e``, scale by the pair's routing
-  weight and add into the token's row.  A ``fori_loop`` over the live tiles
-  only (a device-side trip count), so the work follows the pairs that exist,
-  not the worst case, and no pair is ever dropped.  The gradient is a second
-  loop of the same form that recomputes a tile's products from its tokens:
-  nothing but the layout is kept for the backward.
+  weight and add into the token's row.  The work follows the pairs that
+  exist (a device-side trip count), not the worst case, and no pair is ever
+  dropped.  The gradient has the same form and recomputes a tile's products
+  from its tokens: nothing but the layout is kept for the backward.
+
+Which body ``moe_expert_ffn`` and its gradient run (``_bodies``; the note is
+``compile_cache.stats()["kernel_bodies"]["moe_expert_ffn:<body>"]``):
+
+* ``grouped`` — ``ops/pallas/grouped_experts.py``: on a TPU, one device, no
+  pinned ``FLAGS_pallas_kernels=False``, shapes its ``supported()`` takes
+  (bf16 or float32, widths and tile of whole lane tiles, a step of each
+  kernel inside the VMEM budget: both expert cells').  Rows gathered a chunk
+  of tiles at a time, a tile's expert picked in the weights' index maps,
+  results combined row by row in VMEM: five Pallas kernels, no per-tile XLA
+  operation.
+* ``loop`` — ``expert_ffn`` / ``expert_ffn_grad`` below: everywhere else (the
+  CPU, a mesh, whatever ``supported()`` refuses), and the reference the
+  kernels' tests compare with.  A ``fori_loop`` over the live tiles of
+  per-tile XLA operations.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -240,16 +256,45 @@ def _ffn_args(ins, ctx):
     return x, weight, mats, tuple(ins[s][0] for s in _LAYOUT)
 
 
+# Where the grouped Pallas kernels are the body (tests add "cpu": interpreted).
+_GROUPED_PLATFORMS = ("tpu",)
+
+
+def _bodies(ctx, op_type, x, gate, tile):
+    """(``expert_ffn``, ``expert_ffn_grad``) or the grouped kernels' pair of
+    the same signatures, from what the op can observe: a TPU trace on one
+    device (a per-shard lowering is not written), no pinned
+    ``FLAGS_pallas_kernels=False``, and shapes the kernels' ``supported()``
+    takes.  Notes which under ``op_type``."""
+    from ..compile_cache import note_kernel_body
+    from .pallas import grouped_experts, interpret_mode, kernel_allowed
+
+    if kernel_allowed(ctx, _GROUPED_PLATFORMS) \
+            and getattr(ctx, "mesh", None) is None \
+            and grouped_experts.supported(x, gate, tile):
+        note_kernel_body(op_type, "grouped")
+        interpret = interpret_mode(ctx)
+        return (functools.partial(grouped_experts.forward,
+                                  interpret=interpret),
+                functools.partial(grouped_experts.backward,
+                                  interpret=interpret))
+    note_kernel_body(op_type, "loop")
+    return expert_ffn, expert_ffn_grad
+
+
 def _ffn_compute(ins, attrs, ctx, op_index):
     x, weight, mats, layout = _ffn_args(ins, ctx)
-    y, pairs = expert_ffn(x, weight, *mats, layout, int(attrs["tile"]))
+    tile = int(attrs["tile"])
+    forward, _ = _bodies(ctx, "moe_expert_ffn", x, mats[0], tile)
+    y, pairs = forward(x, weight, *mats, layout, tile)
     return {"Out": y.astype(ins["X"][0].dtype), "Pairs": pairs.reshape(1)}
 
 
 def _ffn_grad_compute(ins, attrs, ctx, op_index):
     x, weight, mats, layout = _ffn_args(ins, ctx)
-    dy = ins["GRAD::Out"][0]
-    grads = expert_ffn_grad(x, weight, *mats, layout, int(attrs["tile"]), dy)
+    tile = int(attrs["tile"])
+    _, backward = _bodies(ctx, "moe_expert_ffn_grad", x, mats[0], tile)
+    grads = backward(x, weight, *mats, layout, tile, ins["GRAD::Out"][0])
     return {"GRAD::" + slot: [g.astype(ins[slot][0].dtype)]
             for slot, g in zip(("X", "TopkWeight", "Gate", "Up", "Down"),
                                grads)}

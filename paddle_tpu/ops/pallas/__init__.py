@@ -18,10 +18,15 @@ op takes it on a TPU whenever the shape is inside its VMEM bound
 ahead of the XLA body (PERF.md 6.6); only a PINNED
 ``FLAGS_pallas_kernels=False`` ("no Pallas") turns it off.  Likewise
 ``streamed_attention`` (grouped heads, selected keys, values narrower than
-the keys, or plain heads too long for the resident kernel; any length) and
+the keys, or plain heads too long for the resident kernel; any length),
 ``topk_select`` (``select_topk_keys`` with a query block's scores held in
-VMEM: one read of the scores where the XLA body makes 46; PERF.md 6.8);
-``kernel_allowed`` is the part of their rules they share.  Under a
+VMEM: one read of the scores where the XLA body makes 46; PERF.md 6.8) and
+``grouped_experts`` (``moe_expert_ffn`` and its gradient over the live tiles
+of the dispatch layout: a tile's expert picked in the weights' index maps,
+results combined in VMEM where the XLA body scatters 640 rows a tile; PERF.md
+6.15); ``kernel_allowed`` is the part of their rules they share, and
+``run_traced`` what traces and lowers a kernel once a step program.  On the
+CPU these ops keep their XLA bodies.  Under a
 ``CPUPlace`` the flag-selected kernels run in interpreter mode, which
 the tests use for numerical parity checks (the packed kernel is not
 selected on the CPU at all; its tests call it interpreted); every call
@@ -29,7 +34,19 @@ site records the body it lowered to
 (``compile_cache.note_kernel_body``).
 """
 
+import functools
+
 from ... import flags  # flag "pallas_kernels" is declared in flags.py
+from ...compile_cache import note_kernel_trace
+
+# What a grid step of the kernels an op picks by shape may hold in VMEM, and
+# the limit they are compiled under (Mosaic's default scoped limit is 16 MiB;
+# a v5e core has 128 MiB): ``streamed_attention`` says what fills it there,
+# ``grouped_experts`` here.
+VMEM_BUDGET = 48 * 1024 * 1024
+# Signatures whose jaxprs ``traced`` keeps (a step program has a handful; a
+# jaxpr is a few hundred equations and holds no array).
+_TRACES_KEPT = 3 * 32
 
 
 def interpret_mode(ctx):
@@ -60,6 +77,40 @@ def kernel_allowed(ctx, platforms):
     return getattr(ctx, "platform", None) in platforms \
         and not (flags.pinned("pallas_kernels")
                  and not flags.flag("pallas_kernels"))
+
+
+@functools.lru_cache(maxsize=_TRACES_KEPT)
+def traced(kernel, call, operands, **statics):
+    """The jaxpr of ``call`` — a function that makes one ``pallas_call`` —
+    on ``operands`` (a ``ShapeDtypeStruct`` each, None for one that is not
+    there) under ``statics``, traced the first time the signature is asked
+    for; ``kernel`` names the family in ``stats()["kernel_traces"]``."""
+    import jax
+
+    note_kernel_trace(kernel, "traces")
+    closed = jax.make_jaxpr(functools.partial(call, **statics))(*operands)
+    assert not closed.consts, "a kernel's trace holds no arrays"
+    return closed.jaxpr
+
+
+def run_traced(kernel, call, operands, **statics):
+    """``call(*operands, **statics)`` by the signature's one jaxpr: every
+    site of a step program binds the SAME ``pallas_call`` equation, under
+    its own name stack, so jax lowers a distinct kernel to Mosaic once
+    (``mlir._cached_lowering`` is keyed on the equation's params, and a
+    ``pallas_call`` built anew carries new index maps and a new partial of
+    its kernel: eighteen traces and eighteen lowerings for the three
+    streamed kernels of a six-block step).  Not a ``jax.jit`` around the
+    kernel: that would lower once too, into a shared function under no
+    op's ``fluid[..]`` scope, where the device trace's readers lose it."""
+    import jax
+
+    note_kernel_trace(kernel, "sites")
+    jaxpr = traced(kernel, call, tuple(
+        None if x is None else jax.ShapeDtypeStruct(x.shape, x.dtype)
+        for x in operands), **statics)
+    return jax.core.eval_jaxpr(
+        jaxpr, (), *[x for x in operands if x is not None])
 
 
 def block_rows(n, row_bytes, max_rows, vmem_budget=4 * 1024 * 1024):

@@ -66,8 +66,9 @@ probabilities recomputed from the saved log-sum-exp).
 A step program calls these kernels once a block with the same shapes.  Each
 of the three ``pallas_call``s is traced ONCE a signature — the operands'
 shapes and dtypes, a selection or none, the heads a step, ``causal``,
-``scale``, ``interpret`` — into a jaxpr that ``_traced`` keeps, and every
-call evaluates that jaxpr on its own operands (``_run``).  All sites of a
+``scale``, ``interpret`` — into a jaxpr that ``pallas.traced`` keeps, and
+every call evaluates that jaxpr on its own operands (``pallas.run_traced``,
+which ``grouped_experts`` shares).  All sites of a
 signature then bind the SAME ``pallas_call`` equation, so jax lowers it to
 Mosaic once (its per-equation lowering cache is keyed on the equation's
 params; a ``pallas_call`` built anew carries new index maps and a new
@@ -86,7 +87,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...compile_cache import note_kernel_trace
+from . import VMEM_BUDGET, run_traced
 from ..sparse_select import KEYS_PER_TILE, LANES
 
 _NEG_INF = -1e30
@@ -110,7 +111,7 @@ _POS_BIG = 1e30
 #   1: 11.56 / 12.10 / 13.95 (the kernels before plain heads shared a step,
 #   to 0.01)   2: 10.92 / 11.16 / 12.64   4: 8.81 / 10.71 / 11.94
 #   8: 8.38 / 10.56 / 11.87 — the same bits out of all of them.
-_VMEM_BUDGET = 48 * 1024 * 1024
+_VMEM_BUDGET = VMEM_BUDGET
 # Heads whose text one turn of the head loop holds: the scheduler runs a
 # head's products on the MXU under its neighbour's softmax on the VPU, which
 # a loop of single heads forbids.  A layer's three kernels at the
@@ -121,9 +122,6 @@ _VMEM_BUDGET = 48 * 1024 * 1024
 # forward 10.50 / 8.38 / 9.05); 4 heads a step one at a time 34.9, by twos
 # 33.9, all four 31.5.
 _HEADS_UNROLLED = 4
-# Signatures whose three jaxprs ``_traced`` keeps (a step program has one or
-# two; a jaxpr is a few hundred equations and holds no array).
-_TRACES_KEPT = 3 * 32
 
 
 def _pick_blocks(t):
@@ -530,30 +528,7 @@ def _dkv(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
     )(*_given(selected, q, k, v, dout, lse, delta))
 
 
-@functools.lru_cache(maxsize=_TRACES_KEPT)
-def _traced(call, operands, **statics):
-    """The jaxpr of ``call`` — one of the three ``pallas_call``s above — on
-    ``operands`` (a ``ShapeDtypeStruct`` each, None for no selection) under
-    ``statics``, traced the first time the signature is asked for."""
-    note_kernel_trace("streamed_attention", "traces")
-    closed = jax.make_jaxpr(functools.partial(call, **statics))(*operands)
-    assert not closed.consts, "a streamed kernel's trace holds no arrays"
-    return closed.jaxpr
-
-
-def _run(call, operands, **statics):
-    """``call(*operands, **statics)`` by the signature's one jaxpr: every
-    site of a step program binds the SAME ``pallas_call`` equation, under
-    its own name stack, so jax lowers a distinct kernel to Mosaic once
-    (``mlir._cached_lowering`` is keyed on the equation's params, and a
-    ``pallas_call`` built anew carries new index maps and a new partial of
-    its kernel: eighteen traces and eighteen lowerings for the three
-    kernels of a six-block step)."""
-    note_kernel_trace("streamed_attention", "sites")
-    jaxpr = _traced(call, tuple(
-        None if x is None else jax.ShapeDtypeStruct(x.shape, x.dtype)
-        for x in operands), **statics)
-    return jax.core.eval_jaxpr(jaxpr, (), *_given(*operands))
+_run = functools.partial(run_traced, "streamed_attention")
 
 
 def _statics(q, k, v, causal, scale, interpret):

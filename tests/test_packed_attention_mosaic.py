@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from paddle_tpu.ops import pallas
 from paddle_tpu.ops.pallas import packed_attention as pa
 
 
@@ -194,7 +195,7 @@ def test_a_step_program_traces_and_lowers_each_streamed_kernel_once(one_chip):
     from paddle_tpu.ops.pallas import streamed_attention as sa
 
     b, h, t, dk, dv, blocks = 1, 32, 8192, 192, 128, 6
-    sa._traced.cache_clear()
+    pallas.traced.cache_clear()
 
     def arg(t, width):
         return jax.ShapeDtypeStruct((b, h, t, width), jnp.bfloat16,
@@ -236,7 +237,7 @@ def test_a_program_traced_again_reuses_the_streamed_kernels_jaxprs():
     h, t, dk, dv = 4, 256, 192, 128
     args = [jax.random.normal(jax.random.key(i), (1, h, t, w), jnp.float32)
             for i, w in enumerate((dk, dk, dv, dv))]
-    sa._traced.cache_clear()
+    pallas.traced.cache_clear()
     step = _blocks_of_attention(sa, 2, None, dk ** -0.5, True)
     sites, traces = _kernel_traces()
     first = jax.jit(step)(*args)
@@ -295,6 +296,158 @@ def test_no_index_of_the_streamed_kernels_divides_through_sign(hk, selected):
     assert [e.primitive.name for e in jaxpr.eqns].count("pallas_call") == 3
     inside = set(_primitives(jaxpr))
     assert {"div", "rem"} & inside and not {"sign", "floor"} & inside
+
+
+def _expert_operands(held, tile, sharding=None):
+    """The expert ops' operands at the two expert cells' size — 8192 tokens
+    of 2048, eight experts a token, ``held`` experts of width 768 at tiles of
+    ``tile`` rows, bf16 — as shapes: (x, routing weights, [gate, up, down],
+    the dispatch layout's four, the layout's capacity in rows)."""
+    from paddle_tpu.ops import moe
+
+    n, d, f, k = 8192, 2048, 768, 8
+    cap = moe.dispatch_capacity(n * k, held, tile)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    mats = [arg((held, d, f)), arg((held, d, f)), arg((held, f, d))]
+    layout = [arg((cap,), jnp.int32), arg((cap,), jnp.int32),
+              arg((cap // tile,), jnp.int32), arg((1,), jnp.int32)]
+    return arg((n, d)), arg((n, k), jnp.float32), mats, layout, cap
+
+
+def test_no_index_of_the_grouped_kernels_divides_through_sign():
+    """The same guard over ``grouped_experts``: its five kernels' bodies
+    and index maps, and the chunk loop around them, hold no ``sign`` and no
+    ``floor`` (the one division, chunks from live tiles, is ``lax.div``)."""
+    from paddle_tpu.ops.pallas import grouped_experts as ge
+
+    tile = 640
+    x, w, mats, layout, _ = _expert_operands(16, tile)
+
+    def step(x, w, gate, up, down, *layout):
+        y, _ = ge.forward(x, w, gate, up, down, layout, tile)
+        return ge.backward(x, w, gate, up, down, layout, tile, y)
+    inside = list(_primitives(jax.make_jaxpr(step)(x, w, *mats,
+                                                   *layout).jaxpr))
+    assert inside.count("pallas_call") == 5
+    assert "div" in inside and not {"sign", "floor"} & set(inside)
+
+
+@pytest.mark.parametrize("tile,held", [(640, 16), (384, 8)])
+def test_grouped_expert_kernels_compile_for_v5e_at_both_cells_shapes(
+        one_chip, tile, held):
+    """``moe_expert_ffn`` and its gradient by the grouped kernels at the two
+    expert cells' shapes — 8192 tokens of 2048, eight experts a token,
+    experts of width 768, bf16; 16 held at tiles of 640 rows, 8 at 384:
+    Mosaic has to accept the experts picked in the index maps from scalar
+    prefetch, the clamped maps of dead tiles, the one-row read-modify-writes
+    at token ids read from SMEM, the results carried in place with their
+    fetch by DMA, and each kernel's VMEM under the limit it states — with
+    the blocks and the chunk the rule derives.  Nothing of the capacity's
+    rows is ever whole: the temporaries are a chunk's."""
+    from paddle_tpu.ops.pallas import grouped_experts as ge
+
+    x, w, mats, layout, cap = _expert_operands(held, tile, one_chip)
+    d = x.shape[1]
+    assert ge.supported(x, mats[0], tile)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        fwd = jax.jit(lambda x, w, g, u, dn, *lay: ge.forward(
+            x, w, g, u, dn, lay, tile)).lower(x, w, *mats, *layout).compile()
+        bwd = jax.jit(lambda x, w, g, u, dn, dy, *lay: ge.backward(
+            x, w, g, u, dn, lay, tile, dy)).lower(
+                x, w, *mats, x, *layout).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    target = 'custom_call_target="tpu_custom_call"'
+    assert fwd.as_text().count(target) == 2
+    assert bwd.as_text().count(target) == 3
+    # ONE [capacity, D] array would be 0.3 GB in bf16, 0.6 in float32: all
+    # the temporaries of either direction together are less
+    assert ge._chunk_tiles(held) * tile * 5 < cap
+    assert fwd.memory_analysis().temp_size_in_bytes < cap * d * 2 // 4
+    assert bwd.memory_analysis().temp_size_in_bytes < cap * d * 2
+
+
+def _tiny_expert_decoder(kind):
+    """A two-expert-layer decoder of either kind at widths the grouped
+    kernels take (whole lane tiles), its startup run: (program, loss, the
+    feeds' names)."""
+    import paddle_tpu as fluid
+    from paddle_tpu.models import sparse_moe_decoder as smd
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        names = ("tok", "lbl") + (("lbl2",) if kind == "latent" else ())
+        feeds = [fluid.layers.data(n, shape=[32, 1], dtype="int64")
+                 for n in names]
+        if kind == "latent":
+            loss, _ = smd.latent_decoder_lm(
+                *feeds, 64, 2, 1, 128, smd.LatentSizes(4, 24, 16, 16, 8, 16),
+                48, (2, 4, 1), 128, 2, 16, route_scale=2.5, expert_tile=128)
+        else:
+            loss, _, _ = smd.decoder_lm(*feeds, 64, 2, 128, 4, 2, 8,
+                                        (2, 4, 1), 128, 2, 2, 8, 8,
+                                        expert_tile=128)
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    fluid.Executor(fluid.CPUPlace()).run(startup)
+    return main, loss, names
+
+
+@pytest.mark.parametrize("kind", ["sparse", "latent"])
+def test_a_decoder_step_traces_and_lowers_each_grouped_kernel_once(one_chip,
+                                                                   kind):
+    """A step of a tiny decoder with two expert layers, traced for a TPU and
+    lowered for the described v5e: both ops of both layers take the grouped
+    kernels, ten call sites reach them (the forward's two and the backward's
+    three a layer) and five jaxprs are made; the lowered text holds ten
+    custom calls of theirs, each under ITS OWN op's Fluid scope."""
+    import re
+
+    import numpy as np
+
+    import paddle_tpu as fluid
+    from paddle_tpu import compile_cache
+    from paddle_tpu import executor as ex
+
+    def counts():
+        stats = compile_cache.stats()
+        traces = stats["kernel_traces"].get("grouped_experts",
+                                            {"sites": 0, "traces": 0})
+        return (stats["kernel_bodies"].get("moe_expert_ffn:grouped", 0),
+                stats["kernel_bodies"].get("moe_expert_ffn_grad:grouped", 0),
+                stats["kernel_bodies"].get("moe_expert_ffn:loop", 0),
+                traces["sites"], traces["traces"])
+    with fluid.scope_guard(fluid.Scope()):
+        main, loss, names = _tiny_expert_decoder(kind)
+        scope = fluid.global_scope()
+        state, writeback = ex.analyze(main, sorted(names), scope, [loss.name])
+        fn, _, _ = ex.trace_program(main, sorted(names), state, writeback,
+                                    [loss.name], platform="tpu")
+
+        def arg(v):
+            return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+        pallas.traced.cache_clear()
+        before = counts()
+        ids = np.zeros((2, 32, 1), "int64")
+        text = jax.jit(fn).lower(
+            [arg(ex._coerce_feed(main.global_block(), n, ids))
+             for n in sorted(names)],
+            [arg(scope.var(n)) for n in state],
+            arg(jax.random.key(0))).as_text(debug_info=True)
+    assert tuple(a - b for a, b in zip(counts(), before)) == (2, 2, 0, 10, 5)
+    locs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, re.M))
+    scopes = [re.search(r"fluid\[\w+\][\w.@]+", locs[c]).group(0)
+              for c in re.findall(
+                  r"stablehlo\.custom_call @tpu_custom_call.*?"
+                  r"loc\((#loc\d+)\)\s*$", text, re.M)]
+    ours = [s for s in scopes if "moe_expert_ffn" in s]
+    forward = [s for s in ours if s.startswith("fluid[moe_expert_ffn]")]
+    grad = [s for s in ours if s.startswith("fluid[moe_expert_ffn_grad]")]
+    assert len(forward) == 4 and len(set(forward)) == 2
+    assert len(grad) == 6 and len(set(grad)) == 2
 
 
 @pytest.mark.parametrize("causal", [True, False])

@@ -214,6 +214,15 @@ def test_topk_kernel_crosses_a_tile_under_causal():
     assert np.asarray(words[0, 4100, 128:133] == 1).all()
 
 
+def _bodies_noted(run):
+    """(the kernel bodies ops noted while ``run()`` ran, what it returned)."""
+    before = dict(compile_cache.stats()["kernel_bodies"])
+    out = run()
+    after = compile_cache.stats()["kernel_bodies"]
+    return {key: n - before.get(key, 0) for key, n in after.items()
+            if n != before.get(key, 0)}, out
+
+
 def _select_body(monkeypatch, x, k, causal, platforms, mesh=None):
     """Trace the op's compute as the CPU executor would; return (kernel
     bodies recorded, outputs)."""
@@ -221,12 +230,8 @@ def _select_body(monkeypatch, x, k, causal, platforms, mesh=None):
 
     monkeypatch.setattr(ss, "_KERNEL_PLATFORMS", platforms)
     ctx = ComputeContext(key=jax.random.key(0), platform="cpu", mesh=mesh)
-    before = dict(compile_cache.stats()["kernel_bodies"])
-    out = jax.jit(lambda x: ss._select_compute(
-        {"X": [x]}, {"k": k, "causal": causal}, ctx, 0))(x)
-    after = compile_cache.stats()["kernel_bodies"]
-    return {key: n - before.get(key, 0) for key, n in after.items()
-            if n != before.get(key, 0)}, out
+    return _bodies_noted(lambda: jax.jit(lambda x: ss._select_compute(
+        {"X": [x]}, {"k": k, "causal": causal}, ctx, 0))(x))
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -628,6 +633,225 @@ def test_one_expert_takes_every_token_and_nothing_is_dropped():
     np.testing.assert_allclose(y, _dense_experts(
         x, routed["TopkIdx"], routed["TopkWeight"], mats, (4, 5)),
         rtol=1e-5, atol=1e-5)
+
+
+# ---- the grouped Pallas kernels against the loop ---------------------------------
+
+def _routes(kind, n, total, k, first, held, r):
+    """Routed ids ``[n, k]``: ``random`` — k distinct of all experts a token;
+    ``none_held`` — never a held expert; ``all_held`` — every pair to a held
+    expert (the worst case); ``one_empty`` — random, but the second held
+    expert gets no token; ``one_heavy`` — every token's first choice is the
+    first held expert."""
+    outside = [e for e in range(total) if not first <= e < first + held]
+    pool = {"none_held": outside, "all_held": range(first, first + held),
+            "one_empty": [e for e in range(total) if e != first + 1]}.get(
+                kind, range(total))
+    idx = np.stack([r.permutation(list(pool))[:k] for _ in range(n)])
+    if kind == "one_heavy":
+        for row in idx:
+            if first in row:
+                row[list(row).index(first)] = row[0]
+            row[0] = first
+    return idx.astype("int32")
+
+
+# name: (n, d, f, total, held, first, k, tile, dtype, routes, tiles a chunk
+# if not the rule's, whether every kernel walks its blocked width in two
+# blocks)
+_GROUPED_CASES = {
+    # the long-document cell's tile and held experts, widths reduced
+    "longdoc_tile_640_held_16": (640, 128, 256, 32, 16, 8, 8, 640,
+                                 "bfloat16", "random", None, False),
+    # the latent cell's
+    "mtp_tile_384_held_8": (384, 128, 256, 32, 8, 4, 8, 384, "bfloat16",
+                            "random", None, False),
+    "an_expert_with_no_token": (256, 128, 128, 8, 4, 2, 2, 128, "float32",
+                                "one_empty", None, False),
+    # 320 pairs of the first held expert: three tiles, in chunks of two
+    "an_expert_with_three_tiles": (320, 128, 128, 8, 3, 1, 2, 128,
+                                   "float32", "one_heavy", 2, False),
+    "no_live_tile": (64, 128, 128, 8, 2, 3, 2, 128, "float32", "none_held",
+                     None, False),
+    "every_pair_held_in_several_chunks": (256, 128, 128, 8, 4, 0, 4, 128,
+                                          "bfloat16", "all_held", 3, False),
+    "widths_walked_in_blocks_float32": (192, 256, 512, 8, 4, 2, 2, 128,
+                                        "float32", "random", 2, True),
+    "widths_walked_in_blocks_bfloat16": (192, 256, 512, 8, 4, 2, 2, 128,
+                                         "bfloat16", "random", 2, True),
+}
+
+
+def _grouped_ctx(monkeypatch, platforms=("tpu", "cpu"), **kw):
+    """A trace context as the CPU executor makes it, the CPU let into the
+    grouped kernels' platforms (interpreted; the program never is)."""
+    from paddle_tpu import flags
+    from paddle_tpu.registry import ComputeContext
+
+    monkeypatch.setattr(flags, "_PINNED", flags._PINNED - {"pallas_kernels"})
+    monkeypatch.setattr(moe, "_GROUPED_PLATFORMS", platforms)
+    return ComputeContext(key=jax.random.key(0), platform="cpu", **kw)
+
+
+@pytest.mark.parametrize("case", sorted(_GROUPED_CASES))
+def test_grouped_kernels_against_the_loop(case, monkeypatch):
+    """``moe_expert_ffn`` and its gradient through the op's compute, the
+    grouped kernels (interpreted) against today's loop: ``Out``, ``Pairs``
+    and all five gradients; nothing dropped."""
+    from paddle_tpu.ops.pallas import grouped_experts as ge
+
+    n, d, f, total, held, first, k, tile, dtype, routes, chunk, halved = \
+        _GROUPED_CASES[case]
+    r = np.random.RandomState(len(case))
+    if chunk is not None:
+        monkeypatch.setattr(ge, "_chunk_tiles", lambda held: chunk)
+    if halved:
+        rule = ge._block
+        monkeypatch.setattr(ge, "_block",
+                            lambda kind, *a: rule(kind, *a) // 2)
+    idx = jnp.asarray(_routes(routes, n, total, k, first, held, r))
+    lay = moe.dispatch_layout(idx, first, held, tile)
+    ins = {"X": [jnp.asarray(r.randn(n, d), dtype)],
+           "TopkWeight": [jnp.asarray(r.rand(n, k), jnp.float32)],
+           "GRAD::Out": [jnp.asarray(r.randn(n, d), dtype)]}
+    for slot, shape in (("Gate", (d, f)), ("Up", (d, f)), ("Down", (f, d))):
+        ins[slot] = [jnp.asarray(r.randn(held, *shape) * 0.3, dtype)]
+    ins.update({s: [lay[s]] for s in moe._LAYOUT})
+    routed = int(np.sum((np.asarray(idx) >= first)
+                        & (np.asarray(idx) < first + held)))
+    live = int(lay["NumTiles"][0])
+    assert live > ge._chunk_tiles(held) or chunk is None
+
+    def run(ctx):
+        return jax.jit(lambda ins: (
+            moe._ffn_compute(ins, {"tile": tile}, ctx, 0),
+            moe._ffn_grad_compute(ins, {"tile": tile}, ctx, 0)))(ins)
+    noted, (want, want_g) = _bodies_noted(
+        lambda: run(_grouped_ctx(monkeypatch, ("tpu",))))
+    assert noted == {"moe_expert_ffn:loop": 1, "moe_expert_ffn_grad:loop": 1}
+    noted, (got, got_g) = _bodies_noted(lambda: run(_grouped_ctx(monkeypatch)))
+    assert noted == {"moe_expert_ffn:grouped": 1,
+                     "moe_expert_ffn_grad:grouped": 1}
+    # dropless: every routed pair computed, by both bodies
+    assert float(got["Pairs"][0]) == float(want["Pairs"][0]) == routed
+    # the forward rounds where the loop rounds; the backward takes c after
+    # the product dy Wd^T, not before it: one rounding fewer in bf16
+    tol = 1e-5 if dtype == "float32" else 2e-2
+
+    def close(a, b, tol):
+        a, b = (np.asarray(v, "float32") for v in (a, b))
+        assert a.shape == b.shape
+        scale = np.abs(b).max()
+        assert np.abs(a - b).max() <= tol * max(scale, 1e-6), (
+            np.abs(a - b).max(), scale)
+    assert got["Out"].dtype == want["Out"].dtype
+    close(got["Out"], want["Out"], 1e-5 if dtype == "float32" else 1e-2)
+    for slot in ("X", "TopkWeight", "Gate", "Up", "Down"):
+        (a,), (b,) = got_g["GRAD::" + slot], want_g["GRAD::" + slot]
+        assert a.dtype == b.dtype
+        close(a, b, tol)
+    counts = np.asarray(lay["Counts"])
+    for slot in ("Gate", "Up", "Down"):
+        grad = np.asarray(got_g["GRAD::" + slot][0], "float32")
+        for e in np.flatnonzero(counts == 0):       # exact zeros, not small
+            assert not grad[e].any()
+    if routes == "one_empty":
+        assert counts[1] == 0 and live > 0
+    if routes == "one_heavy":
+        assert counts[0] == n and -(-n // tile) >= 3
+    if routes == "none_held":
+        assert live == 0 and not np.asarray(got["Out"], "float32").any()
+
+
+@pytest.mark.parametrize("case", ["taken", "cpu", "mesh", "pinned_flag",
+                                  "odd_width", "odd_tile", "float16",
+                                  "mixed_dtypes", "over_budget"])
+def test_grouped_rule_reads_only_what_the_op_observes(case, monkeypatch):
+    """Whatever ``supported()`` or the trace's platform refuses takes the
+    loop, and the op's note says ``loop``."""
+    import types
+
+    from paddle_tpu import flags
+    from paddle_tpu.ops.pallas import grouped_experts as ge
+    from paddle_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(flags, "_PINNED", flags._PINNED - {"pallas_kernels"})
+    tpu = types.SimpleNamespace(platform="tpu", mesh=None)
+    tile, dtype = 640, jnp.bfloat16
+
+    def spec(d=2048, f=768, dtype=dtype, gate_dtype=None):
+        return (jax.ShapeDtypeStruct((8192, d), dtype),
+                jax.ShapeDtypeStruct((16, d, f), gate_dtype or dtype))
+
+    def body(ctx, x, gate, tile=tile):
+        noted, (fwd, bwd) = _bodies_noted(
+            lambda: moe._bodies(ctx, "moe_expert_ffn", x, gate, tile))
+        loop = fwd is moe.expert_ffn and bwd is moe.expert_ffn_grad
+        assert noted == {"moe_expert_ffn:%s" % ("loop" if loop
+                                                else "grouped"): 1}
+        return "loop" if loop else "grouped"
+    if case == "taken":
+        assert body(tpu, *spec()) == "grouped"             # both cells'
+        assert body(tpu, *spec(), tile=384) == "grouped"
+        assert body(tpu, *spec(dtype=jnp.float32), tile=256) == "grouped"
+    elif case == "cpu":
+        assert body(types.SimpleNamespace(platform="cpu", mesh=None),
+                    *spec()) == "loop"
+        assert body(None, *spec()) == "loop"
+    elif case == "mesh":
+        meshed = types.SimpleNamespace(platform="tpu",
+                                       mesh=make_mesh((2, 4), ("dp", "tp")))
+        assert body(meshed, *spec()) == "loop"
+    elif case == "pinned_flag":
+        prev = flags.flag("pallas_kernels")
+        fluid.set_flags({"FLAGS_pallas_kernels": False})      # pins
+        try:
+            assert body(tpu, *spec()) == "loop"
+            fluid.set_flags({"FLAGS_pallas_kernels": True})
+            assert body(tpu, *spec()) == "grouped"
+        finally:
+            fluid.set_flags({"FLAGS_pallas_kernels": prev})
+    elif case == "odd_width":
+        assert body(tpu, *spec(d=2000)) == "loop"
+        assert body(tpu, *spec(f=700)) == "loop"
+    elif case == "odd_tile":
+        # a tile's rows are lanes of the weight gradients' operands
+        assert body(tpu, *spec(), tile=648) == "loop"
+        assert body(tpu, *spec(), tile=64) == "loop"
+        assert body(tpu, *spec(), tile=128) == "grouped"
+    elif case == "float16":
+        assert body(tpu, *spec(dtype=jnp.float16)) == "loop"
+    elif case == "mixed_dtypes":
+        assert body(tpu, *spec(gate_dtype=jnp.float32)) == "loop"
+    else:
+        # no block leaves a step of these inside the budget
+        assert body(tpu, *spec(), tile=8192) == "loop"
+        assert body(tpu, *spec(dtype=jnp.float32), tile=1024) == "loop"
+        monkeypatch.setattr(ge, "_VMEM_BUDGET", 4 * 1024 * 1024)
+        assert body(tpu, *spec()) == "loop"
+
+
+def test_grouped_blocking_follows_from_the_shapes_and_the_budget():
+    """The widest block of F that fits, a chunk of whole tiles: at the two
+    cells' shapes what ``tests/test_packed_attention_mosaic.py`` compiles."""
+    from paddle_tpu.ops.pallas import grouped_experts as ge
+
+    def blocks(tile):
+        return {kind: ge._block(kind, 8192, tile, 2048, 768, 2)
+                for kind in ge._KINDS}
+    assert blocks(640) == {"gate_up": 768, "down": 512, "rows": 384,
+                           "dx": 512, "weights": 384}
+    assert blocks(384) == {"gate_up": 768, "down": 512, "rows": 768,
+                           "dx": 512, "weights": 384}
+    for tile in (640, 384):
+        for kind, blk in blocks(tile).items():
+            assert ge._step_bytes(kind, 8192, tile, 2048, 768, blk,
+                                  2) <= ge._VMEM_BUDGET
+    # the resident [N, D-block] is what bounds the tokens a step may hold
+    assert ge._block("down", 65536, 640, 2048, 768, 2) is None
+    # a chunk: the held experts' tiles and a quarter more, whatever the
+    # capacity (119 and 179 tiles in the two cells)
+    assert (ge._chunk_tiles(16), ge._chunk_tiles(8)) == (20, 10)
 
 
 def _cfg(tiny=True):

@@ -12,6 +12,7 @@ operate on them exactly like the reference does.
 """
 
 from .framework import Parameter, Variable, grad_var_name
+from .profiler import build_pass
 from .registry import make_grad_ops
 
 __all__ = ["append_backward", "calc_gradient"]
@@ -120,6 +121,12 @@ def append_backward(loss, parameter_list=None, no_grad_set=None,
     with an existing Variable instead of ones (calc_gradient's
     target_gradients)."""
     assert isinstance(loss, Variable), "loss must be a Variable"
+    with build_pass(loss.block.program, "append_backward"):
+        return _append_backward(loss, parameter_list, no_grad_set,
+                                loss_grad_input)
+
+
+def _append_backward(loss, parameter_list, no_grad_set, loss_grad_input):
     block = loss.block
     program = block.program
     no_grad = _collect_no_grad_set(block, no_grad_set)

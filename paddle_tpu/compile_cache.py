@@ -25,13 +25,18 @@ Two layers, addressing the two costs a repeated step shape pays:
   process, evaluator clones, tests) reuses the traced+jitted callable and
   performs zero lowerings.
 
-``stats()`` exposes hit/miss/lowering counters; the executors emit
-``compile_cache/hit`` / ``compile_cache/miss`` profiler marks at every
-lookup so cache behavior is visible in the chrome trace next to the
-``trace``/``compile``/``dispatch`` spans.  ``count_compiles()`` is the
-one "nothing new was lowered or compiled" counter (this module's
-lowerings next to jax's own compile events), shared by the tests and
-``chip_smoke.py``.
+``stats()`` exposes hit/miss/lowering counters.  ``compile_log()`` holds
+one record per lowering — which step program, why it was lowered, whether
+the trace cache had it, and what each phase of its first call cost (build
+passes, program trace, jax trace with the Pallas kernels' traces inside,
+jaxpr -> MLIR, executable compiled or read from the persistent cache) —
+written on the cold path only: ``executor.StepPath`` opens it when an
+executor misses its own cache and closes it when the cold call returns,
+and jax's own ``jax.monitoring`` durations land in the record open on the
+calling thread (everything else in ``outside_compiles()``).
+``count_compiles()`` is the one "nothing new was lowered or compiled"
+counter (this module's lowerings next to jax's own compile events), shared
+by the tests and ``chip_smoke.py``.
 """
 
 import collections
@@ -39,14 +44,17 @@ import contextlib
 import hashlib
 import os
 import threading
+import time
 
-from .profiler import mark_event
+from . import monitor
+from .profiler import _append_event, _now_us, is_profiling
 
 __all__ = [
     "program_fingerprint", "program_label", "name_step", "trace_key",
     "trace_flag_values", "lookup",
     "store", "stats", "reset_stats", "clear", "note_kernel_body",
-    "note_kernel_trace",
+    "note_kernel_trace", "open_record", "note_phase", "close_record",
+    "compile_log", "outside_compiles",
     "count_compiles", "persistent_cache_dir", "enable_persistent_cache",
     "rescope_persistent_cache", "CHECKOUT_CACHE_DIR",
 ]
@@ -156,8 +164,15 @@ def name_step(fn, kind, program):
     module reads ``jit_pt_<kind>_<label>`` in the device trace's ``XLA
     Modules`` line instead of ``jit_fn`` (``kind``: ``exe`` for Executor,
     ``pe`` for ParallelExecutor).  Returns ``fn``."""
-    fn.__name__ = fn.__qualname__ = "pt_%s_%s" % (kind, program_label(program))
+    fn.__name__ = fn.__qualname__ = step_name(kind, program)
     return fn
+
+
+def step_name(kind, program):
+    """``pt_<kind>_<label>``: the step function's name, which jax's trace
+    event carries as it is and its lowering and compile events as
+    ``jit(<name>)``."""
+    return "pt_%s_%s" % (kind, program_label(program))
 
 
 def trace_key(program, feed_sig, state_sig, fetch_names, *extras):
@@ -174,16 +189,17 @@ def trace_key(program, feed_sig, state_sig, fetch_names, *extras):
 # ---------------------------------------------------------------------------
 
 def lookup(key):
+    """The cached entry or None; which of the two it was is the
+    ``trace_cache`` field of the record open on the calling thread."""
     with _mu:
         entry = _TRACE_CACHE.get(key)
         if entry is not None:
             _TRACE_CACHE.move_to_end(key)
-            _STATS["trace_hits"] += 1
-            mark_event("compile_cache/hit")
-            return entry
-        _STATS["trace_misses"] += 1
-        mark_event("compile_cache/miss")
-        return None
+        _STATS["trace_hits" if entry is not None else "trace_misses"] += 1
+    rec = getattr(_open, "record", None)
+    if rec is not None:
+        rec["trace_cache"] = "hit" if entry is not None else "miss"
+    return entry
 
 
 def store(key, entry):
@@ -203,9 +219,8 @@ def stats():
     """Counters since process start (or the last ``reset_stats``).
     ``hit_ratio`` (hits / lookups, 0.0 before the first lookup) is the
     StepStats field: a warm steady-state loop sits at ~1.0 and a retrace
-    storm (shape churn, program mutation) drags it visibly down.
-    Per-lookup hit/miss marks additionally double-publish as
-    ``mark/compile_cache/{hit,miss}`` monitor counters."""
+    storm (shape churn, program mutation) drags it visibly down;
+    ``compile_log()`` says which program was lowered again, and why."""
     with _mu:
         out = dict(_STATS)
         out["lowerings_by_program"] = dict(_LOWERINGS_BY_FP)
@@ -226,36 +241,167 @@ def reset_stats():
         _LOWERINGS_BY_FP.clear()
         _KERNEL_BODIES.clear()
         _KERNEL_TRACES.clear()
+        _LOG.clear()
+        _OUTSIDE.clear()
 
 
 def note_kernel_body(op_type, body):
     """Record, at trace time, which compute body an op with a Pallas (or
     ring) alternative lowered to.  A requested kernel that its
     ``supported()`` gate rejects gives way to the XLA reference; this
-    counter (``stats()["kernel_bodies"]``, plus a ``kernel_body/...``
-    profiler mark) is what tells the two apart afterwards."""
+    counter (``stats()["kernel_bodies"]``) is what tells the two apart
+    afterwards."""
     key = "%s:%s" % (op_type, body)
     with _mu:
         _KERNEL_BODIES[key] = _KERNEL_BODIES.get(key, 0) + 1
-    mark_event("kernel_body/%s/%s" % (op_type, body))
 
 
-def note_kernel_trace(kernel, counter):
+def note_kernel_trace(kernel, counter, seconds=0.0):
     """Count, at trace time, what a kernel that traces its ``pallas_call``s
     once a signature did (``ops/pallas/streamed_attention.py``):
     ``"sites"`` — a call reached it, ``"traces"`` — the call's signature was
-    new and its jaxpr was made.  ``stats()["kernel_traces"][kernel]`` holds
-    both: a step program of six blocks reads 18 sites and 3 traces, and
-    sites == traces says the memo never engaged."""
+    new and its jaxpr was made, in ``seconds``.
+    ``stats()["kernel_traces"][kernel]`` holds both counts: a step program
+    of six blocks reads 18 sites and 3 traces, and sites == traces says the
+    memo never engaged.  The seconds go to the record open on the calling
+    thread (``kernel_trace_s``, a part of its ``jax_trace_s``)."""
     with _mu:
         counts = _KERNEL_TRACES.setdefault(kernel, {"sites": 0, "traces": 0})
         counts[counter] += 1
+    if counter == "traces":
+        rec = getattr(_open, "record", None)
+        if rec is None:
+            _outside("kernel_trace", seconds)
+        else:
+            rec["kernel_traces"] += 1
+            rec["kernel_trace_s"] += seconds
 
 
 def clear():
     """Drop every cached trace (tests; frees the traced programs)."""
     with _mu:
         _TRACE_CACHE.clear()
+
+
+# ---------------------------------------------------------------------------
+# the compile record
+# ---------------------------------------------------------------------------
+
+# One plain dict per lowering, newest last; bounded like the trace cache
+# (a retrace storm keeps its latest rows, ``lowerings_by_program`` its count)
+_LOG = collections.deque(maxlen=256)
+# phase -> [events, seconds] of what jax traced, lowered and compiled with
+# no record open to take it: the benchmark's plain reference, eager
+# ``jax.random.key`` / ``fold_in`` programs, ``device_put``s
+_OUTSIDE = {}
+# .record: the record open on this thread; .read / .wrote: the persistent
+# cache answered / was written to since this thread's last backend compile
+_open = threading.local()
+
+_CAUSES = ("first", "feed_signature", "program_changed", "other_key")
+
+
+def lowered_before(program):
+    """Whether this process has lowered the program's fingerprint (since
+    the last ``reset_stats``): the line between a record's ``cause``
+    ``first`` and the others."""
+    with _mu:
+        return program_fingerprint(program)[:12] in _LOWERINGS_BY_FP
+
+
+def open_record(executor, kind, program, cause):
+    """Open the calling thread's compile record: ``executor`` is the
+    step path's name (``executor`` / ``parallel_executor``), ``kind`` its
+    label in the module name, ``cause`` one of ``first`` (fingerprint never
+    lowered in this process), ``feed_signature``, ``program_changed`` (same
+    program object, new ``_version``), ``other_key`` (flags, placement,
+    scope, fetch list).  The program's ``build_s`` — what the whole-program
+    passes of build spent on it (``profiler.build_pass``) — moves into its
+    first record.  A record still open on the thread (its cold call never
+    came) is closed first, with ``first_call_s`` 0."""
+    assert cause in _CAUSES, cause
+    _listen()
+    if getattr(_open, "record", None) is not None:
+        close_record(None)
+    build = dict(getattr(program, "_build_s", ()))
+    if build:
+        program._build_s.clear()
+    _open.record = {
+        "name": step_name(kind, program), "executor": executor,
+        "fingerprint": program_fingerprint(program)[:12],
+        "ops": sum(len(b.ops) for b in program.blocks),
+        "cause": cause, "trace_cache": "miss", "start_us": _now_us(),
+        "build_s": sum(build.values(), 0.0), "build": build,
+        "analyze_s": 0.0, "program_trace_s": 0.0,
+        "jax_trace_s": 0.0, "kernel_trace_s": 0.0, "kernel_traces": 0,
+        "lowering_s": 0.0, "executable_s": 0.0, "executable": "none",
+        "first_call_s": 0.0, "unaccounted_s": 0.0,
+        # (phase, end on the profiler's clock, seconds) as jax reported
+        # them, and whether jax is inside the step function's own trace
+        "_spans": [], "_tracing": False}
+
+
+def note_phase(field, since_ns):
+    """Add the seconds since ``since_ns`` (``time.perf_counter_ns``) to
+    ``field`` of the record open on this thread; returns now, for the
+    next phase."""
+    now = time.perf_counter_ns()
+    rec = getattr(_open, "record", None)
+    if rec is not None:
+        rec[field] += (now - since_ns) / 1e9
+    return now
+
+
+def close_record(call_ns):
+    """Close the calling thread's record (None when there is none) and
+    append it to the log: ``call_ns`` is when the cold call began
+    (``time.perf_counter_ns``; None = there was no cold call),
+    ``unaccounted_s`` what of the call jax reported no phase for.  Under a
+    profiler session the phases jax reported are appended to its events as
+    ``<executor>/jax_trace``, ``/mlir_lowering`` and ``/executable`` spans,
+    back-dated from the moment each duration arrived; with the monitor's
+    JSONL log on, the record is one ``compile_record`` event there."""
+    rec = getattr(_open, "record", None)
+    if rec is None:
+        return None
+    _open.record = None
+    if call_ns is not None:
+        rec["first_call_s"] = (time.perf_counter_ns() - call_ns) / 1e9
+        rec["unaccounted_s"] = rec["first_call_s"] - (
+            rec["jax_trace_s"] + rec["lowering_s"] + rec["executable_s"])
+    spans = rec.pop("_spans")
+    del rec["_tracing"]
+    if is_profiling():
+        # the enclosing <executor>/compile span carries the fingerprint
+        tags = {"module": rec["name"], "cause": rec["cause"]}
+        for phase, end_us, seconds in spans:
+            _append_event("%s/%s" % (rec["executor"], phase),
+                          end_us - seconds * 1e6, seconds * 1e6, dict(tags))
+    with _mu:
+        _LOG.append(rec)
+    monitor.log_event(dict(rec, event="compile_record", ts=time.time()))
+    return rec
+
+
+def compile_log():
+    """The closed records, oldest first, as plain dicts (copies)."""
+    with _mu:
+        return [dict(rec, build=dict(rec["build"])) for rec in _LOG]
+
+
+def outside_compiles():
+    """{phase: {"events", "seconds"}} of what jax traced, lowered and
+    compiled (or read) that belongs to no record."""
+    with _mu:
+        return {phase: {"events": n, "seconds": s}
+                for phase, (n, s) in _OUTSIDE.items()}
+
+
+def _outside(phase, seconds):
+    with _mu:
+        slot = _OUTSIDE.setdefault(phase, [0, 0.0])
+        slot[0] += 1
+        slot[1] += seconds
 
 
 # ---------------------------------------------------------------------------
@@ -279,34 +425,86 @@ _JAX_EVENTS = {
 _JAX_COUNTS = dict.fromkeys(
     [n for pair in _JAX_DURATION_EVENTS.values() for n in pair]
     + list(_JAX_EVENTS.values()), 0)
+# the duration events a record takes, by the span each becomes
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_RECORD_PHASES = {
+    _TRACE_EVENT: ("jax_trace", "jax_trace_s"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("mlir_lowering", "lowering_s"),
+    "/jax/core/compile/backend_compile_duration":
+        ("executable", "executable_s"),
+}
+# inside a backend compile: the persistent cache answered, or was written
+# to (where there is none the compile fires neither: "uncached")
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_WRITE_EVENT = "/jax/compilation_cache/cache_misses"
 _listening = [False]
 
 
 def _listen():
-    """Register the jax.monitoring listeners once per process (jax has
-    no public unregister, so they stay; they run only when jax lowers or
-    compiles something).  Process-global on purpose: the serving loop
-    compiles on its own thread, which thread-local counters miss."""
+    """Register the jax.monitoring listeners once per process — at the
+    first record, the first ``count_compiles()`` or when the persistent
+    cache is turned on, whichever comes first (they stay; they run
+    only when jax traces, lowers or compiles something).  The counters
+    are process-global on purpose: the serving loop compiles on its own
+    thread, which thread-local counters miss.  A duration whose
+    ``fun_name`` is that of the record open on the calling thread is the
+    record's; a trace event that arrives while jax is inside the step
+    function's own trace is a ``jax.jit`` nested in it, already inside the
+    step's seconds, and counts nowhere; everything else is ``outside``."""
     with _mu:
         if _listening[0]:
             return
         _listening[0] = True
     import jax.monitoring
 
-    def on_duration(event, duration, **_):
+    def on_duration(event, duration, fun_name=None, **_):
         names = _JAX_DURATION_EVENTS.get(event)
         if names is not None:
             with _mu:
                 _JAX_COUNTS[names[0]] += 1
                 _JAX_COUNTS[names[1]] += duration
+        if event == _CACHE_READ_EVENT:
+            _open.read = True
+            return
+        if event not in _RECORD_PHASES:
+            return
+        phase, field = _RECORD_PHASES[event]
+        rec = getattr(_open, "record", None)
+        how = None
+        if phase == "executable":
+            how = "read" if getattr(_open, "read", False) else \
+                "compiled" if getattr(_open, "wrote", False) else "uncached"
+            _open.read = _open.wrote = False
+        if rec is not None and fun_name in (rec["name"],
+                                            "jit(%s)" % rec["name"]):
+            rec[field] += duration
+            rec["_spans"].append((phase, _now_us(), duration))
+            if how is not None:
+                rec["executable"] = how
+            if event == _TRACE_EVENT:
+                rec["_tracing"] = False
+        elif not (event == _TRACE_EVENT and rec is not None
+                  and rec["_tracing"]):
+            _outside(phase if how is None else phase + "_" + how, duration)
+
+    def on_scalar(event, value, fun_name=None, **_):
+        # jax's elapsed-time events report their start as a scalar
+        rec = getattr(_open, "record", None)
+        if event == _TRACE_EVENT and rec is not None \
+                and fun_name == rec["name"]:
+            rec["_tracing"] = True
 
     def on_event(event, **_):
         name = _JAX_EVENTS.get(event)
         if name is not None:
             with _mu:
                 _JAX_COUNTS[name] += 1
+        if event == _CACHE_WRITE_EVENT:
+            _open.wrote = True
 
     jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_scalar_listener(on_scalar)
     jax.monitoring.register_event_listener(on_event)
 
 
@@ -410,9 +608,15 @@ def enable_persistent_cache(cache_dir=None, chip_entry=False):
     Thresholds are zeroed so even small modules cache: the win case is
     many small-to-medium modules recompiled across rung subprocesses
     and chip calls.  In a ``jax.distributed`` world the entries land in
-    a ``world_<N>`` subdirectory — see ``rescope_persistent_cache``."""
-    return _apply_persistent_dir(
+    a ``world_<N>`` subdirectory — see ``rescope_persistent_cache``.
+    A process that keeps its executables also hears what jax compiles from
+    here on (``_listen``): what it compiles before its first step — a
+    benchmark's plain reference — is then in ``outside_compiles()``."""
+    cache_dir = _apply_persistent_dir(
         persistent_cache_dir(cache_dir, chip_entry=chip_entry))
+    if cache_dir:
+        _listen()
+    return cache_dir
 
 
 def _apply_persistent_dir(cache_dir):

@@ -22,6 +22,8 @@ SURVEY.md §2.6 float16 demo row).
 
 import jax.numpy as jnp
 
+from ..profiler import build_pass
+
 __all__ = ["AutoMixedPrecisionLists", "AMPPolicy", "decorate",
            "bf16_program_guard", "cast_parameters_to_bf16"]
 
@@ -144,9 +146,13 @@ def decorate(optimizer, amp_lists=None, init_loss_scaling=1.0,
             self._amp_policy = AMPPolicy(amp_lists)
 
         def minimize(self, loss, startup_program=None, **kw):
-            result = self._inner.minimize(
-                loss, startup_program=startup_program, **kw)
-            loss.block.program._amp_policy = self._amp_policy
+            # the rewrite is this mark: the policy casts at trace time,
+            # so the pass's own seconds read ~0 and its cost is part of
+            # the step's jax trace
+            with build_pass(loss.block.program, "mixed_precision"):
+                result = self._inner.minimize(
+                    loss, startup_program=startup_program, **kw)
+                loss.block.program._amp_policy = self._amp_policy
             return result
 
         def __getattr__(self, name):

@@ -26,6 +26,7 @@ TPU-first:
 """
 
 import collections
+import contextlib
 import time
 
 import numpy as np
@@ -479,6 +480,18 @@ def _feed_sig(feed_names, feed_vals):
                  for n, v in zip(feed_names, feed_vals))
 
 
+@contextlib.contextmanager
+def _cold_call(name, span_args):
+    """The cold call's ``<name>/compile`` span, which also closes the
+    program's compile record with the call's wall time."""
+    t0 = time.perf_counter_ns()
+    try:
+        with RecordEvent(name + "/compile", args=span_args):
+            yield
+    finally:
+        compile_cache.close_record(t0)
+
+
 class StepPath:
     """The one step path under both executors: resolve feeds and fetches,
     find or lower the step (trace -> guard -> probe -> jit), put feeds
@@ -600,6 +613,10 @@ class StepPath:
                compile_cache.trace_flag_values()) + self._placement_key(dev)
         compiled = self._cache.get(key)
         if compiled is None:
+            # one compile record a lowering: opened here, closed when the
+            # cold call has returned (_cold_call)
+            compile_cache.open_record(self._name, self._label, program,
+                                      self._cause(program, key))
             # the reference wraps op instantiation in RecordBlock
             # (executor.cc Prepare); here the analog is the trace+jit
             # (_lower consults the process-global trace cache first)
@@ -607,12 +624,33 @@ class StepPath:
                 compiled = self._cache[key] = self._lower(
                     program, scope, feed_names, feed_vals, feed_sig,
                     fetch_names, dev)
+            if feed_sig in compiled.seen_sigs:
+                # the process has run this entry at this signature (the
+                # trace cache's hit): no cold call follows
+                compile_cache.close_record(None)
         return feed_sig, compiled
+
+    def _cause(self, program, key):
+        """Why this executor lowers ``key``: the compile record's
+        ``cause``.  A program object this executor holds only at other
+        versions was changed (its new fingerprint would read ``first``
+        too); a key that differs from a held one in its feed signature
+        alone is a new shape."""
+        held = [k for k in self._cache if k[0] == key[0]]
+        if held and all(k[1] != key[1] for k in held):
+            return "program_changed"
+        if not compile_cache.lowered_before(program):
+            return "first"
+        if any(k[:3] + k[4:] == key[:3] + key[4:] for k in held):
+            return "feed_signature"
+        return "other_key"
 
     def _lower(self, program, scope, feed_names, feed_vals, feed_sig,
                fetch_names, dev):
+        t = time.perf_counter_ns()
         state_names, writeback = analyze(
             program, feed_names, scope, fetch_names)
+        t = compile_cache.note_phase("analyze_s", t)
         # process-global trace cache: a second executor over the same
         # program structure + signature (bench reruns, evaluator clones)
         # reuses the jitted step — zero new lowerings
@@ -663,6 +701,7 @@ class StepPath:
         jitted = jax.jit(
             compile_cache.name_step(fn, self._label, program),
             donate_argnums=(1,) if self.donate_state else (), **jit_kwargs)
+        compile_cache.note_phase("program_trace_s", t)
         return compile_cache.store(tkey, CompiledStep(
             jitted, feed_names, state_in, state_out, fetch_names,
             guarded=guarded, probe=probe, **placed))
@@ -740,8 +779,8 @@ class StepPath:
         if fault.active():
             fault.fire("executor/dispatch", step_idx)
         with RecordEvent(name + "/run"):
-            with RecordEvent(name + ("/compile" if cold else "/dispatch"),
-                             args=span_args):
+            with (_cold_call(name, span_args) if cold else
+                  RecordEvent(name + "/dispatch", args=span_args)):
                 with self._dispatch_scope(dev):
                     fn = compiled.fn
                     slot = (feed_sig, getattr(dev, "id", 0))
@@ -937,9 +976,12 @@ class Executor(StepPath):
         rng = jax.random.key(
             0, impl="rbg" if flags.flag("fast_prng") else None)
         # lower on the executor's device so the executable is the one a
-        # run() of this signature would build
+        # run() of this signature would build; this compile is the
+        # record's cold call
+        t0 = time.perf_counter_ns()
         with self._dispatch_scope(dev):
             cexec = compiled.fn.lower(feed_vals, state_vals, rng).compile()
+        compile_cache.close_record(t0)
         # seed the profile registry AND the entry's AOT-dispatch slot:
         # repeated cost_analysis calls are free, and a later run() of
         # the same signature dispatches through this executable instead

@@ -395,6 +395,15 @@ class Block:
     __str__ = __repr__
 
 
+class _BuildSeconds(dict):
+    """``Program._build_s``: pass name -> seconds the whole-program passes
+    of build (``profiler.build_pass``) spent on THAT program object; a copy
+    of the program starts at nothing."""
+
+    def __deepcopy__(self, memo):
+        return _BuildSeconds()
+
+
 class Program:
     """A whole trainable/inferable computation (reference framework.py:1404 /
     framework.proto:183).  Holds nested blocks; block 0 is global."""
@@ -416,6 +425,8 @@ class Program:
         # executor then writes them into that step's StepStats record
         # (executor._step_extras).  Not structure, not hashed.
         self.step_stats = None
+        # what build's passes cost, until the first compile record takes it
+        self._build_s = _BuildSeconds()
 
     # ---- block management --------------------------------------------------
     def global_block(self):

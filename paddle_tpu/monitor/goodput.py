@@ -103,6 +103,11 @@ SPAN_BUCKETS = {
 EXCLUDED_SPANS = {
     "executor/trace": "nested inside executor/compile",
     "parallel_executor/trace": "nested inside parallel_executor/compile",
+    # the closed compile record's phases, as jax reported them
+    # (compile_cache.close_record): the cold call's inside
+    **{"%s/%s" % (exe, phase): "nested inside %s/compile" % exe
+       for exe in ("executor", "parallel_executor")
+       for phase in ("jax_trace", "mlir_lowering", "executable")},
     "executor/step": "container (the whole run() call)",
     "parallel_executor/step": "container (the whole run() call)",
     "executor/run": "container (whole step)",

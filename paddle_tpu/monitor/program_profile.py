@@ -454,11 +454,35 @@ def summary_for(fingerprint):
 # report
 # ---------------------------------------------------------------------------
 
+def _lowering_columns(records):
+    """{fp12: the report's columns from that program's compile records}:
+    how often it was lowered and why (``first,feed_signature*2`` — a
+    retrace storm reads as its cause), and what set-up paid for it, phase
+    by phase, summed over the records."""
+    out = {}
+    for r in records:
+        c = out.setdefault(r["fingerprint"], {
+            "lowerings": 0, "causes": {}, "build_s": 0.0, "trace_s": 0.0,
+            "lowering_s": 0.0, "executable_s": 0.0})
+        c["lowerings"] += 1
+        c["causes"][r["cause"]] = c["causes"].get(r["cause"], 0) + 1
+        c["build_s"] += r["build_s"]
+        c["trace_s"] += r["analyze_s"] + r["program_trace_s"] \
+            + r["jax_trace_s"]
+        c["lowering_s"] += r["lowering_s"]
+        c["executable_s"] += r["executable_s"]
+    for c in out.values():
+        c["cause"] = ",".join(k if n == 1 else "%s*%d" % (k, n)
+                              for k, n in c.pop("causes").items())
+    return out
+
+
 def report_rows(peak_tflops=None, profiles_by_fp=None, acct_by_fp=None,
-                probe_acct_by_fp=None):
-    """Join profiles + step accounting into per-program report rows,
-    sorted by wall-clock share.  ``profiles_by_fp``/``acct_by_fp``/
-    ``probe_acct_by_fp`` override the live registry (the JSONL-replay
+                probe_acct_by_fp=None, compile_records=None):
+    """Join profiles + step accounting + compile records into per-program
+    report rows, sorted by wall-clock share.  ``profiles_by_fp``/
+    ``acct_by_fp``/``probe_acct_by_fp``/``compile_records`` override the
+    live registry and ``compile_cache.compile_log()`` (the JSONL-replay
     path of ``tools/program_report.py``).
 
     Tuner-probe work (the separate :func:`probe_totals` bucket) renders
@@ -481,6 +505,11 @@ def report_rows(peak_tflops=None, profiles_by_fp=None, acct_by_fp=None,
             cur = profiles_by_fp.get(p.fingerprint)
             if cur is None or p.ts >= cur.ts:
                 profiles_by_fp[p.fingerprint] = p
+    if compile_records is None:
+        from .. import compile_cache
+
+        compile_records = compile_cache.compile_log()
+    lowered = _lowering_columns(compile_records)
     fps = set(acct_by_fp) | set(profiles_by_fp)
     total_wall = sum((acct_by_fp.get(fp) or {}).get("wall_s", 0.0)
                      for fp in fps)
@@ -499,6 +528,9 @@ def report_rows(peak_tflops=None, profiles_by_fp=None, acct_by_fp=None,
                if p is not None else None,
                "peak_hbm_bytes": int(p.peak_hbm_bytes)
                if p is not None else None}
+        row.update(lowered.get(fp[:12]) or {
+            "lowerings": 0, "cause": "", "build_s": None, "trace_s": None,
+            "lowering_s": None, "executable_s": None})
         if probe:
             row["probe"] = True
             row["mfu"] = None
@@ -520,14 +552,20 @@ def report_rows(peak_tflops=None, profiles_by_fp=None, acct_by_fp=None,
 def render_table(rows):
     """Fixed-width text table of :func:`report_rows` output (shared by
     the CLI and in-process reporting)."""
-    hdr = "%-12s %-10s %8s %10s %7s %12s %12s %10s %7s" % (
-        "program", "executor", "steps", "wall(s)", "share",
-        "GFLOP/step", "GB/step", "peakHBM", "MFU")
+    hdr = "%-12s %-10s %8s %10s %7s %12s %12s %10s %7s" \
+          " %8s %8s %8s %8s  %s" % (
+              "program", "executor", "steps", "wall(s)", "share",
+              "GFLOP/step", "GB/step", "peakHBM", "MFU",
+              "build(s)", "trace(s)", "lower(s)", "exec(s)", "lowered")
     lines = [hdr, "-" * len(hdr)]
+
+    def secs(v):
+        return "%.3f" % v if v is not None else "-"
     for r in rows:
         kind = ("probe:" + (r["kind"] or "?")) if r.get("probe") \
             else (r["kind"] or "?")
-        lines.append("%-12s %-10s %8d %10.3f %6.1f%% %12s %12s %10s %7s" % (
+        lines.append("%-12s %-10s %8d %10.3f %6.1f%% %12s %12s %10s %7s"
+                     " %8s %8s %8s %8s  %s" % (
             r["fp12"], kind[:10], r["steps"], r["wall_s"],
             100.0 * r["wall_share"],
             "%.3f" % (r["flops_per_step"] / 1e9)
@@ -536,7 +574,10 @@ def render_table(rows):
             if r["bytes_per_step"] is not None else "-",
             _fmt_mib(r["peak_hbm_bytes"])
             if r["peak_hbm_bytes"] is not None else "-",
-            "%.3f" % r["mfu"] if r["mfu"] is not None else "-"))
+            "%.3f" % r["mfu"] if r["mfu"] is not None else "-",
+            secs(r.get("build_s")), secs(r.get("trace_s")),
+            secs(r.get("lowering_s")), secs(r.get("executable_s")),
+            r.get("cause") or "-"))
     return "\n".join(lines)
 
 

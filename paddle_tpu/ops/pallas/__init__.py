@@ -35,6 +35,7 @@ site records the body it lowered to
 """
 
 import functools
+import time
 
 from ... import flags  # flag "pallas_kernels" is declared in flags.py
 from ...compile_cache import note_kernel_trace
@@ -87,8 +88,9 @@ def traced(kernel, call, operands, **statics):
     for; ``kernel`` names the family in ``stats()["kernel_traces"]``."""
     import jax
 
-    note_kernel_trace(kernel, "traces")
+    t0 = time.perf_counter_ns()
     closed = jax.make_jaxpr(functools.partial(call, **statics))(*operands)
+    note_kernel_trace(kernel, "traces", (time.perf_counter_ns() - t0) / 1e9)
     assert not closed.consts, "a kernel's trace holds no arrays"
     return closed.jaxpr
 

@@ -19,6 +19,7 @@ from .framework import (Parameter, Program, Variable, default_main_program,
                         default_startup_program, program_guard)
 from .initializer import ConstantInitializer
 from .layer_helper import LayerHelper
+from .profiler import build_pass
 from .regularizer import append_regularization_ops
 
 __all__ = [
@@ -157,11 +158,12 @@ class Optimizer:
         program_guard so minimize works outside the guard that built it."""
         from .framework import default_startup_program
 
-        params_grads = append_backward(loss, parameter_list, no_grad_set)
-        with program_guard(loss.block.program,
-                           startup_program or default_startup_program()):
-            optimize_ops = self.apply_gradients(params_grads, loss,
-                                                startup_program)
+        with build_pass(loss.block.program, "minimize"):
+            params_grads = append_backward(loss, parameter_list, no_grad_set)
+            with program_guard(loss.block.program,
+                               startup_program or default_startup_program()):
+                optimize_ops = self.apply_gradients(params_grads, loss,
+                                                    startup_program)
         return optimize_ops, params_grads
 
 

@@ -28,7 +28,7 @@ from jax.profiler import TraceAnnotation
 from . import monitor
 
 __all__ = [
-    "RecordEvent", "record_event", "mark_event", "profiler",
+    "RecordEvent", "record_event", "mark_event", "build_pass", "profiler",
     "start_profiler", "stop_profiler", "reset_profiler", "is_profiling",
     "export_chrome_tracing", "summarize_events", "cuda_profiler",
     "npu_profiler",
@@ -127,6 +127,27 @@ class RecordEvent:
 
 
 record_event = RecordEvent
+
+
+@contextlib.contextmanager
+def build_pass(program, name):
+    """One whole-program pass of build over ``program`` (``append_backward``,
+    ``minimize``, ``mixed_precision``): a ``build/<name>`` span, and the
+    pass's own seconds — less those of a pass nested in it, as
+    ``append_backward`` is in ``minimize`` — added to ``program._build_s``,
+    which the program's first compile record takes as its ``build_s``
+    (``compile_cache.open_record``)."""
+    outer = getattr(_state, "nested_ns", None)
+    _state.nested_ns = 0
+    t0 = time.perf_counter_ns()
+    try:
+        with RecordEvent("build/" + name):
+            yield
+    finally:
+        whole = time.perf_counter_ns() - t0
+        own = whole - _state.nested_ns
+        _state.nested_ns = None if outer is None else outer + whole
+        program._build_s[name] = program._build_s.get(name, 0.0) + own / 1e9
 
 
 def mark_event(name):

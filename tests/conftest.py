@@ -84,6 +84,12 @@ _RESTATED = {
         "the same reading of num_hidden_layers as a width; "
         "test_bm_ouro_cell.py::test_configuration_keeps_every_published_"
         "size holds the file to the rest of that test",
+    "test_bm_ouro_cell.py::test_benchmark_json_holds_the_five_training_"
+    "cells":
+        "wants PR 33's three metrics last in per_layer and pins what its "
+        "cell reports; ISSUE 36 appends the five that move setup_s, in "
+        "every cell (test_bm_setup_metrics.py::test_benchmark_json_holds_"
+        "the_five_cells_and_five_metrics_more says what the pin meant)",
 }
 
 

@@ -4,7 +4,9 @@ the time and the HBM.
 Renders the table the program-profile registry maintains in-process
 (fingerprint, executor kind, steps, wall clock + share, flops/step,
 bytes/step, estimated peak HBM, ground-truth MFU from the compiler's
-own flop accounting) from a monitor JSONL log — the offline twin of
+own flop accounting, and from the compile records what set-up paid to
+lower it — build, trace, lowering, executable seconds — and why: a retrace
+storm reads ``first,feed_signature*40``) from a monitor JSONL log — the offline twin of
 calling ``paddle_tpu.monitor.program_profile.report_rows()`` /
 ``render_table()`` on a live registry.
 
@@ -15,7 +17,8 @@ Usage:
 
 The log must come from a run with the monitor on
 (``FLAGS_monitor_log_dir=...``): ``program_profile`` events carry each
-compiled program's cost/memory analysis, ``step_stats`` events carry the
+compiled program's cost/memory analysis, ``compile_record`` events each
+lowering's cause and phases, ``step_stats`` events carry the
 per-step fingerprint tags this report joins on, and ``device_stats``
 events (mesh runs) feed the per-device peak-HBM block — min/max across
 the mesh devices, the one-table readout of the fsdp 1/N claim.
@@ -61,7 +64,7 @@ def rows_from_records(records, peak_tflops=None, run_id=None):
     from paddle_tpu.monitor.program_profile import (ProgramProfile,
                                                     report_rows)
 
-    profiles, acct, probe_acct = {}, {}, {}
+    profiles, acct, probe_acct, lowered = {}, {}, {}, []
     partitions = {}     # fingerprint -> set of distinct partition ids
     for r in records:
         if not isinstance(r, dict):
@@ -84,6 +87,8 @@ def rows_from_records(records, peak_tflops=None, run_id=None):
                 peak_hbm_bytes=r.get("peak_hbm_bytes", 0),
                 device=r.get("device"),
                 device_kind=r.get("device_kind"))
+        elif ev == "compile_record" and r.get("fingerprint"):
+            lowered.append(r)
         elif ev == "step_stats" and r.get("fingerprint"):
             # tuner-probe steps (tagged by probe_accounting at record
             # time) accumulate separately, mirroring note_step: probe
@@ -98,7 +103,8 @@ def rows_from_records(records, peak_tflops=None, run_id=None):
             a["wall_s"] += r.get("step_seconds", 0.0) or 0.0
             a["examples"] += r.get("examples", 0) or 0
     rows = report_rows(peak_tflops=peak_tflops, profiles_by_fp=profiles,
-                       acct_by_fp=acct, probe_acct_by_fp=probe_acct)
+                       acct_by_fp=acct, probe_acct_by_fp=probe_acct,
+                       compile_records=lowered)
     # one program compiled under SEVERAL mesh/sharding layouts (the
     # replicated-vs-fsdp A/B) shares a fingerprint: step accounting
     # covers all layouts while the profile columns are the latest

@@ -235,6 +235,9 @@ def stats():
 
 
 def reset_stats():
+    """Zero the counters and forget the records, the calling thread's open
+    one too (a lowering that raised leaves one: closed later it would land
+    in a log that was cleared since)."""
     with _mu:
         for k in _STATS:
             _STATS[k] = 0
@@ -243,6 +246,7 @@ def reset_stats():
         _KERNEL_TRACES.clear()
         _LOG.clear()
         _OUTSIDE.clear()
+    _open.record = None
 
 
 def note_kernel_body(op_type, body):

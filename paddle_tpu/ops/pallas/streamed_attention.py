@@ -58,6 +58,28 @@ flight: any sequence length the HBM holds fits.
   index maps clamp to the last block the row of blocks needs, and Pallas
   skips a fetch whose block index did not change.
 
+* **The forward's state.** A head of the step keeps, in VMEM across the key
+  blocks, its float32 accumulator and two ``[bq, 128]`` float32 arrays all
+  of whose lanes are live: the running maximum ``m``, every lane of a row
+  equal, and the running sum ``l``, lane ``j`` the sum over the keys ``j mod
+  128``.  A block pair makes ONE cross-lane reduction a row (the maximum,
+  after Mosaic has taken the scores' lane tiles down to one by vector
+  maxima), whose result every lane holds, so ``m_new``, ``corr = exp(m -
+  m_new)`` and the test for a row with no key yet are full-width vector
+  operations, the subtraction from the scores and the accumulator's rescale
+  read the same registers once a lane tile, and ``l = l * corr +`` the
+  probabilities' lane tiles added up: plain vector adds.  A row's lanes of
+  ``l`` are added up once, where the output and the log-sum-exp are
+  written: the same float32 addends as a row sum a pair, in another order.
+  (Until PR 37 ``m`` and ``l`` were ``[bq, 1]`` columns, one live lane of
+  128, and a pair paid a cross-lane sum and two lane broadcasts a row group
+  more.  Mosaic's schedule of a four-head turn of the loop at 128-wide keys
+  was 7,242 bundles against the two products' 4,096 MXU cycles and is
+  4,088; on the chip the row sum cost 2.0 ms of a 7.45 ms call at the
+  long-document shape, the column maximum and its broadcasts 3.4, the
+  rescale 2.2 — overlapping — and scaling q every pair nothing, which is
+  why it stays where it was: PERF.md 6.17.)
+
 No dropout and no per-row key length: every position is real (the op falls
 back to the XLA body otherwise).  Backward is the standard flash
 decomposition (``delta = rowsum(dO * O)``, one dQ kernel, one dK/dV kernel,
@@ -107,10 +129,15 @@ _POS_BIG = 1e30
 # blocks 0.75, its float32 dK and dV and their output blocks 1.5): 25.5 MiB
 # for 4 heads, 41.5 for 8, which the rule takes and Mosaic compiles; 16 do
 # not fit.  A block's forward / dQ / dK/dV alone on a v5e, ms a call (dQ
-# and dK/dV with ``backward``'s delta), by plain heads a step:
+# and dK/dV with ``backward``'s delta), by plain heads a step, with the
+# forward's state in columns (PR 32):
 #   1: 11.56 / 12.10 / 13.95 (the kernels before plain heads shared a step,
 #   to 0.01)   2: 10.92 / 11.16 / 12.64   4: 8.81 / 10.71 / 11.94
 #   8: 8.38 / 10.56 / 11.87 — the same bits out of all of them.
+# With the state per lane (PR 37; each kernel alone, its operands given):
+#   long-document shape, 1 x 8:   3.96 (was 7.45) / 6.07 / 7.67
+#   latent shape, 8 x 1:          6.54 (was 8.38) / 10.44 / 11.58
+#   16 plain heads of 128 at T = 4096, 8 x 1:   0.62 (was 1.16) / 0.81 / 1.10
 _VMEM_BUDGET = VMEM_BUDGET
 # Heads whose text one turn of the head loop holds: the scheduler runs a
 # head's products on the MXU under its neighbour's softmax on the VPU, which
@@ -120,7 +147,9 @@ _VMEM_BUDGET = VMEM_BUDGET
 # same to its own noise (30-35 s) with any of them.  At the latent cell's,
 # 8 plain heads a step: 33.4 ms with 2, 30.8 with 4, 31.2 with all 8 (the
 # forward 10.50 / 8.38 / 9.05); 4 heads a step one at a time 34.9, by twos
-# 33.9, all four 31.5.
+# 33.9, all four 31.5.  (All read with the forward's state in columns; with
+# it per lane the schedule runs a turn's heads one after the other, each at
+# its products' MXU cycles, and the turn's size was not read again.)
 _HEADS_UNROLLED = 4
 
 
@@ -159,7 +188,10 @@ def _step_bytes(gh, bq, bk, dk, itemsize, dv=None, kh=1):
     heads, by the hungrier of dQ (a query head's Q, dQ and dO row blocks,
     two columns and its float32 accumulator; a K/V head's two blocks) and
     dK/dV (a query head's Q and dO blocks and two columns; a K/V head's two
-    blocks, its float32 dK and dV and their two output blocks)."""
+    blocks, its float32 dK and dV and their two output blocks).  The forward
+    holds less than dQ whatever the widths: a head's Q and O blocks, one
+    column, ``m`` and ``l`` (a column's bytes each, whose lanes are all
+    live now) and the accumulator."""
     dk, dv = _lanes(dk), _lanes(dk if dv is None else dv)
     column = bq * LANES * 4              # [bq, 1] float32 pads to 128 lanes
     kv = 2 * bk * (dk + dv) * itemsize
@@ -200,6 +232,13 @@ def _dot(a, b, contract, in_dtype):
     return jax.lax.dot_general(a.astype(in_dtype), b.astype(in_dtype),
                                (contract, ((), ())),
                                preferred_element_type=jnp.float32)
+
+
+def _lane_tiles(x):
+    """The 128-lane tiles of ``x``'s last axis (whole vector registers: a
+    slice at a tile's edge moves nothing)."""
+    return [x[:, i * LANES:(i + 1) * LANES]
+            for i in range(x.shape[-1] // LANES)]
 
 
 # Whole-number division of a traced index that is never negative (a grid
@@ -286,33 +325,42 @@ def _fwd_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nk, in_dtype):
         l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
         acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
 
+    def across(x, width):
+        # [bq, 128], every lane of a row equal, as [bq, width]: the same
+        # registers named width / 128 times
+        return jnp.tile(x, (1, width // LANES))
+
     def head(h, kv, bias):
         s = _scores(q_ref[0, h], k_ref[0, kv], scale, in_dtype)
         if bias is not None:
             s = s + bias[...]
+        # the block's maximum a row (its lane tiles by vector maxima, then
+        # ONE cross-lane step), held by every lane of the row as m is
         m = m_s[h]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         if bias is None:
-            p = jnp.exp(s - m_new)
+            p = jnp.exp(s - across(m_new, bk))
         else:
             # a row with no key yet keeps m = -1e30: subtract 0.0 there, so
             # that its keys' exp(-1e30) is 0.0 and not exp(0)
-            p = jnp.exp(s - jnp.where(m_new > _NEG_INF, m_new, 0.0))
+            p = jnp.exp(s - across(
+                jnp.where(m_new > _NEG_INF, m_new, 0.0), bk))
         corr = jnp.exp(m - m_new)
-        l_s[h] = l_s[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_s[h] = acc_s[h] * corr + _dot(p, v_ref[0, kv], ((1,), (0,)),
-                                          in_dtype)
+        # lane j sums the keys j mod 128; the lanes are added up at the end
+        l_s[h] = l_s[h] * corr + functools.reduce(jnp.add, _lane_tiles(p))
+        acc_s[h] = acc_s[h] * across(corr, acc_s.shape[-1]) + _dot(
+            p, v_ref[0, kv], ((1,), (0,)), in_dtype)
         m_s[h] = m_new
 
     _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal)
 
     @pl.when(ki == nk - 1)
     def _():
-        l = l_s[...]
+        l = jnp.sum(l_s[...], axis=-1, keepdims=True)
         row = l > 0.0
         o_ref[0] = (acc_s[...] / jnp.where(row, l, 1.0)).astype(o_ref.dtype)
         lse_ref[0] = jnp.where(
-            row, m_s[...] + jnp.log(jnp.maximum(l, 1e-37)), _POS_BIG)
+            row, m_s[:, :, :1] + jnp.log(jnp.maximum(l, 1e-37)), _POS_BIG)
 
 
 def _probs(q, k, lse, bias, scale, in_dtype):
@@ -447,8 +495,8 @@ def _forward(selected, q, k, v, *, heads, vmem, causal, scale, interpret):
         out_specs=[row(dv), col],
         out_shape=[jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
                    jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((n, bq, 1), jnp.float32),
-                        pltpu.VMEM((n, bq, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((n, bq, LANES), jnp.float32),
+                        pltpu.VMEM((n, bq, LANES), jnp.float32),
                         pltpu.VMEM((n, bq, dv), jnp.float32),
                         pltpu.VMEM((bq, bk), jnp.float32)],
         compiler_params=_params(vmem), interpret=interpret,

@@ -401,14 +401,23 @@ def test_kernel_traces_give_their_seconds_to_the_open_record():
     assert compile_cache.close_record(None) is None
 
 
-def test_a_record_left_open_is_closed_by_the_threads_next():
+@pytest.mark.parametrize("reset", [False, True])
+def test_a_record_left_open_is_closed_by_the_threads_next(reset):
     """``cost_analysis`` closes its own; a lowering that raised leaves its
-    record open, and the next one on the thread closes it with no call."""
+    record open, and the next one on the thread closes it with no call —
+    unless ``reset_stats`` came between: it forgets the open record with
+    the closed ones (a later test's log does not start with this one's)."""
     main, startup, loss = _build(27)
     exe = fluid.Executor(fluid.CPUPlace())
     with pytest.raises(RuntimeError, match="startup program"):
         exe.run(main, feed=_x(4), fetch_list=[loss], scope=fluid.Scope())
     assert compile_cache.compile_log() == []
+    if reset:
+        compile_cache.reset_stats()
+        _started(startup)
+        (only,) = compile_cache.compile_log()
+        assert only["first_call_s"] > 0
+        return
     scope = _started(startup)
     failed, start_rec = compile_cache.compile_log()
     assert failed["fingerprint"] == \

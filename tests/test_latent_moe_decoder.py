@@ -216,6 +216,13 @@ def test_streamed_kernel_with_192_wide_keys_matches_the_xla_body(
     assert want.shape == (1, h, t, 128)
     np.testing.assert_allclose(kernel(q, k_nope, kr, v), want, rtol=1e-5,
                                atol=1e-5)
+    # the rows' log-sum-exp, which the backward reads: the forward's running
+    # maximum and per-lane sums put together, to float32
+    k = _join(k_nope, kr)
+    np.testing.assert_allclose(
+        sa.forward(q, k, v, None, causal, scale, True)[1],
+        fa.reference_attention(q, k, v, None, None, causal, 0.0, scale, None,
+                               True)[1], rtol=1e-6, atol=2e-6)
     ct = jnp.asarray(_rand(want.shape, 12))
     args = (q, k_nope, kr, v)
     want = jax.grad(lambda *a: jnp.sum(xla(*a) * ct), (0, 1, 2, 3))(*args)
@@ -226,13 +233,14 @@ def test_streamed_kernel_with_192_wide_keys_matches_the_xla_body(
 
 
 @pytest.mark.parametrize("selected", [False, True])
-@pytest.mark.parametrize("dk,dv", [(128, 128), (192, 128)])
+@pytest.mark.parametrize("dk,dv", [(128, 128), (192, 128), (256, 256)])
 @pytest.mark.parametrize("a_step", [1, 2, 4, 8])
 def test_plain_heads_a_step_give_the_bits_of_one_head_a_step(
         a_step, dk, dv, selected, monkeypatch):
     """8 plain heads, ``a_step`` of them a grid step (the rule answers what
-    the test tells it to), causal over two by two blocks of 128, with a
-    selection or without: forward, log-sum-exp, dQ, dK and dV are, bit for
+    the test tells it to), causal over one block pair of 256 (values one
+    lane tile wide or two), with a selection or without: forward,
+    log-sum-exp, dQ, dK and dV are, bit for
     bit, those of one head a step — a head's arithmetic does not depend on
     which heads share its step — and within the standing tolerance of the
     XLA body."""

@@ -115,23 +115,26 @@ def test_streamed_kernel_compiles_for_v5e_at_the_long_document_shape(
     assert compiled.memory_analysis().temp_size_in_bytes < 512 * 1024 * 1024
 
 
-def test_streamed_kernel_compiles_for_v5e_at_the_latent_attention_shape(
-        one_chip):
+@pytest.mark.parametrize("h,t,dk,dv", [(32, 8192, 192, 128),
+                                       (16, 4096, 128, 128)])
+def test_streamed_kernel_compiles_for_v5e_at_the_plain_head_cells_shapes(
+        one_chip, h, t, dk, dv):
     """The streamed kernel at ``joyai_llm_flash.train_mtp_8k``'s shape — 32
     plain heads, keys 192 wide over values 128 wide, T = 8192, bf16, no
     selection — forward, dQ and dK/dV: Mosaic has to accept a block whose
     last axis is the array's full 192 (two lane tiles, the second half
     full) and the contraction over it, several heads a grid step with a
     ``[512, 192]`` float32 dK accumulator beside a ``[512, 128]`` one for
-    dV each, all inside the VMEM limit the kernels state, and nowhere the
-    ``[32, 8192, 8192]`` scores."""
+    dV each, the forward's per-lane state, all inside the VMEM limit the
+    kernels state, and nowhere the ``[32, 8192, 8192]`` scores.  And at
+    ``ouro_2_6b.train_loop_4k``'s: 16 plain heads of 128, T = 4096.  Eight
+    heads a grid step at both."""
     from paddle_tpu.ops.pallas import streamed_attention as sa
 
-    b, h, t, dk, dv = 1, 32, 8192, 192, 128
+    b = 1
     assert sa.supported((b, h, t, dk), (b, h, t, dk), jnp.bfloat16, True,
                         False, 0.0, dv)
-    kh, gh = sa._heads_per_step(1, h, 512, 512, dk, 2, dv)
-    assert kh > 1 and h % kh == 0 and gh == 1
+    assert sa._heads_per_step(1, h, 512, 512, dk, 2, dv) == (8, 1)
 
     def step(q, k, v, ct):
         out, lse = sa.forward(q, k, v, None, True, dk ** -0.5, False)

@@ -344,8 +344,9 @@ def _low_scoring_keys(t, first, last, seed=11):
 
 # (query heads, key/value heads, T, D, causal, selection[, plain heads a grid
 # step]): the selection is None, or (low-scoring keys' range, keys a query
-# selects); with a number of heads a step, the VMEM budget is what that many
-# plain heads take, and the rule has to find it
+# selects[, a range of query rows that select NO key]); with a number of heads
+# a step, the VMEM budget is what that many plain heads take, and the rule has
+# to find it
 _STREAMED_CASES = {
     # T = 512 is ONE 512 x 512 block pair, two heads a key/value head
     "one_block_selected": (4, 2, 512, 128, True, ((128, 256), 64)),
@@ -362,6 +363,15 @@ _STREAMED_CASES = {
     # from query 192 on no key of the FIRST key block is selected: those
     # rows pass it with no key yet, for every head of the loop in turn
     "first_key_block_empty": (8, 2, 384, 128, True, ((0, 128), 64)),
+    # from query 320 on none of the first TWO key blocks: the running maximum
+    # stays -1e30 over two pairs and every lane of the running sum 0.0, then
+    # both start in the third
+    "two_key_blocks_empty": (8, 2, 384, 128, True, ((0, 256), 64)),
+    # queries 120..199 select no key at all, in the first two query blocks:
+    # out 0.0 and log-sum-exp +1e30 exactly, no gradient from those rows
+    "rows_without_keys": (4, 2, 384, 128, True, ((128, 256), 64, (120, 200))),
+    "rows_without_keys_plain": (2, 2, 384, 128, False,
+                                ((128, 256), 64, (120, 200))),
     # 16 heads a key/value head at D = 256 in float32 are more than the
     # VMEM budget takes a step: 8 a step, two steps a block pair; T = 1024
     # is two by two blocks of 512; T = 768 three by three of 256
@@ -377,6 +387,13 @@ _STREAMED_CASES = {
     "plain6_by3_noncausal_selected": (6, 6, 384, 128, False,
                                       ((128, 256), 64), 3),
     "plain6_by2_causal": (6, 6, 384, 128, True, None, 2),
+    # ONE block pair a row of blocks, two blocks of plain heads: the state
+    # is started and the lanes of the sums added up in the same grid step
+    "plain4_by2_one_block": (4, 4, 256, 128, True, None, 2),
+    # output two lane tiles wide under the per-row factor, three by three
+    # blocks of 128
+    "plain2_d256_causal": (2, 2, 384, 256, True, None),
+    "g2_d256_selected": (4, 2, 384, 256, True, ((0, 128), 64)),
 }
 
 
@@ -389,12 +406,15 @@ def test_streamed_kernel_interpreted_matches_the_xla_body(case, monkeypatch):
     softmax as it was."""
     h, hk, t, d, causal, selection, *a_step = _STREAMED_CASES[case]
     q, k, v = _qkv(h=h, hk=hk, t=t, d=d)
-    packed = None
+    packed, empty = None, slice(0, 0)
     if selection is not None:
-        (first, last), keep = selection
+        (first, last), keep, *no_key = selection
         sel = ss.topk_key_mask(_low_scoring_keys(t, first, last), keep,
                                causal)
         assert not bool(sel[0, last + keep:, first:last].any())
+        if no_key:
+            empty = slice(*no_key[0])
+            sel = sel.at[:, empty].set(False)
         packed = ss.pack_key_mask(sel)
     g, block = h // hk, sa._pick_blocks(t)
     if a_step:
@@ -410,8 +430,21 @@ def test_streamed_kernel_interpreted_matches_the_xla_body(case, monkeypatch):
 
     def kernel(q, k, v):
         return sa.streamed_attention(q, k, v, packed, causal, None, True)
-    np.testing.assert_allclose(kernel(q, k, v), xla(q, k, v), rtol=1e-5,
-                               atol=1e-5)
+    # the forward's two results: the output, and the rows' log-sum-exp —
+    # the running maximum and the sums' lanes put together — to float32
+    out, lse = sa.forward(q, k, v, packed, causal, None, True)
+    want, want_lse = fa.reference_attention(q, k, v, None, None, causal, 0.0,
+                                            None, packed, True)
+    assert lse.shape == (1, h, t, 1) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(lse, want_lse, rtol=1e-6, atol=2e-6)
+    # a row with no key: exactly +1e30 and 0.0; every other row is real
+    no_key = np.zeros(t, bool)
+    no_key[empty] = True
+    np.testing.assert_array_equal(np.asarray(lse)[0, :, no_key], np.float32(
+        1e30))
+    np.testing.assert_array_equal(np.asarray(out)[0, :, no_key], 0.0)
+    assert (np.asarray(lse)[0, :, ~no_key] < 1e3).all()
     ct = jnp.asarray(_rand(q.shape, 12))
     want = jax.grad(lambda *a: jnp.sum(xla(*a) * ct), (0, 1, 2))(q, k, v)
     got = jax.grad(lambda *a: jnp.sum(kernel(*a) * ct), (0, 1, 2))(q, k, v)
@@ -426,6 +459,7 @@ def test_streamed_kernel_interpreted_matches_the_xla_body(case, monkeypatch):
     (32, 2, 128, 128, 128, 2),
     # plain heads: several K/V heads a step, each with its own blocks
     (1, 32, 512, 192, 128, 2),       # the latent-attention cell
+    (1, 16, 512, 128, 128, 2),       # the looped decoder's cell
     (1, 32, 512, 128, 128, 2), (1, 6, 512, 256, 256, 4),
     (1, 7, 512, 256, 256, 4), (1, 3, 128, 128, 128, 2), (1, 1, 512, 128, 128, 2),
 ])
@@ -455,7 +489,8 @@ def test_heads_per_step_is_a_divisor_inside_the_vmem_budget(g, hk, block, dk,
               (1, 3, 128): (3, 1), (1, 1, 128): (1, 1),
               # the latent cell's 32 plain heads at 192 / 128 bf16, 512
               # blocks: 8 a step fit (41.5 MiB of the 48), 16 do not
-              (1, 32, 192): (8, 1), (1, 32, 128): (8, 1)}
+              (1, 32, 192): (8, 1), (1, 32, 128): (8, 1),
+              (1, 16, 128): (8, 1)}
     if (g, hk, dk) in expect:
         assert (kh, gh) == expect[(g, hk, dk)]
     if (g, hk, dk, dv) == (1, 32, 192, 128):

@@ -45,12 +45,13 @@ from paddle_tpu import compile_cache, recordio, serving
 from paddle_tpu.contrib import mixed_precision
 from paddle_tpu.models import transformer as tfm
 from paddle_tpu.monitor import program_profile
-from paddle_tpu.ops import moe, sparse_select
+from paddle_tpu.ops import moe, sparse_select, state_space
 from paddle_tpu.ops.activation import rotary_tables
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import layer_norm as pallas_ln
 from paddle_tpu.ops.pallas import packed_attention as pa
 from paddle_tpu.ops.pallas import quant_matmul as pallas_qm
+from paddle_tpu.ops.pallas import selective_scan
 from paddle_tpu.ops.pallas import softmax_xent as pallas_xent
 from paddle_tpu.ops.pallas import streamed_attention as sa
 from paddle_tpu.ops.pallas import topk_select
@@ -528,6 +529,51 @@ def grouped_experts_through_the_op(name, total, chunks):
     return errs
 
 
+def selective_scan_kernels(b, t, e, n, chunk=64):
+    """A state-space layer's scan at the hybrid cell's shape — ``[b, t, e]``
+    channels, ``n`` states a channel, float32 — through the chunked Pallas
+    kernels against the op's XLA body: the output, the final state, the
+    chunks' starting states, and all six gradients."""
+    if not selective_scan.supported((b, t, e), n, chunk):
+        raise AssertionError("selective_scan.supported rejects the shape")
+    delta = jax.nn.softplus(normal(1, (b, t, e), jnp.float32) - 3.0)
+    a = -jnp.broadcast_to(jnp.arange(1, n + 1, dtype=jnp.float32), (e, n))
+    args = (delta, normal(2, (b, t, e), jnp.float32), a,
+            normal(3, (b, t, n), jnp.float32),
+            normal(4, (b, t, n), jnp.float32),
+            normal(5, (e,), jnp.float32))
+    dy = normal(6, (b, t, e), jnp.float32)
+
+    def kernels(*args):
+        y, state, starts = selective_scan.forward(*args[:6], chunk, False)
+        return (y, state, starts) + selective_scan.backward(
+            *args[:6], starts, args[6], chunk, False)
+
+    def body(*args):
+        (y, state, starts), vjp = jax.vjp(
+            lambda *a: state_space.scan_xla(*a, chunk), *args[:6])
+        return (y, state, starts) + vjp((args[6], jnp.zeros_like(state),
+                                         jnp.zeros_like(starts)))
+    kernel = mosaic_jit(kernels, *args, dy)
+    got, want = kernel(*args, dy), jax.jit(body)(*args, dy)
+    names = ("y", "state", "starts", "d_delta", "dx", "dA", "dB", "dC", "dD")
+    errs = {what: close(g, w, TOL_KERNEL["float32"], "selective_scan " + what)
+            for what, g, w in zip(names, got, want)}
+
+    def ms(fn):
+        jax.block_until_ready(fn(*args, dy))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            out = fn(*args, dy)
+        jax.block_until_ready(out)
+        return round((time.perf_counter() - t0) / 5 * 1e3, 3)
+    errs["ms_per_call"] = {"chunked": ms(kernel)}
+    log("kernel selective_scan [%d, %d, %d] x %d: %s (ms: forward + backward, "
+        "host clock over 5 calls; information, not a metric)"
+        % (b, t, e, n, errs))
+    return errs
+
+
 def phase_kernels():
     out = {}
 
@@ -584,13 +630,14 @@ def phase_kernels():
     packed("packed_attention_causal", SEQ, True)
     packed("packed_attention_cross", 2 * SEQ, False)
 
-    def streamed(name, h, hk, t, d, keep, dv=None):
+    def streamed(name, h, hk, t, d, keep, dv=None, window=None):
         """The long-document kernels (K/V streamed by blocks, several heads
         a grid step — a key/value head's query group, or some of the plain
         heads and not all — the packed selection when ``keep`` keys a query
         are selected, values ``dv`` wide) at a causal shape of several
         block pairs, bf16: forward, dQ, dK and dV against the XLA body over
-        the same selection."""
+        the same selection; under ``window`` over each query's nearest
+        ``window`` keys."""
         dv = d if dv is None else dv
         if not sa.supported((1, h, t, d), (1, hk, t, d), jnp.bfloat16, True,
                             False, 0.0, dv):
@@ -608,9 +655,9 @@ def phase_kernels():
         out[name] = check_kernel(
             name,
             lambda q, k, v: sa.streamed_attention(q, k, v, words, True,
-                                                  None, False),
-            lambda q, k, v: fa.reference_attention(q, k, v, None, None, True,
-                                                   0.0, None, words),
+                                                  None, False, window),
+            lambda q, k, v: fa.reference_attention(
+                q, k, v, None, None, True, 0.0, None, words, False, window),
             args, 3, TOL_KERNEL["matmul"])
         if h == hk:
             out[name]["one_head_a_step"] = same_bits_as_one_head_a_step(
@@ -631,6 +678,12 @@ def phase_kernels():
     # 128 wide, rotary on the whole head, T = 4096
     out["streamed_attention_plain_128"] = {"step": plain_heads_through_the_op(
         16, 4096, 128, 128, rope_theta=1e6)}
+    # differential attention's shape: two query heads a key/value head, keys
+    # 64 wide (half a lane tile) over values 128 wide, T = 4096 in eight
+    # blocks, under a 512-key window (two of a row's blocks run) and without
+    streamed("streamed_attention_window", 20, 10, 4096, 64, None, 128, 512)
+    streamed("streamed_attention_pairs", 20, 10, 4096, 64, None, 128)
+    out["selective_scan"] = selective_scan_kernels(1, 4096, 5120, 16)
     out["grouped_experts"] = grouped_experts_through_the_op(
         "grouped_experts", 128, 1)
     out["grouped_experts_chunks"] = grouped_experts_through_the_op(

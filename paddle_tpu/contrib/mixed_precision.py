@@ -67,6 +67,11 @@ class AutoMixedPrecisionLists:
     # holds none of their ops, so they must not rename it.
     WHITE_SPARSE_MOE = {"indexer_score", "moe_expert_ffn"}
     BLACK_SPARSE_MOE = {"moe_router"}
+    # A state-space layer's recurrence (ops/state_space.py): its step size,
+    # ``A``, state and output stay float32 — 4096 steps multiply a state by
+    # ``exp(delta A)`` and a bf16 step size compounds.  ``causal_conv1d`` is
+    # on no list, like layer_norm.  Apart from BLACK for the reason above.
+    BLACK_STATE_SPACE = {"selective_scan"}
     # white ops that round their own operands: the grouped expert products
     # take X and the expert matrices in bf16 but keep the routing weights,
     # the incoming gradient and every sum in float32, which a cast of all
@@ -82,14 +87,16 @@ class AutoMixedPrecisionLists:
     def colour(self, op_type):
         """"white", "black" or None (follow the inputs) for a forward op
         type: the lists first — a custom entry overrides a default — then
-        the sparse-attention mixture-of-experts defaults."""
+        the sparse-attention mixture-of-experts and state-space
+        defaults."""
         if op_type in self.white_list:
             return "white"
         if op_type in self.black_list:
             return "black"
         if op_type in self.WHITE_SPARSE_MOE:
             return "white"
-        if op_type in self.BLACK_SPARSE_MOE:
+        if op_type in self.BLACK_SPARSE_MOE \
+                or op_type in self.BLACK_STATE_SPACE:
             return "black"
         return None
 

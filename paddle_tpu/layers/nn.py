@@ -20,6 +20,8 @@ __all__ = [
     "softmax_with_cross_entropy",
     "exit_gate_loss",
     "fused_attention",
+    "selective_scan",
+    "causal_conv1d",
     "paged_attention",
     "rms_norm",
     "rotary_embedding",
@@ -268,7 +270,8 @@ def exit_gate_loss(hiddens, token_losses, beta=0.0, param_attr=None,
 
 
 def fused_attention(q, k, v, k_len=None, causal=False, dropout_rate=0.0,
-                    is_test=False, scale=None, selected=None, name=None):
+                    is_test=False, scale=None, selected=None, name=None,
+                    window=None):
     """Flash attention over head-split tensors q/k/v [B, H, T, D].
 
     k and v may carry fewer heads than q (grouped-query attention: H a
@@ -279,7 +282,10 @@ def fused_attention(q, k, v, k_len=None, causal=False, dropout_rate=0.0,
     self-attention too long for the resident-K/V kernel
     (``ops.attention.streams_plain_heads``), a TPU trace takes the kernel
     that streams K/V by blocks (``ops/pallas/streamed_attention.py``) and
-    the CPU the XLA body.
+    the CPU the XLA body.  ``window`` (an int, with ``causal``, Tq == Tk, no
+    ``k_len`` and no dropout) keeps of a query's keys the nearest
+    ``window``: key ``s`` counts for query ``t`` iff ``t - window < s <=
+    t``; it runs on those two bodies too.
 
     ``k_len`` [B] int masks padded key positions; ``causal`` adds the
     autoregressive mask.  One op, identical semantics in every body; the
@@ -307,6 +313,14 @@ def fused_attention(q, k, v, k_len=None, causal=False, dropout_rate=0.0,
     marked = selected is None and k.shape[1] == q.shape[1] \
         and streams_plain_heads(q.shape, k.shape, v.shape, k_len is not None,
                                 float(dropout_rate))
+    if window is not None:
+        if k_len is not None or dropout_rate:
+            raise ValueError("fused_attention: a window takes no k_len and "
+                             "no dropout")
+        # only an op with a window carries the attribute: a program
+        # without one keeps its text, and its compiled module its name
+        attrs["window"] = int(window)
+        marked = True
     if marked:
         attrs["keep_lse"] = True
     if marked or selected is not None or k.shape[1] != q.shape[1]:
@@ -318,6 +332,57 @@ def fused_attention(q, k, v, k_len=None, causal=False, dropout_rate=0.0,
     helper.append_op(
         type="fused_attention", inputs=inputs, outputs=outputs, attrs=attrs,
     )
+    return out
+
+
+def selective_scan(x, delta, a, b, c, d, delta_bias=None, chunk=64,
+                   name=None):
+    """A state-space (Mamba) layer's recurrence over time, in float32:
+    ``step_t = softplus(delta_t + delta_bias)``, ``s_t = exp(step_t (x) 1 *
+    a) * s_{t-1} + (step_t * x_t) (x) b_t`` from ``s_0 = 0``, ``y_t = s_t c_t
+    + d * x_t``.  ``x`` and ``delta`` (the step size BEFORE its softplus) are
+    ``[B, T, E]``, ``a`` ``[E, N]`` (negative), ``b`` and ``c`` ``[B, T, N]``,
+    ``d`` and ``delta_bias`` ``[E]`` — variables, whoever made them.  Returns
+    ``(y [B, T, E], state [B, E, N])``: ``state`` is ``s_T`` and carries no
+    gradient.  On a TPU the op and its gradient are Pallas kernels chunked
+    over time, ``chunk`` steps a chunk, the backward recomputing a chunk
+    from the state the forward kept at its start
+    (``ops/pallas/selective_scan.py``); elsewhere an XLA ``lax.scan`` over
+    chunks.  Under mixed precision the op and its gradient are float32."""
+    helper = LayerHelper("selective_scan", name=name)
+    y, state, starts = (helper.create_variable_for_type_inference(
+        dtype="float32") for _ in range(3))
+    state.stop_gradient = starts.stop_gradient = True
+    inputs = {"X": [x], "Delta": [delta], "A": [a], "B": [b], "C": [c],
+              "D": [d]}
+    if delta_bias is not None:
+        inputs["DeltaBias"] = [delta_bias]
+    helper.append_op(type="selective_scan", inputs=inputs,
+                     outputs={"Out": [y], "State": [state],
+                              "Starts": [starts]},
+                     attrs={"chunk": int(chunk)})
+    return y, state
+
+
+def causal_conv1d(x, width, act=None, param_attr=None, bias_attr=None,
+                  name=None):
+    """A short causal depthwise convolution over time: ``y_t = act(bias +
+    sum_j w[j] * x_{t - (width - 1) + j})`` for ``x`` ``[B, T, E]``, every
+    channel reading itself alone and zeros before the start; ``w`` ``[width,
+    E]``, ``bias`` ``[E]`` (``bias_attr=False``: none), ``act`` None or
+    ``"silu"``.  ``width`` shifted multiply-adds."""
+    helper = LayerHelper("causal_conv1d", param_attr=param_attr,
+                         bias_attr=bias_attr, name=name)
+    e = x.shape[-1]
+    inputs = {"X": [x], "W": [helper.create_parameter(
+        attr=helper.param_attr, shape=[int(width), e], dtype="float32")]}
+    if bias_attr is not False:
+        inputs["Bias"] = [helper.create_parameter(
+            attr=helper.bias_attr, shape=[e], dtype="float32", is_bias=True)]
+    out = helper.create_variable_for_type_inference(dtype=x.dtype)
+    helper.append_op(type="causal_conv1d", inputs=inputs,
+                     outputs={"Out": [out]},
+                     attrs={"activation": act or ""})
     return out
 
 

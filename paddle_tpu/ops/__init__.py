@@ -34,4 +34,5 @@ from . import (  # noqa: F401
     selected_rows,
     sequence,
     sparse_select,
+    state_space,
 )

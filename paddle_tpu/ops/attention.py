@@ -28,7 +28,11 @@ choice changes schedule, not math:
   the blockwise kernel that streams K/V by blocks and applies the
   selection per block (``ops/pallas/streamed_attention.py``); the
   [B, H, T, T] scores of such a model's long rows do not fit HBM, so there
-  is nothing to weigh it against.
+  is nothing to weigh it against.  A ``window`` attribute (causal
+  self-attention over each query's nearest ``window`` keys: key ``s`` counts
+  for query ``t`` iff ``t - window < s <= t``) runs on this body too, where
+  a block of keys wholly outside the window is neither fetched nor computed,
+  and on the XLA body elsewhere.
 * **pallas** — the long-sequence blockwise kernel
   (``ops/pallas/flash_attention.py``) under ``FLAGS_pallas_kernels`` or a
   tuned per-shape ruling (``autotune.attention_choice``), never
@@ -105,6 +109,15 @@ def _fused_attention_infer(op, block):
         raise ValueError(
             "fused_attention: causal=True requires Tq <= Tk (got %d vs "
             "%d)" % (q.shape[2], k.shape[2]))
+    if op.attrs.get("window") is not None and (
+            not op.attrs.get("causal", False) or q.shape[2] != k.shape[2]
+            or int(op.attrs["window"]) < 1 or not op.outputs.get("LSE")):
+        raise ValueError(
+            "fused_attention: a window (%r) is of causal self-attention "
+            "(Tq == Tk, got %d vs %d), at least one key wide, on the "
+            "bodies that keep the rows' log-sum-exp: build the op with "
+            "layers.fused_attention" % (op.attrs["window"], q.shape[2],
+                                        k.shape[2]))
     set_output(op, block, "Out", tuple(q.shape[:3]) + (v.shape[3],), q.dtype)
     if op.outputs.get("LSE"):
         set_output(op, block, "LSE", tuple(q.shape[:3]) + (1,), "float32")
@@ -167,14 +180,16 @@ def _fused_attention_compute(ins, attrs, ctx, op_index):
         from .pallas import interpret_mode
         from .pallas import streamed_attention as sa
 
+        # None but for a window layer: the kernels bind no such argument
+        window = attrs.get("window")
         if _streamed_applicable(ctx, q.shape, k.shape, q.dtype, causal,
                                 k_len is not None, rate, v.shape[3]):
             note_kernel_body("fused_attention", "streamed")
             # K/V heads x query heads of each that a grid step serves
             note_kernel_body("streamed_step", "%dx%d" % sa.step_heads(q, k, v))
             out, lse = sa.forward(q, k, v, selected, causal, scale,
-                                  interpret_mode(ctx))
-        elif _plain(q, k, v, selected) \
+                                  interpret_mode(ctx), window)
+        elif _plain(q, k, v, selected) and window is None \
                 and _ring_selected(ctx, q.shape, k.shape, causal):
             # a sequence-parallel mesh keeps its ring, which keeps no
             # log-sum-exp: its gradient differentiates the body
@@ -185,7 +200,8 @@ def _fused_attention_compute(ins, attrs, ctx, op_index):
         else:
             note_kernel_body("fused_attention", "xla")
             out, lse = fa.reference_attention(
-                q, k, v, k_len, seed, causal, rate, scale, selected, True)
+                q, k, v, k_len, seed, causal, rate, scale, selected, True,
+                window)
         if post is not None:
             out = out * jnp.asarray(post, out.dtype)
         return {"Out": out, "LSE": lse}
@@ -266,7 +282,8 @@ def _fused_attention_grad_compute(ins, attrs, ctx, op_index):
         if post is not None:
             dout = dout * jnp.asarray(post, dout.dtype)
         dq, dk, dv = sa.backward(q, k, v, selected, out, lse, dout, causal,
-                                 scale, interpret_mode(ctx))
+                                 scale, interpret_mode(ctx),
+                                 attrs.get("window"))
         return {"GRAD::Q": [dq], "GRAD::K": [dk], "GRAD::V": [dv]}
     if dout is None or _ring_selected(ctx, q.shape, k.shape, causal) \
             or not _packed_applicable(ctx, q.shape, k.shape, q.dtype, causal):

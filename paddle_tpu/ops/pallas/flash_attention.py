@@ -460,14 +460,17 @@ def paged_attention(q, k_pool, v_pool, table, k_len, k_scale=None,
 
 
 def reference_attention(q, k, v, k_len, seed, causal=False, dropout_rate=0.0,
-                        scale=None, selected=None, with_lse=False):
+                        scale=None, selected=None, with_lse=False,
+                        window=None):
     """XLA fallback with bit-identical semantics (same hash dropout mask):
     used when the pallas flag is off or shapes exceed the VMEM budget.
     K/V of fewer heads than Q are read by whole groups of query heads;
     ``selected`` is the packed per-query key mask of
-    ``ops/sparse_select.py``.  ``with_lse`` also returns the rows'
-    log-sum-exp ``[B, H, Tq, 1]`` (+1e30 for a row with no valid key, as
-    the kernels write it)."""
+    ``ops/sparse_select.py``.  ``window`` (with ``causal``, self-attention)
+    keeps of a query's keys the nearest ``window``: key ``s`` counts for
+    query ``t`` iff ``t - window < s <= t``.  ``with_lse`` also returns the
+    rows' log-sum-exp ``[B, H, Tq, 1]`` (+1e30 for a row with no valid key,
+    as the kernels write it)."""
     b, h, tq, d = q.shape
     tk = k.shape[2]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
@@ -479,7 +482,7 @@ def reference_attention(q, k, v, k_len, seed, causal=False, dropout_rate=0.0,
         g = h // k.shape[1]
         q5 = q.reshape(b, k.shape[1], g, tq, d)
         outs = [reference_attention(q5[:, :, i], k, v, k_len, seed, causal,
-                                    0.0, scale, selected, True)
+                                    0.0, scale, selected, True, window)
                 for i in range(g)]
         out = jnp.stack([o for o, _ in outs], 2).reshape(b, h, tq,
                                                          v.shape[3])
@@ -499,6 +502,8 @@ def reference_attention(q, k, v, k_len, seed, causal=False, dropout_rate=0.0,
     if causal:
         valid = valid & _causal_valid(gq[None, None], gk[None, None],
                                       klen.reshape(b, 1, 1, 1), tq, tk)
+    if window is not None:
+        valid = valid & (gq - gk < window)[None, None]
     if selected is not None:
         from ..sparse_select import unpack_key_mask
         valid = valid & unpack_key_mask(selected, tk)[:, None]

@@ -57,6 +57,16 @@ flight: any sequence length the HBM holds fits.
   the diagonal run no arithmetic (``pl.when``) and fetch nothing: their
   index maps clamp to the last block the row of blocks needs, and Pallas
   skips a fetch whose block index did not change.
+* **Window.** ``window`` (with ``causal``): key ``s`` counts for query ``t``
+  iff ``t - window < s <= t``.  The pairs' scratch gains the lower edge in
+  the blocks it crosses; a block wholly below it runs nothing and fetches
+  nothing, its index clamped from below as the diagonal clamps it from
+  above (at 512-key blocks a 512-key window needs two of a row's blocks).
+  A row may meet its first key in the SECOND block that runs for it, which
+  the forward's state allows as it does under a selection.  A call without
+  a window is bound, traced and lowered as it was before there was one: no
+  operand, scratch or comparison is added, and ``window`` is not among its
+  statics.
 
 * **The forward's state.** A head of the step keeps, in VMEM across the key
   blocks, its float32 accumulator and two ``[bq, 128]`` float32 arrays all
@@ -256,14 +266,17 @@ def _split(refs, has_sel, n_in):
     return None, refs[:n_in - 1], refs[n_in - 1:]
 
 
-def _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal):
+def _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal,
+               window=None):
     """Block pair (qi, ki) for the step's ``kh * gh`` query heads:
     ``head(h, kv, bias)`` for each, where ``kv`` is the one of the step's
     ``kh`` K/V heads that query head ``h`` reads (``h // gh``) and ``bias``
     is ``bias_s`` — float32 ``[bq, bk]``, 0.0 for a (query, key) pair that
     counts and -1e30 for one that does not, written here once for all the
     heads — or None when every pair counts.  Nothing runs for a pair wholly
-    above the diagonal."""
+    above the diagonal, nor, under ``window`` (key ``s`` counts for query
+    ``t`` iff ``t - window < s <= t``), for one wholly below the window's
+    lower edge."""
     n = kh * gh
     together = max(m for m in range(1, _HEADS_UNROLLED + 1) if n % m == 0)
 
@@ -278,10 +291,21 @@ def _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal):
     def below_diagonal():
         rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         keys = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        return rows - keys >= ki * bk - qi * bq
+        ahead = rows - keys                 # t - s - (qi * bq - ki * bk)
+        if window is None:
+            return ahead >= ki * bk - qi * bq
+        return (ahead >= ki * bk - qi * bq) \
+            & (ahead < ki * bk - qi * bq + window)
 
     runs = ki * bk <= qi * bq + bq - 1
     crossed = runs & (ki * bk + bk - 1 > qi * bq)
+    if window is not None:
+        # the block's last key is inside the first query's window; the
+        # lower edge crosses a block whose first key is outside the last
+        # query's
+        runs = runs & (ki * bk + bk - 1 > qi * bq - window)
+        crossed = runs & ((ki * bk + bk - 1 > qi * bq)
+                          | (ki * bk <= qi * bq + bq - 1 - window))
     if sel_ref is not None:
         def block():
             words = sel_ref[0]                                  # [bq, 128]
@@ -314,7 +338,8 @@ def _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal):
         heads(None)
 
 
-def _fwd_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nk, in_dtype):
+def _fwd_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nk, in_dtype,
+                window=None):
     sel_ref, (q_ref, k_ref, v_ref), \
         (o_ref, lse_ref, m_s, l_s, acc_s, bias_s) = _split(refs, has_sel, 4)
     qi, ki = pl.program_id(2), pl.program_id(3)
@@ -352,7 +377,8 @@ def _fwd_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nk, in_dtype):
             p, v_ref[0, kv], ((1,), (0,)), in_dtype)
         m_s[h] = m_new
 
-    _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal)
+    _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal,
+               window)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -370,7 +396,8 @@ def _probs(q, k, lse, bias, scale, in_dtype):
     return jnp.exp(s - lse)                          # empty rows: lse = +BIG
 
 
-def _dq_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nk, in_dtype):
+def _dq_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nk, in_dtype,
+               window=None):
     sel_ref, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), \
         (dq_ref, acc_s, bias_s) = _split(refs, has_sel, 7)
     qi, ki = pl.program_id(2), pl.program_id(3)
@@ -386,7 +413,8 @@ def _dq_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nk, in_dtype):
         ds = p * (g - delta_ref[0, h])
         acc_s[h] += _dot(ds, k_ref[0, kv], ((1,), (0,)), in_dtype)
 
-    _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal)
+    _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal,
+               window)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -394,7 +422,7 @@ def _dq_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nk, in_dtype):
 
 
 def _dkv_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nq, nr,
-                in_dtype):
+                in_dtype, window=None):
     sel_ref, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), \
         (dk_ref, dv_ref, dk_s, dv_s, bias_s) = _split(refs, has_sel, 7)
     ki, r = pl.program_id(2), pl.program_id(3)
@@ -418,7 +446,7 @@ def _dkv_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nq, nr,
                                ((0,), (0,)), in_dtype)
 
     _each_head(head, sel_ref, bias_s, _rem(r, nq), ki, kh, gh, bq, bk,
-               causal)
+               causal, window)
 
     @pl.when(r == nr - 1)
     def _():
@@ -448,17 +476,24 @@ def step_heads(q, k, v):
     return _heads_per_step(g, k.shape[1], bq, bk, dk, q.dtype.itemsize, dv)
 
 
-def _row_specs(g, kh, gh, bq, bk, causal):
+def _row_specs(g, kh, gh, bq, bk, causal, window=None):
     """Block specs of a grid (batch, block of ``kh * gh`` query heads, query
     block, key block): (the heads' query-row blocks ``[kh * gh, bq, d]`` for
     a width ``d``, a ``[kh * gh, bq, 1]`` column of them, the ``d``-wide K/V
     blocks ``[kh, bk, d]`` of the heads' K/V heads, the selection's word
     tile).  Under ``causal`` the key index clamps to the last block the
-    query block needs, so a skipped step fetches nothing."""
+    query block needs — and under ``window`` to the first, from below —
+    so a skipped step fetches nothing."""
     per_tile = KEYS_PER_TILE // bk
 
     def key_block(qi, ki):
-        return jnp.minimum(ki, _div(qi * bq + bq - 1, bk)) if causal else ki
+        if not causal:
+            return ki
+        ki = jnp.minimum(ki, _div(qi * bq + bq - 1, bk))
+        if window is None:
+            return ki
+        return jnp.maximum(ki, _div(jnp.maximum(qi * bq - window + 1, 0),
+                                    bk))
 
     def q_map(bi, hi, qi, ki):
         return (bi, hi, qi, 0)
@@ -480,16 +515,23 @@ def _given(*operands):
     return [x for x in operands if x is not None]
 
 
-def _forward(selected, q, k, v, *, heads, vmem, causal, scale, interpret):
+def _windowed(window):
+    """A kernel's ``window`` argument where there is one (a kernel without
+    is bound as it was before there was one)."""
+    return {} if window is None else {"window": window}
+
+
+def _forward(selected, q, k, v, *, heads, vmem, causal, scale, interpret,
+             window=None):
     b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v)
     kh, gh = heads
-    row, col, kv, sel = _row_specs(g, kh, gh, bq, bk, causal)
+    row, col, kv, sel = _row_specs(g, kh, gh, bq, bk, causal, window)
     has_sel = selected is not None
     n = kh * gh
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           has_sel=has_sel, kh=kh, gh=gh, bq=bq, bk=bk, nk=nk,
-                          in_dtype=q.dtype),
+                          in_dtype=q.dtype, **_windowed(window)),
         grid=(b, h // n, nq, nk),
         in_specs=([sel] if has_sel else []) + [row(dk), kv(dk), kv(dv)],
         out_specs=[row(dv), col],
@@ -504,17 +546,17 @@ def _forward(selected, q, k, v, *, heads, vmem, causal, scale, interpret):
 
 
 def _dq(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
-        scale, interpret):
+        scale, interpret, window=None):
     """dQ: grid (B, blocks of kh x gh heads, query blocks, key blocks)."""
     b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v)
     kh, gh = heads
     n = kh * gh
     has_sel = selected is not None
-    row, col, kv, sel = _row_specs(g, kh, gh, bq, bk, causal)
+    row, col, kv, sel = _row_specs(g, kh, gh, bq, bk, causal, window)
     return pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           has_sel=has_sel, kh=kh, gh=gh, bq=bq, bk=bk, nk=nk,
-                          in_dtype=q.dtype),
+                          in_dtype=q.dtype, **_windowed(window)),
         grid=(b, h // n, nq, nk),
         in_specs=([sel] if has_sel else [])
         + [row(dk), kv(dk), kv(dv), row(dv), col, col],
@@ -527,7 +569,7 @@ def _dq(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
 
 
 def _dkv(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
-         scale, interpret):
+         scale, interpret, window=None):
     """dK, dV: grid (B, blocks of kh K/V heads, key blocks, a group's blocks
     of gh heads x query blocks); kh > 1 only if gh == g."""
     b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v)
@@ -541,7 +583,13 @@ def _dkv(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
 
     def clamp_q(ki, r):
         qi = _rem(r, nq)
-        return jnp.maximum(qi, first_q(ki)) if causal else qi
+        if not causal:
+            return qi
+        qi = jnp.maximum(qi, first_q(ki))
+        if window is None:
+            return qi
+        # the last query block whose window reaches key block ki
+        return jnp.minimum(qi, _div(ki * bk + bk + window - 2, bq))
 
     def q_map(bi, hk, ki, r):
         return (bi, hk * (g // gh) + _div(r, nq), clamp_q(ki, r), 0)
@@ -562,7 +610,7 @@ def _dkv(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
     return pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           has_sel=has_sel, kh=kh, gh=gh, bq=bq, bk=bk, nq=nq,
-                          nr=nr, in_dtype=q.dtype),
+                          nr=nr, in_dtype=q.dtype, **_windowed(window)),
         grid=(b, h // (g * kh), nk, nr),
         in_specs=([pl.BlockSpec((1, bq, LANES), sel_map)] if has_sel else [])
         + [row(dk), kv(dk), kv(dv), row(dv), col, col],
@@ -579,53 +627,62 @@ def _dkv(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
 _run = functools.partial(run_traced, "streamed_attention")
 
 
-def _statics(q, k, v, causal, scale, interpret):
+def _statics(q, k, v, causal, scale, interpret, window=None):
+    """A signature's statics; ``window`` is among them only where a call
+    has one, so that a call without keeps the signature — and the one
+    trace — it had."""
+    if window is not None and (not causal or int(window) < 1):
+        raise ValueError("a window (%r) is of causal attention, at least "
+                         "one key wide" % (window,))
     return dict(heads=step_heads(q, k, v), vmem=_VMEM_BUDGET,
                 causal=bool(causal),
                 scale=float(q.shape[-1] ** -0.5 if scale is None else scale),
-                interpret=bool(interpret))
+                interpret=bool(interpret),
+                **_windowed(None if window is None else int(window)))
 
 
-def forward(q, k, v, selected, causal=False, scale=None, interpret=False):
+def forward(q, k, v, selected, causal=False, scale=None, interpret=False,
+            window=None):
     """q ``[B, H, T, Dk]``; k ``[B, H / g, T, Dk]``, v ``[B, H / g, T,
-    Dv]``; ``selected`` the packed key mask ``[B, T, W]`` int32 or None.
+    Dv]``; ``selected`` the packed key mask ``[B, T, W]`` int32 or None;
+    ``window`` (with ``causal``) the keys a query reads back from its own.
     Returns the output ``[B, H, T, Dv]`` in q's dtype and the rows'
     log-sum-exp ``[B, H, T, 1]`` float32, which ``backward`` wants back."""
     out, lse = _run(_forward, (selected, q, k, v),
-                    **_statics(q, k, v, causal, scale, interpret))
+                    **_statics(q, k, v, causal, scale, interpret, window))
     return out, lse
 
 
 def backward(q, k, v, selected, out, lse, dout, causal=False, scale=None,
-             interpret=False):
+             interpret=False, window=None):
     """(dQ, dK, dV) from the forward's operands and results."""
     dout = dout.astype(q.dtype)
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), -1,
                     keepdims=True)
     operands = (selected, q, k, v, dout, lse, delta)
-    statics = _statics(q, k, v, causal, scale, interpret)
+    statics = _statics(q, k, v, causal, scale, interpret, window)
     (dq,) = _run(_dq, operands, **statics)
     dk, dv = _run(_dkv, operands, **statics)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def streamed_attention(q, k, v, selected, causal=False, scale=None,
-                       interpret=False):
+                       interpret=False, window=None):
     """``forward``'s output alone, differentiable (``jax.grad`` runs
     ``backward`` on the saved log-sum-exp)."""
-    return forward(q, k, v, selected, causal, scale, interpret)[0]
+    return forward(q, k, v, selected, causal, scale, interpret, window)[0]
 
 
-def _fwd_rule(q, k, v, selected, causal, scale, interpret):
-    out, lse = forward(q, k, v, selected, causal, scale, interpret)
+def _fwd_rule(q, k, v, selected, causal, scale, interpret, window):
+    out, lse = forward(q, k, v, selected, causal, scale, interpret, window)
     return out, (q, k, v, selected, out, lse)
 
 
-def _bwd_rule(causal, scale, interpret, res, dout):
+def _bwd_rule(causal, scale, interpret, window, res, dout):
     q, k, v, selected, out, lse = res
     return backward(q, k, v, selected, out, lse, dout, causal, scale,
-                    interpret) + (None,)
+                    interpret, window) + (None,)
 
 
 streamed_attention.defvjp(_fwd_rule, _bwd_rule)

@@ -90,6 +90,18 @@ _RESTATED = {
         "cell reports; ISSUE 36 appends the five that move setup_s, in "
         "every cell (test_bm_setup_metrics.py::test_benchmark_json_holds_"
         "the_five_cells_and_five_metrics_more says what the pin meant)",
+    "test_bm_setup_metrics.py::test_benchmark_json_holds_the_five_cells_"
+    "and_five_metrics_more":
+        "pins BENCHMARK.json to PR 36's five cells, four configurations "
+        "and wants the five set-up metrics last in per_layer; ISSUE 38 "
+        "appends phi4_mini_flash.train_reason_4k and its three metrics "
+        "(test_bm_phi4_cell.py::test_benchmark_json_holds_the_six_cells_"
+        "and_five_configurations says what the pin meant)",
+    "test_bm_contract.py::test_configuration_entry_and_file"
+    "[phi4_mini_flash]":
+        "the same reading of num_hidden_layers as a width; "
+        "test_bm_phi4_cell.py::test_configuration_keeps_every_published_"
+        "size holds the file to the rest of that test",
 }
 
 

@@ -23,6 +23,10 @@ def _clean_autotune_state():
     prev_pins = {n: flags.pinned(n)
                  for n in ("pallas_kernels", "pallas_attention_max_seq",
                            "autotune_hbm_bytes", "autotune_dir")}
+    # a test file run earlier on this worker may have left one PINNED
+    # (``set_flags`` pins by default): these tests start from none, and
+    # the pins found are put back after
+    flags._restore_pins(dict.fromkeys(prev_pins, False))
     yield
     fluid.set_flags({"FLAGS_autotune_hbm_bytes": 0,
                      "FLAGS_autotune_dir": "",

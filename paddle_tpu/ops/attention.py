@@ -40,7 +40,11 @@ choice changes schedule, not math:
 * **xla** — everything else, and every CPU trace: the XLA body.
 
 Which one a trace took is counted in
-``compile_cache.stats()["kernel_bodies"]`` (``fused_attention:<body>``).
+``compile_cache.stats()["kernel_bodies"]`` (``fused_attention:<body>``; the
+streamed body's gradient ``fused_attention_grad:streamed_fused`` — one
+backward kernel — or ``:streamed`` — dQ and dK/dV —, and the heads a grid
+step serves ``streamed_step:KxG`` forward, ``streamed_grad_step:KxG``
+backward).
 
 Masking is structural: an optional per-batch valid-key count ``KLen`` [B]
 (the ``<name>@LEN`` companion of the key sequence) and a ``causal`` attr —
@@ -253,8 +257,7 @@ def _fused_attention_grad_compute(ins, attrs, ctx, op_index):
     calls (3 kernels an attention in the compiled step) — so there the
     gradient IS the one backward kernel, over the program's own Q, K, V
     and dO.  Likewise the streamed body (grouped heads / selected keys):
-    its dQ and dK/dV kernels run on the forward op's own ``Out`` and
-    ``LSE``."""
+    its backward runs on the forward op's own ``Out`` and ``LSE``."""
     from ..registry import _generic_grad_compute
 
     fwd_index = attrs.get("__fwd_op_index__", op_index)
@@ -263,10 +266,11 @@ def _fused_attention_grad_compute(ins, attrs, ctx, op_index):
     dout = (ins.get("GRAD::Out") or [None])[0]
     selected = (ins.get("Selected") or [None])[0]
     if _keeps_lse(q, k, selected, attrs):
-        # the streamed body: its two backward kernels from the forward's
-        # own output and log-sum-exp (the generic rule would run the
-        # forward kernel a second time to get them); the XLA body and the
-        # ring differentiate themselves
+        # the streamed body: its backward — one fused kernel, or dQ and
+        # dK/dV where a K/V head's gradients do not fit VMEM — from the
+        # forward's own output and log-sum-exp (the generic rule would run
+        # the forward kernel a second time to get them); the XLA body and
+        # the ring differentiate themselves
         out = (ins.get("Out::Out") or [None])[0]
         lse = (ins.get("Out::LSE") or [None])[0]
         if dout is None or out is None or lse is None \
@@ -278,7 +282,9 @@ def _fused_attention_grad_compute(ins, attrs, ctx, op_index):
         from .pallas import interpret_mode
         from .pallas import streamed_attention as sa
 
-        note_kernel_body("fused_attention_grad", "streamed")
+        body, heads = sa.grad_step(q, k, v)
+        note_kernel_body("fused_attention_grad", body)
+        note_kernel_body("streamed_grad_step", "%dx%d" % heads)
         if post is not None:
             dout = dout * jnp.asarray(post, dout.dtype)
         dq, dk, dv = sa.backward(q, k, v, selected, out, lse, dout, causal,

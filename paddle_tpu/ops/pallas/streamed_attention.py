@@ -30,7 +30,9 @@ flight: any sequence length the HBM holds fits.
   selection or without: the body of long plain-head self-attention whose
   K/V the resident kernel cannot hold, and of latent attention): ``g'`` =
   1 and ``kh`` a divisor of the heads, each head of the step with its own
-  K/V block and, in dK/dV, its own float32 dK and dV.  What the heads of a
+  K/V block and, in the backward, its own float32 dK and dV.  The forward
+  and the backward each have the heads a step their own blocks allow
+  (``step_heads``, ``grad_step``).  What the heads of a
   step share is done ONCE a step: the selection's word tile is fetched
   once (and a group's K/V block), and which (query, key) pairs count is
   worked out once, into a float32 ``[bq, bk]`` scratch that holds 0 for a
@@ -38,10 +40,9 @@ flight: any sequence length the HBM holds fits.
   scores.  So is what a step costs whatever it holds: its prologue (the
   blocks' DMAs issued and awaited, the ``pl.when`` tests, the
   accumulators' first and last touch) and, under ``causal``, the empty
-  steps above the diagonal.  Forward and dQ run on the grid (B, H / (kh x
-  g'), query blocks, key blocks); dK/dV on (B, H / (g x kh), key blocks,
-  (g / g') x query blocks), head ``j`` of a step adding into the float32
-  dK and dV of K/V head ``j // g'``.
+  steps above the diagonal.  The forward and the backward run on the grid
+  (B, H / (kh x g'), query blocks, key blocks), query-major; head ``j`` of
+  a backward step adds into the float32 dK and dV of K/V head ``j // g'``.
 * **Selected keys.** ``selected`` is the packed bit mask of
   ``ops/sparse_select.py`` (``[B, Tq, W]`` int32; key ``s`` is bit ``(s %
   4096) // 128`` of word ``(s // 4096) * 128 + s % 128``).  One ``[bq, 128]``
@@ -91,12 +92,37 @@ flight: any sequence length the HBM holds fits.
   why it stays where it was: PERF.md 6.17.)
 
 No dropout and no per-row key length: every position is real (the op falls
-back to the XLA body otherwise).  Backward is the standard flash
-decomposition (``delta = rowsum(dO * O)``, one dQ kernel, one dK/dV kernel,
-probabilities recomputed from the saved log-sum-exp).
+back to the XLA body otherwise).
+
+* **The backward** is the flash decomposition (``delta = rowsum(dO * O)``
+  by XLA, probabilities recomputed from the saved log-sum-exp) in ONE
+  kernel: a head's block pair computes ``s = q k^T``, ``p = exp(s - lse)``,
+  ``g = dO v^T`` and ``ds = p (g - delta)`` once and makes the three
+  gradient products from them — ``dV += p^T dO``, ``dQ += ds k``, ``dK +=
+  ds^T q``: five products a pair.  dQ sums over a row's key blocks, in a
+  float32 ``[heads of the step, bq, Dk]`` scratch written at the row's last
+  block; dK and dV sum over the QUERY blocks, so the step's K/V heads'
+  WHOLE float32 dK ``[T, Dk]`` and dV ``[T, Dv]`` stay in VMEM scratch
+  across both inner axes (and across a group's head blocks, where ``g'`` <
+  ``g``): zeroed at the head group's first step, a pair adding into its key
+  block's rows, cast into the dK and dV output blocks — a K/V head's whole
+  ``[T, d]``, indexed by (batch, head block) alone, ONE buffer each — at the
+  group's last step, which Pallas writes back once.  No float32 gradient
+  ever reaches HBM.  A key block's additions arrive query blocks ascending
+  with the step's heads inside, the order the dK/dV kernel below has, so
+  the two bodies agree to the bit wherever they split a group's heads over
+  steps alike.  Where not even one K/V head's resident gradients fit the
+  budget (``T x lanes(Dk + Dv) x (4 + itemsize)`` bytes: 32k tokens at
+  128-wide bf16 heads) the backward is the two kernels the fused one came
+  from — ``_dq`` on the same grid and ``_dkv`` on (B, H / (g x kh), key
+  blocks, (g / g') x query blocks), each recomputing ``s``, ``p``, ``g``
+  and ``ds``: seven products a pair — at the forward's heads a step
+  (``grad_step``: the operands' shapes and the budget decide, nothing
+  else).
 
 A step program calls these kernels once a block with the same shapes.  Each
-of the three ``pallas_call``s is traced ONCE a signature — the operands'
+``pallas_call`` — the forward and the fused backward; three with the two
+backward kernels — is traced ONCE a signature — the operands'
 shapes and dtypes, a selection or none, the heads a step, ``causal``,
 ``scale``, ``interpret`` — into a jaxpr that ``pallas.traced`` keeps, and
 every call evaluates that jaxpr on its own operands (``pallas.run_traced``,
@@ -109,7 +135,7 @@ under that site's own name stack — the ``fluid[<op type>]`` scope by which
 the device trace is read.  Not a ``jax.jit`` around the kernels: that would
 lower once too, into shared functions under no block's scope.
 ``compile_cache.stats()["kernel_traces"]["streamed_attention"]`` counts the
-sites and the traces (18 and 3 in a step of six plain-head blocks).
+sites and the traces (12 and 2 in a step of six plain-head blocks).
 """
 
 import functools
@@ -127,8 +153,10 @@ _POS_BIG = 1e30
 
 # What a grid step may hold in VMEM, and the limit the kernels are compiled
 # under (Mosaic's default scoped limit is 16 MiB; a v5e core has 128 MiB).
-# At the long-document cell's shape — g = 8 heads a step, bq = bk = 512,
-# D = 128, bf16 — ``_step_bytes`` reads 26 MiB: a head's Q, dO and dQ blocks
+# The forward's heads a step are decided by ``_step_bytes``, the hungrier of
+# the dQ and dK/dV kernels (which run at those heads where they are the
+# backward).  At the long-document cell's shape — g = 8 heads a step, bq =
+# bk = 512, D = 128, bf16 — it reads 26 MiB: a head's Q, dO and dQ blocks
 # are 128 KB each but its ``[bq, 1]`` float32 columns (log-sum-exp, delta)
 # pad to 128 lanes, 256 KB each, and all five are double-buffered (2 MB a
 # head with the float32 accumulator, 16 MB the group); the rest is the K/V
@@ -138,9 +166,20 @@ _POS_BIG = 1e30
 # hungrier there (Q and dO blocks and two columns 1.75, its own K and V
 # blocks 0.75, its float32 dK and dV and their output blocks 1.5): 25.5 MiB
 # for 4 heads, 41.5 for 8, which the rule takes and Mosaic compiles; 16 do
-# not fit.  A block's forward / dQ / dK/dV alone on a v5e, ms a call (dQ
-# and dK/dV with ``backward``'s delta), by plain heads a step, with the
-# forward's state in columns (PR 32):
+# not fit.
+# The fused backward's step (``_fused_step_bytes``) holds dQ's blocks and,
+# resident, a K/V head's whole float32 dK and dV with the output blocks they
+# are cast into, ``T x lanes(Dk + Dv) x (4 + 2)`` bytes in bf16 — by cell,
+# the rule's heads a step, its sum and the least limit Mosaic compiles under:
+#   long document (T 8192, 128 + 128): 12 MiB a K/V head; 1 x 8: 32.0 / 31
+#   latent (T 8192, 192 -> 256 lanes + 128): 18 MiB a head; 2 x 1: 46.5 / 44
+#     (4 x 1 would take 89)
+#   looped (T 4096, 128 + 128): 6 MiB a head; 4 x 1: 37.5 / 37 (8 x 1: 72)
+#   hybrid (T 4096, 64 -> 128 lanes + 128): 6 MiB; 1 x 2: 14.0
+# and 32k tokens of 128-wide heads are 48 MiB a K/V head: the two kernels.
+# A block's forward / dQ / dK/dV alone on a v5e, ms a call (dQ and dK/dV
+# with ``backward``'s delta), by plain heads a step, with the forward's
+# state in columns (PR 32):
 #   1: 11.56 / 12.10 / 13.95 (the kernels before plain heads shared a step,
 #   to 0.01)   2: 10.92 / 11.16 / 12.64   4: 8.81 / 10.71 / 11.94
 #   8: 8.38 / 10.56 / 11.87 — the same bits out of all of them.
@@ -148,6 +187,15 @@ _POS_BIG = 1e30
 #   long-document shape, 1 x 8:   3.96 (was 7.45) / 6.07 / 7.67
 #   latent shape, 8 x 1:          6.54 (was 8.38) / 10.44 / 11.58
 #   16 plain heads of 128 at T = 4096, 8 x 1:   0.62 (was 1.16) / 0.81 / 1.10
+# The whole backward alone, the two kernels against the fused one by heads
+# a step (PR 39; ms a call with delta, the same bits wherever a group's
+# heads share steps alike):
+#   long-document shape: 13.34 | 1 x 8: 9.66   1 x 4: 10.04   1 x 2: 11.20
+#   latent shape:        20.96 | 1 x 1: 17.71  2 x 1: 16.66
+#                                (4 x 1 under a 96 MiB limit: 16.13)
+#   looped cell's shape:  1.887 | 4 x 1: 1.399  2 x 1: 1.513
+#                                (8 x 1 under 80 MiB: 1.480 — no faster)
+#   hybrid, 512-key window: 3.179 | 1 x 2: 2.457;  no window: 5.392 | 4.084
 _VMEM_BUDGET = VMEM_BUDGET
 # Heads whose text one turn of the head loop holds: the scheduler runs a
 # head's products on the MXU under its neighbour's softmax on the VPU, which
@@ -193,15 +241,17 @@ def _lanes(d):
 
 
 def _step_bytes(gh, bq, bk, dk, itemsize, dv=None, kh=1):
-    """VMEM bytes of a grid step that serves ``kh`` K/V heads of ``dk``-wide
-    keys and ``dv``-wide values (``dk`` by default), each with ``gh`` query
-    heads, by the hungrier of dQ (a query head's Q, dQ and dO row blocks,
-    two columns and its float32 accumulator; a K/V head's two blocks) and
-    dK/dV (a query head's Q and dO blocks and two columns; a K/V head's two
-    blocks, its float32 dK and dV and their two output blocks).  The forward
-    holds less than dQ whatever the widths: a head's Q and O blocks, one
-    column, ``m`` and ``l`` (a column's bytes each, whose lanes are all
-    live now) and the accumulator."""
+    """VMEM bytes of a grid step of the forward, dQ or dK/dV kernel that
+    serves ``kh`` K/V heads of ``dk``-wide keys and ``dv``-wide values
+    (``dk`` by default), each with ``gh`` query heads, by the hungrier of dQ
+    (a query head's Q, dQ and dO row blocks, two columns and its float32
+    accumulator; a K/V head's two blocks) and dK/dV (a query head's Q and
+    dO blocks and two columns; a K/V head's two blocks, its float32 dK and
+    dV and their two output blocks).  The forward, whose heads a step this
+    decides whichever body the backward takes, holds less than dQ whatever
+    the widths: a head's Q and O blocks, one column, ``m`` and ``l`` (a
+    column's bytes each, whose lanes are all live now) and the accumulator.
+    (``_fused_step_bytes`` is the fused backward's.)"""
     dk, dv = _lanes(dk), _lanes(dk if dv is None else dv)
     column = bq * LANES * 4              # [bq, 1] float32 pads to 128 lanes
     kv = 2 * bk * (dk + dv) * itemsize
@@ -214,21 +264,57 @@ def _step_bytes(gh, bq, bk, dk, itemsize, dv=None, kh=1):
     return max(dq, dkv) + shared + temporaries
 
 
-def _heads_per_step(g, hk, bq, bk, dk, itemsize, dv=None):
-    """(``kh``, ``g'``): the K/V heads one grid step serves, of ``hk``, and
-    the query heads of each, of its group of ``g`` — the most whose blocks
-    and scratch fit the budget.  Heads that share a K/V head: one K/V head a
-    step and the largest divisor of ``g`` that fits.  Plain heads (``g`` =
-    1): the largest divisor of ``hk`` that fits."""
-    def fits(kh, gh):
-        return _step_bytes(gh, bq, bk, dk, itemsize, dv, kh) <= _VMEM_BUDGET
+def _fused_step_bytes(gh, bq, bk, t, dk, itemsize, dv=None, kh=1):
+    """VMEM bytes of a grid step of the fused backward that serves ``kh``
+    K/V heads, each with ``gh`` query heads: a query head's Q, dO and dQ row
+    blocks, two columns and its float32 dQ accumulator; a K/V head's two
+    blocks and, RESIDENT across the head group's steps, its whole float32
+    dK ``[t, dk]`` and dV ``[t, dv]`` with the one-buffered output blocks
+    they are cast into; the word tile, the pairs' scratch and the head
+    loop's temporaries (Mosaic keeps few of a pair's ``[bq, bk]`` values
+    whole: the least limit it compiles under reads 1-2.5 MiB UNDER this sum
+    at the cells' shapes)."""
+    dk, dv = _lanes(dk), _lanes(dk if dv is None else dv)
+    column = bq * LANES * 4
+    rows = kh * gh * (2 * (bq * (2 * dk + dv) * itemsize + 2 * column)
+                      + bq * dk * 4)
+    kv = kh * (2 * bk * (dk + dv) * itemsize + t * (dk + dv) * (4 + itemsize))
+    shared = 2 * bq * LANES * 4 + bq * bk * 4
+    temporaries = 2 * bq * bk * 4
+    return rows + kv + shared + temporaries
 
+
+def _most_heads(g, hk, fits):
+    """(``kh``, ``g'``), the most heads a step by ``fits(kh, g')``: heads
+    that share a K/V head, one K/V head and the largest divisor of ``g``;
+    plain heads (``g`` = 1), the largest divisor of ``hk``.  None where not
+    even one head fits."""
     def most(n, ok):
-        return max(m for m in range(1, n + 1)
-                   if n % m == 0 and (m == 1 or ok(m)))
+        return max((m for m in range(1, n + 1) if n % m == 0 and ok(m)),
+                   default=None)
     if g > 1:
-        return 1, most(g, lambda m: fits(1, m))
-    return most(hk, lambda m: fits(m, 1)), 1
+        gh = most(g, lambda m: fits(1, m))
+        return None if gh is None else (1, gh)
+    kh = most(hk, lambda m: fits(m, 1))
+    return None if kh is None else (kh, 1)
+
+
+def _heads_per_step(g, hk, bq, bk, dk, itemsize, dv=None):
+    """(``kh``, ``g'``): the K/V heads one grid step of the forward (and of
+    the dQ and dK/dV kernels) serves, of ``hk``, and the query heads of
+    each, of its group of ``g`` — the most whose blocks and scratch fit the
+    budget, one head where none does."""
+    return _most_heads(g, hk, lambda kh, gh: kh * gh == 1 or _step_bytes(
+        gh, bq, bk, dk, itemsize, dv, kh) <= _VMEM_BUDGET)
+
+
+def _fused_heads_per_step(g, hk, bq, bk, t, dk, itemsize, dv=None):
+    """The same for the fused backward, whose step also holds its K/V
+    heads' whole float32 dK and dV; None where one K/V head's do not fit
+    (about 32k tokens at 128-wide heads), which is where the dQ and dK/dV
+    kernels are the backward's body."""
+    return _most_heads(g, hk, lambda kh, gh: _fused_step_bytes(
+        gh, bq, bk, t, dk, itemsize, dv, kh) <= _VMEM_BUDGET)
 
 
 def _scores(q, k, scale, in_dtype):
@@ -455,9 +541,79 @@ def _dkv_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nq, nr,
             dv_ref[0, kv] = dv_s[rows(kv)].astype(dv_ref.dtype)
 
 
-def _params(vmem):
+def _grad_kernel(*refs, scale, causal, has_sel, kh, gh, parts, bq, bk, nq,
+                 nk, in_dtype, window=None):
+    """dQ, dK and dV of a block pair from ONE ``s``, ``p``, ``g`` and ``ds``
+    (five products a pair).  dQ adds up across the key blocks in ``acc_s``;
+    the step's K/V heads' float32 dK and dV stay in ``dk_s`` and ``dv_s``
+    (K/V head ``kv``'s rows at ``kv * T``) across the query blocks, the key
+    blocks and the ``parts`` head blocks of a group, a pair adding into its
+    key block's rows."""
+    sel_ref, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), \
+        (dq_ref, dk_ref, dv_ref, acc_s, dk_s, dv_s, bias_s) = _split(
+            refs, has_sel, 7)
+    hi, qi, ki = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    first, last = (qi == 0) & (ki == 0), (qi == nq - 1) & (ki == nk - 1)
+    if parts > 1:
+        first = first & (_rem(hi, parts) == 0)
+        last = last & (_rem(hi, parts) == parts - 1)
+    t = nk * bk
+
+    def rows(kv, block):           # K/V head kv's key block of dk_s and dv_s
+        return pl.ds(pl.multiple_of(kv * t + block * bk, bk), bk)
+
+    def each_block(body):      # body(i) for every key block i, rolled
+        def step(i, carry):
+            body(i)
+            return carry
+        jax.lax.fori_loop(0, nk, step, 0)
+
+    @pl.when(first)
+    def _():
+        def zero(i):
+            for kv in range(kh):
+                dk_s[rows(kv, i)] = jnp.zeros((bk, dk_s.shape[1]), jnp.float32)
+                dv_s[rows(kv, i)] = jnp.zeros((bk, dv_s.shape[1]), jnp.float32)
+        each_block(zero)
+
+    @pl.when(ki == 0)
+    def _():
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    def head(h, kv, bias):
+        q, k, do = q_ref[0, h], k_ref[0, kv], do_ref[0, h]
+        p = _probs(q, k, lse_ref[0, h], bias, scale, in_dtype)
+        dv_s[rows(kv, ki)] += _dot(p, do, ((0,), (0,)), in_dtype)
+        g = _dot(do, v_ref[0, kv], ((1,), (1,)), in_dtype)
+        ds = (p * (g - delta_ref[0, h])).astype(in_dtype)
+        acc_s[h] += _dot(ds, k, ((1,), (0,)), in_dtype)
+        dk_s[rows(kv, ki)] += _dot(ds, q.astype(jnp.float32) * scale,
+                                   ((0,), (0,)), in_dtype)
+
+    _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal,
+               window)
+
+    @pl.when(ki == nk - 1)
+    def _():
+        dq_ref[0] = (acc_s[...] * scale).astype(dq_ref.dtype)
+
+    @pl.when(last)
+    def _():
+        def write(i):
+            block = pl.ds(pl.multiple_of(i * bk, bk), bk)
+            for kv in range(kh):
+                dk_ref[0, kv, block] = dk_s[rows(kv, i)].astype(dk_ref.dtype)
+                dv_ref[0, kv, block] = dv_s[rows(kv, i)].astype(dv_ref.dtype)
+        each_block(write)
+
+
+def _params(vmem, *inner):
+    """The kernels' compiler parameters; ``inner`` the semantics of the
+    grid's axes after the batch's, all ``parallel`` but the last by
+    default."""
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel",) + (
+            inner or ("parallel", "parallel", "arbitrary")),
         vmem_limit_bytes=vmem)
 
 
@@ -470,10 +626,24 @@ def _geometry(q, k, v):
 
 
 def step_heads(q, k, v):
-    """(K/V heads, query heads of each) that one grid step of the three
-    kernels serves for these operands."""
+    """(K/V heads, query heads of each) that one grid step of the forward
+    serves for these operands."""
     b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v)
     return _heads_per_step(g, k.shape[1], bq, bk, dk, q.dtype.itemsize, dv)
+
+
+def grad_step(q, k, v):
+    """(body, (K/V heads, query heads of each) a grid step) of the backward
+    for these operands: ``streamed_fused``, the one kernel, where a K/V
+    head's float32 dK and dV fit the budget beside a step's blocks;
+    ``streamed``, the dQ and dK/dV kernels with the forward's heads a step,
+    where they do not."""
+    b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v)
+    heads = _fused_heads_per_step(g, k.shape[1], bq, bk, t, dk,
+                                  q.dtype.itemsize, dv)
+    if heads is None:
+        return "streamed", step_heads(q, k, v)
+    return "streamed_fused", heads
 
 
 def _row_specs(g, kh, gh, bq, bk, causal, window=None):
@@ -624,17 +794,58 @@ def _dkv(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
     )(*_given(selected, q, k, v, dout, lse, delta))
 
 
+def _grad(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
+          scale, interpret, window=None):
+    """dQ, dK, dV by the fused kernel, on the forward's grid: (B, blocks of
+    kh x gh heads, query blocks, key blocks).  The dK and dV blocks are a
+    K/V head's whole ``[T, d]``, their index a function of the batch and
+    the head block alone, so they stay in VMEM — one buffer, written back
+    once — while the two inner axes (and a group's head blocks, where gh <
+    g) run, none of which is ``parallel`` therefore."""
+    b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v)
+    kh, gh = heads
+    n = kh * gh
+    has_sel = selected is not None
+    row, col, kv, sel = _row_specs(g, kh, gh, bq, bk, causal, window)
+
+    def whole(d):
+        return pl.BlockSpec(
+            (1, kh, t, d), lambda bi, hi, qi, ki: (bi, _div(hi * gh, g), 0, 0),
+            pipeline_mode=pl.Buffered(1))
+    return pl.pallas_call(
+        functools.partial(_grad_kernel, scale=scale, causal=causal,
+                          has_sel=has_sel, kh=kh, gh=gh, parts=g // gh, bq=bq,
+                          bk=bk, nq=nq, nk=nk, in_dtype=q.dtype,
+                          **_windowed(window)),
+        grid=(b, h // n, nq, nk),
+        in_specs=([sel] if has_sel else [])
+        + [row(dk), kv(dk), kv(dv), row(dv), col, col],
+        out_specs=[row(dk), whole(dk), whole(dv)],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((n, bq, dk), jnp.float32),
+                        pltpu.VMEM((kh * t, dk), jnp.float32),
+                        pltpu.VMEM((kh * t, dv), jnp.float32),
+                        pltpu.VMEM((bq, bk), jnp.float32)],
+        compiler_params=_params(
+            vmem, "parallel" if gh == g else "arbitrary", "arbitrary",
+            "arbitrary"),
+        interpret=interpret,
+    )(*_given(selected, q, k, v, dout, lse, delta))
+
+
 _run = functools.partial(run_traced, "streamed_attention")
 
 
-def _statics(q, k, v, causal, scale, interpret, window=None):
-    """A signature's statics; ``window`` is among them only where a call
-    has one, so that a call without keeps the signature — and the one
-    trace — it had."""
+def _statics(q, k, v, causal, scale, interpret, window=None, heads=None):
+    """A signature's statics (``heads`` the forward's unless given);
+    ``window`` is among them only where a call has one, so that a call
+    without keeps the signature — and the one trace — it had."""
     if window is not None and (not causal or int(window) < 1):
         raise ValueError("a window (%r) is of causal attention, at least "
                          "one key wide" % (window,))
-    return dict(heads=step_heads(q, k, v), vmem=_VMEM_BUDGET,
+    return dict(heads=heads or step_heads(q, k, v), vmem=_VMEM_BUDGET,
                 causal=bool(causal),
                 scale=float(q.shape[-1] ** -0.5 if scale is None else scale),
                 interpret=bool(interpret),
@@ -660,7 +871,10 @@ def backward(q, k, v, selected, out, lse, dout, causal=False, scale=None,
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), -1,
                     keepdims=True)
     operands = (selected, q, k, v, dout, lse, delta)
-    statics = _statics(q, k, v, causal, scale, interpret, window)
+    body, heads = grad_step(q, k, v)
+    statics = _statics(q, k, v, causal, scale, interpret, window, heads)
+    if body == "streamed_fused":
+        return tuple(_run(_grad, operands, **statics))
     (dq,) = _run(_dq, operands, **statics)
     dk, dv = _run(_dkv, operands, **statics)
     return dq, dk, dv

@@ -277,6 +277,23 @@ def test_a_window_in_every_body_against_a_dense_masked_softmax(body, window):
         assert _rel(g, w) < 5e-6
 
 
+@pytest.mark.parametrize("window,heads", [
+    (50, (1, 2)), (128, (1, 2)), (200, (1, 2)), (200, (1, 1)), (300, (1, 2)),
+    (None, (1, 2)), (None, (1, 1))])
+def test_fused_backward_under_a_window_against_the_two_kernels(
+        monkeypatch, window, heads):
+    """Keys narrower than values (64 under 128), two query heads a K/V
+    head, a window whose lower edge starts mid-block (50, 200, 300 of
+    128-key blocks), on a block's edge, and none: the fused backward
+    against ``jax.vjp`` of the XLA body and the dQ and dK/dV kernels — dQ to
+    the bit, dK and dV to the bit where the pair shares a step."""
+    from streamed_backward import check_fused_backward
+
+    q, k, v, do = _qkv(seed=8)
+    check_fused_backward(monkeypatch, q, k, v, do, None, True, DK ** -0.5,
+                         window, heads, same_bits=heads == (1, 2))
+
+
 @pytest.mark.parametrize("window", [T, 1000])
 def test_a_window_of_the_whole_sequence_is_causal_bit_for_bit(window):
     q, k, v, do = _qkv(seed=2)
@@ -352,8 +369,9 @@ def test_a_window_through_the_op_and_its_gradient_op(monkeypatch, streamed,
     assert stats.get("fused_attention:" + body, 0) \
         > before.get("fused_attention:" + body, 0)
     if streamed:
-        assert stats.get("fused_attention_grad:streamed", 0) \
-            > before.get("fused_attention_grad:streamed", 0)
+        for note in ("fused_attention_grad:streamed_fused",
+                     "streamed_grad_step:1x2"):
+            assert stats.get(note, 0) > before.get(note, 0)
     ref, vjp = jax.vjp(lambda q, k, v: _dense(q, k, v, window), q, k, v)
     for g, w in zip(got, (ref,) + vjp(do)):
         assert _rel(g, w) < 5e-6
@@ -398,11 +416,16 @@ CELL_CALLS = {
 }
 # (operands, outputs, scratch arrays, pl.when branches, comparisons) of the
 # forward, dQ and dK/dV kernels, as they were before there was a window
-# (counted on the parent commit, fd289b7)
+# (counted on the parent commit, fd289b7), and of the fused backward (PR 39:
+# dQ's operands, all three outputs, both kernels' accumulators, two more
+# branches for the resident dK and dV's first and last touch)
 NO_WINDOW_KERNELS = {
-    "train_longdoc_8k": [(4, 2, 4, 4, 14), (7, 1, 2, 4, 9), (7, 2, 3, 4, 9)],
-    "train_mtp_8k": [(3, 2, 4, 4, 10), (6, 1, 2, 4, 5), (6, 2, 3, 4, 5)],
-    "train_loop_4k": [(3, 2, 4, 4, 10), (6, 1, 2, 4, 5), (6, 2, 3, 4, 5)],
+    "train_longdoc_8k": [(4, 2, 4, 4, 14), (7, 1, 2, 4, 9), (7, 2, 3, 4, 9),
+                         (7, 3, 4, 6, 13)],
+    "train_mtp_8k": [(3, 2, 4, 4, 10), (6, 1, 2, 4, 5), (6, 2, 3, 4, 5),
+                     (6, 3, 4, 6, 9)],
+    "train_loop_4k": [(3, 2, 4, 4, 10), (6, 1, 2, 4, 5), (6, 2, 3, 4, 5),
+                      (6, 3, 4, 6, 9)],
 }
 
 
@@ -432,11 +455,12 @@ def _kernel_counts(call, operands, statics):
 
 @pytest.mark.parametrize("cell", sorted(CELL_CALLS))
 def test_a_call_without_a_window_builds_the_kernels_it_built(cell):
-    """One call of each decoder cell's shape, no window: the three kernels
-    have the operands, scratch, ``pl.when`` branches and comparisons they
-    had on the parent commit, and ``window`` is not among the statics of
-    their one trace; the same call WITH a window adds comparisons and no
-    operand or scratch."""
+    """One call of each decoder cell's shape, no window: the forward, dQ
+    and dK/dV kernels have the operands, scratch, ``pl.when`` branches and
+    comparisons they had before there was a window (the forward binds the
+    signature it had: its statics are its own heads a step and no
+    ``window``), and so has the fused backward; the same call WITH a window
+    adds comparisons and no operand or scratch."""
     qs, ks, vs, selected = CELL_CALLS[cell]
     q, k, v = (jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in (qs, ks, vs))
     sel = jax.ShapeDtypeStruct((1, qs[2], qs[2] // 32), jnp.int32) \
@@ -445,15 +469,20 @@ def test_a_call_without_a_window_builds_the_kernels_it_built(cell):
     assert "window" not in statics
     do = jax.ShapeDtypeStruct(qs[:3] + vs[3:], jnp.bfloat16)
     col = jax.ShapeDtypeStruct(qs[:3] + (1,), jnp.float32)
-    calls = [(sa._forward, (sel, q, k, v)),
-             (sa._dq, (sel, q, k, v, do, col, col)),
-             (sa._dkv, (sel, q, k, v, do, col, col))]
-    got = [_kernel_counts(c, ops, statics) for c, ops in calls]
+    body, heads = sa.grad_step(q, k, v)
+    assert body == "streamed_fused"
+    fused = sa._statics(q, k, v, True, None, False, None, heads)
+    assert "window" not in fused and fused["heads"] == heads
+    calls = [(sa._forward, (sel, q, k, v), statics),
+             (sa._dq, (sel, q, k, v, do, col, col), statics),
+             (sa._dkv, (sel, q, k, v, do, col, col), statics),
+             (sa._grad, (sel, q, k, v, do, col, col), fused)]
+    got = [_kernel_counts(c, ops, st) for c, ops, st in calls]
     assert got == NO_WINDOW_KERNELS[cell]
     windowed = sa._statics(q, k, v, True, None, False, 512)
     assert windowed["window"] == 512
-    for (c, ops), plain in zip(calls, got):
-        with_window = _kernel_counts(c, ops, windowed)
+    for (c, ops, st), plain in zip(calls, got):
+        with_window = _kernel_counts(c, ops, dict(st, window=512))
         assert with_window[:3] == plain[:3]
         assert with_window[4] > plain[4]
 

@@ -232,6 +232,24 @@ def test_streamed_kernel_with_192_wide_keys_matches_the_xla_body(
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("t,causal,h,a_step", [
+    (384, True, 4, 1), (384, True, 4, 2), (384, True, 4, 4),
+    (384, False, 4, 2), (640, True, 2, 2), (256, True, 2, 1)])
+def test_fused_backward_with_192_wide_keys_against_the_two_kernels(
+        t, causal, h, a_step, monkeypatch):
+    """Keys wider than values (192 over 128), plain heads, ``a_step`` a grid
+    step — each with its own K/V block and its own resident float32 dK
+    ``[T, 192]`` and dV ``[T, 128]``: the fused backward against ``jax.vjp``
+    of the XLA body and, bit for bit, the dQ and dK/dV kernels; three, five
+    and two blocks a row."""
+    from streamed_backward import check_fused_backward
+
+    q, k_nope, kr, v = _latent_qkv(h=h, t=t)
+    check_fused_backward(monkeypatch, q, _join(k_nope, kr), v,
+                         jnp.asarray(_rand(v.shape, 12)), None, causal,
+                         192 ** -0.5, heads=(a_step, 1))
+
+
 @pytest.mark.parametrize("selected", [False, True])
 @pytest.mark.parametrize("dk,dv", [(128, 128), (192, 128), (256, 256)])
 @pytest.mark.parametrize("a_step", [1, 2, 4, 8])
@@ -254,7 +272,9 @@ def test_plain_heads_a_step_give_the_bits_of_one_head_a_step(
 
     def kernels(n):
         monkeypatch.setattr(sa, "_heads_per_step", lambda *a: (n, 1))
+        monkeypatch.setattr(sa, "_fused_heads_per_step", lambda *a: (n, 1))
         assert sa.step_heads(q, k, v) == (n, 1)
+        assert sa.grad_step(q, k, v) == ("streamed_fused", (n, 1))
         out, lse = sa.forward(q, k, v, packed, True, scale, True)
         return (out, lse) + sa.backward(q, k, v, packed, out, lse, ct, True,
                                         scale, True)
@@ -307,11 +327,13 @@ def test_fused_attention_op_takes_values_narrower_than_keys(body,
 
     def bodies():
         got = compile_cache.stats()["kernel_bodies"]
-        # the note: both plain heads in one grid step, each its own K/V
-        # head; the last: calls that reached the three streamed kernels
+        # the notes: both plain heads in one grid step, each its own K/V
+        # head, forward and backward; the last: calls that reached the
+        # streamed kernels (the forward and the fused backward)
         return (got.get("fused_attention:" + body, 0),
-                got.get("fused_attention_grad:streamed", 0),
+                got.get("fused_attention_grad:streamed_fused", 0),
                 got.get("streamed_step:2x1", 0),
+                got.get("streamed_grad_step:2x1", 0),
                 compile_cache.stats()["kernel_traces"].get(
                     "streamed_attention", {}).get("sites", 0))
     before = bodies()
@@ -321,7 +343,7 @@ def test_fused_attention_op_takes_values_narrower_than_keys(body,
         fetch_list=[out, "q@GRAD", "k@GRAD", "v@GRAD"])
     after = bodies()
     assert tuple(x - y for x, y in zip(after, before)) == (
-        (1, 1, 1, 3) if body == "streamed" else (2, 0, 0, 0))
+        (1, 1, 1, 1, 2) if body == "streamed" else (2, 0, 0, 0, 0))
 
     def dense(q, k, v):
         return fa.reference_attention(q, k, v, None, None, True, 0.0,

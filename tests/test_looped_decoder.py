@@ -521,3 +521,24 @@ def test_plain_128_wide_heads_at_4096_are_marked_for_the_streamed_kernels():
     from paddle_tpu.ops.pallas import streamed_attention as sa
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     assert sa.step_heads(x, x, x) == (8, 1)
+    # the backward's own heads a step: four, each with its whole float32 dK
+    # and dV [4096, 128] resident beside the step's blocks
+    assert sa.grad_step(x, x, x) == ("streamed_fused", (4, 1))
+
+
+@pytest.mark.parametrize("t,a_step", [(640, 1), (640, 2), (640, 4),
+                                      (1024, 4), (384, 2)])
+def test_fused_backward_of_plain_128_wide_heads_against_the_two_kernels(
+        t, a_step, monkeypatch):
+    """The cell's heads — plain, keys and values 128 wide, ``causal`` — with
+    more than two blocks a row (five of 128, two of 512, three of 128):
+    pairs above the diagonal skipped, pairs it crosses, pairs below it; the
+    fused backward's dQ, dK and dV are the two kernels' to the bit at any
+    heads a step."""
+    from streamed_backward import check_fused_backward
+
+    rng = np.random.default_rng(t + a_step)
+    q, k, v, ct = (jnp.asarray(rng.normal(size=(1, 4, t, 128)), jnp.float32)
+                   for _ in range(4))
+    check_fused_backward(monkeypatch, q, k, v, ct, None, True, 128 ** -0.5,
+                         heads=(a_step, 1))

@@ -72,13 +72,15 @@ def test_streamed_kernel_compiles_for_v5e_at_the_long_document_shape(
         one_chip, hk):
     """The streamed kernel at ``keye_vl2_30b_a3b.train_longdoc_8k``'s shape
     — 32 query heads over 4 key/value heads of 128, T = 8192, bf16, a
-    selection — forward, dQ and dK/dV: Mosaic has to accept the bit-plane
-    unpacking of the packed key mask, the clamped block index maps, the
-    rolled loop over the eight heads a grid step serves and the VMEM their
-    ``[8, 512, 128]`` blocks, padded columns and scratch take (more than
-    the default scoped limit: the kernels state their own).  And the same
-    over 32 key/value heads: plain heads, several a grid step, each with
-    its own K/V block."""
+    selection — forward and the fused backward: Mosaic has to accept the
+    bit-plane unpacking of the packed key mask, the clamped block index
+    maps, the rolled loop over the eight heads a grid step serves, the
+    K/V head's whole float32 dK and dV ``[8192, 128]`` resident beside the
+    step's ``[8, 512, 128]`` blocks, padded columns and scratch, and their
+    one-buffered ``[8192, 128]`` output blocks (more than the default
+    scoped limit: the kernels state their own).  And the same over 32
+    key/value heads: plain heads, several a grid step, each with its own
+    K/V block and resident gradients."""
     from paddle_tpu.ops import sparse_select as ss
     from paddle_tpu.ops.pallas import streamed_attention as sa
 
@@ -88,6 +90,9 @@ def test_streamed_kernel_compiles_for_v5e_at_the_long_document_shape(
     kh, gh = sa._heads_per_step(h // hk, hk, 512, 512, d, 2)
     # 8 query heads a step over their one K/V head; plain heads by the block
     assert (kh, gh) == (1, 8) if hk == 4 else (kh > 1 and gh == 1)
+    x = [jax.ShapeDtypeStruct((b, n, t, d), jnp.bfloat16) for n in (h, hk, hk)]
+    assert sa.grad_step(*x) == ("streamed_fused",
+                                (1, 8) if hk == 4 else (2, 1))
 
     def step(q, k, v, sel, ct):
         out, vjp = jax.vjp(
@@ -109,7 +114,7 @@ def test_streamed_kernel_compiles_for_v5e_at_the_long_document_shape(
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
     assert compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"') == 3
+        'custom_call_target="tpu_custom_call"') == 2
     # no [32, 8192, 8192] scores anywhere: the temporaries are the
     # log-sum-exp and delta columns and the outputs' staging
     assert compiled.memory_analysis().temp_size_in_bytes < 512 * 1024 * 1024
@@ -121,20 +126,22 @@ def test_streamed_kernel_compiles_for_v5e_at_the_plain_head_cells_shapes(
         one_chip, h, t, dk, dv):
     """The streamed kernel at ``joyai_llm_flash.train_mtp_8k``'s shape — 32
     plain heads, keys 192 wide over values 128 wide, T = 8192, bf16, no
-    selection — forward, dQ and dK/dV: Mosaic has to accept a block whose
-    last axis is the array's full 192 (two lane tiles, the second half
-    full) and the contraction over it, several heads a grid step with a
-    ``[512, 192]`` float32 dK accumulator beside a ``[512, 128]`` one for
+    selection — forward and the fused backward: Mosaic has to accept a
+    block whose last axis is the array's full 192 (two lane tiles, the
+    second half full) and the contraction over it, several heads a grid
+    step with a resident ``[8192, 192]`` float32 dK beside a ``[8192, 128]``
     dV each, the forward's per-lane state, all inside the VMEM limit the
     kernels state, and nowhere the ``[32, 8192, 8192]`` scores.  And at
     ``ouro_2_6b.train_loop_4k``'s: 16 plain heads of 128, T = 4096.  Eight
-    heads a grid step at both."""
+    heads a grid step forward at both; backward two and four."""
     from paddle_tpu.ops.pallas import streamed_attention as sa
 
     b = 1
     assert sa.supported((b, h, t, dk), (b, h, t, dk), jnp.bfloat16, True,
                         False, 0.0, dv)
     assert sa._heads_per_step(1, h, 512, 512, dk, 2, dv) == (8, 1)
+    assert sa._fused_heads_per_step(1, h, 512, 512, t, dk, 2, dv) == (
+        (2, 1) if dk == 192 else (4, 1))
 
     def step(q, k, v, ct):
         out, lse = sa.forward(q, k, v, None, True, dk ** -0.5, False)
@@ -153,10 +160,45 @@ def test_streamed_kernel_compiles_for_v5e_at_the_plain_head_cells_shapes(
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
     assert compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"') == 3
+        'custom_call_target="tpu_custom_call"') == 2
     # (float32 scores would be 8.6 GB; the log-sum-exp and delta columns
     # pad to 128 lanes, 134 MB each, and so do the backward's stagings)
     assert compiled.memory_analysis().temp_size_in_bytes < 1024 * 1024 * 1024
+
+
+@pytest.mark.parametrize("h,hk,t,dk,dv,window,kernels", [
+    # the hybrid decoder's differential pairs: 64-wide keys under 128-wide
+    # values, two query heads a K/V head, under the 512-key window and not
+    (40, 20, 4096, 64, 128, 512, 2), (40, 20, 4096, 64, 128, None, 2),
+    # 32k tokens: a K/V head's float32 dK and dV (48 MiB with the block they
+    # are cast into) do not fit beside a step's blocks — dQ and dK/dV
+    (8, 1, 32768, 128, 128, None, 3)])
+def test_streamed_backward_compiles_for_v5e_in_either_body(
+        one_chip, h, hk, t, dk, dv, window, kernels):
+    """The body the rule picks by the operands' shapes — the fused kernel
+    with its resident gradients, or dQ and dK/dV where they do not fit —
+    compiles inside the VMEM limit the kernels state."""
+    from paddle_tpu.ops.pallas import streamed_attention as sa
+
+    def arg(n, width, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((1, n, t, width), dtype,
+                                    sharding=one_chip)
+    q, k, v = arg(h, dk), arg(hk, dk), arg(hk, dv)
+    assert sa.grad_step(q, k, v)[0] == (
+        "streamed_fused" if kernels == 2 else "streamed")
+
+    def step(q, k, v, ct):
+        out, lse = sa.forward(q, k, v, None, True, None, False, window)
+        return (out,) + sa.backward(q, k, v, None, out, lse, ct, True, None,
+                                    False, window)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(step).lower(q, k, v, arg(h, dv)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == kernels
 
 
 def _blocks_of_attention(sa, n, selected, scale, interpret):
@@ -187,12 +229,13 @@ def _kernel_traces():
 
 def test_a_step_program_traces_and_lowers_each_streamed_kernel_once(one_chip):
     """Six blocks of forward + backward at the latent cell's shape in one
-    jitted function, lowered for the described v5e: 18 call sites reach the
-    kernels and 3 jaxprs are made (``compile_cache.stats()``); the lowered
-    text holds 18 custom calls, each under ITS OWN block's Fluid scope —
-    the device trace's readers find the kernels by that scope, which is why
-    the kernels may not move into shared jitted functions.  A second
-    signature adds exactly three traces."""
+    jitted function, lowered for the described v5e: 12 call sites reach the
+    kernels — a block's forward and its fused backward — and 2 jaxprs are
+    made (``compile_cache.stats()``); the lowered text holds 12 custom
+    calls, each under ITS OWN block's Fluid scope — the device trace's
+    readers find the kernels by that scope, which is why the kernels may
+    not move into shared jitted functions.  A second signature adds exactly
+    two traces."""
     import re
 
     from paddle_tpu.ops.pallas import streamed_attention as sa
@@ -207,30 +250,30 @@ def test_a_step_program_traces_and_lowers_each_streamed_kernel_once(one_chip):
     sites, traces = _kernel_traces()
     text = jax.jit(step).lower(arg(t, dk), arg(t, dk), arg(t, dv),
                                arg(t, dv)).as_text(debug_info=True)
-    assert _kernel_traces() == (sites + 3 * blocks, traces + 3)
+    assert _kernel_traces() == (sites + 2 * blocks, traces + 2)
     calls = re.findall(
         r"stablehlo\.custom_call @tpu_custom_call.*?loc\((#loc\d+)\)\s*$",
         text, re.M)
     locs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, re.M))
-    assert len(calls) == 3 * blocks
+    assert len(calls) == 2 * blocks
     scopes = [re.search(r"fluid\[\w+\][\w.]+", locs[c]).group(0)
               for c in calls]
     assert scopes == [s for i in range(blocks) for s in
-                      ["fluid[fused_attention]out_%d" % i]
-                      + 2 * ["fluid[fused_attention_grad]q_%d.GRAD" % i]]
-    # another length: three more jaxprs; the first length again: none
+                      ["fluid[fused_attention]out_%d" % i,
+                       "fluid[fused_attention_grad]q_%d.GRAD" % i]]
+    # another length: two more jaxprs; the first length again: none
     jax.jit(step).lower(arg(t // 2, dk), arg(t // 2, dk), arg(t // 2, dv),
                         arg(t // 2, dv))
-    assert _kernel_traces() == (sites + 6 * blocks, traces + 6)
+    assert _kernel_traces() == (sites + 4 * blocks, traces + 4)
     jax.jit(lambda *a: step(*a)).lower(arg(t, dk), arg(t, dk), arg(t, dv),
                                        arg(t, dv))
-    assert _kernel_traces() == (sites + 9 * blocks, traces + 6)
+    assert _kernel_traces() == (sites + 6 * blocks, traces + 4)
 
 
 def test_a_program_traced_again_reuses_the_streamed_kernels_jaxprs():
     """The same function traced a second time (a new ``jax.jit`` of it, as
     a program lowered again is) makes no new jaxpr and, interpreted, gives
-    the first trace's results; a selection is another signature: three
+    the first trace's results; a selection is another signature: two
     more."""
     import numpy as np
 
@@ -244,15 +287,15 @@ def test_a_program_traced_again_reuses_the_streamed_kernels_jaxprs():
     step = _blocks_of_attention(sa, 2, None, dk ** -0.5, True)
     sites, traces = _kernel_traces()
     first = jax.jit(step)(*args)
-    assert _kernel_traces() == (sites + 6, traces + 3)
+    assert _kernel_traces() == (sites + 4, traces + 2)
     again = jax.jit(lambda *a: step(*a))(*args)
-    assert _kernel_traces() == (sites + 12, traces + 3)
+    assert _kernel_traces() == (sites + 8, traces + 2)
     for a, b in zip(jax.tree.leaves(first), jax.tree.leaves(again)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     words = ss.pack_key_mask(ss.topk_key_mask(
         jax.random.normal(jax.random.key(9), (1, t, t)), 64, True))
     jax.jit(_blocks_of_attention(sa, 2, words, dk ** -0.5, True))(*args)
-    assert _kernel_traces() == (sites + 18, traces + 6)
+    assert _kernel_traces() == (sites + 12, traces + 4)
 
 
 def _primitives(jaxpr):
@@ -276,16 +319,22 @@ def _primitives(jaxpr):
             yield from inside(value)
 
 
+@pytest.mark.parametrize("kernels", [2, 3])
 @pytest.mark.parametrize("hk,selected", [(32, False), (4, True), (32, True)])
-def test_no_index_of_the_streamed_kernels_divides_through_sign(hk, selected):
+def test_no_index_of_the_streamed_kernels_divides_through_sign(
+        hk, selected, kernels, monkeypatch):
     """``//`` and ``%`` on a traced index round to the floor through
     ``sign`` and a select — a dozen scalar operations each in the kernel's
     text, 480 of them once in a head loop (6.6 s of a step's lowering).
     The kernels' bodies and index maps divide with ``lax.div`` /
-    ``lax.rem``: no ``sign``, no ``floor`` in any of the three."""
+    ``lax.rem``: no ``sign``, no ``floor`` in the forward and the fused
+    backward, nor in the dQ and dK/dV kernels of a sequence whose gradients
+    VMEM does not hold."""
     from paddle_tpu.ops import sparse_select as ss
     from paddle_tpu.ops.pallas import streamed_attention as sa
 
+    if kernels == 3:
+        monkeypatch.setattr(sa, "_fused_heads_per_step", lambda *a: None)
     h, t, d = 32, 8192, 128
     q, ct = (jax.ShapeDtypeStruct((1, h, t, d), jnp.bfloat16),) * 2
     k = v = jax.ShapeDtypeStruct((1, hk, t, d), jnp.bfloat16)
@@ -296,7 +345,8 @@ def test_no_index_of_the_streamed_kernels_divides_through_sign(hk, selected):
         return sa.backward(q, k, v, words, out, lse, ct, True, None, False)
     jaxpr = jax.make_jaxpr(step)(q, k, v, ct,
                                  *([words] if selected else [])).jaxpr
-    assert [e.primitive.name for e in jaxpr.eqns].count("pallas_call") == 3
+    assert [e.primitive.name for e in jaxpr.eqns].count(
+        "pallas_call") == kernels
     inside = set(_primitives(jaxpr))
     assert {"div", "rem"} & inside and not {"sign", "floor"} & inside
 
@@ -399,9 +449,22 @@ def _tiny_expert_decoder(kind):
     return main, loss, names
 
 
+@pytest.fixture
+def no_pinned_pallas_flag():
+    """An earlier file of this worker may have left ``FLAGS_pallas_kernels``
+    pinned to False (``test_flash_attention.py`` and ``test_pallas_kernels.py``
+    set it and do not unpin), under which an op picks no kernel by shape."""
+    from paddle_tpu import flags
+
+    was = flags.pinned("pallas_kernels")
+    flags._restore_pins({"pallas_kernels": False})
+    yield
+    flags._restore_pins({"pallas_kernels": was})
+
+
 @pytest.mark.parametrize("kind", ["sparse", "latent"])
-def test_a_decoder_step_traces_and_lowers_each_grouped_kernel_once(one_chip,
-                                                                   kind):
+def test_a_decoder_step_traces_and_lowers_each_grouped_kernel_once(
+        one_chip, kind, no_pinned_pallas_flag):
     """A step of a tiny decoder with two expert layers, traced for a TPU and
     lowered for the described v5e: both ops of both layers take the grouped
     kernels, ten call sites reach them (the forward's two and the backward's

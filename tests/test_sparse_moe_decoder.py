@@ -397,6 +397,19 @@ _STREAMED_CASES = {
 }
 
 
+def _packed_selection(t, selection, causal):
+    """(the packed key mask, the query rows that select NO key) of one of
+    ``_STREAMED_CASES``' selections — (low-scoring keys' range, keys a query
+    selects[, rows without keys]) —, (None, no rows) without one."""
+    if selection is None:
+        return None, slice(0, 0)
+    (first, last), keep, *no_key = selection
+    sel = ss.topk_key_mask(_low_scoring_keys(t, first, last), keep, causal)
+    assert not bool(sel[0, last + keep:, first:last].any())
+    empty = slice(*no_key[0]) if no_key else slice(0, 0)
+    return ss.pack_key_mask(sel.at[:, empty].set(False)), empty
+
+
 @pytest.mark.parametrize("case", sorted(_STREAMED_CASES))
 def test_streamed_kernel_interpreted_matches_the_xla_body(case, monkeypatch):
     """Forward and the three gradients against ``reference_attention``.
@@ -406,16 +419,7 @@ def test_streamed_kernel_interpreted_matches_the_xla_body(case, monkeypatch):
     softmax as it was."""
     h, hk, t, d, causal, selection, *a_step = _STREAMED_CASES[case]
     q, k, v = _qkv(h=h, hk=hk, t=t, d=d)
-    packed, empty = None, slice(0, 0)
-    if selection is not None:
-        (first, last), keep, *no_key = selection
-        sel = ss.topk_key_mask(_low_scoring_keys(t, first, last), keep,
-                               causal)
-        assert not bool(sel[0, last + keep:, first:last].any())
-        if no_key:
-            empty = slice(*no_key[0])
-            sel = sel.at[:, empty].set(False)
-        packed = ss.pack_key_mask(sel)
+    packed, empty = _packed_selection(t, selection, causal)
     g, block = h // hk, sa._pick_blocks(t)
     if a_step:
         monkeypatch.setattr(sa, "_VMEM_BUDGET", sa._step_bytes(
@@ -448,6 +452,100 @@ def test_streamed_kernel_interpreted_matches_the_xla_body(case, monkeypatch):
     ct = jnp.asarray(_rand(q.shape, 12))
     want = jax.grad(lambda *a: jnp.sum(xla(*a) * ct), (0, 1, 2))(q, k, v)
     got = jax.grad(lambda *a: jnp.sum(kernel(*a) * ct), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+# (query heads, key/value heads, T, causal, selection as in _STREAMED_CASES,
+# the fused kernel's (K/V heads, query heads of each) a grid step): T = 384 is
+# three by three blocks of 128, T = 640 five by five
+_FUSED_BACKWARD_CASES = {
+    "g8_selected": (8, 1, 384, True, ((128, 256), 64), (1, 8)),
+    "g8_causal_five_blocks": (8, 1, 640, True, None, (1, 8)),
+    "g8_dense": (8, 1, 384, False, None, (1, 8)),
+    # a group's heads over two and four steps: the K/V head's dK and dV stay
+    # put while the head blocks pass, and add their heads in another order
+    "g8_by4_selected": (8, 1, 384, True, ((128, 256), 64), (1, 4)),
+    "g8_by2_causal": (8, 1, 384, True, None, (1, 2)),
+    "g2_selected": (4, 2, 384, True, ((128, 256), 64), (1, 2)),
+    "g2_by1_selected": (4, 2, 384, True, ((0, 128), 64), (1, 1)),
+    "g2_noncausal_selected": (4, 2, 384, False, ((128, 256), 64), (1, 2)),
+    # rows that select no key at all: no gradient from them, in any body
+    "rows_without_keys": (4, 2, 384, True, ((128, 256), 64, (120, 200)),
+                          (1, 2)),
+    "rows_without_keys_plain": (2, 2, 384, False,
+                                ((128, 256), 64, (120, 200)), (2, 1)),
+    # plain heads under a selection, one and several a step
+    "plain4_by1_selected": (4, 4, 384, True, ((128, 256), 64), (1, 1)),
+    "plain4_by2_selected": (4, 4, 384, True, ((0, 128), 64), (2, 1)),
+    "plain4_by4_selected": (4, 4, 384, True, ((128, 256), 64), (4, 1)),
+    # the rule's own answer
+    "g4_rule": (8, 2, 512, True, ((128, 256), 64), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FUSED_BACKWARD_CASES))
+def test_fused_backward_interpreted_against_the_two_kernels(case,
+                                                            monkeypatch):
+    """The fused backward against ``jax.vjp`` of the XLA body AND against
+    the dQ and dK/dV kernels on the same operands: dQ to the bit; dK and dV
+    to the bit where the group's heads share steps as the two kernels'
+    do."""
+    from streamed_backward import check_fused_backward
+
+    h, hk, t, causal, selection, heads = _FUSED_BACKWARD_CASES[case]
+    q, k, v = _qkv(h=h, hk=hk, t=t)
+    packed, empty = _packed_selection(t, selection, causal)
+    g = h // hk
+    dq, dk, dv = check_fused_backward(
+        monkeypatch, q, k, v, jnp.asarray(_rand(q.shape, 12)), packed, causal,
+        heads=heads, same_bits=heads is None or g == 1 or heads[1] == g)
+    np.testing.assert_array_equal(np.asarray(dq)[0, :, empty], 0.0)
+
+
+def test_resident_gradients_over_the_budget_take_the_two_kernels(monkeypatch):
+    """The backward's rule: the fused kernel where a K/V head's float32 dK
+    and dV (and the block they are cast into) fit the VMEM budget beside a
+    step's blocks, with as many heads a step as fit; the dQ and dK/dV
+    kernels, at the forward's heads a step, where one K/V head's do not —
+    by the operands' shapes alone."""
+    def grad_step(h, hk, t, dk, dv):
+        q, k, v = (jax.ShapeDtypeStruct((1, n, t, w), jnp.bfloat16)
+                   for n, w in ((h, dk), (hk, dk), (hk, dv)))
+        return sa.grad_step(q, k, v), sa.step_heads(q, k, v)
+    # the four decoder cells: all fused; the forward keeps its own heads
+    assert grad_step(32, 4, 8192, 128, 128) == (("streamed_fused", (1, 8)),
+                                                (1, 8))
+    assert grad_step(32, 32, 8192, 192, 128) == (("streamed_fused", (2, 1)),
+                                                 (8, 1))
+    assert grad_step(16, 16, 4096, 128, 128) == (("streamed_fused", (4, 1)),
+                                                 (8, 1))
+    assert grad_step(40, 20, 4096, 64, 128) == (("streamed_fused", (1, 2)),
+                                                (1, 2))
+    # T x lanes(Dk + Dv) x (4 + 2) bytes: 24 MiB at 16k tokens fit, 48 MiB
+    # at 32k do not, whatever the heads
+    assert grad_step(32, 4, 16384, 128, 128)[0][0] == "streamed_fused"
+    for h, hk in ((32, 4), (8, 8), (1, 1)):
+        (body, heads), forwards = grad_step(h, hk, 32768, 128, 128)
+        assert (body, heads) == ("streamed", forwards)
+    # and a call under such a budget runs the two kernels: three traces a
+    # signature with the forward, the standing results
+    q, k, v = _qkv(h=4, hk=2, t=384)
+    monkeypatch.setattr(sa, "_VMEM_BUDGET", sa._fused_step_bytes(
+        1, 128, 128, 384, 128, 4) - 1)
+    assert sa.grad_step(q, k, v) == ("streamed", sa.step_heads(q, k, v))
+    from paddle_tpu.ops import pallas
+    pallas.traced.cache_clear()
+    before = dict(compile_cache.stats()["kernel_traces"].get(
+        "streamed_attention", {"sites": 0, "traces": 0}))
+    ct = jnp.asarray(_rand(q.shape, 12))
+    got = jax.grad(lambda *a: jnp.sum(sa.streamed_attention(
+        *a, None, True, None, True) * ct), (0, 1, 2))(q, k, v)
+    after = compile_cache.stats()["kernel_traces"]["streamed_attention"]
+    assert (after["sites"] - before["sites"],
+            after["traces"] - before["traces"]) == (3, 3)
+    want = jax.grad(lambda *a: jnp.sum(fa.reference_attention(
+        *a, None, None, True, 0.0, None) * ct), (0, 1, 2))(q, k, v)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
@@ -548,16 +646,19 @@ def test_fused_attention_op_takes_grouped_heads_and_a_selection(body,
     def bodies():
         got = compile_cache.stats()["kernel_bodies"]
         return (got.get("fused_attention:" + body, 0),
-                got.get("fused_attention_grad:streamed", 0))
+                got.get("fused_attention_grad:streamed_fused", 0),
+                got.get("fused_attention_grad:streamed", 0),
+                got.get("streamed_grad_step:1x2", 0))
     before = bodies()
     got = fluid.Executor(fluid.CPUPlace()).run(
         main, feed=feed, fetch_list=[out, "q@GRAD", "k@GRAD", "v@GRAD"])
     # the XLA body's gradient differentiates (and so re-traces) the
-    # forward; the streamed body's runs its two backward kernels on the
-    # forward op's own output and log-sum-exp, and no second forward
+    # forward; the streamed body's runs its backward — the fused kernel, a
+    # K/V head's two query heads a step — on the forward op's own output
+    # and log-sum-exp, and no second forward
     after = bodies()
-    assert (after[0] - before[0], after[1] - before[1]) == (
-        (1, 1) if body == "streamed" else (2, 0))
+    assert tuple(a - b for a, b in zip(after, before)) == (
+        (1, 1, 0, 1) if body == "streamed" else (2, 0, 0, 0))
     want = _dense_attention(q, k, v, sel[:, None])
     np.testing.assert_allclose(got[0], want, rtol=1e-4, atol=1e-4)
     grads = jax.grad(lambda *a: jnp.sum(_dense_attention(

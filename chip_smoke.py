@@ -840,6 +840,66 @@ def phase_select_keys():
             "ms_per_call": timing}
 
 
+# the hybrid cell's lookup: ids a step, the tied table's rows and width
+EMBEDDING_GRAD = (4096, 25008, 2560)
+
+
+def phase_embedding_grad():
+    """``layers.embedding`` + ``append_backward`` at the hybrid cell's tied
+    table — 4096 ids into ``[25,008, 2560]`` float32 — through the executor
+    on the chip: the dense gradient lowers to the sorted-segment kernel
+    (``kernel_bodies``), and its rows are the scattered add's, bit for bit
+    (float32 sums of the same addends in the same order)."""
+    from paddle_tpu.ops.pallas import embedding_grad as eg
+
+    n, v, d = EMBEDDING_GRAD
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        ids = fluid.layers.data("ids", shape=[n, 1], dtype="int64")
+        weight = fluid.layers.data("weight", shape=[n, d], dtype="float32")
+        emb = fluid.layers.embedding(
+            ids, size=[v, d], param_attr=fluid.ParamAttr(name="smoke_table"))
+        loss = fluid.layers.reduce_sum(
+            fluid.layers.elementwise_mul(emb, weight))
+        fluid.backward.append_backward(loss)
+    rows = np.random.RandomState(5).randint(0, v, (1, n, 1)).astype("int64")
+    rows[0, :64, 0] = 7                       # one row takes 64 addends
+    gout = normal(21, (1, n, d), jnp.float32)
+    before = kernel_bodies("lookup_table_grad")
+    exe = fluid.Executor(fluid.TPUPlace(0))
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        got, = exe.run(main, feed={"ids": rows, "weight": np.asarray(gout)},
+                       fetch_list=["smoke_table@GRAD"], return_numpy=False)
+    bodies = bodies_since(before, "lookup_table_grad")
+    if bodies != {"lookup_table_grad:segment": 1}:
+        raise AssertionError("lookup_table_grad bodies %s, expected the "
+                             "segment one" % bodies)
+    flat = jnp.asarray(rows.reshape(-1), jnp.int32)
+    xla = jax.jit(lambda i, g: jnp.zeros((v, d), jnp.float32).at[i].add(g))
+    kernel = mosaic_jit(lambda i, g: eg.embedding_grad(i, g, v), flat,
+                        gout[0])
+    want = xla(flat, gout[0])
+    differ = int(jnp.sum(got != want)) + int(jnp.sum(
+        kernel(flat, gout[0]) != want))
+    if differ:
+        raise AssertionError("lookup_table_grad: %d elements differ from "
+                             "the scattered add's" % differ)
+
+    def ms(fn):
+        jax.block_until_ready(fn(flat, gout[0]))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            out = fn(flat, gout[0])
+        jax.block_until_ready(out)
+        return round((time.perf_counter() - t0) / 5 * 1e3, 3)
+    timing = {"segment": ms(kernel), "xla": ms(xla)}
+    log("lookup_table_grad %d rows into [%d, %d]: bodies %s, ms a call %s "
+        "(host clock over 5 calls; information, not a metric)"
+        % (n, v, d, bodies, timing))
+    return {"bodies": bodies, "ms_per_call": timing}
+
+
 # -- serving --------------------------------------------------------------------
 
 def serve_prompts():
@@ -1091,6 +1151,7 @@ def main():
     train = run("train_1chip", phase_train_1chip)
     run("kernels", phase_kernels)
     run("select_keys", phase_select_keys)
+    run("embedding_grad", phase_embedding_grad)
     run("serve_1chip", phase_serve_1chip)
     run("train_4chip", phase_train_4chip, train["losses"][0])
 

@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 
 from ..core import convert_dtype, long_dtype, materialize_dtype
-from ..registry import register_op, set_output, in_var
+from ..registry import (register_op, set_output, in_var,
+                        _generic_grad_infer)
 
 
 # -- reshape ----------------------------------------------------------------
@@ -460,6 +461,52 @@ register_op(
     compute=_lookup_table_compute, grad=_lookup_table_grad,
     no_grad_inputs=("Ids",),
 )
+
+
+# Where the segment kernel may be the dense gradient's body (tests add
+# "cpu": interpreted).
+_SEGMENT_PLATFORMS = ("tpu",)
+
+
+def segment_body(ctx, n, v, d, w_dtype, g_dtype):
+    """Whether ``lookup_table_grad`` — ``n`` rows into a ``[v, d]`` table —
+    lowers to ``ops/pallas/embedding_grad.py``: a TPU, one device, and a
+    shape the kernel takes."""
+    from .pallas import embedding_grad as eg, kernel_allowed
+
+    return kernel_allowed(ctx, _SEGMENT_PLATFORMS) \
+        and getattr(ctx, "mesh", None) is None \
+        and eg.supported(n, v, d, w_dtype, g_dtype)
+
+
+def _lookup_table_grad_compute(ins, attrs, ctx, op_index):
+    """The dense gradient (``is_sparse`` tables emit
+    ``lookup_table_sparse_grad`` instead): the sorted-segment kernel where
+    ``segment_body`` says so, elsewhere ``jax.vjp`` of the forward's
+    ``jnp.take`` — one scattered add."""
+    from ..compile_cache import note_kernel_body
+    from ..registry import _generic_grad_compute
+
+    w, ids = ins["W"][0], ins["Ids"][0]
+    gout = (ins.get("GRAD::Out") or [None])[0]
+    n, (v, d) = ids.size, w.shape
+    segment = gout is not None and segment_body(ctx, n, v, d, w.dtype,
+                                                gout.dtype)
+    note_kernel_body("lookup_table_grad", "segment" if segment else "xla")
+    if not segment:
+        return _generic_grad_compute(ins, attrs, ctx, op_index)
+    from .pallas import embedding_grad as eg, interpret_mode
+
+    grad = eg.embedding_grad(ids.reshape(-1), gout.reshape(n, d), v,
+                             attrs.get("padding_idx", -1),
+                             interpret_mode(ctx))
+    return {"GRAD::W": [grad.astype(w.dtype)]}
+
+
+# the gradient op the default grad maker emits for a dense table
+register_op("lookup_table_grad", (), (), infer=_generic_grad_infer,
+            compute=_lookup_table_grad_compute, grad=None,
+            doc="dense gradient of lookup_table")
 
 
 # -- crop (reference crop_op.cc) --------------------------------------------

@@ -24,7 +24,9 @@ VMEM: one read of the scores where the XLA body makes 46; PERF.md 6.8) and
 ``grouped_experts`` (``moe_expert_ffn`` and its gradient over the live tiles
 of the dispatch layout: a tile's expert picked in the weights' index maps,
 results combined in VMEM where the XLA body scatters 640 rows a tile; PERF.md
-6.15); ``kernel_allowed`` is the part of their rules they share, and
+6.15) and ``embedding_grad`` (``lookup_table_grad`` into a dense table: the
+sorted rows added into blocks of the table resident in VMEM, each written
+once; PERF.md 6.20); ``kernel_allowed`` is the part of their rules they share, and
 ``run_traced`` what traces and lowers a kernel once a step program.  On the
 CPU these ops keep their XLA bodies.  Under a
 ``CPUPlace`` the flag-selected kernels run in interpreter mode, which

@@ -559,11 +559,23 @@ def _sum_of(block, name):
     return op.inputs["X"]
 
 
-def test_the_tied_table_gets_the_lookups_rows_plus_the_heads_product():
+@pytest.mark.parametrize("body", ["xla", "segment"])
+def test_the_tied_table_gets_the_lookups_rows_plus_the_heads_product(
+        monkeypatch, body):
     """One parameter, two uses: the table's gradient is ONE ``sum`` of the
     lookup's scattered rows and the head's product; against the reference,
     the lookup's part alone is what an untied head leaves
-    (``head_untied``) and the rest is the head's."""
+    (``head_untied``) and the rest is the head's — with the lookup's rows
+    added by the scattered add and by the sorted-segment kernel (interpreted
+    here, where the tiny table's 97 x 64 need no whole tiles)."""
+    from paddle_tpu import compile_cache
+    from paddle_tpu.ops import manipulation
+
+    if body == "segment":
+        monkeypatch.setattr(manipulation, "segment_body",
+                            lambda ctx, *shape: ctx.mesh is None)
+    compile_cache.clear()
+    before = dict(compile_cache.stats()["kernel_bodies"])
     cfg, traffic, model = _tiny_program()
     block = model.main.global_block()
     assert not any(v.name == "out_w" for v in block.all_parameters())
@@ -596,7 +608,11 @@ def test_the_tied_table_gets_the_lookups_rows_plus_the_heads_product():
     assert _rel(got[renamed], lookup["tok_emb"]) < 1e-4
     assert _rel(got["tok_emb@GRAD"], whole["tok_emb"]) < 1e-4
     assert _rel(got["tok_emb@GRAD"] - got[renamed], head) < 1e-4
+    bodies = compile_cache.stats()["kernel_bodies"]
+    assert bodies.get("lookup_table_grad:" + body, 0) \
+        > before.get("lookup_table_grad:" + body, 0)
     model.close()
+    compile_cache.clear()
 
 
 def test_shared_keys_values_and_memory_sum_their_two_readers():

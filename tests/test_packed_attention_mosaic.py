@@ -546,3 +546,44 @@ def test_topk_select_kernel_compiles_for_v5e_at_the_long_document_shape(
     assert mem.temp_size_in_bytes < 1024 * 1024
     assert mem.output_size_in_bytes < 9 * 1024 * 1024
     assert ss.packed_width(shape[2]) == 256
+
+
+@pytest.mark.parametrize("n,v,d,dtype", [
+    (4096, 25008, 2560, jnp.float32),      # phi4_mini_flash.train_reason_4k
+    (4096, 25008, 2560, jnp.bfloat16),
+    (8192, 18992, 2048, jnp.float32),      # keye_vl2_30b_a3b.train_longdoc_8k
+    (8192, 16160, 2048, jnp.float32),      # joyai_llm_flash.train_mtp_8k
+    (4096, 6144, 2048, jnp.float32),       # ouro_2_6b.train_loop_4k
+    (16384, 32000, 512, jnp.float32),      # transformer_base.train_nmt
+    (4096, 200064, 2560, jnp.float32),     # the published tied table
+])
+def test_embedding_grad_kernel_compiles_for_v5e_at_the_cells_shapes(
+        one_chip, n, v, d, dtype):
+    """``lookup_table_grad``'s segment body at the one-chip cells' tables:
+    Mosaic has to accept the five scalar-prefetched arrays (the sorted ids
+    among them: 64 KB of SMEM at 16,384 rows), the one-row dynamic
+    read-modify-write, the row loop between bounds read from SMEM, a last
+    block that ends past the table (25,008 = 97 x 256 + 176) and four
+    2.6 MB blocks under the kernel's own VMEM limit.  Beside the result and
+    the sorted rows there is nothing of the table's size."""
+    from paddle_tpu.ops.pallas import embedding_grad as eg
+
+    assert eg.supported(n, v, d, jnp.float32, dtype)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(
+            lambda ids, g: eg.embedding_grad(ids, g, v)
+        ).lower(arg((n,), jnp.int32), arg((n, d), dtype)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes <= v * d * 4 + 4096
+    rows = -(-n // eg.block_rows(d)) * eg.block_rows(d) * d * 4
+    assert mem.temp_size_in_bytes <= rows + 1024 * 1024

@@ -45,6 +45,7 @@ from paddle_tpu import compile_cache, recordio, serving
 from paddle_tpu.contrib import mixed_precision
 from paddle_tpu.models import transformer as tfm
 from paddle_tpu.monitor import program_profile
+from paddle_tpu.ops import loss as loss_ops
 from paddle_tpu.ops import moe, sparse_select, state_space
 from paddle_tpu.ops.activation import rotary_tables
 from paddle_tpu.ops.pallas import flash_attention as fa
@@ -900,6 +901,79 @@ def phase_embedding_grad():
     return {"bodies": bodies, "ms_per_call": timing}
 
 
+# transformer_base's head: target positions a step, model width, vocabulary
+HEAD_GRAD = (16384, 512, 32000)
+
+
+def phase_head_grad():
+    """A bf16-AMP head at ``transformer_base``'s shape — ``[16384, 512]``
+    into 32,000 columns with a bias, uniform smoothing 0.1, a fifth of the
+    rows masked out of the loss — through the executor on the chip, twice:
+    the chain rule's kernel (``mul_grad:head_fused``) and the three ops one
+    by one (``mul_grad:head_by_op``).  dX, dW and db of the two agree to
+    the products' bf16 rounding."""
+    n, d, v = HEAD_GRAD
+
+    def build():
+        main, startup = fluid.Program(), fluid.Program()
+        main.random_seed = startup.random_seed = 11
+        with fluid.program_guard(main, startup), fluid.unique_name.guard():
+            x = fluid.layers.data("x", shape=[d], dtype="float32")
+            x.stop_gradient = False
+            label = fluid.layers.data("label", shape=[1], dtype="int64")
+            keep = fluid.layers.data("keep", shape=[1], dtype="float32")
+            logits = fluid.layers.fc(x, size=v, name="smoke_head")
+            cost = fluid.layers.softmax_with_cross_entropy(
+                logits, label, label_smooth_eps=0.1)
+            loss = fluid.layers.reduce_sum(
+                fluid.layers.elementwise_mul(cost, keep))
+            fluid.backward.append_backward(loss)
+        main._amp_policy = mixed_precision.AMPPolicy()
+        return main, startup
+    rng = np.random.RandomState(3)
+    feed = {"x": np.asarray(normal(31, (n, d), jnp.float32)),
+            "label": rng.randint(0, v, (n, 1)).astype("int64"),
+            "keep": ((rng.rand(n, 1) > 0.2) / n).astype("float32")}
+    names = ["x@GRAD", "smoke_head.w_0@GRAD", "smoke_head.b_0@GRAD"]
+    got, timing = {}, {}
+    for body, platforms in (("head_fused", ("tpu",)), ("head_by_op", ())):
+        loss_ops._HEAD_PLATFORMS = platforms
+        compile_cache.clear()       # the patch is in no cache key
+        try:
+            main, startup = build()
+            before = kernel_bodies("mul_grad")
+            exe = fluid.Executor(fluid.TPUPlace(0))
+            with fluid.scope_guard(fluid.Scope()):
+                exe.run(startup)
+                got[body] = exe.run(main, feed=feed, fetch_list=names)
+                t0 = time.perf_counter()
+                for _ in range(5):
+                    out = exe.run(main, feed=feed, fetch_list=names,
+                                  return_numpy=False)
+                jax.block_until_ready(out)
+                timing[body] = round((time.perf_counter() - t0) / 5 * 1e3, 3)
+        finally:
+            loss_ops._HEAD_PLATFORMS = ("tpu",)
+        if bodies_since(before, "mul_grad") != {"mul_grad:" + body: 1}:
+            raise AssertionError("the head's chain took %s, expected %s"
+                                 % (bodies_since(before, "mul_grad"), body))
+    errs = {}
+    for name, a, b, tol in zip(names, got["head_fused"], got["head_by_op"],
+                               (5e-3, 5e-3, 5e-4)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if not np.isfinite(a).all() or not np.any(b):
+            raise AssertionError("%s: non-finite or all zero" % name)
+        errs[name] = float(np.linalg.norm(a - b) / np.linalg.norm(b))
+        if errs[name] > tol:
+            raise AssertionError("%s: the kernel's is %g of the chain's "
+                                 "norm away, over %g" % (name, errs[name],
+                                                         tol))
+    log("head_grad [%d, %d] x [%d, %d]: relative errors %s, ms a step %s "
+        "(forward + backward, host clock over 5 steps; information, not a "
+        "metric)" % (n, d, d, v, errs, timing))
+    return {"rel_err": errs, "ms_per_step": timing}
+
+
 # -- serving --------------------------------------------------------------------
 
 def serve_prompts():
@@ -1152,6 +1226,7 @@ def main():
     run("kernels", phase_kernels)
     run("select_keys", phase_select_keys)
     run("embedding_grad", phase_embedding_grad)
+    run("head_grad", phase_head_grad)
     run("serve_1chip", phase_serve_1chip)
     run("train_4chip", phase_train_4chip, train["losses"][0])
 

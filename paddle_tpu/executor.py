@@ -264,6 +264,7 @@ def trace_program(program, feed_names, state_names, writeback, fetch_names,
     # every read state var is also returned so XLA donation never leaves
     # a dangling (invalidated) buffer in the scope
     state_out = list(dict.fromkeys(list(state_names) + list(writeback)))
+    kept = frozenset(fetch_names) | frozenset(state_out)
 
     def fn(feed_vals, state_vals, key):
         env = {}
@@ -279,8 +280,7 @@ def trace_program(program, feed_names, state_names, writeback, fetch_names,
             ctx.state_specs = dict(state_specs)
         ctx.program = program
         ctx.amp = getattr(program, '_amp_policy', None)
-        for i, op in enumerate(ops):
-            registry.compute_op(op, env, ctx, op_index=i)
+        registry.compute_ops(ops, env, ctx, kept)
         fetches = [env[n] for n in fetch_names]
         new_state = [env[n] for n in state_out]
         return fetches, new_state

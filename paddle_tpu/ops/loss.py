@@ -7,6 +7,18 @@ star), ``sigmoid_cross_entropy_with_logits_op.cc``, ``huber_loss_op.cc``,
 ``rank_loss_op.cc``, ``margin_rank_loss_op.cc`` — TPU-native: the fused
 softmax+CE is written as logsumexp-based log-softmax so its vjp is exactly
 the numerically-stable ``softmax - onehot`` kernel the reference hand-writes.
+
+A vocabulary head's backward — ``softmax_with_cross_entropy_grad`` ->
+``elementwise_add_grad`` (the bias; optional) -> ``mul_grad`` of the same
+logits — lowers as ONE body where ``_head_chain_rule`` (below) takes it:
+``ops/pallas/head_grad.py`` makes a tile of the logits' gradient once and
+feeds the bias's sum and both products, where XLA rebuilds it inside each of
+the three (14.4 -> 6.3 ms a step at ``[16384, 512] x [512, 32000]``;
+PERF.md 6.21).  The Fluid program is not edited: the rule reads it at trace
+time, and ``compile_cache.stats()["kernel_bodies"]`` says which body a chain
+took (``mul_grad:head_fused`` / ``mul_grad:head_by_op``).  A test forces
+either body through ``_HEAD_PLATFORMS`` (and ``compile_cache.clear()``: the
+patch is in no cache key).
 """
 
 import numpy as np
@@ -14,7 +26,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..registry import register_op, set_output, in_var, same_shape_infer
+from ..registry import (fluid_scope_name, in_var, register_chain,
+                        register_op, same_shape_infer, set_output)
 
 
 def _rowwise_out_infer(op, block, x_slot="X"):
@@ -84,7 +97,16 @@ def _swce_compute(ins, attrs, ctx, op_index):
         lse = jax.scipy.special.logsumexp(logits, axis=-1, keepdims=True)
         log_sm = logits - lse
         idx = label if label.shape[-1] == 1 else label[..., None]
-        picked = jnp.take_along_axis(log_sm, idx.astype(jnp.int32), axis=-1)
+        # the label's logit as a masked row sum (the same float: zeros
+        # added), which XLA folds into the pass that sums the exponentials;
+        # ``take_along_axis(log_sm, idx)`` is a gather, whose operand it
+        # writes out whole — 2.1 GB of float32 at [16384, 32000], which rode
+        # in the bias gradient's reduction until the head's backward became
+        # one kernel (PERF.md 6.21)
+        hit = jax.lax.broadcasted_iota(jnp.int32, logits.shape,
+                                       logits.ndim - 1) == idx
+        picked = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1,
+                         keepdims=True) - lse
         uniform = lse[..., 0:1] - jnp.mean(logits, axis=-1, keepdims=True)
         loss = (1.0 - eps) * -picked + eps * uniform
         ignore = attrs.get("ignore_index", -100)
@@ -113,6 +135,200 @@ register_op(
     "softmax_with_cross_entropy", ["Logits", "Label"], ["Softmax", "Loss"],
     infer=_swce_infer, compute=_swce_compute, no_grad_inputs=("Label",),
 )
+
+
+# -- a vocabulary head's backward as ONE body --------------------------------
+#
+# ``softmax_with_cross_entropy_grad`` -> ``elementwise_add_grad`` (the bias;
+# optional) -> ``mul_grad`` of the same logits.  Op by op XLA rebuilds the
+# ``[N, V]`` gradient inside each of its three consumers; the chain rule
+# below lowers the two or three ops with ``ops/pallas/head_grad.py``, which
+# makes a tile of it once and feeds the bias's sum and both products.
+
+# Where the kernel may be the chain's body (tests add "cpu": interpreted).
+_HEAD_PLATFORMS = ("tpu",)
+
+
+def _only(names):
+    """A slot's one name (None: a hole, no name or several)."""
+    names = list(names or ())
+    return names[0] if len(names) == 1 and names[0] else None
+
+
+def head_chain(ops, i):
+    """``(elementwise_add_grad or None, mul_grad)`` where ``ops[i]`` — a
+    ``softmax_with_cross_entropy_grad`` — and the one or two ops after it
+    are a head's backward: hard labels, no ``ignore_index``, no gradient
+    arriving through ``Softmax``, the logits' gradient handed to the
+    gradient of the bias add and of the ``mul`` that made them (or of the
+    ``mul`` alone), every result named.  None otherwise."""
+    op = ops[i]
+    if op.attrs.get("soft_label", False) \
+            or op.attrs.get("ignore_index", -100) != -100 \
+            or any(op.inputs.get("GRAD::Softmax", ())):
+        return None
+    made, grad = _only(op.inputs.get("Logits")), \
+        _only(op.outputs.get("GRAD::Logits"))
+    rest = ops[i + 1:i + 3]
+    add = None
+    if rest and rest[0].type == "elementwise_add_grad":
+        add, rest = rest[0], rest[1:]
+        if _only(add.inputs.get("Out::Out")) != made \
+                or _only(add.inputs.get("GRAD::Out")) != grad \
+                or not _only(add.outputs.get("GRAD::Y")):
+            return None
+        made, grad = _only(add.inputs.get("X")), \
+            _only(add.outputs.get("GRAD::X"))
+    if not rest or rest[0].type != "mul_grad" or not made or not grad:
+        return None
+    mul = rest[0]
+    if _only(mul.inputs.get("Out::Out")) != made \
+            or _only(mul.inputs.get("GRAD::Out")) != grad \
+            or not _only(mul.outputs.get("GRAD::X")) \
+            or not _only(mul.outputs.get("GRAD::Y")):
+        return None
+    return add, mul
+
+
+def _read_outside(program, chain, names):
+    """Whether an op of ``program`` that is not of ``chain`` reads one of
+    ``names``."""
+    inside = {id(op) for op in chain}
+    return any(n in names
+               for block in program.blocks for op in block.ops
+               if id(op) not in inside
+               for slot in op.inputs.values() for n in slot)
+
+
+def _head_shards(ctx, names, batch):
+    """The mesh axes the head's rows are split over, for a per-shard kernel
+    whose dW and db are summed over them: () on one device; the populated
+    data axes under a mesh that has no other populated axis, divides the
+    batch and keeps the variables ``names`` (the weight, the bias) whole on
+    every device; None where that cannot be said (the chain then stays op by
+    op)."""
+    mesh = getattr(ctx, "mesh", None)
+    if mesh is None:
+        return ()
+    from ..parallel.embedding import _data_axes, _extent
+
+    axes = _data_axes(ctx)
+    specs = getattr(ctx, "state_specs", None) or {}
+    split = any(e is not None for n in names for e in specs.get(n) or ())
+    if not axes or split or batch % mesh.devices.size \
+            or _extent(mesh, axes) != mesh.devices.size:
+        return None
+    return axes
+
+
+def _head_operands(op, add, mul, env, ctx):
+    """The chain's operands as its ops see them (the AMP policy's casts
+    applied), flattened to the kernel's ``[N, D] x [D, V]`` — (x, w, the
+    stored product, the bias or zeros, the float32 logits, labels, the rows'
+    ``Loss@GRAD``) — or None where they are not a float32 loss over a 2-D
+    weight's bf16 product (float32 products: XLA's run one bf16 pass by
+    default, Mosaic's several)."""
+    from .math import _flatten_to_2d
+
+    def seen(o, *slots):
+        ins = {s: [env[_only(o.inputs[s])]] for s in slots}
+        if ctx.amp is not None:
+            ins = ctx.amp.cast_inputs(o.type, ins)
+        return [ins[s][0] for s in slots]
+    logits, ct = seen(op, "Logits", "GRAD::Loss")
+    label = env[_only(op.inputs["Label"])]
+    x, w, z = seen(mul, "X", "Y", "Out::Out")
+    xnc = mul.attrs.get("x_num_col_dims", 1)
+    if w.ndim != 2 or mul.attrs.get("y_num_col_dims", 1) != 1 \
+            or xnc != x.ndim - 1 or logits.dtype != jnp.float32 \
+            or not x.dtype == w.dtype == z.dtype == jnp.bfloat16 \
+            or label.size * w.shape[1] != z.size:
+        return None
+    bias = jnp.zeros(w.shape[1:], jnp.float32)
+    if add is not None:
+        (bias,) = seen(add, "Y")
+        if bias.dtype != jnp.float32 or bias.shape != w.shape[1:] \
+                or add.attrs.get("axis", -1) not in (-1, z.ndim - 1):
+            return None
+    n = label.size
+    return (_flatten_to_2d(x, xnc), w, z.reshape(n, -1), bias,
+            logits.reshape(n, -1), label.reshape(n), ct.reshape(n))
+
+
+def _row_lse(logits, eps):
+    """The rows' log-sum-exp by the expression the forward op computed it
+    with (``_swce_compute``'s two hard-label branches), so that XLA merges
+    the two and the backward makes no pass of its own over the logits."""
+    if eps:
+        return jax.scipy.special.logsumexp(logits, axis=-1)
+    m = jnp.max(logits, axis=-1, keepdims=True)
+    return (jnp.log(jnp.sum(jnp.exp(logits - m), axis=-1, keepdims=True))
+            + m)[:, 0]
+
+
+def _head_chain_rule(ops, i, env, ctx, kept):
+    """``registry.compute_ops``'s rule at a
+    ``softmax_with_cross_entropy_grad``: the two or three ops of a head's
+    backward by the one kernel — under ``mul_grad``'s Fluid scope, its
+    ``X@GRAD`` and ``Y@GRAD`` and the bias's ``Y@GRAD`` written, the ``[N,
+    V]`` gradients in between never whole — where nothing else reads those
+    or the ``Softmax`` output, on a platform of ``_HEAD_PLATFORMS``, at a
+    shape the kernel takes (per shard under a data-parallel mesh).
+    ``kernel_bodies`` says ``mul_grad:head_fused`` then, and
+    ``mul_grad:head_by_op`` for a head's chain left op by op."""
+    from ..compile_cache import note_kernel_body
+    from .pallas import head_grad as hg, interpret_mode, kernel_allowed
+
+    chain = head_chain(ops, i)
+    if chain is None:
+        return 0
+    op, (add, mul) = ops[i], chain
+    ours = [o for o in (op, add, mul) if o is not None]
+    hidden = {_only(o.outputs[s]) for o, s in zip(ours[:-1], (
+        "GRAD::Logits", "GRAD::X"))} | set(op.inputs.get("Out::Softmax", ()))
+    args = axes = None
+    if kernel_allowed(ctx, _HEAD_PLATFORMS) and not hidden.intersection(kept) \
+            and not _read_outside(ctx.program, ours, hidden):
+        args = _head_operands(op, add, mul, env, ctx)
+    if args is not None:
+        axes = _head_shards(ctx, [_only(o.inputs["Y"]) for o in ours[1:]],
+                            env[_only(mul.inputs["X"])].shape[0])
+    fused = axes is not None and hg.supported(
+        args[0].shape[0] // (ctx.mesh.devices.size if axes else 1),
+        *args[1].shape, args[0].dtype)
+    note_kernel_body("mul_grad", "head_fused" if fused else "head_by_op")
+    if not fused:
+        return 0
+    eps = float(op.attrs.get("label_smooth_eps", 0.0))
+    interpret = interpret_mode(ctx)
+
+    def run(x, w, z, bias, lse, label, ct):
+        dx, dw, db = hg.head_grad(x, w, z, bias, lse, label, ct, eps,
+                                  interpret)
+        if axes:
+            dw, db = jax.lax.psum((dw, db), axes)
+        return dx, dw, db
+    if axes:
+        from jax.sharding import PartitionSpec as P
+
+        from ..parallel.mesh import shard_map_norep
+
+        rows = P(axes if len(axes) > 1 else axes[0])
+        run = shard_map_norep(
+            run, ctx.mesh, in_specs=(rows, P(), rows, P(), rows, rows, rows),
+            out_specs=(rows, P(), P()))
+    x, w, z, bias, logits, label, ct = args
+    with jax.named_scope(fluid_scope_name(mul)):
+        dx, dw, db = run(x, w, z, bias, _row_lse(logits, eps), label, ct)
+    env[_only(mul.outputs["GRAD::X"])] = dx.reshape(
+        env[_only(mul.inputs["X"])].shape)
+    env[_only(mul.outputs["GRAD::Y"])] = dw
+    if add is not None:
+        env[_only(add.outputs["GRAD::Y"])] = db
+    return len(ours)
+
+
+register_chain("softmax_with_cross_entropy_grad", _head_chain_rule)
 
 
 # -- exit_gate_loss: a looped model's expected loss over its exit gate -------

@@ -27,7 +27,18 @@ results combined in VMEM where the XLA body scatters 640 rows a tile; PERF.md
 6.15) and ``embedding_grad`` (``lookup_table_grad`` into a dense table: the
 sorted rows added into blocks of the table resident in VMEM, each written
 once; PERF.md 6.20); ``kernel_allowed`` is the part of their rules they share, and
-``run_traced`` what traces and lowers a kernel once a step program.  On the
+``run_traced`` what traces and lowers a kernel once a step program.
+``head_grad`` is the one body of SEVERAL ops: where a program's backward
+holds ``softmax_with_cross_entropy_grad`` -> ``elementwise_add_grad`` (the
+bias; optional) -> ``mul_grad`` of the same logits, consecutive, hard labels,
+no ``ignore_index``, nothing else reading the ``Softmax`` output or the two
+gradients in between, the chain rule in ``ops/loss.py``
+(``registry.compute_ops`` asks it) lowers the two or three ops with it under
+``mul_grad``'s Fluid scope — on a TPU, bf16 products under a float32 loss, a
+2-D weight at most 1024 wide, whole tiles (V % 128, N % 128), dX inside
+VMEM; per shard under a mesh whose every populated axis is a data axis, dW
+and db summed over it (``kernel_bodies``: ``mul_grad:head_fused``, else
+``mul_grad:head_by_op``; PERF.md 6.21).  On the
 CPU these ops keep their XLA bodies.  Under a
 ``CPUPlace`` the flag-selected kernels run in interpreter mode, which
 the tests use for numerical parity checks (the packed kernel is not

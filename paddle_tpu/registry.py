@@ -38,6 +38,8 @@ __all__ = [
     "get_op_def",
     "infer_op",
     "compute_op",
+    "compute_ops",
+    "register_chain",
     "make_grad_ops",
     "OPS",
 ]
@@ -206,6 +208,35 @@ def compute_op(op, env, ctx, op_index=0):
         for name, val in zip(names, vals):
             if name:
                 env[name] = val
+    return env
+
+
+# A chain rule lowers SEVERAL consecutive ops with one body: ``rule(ops, i,
+# env, ctx, kept)`` is asked at every op of its first type, and returns how
+# many ops from ``ops[i]`` on it lowered — their results that the rest of
+# the program reads written to ``env`` — or 0 to leave them op by op.
+# ``kept`` are the names the step fetches or writes back: a chain never
+# swallows one.  The Fluid program is not edited; the rule reads it.
+CHAIN_RULES = {}
+
+
+def register_chain(first_type, rule):
+    if first_type in CHAIN_RULES:
+        raise ValueError("op type %r already starts a chain" % first_type)
+    CHAIN_RULES[first_type] = rule
+
+
+def compute_ops(ops, env, ctx, kept=()):
+    """Lower a block's ``ops`` in order inside a trace: op by op
+    (``compute_op``), but for the ops a chain rule takes together."""
+    i = 0
+    while i < len(ops):
+        rule = CHAIN_RULES.get(ops[i].type)
+        done = rule(ops, i, env, ctx, kept) if rule is not None else 0
+        if not done:
+            compute_op(ops[i], env, ctx, op_index=i)
+            done = 1
+        i += done
     return env
 
 

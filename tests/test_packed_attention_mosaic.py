@@ -587,3 +587,43 @@ def test_embedding_grad_kernel_compiles_for_v5e_at_the_cells_shapes(
     assert mem.output_size_in_bytes <= v * d * 4 + 4096
     rows = -(-n // eg.block_rows(d)) * eg.block_rows(d) * d * 4
     assert mem.temp_size_in_bytes <= rows + 1024 * 1024
+
+
+@pytest.mark.parametrize("n", [16384, 4096])   # train_nmt; a shard of _dp4
+def test_head_grad_kernel_compiles_for_v5e_at_the_cell_shape(one_chip, n):
+    """The head's backward kernel at ``transformer_base``'s shape — 16,384
+    target positions (4,096 a chip under the four-chip mesh) of 512-wide
+    bf16 operands over 32,000 columns: Mosaic has to accept the whole float32
+    dX ``[n, 512]`` as ONE resident, one-buffered output block (32 MiB)
+    beside the ``[1024, 1280]`` tiles under the kernel's own VMEM limit, the
+    ``[1024, 1]`` columns of the rows' scalars, and the row block's dynamic
+    slice of dX.  Beside the results there are the two transposed operands
+    and nothing of the logits' size."""
+    from paddle_tpu.ops.pallas import head_grad as hg
+
+    d, v = 512, 32000
+    assert hg.supported(n, d, v, jnp.bfloat16)
+    assert hg.blocks(n, d, v) == (1024, 1280)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(
+            lambda x, w, z, b, lse, label, ct: hg.head_grad(
+                x, w, z, b, lse, label, ct, 0.1)
+        ).lower(arg((n, d), jnp.bfloat16), arg((d, v), jnp.bfloat16),
+                arg((n, v), jnp.bfloat16), arg((v,), jnp.float32),
+                arg((n,), jnp.float32), arg((n,), jnp.int32),
+                arg((n,), jnp.float32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes <= (n * d + d * v) * 2 + v * 4 + 4096
+    # x^T, w^T, the float32 dX before its rounding, the rows' columns
+    assert mem.temp_size_in_bytes <= (n * d + d * v) * 2 + n * d * 4 \
+        + 2 * 1024 * 1024

@@ -117,7 +117,7 @@ def _bench_program(main, startup, feed_fn, fetch, place, iterations,
         fluid.set_flags({"FLAGS_monitor": True})
     monitor.step_stats().reset()
     # per-rung program accounting: without this, A/B rungs that share a
-    # program fingerprint (e.g. pallas on/off) would merge their steps/
+    # program fingerprint (e.g. fast_prng on/off) would merge their steps/
     # wall clock and the rung's program_report MFU would be a blend
     from paddle_tpu.monitor import program_profile
     program_profile.reset_accounting()
@@ -2074,13 +2074,10 @@ def bench_transformer_realdist(args, use_amp=True):
 
 
 def bench_longctx(args, use_amp=True):
-    """Long-context decoder-only LM step (T=4k/8k, single chip): the
-    regime the Pallas flash-attention kernel exists for — XLA's batched
-    attention materializes [B, H, T, T] scores (T=8192, H=8: 1GB bf16
-    per direction per layer), the blockwise kernel never does.  Measures
-    tokens/sec with the XLA fallback vs FLAGS_pallas_kernels at each T
-    and reports both (VERDICT r3 #4: prove the kernel's regime or
-    demote it)."""
+    """Long-context decoder-only LM step (T=4k/8k, single chip), plain
+    64-wide heads: the body ``fused_attention``'s rules pick (the XLA one
+    today, which materializes [B, H, T, T] scores — T=8192, H=8: 1GB bf16
+    per direction per layer).  Measures tokens/sec at each T."""
     import paddle_tpu as fluid
 
     d_model, n_head, n_layer = 512, 8, 2
@@ -2092,7 +2089,6 @@ def bench_longctx(args, use_amp=True):
     configs = {"4096": ((4096, 2),), "8192": ((8192, 1),),
                "both": ((4096, 2), (8192, 1))}[args.longctx_t]
     for seq_len, batch in configs:
-        fluid.set_flags({"FLAGS_pallas_attention_max_seq": seq_len})
         with fluid.program_guard(fluid.Program(), fluid.Program()):
             ids = fluid.layers.data("ids", shape=[seq_len, 1],
                                     dtype="int64")
@@ -2142,36 +2138,22 @@ def bench_longctx(args, use_amp=True):
                 return {"ids": rng.randint(
                     2, vocab, (batch, seq_len, 1)).astype("int64")}
 
-            for pallas in (False, True):
-                fluid.set_flags({"FLAGS_pallas_kernels": pallas})
-                try:
-                    step_time, _ = _bench_program(
-                        fluid.default_main_program(),
-                        fluid.default_startup_program(),
-                        feed_fn, loss, _place(args), args.iterations,
-                        args.skip_batch_num)
-                    tps = batch * seq_len / step_time
-                    results["T%d_%s" % (seq_len,
-                                        "pallas" if pallas else "xla")] = \
-                        round(tps, 2)
-                except Exception as e:  # noqa: BLE001 — record the rung
-                    results["T%d_%s_error" % (
-                        seq_len, "pallas" if pallas else "xla")] = \
-                        str(e)[:200]
-            fluid.set_flags({"FLAGS_pallas_kernels": False})
-    for t in (4096, 8192):
-        p = results.get("T%d_pallas" % t)
-        x = results.get("T%d_xla" % t)
-        if isinstance(p, float) and isinstance(x, float) and x > 0:
-            results["T%d_pallas_vs_xla" % t] = round(p / x, 3)
-    # the primary is PINNED to the T=4096 Pallas rung so the metric's
-    # meaning is stable across rounds; vs_baseline for this entry is the
-    # pallas/xla ratio at that T (there is no era-hardware target)
-    val = results.get("T4096_pallas")
-    return dict({"metric": "longctx_decoder_tokens_per_sec_pallas",
+            try:
+                step_time, _ = _bench_program(
+                    fluid.default_main_program(),
+                    fluid.default_startup_program(),
+                    feed_fn, loss, _place(args), args.iterations,
+                    args.skip_batch_num)
+                results["T%d" % seq_len] = round(
+                    batch * seq_len / step_time, 2)
+            except Exception as e:  # noqa: BLE001 — record the rung
+                results["T%d_error" % seq_len] = str(e)[:200]
+    # the primary is the T=4096 rung where it ran, so the metric's meaning
+    # is stable across rounds (there is no era-hardware target)
+    val = results.get("T4096", results.get("T8192"))
+    return dict({"metric": "longctx_decoder_tokens_per_sec",
                  "value": val if isinstance(val, float) else 0.0,
-                 "unit": "tokens/sec",
-                 "vs_baseline": results.get("T4096_pallas_vs_xla", 0.0)},
+                 "unit": "tokens/sec", "vs_baseline": 0.0},
                 **results)
 
 
@@ -2371,8 +2353,6 @@ def main():
     p.add_argument("--fp32_only", action="store_true")
     p.add_argument("--with_reader", action="store_true",
                    help="re-feed fresh host batches every step")
-    p.add_argument("--pallas", action="store_true",
-                   help="enable FLAGS_pallas_kernels (flash attention etc.)")
     p.add_argument("--longctx_t", default="both",
                    choices=["4096", "8192", "both"],
                    help="which long-context rungs to measure")
@@ -2494,15 +2474,12 @@ def main():
         # not a first-class scored comparison "informational": true
         # (fp32 = dtype-ruling rungs, era/infer = load-noise-hostage
         # rungs per PERF.md, with_reader = input-pipeline-bound,
-        # longctx = a pallas-vs-xla A/B with no era target).
+        # longctx = no era target).
         import subprocess
         import sys
 
-        # configs are the fetch-synced-measured best (r3): the XLA
-        # attention beats the Pallas flash kernel at these short-sequence
-        # shapes (101.6k vs 65.2k tok/s true), and the rbg PRNG saves the
-        # threefry dropout-mask cost (135.9k with both).  --pallas stays
-        # available for long-context/memory-bound regimes.
+        # configs are the fetch-synced-measured best (r3): the rbg PRNG
+        # saves the threefry dropout-mask cost.
         # (model, extra, informational, per-rung cap seconds)
         runs = [
             # --- scored rungs (compute-bound; PERF.md measured them
@@ -2570,7 +2547,7 @@ def main():
             # step (never re-measured on this machine — ROADMAP S2)
             ("resnet50", ["--with_reader", "--n_windows", "3"],
              True, 480),
-            # pallas-vs-xla A/B at T=4096; compile-heavy
+            # plain heads at T=4096; compile-heavy
             ("longctx", ["--iterations", "8", "--skip_batch_num", "2",
                          "--longctx_t", "4096", "--n_windows", "3"],
              True, 600),   # rung_name special-cases this to longctx_t4096
@@ -2735,9 +2712,8 @@ def main():
     if args.device == "cpu":
         # the environment may name the chip first (JAX_PLATFORMS=tpu,cpu)
         jax.config.update("jax_platforms", "cpu")
-    if args.pallas or args.fast_prng:
-        fluid.set_flags({"FLAGS_pallas_kernels": args.pallas,
-                         "FLAGS_fast_prng": args.fast_prng})
+    if args.fast_prng:
+        fluid.set_flags({"FLAGS_fast_prng": True})
     compile_cache.enable_persistent_cache(
         args.compile_cache_dir, chip_entry=args.device != "cpu")
 
@@ -2782,9 +2758,8 @@ def main():
         kwargs = {"infer": True} if args.infer else {}
         result = fn(args, use_amp=not args.fp32_only,
                     per_step_feed=args.with_reader, **kwargs)
-    # record the kernel/PRNG choices so A/Bs stay distinguishable in the
+    # record the PRNG choice so A/Bs stay distinguishable in the
     # artifact (metric names stay stable across rounds)
-    result["pallas"] = bool(args.pallas)
     result["fast_prng"] = bool(args.fast_prng)
     # recorded unconditionally; the passes only apply to the resnet model
     result["fuse_conv_bn"] = bool(args.fuse_conv_bn)

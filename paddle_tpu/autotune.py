@@ -1,8 +1,7 @@
 """Profile-guided auto-configuration (ISSUE 9 tentpole).
 
 PERF.md is a graveyard of hand-measured config decisions — b512 not
-b1024, 4 bucket bounds not 6, Pallas flash attention only where the
-measured A/B favors it, checkpoint cadence picked by eye — while the
+b1024, 4 bucket bounds not 6, checkpoint cadence picked by eye — while the
 compiler's own cost/memory accounting per program has been free at
 runtime since the program-profile work (``monitor/program_profile.py``:
 XLA ``cost_analysis``/``memory_analysis`` captured at the one compile
@@ -10,7 +9,7 @@ each signature already pays).  This module closes the loop: an
 auto-tuner that searches the config space using that machinery instead
 of blind timing sweeps.
 
-Five knobs, five decision procedures (each a PURE function of
+Four knobs, four decision procedures (each a PURE function of
 measurements, so the policy is unit-testable without a device):
 
 * **batch size** (:func:`run_batch_ladder` / :func:`tune_batch_size`) —
@@ -25,15 +24,6 @@ measurements, so the policy is unit-testable without a device):
   step-time window; the ladder stops when seconds-per-example regresses
   (the PERF.md b512-not-b1024 shape: amortization plateaus, HBM-pressure
   scheduling takes over).
-* **attention kernel per shape** (:func:`decide_attention_kernel` /
-  :func:`tune_attention_kernel`) — XLA vs Pallas flash measured A/B at
-  the model's (Tq, Tk, d, dtype), cached in a persistent
-  :class:`AttentionDecisionTable` keyed by
-  ``compile_cache.program_fingerprint`` + shape: a warm process reads
-  the table and pays nothing.  Tuned choices are consulted by the
-  ``fused_attention`` op itself (shape-matched), and a PINNED
-  ``FLAGS_pallas_kernels`` — set by the user via env or ``set_flags``
-  — always wins over the table.
 * **bucket bounds** (:func:`choose_bucket_bounds`) — pick K bounds from
   an observed length histogram maximizing real-token fill, restricted
   to hardware-friendly multiples FIRST (the PERF.md r4 finding: six
@@ -83,26 +73,21 @@ import contextlib
 import json
 import math
 import os
-import threading
 import time
 
 import numpy as np
 
 __all__ = [
-    "TunedConfig", "AttentionDecisionTable", "attention_table",
-    "attention_choice", "attention_shape_key", "trace_token",
-    "hbm_ceiling", "batch_ladder", "project_peak_hbm",
-    "run_batch_ladder", "decide_attention_kernel", "token_fill",
-    "choose_bucket_bounds", "decide_checkpoint_interval",
-    "tune_batch_size", "tune_attention_kernel",
+    "TunedConfig", "hbm_ceiling", "batch_ladder", "project_peak_hbm",
+    "run_batch_ladder", "token_fill", "choose_bucket_bounds",
+    "decide_checkpoint_interval", "tune_batch_size",
     "tune_checkpoint_interval", "measure_step_window",
     "decide_pipeline", "tune_pipeline",
-    "quant_kernel_table", "quant_kernel_choice", "quant_shape_key",
-    "decide_quant_kernel", "tune_quant_kernel",
     "decide_quantization", "tune_quantization",
 ]
 
-_mu = threading.Lock()
+# knobs of artifacts older runs wrote that nothing reads any more
+_RETIRED_KNOBS = ("attention_kernel", "quant_kernel")
 
 
 def _flag(name, default):
@@ -226,24 +211,6 @@ def run_batch_ladder(ladder, hbm_limit, probe_fn, measure_fn,
         decision["chosen_s_per_example"] = best[0]
         decision["chosen_step_s"] = round(best[2], 6)
     return decision
-
-
-def decide_attention_kernel(xla_step_s, pallas_step_s, min_speedup=1.03):
-    """Pick the Pallas flash kernel only where the measured A/B favors
-    it by at least ``min_speedup`` (the PERF.md shape: Pallas wins
-    1.3-1.9x at T=4096 and LOSES ~1.5x at T<=64 — ties go to XLA, whose
-    global fusion is the safer default)."""
-    xla_step_s = float(xla_step_s)
-    pallas_step_s = float(pallas_step_s)
-    use_pallas = (pallas_step_s > 0
-                  and xla_step_s / pallas_step_s >= float(min_speedup))
-    return {"knob": "attention_kernel", "pallas": bool(use_pallas),
-            "xla_step_s": round(xla_step_s, 6),
-            "pallas_step_s": round(pallas_step_s, 6),
-            "speedup": round(xla_step_s / pallas_step_s, 4)
-            if pallas_step_s > 0 else None,
-            "min_speedup": float(min_speedup),
-            "evidence": "measured_ab_window"}
 
 
 def _length_counts(lengths):
@@ -437,7 +404,7 @@ class TunedConfig:
         d.setdefault("source", source)
         self.decisions.append(d)
         _event({"event": "autotune_decision", "knob": d.get("knob"),
-                "chosen": d.get("chosen", d.get("pallas")),
+                "chosen": d.get("chosen"),
                 "source": d.get("source"),
                 "fingerprint": d.get("fingerprint")})
         return d
@@ -453,7 +420,7 @@ class TunedConfig:
         d = self.get(knob)
         if d is None:
             return default
-        return d.get("chosen", d.get("pallas", default))
+        return d.get("chosen", default)
 
     def as_dict(self):
         return {"meta": dict(self.meta),
@@ -480,318 +447,33 @@ class TunedConfig:
 
     # -- application ---------------------------------------------------
     def apply(self):
-        """Apply the flag-backed decisions to the process, RESPECTING
-        pins: a flag the user set explicitly (env or ``set_flags``)
-        always wins over the tuner.  Returns a list of (knob, outcome)
-        pairs — outcome is "applied", "pinned" (user override wins), or
-        "advisory" (knobs like batch size that callers read from the
-        artifact rather than a flag).  Attention-kernel decisions
-        install into the process :class:`AttentionDecisionTable` (the
-        ``fused_attention`` op consults it per shape)."""
+        """Walk the decisions for the process, RESPECTING pins: a flag
+        the user set explicitly (env or ``set_flags``) always wins over
+        the tuner.  Returns a list of (knob, outcome) pairs — outcome is
+        "pinned" (user override wins), "advisory" (knobs like batch size
+        that callers read from the artifact rather than a flag) or
+        "ignored" (a knob this program no longer has: an artifact is
+        input from outside, and one written by an older run may still
+        hold an ``attention_kernel`` or ``quant_kernel`` ruling — kernels
+        are chosen by the ops' own rules now)."""
         from . import flags
 
         outcomes = []
         for d in self.decisions:
             knob = d.get("knob")
-            if knob == "attention_kernel" and d.get("shape"):
-                if flags.pinned("pallas_kernels"):
-                    outcomes.append((knob, "pinned"))
-                    continue
-                attention_table().record(
-                    d.get("fingerprint") or "", d["shape"],
-                    bool(d.get("pallas")), d, persist=False)
-                outcomes.append((knob, "applied"))
-            elif knob == "quant_kernel" and d.get("shape"):
-                if flags.pinned("pallas_kernels"):
-                    outcomes.append((knob, "pinned"))
-                    continue
-                quant_kernel_table().record(
-                    d.get("fingerprint") or "", d["shape"],
-                    bool(d.get("pallas")), d, persist=False)
-                outcomes.append((knob, "applied"))
-            elif knob == "quantization":
-                if flags.pinned("quantize_mode"):
-                    outcomes.append((knob, "pinned"))
-                    continue
-                # consumed by the serving engines / quantize_inference
-                # callers from the artifact, not a flag
-                outcomes.append((knob, "advisory"))
-            elif knob == "checkpoint_interval":
-                # applied by the Trainer against its manager (not a
-                # flag); recorded here so the trail is complete
-                outcomes.append((knob, "advisory"))
+            if knob in _RETIRED_KNOBS:
+                outcome = "ignored"
+            elif knob == "quantization" and flags.pinned("quantize_mode"):
+                outcome = "pinned"
             else:
-                outcomes.append((knob, "advisory"))
+                # read from the artifact by its consumer (the serving
+                # engines' quantization, the Trainer's checkpoint
+                # interval), not a flag; recorded so the trail is complete
+                outcome = "advisory"
+            outcomes.append((knob, outcome))
         _event({"event": "autotune_applied",
                 "outcomes": [list(o) for o in outcomes]})
         return outcomes
-
-
-# ---------------------------------------------------------------------------
-# persistent attention-kernel decision table
-# ---------------------------------------------------------------------------
-
-def attention_shape_key(q_shape, k_shape, dtype):
-    """Stable shape key for the attention decision table: (Tq, Tk, d,
-    dtype) — batch and head count don't change the kernel ruling's
-    regime (the [T, T] score materialization does)."""
-    return "T%d:K%d:d%d:%s" % (int(q_shape[2]), int(k_shape[2]),
-                               int(q_shape[3]), np.dtype(dtype).name
-                               if not isinstance(dtype, str) else dtype)
-
-
-class AttentionDecisionTable:
-    """Persistent per-shape XLA-vs-Pallas decisions, keyed by
-    ``fingerprint + shape key``.  Lives as JSON under
-    ``FLAGS_autotune_dir`` (in-memory only when the flag is unset), so a
-    warm process — or a warm bench rung subprocess sharing the dir —
-    reads the measured ruling and pays zero A/B compiles.
-
-    Mutations bump a content token that ``compile_cache.
-    trace_flag_values`` folds into every trace/AOT cache key: a changed
-    ruling re-lowers instead of serving the other kernel's stale trace.
-    """
-
-    FILENAME = "attention_decisions.json"
-
-    def __init__(self, dirname=None, filename=None):
-        self._dir = dirname
-        # the table machinery is knob-agnostic (string shape keys ->
-        # pallas rulings); a second knob persists under its own file
-        # (quant_kernel_table)
-        self._filename = filename or self.FILENAME
-        self._entries = {}
-        self._loaded = False
-        # content token cached as an immutable tuple: trace_token() is
-        # on every executor cache-key computation (per step), so the
-        # sorted rebuild happens per MUTATION, not per step
-        self._token = None
-        self._mu = threading.Lock()
-
-    def _path(self):
-        d = self._dir if self._dir is not None \
-            else str(_flag("autotune_dir", "") or "")
-        return os.path.join(d, self._filename) if d else None
-
-    def _load_locked(self):
-        if self._loaded:
-            return
-        self._loaded = True
-        path = self._path()
-        if not path or not os.path.exists(path):
-            return
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-            entries = doc.get("entries", {})
-            if isinstance(entries, dict):
-                # on-disk rulings merge UNDER in-memory ones (the
-                # running process's fresher measurements win)
-                merged = dict(entries)
-                merged.update(self._entries)
-                self._entries = merged
-                self._token = None
-        except (ValueError, OSError):
-            # a torn write must not poison tuning; re-measure instead
-            self._entries = dict(self._entries)
-
-    def _persist_locked(self):
-        path = self._path()
-        if not path:
-            return
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        tmp = path + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump({"entries": self._entries}, f, indent=2,
-                      sort_keys=True)
-        os.replace(tmp, path)
-
-    @staticmethod
-    def _key(fingerprint, shape_key):
-        return "%s|%s" % ((fingerprint or "")[:12], shape_key)
-
-    def lookup(self, fingerprint, shape_key):
-        """The ruling for (fingerprint, shape) — falling back to any
-        fingerprint's ruling at the same shape (the regime is the
-        shape's property; the fingerprint records provenance).  Returns
-        the entry dict or None."""
-        with self._mu:
-            self._load_locked()
-            e = self._entries.get(self._key(fingerprint, shape_key))
-            if e is not None:
-                return dict(e)
-            suffix = "|" + shape_key
-            newest = None
-            for k, v in self._entries.items():
-                if k.endswith(suffix) and (
-                        newest is None
-                        or v.get("ts", 0) >= newest.get("ts", 0)):
-                    newest = v
-            return dict(newest) if newest else None
-
-    def record(self, fingerprint, shape_key, pallas, evidence=None,
-               persist=True):
-        entry = {"pallas": bool(pallas), "shape": shape_key,
-                 "fingerprint": (fingerprint or "")[:12],
-                 "ts": time.time()}
-        if evidence:
-            entry["evidence"] = {
-                k: evidence[k] for k in ("xla_step_s", "pallas_step_s",
-                                         "speedup", "min_speedup",
-                                         "source")
-                if k in evidence}
-        with self._mu:
-            self._load_locked()
-            self._entries[self._key(fingerprint, shape_key)] = entry
-            self._token = None
-            if persist:
-                self._persist_locked()
-        return entry
-
-    def entries(self):
-        with self._mu:
-            self._load_locked()
-            return {k: dict(v) for k, v in self._entries.items()}
-
-    def content_token(self):
-        """Hashable digest of every ruling — part of the trace-cache
-        key (two processes with identical tables key identically).
-        Cached until the next mutation; the warm path is one attribute
-        read."""
-        t = self._token
-        if t is not None:
-            return t
-        with self._mu:
-            self._load_locked()
-            if self._token is None:
-                self._token = tuple(sorted(
-                    (k, bool(v.get("pallas"))) for k, v in
-                    self._entries.items()))
-            return self._token
-
-    def clear(self):
-        with self._mu:
-            self._entries.clear()
-            self._loaded = True
-            self._token = None
-
-
-_table = [None]
-
-
-def attention_table():
-    """The process-global attention decision table."""
-    with _mu:
-        if _table[0] is None:
-            _table[0] = AttentionDecisionTable()
-        return _table[0]
-
-
-def _active_table():
-    """The table consulted on hot paths (the ``fused_attention`` op and
-    the trace-cache token): the instantiated process table, or — when
-    ``FLAGS_autotune_dir`` names a persisted table — a lazily loaded
-    one (setting the dir IS the opt-in: a fresh process with the flag
-    picks up the warm rulings without re-running the tuner).  None when
-    neither exists.  Both callers share this helper so the trace key
-    and the lowering always agree on which rulings are in force."""
-    t = _table[0]
-    if t is not None:
-        return t
-    if str(_flag("autotune_dir", "") or ""):
-        return attention_table()
-    return None
-
-
-def reset_attention_table():
-    """Drop the process table (tests); the on-disk file is untouched."""
-    with _mu:
-        _table[0] = None
-
-
-_qtable = [None]
-QUANT_FILENAME = "quant_kernel_decisions.json"
-
-
-def quant_kernel_table():
-    """The process-global dequant-matmul kernel decision table (same
-    machinery as the attention table, its own persisted file)."""
-    with _mu:
-        if _qtable[0] is None:
-            _qtable[0] = AttentionDecisionTable(filename=QUANT_FILENAME)
-        return _qtable[0]
-
-
-def _active_quant_table():
-    t = _qtable[0]
-    if t is not None:
-        return t
-    if str(_flag("autotune_dir", "") or ""):
-        return quant_kernel_table()
-    return None
-
-
-def reset_quant_kernel_table():
-    """Drop the process quant-kernel table (tests); disk untouched."""
-    with _mu:
-        _qtable[0] = None
-
-
-def trace_token():
-    """Token folded into every trace/AOT cache key
-    (``compile_cache.trace_flag_values``): tuned kernel rulings are
-    baked into the lowered jaxpr, so a changed table must re-lower
-    rather than serve the other kernel's stale trace.  Covers BOTH
-    per-shape tables (attention and dequant-matmul).  Cheap when no
-    table exists (the overwhelmingly common case)."""
-    parts = ()
-    t = _active_table()
-    if t is not None:
-        parts += (("attention",) + t.content_token(),)
-    q = _active_quant_table()
-    if q is not None:
-        parts += (("quant",) + q.content_token(),)
-    return parts
-
-
-def attention_choice(q_shape, k_shape, dtype):
-    """The tuned kernel ruling for this attention shape, or None when
-    there is none — or when the user PINNED ``FLAGS_pallas_kernels``
-    (an explicit flag always beats the tuner).  Called by the
-    ``fused_attention`` op at trace time."""
-    t = _active_table()
-    if t is None:
-        return None
-    from . import flags
-
-    if flags.pinned("pallas_kernels"):
-        return None
-    e = t.lookup("", attention_shape_key(q_shape, k_shape, dtype))
-    return None if e is None else bool(e["pallas"])
-
-
-def quant_shape_key(m, k, n, dtype, mode="weight_only"):
-    """Stable shape key for the dequant-matmul kernel table: the
-    flattened GEMM dims plus activation dtype and quantization mode
-    (the regime-setting properties)."""
-    name = np.dtype(dtype).name if not isinstance(dtype, str) else dtype
-    return "M%d:K%d:N%d:%s:%s" % (int(m), int(k), int(n), name, mode)
-
-
-def quant_kernel_choice(m, k, n, dtype, mode="weight_only"):
-    """The tuned Pallas-vs-XLA ruling for this dequant-matmul shape, or
-    None when there is none — or when the user PINNED
-    ``FLAGS_pallas_kernels``.  Called by the ``dequant_matmul`` op at
-    trace time (the exact analog of :func:`attention_choice`)."""
-    t = _active_quant_table()
-    if t is None:
-        return None
-    from . import flags
-
-    if flags.pinned("pallas_kernels"):
-        return None
-    e = t.lookup("", quant_shape_key(m, k, n, dtype, mode))
-    return None if e is None else bool(e["pallas"])
 
 
 # ---------------------------------------------------------------------------
@@ -921,72 +603,6 @@ def tune_batch_size(main_program, startup_program, make_feed, fetch,
     else:
         _event({"event": "autotune_decision", "knob": "batch_size",
                 "chosen": decision["chosen"], "fingerprint": fp[:12]})
-    return decision
-
-
-def tune_attention_kernel(main_program, startup_program, feed, fetch,
-                          place, shape, probe_steps=4, warmup_steps=1,
-                          min_speedup=1.03, table=None, config=None):
-    """Measured XLA-vs-Pallas A/B for one attention shape, served from
-    the persistent decision table when warm (zero compiles).
-
-    ``shape``: ``(q_shape, k_shape, dtype)`` of the model's attention —
-    or a ready shape-key string.  The A/B flips
-    ``FLAGS_pallas_kernels`` (and raises the flash kernel's seq gate to
-    cover the shape) UNPINNED and restores both afterwards, so tuning
-    never counts as the user's explicit choice."""
-    from . import compile_cache, flags
-
-    key = shape if isinstance(shape, str) else attention_shape_key(*shape)
-    table = table or attention_table()
-    fp = compile_cache.program_fingerprint(main_program)
-    cached = table.lookup(fp, key)
-    if cached is not None:
-        decision = {"knob": "attention_kernel", "shape": key,
-                    "pallas": bool(cached["pallas"]),
-                    "evidence": "decision_table",
-                    "cached": True}
-        decision.update(cached.get("evidence") or {})
-        if config is not None:
-            config.add(decision, fingerprint=fp[:12], source="cached")
-        return decision
-
-    seq = 0
-    if not isinstance(shape, str):
-        seq = max(int(shape[0][2]), int(shape[1][2]))
-    fetch_list = [fetch]
-    measured = {}
-    saved = flags.get_flags(["pallas_kernels",
-                             "pallas_attention_max_seq"])
-    saved_pins = {n: flags.pinned(n)
-                  for n in ("pallas_kernels", "pallas_attention_max_seq")}
-    try:
-        for pallas in (False, True):
-            updates = {"pallas_kernels": pallas}
-            if pallas and seq > int(flags.flag(
-                    "pallas_attention_max_seq")):
-                updates["pallas_attention_max_seq"] = seq
-            flags.set_flags(updates, pin=False)
-            with _probe_run(place) as (exe, scope):
-                exe.run(startup_program, scope=scope)
-                exe.cost_analysis(main_program, feed, fetch_list,
-                                  scope=scope)
-                measured[pallas] = measure_step_window(
-                    exe, main_program, feed, fetch_list,
-                    steps=probe_steps, warmup=warmup_steps, scope=scope)
-            _event({"event": "autotune_probe",
-                    "knob": "attention_kernel", "shape": key,
-                    "pallas": pallas,
-                    "step_s": round(measured[pallas], 6)})
-    finally:
-        flags.set_flags({k: v for k, v in saved.items()}, pin=False)
-        flags._restore_pins(saved_pins)
-    decision = decide_attention_kernel(measured[False], measured[True],
-                                       min_speedup=min_speedup)
-    decision["shape"] = key
-    table.record(fp, key, decision["pallas"], decision)
-    if config is not None:
-        config.add(decision, fingerprint=fp[:12])
     return decision
 
 
@@ -1269,112 +885,8 @@ def tune_pipeline(main_program, startup_program, feed, fetch, mesh,
 
 
 # ---------------------------------------------------------------------------
-# quantized execution: kernel A/B + accuracy-gated program A/B (ISSUE 14)
+# quantized execution: accuracy-gated program A/B (ISSUE 14)
 # ---------------------------------------------------------------------------
-
-def decide_quant_kernel(xla_step_s, pallas_step_s, min_speedup=1.03):
-    """Pick the Pallas fused dequant-matmul only where the measured A/B
-    favors it by ``min_speedup`` (ties go to XLA, same policy as the
-    attention kernel)."""
-    xla_step_s = float(xla_step_s)
-    pallas_step_s = float(pallas_step_s)
-    use_pallas = (pallas_step_s > 0
-                  and xla_step_s / pallas_step_s >= float(min_speedup))
-    return {"knob": "quant_kernel", "pallas": bool(use_pallas),
-            "xla_step_s": round(xla_step_s, 6),
-            "pallas_step_s": round(pallas_step_s, 6),
-            "speedup": round(xla_step_s / pallas_step_s, 4)
-            if pallas_step_s > 0 else None,
-            "min_speedup": float(min_speedup),
-            "evidence": "measured_ab_window"}
-
-
-def _quant_microbench(m, k, n, dtype, mode, seed=0):
-    """A one-op dequant_matmul program + synthetic int8 weights for the
-    kernel A/B (kernel speed only; accuracy is tune_quantization's
-    job).  Returns (program, feed, state values, fetch var)."""
-    from .framework import Operator, Program
-    from .registry import infer_op
-
-    prog = Program()
-    block = prog.global_block()
-    x = block.create_var(name="qmb_x", shape=(int(m), int(k)),
-                         dtype=dtype, is_data=True)
-    qw = block.create_var(name="qmb_w", shape=(int(k), int(n)),
-                          dtype="int8", persistable=True)
-    sc = block.create_var(name="qmb_s", shape=(int(n),),
-                          dtype="float32", persistable=True)
-    out = block.create_var(name="qmb_out", dtype=dtype)
-    op = Operator(block, type="dequant_matmul",
-                  inputs={"X": [x.name], "QWeight": [qw.name],
-                          "Scale": [sc.name]},
-                  outputs={"Out": [out.name]},
-                  attrs={"x_num_col_dims": 1, "mode": mode})
-    infer_op(op, block)
-    block.ops.append(op)
-    prog._version += 1
-    rng = np.random.RandomState(seed)
-    w = (rng.randn(k, n) * 0.05).astype(np.float32)
-    s = (np.maximum(np.abs(w).max(axis=0), 1e-12) / 127.0).astype(
-        np.float32)
-    qwv = np.clip(np.round(w / s), -127, 127).astype(np.int8)
-    feed = {"qmb_x": rng.randn(m, k).astype(np.float32)}
-    return prog, feed, {"qmb_w": qwv, "qmb_s": s}, out
-
-
-def tune_quant_kernel(m, k, n, dtype="float32", place=None,
-                      mode="weight_only", probe_steps=4, warmup_steps=1,
-                      min_speedup=1.03, table=None, config=None):
-    """Measured Pallas-vs-XLA A/B for one dequant-matmul shape, served
-    from the persistent quant-kernel decision table when warm (zero
-    compiles) — the exact analog of :func:`tune_attention_kernel`.
-    The A/B flips ``FLAGS_pallas_kernels`` UNPINNED and restores it, so
-    tuning never counts as the user's explicit choice."""
-    from . import compile_cache, flags
-    from .executor import CPUPlace
-
-    place = place if place is not None else CPUPlace()
-    key = quant_shape_key(m, k, n, dtype, mode)
-    table = table or quant_kernel_table()
-    prog, feed, values, fetch = _quant_microbench(m, k, n, dtype, mode)
-    fp = compile_cache.program_fingerprint(prog)
-    cached = table.lookup(fp, key)
-    if cached is not None:
-        decision = {"knob": "quant_kernel", "shape": key,
-                    "pallas": bool(cached["pallas"]),
-                    "evidence": "decision_table", "cached": True}
-        decision.update(cached.get("evidence") or {})
-        if config is not None:
-            config.add(decision, fingerprint=fp[:12], source="cached")
-        return decision
-
-    measured = {}
-    saved = flags.get_flags(["pallas_kernels"])
-    saved_pins = {"pallas_kernels": flags.pinned("pallas_kernels")}
-    try:
-        for pallas in (False, True):
-            flags.set_flags({"pallas_kernels": pallas}, pin=False)
-            with _probe_run(place) as (exe, scope):
-                for name, v in values.items():
-                    scope.set_var(name, v)
-                exe.cost_analysis(prog, feed, [fetch], scope=scope)
-                measured[pallas] = measure_step_window(
-                    exe, prog, feed, [fetch], steps=probe_steps,
-                    warmup=warmup_steps, scope=scope)
-            _event({"event": "autotune_probe", "knob": "quant_kernel",
-                    "shape": key, "pallas": pallas,
-                    "step_s": round(measured[pallas], 6)})
-    finally:
-        flags.set_flags(saved, pin=False)
-        flags._restore_pins(saved_pins)
-    decision = decide_quant_kernel(measured[False], measured[True],
-                                   min_speedup=min_speedup)
-    decision["shape"] = key
-    table.record(fp, key, decision["pallas"], decision)
-    if config is not None:
-        config.add(decision, fingerprint=fp[:12])
-    return decision
-
 
 def eval_delta(reference, outputs):
     """Relative-L1 accuracy delta between two fetch lists: the

@@ -61,14 +61,12 @@ __all__ = [
 
 
 def trace_flag_values():
-    """Values of every FLAGS_* knob that alters the traced jaxpr (kernel
-    selection, BN variance form, flash-attention seq cutoff).  Every key
-    under which a trace/compiled step is cached — the executors' per-
-    instance keys AND the trace-cache keys here — must include this
-    tuple, or set_flags between runs serves a stale trace."""
-    from . import autotune, flags
-
-    from . import guardian
+    """Values of every FLAGS_* knob that alters the traced jaxpr (whether
+    the ops' rules may pick Pallas kernels, BN variance form, the guard and
+    the probe).  Every key under which a trace/compiled step is cached —
+    the executors' per-instance keys AND the trace-cache keys here — must
+    include this tuple, or set_flags between runs serves a stale trace."""
+    from . import flags, guardian
     from .monitor import health
 
     # the guardian's in-graph skip guard wraps the traced step (extra
@@ -76,17 +74,10 @@ def trace_flag_values():
     # identity: flipping FLAGS_guardian re-lowers instead of serving an
     # unguarded (or guarded) stale trace.  Same for the health probe
     # (extra grad fetches + the stats reduction); its CADENCE is host-
-    # side publication only and deliberately not keyed.  The autotune
-    # trace token carries the attention decision table's content: a
-    # tuned kernel ruling is baked into the lowered step the same way
-    # the flags are, so a changed ruling must re-lower too.  Whether
-    # FLAGS_pallas_kernels is PINNED is part of it: a pinned False turns
-    # the shape-chosen packed attention kernel off (ops/attention.py).
-    return (flags.flag("pallas_kernels"), flags.pinned("pallas_kernels"),
-            flags.flag("bn_two_pass"),
-            flags.flag("pallas_attention_max_seq"),
-            guardian.skip_guard_enabled(), health.probe_enabled(),
-            autotune.trace_token())
+    # side publication only and deliberately not keyed.
+    return (flags.flag("pallas_kernels"), flags.flag("bn_two_pass"),
+            guardian.skip_guard_enabled(), health.probe_enabled())
+
 
 _mu = threading.Lock()
 # LRU of jitted step entries: the jitted callables keep their traced

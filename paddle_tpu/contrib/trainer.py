@@ -191,7 +191,6 @@ class Trainer:
             self._autotune = autotune if isinstance(
                 autotune, _at.TunedConfig) else _at.TunedConfig.load(
                 autotune)
-            # flag-backed decisions (attention-kernel table install);
             # pinned flags win inside apply()
             self._autotune.apply()
             interval = self._autotune.value("checkpoint_interval")

@@ -159,8 +159,10 @@ def _on_cpu_deterministic(val):
 
 
 register_flag("check_nan_inf", False, bool)
-# opt-in hand-tiled Pallas kernels for hot ops (ops/pallas/)
-register_flag("pallas_kernels", False, bool)
+# whether the ops' own rules may pick Pallas kernels (ops/pallas/
+# kernel_allowed).  False is the operator's switch against a kernel that
+# miscompiles on a new runtime: every op then lowers to its XLA body
+register_flag("pallas_kernels", True, bool)
 # rbg counter PRNG for in-graph randomness (dropout masks etc.):
 # cheaper random bits on TPU than the default threefry; different (but
 # still deterministic-per-seed) random streams.  Fetch-synced A/B on the
@@ -171,10 +173,8 @@ register_flag("fast_prng", False, bool)
 # default fused one-pass E[x^2]-E[x]^2 form; costs one extra full
 # activation read per BN (see ops/norm.py)
 register_flag("bn_two_pass", False, bool)
-# sequence-length gate for the flash-attention Pallas kernel: longer
-# sequences take the XLA attention.  A selection default, not a compile
-# limit (see ops/pallas/flash_attention.supported)
-register_flag("pallas_attention_max_seq", 2048, int)
+
+
 def _on_compile_cache_dir(val):
     from . import compile_cache
 
@@ -388,10 +388,8 @@ def _on_fault_spec(val):
     fault.install_from_spec(val)
 
 
-# Profile-guided auto-configuration (autotune.py): where TunedConfig
-# artifacts and the persistent attention-kernel decision table live
-# ("" = decision table stays in-memory only; warm processes then
-# re-measure)
+# Profile-guided auto-configuration (autotune.py): where bench.py writes
+# its TunedConfig artifacts ("" = nowhere)
 register_flag("autotune_dir", "", str)
 # device-memory ceiling override in bytes for the tuner's batch-size
 # probe (0 = fall back to FLAGS_preflight_hbm_bytes, then the device's

@@ -279,8 +279,8 @@ def fused_attention(q, k, v, k_len=None, causal=False, dropout_rate=0.0,
     T, Dv]``: the result is ``[B, H, T, Dv]``).  ``selected`` is
     ``select_keys``'s packed mask of the keys each query may read, beside
     ``k_len`` and ``causal``.  With any of the three, or for plain-head
-    self-attention too long for the resident-K/V kernel
-    (``ops.attention.streams_plain_heads``), a TPU trace takes the kernel
+    self-attention past ``ops.attention.streams_plain_heads``'s length
+    (T above 3584 at D = 128), a TPU trace takes the kernel
     that streams K/V by blocks (``ops/pallas/streamed_attention.py``) and
     the CPU the XLA body.  ``window`` (an int, with ``causal``, Tq == Tk, no
     ``k_len`` and no dropout) keeps of a query's keys the nearest
@@ -289,14 +289,10 @@ def fused_attention(q, k, v, k_len=None, causal=False, dropout_rate=0.0,
 
     ``k_len`` [B] int masks padded key positions; ``causal`` adds the
     autoregressive mask.  One op, identical semantics in every body; the
-    body is chosen at trace time (``ops/attention.py``): ring attention on
-    a mesh with an ``sp`` axis; on a TPU, for sequences short enough that
-    a batch row's blocks fit VMEM (up to 384 at H*D = 512 in bf16), the
-    packed Pallas kernel over ``[B, T, H*D]``, against which a model's
-    own head split / merge around this layer cancels; the long-sequence
-    Pallas kernel under FLAGS_pallas_kernels or a tuned ruling; the XLA
-    body otherwise and on the CPU.  A pinned FLAGS_pallas_kernels=False
-    keeps every Pallas body off."""
+    body — streamed, ring, packed or XLA, each with its gradient — is
+    chosen at trace time by the op's own rules over platform, mesh and
+    shapes (``ops/attention.py`` says which and why);
+    ``FLAGS_pallas_kernels=False`` keeps every Pallas body off."""
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(dtype=q.dtype)
     inputs = {"Q": [q], "K": [k], "V": [v]}

@@ -1,15 +1,29 @@
-"""Fused attention op — the single-chip flash-attention surface.
+"""Fused attention op — one op, one algorithm, four bodies.
 
 Capability parity target: the reference's only attention implementation,
 ``nets.scaled_dot_product_attention`` (``python/paddle/fluid/nets.py:323``) —
 batched QK^T, softmax, optional dropout on the weights, PV.  TPU-first
-redesign: one op, one algorithm, and a body chosen at trace time from what
-the op can observe (platform, mesh, shapes), with identical semantics in
-every body (same structural masks, same counter-hash dropout mask), so the
-choice changes schedule, not math:
+redesign: a body chosen at trace time from what the op can observe (platform,
+mesh, shapes), with identical semantics in every body (same structural masks,
+same counter-hash dropout mask), so the choice changes schedule, not math.
+Each body with its gradient, in the order the op asks:
 
+* **streamed** — on a TPU, one device, self-attention with grouped-query
+  heads (K/V of ``H / g`` heads), a ``Selected`` key set, values narrower
+  than the keys, or plain heads the layer marked ``keep_lse``
+  (``streams_plain_heads``): the blockwise kernels that stream K/V by blocks
+  and apply the selection per block (``ops/pallas/streamed_attention.py``);
+  the [B, H, T, T] scores of such a model's long rows do not fit HBM, so
+  there is nothing to weigh it against.  A ``window`` attribute (causal
+  self-attention over each query's nearest ``window`` keys: key ``s`` counts
+  for query ``t`` iff ``t - window < s <= t``) runs on this body too, where a
+  block of keys wholly outside the window is neither fetched nor computed.
+  The forward hands the gradient op its output and the rows' log-sum-exp
+  (the op's ``LSE`` output); the gradient is the kernels' own backward on
+  them.
 * **ring** — the mesh has a populated ``sp`` axis the sequence dims divide:
-  sequence-parallel ring attention (``parallel/ring_attention.py``).
+  sequence-parallel ring attention (``parallel/ring_attention.py``).  The
+  gradient differentiates the body (``jax.vjp``).
 * **packed** — on a TPU, when one batch row's blocks for the backward fit
   the VMEM budget (``ops/pallas/packed_attention.supported``: sequences up
   to 384 at H*D = 512 in bf16) and the shape is not the suffix-causal
@@ -19,27 +33,16 @@ choice changes schedule, not math:
   ``reshape(transpose(.))`` these are a transpose of a transpose and a
   reshape of a reshape, which XLA removes, forward and in the gradient ops.
   Under a mesh the kernel runs per shard (``shard_map``: batch over the
-  data axes, whole heads over ``tp``).  No flag turns it on; a PINNED
-  ``FLAGS_pallas_kernels=False`` ("no Pallas") turns it off.
-* **streamed** — on a TPU, self-attention with grouped-query heads (K/V of
-  ``H / g`` heads), a ``Selected`` key set, values narrower than the keys,
-  or plain heads at a length whose K/V the resident kernel below cannot
-  hold (``streams_plain_heads``; the layer marks those ops ``keep_lse``):
-  the blockwise kernel that streams K/V by blocks and applies the
-  selection per block (``ops/pallas/streamed_attention.py``); the
-  [B, H, T, T] scores of such a model's long rows do not fit HBM, so there
-  is nothing to weigh it against.  A ``window`` attribute (causal
-  self-attention over each query's nearest ``window`` keys: key ``s`` counts
-  for query ``t`` iff ``t - window < s <= t``) runs on this body too, where
-  a block of keys wholly outside the window is neither fetched nor computed,
-  and on the XLA body elsewhere.
-* **pallas** — the long-sequence blockwise kernel
-  (``ops/pallas/flash_attention.py``) under ``FLAGS_pallas_kernels`` or a
-  tuned per-shape ruling (``autotune.attention_choice``), never
-  materializing the [B, H, Tq, Tk] scores.
-* **xla** — everything else, and every CPU trace: the XLA body.
+  data axes, whole heads over ``tp``).  The gradient is the one backward
+  kernel over the program's own Q, K, V and dO.
+* **xla** — everything else, and every CPU trace: the XLA body
+  (``ops/attention_xla.reference_attention``), which an op that keeps the
+  log-sum-exp also falls back to.  The gradient differentiates the body.
 
-Which one a trace took is counted in
+A kernel is chosen by its rule alone (``_streamed_applicable``,
+``_packed_applicable``, through ``ops.pallas.kernel_allowed``);
+``FLAGS_pallas_kernels=False`` is the operator's one switch against all of
+them ("no Pallas").  Which body a trace took is counted in
 ``compile_cache.stats()["kernel_bodies"]`` (``fused_attention:<body>``; the
 streamed body's gradient ``fused_attention_grad:streamed_fused`` — one
 backward kernel — or ``:streamed`` — dQ and dK/dV —, and the heads a grid
@@ -127,15 +130,35 @@ def _fused_attention_infer(op, block):
         set_output(op, block, "LSE", tuple(q.shape[:3]) + (1,), "float32")
 
 
+def _resident_kv_fits(tq, tk, d):
+    """Whether one (batch, head)'s whole K and V, its Q and dO and a
+    [256, 512] block of float32 scores fit, twice over (a pipeline's two
+    buffers), under 10 MiB in bfloat16.  A boundary INHERITED from the
+    resident-K/V flash kernel this op had until PR 45 — no body that is left
+    has it — and kept because ``streams_plain_heads`` draws the line between
+    ``keep_lse`` programs and the others with it; ROADMAP S15 (plain heads
+    of 512 <= T <= 2048) moves it by measurement."""
+    def ceil_to(x, m):
+        return -(-x // m) * m
+
+    if tq < 1 or tk < 1 or d < 1 or d > 512:
+        return False
+    bq = min(256, ceil_to(tq, 8))
+    bk = min(512, ceil_to(tk, 128 if tk >= 128 else 8))
+    tq_pad, tk_pad = ceil_to(tq, bq), ceil_to(tk, bk)
+    resident = 2 * tk_pad * d * 2 + 2 * tq_pad * d * 2 + 2 * tq_pad * 4
+    blocks = (3 * bq * d + 2 * bq * bk) * 4
+    return 2 * (resident + blocks) < 10 * 1024 * 1024
+
+
 def streams_plain_heads(q_shape, k_shape, v_shape, has_klen, rate):
     """Whether plain-head attention of these shapes belongs to the bodies
     that keep the rows' log-sum-exp (streamed on a TPU): values of another
     width than the keys, or self-attention the streamed kernel takes at a
-    length where the resident-K/V kernel's VMEM bound says no even in
-    bfloat16 (beyond T = 2048 at D = 128).  Shapes alone, so that the layer
-    can ask it when it builds the op (``keep_lse``) and the trace need
-    not."""
-    from .pallas import flash_attention as fa, streamed_attention as sa
+    length past ``_resident_kv_fits`` (T above 3584 at D = 128, above 1536
+    at D = 256).  Shapes alone, so that the layer can ask it when it builds
+    the op (``keep_lse``) and the trace need not."""
+    from .pallas import streamed_attention as sa
 
     if len(q_shape) != 4 or len(v_shape) != 4:
         return False
@@ -143,8 +166,7 @@ def streams_plain_heads(q_shape, k_shape, v_shape, has_klen, rate):
         return True
     return sa.supported(q_shape, k_shape, jnp.bfloat16, True, has_klen,
                         rate) \
-        and not fa.supported(q_shape, k_shape, jnp.bfloat16,
-                             max_seq=max(q_shape[2], k_shape[2]))
+        and not _resident_kv_fits(q_shape[2], k_shape[2], q_shape[3])
 
 
 def _attention_args(ins, attrs, ctx, op_index):
@@ -173,7 +195,7 @@ def _fused_attention_compute(ins, attrs, ctx, op_index):
         ins, attrs, ctx, op_index)
     selected = (ins.get("Selected") or [None])[0]
 
-    from .pallas import flash_attention as fa
+    from . import attention_xla
     from ..compile_cache import note_kernel_body
 
     if _keeps_lse(q, k, selected, attrs):
@@ -203,7 +225,7 @@ def _fused_attention_compute(ins, attrs, ctx, op_index):
             lse = jnp.zeros(q.shape[:3] + (1,), jnp.float32)
         else:
             note_kernel_body("fused_attention", "xla")
-            out, lse = fa.reference_attention(
+            out, lse = attention_xla.reference_attention(
                 q, k, v, k_len, seed, causal, rate, scale, selected, True,
                 window)
         if post is not None:
@@ -218,46 +240,22 @@ def _fused_attention_compute(ins, attrs, ctx, op_index):
         (out,) = _packed_run(ctx, (q, k, v), k_len, seed, causal, rate,
                              scale)
     else:
-        from .. import autotune
-        from ..flags import flag
-
-        # kernel selection: a tuned per-shape ruling (the autotune
-        # decision table's measured A/B) overrides the global flag —
-        # unless the operator PINNED FLAGS_pallas_kernels, in which
-        # case attention_choice returns None and the flag rules
-        choice = autotune.attention_choice(q.shape, k.shape, q.dtype)
-        use_pallas = flag("pallas_kernels") if choice is None else choice
-        # a tuned Pallas ruling was measured AT this sequence length, so
-        # it lifts the flag's seq gate for this shape (the VMEM budget
-        # inside supported() still applies)
-        max_seq = max(q.shape[2], k.shape[2]) if choice else None
-        # a requested kernel that supported() rejects gives way to the
-        # XLA body; note_kernel_body records which one this trace took
-        if use_pallas and fa.supported(q.shape, k.shape, q.dtype,
-                                       max_seq=max_seq):
-            from .pallas import interpret_mode
-            note_kernel_body("fused_attention", "pallas")
-            out = fa.flash_attention(q, k, v, k_len, seed, causal, rate,
-                                     scale, interpret_mode(ctx))
-        else:
-            note_kernel_body("fused_attention", "xla")
-            out = fa.reference_attention(q, k, v, k_len, seed, causal, rate,
-                                         scale)
+        note_kernel_body("fused_attention", "xla")
+        out = attention_xla.reference_attention(q, k, v, k_len, seed, causal,
+                                                rate, scale)
     if post is not None:
         out = out * jnp.asarray(post, out.dtype)
     return {"Out": out}
 
 
 def _fused_attention_grad_compute(ins, attrs, ctx, op_index):
-    """The op's gradient.  Every body but the packed one differentiates
-    the forward (``registry._generic_grad_compute``: ``jax.vjp`` over the
-    forward's compute).  On the packed body that would run the forward
-    kernel a second time per attention — the vjp's forward is a second
-    custom call with the same operands, and XLA does not merge custom
-    calls (3 kernels an attention in the compiled step) — so there the
-    gradient IS the one backward kernel, over the program's own Q, K, V
-    and dO.  Likewise the streamed body (grouped heads / selected keys):
-    its backward runs on the forward op's own ``Out`` and ``LSE``."""
+    """The op's gradient, by the forward's body (the module docstring).
+    The ring and the XLA body differentiate the forward
+    (``registry._generic_grad_compute``: ``jax.vjp`` over the forward's
+    compute).  On a kernel that would run the forward kernel a second time
+    per attention — the vjp's forward is a second custom call with the same
+    operands, and XLA does not merge custom calls — so the packed and the
+    streamed bodies run their own backward kernels."""
     from ..registry import _generic_grad_compute
 
     fwd_index = attrs.get("__fwd_op_index__", op_index)
@@ -335,8 +333,8 @@ def _plain(q, k, v, selected):
 def _streamed_applicable(ctx, q_shape, k_shape, dtype, causal, has_klen,
                          rate, dv=None):
     """The streamed kernel's rule: a TPU trace on one device (it has no
-    per-shard lowering yet), no pinned ``FLAGS_pallas_kernels=False``, and
-    a call its ``supported()`` takes."""
+    per-shard lowering yet), no ``FLAGS_pallas_kernels=False``, and a call
+    its ``supported()`` takes."""
     from .pallas import kernel_allowed, streamed_attention as sa
 
     return kernel_allowed(ctx, _STREAMED_PLATFORMS) \
@@ -363,8 +361,8 @@ def _packed_axes(ctx, b, h, d):
 
 def _packed_applicable(ctx, q_shape, k_shape, dtype, causal):
     """The packed short-sequence kernel's rule, from what the op can
-    observe: a TPU trace, no pinned ``FLAGS_pallas_kernels=False``, not
-    the suffix-causal decode shape (K and V come from a cache there, not
+    observe: a TPU trace, no ``FLAGS_pallas_kernels=False``, not the
+    suffix-causal decode shape (K and V come from a cache there, not
     from a transpose: merging heads would ADD copies), and one shard's
     row fits the kernel's VMEM budget."""
     from .pallas import kernel_allowed, packed_attention as pa
@@ -558,26 +556,11 @@ def _paged_attention_compute(ins, attrs, ctx, op_index):
     v_scale = ins.get("VScale", [None])[0]
     scale = attrs.get("scale", None)
 
-    from .pallas import flash_attention as fa
-    from .pallas import interpret_mode
-    from .. import autotune
-    from ..compile_cache import note_kernel_body
-    from ..flags import flag
+    from . import attention_xla
 
-    # kernel selection on the GATHERED shape (the shape the kernel
-    # actually runs): tuned per-shape ruling wins unless the operator
-    # pinned FLAGS_pallas_kernels — the fused_attention discipline
-    tmax = table.shape[1] * k_pool.shape[2]
-    k_shape = (q.shape[0], q.shape[1], tmax, q.shape[3])
-    choice = autotune.attention_choice(q.shape, k_shape, q.dtype)
-    use_pallas = (flag("pallas_kernels") if choice is None else choice) \
-        and fa.supported(q.shape, k_shape, q.dtype)
-    note_kernel_body("paged_attention", "pallas" if use_pallas else "xla")
-    out = fa.paged_attention(
+    out = attention_xla.paged_attention(
         q, k_pool, v_pool, table, k_len, k_scale, v_scale,
-        causal=attrs.get("causal", True), scale=scale,
-        use_pallas=use_pallas,
-        interpret=interpret_mode(ctx) if use_pallas else False)
+        causal=attrs.get("causal", True), scale=scale)
     return {"Out": out}
 
 

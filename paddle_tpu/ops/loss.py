@@ -73,22 +73,6 @@ def _swce_infer(op, block):
 def _swce_compute(ins, attrs, ctx, op_index):
     logits, label = ins["Logits"][0], ins["Label"][0]
     eps = float(attrs.get("label_smooth_eps", 0.0))
-    if not attrs.get("soft_label", False) and \
-            attrs.get("ignore_index", -100) == -100:
-        # hand-tiled kernel covers both the plain and the fused
-        # label-smoothing loss (ops/pallas/softmax_xent.py); no ignore
-        # mask there (-100 sentinel = none, matching the sigmoid variant)
-        from ..flags import flag
-        if flag("pallas_kernels"):
-            from ..compile_cache import note_kernel_body
-            from .pallas import interpret_mode, softmax_xent as px
-            note_kernel_body("softmax_with_cross_entropy", "pallas")
-            flat = logits.reshape(-1, logits.shape[-1])
-            lbl = label.reshape(-1)
-            loss, softmax = px.softmax_xent(flat, lbl, interpret_mode(ctx),
-                                            eps)
-            return {"Softmax": softmax.reshape(logits.shape),
-                    "Loss": loss.reshape(logits.shape[:-1] + (1,))}
     if eps and not attrs.get("soft_label", False):
         # fused uniform label smoothing: target = (1-eps)*onehot + eps/C;
         # loss = (1-eps)*nll + eps*(lse - mean(logits)).  Keeps the [N, C]

@@ -30,7 +30,7 @@ Which body ``moe_expert_ffn`` and its gradient run (``_bodies``; the note is
 ``compile_cache.stats()["kernel_bodies"]["moe_expert_ffn:<body>"]``):
 
 * ``grouped`` — ``ops/pallas/grouped_experts.py``: on a TPU, one device, no
-  pinned ``FLAGS_pallas_kernels=False``, shapes its ``supported()`` takes
+  ``FLAGS_pallas_kernels=False``, shapes its ``supported()`` takes
   (bf16 or float32, widths and tile of whole lane tiles, a step of each
   kernel inside the VMEM budget: both expert cells').  Rows gathered a chunk
   of tiles at a time, a tile's expert picked in the weights' index maps,
@@ -263,7 +263,7 @@ _GROUPED_PLATFORMS = ("tpu",)
 def _bodies(ctx, op_type, x, gate, tile):
     """(``expert_ffn``, ``expert_ffn_grad``) or the grouped kernels' pair of
     the same signatures, from what the op can observe: a TPU trace on one
-    device (a per-shard lowering is not written), no pinned
+    device (a per-shard lowering is not written), no
     ``FLAGS_pallas_kernels=False``, and shapes the kernels' ``supported()``
     takes.  Notes which under ``op_type``."""
     from ..compile_cache import note_kernel_body

@@ -14,8 +14,6 @@ MeanOut/VarianceOut write back to the persistable stat vars in the scope
 python/paddle/fluid/layers/nn.py batch_norm).
 """
 
-import numpy as np
-
 import jax.numpy as jnp
 from jax import lax
 
@@ -229,26 +227,6 @@ def _ln_compute(ins, attrs, ctx, op_index):
         else None
     axis = attrs.get("begin_norm_axis", 1)
     eps = attrs.get("epsilon", 1e-5)
-    if scale is not None and bias is not None and scale.ndim == 1:
-        from ..flags import flag
-        if flag("pallas_kernels"):
-            # opt-in hand-tiled kernel (ops/pallas/layer_norm.py)
-            from ..compile_cache import note_kernel_body
-            from .pallas import interpret_mode, layer_norm as pln
-            note_kernel_body("layer_norm", "pallas")
-            d = int(np.prod(x.shape[axis:]))
-            flat = x.reshape(-1, d)
-            y = pln.layer_norm(flat, scale.reshape(d), bias.reshape(d),
-                               float(eps), interpret_mode(ctx))
-            # Mean/Variance side outputs recomputed cheaply (fetch-only
-            # parity outputs; XLA dead-code-eliminates them when unused)
-            red = tuple(range(axis, x.ndim))
-            mean = jnp.mean(x, axis=red)
-            var = jnp.mean(jnp.square(
-                x - mean.reshape(mean.shape + (1,) * (x.ndim - axis))),
-                axis=red)
-            return {"Y": y.reshape(x.shape), "Mean": mean,
-                    "Variance": var}
     # statistics in fp32 regardless of activation dtype (AMP-gray op:
     # bf16 activations pass through; XLA fuses the casts into the
     # reduction/normalize chain)

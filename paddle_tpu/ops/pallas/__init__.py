@@ -1,56 +1,62 @@
 """Pallas TPU kernels — alternative compute bodies for hot ops.
 
-The op registry's kernels are pure JAX (``registry.py``); the modules
-here provide hand-tiled Pallas implementations for ops where explicit
-VMEM staging/fusion can beat XLA's automatic fusion (SURVEY.md §7 hot-op
-list: softmax_with_cross_entropy, layer_norm).
+The op registry's kernels are pure JAX (``registry.py``); the modules here
+are hand-tiled Pallas bodies for ops where explicit VMEM staging beats XLA's
+automatic fusion on a ledger line.
 
-Selection: softmax_xent, layer_norm, quant_matmul, conv_bn and the
-long-sequence ``flash_attention`` are gated at each call site by the
-``pallas_kernels`` runtime flag (``flags.flag("pallas_kernels")`` /
-FLAGS_pallas_kernels env, part of the executor compile-cache key);
-default off — nothing on the v5e has yet shown them ahead of XLA's
-fused code at the scored shapes, so they are an opt-in escape hatch.
-``packed_attention`` — short-sequence attention over the projections'
-``[B, T, H*D]`` layout — is NOT behind the flag: the ``fused_attention``
-op takes it on a TPU whenever the shape is inside its VMEM bound
-(``packed_attention.supported``), because there it was measured 2.8x
-ahead of the XLA body (PERF.md 6.6); only a PINNED
-``FLAGS_pallas_kernels=False`` ("no Pallas") turns it off.  Likewise
-``streamed_attention`` (grouped heads, selected keys, values narrower than
-the keys, or plain heads too long for the resident kernel; any length),
-``topk_select`` (``select_topk_keys`` with a query block's scores held in
-VMEM: one read of the scores where the XLA body makes 46; PERF.md 6.8) and
-``grouped_experts`` (``moe_expert_ffn`` and its gradient over the live tiles
-of the dispatch layout: a tile's expert picked in the weights' index maps,
-results combined in VMEM where the XLA body scatters 640 rows a tile; PERF.md
-6.15) and ``embedding_grad`` (``lookup_table_grad`` into a dense table: the
-sorted rows added into blocks of the table resident in VMEM, each written
-once; PERF.md 6.20); ``kernel_allowed`` is the part of their rules they share, and
-``run_traced`` what traces and lowers a kernel once a step program.
-``head_grad`` is the one body of SEVERAL ops: where a program's backward
-holds ``softmax_with_cross_entropy_grad`` -> ``elementwise_add_grad`` (the
-bias; optional) -> ``mul_grad`` of the same logits, consecutive, hard labels,
-no ``ignore_index``, nothing else reading the ``Softmax`` output or the two
-gradients in between, the chain rule in ``ops/loss.py``
-(``registry.compute_ops`` asks it) lowers the two or three ops with it under
-``mul_grad``'s Fluid scope — on a TPU, bf16 products under a float32 loss, a
-2-D weight at most 1024 wide, whole tiles (V % 128, N % 128), dX inside
-VMEM; per shard under a mesh whose every populated axis is a data axis, dW
-and db summed over it (``kernel_bodies``: ``mul_grad:head_fused``, else
-``mul_grad:head_by_op``; PERF.md 6.21).  On the
-CPU these ops keep their XLA bodies.  Under a
-``CPUPlace`` the flag-selected kernels run in interpreter mode, which
-the tests use for numerical parity checks (the packed kernel is not
-selected on the CPU at all; its tests call it interpreted); every call
-site records the body it lowered to
-(``compile_cache.note_kernel_body``).
+Selection: ONE way.  Each kernel is picked by a rule over what its op can
+observe — platform, mesh, shapes, dtype — written beside the op, and every
+rule goes through ``kernel_allowed`` here: a trace for one of the rule's
+platforms, and ``FLAGS_pallas_kernels`` (default True: "the rules may pick
+Pallas kernels"; False is the operator's switch against a kernel that
+miscompiles on a new runtime, part of every step's cache key, and then every
+op lowers to its XLA body).  No flag, table or environment variable turns a
+kernel ON.  The rules:
+
+* ``packed_attention`` — short-sequence attention over the projections'
+  ``[B, T, H*D]`` layout: ``ops/attention._packed_applicable`` (a TPU, the
+  shape inside its VMEM bound, ``packed_attention.supported``; measured 2.8x
+  ahead of the XLA body, PERF.md 6.6).
+* ``streamed_attention`` — grouped heads, selected keys, values narrower than
+  the keys, or plain heads the layer marked ``keep_lse``; any length:
+  ``ops/attention._streamed_applicable``.
+* ``topk_select`` — ``select_topk_keys`` with a query block's scores held in
+  VMEM, one read of the scores where the XLA body makes 46 (PERF.md 6.8):
+  ``ops/sparse_select._kernel_applicable``.
+* ``grouped_experts`` — ``moe_expert_ffn`` and its gradient over the live
+  tiles of the dispatch layout, a tile's expert picked in the weights' index
+  maps, results combined in VMEM where the XLA body scatters 640 rows a tile
+  (PERF.md 6.15): ``ops/moe``'s rule.
+* ``embedding_grad`` — ``lookup_table_grad`` into a dense table, the sorted
+  rows added into blocks of the table resident in VMEM, each written once
+  (PERF.md 6.20): ``ops/manipulation.segment_body``.
+* ``selective_scan`` — the chunked scan and its backward:
+  ``ops/state_space``'s rule.
+* ``head_grad`` — the one body of SEVERAL ops: where a program's backward
+  holds ``softmax_with_cross_entropy_grad`` -> ``elementwise_add_grad`` (the
+  bias; optional) -> ``mul_grad`` of the same logits, consecutive, hard
+  labels, no ``ignore_index``, nothing else reading the ``Softmax`` output or
+  the two gradients in between, the chain rule in ``ops/loss.py``
+  (``registry.compute_ops`` asks it) lowers the two or three ops with it
+  under ``mul_grad``'s Fluid scope — on a TPU, bf16 products under a float32
+  loss, a 2-D weight at most 1024 wide, whole tiles (V % 128, N % 128), dX
+  inside VMEM; per shard under a mesh whose every populated axis is a data
+  axis, dW and db summed over it (``kernel_bodies``: ``mul_grad:head_fused``,
+  else ``mul_grad:head_by_op``; PERF.md 6.21).
+
+(``conv_bn`` is the body of the fused conv+BN ops, which only the
+``fuse_conv_bn`` program pass emits.)  On the CPU these ops keep their XLA
+bodies: no rule names it as a platform, and the tests that want a kernel
+through its op patch the rule's platform tuple and run it interpreted
+(``interpret_mode``).  Every call site records the body it lowered to
+(``compile_cache.note_kernel_body``); ``run_traced`` traces and lowers a
+kernel once a step program.
 """
 
 import functools
 import time
 
-from ... import flags  # flag "pallas_kernels" is declared in flags.py
+from ...flags import flag
 from ...compile_cache import note_kernel_trace
 
 # What a grid step of the kernels an op picks by shape may hold in VMEM, and
@@ -86,11 +92,11 @@ def interpret_mode(ctx):
 
 
 def kernel_allowed(ctx, platforms):
-    """What the kernels an op picks by shape share: a trace for one of
-    ``platforms`` and no pinned ``FLAGS_pallas_kernels=False``."""
+    """What every kernel's rule shares: a trace for one of ``platforms``,
+    and the operator has not switched Pallas off
+    (``FLAGS_pallas_kernels=False``)."""
     return getattr(ctx, "platform", None) in platforms \
-        and not (flags.pinned("pallas_kernels")
-                 and not flags.flag("pallas_kernels"))
+        and flag("pallas_kernels")
 
 
 @functools.lru_cache(maxsize=_TRACES_KEPT)
@@ -143,16 +149,3 @@ def block_rows(n, row_bytes, max_rows, vmem_budget=4 * 1024 * 1024):
     bn = max(8, (bn // 8) * 8)
     n_padded = ((n + bn - 1) // bn) * bn
     return bn, n_padded
-
-
-def pad_rows(a, n_padded):
-    """Zero-pad dim 0 of ``a`` to n_padded rows."""
-    import jax.numpy as jnp
-
-    n = a.shape[0]
-    if n == n_padded:
-        return a
-    return jnp.pad(a, [(0, n_padded - n)] + [(0, 0)] * (a.ndim - 1))
-
-
-from . import softmax_xent, layer_norm, quant_matmul  # noqa: E402,F401

@@ -45,7 +45,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _NEG_INF, _causal_valid, _keep_mask
+from ..attention_xla import _NEG_INF, _causal_valid, _keep_mask
 
 # what one grid step may hold: Pallas double-buffers every block, and
 # the default scoped-VMEM limit of a v5e core is 16 MiB; the kernel

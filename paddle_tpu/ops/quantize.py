@@ -24,11 +24,7 @@ a fused dequant-matmul.  Two modes:
   abs-max grid, or a trained QAT ``XScale`` when the pass found one) and
   the dot runs int8 x int8 with an int32 accumulator.
 
-The kernel per shape is the Pallas fused kernel
-(``ops/pallas/quant_matmul.py``) or the XLA ``dot_general`` fallback,
-chosen like ``fused_attention`` chooses: a tuned per-shape ruling in the
-autotune decision table wins unless the operator pinned
-``FLAGS_pallas_kernels``.
+Both are XLA ``dot_general`` bodies (``xla_dequant_matmul``).
 """
 
 import numpy as np
@@ -173,7 +169,7 @@ register_op(
 
 def xla_dequant_matmul(x2, qw, scale, mode="weight_only", xscale=None,
                        bit_length=8):
-    """XLA fallback for the fused dequant-matmul: ``x2`` [M, K] float,
+    """The fused dequant-matmul: ``x2`` [M, K] float,
     ``qw`` [K, N] int8, ``scale`` [N] f32 dequant multipliers
     (``w ~= qw * scale``).  ``weight_only`` dequantizes into the f32
     accumulator (int8 values are exact in f32; one GEMM, scale applied
@@ -220,32 +216,10 @@ def _dequant_matmul_compute(ins, attrs, ctx, op_index):
     xnc = attrs.get("x_num_col_dims", 1)
     mode = attrs.get("mode", "weight_only")
     bits = attrs.get("bit_length", 8)
-    x2 = _flatten_to_2d(x, xnc)
-    m, k = x2.shape
-    n = qw.shape[-1]
-
-    from .. import autotune
-    from ..compile_cache import note_kernel_body
-    from ..flags import flag
-    from .pallas import interpret_mode
-    from .pallas import quant_matmul as qm
-
-    # kernel selection mirrors fused_attention: a tuned per-shape ruling
-    # from the autotune decision table wins, unless the operator PINNED
-    # FLAGS_pallas_kernels (then quant_kernel_choice returns None and
-    # the flag rules); supported() still gates either way
-    choice = autotune.quant_kernel_choice(m, k, n, x.dtype, mode)
-    use_pallas = flag("pallas_kernels") if choice is None else choice
-    if use_pallas and xscale is None and qm.supported(m, k, n, x.dtype):
-        note_kernel_body("dequant_matmul", "pallas")
-        acc = qm.dequant_matmul(x2, qw, scale, mode=mode,
-                                bit_length=bits,
-                                interpret=interpret_mode(ctx))
-    else:
-        note_kernel_body("dequant_matmul", "xla")
-        acc = xla_dequant_matmul(x2, qw, scale, mode=mode, xscale=xscale,
-                                 bit_length=bits)
-    out = acc.astype(x.dtype).reshape(tuple(x.shape[:xnc]) + (n,))
+    acc = xla_dequant_matmul(_flatten_to_2d(x, xnc), qw, scale, mode=mode,
+                             xscale=xscale, bit_length=bits)
+    out = acc.astype(x.dtype).reshape(
+        tuple(x.shape[:xnc]) + (qw.shape[-1],))
     return {"Out": out}
 
 

@@ -22,7 +22,7 @@ ties, each pass a fusion over the whole ``[B, T, Tk]`` key matrix — is the
 **definition** of ``select_topk_keys``, and the body of every CPU trace, of
 a trace under a mesh and of scores the kernel does not take.  A TPU trace
 on one device whose float32 scores come in whole 128-key slabs
-(``_kernel_applicable``: nothing but what the op can observe, and a pinned
+(``_kernel_applicable``: nothing but what the op can observe, and
 ``FLAGS_pallas_kernels=False`` says no) lowers to
 ``ops/pallas/topk_select.py``: the same decisions with a block of rows held
 in VMEM, every score read once, the same words bit for bit.
@@ -189,7 +189,7 @@ _KERNEL_PLATFORMS = ("tpu",)
 def _kernel_applicable(ctx, x_shape, dtype):
     """The Pallas body's rule, from what the op can observe: a TPU trace on
     one device (the rows are independent, but a per-shard lowering is not
-    written), no pinned ``FLAGS_pallas_kernels=False``, and scores its
+    written), no ``FLAGS_pallas_kernels=False``, and scores its
     ``supported()`` takes."""
     from .pallas import kernel_allowed, topk_select
 

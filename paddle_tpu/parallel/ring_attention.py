@@ -50,7 +50,7 @@ def ring_attention_shard(q, k, v, axis_name, causal=False, scale=None,
     ``k_len`` [B] masks padded key positions (global valid-key counts for
     this shard's batch rows); ``dropout_rate``/``seed`` apply the same
     counter-hash weight dropout as the single-chip fused_attention op
-    (``ops/pallas/flash_attention._keep_mask`` on GLOBAL positions, so a
+    (``ops/attention_xla._keep_mask`` on GLOBAL positions, so a
     ring run reproduces a single-chip run's mask bit-for-bit —
     downgrade_in_infer semantics: masked, not upscaled).
     ``batch_axis_name`` names the mesh axis the batch is sharded over, so
@@ -78,7 +78,7 @@ def ring_attention_shard(q, k, v, axis_name, causal=False, scale=None,
     q_pos = idx * tq + jnp.arange(tq)             # global query positions
     masked = causal or k_len is not None
     if dropout_rate:
-        from ..ops.pallas.flash_attention import _keep_mask
+        from ..ops.attention_xla import _keep_mask
         if seed is None:
             seed = jnp.zeros((), jnp.uint32)
         b_off = 0

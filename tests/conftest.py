@@ -53,6 +53,17 @@ def fresh_programs():
     compile_cache.clear()
 
 
+@pytest.fixture
+def no_pallas():
+    """``FLAGS_pallas_kernels=False`` — the operator's "no Pallas" — until
+    the test ends (an xdist worker goes on to other files)."""
+    from paddle_tpu import flags
+
+    flags.set_flags({"FLAGS_pallas_kernels": False})
+    yield
+    flags.set_flags({"FLAGS_pallas_kernels": True})
+
+
 # Tests of tests/benchmark_suite/ that state what was true of the benchmark
 # when they were written and is not of one with a further configuration; the
 # benchmark's own files change in a benchmark PR only, so until one

@@ -8,7 +8,7 @@ import numpy as np
 import jax
 
 from paddle_tpu import compile_cache
-from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops import attention_xla as fa
 from paddle_tpu.ops.pallas import streamed_attention as sa
 
 

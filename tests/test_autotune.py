@@ -7,7 +7,6 @@ ceiling — never an OOM — which is exactly what makes these tests
 hardware-free)."""
 
 import json
-import os
 
 import numpy as np
 import pytest
@@ -19,20 +18,16 @@ from paddle_tpu.monitor import program_profile
 
 @pytest.fixture(autouse=True)
 def _clean_autotune_state():
-    autotune.reset_attention_table()
     prev_pins = {n: flags.pinned(n)
-                 for n in ("pallas_kernels", "pallas_attention_max_seq",
-                           "autotune_hbm_bytes", "autotune_dir")}
+                 for n in ("autotune_hbm_bytes", "autotune_dir")}
     # a test file run earlier on this worker may have left one PINNED
     # (``set_flags`` pins by default): these tests start from none, and
     # the pins found are put back after
     flags._restore_pins(dict.fromkeys(prev_pins, False))
     yield
     fluid.set_flags({"FLAGS_autotune_hbm_bytes": 0,
-                     "FLAGS_autotune_dir": "",
-                     "FLAGS_pallas_kernels": False}, pin=False)
+                     "FLAGS_autotune_dir": ""}, pin=False)
     flags._restore_pins(prev_pins)
-    autotune.reset_attention_table()
     program_profile.reset()
     if monitor.enabled():
         monitor.disable()
@@ -119,17 +114,8 @@ def test_ladder_no_limit_measures_every_rung():
 
 
 # ---------------------------------------------------------------------------
-# attention kernel + bucket bounds (pure)
+# bucket bounds (pure)
 # ---------------------------------------------------------------------------
-
-def test_decide_attention_kernel_thresholds():
-    assert autotune.decide_attention_kernel(0.010, 0.006)["pallas"]
-    # a tie (or anything under min_speedup) goes to XLA
-    assert not autotune.decide_attention_kernel(0.010, 0.010)["pallas"]
-    assert not autotune.decide_attention_kernel(0.010, 0.0099)["pallas"]
-    d = autotune.decide_attention_kernel(0.012, 0.004, min_speedup=1.1)
-    assert d["pallas"] and d["speedup"] == pytest.approx(3.0)
-
 
 def _wmt16_like_lengths():
     """The bench's realistic skewed mix: lognormal lengths clipped to
@@ -230,50 +216,44 @@ def test_tuned_config_round_trip(tmp_path):
     assert doc["meta"]["version"] == autotune.TunedConfig.VERSION
 
 
-def test_pinned_flag_beats_tuned_attention_decision():
-    """A user-set FLAGS_pallas_kernels always wins over the decision
-    table: attention_choice returns None (flag rules), and apply()
-    records the pin instead of installing."""
-    q = k = (2, 2, 32, 16)
-    key = autotune.attention_shape_key(q, k, "float32")
-    autotune.attention_table().record("fp", key, True, persist=False)
-    assert autotune.attention_choice(q, k, "float32") is True
-    # the user pins the flag: the table is ignored
-    fluid.set_flags({"FLAGS_pallas_kernels": False})     # pin=True
-    assert flags.pinned("pallas_kernels")
-    assert autotune.attention_choice(q, k, "float32") is None
-    cfg = autotune.TunedConfig()
-    cfg.decisions.append({"knob": "attention_kernel", "shape": key,
-                          "pallas": True})
-    assert ("attention_kernel", "pinned") in cfg.apply()
-    # unpinned again: the ruling applies
-    flags._restore_pins({"pallas_kernels": False})
-    assert autotune.attention_choice(q, k, "float32") is True
-    assert ("attention_kernel", "applied") in cfg.apply()
+_OLD_RULINGS = {
+    # as ``tune_attention_kernel`` / ``tune_quant_kernel`` wrote them
+    # until PR 45
+    "attention_kernel": {
+        "knob": "attention_kernel", "shape": "T4096:K4096:d64:bfloat16",
+        "pallas": True, "xla_step_s": 0.012, "pallas_step_s": 0.004,
+        "speedup": 3.0, "min_speedup": 1.03,
+        "evidence": "measured_ab_window", "fingerprint": "abcdef012345",
+        "source": "measured"},
+    "quant_kernel": {
+        "knob": "quant_kernel", "shape": "M16:K2048:N2048:float32:dynamic",
+        "pallas": True, "evidence": "decision_table", "cached": True,
+        "fingerprint": "abcdef012345", "source": "cached"},
+}
 
 
-def test_attention_table_persists_and_rekeys_traces(tmp_path):
-    fluid.set_flags({"FLAGS_autotune_dir": str(tmp_path)}, pin=False)
-    t0 = compile_cache.trace_flag_values()
-    key = autotune.attention_shape_key((1, 1, 64, 16), (1, 1, 64, 16),
-                                       "float32")
-    autotune.attention_table().record("fp1", key, True)
-    # a new ruling re-keys every trace/AOT cache entry
-    assert compile_cache.trace_flag_values() != t0
-    assert os.path.exists(
-        str(tmp_path / autotune.AttentionDecisionTable.FILENAME))
-    # a cold process (fresh table) reads the persisted ruling
-    autotune.reset_attention_table()
-    e = autotune.attention_table().lookup("fp1", key)
-    assert e is not None and e["pallas"] is True
-    # shape-level fallback: another program's same shape gets the ruling
-    assert autotune.attention_table().lookup("other", key)["pallas"]
-    # and the OP-level chooser lazily activates the persisted table off
-    # the dir flag alone — a fresh process with FLAGS_autotune_dir set
-    # serves warm rulings without ever invoking the tuner
-    autotune.reset_attention_table()
-    assert autotune.attention_choice((1, 1, 64, 16), (1, 1, 64, 16),
-                                     "float32") is True
+@pytest.mark.parametrize("knob", sorted(_OLD_RULINGS))
+def test_an_old_artifacts_kernel_ruling_is_ignored(knob, tmp_path):
+    """An artifact an older run wrote is input from outside: its
+    ``attention_kernel`` / ``quant_kernel`` ruling loads, reads ``ignored``
+    — never an error — and changes nothing a step is keyed on or a rule
+    reads; the decisions beside it apply as before."""
+    path = str(tmp_path / "tuned.json")
+    with open(path, "w") as f:
+        json.dump({"meta": {"version": 1, "run_id": "r-old"},
+                   "decisions": [
+                       {"knob": "batch_size", "chosen": 512},
+                       _OLD_RULINGS[knob],
+                       {"knob": "checkpoint_interval", "chosen": 40}]}, f)
+    cfg = autotune.TunedConfig.load(path)
+    keyed, every = compile_cache.trace_flag_values(), dict(flags._FLAGS)
+    assert cfg.apply() == [("batch_size", "advisory"), (knob, "ignored"),
+                           ("checkpoint_interval", "advisory")]
+    assert compile_cache.trace_flag_values() == keyed
+    assert flags._FLAGS == every
+    assert cfg.value("batch_size") == 512
+    assert cfg.value("checkpoint_interval") == 40
+    assert cfg.value(knob) is None
 
 
 # ---------------------------------------------------------------------------
@@ -429,50 +409,6 @@ def test_tune_batch_size_twice_warm_registry_same_peaks():
     assert d2["chosen"] is not None
     assert [c["status"] for c in d2["candidates"]] \
         == [c["status"] for c in d1["candidates"]]
-
-
-def test_tune_attention_kernel_ab_and_warm_table(tmp_path):
-    """The measured A/B picks XLA at tiny shapes on CPU (the Pallas
-    kernel runs interpreted there), persists the ruling, and a warm
-    tuner call serves it with zero compiles."""
-    fluid.set_flags({"FLAGS_autotune_dir": str(tmp_path)}, pin=False)
-    n_head, T, dh, b = 2, 32, 16, 4
-    q = fluid.layers.data("q", shape=[n_head, T, dh])
-    k = fluid.layers.data("k", shape=[n_head, T, dh])
-    v = fluid.layers.data("v", shape=[n_head, T, dh])
-    att = fluid.layers.fused_attention(q, k, v, causal=True)
-    loss = fluid.layers.reduce_mean(att)
-    fluid.optimizer.SGD(learning_rate=0.0).minimize(loss)
-    rng = np.random.RandomState(0)
-    feed = {n: rng.rand(b, n_head, T, dh).astype("float32")
-            for n in "qkv"}
-    shape = ((b, n_head, T, dh), (b, n_head, T, dh), "float32")
-    cfg = autotune.TunedConfig()
-    d = autotune.tune_attention_kernel(
-        fluid.default_main_program(), fluid.default_startup_program(),
-        feed, loss, fluid.CPUPlace(), shape=shape, probe_steps=2,
-        config=cfg)
-    # both arms really ran, and the ruling IS the measured comparison
-    # (which kernel wins at toy CPU shapes is timing noise, not the
-    # contract — the contract is measured-A/B-decides)
-    assert d["xla_step_s"] > 0 and d["pallas_step_s"] > 0
-    assert d["pallas"] == (
-        d["xla_step_s"] / d["pallas_step_s"] >= d["min_speedup"])
-    # the A/B restored the flags unpinned
-    assert not flags.pinned("pallas_kernels")
-    assert flags.flag("pallas_kernels") is False
-    # warm process: fresh table object reads the persisted ruling and
-    # the tuner pays nothing — zero lowerings, zero measurement
-    autotune.reset_attention_table()
-    with compile_cache.count_compiles() as n:
-        d2 = autotune.tune_attention_kernel(
-            fluid.default_main_program(),
-            fluid.default_startup_program(), feed, loss,
-            fluid.CPUPlace(), shape=shape, probe_steps=2)
-    assert d2.get("cached") and d2["pallas"] == d["pallas"]
-    assert n()["jax_lowerings"] == 0
-    # and the op-level chooser serves the tuned ruling
-    assert autotune.attention_choice(*shape) == d["pallas"]
 
 
 def test_trainer_consumes_tuned_config(tmp_path):

@@ -38,12 +38,6 @@ def _ids(kind, n, v, rng):
     return rng.integers(0, v, n)        # unsorted, with repeats
 
 
-@pytest.fixture(autouse=True)
-def unpinned(monkeypatch):
-    """No ``FLAGS_pallas_kernels`` another test of the worker left pinned."""
-    monkeypatch.setattr(flags, "_PINNED", flags._PINNED - {"pallas_kernels"})
-
-
 @pytest.fixture
 def small_blocks(monkeypatch):
     """Blocks and chunks of 16 rows at width 512 (8 at 2048 and 2560): a few
@@ -211,8 +205,8 @@ CELL_SHAPES = {
 def test_the_rule_at_the_cells_shapes(monkeypatch, cell):
     """On a TPU, one device, every one-chip cell's table takes the segment
     body (float32 tables and rows, whole lane and sublane tiles); under a
-    mesh, on the CPU, or with ``FLAGS_pallas_kernels`` pinned off, the
-    generic one."""
+    mesh, on the CPU, or with ``FLAGS_pallas_kernels`` off, the generic
+    one."""
     n, v, d = CELL_SHAPES[cell]
     f32 = jnp.float32
     assert manipulation.segment_body(_ctx("tpu"), n, v, d, f32, f32)
@@ -220,10 +214,7 @@ def test_the_rule_at_the_cells_shapes(monkeypatch, cell):
                                          f32, f32)
     assert not manipulation.segment_body(_ctx("cpu"), n, v, d, f32, f32)
     monkeypatch.setitem(flags._FLAGS, "pallas_kernels", False)
-    monkeypatch.setattr(flags, "_PINNED", flags._PINNED | {"pallas_kernels"})
     assert not manipulation.segment_body(_ctx("tpu"), n, v, d, f32, f32)
-    monkeypatch.setattr(flags, "_PINNED", flags._PINNED - {"pallas_kernels"})
-    assert manipulation.segment_body(_ctx("tpu"), n, v, d, f32, f32)
 
 
 @pytest.mark.parametrize("n,v,d,w_dtype,g_dtype,takes", [

@@ -1,11 +1,12 @@
-"""Flash-attention kernel + fused_attention op tests.
+"""The XLA attention body, the fused_attention op and the packed kernel.
 
 Parity oracle: a plain materialized softmax-attention (the reference's
-``nets.scaled_dot_product_attention`` math, ``nets.py:323``) — the Pallas
-kernel (interpret mode on CPU) and the XLA fallback must both match it
-forward and backward, under padding masks, causal masks, and dropout
-(the dropout mask is a shared counter hash, so the two paths agree
-exactly)."""
+``nets.scaled_dot_product_attention`` math, ``nets.py:323``) written here —
+the XLA body (``ops/attention_xla.reference_attention``) must match it
+forward and backward, under padding masks, causal masks, the suffix-causal
+decode shape and dropout (the dropout mask is a counter hash, restated here
+in numpy); the packed kernel (interpret mode on CPU) is held to the XLA
+body."""
 
 import numpy as np
 import pytest
@@ -14,10 +15,10 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu as fluid
-from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops import attention_xla as ax
 
 
-def _oracle(q, k, v, k_len=None, causal=False, scale=None):
+def _oracle(q, k, v, k_len=None, causal=False, scale=None, keep=None):
     b, h, tq, d = q.shape
     tk = k.shape[2]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
@@ -31,6 +32,9 @@ def _oracle(q, k, v, k_len=None, causal=False, scale=None):
     s = jnp.where(mask, s, -1e30)
     y = jax.nn.softmax(s, axis=-1)
     y = jnp.where(mask, y, 0.0)
+    if keep is not None:
+        # downgrade_in_infer: dropped, not upscaled
+        y = jnp.where(keep, y, 0.0)
     return jnp.einsum("bhqk,bhkd->bhqd", y, v)
 
 
@@ -39,100 +43,160 @@ def _rand(shape, seed=0):
         np.random.RandomState(seed).randn(*shape).astype("float32"))
 
 
-@pytest.mark.parametrize("tq,tk,causal", [
-    (16, 16, False), (16, 16, True),
-    (24, 40, False),          # non-multiple-of-block lengths, cross shape
-    (64, 64, True),
-])
-def test_fwd_parity(tq, tk, causal):
-    if causal and tq != tk:
-        pytest.skip("causal needs tq == tk")
-    q = _rand((2, 3, tq, 8), 0)
-    k = _rand((2, 3, tk, 8), 1)
-    v = _rand((2, 3, tk, 8), 2)
-    out = fa.flash_attention(q, k, v, None, None, causal, 0.0, None, True)
-    ref = _oracle(q, k, v, causal=causal)
-    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
-    # XLA fallback agrees too
-    fb = fa.reference_attention(q, k, v, None, None, causal, 0.0, None)
-    np.testing.assert_allclose(fb, ref, rtol=2e-5, atol=2e-5)
+def _keep_by_hand(seed, b, h, tq, tk, rate):
+    """The dropout hash in numpy: murmur3's finalizer over (query, key)
+    position words and the seed offset by the (batch, head) index; the top
+    24 bits against the rate."""
+    u = np.uint32
+    with np.errstate(over="ignore"):
+        x = (np.arange(tq, dtype=u)[:, None] * u(0x85EBCA6B)) ^ \
+            (np.arange(tk, dtype=u)[None, :] * u(0xC2B2AE35))
+        x = x ^ (u(seed) + np.arange(b * h, dtype=u).reshape(b, h, 1, 1)
+                 * u(0x9E3779B1))
+        x = x ^ (x >> u(16))
+        x = x * u(0x7FEB352D)
+        x = x ^ (x >> u(15))
+        x = x * u(0x846CA68B)
+        x = x ^ (x >> u(16))
+    return (x >> u(8)) >= u(int(rate * float(1 << 24)))
 
 
-def test_fwd_klen_padding():
-    q, k, v = _rand((3, 2, 16, 8), 0), _rand((3, 2, 16, 8), 1), \
-        _rand((3, 2, 16, 8), 2)
+def _qkv(b, h, tq, tk, d):
+    return _rand((b, h, tq, d), 0), _rand((b, h, tk, d), 1), \
+        _rand((b, h, tk, d), 2)
+
+
+def _close(got, want, tol=2e-5):
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+
+def _forward_case(tq, tk, causal):
+    def run():
+        q, k, v = _qkv(2, 3, tq, tk, 8)
+        _close(ax.reference_attention(q, k, v, None, None, causal, 0.0, None),
+               _oracle(q, k, v, causal=causal))
+    return run
+
+
+def _klen_padding():
+    q, k, v = _qkv(3, 2, 16, 16, 8)
     k_len = jnp.asarray([16, 7, 1], jnp.int32)
-    out = fa.flash_attention(q, k, v, k_len, None, False, 0.0, None, True)
-    ref = _oracle(q, k, v, k_len=k_len)
-    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+    _close(ax.reference_attention(q, k, v, k_len, None, False, 0.0, None),
+           _oracle(q, k, v, k_len=k_len))
 
 
-def test_fully_masked_rows_are_zero_and_grad_safe():
-    # causal + k_len=0 would be degenerate; here: k_len smaller than some
-    # query positions under causal gives rows with zero valid keys only if
-    # k_len == 0 — use k_len 0 on one batch element
-    q, k, v = _rand((2, 1, 8, 4), 0), _rand((2, 1, 8, 4), 1), \
-        _rand((2, 1, 8, 4), 2)
+def _fully_masked_rows():
+    # a batch row with no valid key: zeros out, finite gradients
+    q, k, v = _qkv(2, 1, 8, 8, 4)
     k_len = jnp.asarray([8, 0], jnp.int32)
 
     def f(q, k, v):
-        return jnp.sum(fa.flash_attention(q, k, v, k_len, None, False, 0.0,
-                                          None, True) ** 2)
-
-    out = fa.flash_attention(q, k, v, k_len, None, False, 0.0, None, True)
+        return jnp.sum(ax.reference_attention(q, k, v, k_len, None, False,
+                                              0.0, None) ** 2)
+    out = ax.reference_attention(q, k, v, k_len, None, False, 0.0, None)
     assert np.all(np.asarray(out[1]) == 0.0)
-    grads = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-    for g in grads:
+    for g in jax.grad(f, argnums=(0, 1, 2))(q, k, v):
         assert np.isfinite(np.asarray(g)).all()
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_grad_parity(causal):
-    q, k, v = _rand((2, 2, 16, 8), 0), _rand((2, 2, 16, 8), 1), \
-        _rand((2, 2, 16, 8), 2)
-    k_len = jnp.asarray([16, 11], jnp.int32)
-    w = _rand((2, 2, 16, 8), 3)   # nonuniform cotangent
+def _grad_case(causal, rate=0.0):
+    def run():
+        q, k, v = _qkv(2, 2, 16, 16, 8)
+        k_len = jnp.asarray([16, 11], jnp.int32)
+        w = _rand((2, 2, 16, 8), 3)   # nonuniform cotangent
+        seed = jnp.asarray(1234, jnp.uint32) if rate else None
+        keep = _keep_by_hand(1234, 2, 2, 16, 16, rate) if rate else None
 
-    def f_flash(q, k, v):
-        return jnp.sum(w * fa.flash_attention(q, k, v, k_len, None, causal,
-                                              0.0, None, True))
+        def f_body(q, k, v):
+            return jnp.sum(w * ax.reference_attention(
+                q, k, v, k_len, seed, causal, rate, None))
 
-    def f_ref(q, k, v):
-        return jnp.sum(w * _oracle(q, k, v, k_len=k_len, causal=causal))
+        def f_ref(q, k, v):
+            return jnp.sum(w * _oracle(q, k, v, k_len=k_len, causal=causal,
+                                       keep=keep))
+        for a, b in zip(jax.grad(f_body, argnums=(0, 1, 2))(q, k, v),
+                        jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)):
+            _close(a, b, 2e-4)
+    return run
 
-    gf = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(gf, gr):
-        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
 
-
-def test_dropout_fwd_and_grad_match_fallback():
-    """Pallas path and XLA fallback share the counter-hash dropout mask:
-    outputs and gradients agree exactly (same math, different schedule)."""
-    q, k, v = _rand((2, 2, 16, 8), 0), _rand((2, 2, 16, 8), 1), \
-        _rand((2, 2, 16, 8), 2)
-    seed = jnp.asarray(1234, jnp.uint32)
+def _dropout_mask():
+    """The weights the body drops are the hash's, a seed apart they are
+    others, and about ``rate`` of them go."""
+    q, k, v = _qkv(2, 2, 16, 16, 8)
     rate = 0.4
+    keep = _keep_by_hand(1234, 2, 2, 16, 16, rate)
+    assert 0.25 < 1.0 - keep.mean() < 0.55
+    out = ax.reference_attention(q, k, v, None, jnp.asarray(1234, jnp.uint32),
+                                 False, rate)
+    _close(out, _oracle(q, k, v, keep=keep), 1e-5)
+    assert np.array_equal(
+        np.asarray(ax._keep_mask(jnp.uint32(1234),
+                                 jnp.arange(4, dtype=jnp.int32
+                                            ).reshape(2, 2, 1, 1),
+                                 jnp.arange(16)[:, None],
+                                 jnp.arange(16)[None, :], rate)), keep)
+    other = ax.reference_attention(q, k, v, None,
+                                   jnp.asarray(1235, jnp.uint32), False, rate)
+    assert not np.allclose(out, other)
 
-    def f_pl(q, k, v):
-        return jnp.sum(fa.flash_attention(q, k, v, None, seed, False, rate,
-                                          None, True) ** 2)
 
-    def f_fb(q, k, v):
-        return jnp.sum(fa.reference_attention(q, k, v, None, seed, False,
-                                              rate) ** 2)
+def _suffix_case(tq, klen):
+    """causal with Tq < Tk: queries are the LAST tq of the klen valid
+    keys — parity against the sliced rows of a full-length causal call
+    (the workaround this mask retires)."""
+    def run():
+        tk, b = 16, 2
+        q_full, k, v = _qkv(b, 3, tk, tk, 8)
+        k_len = jnp.asarray([klen] * b, jnp.int32)
+        full = _oracle(q_full, k, v, k_len=k_len, causal=True)
+        lo = klen - tq
+        got = ax.reference_attention(q_full[:, :, lo:klen, :], k, v, k_len,
+                                     None, True, 0.0, None)
+        _close(got, full[:, :, lo:klen, :])
+    return run
 
-    out_pl = fa.flash_attention(q, k, v, None, seed, False, rate, None, True)
-    out_fb = fa.reference_attention(q, k, v, None, seed, False, rate)
-    np.testing.assert_allclose(out_pl, out_fb, rtol=1e-5, atol=1e-5)
-    g_pl = jax.grad(f_pl, argnums=(0, 1, 2))(q, k, v)
-    g_fb = jax.grad(f_fb, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g_pl, g_fb):
-        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
-    # different seeds give different masks
-    out2 = fa.flash_attention(q, k, v, None, seed + 1, False, rate, None,
-                              True)
-    assert not np.allclose(out_pl, out2)
+
+def _suffix_per_batch_lengths():
+    """Single-token decode (Tq=1) with DIFFERENT valid lengths per batch
+    row: each query sits at its own batch's position klen-1."""
+    tk, b = 16, 3
+    q_full, k, v = _qkv(b, 2, tk, tk, 8)
+    k_len = jnp.asarray([16, 9, 1], jnp.int32)
+    full = np.asarray(_oracle(q_full, k, v, k_len=k_len, causal=True))
+    q_suf = jnp.stack([q_full[i, :, int(k_len[i]) - 1: int(k_len[i]), :]
+                       for i in range(b)])
+    want = np.stack([full[i, :, int(k_len[i]) - 1: int(k_len[i]), :]
+                     for i in range(b)])
+    _close(ax.reference_attention(q_suf, k, v, k_len, None, True, 0.0, None),
+           want)
+
+
+XLA_BODY_CASES = {
+    "forward_16": _forward_case(16, 16, False),
+    "forward_causal_16": _forward_case(16, 16, True),
+    "forward_cross_24x40": _forward_case(24, 40, False),
+    "forward_causal_64": _forward_case(64, 64, True),
+    "klen_padding": _klen_padding,
+    "fully_masked_rows_zero_and_grad_safe": _fully_masked_rows,
+    "grad": _grad_case(False),
+    "grad_causal": _grad_case(True),
+    "dropout_mask_is_the_hash": _dropout_mask,
+    "dropout_grad": _grad_case(False, 0.4),
+    "suffix_causal_1_of_16": _suffix_case(1, 16),
+    "suffix_causal_4_of_9": _suffix_case(4, 9),
+    "suffix_causal_8_of_16": _suffix_case(8, 16),
+    "suffix_causal_per_batch_lengths": _suffix_per_batch_lengths,
+}
+
+
+@pytest.mark.parametrize("case", sorted(XLA_BODY_CASES))
+def test_xla_body_against_a_composition(case):
+    """``reference_attention`` — the body every kernel is held to, and what
+    the op lowers to wherever no rule takes the call — against the plain
+    composition above: each mask shape, the fully-masked-row contract, the
+    dropout hash's mask and its gradient."""
+    XLA_BODY_CASES[case]()
 
 
 def test_dropout_expectation_matches_infer_scale():
@@ -141,7 +205,7 @@ def test_dropout_expectation_matches_infer_scale():
     q, k, v = _rand((1, 1, 32, 8), 0), _rand((1, 1, 32, 8), 1), \
         _rand((1, 1, 32, 8), 2)
     rate = 0.3
-    outs = [fa.reference_attention(q, k, v, None,
+    outs = [ax.reference_attention(q, k, v, None,
                                    jnp.asarray(s, jnp.uint32), False, rate)
             for s in range(40)]
     mean = np.mean([np.asarray(o) for o in outs], axis=0)
@@ -188,16 +252,6 @@ def test_fused_attention_op_matches_composition():
     fused = _attention_program(True)
     manual = _attention_program(False)
     np.testing.assert_allclose(fused, manual, rtol=1e-4, atol=1e-4)
-
-
-def test_fused_attention_op_pallas_flag():
-    base = _attention_program(True)
-    fluid.set_flags({"FLAGS_pallas_kernels": True})
-    try:
-        pallas = _attention_program(True)
-    finally:
-        fluid.set_flags({"FLAGS_pallas_kernels": False})
-    np.testing.assert_allclose(base, pallas, rtol=1e-4, atol=1e-4)
 
 
 def test_label_smooth_fused_matches_composition():
@@ -248,86 +302,16 @@ def test_transformer_emits_fused_attention():
         assert "one_hot" not in ops
 
 
-def test_label_smooth_pallas_kernel_matches_xla():
-    """The hand-tiled softmax_xent kernel with fused label smoothing must
-    match the XLA fused path forward and backward."""
-    import jax
-    import jax.numpy as jnp
-    from paddle_tpu.ops.pallas import softmax_xent as px
-
-    n, c, eps = 12, 17, 0.1
-    rng = np.random.RandomState(1)
-    logits = jnp.asarray(rng.randn(n, c).astype("float32"))
-    label = jnp.asarray(rng.randint(0, c, (n,)))
-
-    def xla(lg):
-        lse = jax.scipy.special.logsumexp(lg, axis=-1, keepdims=True)
-        picked = jnp.take_along_axis(lg - lse, label[:, None], axis=-1)
-        uni = lse - jnp.mean(lg, axis=-1, keepdims=True)
-        return jnp.sum(((1 - eps) * -picked + eps * uni) ** 2)
-
-    def pallas(lg):
-        loss, _ = px.softmax_xent(lg, label, True, eps)
-        return jnp.sum(loss ** 2)
-
-    np.testing.assert_allclose(xla(logits), pallas(logits), rtol=1e-5)
-    np.testing.assert_allclose(jax.grad(xla)(logits),
-                               jax.grad(pallas)(logits),
-                               rtol=1e-4, atol=1e-5)
-
-
 # ---------------------------------------------------------------------------
 # suffix-query (bottom-aligned) causal masks: the KV-cache decode shape
 # ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("tq,klen", [(1, 16), (4, 9), (8, 16)])
-def test_suffix_causal_decode_parity(tq, klen):
-    """causal with Tq < Tk: queries are the LAST tq of the klen valid
-    keys — parity against the sliced rows of a full-length causal call
-    (the workaround this mask retires)."""
-    tk, b, h, d = 16, 2, 3, 8
-    q_full = _rand((b, h, tk, d), 0)
-    k = _rand((b, h, tk, d), 1)
-    v = _rand((b, h, tk, d), 2)
-    k_len = jnp.asarray([klen] * b, jnp.int32)
-    full = _oracle(q_full, k, v, k_len=k_len, causal=True)
-    lo = klen - tq
-    q_suf = q_full[:, :, lo:klen, :]
-    want = full[:, :, lo:klen, :]
-    got_fb = fa.reference_attention(q_suf, k, v, k_len, None, True, 0.0,
-                                    None)
-    np.testing.assert_allclose(got_fb, want, rtol=2e-5, atol=2e-5)
-    got_pl = fa.flash_attention(q_suf, k, v, k_len, None, True, 0.0, None,
-                                True)
-    np.testing.assert_allclose(got_pl, want, rtol=2e-5, atol=2e-5)
-
-
-def test_suffix_causal_per_batch_lengths():
-    """Single-token decode (Tq=1) with DIFFERENT valid lengths per batch
-    row: each query sits at its own batch's position klen-1."""
-    tk, b, h, d = 16, 3, 2, 8
-    q_full = _rand((b, h, tk, d), 0)
-    k = _rand((b, h, tk, d), 1)
-    v = _rand((b, h, tk, d), 2)
-    k_len = jnp.asarray([16, 9, 1], jnp.int32)
-    full = np.asarray(_oracle(q_full, k, v, k_len=k_len, causal=True))
-    q_suf = jnp.stack([q_full[i, :, int(k_len[i]) - 1: int(k_len[i]), :]
-                       for i in range(b)])
-    want = np.stack([full[i, :, int(k_len[i]) - 1: int(k_len[i]), :]
-                     for i in range(b)])
-    for fn in (fa.reference_attention,
-               lambda *a: fa.flash_attention(*a, True)):
-        got = fn(q_suf, k, v, k_len, None, True, 0.0, None)
-        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
-
 
 @pytest.mark.slow
 def test_suffix_causal_grad_parity():
     """Backward parity for the chunked-decode shape: grads of the suffix
     call equal the corresponding grads of the sliced full-length
-    objective (rows outside the suffix contribute nothing).  Slow: two
-    interpret-mode backward kernel compiles; the fwd parity set above
-    stays tier-1."""
+    objective (rows outside the suffix contribute nothing); the forward
+    parity cases above stay tier-1."""
     tk, tq, klen, b, h, d = 16, 4, 11, 2, 2, 8
     q_full = _rand((b, h, tk, d), 0)
     k = _rand((b, h, tk, d), 1)
@@ -342,16 +326,14 @@ def test_suffix_causal_grad_parity():
 
     gq_full, gk_full, gv_full = jax.grad(f_full, (0, 1, 2))(q_full, k, v)
     q_suf = q_full[:, :, lo:klen, :]
-    for fn in (lambda q, k, v: fa.flash_attention(q, k, v, k_len, None,
-                                                  True, 0.0, None, True),
-               lambda q, k, v: fa.reference_attention(q, k, v, k_len,
-                                                      None, True, 0.0)):
-        f = lambda q, k, v: jnp.sum(w * fn(q, k, v))  # noqa: E731
-        gq, gk, gv = jax.grad(f, (0, 1, 2))(q_suf, k, v)
-        np.testing.assert_allclose(gq, gq_full[:, :, lo:klen, :],
-                                   rtol=2e-4, atol=2e-4)
-        np.testing.assert_allclose(gk, gk_full, rtol=2e-4, atol=2e-4)
-        np.testing.assert_allclose(gv, gv_full, rtol=2e-4, atol=2e-4)
+    def f(q, k, v):
+        return jnp.sum(w * ax.reference_attention(q, k, v, k_len, None, True,
+                                                  0.0))
+    gq, gk, gv = jax.grad(f, (0, 1, 2))(q_suf, k, v)
+    np.testing.assert_allclose(gq, gq_full[:, :, lo:klen, :],
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(gk, gk_full, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(gv, gv_full, rtol=2e-4, atol=2e-4)
 
 
 def test_fused_attention_op_rejects_query_longer_than_keys():
@@ -415,7 +397,7 @@ def test_packed_kernel_matches_reference(kind, dtype):
     mode) against ``reference_attention`` on the same operands."""
     q, k, v, k_len, causal = _packed_case(kind, jnp.dtype(dtype))
     ref, ref_vjp = jax.vjp(
-        lambda q, k, v: fa.reference_attention(q, k, v, k_len, None, causal),
+        lambda q, k, v: ax.reference_attention(q, k, v, k_len, None, causal),
         q, k, v)
     out, out_vjp = jax.vjp(
         lambda q, k, v: _packed(q, k, v, k_len, None, causal, 0.0), q, k, v)
@@ -442,7 +424,7 @@ def test_packed_dropout_mask_and_gradient_match_xla_body(causal):
     seed = jnp.asarray(20250925, jnp.uint32)
     rate = 0.4
     ref, ref_vjp = jax.vjp(
-        lambda q, k, v: fa.reference_attention(q, k, v, k_len, seed, causal,
+        lambda q, k, v: ax.reference_attention(q, k, v, k_len, seed, causal,
                                                rate), q, k, v)
     out, out_vjp = jax.vjp(
         lambda q, k, v: _packed(q, k, v, k_len, seed, causal, rate), q, k, v)
@@ -485,17 +467,13 @@ def test_packed_supported_states_its_bound():
 
 def _cpu_takes_packed(monkeypatch, on=True):
     """Let a CPU trace take the packed kernel (interpreted), as a TPU
-    trace does, until the test ends.  FLAGS_pallas_kernels starts
-    unpinned — an earlier test's ``set_flags`` leaves it pinned False,
-    which is "no Pallas" and would rule the kernel out — and the trace
-    cache is emptied: the patched tuple is in no cache key."""
-    from paddle_tpu import compile_cache, flags
+    trace does, until the test ends.  The trace cache is emptied: the
+    patched tuple is in no cache key."""
+    from paddle_tpu import compile_cache
     from paddle_tpu.ops import attention as att
 
     monkeypatch.setattr(att, "_PACKED_PLATFORMS",
                         ("tpu", "cpu") if on else ("tpu",))
-    monkeypatch.setattr(flags, "_PINNED",
-                        flags._PINNED - {"pallas_kernels"})
     compile_cache.clear()
 
 
@@ -522,28 +500,24 @@ def _op_body(monkeypatch, q_shape, k_shape, causal=False, mesh=None,
     after = compile_cache.stats()["kernel_bodies"]
     bodies = {key: n - before.get(key, 0) for key, n in after.items()
               if n != before.get(key, 0)}
-    ref = fa.reference_attention(q, k, v, None, None, causal)
+    ref = ax.reference_attention(q, k, v, None, None, causal)
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
     return bodies
 
 
 @pytest.mark.parametrize("case", ["in_bound", "out_of_bound", "decode_shape",
-                                  "sp_mesh", "pinned_flag", "cpu_keeps_xla"])
+                                  "sp_mesh", "flag_off", "cpu_keeps_xla"])
 def test_fused_attention_body_is_chosen_from_what_the_op_observes(
-        case, monkeypatch):
+        case, monkeypatch, request):
     """The packed body's rule: platform, mesh, shapes — no flag turns it
-    on, a PINNED FLAGS_pallas_kernels=False turns it off.  (The test lets
-    the CPU platform take the kernel, interpreted; the program never
-    does.)"""
-    from paddle_tpu import flags
+    on, FLAGS_pallas_kernels=False turns it off.  (The test lets the CPU
+    platform take the kernel, interpreted; the program never does.)"""
     from paddle_tpu.parallel.mesh import make_mesh
 
     small = (2, 2, 16, 64)
     if case == "in_bound":
         assert _op_body(monkeypatch, small, small) == \
             {"fused_attention:packed": 1}
-        # an UNPINNED False is the flag's default, not an operator's word
-        assert not flags.pinned("pallas_kernels")
     elif case == "out_of_bound":
         big = (1, 8, 512, 64)
         assert _op_body(monkeypatch, big, big) == {"fused_attention:xla": 1}
@@ -554,11 +528,9 @@ def test_fused_attention_body_is_chosen_from_what_the_op_observes(
         mesh = make_mesh((2, 4), ("dp", "sp"))
         assert _op_body(monkeypatch, small, small, mesh=mesh) == \
             {"fused_attention:ring": 1}
-    elif case == "pinned_flag":
-        _cpu_takes_packed(monkeypatch)
-        fluid.set_flags({"FLAGS_pallas_kernels": False})      # pins
-        assert flags.pinned("pallas_kernels")
-        assert _op_body(monkeypatch, small, small, on_cpu=None) == \
+    elif case == "flag_off":
+        request.getfixturevalue("no_pallas")
+        assert _op_body(monkeypatch, small, small) == \
             {"fused_attention:xla": 1}
     else:
         assert _op_body(monkeypatch, small, small, on_cpu=False) == \
@@ -692,7 +664,7 @@ def test_packed_body_under_dp_gathers_nothing(monkeypatch):
         and "collective-permute" not in text and "all-to-all" not in text
     out, dq, dk, dv = compiled(q, k, v, klen, ct)
     ref, vjp = jax.vjp(
-        lambda q, k, v: fa.reference_attention(q, k, v, klen, None, True),
+        lambda q, k, v: ax.reference_attention(q, k, v, klen, None, True),
         q, k, v)
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
     for g, g_ref in zip((dq, dk, dv), vjp(ct)):

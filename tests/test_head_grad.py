@@ -26,12 +26,6 @@ from paddle_tpu.parallel import make_mesh
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-@pytest.fixture(autouse=True)
-def unpinned(monkeypatch):
-    """No ``FLAGS_pallas_kernels`` another test of the worker left pinned."""
-    monkeypatch.setattr(flags, "_PINNED", flags._PINNED - {"pallas_kernels"})
-
-
 @pytest.fixture
 def small_tiles(monkeypatch):
     """Tiles of 128 x 128: a few hundred rows and columns are several row
@@ -246,13 +240,13 @@ def test_a_loss_the_kernel_does_not_make_keeps_the_chain_op_by_op(
 
 @pytest.mark.parametrize("why", ["softmax_read", "gradient_fetched",
                                  "off_the_tiles", "float32_program",
-                                 "pinned_off"])
+                                 "flag_off"])
 def test_a_chain_the_rule_cannot_take_falls_back_and_says_so(
         on_the_cpu, monkeypatch, why):
     """The chain is there, the kernel is not its body: the ``Softmax``
     output read by another op, a gradient in between fetched, a vocabulary
     off the lane tiles, float32 products (the kernel was measured on bf16),
-    ``FLAGS_pallas_kernels`` pinned off — ``mul_grad:head_by_op``, and the
+    ``FLAGS_pallas_kernels`` off — ``mul_grad:head_by_op``, and the
     step is the op-by-op step."""
     n, d, v = 256, 128, (200 if why == "off_the_tiles" else 256)
     main, startup, loss = _head_program(
@@ -260,10 +254,8 @@ def test_a_chain_the_rule_cannot_take_falls_back_and_says_so(
         amp=why != "float32_program")
     ops, i = _head_ops(main)
     assert loss_ops.head_chain(ops, i) is not None
-    if why == "pinned_off":
+    if why == "flag_off":
         monkeypatch.setitem(flags._FLAGS, "pallas_kernels", False)
-        monkeypatch.setattr(flags, "_PINNED",
-                            flags._PINNED | {"pallas_kernels"})
     fetch = [loss] + (["head.tmp_1@GRAD"] if why == "gradient_fetched"
                       else [])
     before = dict(compile_cache.stats()["kernel_bodies"])

@@ -19,7 +19,7 @@ import paddle_tpu as fluid
 from paddle_tpu.models import hybrid_decoder as hd
 from paddle_tpu.ops import attention as att
 from paddle_tpu.ops import state_space
-from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops import attention_xla as fa
 from paddle_tpu.ops.pallas import selective_scan as ss
 from paddle_tpu.ops.pallas import streamed_attention as sa
 

@@ -20,7 +20,7 @@ from op_test import OpTest
 from paddle_tpu import compile_cache
 from paddle_tpu.ops import attention as att
 from paddle_tpu.ops import moe
-from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops import attention_xla as fa
 from paddle_tpu.ops.pallas import streamed_attention as sa
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -355,10 +355,40 @@ def test_fused_attention_op_takes_values_narrower_than_keys(body,
     compile_cache.clear()
 
 
+# (H, Hkv, T, Dk, Dv) -> the answer of the tree before PR 45 (where the
+# bound was the flash kernel's ``supported(bf16, max_seq=T)``), generated
+# from that tree and frozen: every decoder cell's program carries the answer
+# as ``keep_lse``, so it must not move with the code that states it
+PLAIN_HEADS_TABLE = [
+    ((16, 16, 512, 64, 64), False), ((16, 16, 2048, 64, 64), False),
+    ((16, 16, 4096, 64, 64), False), ((16, 16, 8192, 64, 64), False),
+    ((16, 16, 512, 128, 128), False), ((16, 16, 2048, 128, 128), False),
+    ((16, 16, 2560, 128, 128), False), ((16, 16, 3072, 128, 128), False),
+    ((16, 16, 3584, 128, 128), False), ((16, 16, 3712, 128, 128), True),
+    ((16, 16, 4096, 128, 128), True),
+    ((16, 16, 8192, 128, 128), True),
+    ((16, 16, 512, 192, 128), True), ((16, 16, 2048, 192, 128), True),
+    ((16, 16, 8192, 192, 128), True),
+    ((16, 16, 1024, 256, 256), False), ((16, 16, 1536, 256, 256), False),
+    ((16, 16, 1792, 256, 256), True), ((16, 16, 2048, 256, 256), True),
+    ((32, 4, 8192, 128, 128), True), ((16, 16, 2000, 128, 128), False),
+    ((16, 16, 4096, 96, 96), False),
+]
+
+
+@pytest.mark.parametrize("shape,parents", PLAIN_HEADS_TABLE,
+                         ids=["x".join(map(str, s))
+                              for s, _ in PLAIN_HEADS_TABLE])
+def test_streams_plain_heads_answers_as_the_parent_did(shape, parents):
+    h, hk, t, dk, dv = shape
+    assert att.streams_plain_heads((2, h, t, dk), (2, hk, t, dk),
+                                   (2, hk, t, dv), False, 0.0) == parents
+
+
 def test_which_attention_calls_keep_their_log_sum_exp():
     """Shapes alone decide: values of another width, or plain-head
-    self-attention the streamed kernel takes at a length where the
-    resident-K/V kernel's VMEM bound says no.  The calls the benchmark's
+    self-attention the streamed kernel takes at a length past the inherited
+    resident-K/V bound (``_resident_kv_fits``).  The calls the benchmark's
     other steps make keep the bodies they had: Transformer-base's (64-wide
     heads, padding masks, T = 64; cross attention) and anything short."""
     long, short = (1, 32, 8192, 128), (256, 8, 64, 64)
@@ -369,8 +399,8 @@ def test_which_attention_calls_keep_their_log_sum_exp():
     assert not att.streams_plain_heads(long, long, long, False, 0.1)
     assert not att.streams_plain_heads(short, short, short, True, 0.0)
     assert not att.streams_plain_heads(short, short, short, False, 0.0)
-    mid = (2, 8, 2048, 128)        # the resident kernel still holds these
-    assert fa.supported(mid, mid, jnp.bfloat16, max_seq=2048)
+    mid = (2, 8, 2048, 128)        # inside the inherited bound
+    assert att._resident_kv_fits(2048, 2048, 128)
     assert not att.streams_plain_heads(mid, mid, mid, False, 0.0)
     cross = (2, 8, 8192, 128)
     assert not att.streams_plain_heads((2, 8, 128, 128), cross, cross,

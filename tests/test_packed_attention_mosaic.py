@@ -449,22 +449,9 @@ def _tiny_expert_decoder(kind):
     return main, loss, names
 
 
-@pytest.fixture
-def no_pinned_pallas_flag():
-    """An earlier file of this worker may have left ``FLAGS_pallas_kernels``
-    pinned to False (``test_flash_attention.py`` and ``test_pallas_kernels.py``
-    set it and do not unpin), under which an op picks no kernel by shape."""
-    from paddle_tpu import flags
-
-    was = flags.pinned("pallas_kernels")
-    flags._restore_pins({"pallas_kernels": False})
-    yield
-    flags._restore_pins({"pallas_kernels": was})
-
-
 @pytest.mark.parametrize("kind", ["sparse", "latent"])
 def test_a_decoder_step_traces_and_lowers_each_grouped_kernel_once(
-        one_chip, kind, no_pinned_pallas_flag):
+        one_chip, kind):
     """A step of a tiny decoder with two expert layers, traced for a TPU and
     lowered for the described v5e: both ops of both layers take the grouped
     kernels, ten call sites reach them (the forward's two and the backward's

@@ -1,165 +1,153 @@
-"""Pallas kernel parity tests: the hand-tiled softmax_with_cross_entropy
-and layer_norm bodies (ops/pallas/) must match the pure-JAX registry
-kernels bit-for-tolerance, forward and backward, on the CPU interpreter
-(pallas interpret mode)."""
+"""The one switch over the kernels' rules.
+
+Every Pallas kernel is picked by its op's own rule (platform, mesh, shapes),
+and every rule goes through ``ops.pallas.kernel_allowed``, which reads
+``FLAGS_pallas_kernels`` (default True): set to False — the operator's "no
+Pallas", against a kernel that miscompiles on a new runtime — a cached
+program lowers again (the flag is in every step's cache key) and each rule
+says no.  One case a rule; the CPU is let into the rule's platforms for the
+test (interpreted), as each kernel's own tests do."""
 
 import numpy as np
 import pytest
 
 import paddle_tpu as fluid
+from paddle_tpu import compile_cache, flags
+from paddle_tpu.ops import attention, loss as loss_ops, manipulation, moe, \
+    pallas, sparse_select, state_space
+from paddle_tpu.ops.pallas import head_grad
 
 
-def _train_step_losses(use_pallas, steps=5):
-    with fluid.program_guard(fluid.Program(), fluid.Program()):
-        fluid.default_startup_program().random_seed = 3
-        x = fluid.layers.data("x", shape=[32])
-        label = fluid.layers.data("label", shape=[1], dtype="int64")
-        h = fluid.layers.fc(x, size=64, act=None)
-        h = fluid.layers.layer_norm(h)
-        h = fluid.layers.relu(h)
-        logits = fluid.layers.fc(h, size=10, act=None)
-        loss = fluid.layers.mean(
-            fluid.layers.softmax_with_cross_entropy(logits, label))
-        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
-        rng = np.random.RandomState(0)
-        xs = rng.rand(16, 32).astype("float32")
-        ys = rng.randint(0, 10, (16, 1)).astype("int64")
-        scope = fluid.Scope()
-        with fluid.scope_guard(scope):
-            exe = fluid.Executor(fluid.CPUPlace())
-            exe.run(fluid.default_startup_program())
-            fluid.set_flags({"FLAGS_pallas_kernels": use_pallas})
-            try:
-                losses = [float(exe.run(feed={"x": xs, "label": ys},
-                                        fetch_list=[loss])[0].ravel()[0])
-                          for _ in range(steps)]
-            finally:
-                fluid.set_flags({"FLAGS_pallas_kernels": False})
-    return losses
+def _data(name, shape, dtype="float32"):
+    return fluid.layers.data(name, shape=list(shape), dtype=dtype,
+                             append_batch_size=False)
 
 
-def test_pallas_training_matches_xla_path():
-    ref = _train_step_losses(False)
-    pal = _train_step_losses(True)
-    np.testing.assert_allclose(pal, ref, rtol=1e-4)
+def _normal(rng, *shape):
+    return rng.standard_normal(shape).astype("float32")
 
 
-def test_pallas_softmax_xent_forward_backward_parity():
-    from paddle_tpu.ops.pallas import softmax_xent as px
-    import jax
-    import jax.numpy as jnp
-
-    rng = np.random.RandomState(1)
-    logits = rng.randn(24, 50).astype("float32") * 3
-    label = rng.randint(0, 50, (24,))
-
-    def pallas_loss(lg):
-        loss, _ = px.softmax_xent(lg, jnp.asarray(label), True)
-        return jnp.sum(loss)
-
-    def ref_loss(lg):
-        ls = jax.nn.log_softmax(lg, axis=-1)
-        return -jnp.sum(jnp.take_along_axis(ls, jnp.asarray(label)[:, None],
-                                            axis=-1))
-
-    lv_p, g_p = jax.value_and_grad(pallas_loss)(jnp.asarray(logits))
-    lv_r, g_r = jax.value_and_grad(ref_loss)(jnp.asarray(logits))
-    assert float(lv_p) == pytest.approx(float(lv_r), rel=1e-5)
-    np.testing.assert_allclose(np.asarray(g_p), np.asarray(g_r),
-                               atol=1e-5)
+def _attention(q_shape, kv_heads, dv):
+    def build(rng):
+        b, h, t, d = q_shape
+        shapes = {"q": q_shape, "k": (b, kv_heads, t, d),
+                  "v": (b, kv_heads, t, dv)}
+        out = fluid.layers.fused_attention(
+            *(_data(n, s) for n, s in shapes.items()), causal=True)
+        return {n: _normal(rng, *s) for n, s in shapes.items()}, [out]
+    return build
 
 
-def test_pallas_softmax_cotangent_through_softmax_output():
-    """Gradient must be right when the SOFTMAX output (not just the
-    loss) is consumed downstream — the Jacobian-vector-product path."""
-    from paddle_tpu.ops.pallas import softmax_xent as px
-    import jax
-    import jax.numpy as jnp
-
-    rng = np.random.RandomState(3)
-    logits = rng.randn(6, 9).astype("float32")
-    label = jnp.asarray(rng.randint(0, 9, (6,)))
-
-    def pallas_obj(lg):
-        loss, sm = px.softmax_xent(lg, label, True)
-        return jnp.sum(loss) + jnp.sum(sm ** 2)
-
-    def ref_obj(lg):
-        ls = jax.nn.log_softmax(lg, axis=-1)
-        sm = jnp.exp(ls)
-        loss = -jnp.take_along_axis(ls, label[:, None], axis=-1)
-        return jnp.sum(loss) + jnp.sum(sm ** 2)
-
-    g_p = jax.grad(pallas_obj)(jnp.asarray(logits))
-    g_r = jax.grad(ref_obj)(jnp.asarray(logits))
-    np.testing.assert_allclose(np.asarray(g_p), np.asarray(g_r),
-                               atol=1e-5)
+def _select(rng):
+    t, heads, dim = 128, 2, 8
+    shapes = {"iq": (1, t, heads, dim), "ik": (1, t, dim), "iw": (1, t, heads)}
+    words, share = fluid.layers.select_keys(
+        *(_data(n, s) for n, s in shapes.items()), 16, scale=dim ** -0.5)
+    return {n: _normal(rng, *s) for n, s in shapes.items()}, [words, share]
 
 
-def test_pallas_handles_odd_and_empty_row_counts():
-    from paddle_tpu.ops.pallas import layer_norm as pln
-    from paddle_tpu.ops.pallas import softmax_xent as px
-    import jax.numpy as jnp
-
-    # prime row count must not degenerate or crash (padding path)
-    x = np.random.RandomState(4).randn(13, 20).astype("float32")
-    g = np.ones(20, "float32")
-    b = np.zeros(20, "float32")
-    y = pln.layer_norm(jnp.asarray(x), jnp.asarray(g), jnp.asarray(b),
-                       1e-5, True)
-    mu = x.mean(-1, keepdims=True)
-    want = (x - mu) / np.sqrt(x.var(-1, keepdims=True) + 1e-5)
-    np.testing.assert_allclose(np.asarray(y), want, atol=1e-5)
-    # empty batch returns empty outputs, no ZeroDivisionError
-    loss, sm = px.softmax_xent(jnp.zeros((0, 7)), jnp.zeros((0,),
-                                                            jnp.int32),
-                               True)
-    assert loss.shape == (0, 1) and sm.shape == (0, 7)
-    assert pln.layer_norm(jnp.zeros((0, 5)), jnp.ones(5), jnp.zeros(5),
-                          1e-5, True).shape == (0, 5)
+def _experts(rng):
+    x = _data("x", (256, 128))
+    out, counts, _ = fluid.layers.routed_experts(x, 4, 2, 128, tile=128)
+    return {"x": _normal(rng, 256, 128)}, [out, counts]
 
 
-def test_flag_toggle_recompiles_cached_program():
-    """Toggling FLAGS_pallas_kernels must not reuse the stale compiled
-    function (the flag is part of the executor cache key)."""
-    with fluid.program_guard(fluid.Program(), fluid.Program()):
-        x = fluid.layers.data("x", shape=[4])
-        out = fluid.layers.softmax(x)
+def _embedding(rng):
+    ids = _data("ids", (48, 1), "int64")
+    rows = fluid.layers.embedding(
+        ids, size=[64, 128], param_attr=fluid.ParamAttr(name="table"))
+    loss = fluid.layers.mean(fluid.layers.square(rows))
+    fluid.optimizer.SGD(learning_rate=0.0).minimize(loss)
+    return {"ids": rng.integers(0, 64, (48, 1))}, [loss, "table@GRAD"]
+
+
+def _head(rng):
+    from paddle_tpu.contrib import mixed_precision
+
+    n, d, v = 256, 128, 256
+    x, label = _data("x", (n, d)), _data("label", (n, 1), "int64")
+    # a layer below the head: the chain has the head's dX to make
+    logits = fluid.layers.fc(fluid.layers.fc(x, size=d, act="tanh"), size=v,
+                             name="head")
+    loss = fluid.layers.mean(
+        fluid.layers.softmax_with_cross_entropy(logits, label))
+    mixed_precision.decorate(
+        fluid.optimizer.SGD(learning_rate=0.0)).minimize(loss)
+    return {"x": _normal(rng, n, d),
+            "label": rng.integers(0, v, (n, 1))}, [loss, "head.w_0@GRAD"]
+
+
+def _scan(rng):
+    t, e, n = 24, 128, 16
+    shapes = {"x": (1, t, e), "dl": (1, t, e), "a": (e, n), "b": (1, t, n),
+              "c": (1, t, n), "d": (e,)}
+    v = {name: _data(name, s) for name, s in shapes.items()}
+    y, state = fluid.layers.selective_scan(
+        v["x"], v["dl"], v["a"], v["b"], v["c"], v["d"], chunk=8)
+    feed = {name: _normal(rng, *s) for name, s in shapes.items()}
+    feed["a"] = -np.abs(feed["a"]) - 0.1
+    return feed, [y, state]
+
+
+# rule -> (the module and platform tuple its rule reads, program, the note
+# with the kernel, the note without)
+RULES = {
+    "packed": (attention, "_PACKED_PLATFORMS",
+               _attention((2, 2, 16, 64), 2, 64),
+               "fused_attention:packed", "fused_attention:xla"),
+    "streamed": (attention, "_STREAMED_PLATFORMS",
+                 _attention((1, 2, 128, 64), 1, 128),
+                 "fused_attention:streamed", "fused_attention:xla"),
+    "select_topk": (sparse_select, "_KERNEL_PLATFORMS", _select,
+                    "select_topk_keys:pallas", "select_topk_keys:xla"),
+    "grouped": (moe, "_GROUPED_PLATFORMS", _experts,
+                "moe_expert_ffn:grouped", "moe_expert_ffn:loop"),
+    "segment": (manipulation, "_SEGMENT_PLATFORMS", _embedding,
+                "lookup_table_grad:segment", "lookup_table_grad:xla"),
+    "head_fused": (loss_ops, "_HEAD_PLATFORMS", _head,
+                   "mul_grad:head_fused", "mul_grad:head_by_op"),
+    "chunked": (state_space, "_KERNEL_PLATFORMS", _scan,
+                "selective_scan:chunked", "selective_scan:xla"),
+}
+
+
+@pytest.mark.parametrize("rule", sorted(RULES))
+def test_flag_off_relowers_a_cached_program_without_the_kernel(
+        rule, monkeypatch, request):
+    module, platforms, build, with_kernel, without = RULES[rule]
+    monkeypatch.setattr(module, platforms, ("tpu", "cpu"))
+    # tiles of 128 x 128: the interpreter's grid has more than one step
+    monkeypatch.setattr(head_grad, "_MAX_ROWS", 128)
+    monkeypatch.setattr(head_grad, "_MAX_COLS", 128)
+    pallas.traced.cache_clear()
+    compile_cache.clear()
+    assert flags.flag("pallas_kernels") is True            # the default
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 5
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        feed, fetch = build(np.random.default_rng(0))
+
+    def noted():
+        bodies = compile_cache.stats()["kernel_bodies"]
+        return bodies.get(with_kernel, 0), bodies.get(without, 0)
+    with fluid.scope_guard(fluid.Scope()):
         exe = fluid.Executor(fluid.CPUPlace())
-        xv = np.random.rand(2, 4).astype("float32")
-        exe.run(feed={"x": xv}, fetch_list=[out])
-        n_before = len(exe._cache)
-        fluid.set_flags({"FLAGS_pallas_kernels": True})
-        try:
-            exe.run(feed={"x": xv}, fetch_list=[out])
-        finally:
-            fluid.set_flags({"FLAGS_pallas_kernels": False})
-        assert len(exe._cache) == n_before + 1
-
-
-def test_pallas_layer_norm_forward_backward_parity():
-    from paddle_tpu.ops.pallas import layer_norm as pln
-    import jax
-    import jax.numpy as jnp
-
-    rng = np.random.RandomState(2)
-    x = rng.randn(16, 40).astype("float32")
-    gamma = rng.rand(40).astype("float32") + 0.5
-    beta = rng.randn(40).astype("float32")
-
-    def pallas_fn(x_, g_, b_):
-        return jnp.sum(pln.layer_norm(x_, g_, b_, 1e-5, True) ** 2)
-
-    def ref_fn(x_, g_, b_):
-        mu = jnp.mean(x_, -1, keepdims=True)
-        var = jnp.mean((x_ - mu) ** 2, -1, keepdims=True)
-        y = (x_ - mu) * jax.lax.rsqrt(var + 1e-5) * g_ + b_
-        return jnp.sum(y ** 2)
-
-    args = (jnp.asarray(x), jnp.asarray(gamma), jnp.asarray(beta))
-    v_p, g_p = jax.value_and_grad(pallas_fn, argnums=(0, 1, 2))(*args)
-    v_r, g_r = jax.value_and_grad(ref_fn, argnums=(0, 1, 2))(*args)
-    assert float(v_p) == pytest.approx(float(v_r), rel=1e-5)
-    for a, b in zip(g_p, g_r):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=2e-4)
+        exe.run(startup)
+        before = noted()
+        first = exe.run(main, feed=feed, fetch_list=fetch)
+        taken = noted()
+        assert taken[0] > before[0] and taken[1] == before[1]
+        held = len(exe._cache)
+        exe.run(main, feed=feed, fetch_list=fetch)
+        assert len(exe._cache) == held and noted() == taken    # cached
+        request.getfixturevalue("no_pallas")     # set_flags, until the end
+        second = exe.run(main, feed=feed, fetch_list=fetch)
+        off = noted()
+        assert len(exe._cache) == held + 1                     # lowered again
+        assert off[0] == taken[0] and off[1] > taken[1]
+    # the same step by the other body (learning rate 0 where it trains)
+    for a, b in zip(first, second):
+        a, b = np.asarray(a, "float32"), np.asarray(b, "float32")
+        assert np.abs(a - b).max() <= 2e-2 * max(np.abs(b).max(), 1e-6)
+    pallas.traced.cache_clear()
+    compile_cache.clear()

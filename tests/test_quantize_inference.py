@@ -1,9 +1,9 @@
 """Quantized execution (ISSUE 14): the ``quantize_inference`` program
-pass, the ``dequant_matmul`` kernels, the accuracy-gated
+pass, the ``dequant_matmul`` op's two XLA bodies, the accuracy-gated
 ``tune_quantization`` decision procedure, and the serving wiring.
 
 CPU-testable by design: gate logic and pass semantics run on the XLA
-int8 fallback; the Pallas kernels verify in interpreter mode."""
+int8 body."""
 
 import os
 
@@ -87,7 +87,7 @@ def test_pass_skips_unquantizable_ops():
         [op.type for op in main.global_block().ops]
 
 
-def test_dequant_matmul_xla_fallback_numerics():
+def test_dequant_matmul_numerics():
     from paddle_tpu.ops.quantize import xla_dequant_matmul
 
     rng = np.random.RandomState(1)
@@ -107,43 +107,6 @@ def test_dequant_matmul_xla_fallback_numerics():
     qx = np.clip(np.round(x / sx), -127, 127).astype(np.int64)
     ref = (qx @ qw.astype(np.int64)).astype(np.float64) * sx * sw
     np.testing.assert_allclose(dyn, ref, rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.slow
-def test_pallas_kernel_parity_interpret():
-    """Pallas fused kernels vs the XLA fallback, interpreter mode (the
-    CPU-drivable half of the kernel contract; slow-marked per the
-    ISSUE's budget allowance — the XLA int8 fallback is the tier-1
-    CPU coverage via test_dequant_matmul_xla_fallback_numerics and
-    every pass/serving test)."""
-    from paddle_tpu.ops.pallas import quant_matmul as qm
-
-    import jax.numpy as jnp
-
-    rng = np.random.RandomState(2)
-    x = rng.randn(5, 130).astype(np.float32)     # ragged everything
-    w = (rng.randn(130, 200) * 0.05).astype(np.float32)
-    sw = (np.abs(w).max(axis=0) / 127.0).astype(np.float32)
-    qw = np.clip(np.round(w / sw), -127, 127).astype(np.int8)
-    wo = np.asarray(qm.dequant_matmul(jnp.asarray(x), jnp.asarray(qw),
-                                      jnp.asarray(sw), interpret=True))
-    np.testing.assert_allclose(wo, x @ (qw.astype(np.float32) * sw),
-                               rtol=1e-5, atol=1e-5)
-    dyn = np.asarray(qm.dequant_matmul(jnp.asarray(x), jnp.asarray(qw),
-                                       jnp.asarray(sw), mode="dynamic",
-                                       interpret=True))
-    sx = np.maximum(np.abs(x).max(axis=1, keepdims=True), 1e-12) / 127.0
-    qx = np.clip(np.round(x / sx), -127, 127).astype(np.int64)
-    ref = (qx @ qw.astype(np.int64)).astype(np.float64) * sx * sw
-    np.testing.assert_allclose(dyn, ref, rtol=1e-5, atol=1e-5)
-    # bf16 activations: int8 values are exact in bf16's mantissa? No —
-    # the kernel upcasts to f32 BEFORE the dot, so bf16 x only loses
-    # its own input precision
-    xb = jnp.asarray(x, jnp.bfloat16)
-    wob = np.asarray(qm.dequant_matmul(xb, jnp.asarray(qw),
-                                       jnp.asarray(sw), interpret=True))
-    ref_b = np.asarray(xb, np.float32) @ (qw.astype(np.float32) * sw)
-    np.testing.assert_allclose(wob, ref_b, rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -437,73 +400,6 @@ def test_decide_quantization_pure_policy():
                "step_s": 1.5}], budget=0.02)
     assert d2["chosen"] is None
     assert d2["candidates"][0]["status"] == "rejected_slower"
-
-
-# ---------------------------------------------------------------------------
-# kernel decision table
-# ---------------------------------------------------------------------------
-
-def test_quant_kernel_table_and_choice(tmp_path):
-    from paddle_tpu import flags as _flags
-
-    autotune.reset_quant_kernel_table()
-    # earlier suite tests may have left FLAGS_pallas_kernels PINNED
-    # (set_flags defaults to pin=True); choice semantics under a pin
-    # are asserted explicitly below, so start unpinned
-    entry_pin = _flags.pinned("pallas_kernels")
-    _flags._restore_pins({"pallas_kernels": False})
-    try:
-        table = autotune.AttentionDecisionTable(
-            dirname=str(tmp_path), filename=autotune.QUANT_FILENAME)
-        tok0 = autotune.trace_token()
-        d = autotune.tune_quant_kernel(8, 128, 128, "float32",
-                                       fluid.CPUPlace(), table=table)
-        assert d["knob"] == "quant_kernel" and "pallas" in d
-        key = autotune.quant_shape_key(8, 128, 128, "float32")
-        assert table.lookup("", key) is not None
-        # warm: the second call serves from the table, no measuring
-        d2 = autotune.tune_quant_kernel(8, 128, 128, "float32",
-                                        fluid.CPUPlace(), table=table)
-        assert d2.get("cached") is True and d2["pallas"] == d["pallas"]
-        # the ruling lives in the process table consulted at trace time
-        autotune.quant_kernel_table().record("", key, True)
-        assert autotune.quant_kernel_choice(8, 128, 128,
-                                            "float32") is True
-        # a mutated table re-keys the trace caches
-        assert autotune.trace_token() != tok0
-        # a pinned FLAGS_pallas_kernels beats the table
-        was = _flags.pinned("pallas_kernels")
-        fluid.set_flags({"FLAGS_pallas_kernels": False})
-        try:
-            assert autotune.quant_kernel_choice(8, 128, 128,
-                                                "float32") is None
-        finally:
-            _flags.set_flags({"pallas_kernels": False}, pin=False)
-            _flags._restore_pins({"pallas_kernels": was})
-    finally:
-        autotune.reset_quant_kernel_table()
-        _flags._restore_pins({"pallas_kernels": entry_pin})
-
-
-def test_tuned_config_applies_quant_kernel_rulings():
-    from paddle_tpu import flags as _flags
-
-    autotune.reset_quant_kernel_table()
-    entry_pin = _flags.pinned("pallas_kernels")
-    _flags._restore_pins({"pallas_kernels": False})
-    try:
-        key = autotune.quant_shape_key(16, 256, 256, "bfloat16")
-        cfg = autotune.TunedConfig(decisions=[
-            {"knob": "quant_kernel", "shape": key, "pallas": True},
-            {"knob": "quantization", "chosen": "weight_only"}])
-        outcomes = dict(cfg.apply())
-        assert outcomes["quant_kernel"] == "applied"
-        assert outcomes["quantization"] == "advisory"
-        assert autotune.quant_kernel_choice(16, 256, 256,
-                                            "bfloat16") is True
-    finally:
-        autotune.reset_quant_kernel_table()
-        _flags._restore_pins({"pallas_kernels": entry_pin})
 
 
 # ---------------------------------------------------------------------------

@@ -131,7 +131,7 @@ def test_ring_attention_tp_heads_dropout_mask_parity():
     """The dropout hash must use GLOBAL head indices: a tp-sharded ring
     run reproduces the single-chip mask bit-for-bit."""
     from paddle_tpu.ops.attention import _ring_attention
-    from paddle_tpu.ops.pallas.flash_attention import reference_attention
+    from paddle_tpu.ops.attention_xla import reference_attention
 
     rng = np.random.RandomState(8)
     b, h, t, d = 2, 4, 4, 4

@@ -19,7 +19,7 @@ from op_test import OpTest
 from paddle_tpu import compile_cache
 from paddle_tpu.ops import moe
 from paddle_tpu.ops import sparse_select as ss
-from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops import attention_xla as fa
 from paddle_tpu.ops.pallas import streamed_attention as sa
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -240,9 +240,6 @@ def test_select_op_takes_the_kernel_when_its_rule_says_so(causal,
     """A CPU trace keeps the XLA body and says so; let the CPU platform take
     the kernel (interpreted; the program never does) and the op's words
     and share are the XLA body's."""
-    from paddle_tpu import flags
-
-    monkeypatch.setattr(flags, "_PINNED", flags._PINNED - {"pallas_kernels"})
     x = jnp.asarray(_levels((2, 72, 256), 7, 8))
     bodies, want = _select_body(monkeypatch, x, 16, causal, ("tpu",))
     assert bodies == {"select_topk_keys:xla": 1}
@@ -253,7 +250,7 @@ def test_select_op_takes_the_kernel_when_its_rule_says_so(causal,
     assert got["Share"].shape == (1,) and got["Share"].dtype == jnp.float32
 
 
-@pytest.mark.parametrize("case", ["cell_shape", "mesh", "pinned_flag",
+@pytest.mark.parametrize("case", ["cell_shape", "mesh", "flag_off",
                                   "odd_tk", "odd_rows", "bf16", "cpu",
                                   "row_over_budget"])
 def test_select_kernel_rule_reads_only_what_the_op_observes(case,
@@ -263,25 +260,17 @@ def test_select_kernel_rule_reads_only_what_the_op_observes(case,
     from paddle_tpu import flags
     from paddle_tpu.parallel.mesh import make_mesh
 
-    monkeypatch.setattr(flags, "_PINNED", flags._PINNED - {"pallas_kernels"})
     tpu = types.SimpleNamespace(platform="tpu", mesh=None)
     cell = (1, 8192, 8192)
     if case == "cell_shape":
         assert ss._kernel_applicable(tpu, cell, jnp.float32)
-        assert not flags.pinned("pallas_kernels")
     elif case == "mesh":
         meshed = types.SimpleNamespace(platform="tpu",
                                        mesh=make_mesh((2, 4), ("dp", "tp")))
         assert not ss._kernel_applicable(meshed, cell, jnp.float32)
-    elif case == "pinned_flag":
-        prev = flags.flag("pallas_kernels")
-        fluid.set_flags({"FLAGS_pallas_kernels": False})      # pins
-        try:
-            assert not ss._kernel_applicable(tpu, cell, jnp.float32)
-            fluid.set_flags({"FLAGS_pallas_kernels": True})
-            assert ss._kernel_applicable(tpu, cell, jnp.float32)
-        finally:
-            fluid.set_flags({"FLAGS_pallas_kernels": prev})
+    elif case == "flag_off":
+        monkeypatch.setitem(flags._FLAGS, "pallas_kernels", False)
+        assert not ss._kernel_applicable(tpu, cell, jnp.float32)
     elif case == "odd_tk":
         assert not ss._kernel_applicable(tpu, (1, 8192, 8200), jnp.float32)
         assert not ss._kernel_applicable(tpu, (1, 40, 40), jnp.float32)
@@ -821,10 +810,8 @@ _GROUPED_CASES = {
 def _grouped_ctx(monkeypatch, platforms=("tpu", "cpu"), **kw):
     """A trace context as the CPU executor makes it, the CPU let into the
     grouped kernels' platforms (interpreted; the program never is)."""
-    from paddle_tpu import flags
     from paddle_tpu.registry import ComputeContext
 
-    monkeypatch.setattr(flags, "_PINNED", flags._PINNED - {"pallas_kernels"})
     monkeypatch.setattr(moe, "_GROUPED_PLATFORMS", platforms)
     return ComputeContext(key=jax.random.key(0), platform="cpu", **kw)
 
@@ -899,7 +886,7 @@ def test_grouped_kernels_against_the_loop(case, monkeypatch):
         assert live == 0 and not np.asarray(got["Out"], "float32").any()
 
 
-@pytest.mark.parametrize("case", ["taken", "cpu", "mesh", "pinned_flag",
+@pytest.mark.parametrize("case", ["taken", "cpu", "mesh", "flag_off",
                                   "odd_width", "odd_tile", "float16",
                                   "mixed_dtypes", "over_budget"])
 def test_grouped_rule_reads_only_what_the_op_observes(case, monkeypatch):
@@ -911,7 +898,6 @@ def test_grouped_rule_reads_only_what_the_op_observes(case, monkeypatch):
     from paddle_tpu.ops.pallas import grouped_experts as ge
     from paddle_tpu.parallel.mesh import make_mesh
 
-    monkeypatch.setattr(flags, "_PINNED", flags._PINNED - {"pallas_kernels"})
     tpu = types.SimpleNamespace(platform="tpu", mesh=None)
     tile, dtype = 640, jnp.bfloat16
 
@@ -938,15 +924,9 @@ def test_grouped_rule_reads_only_what_the_op_observes(case, monkeypatch):
         meshed = types.SimpleNamespace(platform="tpu",
                                        mesh=make_mesh((2, 4), ("dp", "tp")))
         assert body(meshed, *spec()) == "loop"
-    elif case == "pinned_flag":
-        prev = flags.flag("pallas_kernels")
-        fluid.set_flags({"FLAGS_pallas_kernels": False})      # pins
-        try:
-            assert body(tpu, *spec()) == "loop"
-            fluid.set_flags({"FLAGS_pallas_kernels": True})
-            assert body(tpu, *spec()) == "grouped"
-        finally:
-            fluid.set_flags({"FLAGS_pallas_kernels": prev})
+    elif case == "flag_off":
+        monkeypatch.setitem(flags._FLAGS, "pallas_kernels", False)
+        assert body(tpu, *spec()) == "loop"
     elif case == "odd_width":
         assert body(tpu, *spec(d=2000)) == "loop"
         assert body(tpu, *spec(f=700)) == "loop"

@@ -23,10 +23,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 def _fmt_value(d):
     """The chosen value column: each knob renders its own shape."""
-    knob = d.get("knob")
-    if knob == "attention_kernel":
-        return "%s @ %s" % ("pallas" if d.get("pallas") else "xla",
-                            d.get("shape", "?"))
     v = d.get("chosen")
     if isinstance(v, list):
         return "{%s}" % ",".join(str(x) for x in v)
@@ -52,14 +48,6 @@ def _fmt_evidence(d):
             parts.append("ceiling %.1f MiB"
                          % (d["hbm_limit_bytes"] / 1048576.0))
         return ", ".join(parts)
-    if knob == "attention_kernel":
-        if d.get("cached"):
-            return "decision table (warm, no probes)"
-        if d.get("xla_step_s") is not None:
-            return "A/B xla %.4fs vs pallas %.4fs (speedup %s, min %s)" % (
-                d.get("xla_step_s", 0.0), d.get("pallas_step_s", 0.0),
-                d.get("speedup"), d.get("min_speedup"))
-        return d.get("evidence", "")
     if knob == "bucket_bounds":
         return "fill %.1f%% vs pad-to-max %.1f%% (%d multiples-of-%d " \
             "considered)" % (100 * d.get("fill", 0.0),

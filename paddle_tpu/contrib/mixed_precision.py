@@ -72,11 +72,17 @@ class AutoMixedPrecisionLists:
     # ``exp(delta A)`` and a bf16 step size compounds.  ``causal_conv1d`` is
     # on no list, like layer_norm.  Apart from BLACK for the reason above.
     BLACK_STATE_SPACE = {"selective_scan"}
-    # white ops that round their own operands: the grouped expert products
-    # take X and the expert matrices in bf16 but keep the routing weights,
-    # the incoming gradient and every sum in float32, which a cast of all
-    # their inputs would lose (ops/moe.py ``_ffn_args``)
-    SELF_CAST = {"moe_expert_ffn"}
+    # ops that cast their own operands, whatever their colour: the grouped
+    # expert products (white) take X and the expert matrices in bf16 but
+    # keep the routing weights, the incoming gradient and every sum in
+    # float32, which a cast of all their inputs would lose (ops/moe.py
+    # ``_ffn_args``).  ``gated_delta_rule`` is float32 by its own text
+    # (ops/gated_delta_rule.py: its decays compound over the sequence and
+    # its chunk algebra inverts a matrix) and on no other list: it takes
+    # what the products and convolutions left (bf16) and widens it INSIDE,
+    # its gradient op behind a barrier, so that a step keeps the bf16
+    # operands between the two and not float32 copies of them.
+    SELF_CAST = {"moe_expert_ffn", "gated_delta_rule"}
 
     def __init__(self, custom_white_list=None, custom_black_list=None):
         self.white_list = (set(self.WHITE) | set(custom_white_list or ())) \

@@ -22,6 +22,7 @@ __all__ = [
     "fused_attention",
     "selective_scan",
     "causal_conv1d",
+    "gated_delta_rule",
     "paged_attention",
     "rms_norm",
     "rotary_embedding",
@@ -358,6 +359,48 @@ def selective_scan(x, delta, a, b, c, d, delta_bias=None, chunk=64,
                               "Starts": [starts]},
                      attrs={"chunk": int(chunk)})
     return y, state
+
+
+def gated_delta_rule(q, k, v, g, beta, a_log, dt_bias, out_gate, scale,
+                     chunk=64, epsilon=1e-6, out_norm_attr=None, name=None):
+    """A delta-attention mixer's recurrence with what the mixer does around
+    it, in float32, a head at a time: the gated delta rule with a decay for
+    every key channel, ``S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1}
+    + beta_t k_t v_t^T`` from ``S_0 = 0``, ``o_t = S_t^T q_t``.  ``q``, ``k``
+    and the gate's pre-activation ``g`` are ``[B, T, H, Dk]``, ``v`` and the
+    output gate's pre-activation ``out_gate`` ``[B, T, H, Dv]``, ``beta``
+    ``[B, T, H]``, ``a_log`` ``[H]``, ``dt_bias`` ``[H, Dk]`` — variables,
+    whoever made them.  The op L2-normalises ``q`` and ``k`` a head,
+    multiplies ``q`` by ``scale``, makes the log-decays ``-exp(a_log) *
+    softplus(g + dt_bias)`` and returns ``rms_norm(o; gain, epsilon) *
+    sigmoid(out_gate)`` a head, the gain ``[Dv]`` a parameter of
+    ``out_norm_attr`` (initialised to 1).  Returns ``(out [B, T, H, Dv],
+    state [B, H, Dk, Dv])``: ``state`` is ``S_T`` and carries no gradient.
+    Chunked over time, ``chunk`` steps a chunk (16 times a power of two),
+    matrix products inside; the backward makes a group of chunks again from
+    the state the forward kept at its start (``ops/gated_delta_rule.py``).
+    Under mixed precision the op takes its operands as they come and is
+    float32 inside, and so is its gradient."""
+    from ..initializer import ConstantInitializer
+
+    helper = LayerHelper("gated_delta_rule", param_attr=out_norm_attr,
+                         name=name)
+    out, state, starts = (helper.create_variable_for_type_inference(
+        dtype="float32") for _ in range(3))
+    state.stop_gradient = starts.stop_gradient = True
+    gain = helper.create_parameter(
+        attr=helper.param_attr, shape=[v.shape[-1]], dtype="float32",
+        default_initializer=ConstantInitializer(1.0))
+    helper.append_op(type="gated_delta_rule",
+                     inputs={"Q": [q], "K": [k], "V": [v], "G": [g],
+                             "Beta": [beta], "ALog": [a_log],
+                             "DtBias": [dt_bias], "OutGate": [out_gate],
+                             "OutNorm": [gain]},
+                     outputs={"Out": [out], "State": [state],
+                              "Starts": [starts]},
+                     attrs={"chunk": int(chunk), "scale": float(scale),
+                            "epsilon": float(epsilon)})
+    return out, state
 
 
 def causal_conv1d(x, width, act=None, param_attr=None, bias_attr=None,

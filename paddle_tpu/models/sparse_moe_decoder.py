@@ -1,7 +1,9 @@
 """Decoder-only language models parameterised by their sizes and built
 from ``layers`` functions into a Fluid ``Program``: one family, three kinds
-of block.  Two are here, pre-norm blocks with routed SiLU-gated experts;
-the third, the sandwich-norm block of a looped dense decoder, is in
+of block.  Two are here, pre-norm blocks with routed SiLU-gated experts —
+and a third decoder whose MIXER differs by layer (delta attention or latent
+attention) over the same two FFN halves; the third kind of block, the
+sandwich-norm block of a looped dense decoder, is in
 :mod:`.looped_decoder`.  What the kinds share exists once, here: the
 bias-free projection (``_proj``), the SiLU-gated products of a dense FFN
 (``_gated_ffn``: the latent kind's leading dense layer and every block of
@@ -53,6 +55,27 @@ sizes are a ``LatentSizes``):
    ``tok[t+2]``; the step minimises ``L_main + mtp_weight * L_mtp``, so the
    shared tables' gradients are the two uses' sum.
 
+**Delta-attention / latent-attention blocks**
+(``linear_latent_decoder_lm``; the sizes are a ``DeltaSizes`` and a
+``LatentSizes``), one mixer a layer from a list:
+
+1. ``"kda"`` (``_delta_attention``): ``h = rms_norm(x)``; ``q~, k~, v =
+   silu(causal_conv1d(h Wq | h Wk | h Wv))`` (depthwise, no bias), heads of
+   ``head_dim``; the gate's pre-activation ``(h Wfa) Wfb``, ``beta =
+   sigmoid(h Wb)`` a head, the output gate's ``(h Wga) Wgb``.
+   ``layers.gated_delta_rule`` does the rest in float32: ``q, k`` L2-normed
+   a head, ``q`` times ``head_dim^-0.5``, the log-decay ``g = -exp(A_log) *
+   softplus(. + dt_bias)`` for every key channel, the rule ``S_t = (I -
+   beta k k^T) Diag(exp g) S_{t-1} + beta k v^T``, ``o = S^T q``, and
+   ``rms_norm(o) * sigmoid(output gate)`` a head; ``x += (.) Wo``.
+2. ``"mla"``: ``_latent_attention`` with ``q_rank`` None — the queries ONE
+   product ``h Wq`` — and ``rope_theta`` None — no rotation of either side,
+   the shared key part joined as it is (NoPE: position comes from the delta
+   layers alone).
+3. The first ``n_dense`` blocks' FFN half is ``_dense_half``, the others'
+   ``_expert_half`` with the latent kind's router (sigmoid, a frozen bias, a
+   scale, a shared expert).  No module; one loss.
+
 After the last block a final RMSNorm, an untied head over ``vocab_size``
 rows and the mean next-token cross entropy in float32.
 """
@@ -60,11 +83,11 @@ rows and the mean next-token cross entropy in float32.
 import collections
 
 from .. import layers
-from ..initializer import NormalInitializer
+from ..initializer import ConstantInitializer, NormalInitializer
 from ..param_attr import ParamAttr
 
 __all__ = ["decoder_block", "decoder_lm", "LatentSizes", "latent_block",
-           "latent_decoder_lm"]
+           "latent_decoder_lm", "DeltaSizes", "linear_latent_decoder_lm"]
 
 _INIT_STD = 0.02
 # the fields of ``decoder_lm``'s step counters, as the executor names them in
@@ -73,10 +96,17 @@ STEP_STATS = ("moe_pairs_routed", "moe_pairs_computed",
               "moe_max_expert_tokens", "selected_key_share")
 # ... and of ``latent_decoder_lm``'s
 LATENT_STEP_STATS = STEP_STATS[:3] + ("mtp_loss",)
+# ... and of ``linear_latent_decoder_lm``'s
+LINEAR_STEP_STATS = STEP_STATS[:3] + ("delta_state_rms", "decay_mean",
+                                      "beta_mean")
 
 # the sizes of a latent-attention block's attention half
 LatentSizes = collections.namedtuple(
     "LatentSizes", "n_head q_rank kv_rank nope_dim rope_dim v_dim")
+# ... and of a delta-attention block's: heads of ``head_dim`` keys and
+# values, the short convolution's taps, the two gates' rank, the rule's chunk
+DeltaSizes = collections.namedtuple(
+    "DeltaSizes", "n_head head_dim conv_width gate_rank chunk")
 
 
 def _attr(name, trainable=True):
@@ -257,7 +287,10 @@ def _dense_half(x, prefix, width, rms_eps):
 
 
 def _latent_attention(x, prefix, sizes, rope_theta, rms_eps):
-    """``x += latent attention(rms_norm(x))``: see the module's text."""
+    """``x += latent attention(rms_norm(x))``: see the module's text.
+    ``sizes.q_rank`` None: the queries are ONE product ``h Wq``
+    (``attn.q``); ``rope_theta`` None: no rotation of either side, the
+    shared key part joined as it is (NoPE)."""
     n, nope, rope, dv = (sizes.n_head, sizes.nope_dim, sizes.rope_dim,
                          sizes.v_dim)
 
@@ -265,15 +298,21 @@ def _latent_attention(x, prefix, sizes, rope_theta, rms_eps):
         return layers.rms_norm(v, rms_eps, ParamAttr(name=prefix + name))
 
     def rotate(v):
+        if rope_theta is None:
+            return v
         return layers.rotary_embedding(v, theta=rope_theta, interleaved=True)
 
     def to_bhtd(t):
         return layers.transpose(t, perm=[0, 2, 1, 3])
     h = norm(x, "ln1.g")
-    cq = norm(_proj(h, sizes.q_rank, prefix + "attn.q_a"), "attn.q_a_g")
-    q_nope, q_rope = layers.split(layers.reshape(
-        _proj(cq, n * (nope + rope), prefix + "attn.q_b"),
-        shape=[0, 0, n, nope + rope]), [nope, rope], dim=-1)
+    if sizes.q_rank is None:
+        q = _proj(h, n * (nope + rope), prefix + "attn.q")
+    else:
+        cq = norm(_proj(h, sizes.q_rank, prefix + "attn.q_a"), "attn.q_a_g")
+        q = _proj(cq, n * (nope + rope), prefix + "attn.q_b")
+    q = layers.reshape(q, shape=[0, 0, n, nope + rope])
+    if rope_theta is not None:
+        q_nope, q_rope = layers.split(q, [nope, rope], dim=-1)
     ckv, kr = layers.split(_proj(h, sizes.kv_rank + rope,
                                  prefix + "attn.kv_a"),
                            [sizes.kv_rank, rope], dim=-1)
@@ -281,16 +320,89 @@ def _latent_attention(x, prefix, sizes, rope_theta, rms_eps):
         _proj(norm(ckv, "attn.kv_a_g"), n * (nope + dv),
               prefix + "attn.kv_b"),
         shape=[0, 0, n, nope + dv]), [nope, dv], dim=-1)
-    # ONE rotary key for all the heads, joined onto each head's own part
+    # ONE shared key part for all the heads, joined onto each head's own
     kr = layers.expand(rotate(layers.reshape(kr, shape=[0, 0, 1, rope])),
                        [1, 1, n, 1])
-    q = layers.concat([q_nope, rotate(q_rope)], axis=3)
+    if rope_theta is not None:
+        q = layers.concat([q_nope, rotate(q_rope)], axis=3)
     k = layers.concat([k_nope, kr], axis=3)
     ctx = layers.fused_attention(to_bhtd(q), to_bhtd(k), to_bhtd(v),
                                  causal=True, scale=(nope + rope) ** -0.5)
     ctx = layers.reshape(to_bhtd(ctx), shape=[0, 0, n * dv])
     return layers.elementwise_add(x, _proj(ctx, x.shape[-1],
                                            prefix + "attn.o"))
+
+
+def _delta_attention(x, prefix, sizes, rms_eps):
+    """``x += delta attention(rms_norm(x))``: see the module's text.
+    Returns ``(x, state, g, beta)``: the layer's final state [B, H, Dk, Dv],
+    the gate's pre-activation [B, T, H, Dk] with the two parameters that
+    make the decays of it (``(g, a_log, dt_bias)``), and the rule's step
+    sizes [B, T, H]."""
+    n, dh = sizes.n_head, sizes.head_dim
+    h = layers.rms_norm(x, rms_eps, ParamAttr(name=prefix + "ln1.g"))
+
+    def heads(v, width=dh):
+        return layers.reshape(v, shape=[0, 0, n, width])
+
+    def mixed(name):
+        return heads(layers.causal_conv1d(
+            _proj(h, n * dh, prefix + "kda." + name), sizes.conv_width,
+            act="silu", param_attr=_attr(prefix + "kda.%s_conv" % name),
+            bias_attr=False))
+
+    def low_rank(name):
+        return heads(_proj(
+            _proj(h, sizes.gate_rank, prefix + "kda.%s_a" % name), n * dh,
+            prefix + "kda.%s_b" % name))
+
+    def vector(name, shape):
+        return layers.create_parameter(shape, "float32", attr=ParamAttr(
+            name=prefix + "kda." + name,
+            initializer=ConstantInitializer(0.0)))
+    # the rule takes its operands as the products and convolutions left
+    # them and makes the norms, the scale, the decays -exp(A_log) softplus(.
+    # + dt_bias) and the gated head-wise norm of its result in float32
+    # itself; beta goes to float32 before its sigmoid
+    gate = (low_rank("f"), vector("A_log", [n]), vector("dt_bias", [n, dh]))
+    beta = layers.sigmoid(layers.cast(_proj(h, n, prefix + "kda.b"),
+                                      "float32"))
+    o, state = layers.gated_delta_rule(
+        mixed("q"), mixed("k"), mixed("v"), gate[0], beta, gate[1], gate[2],
+        low_rank("g"), dh ** -0.5, chunk=sizes.chunk, epsilon=rms_eps,
+        out_norm_attr=ParamAttr(name=prefix + "kda.o_g"))
+    o = layers.reshape(o, shape=[0, 0, n * dh])
+    return (layers.elementwise_add(x, _proj(o, x.shape[-1],
+                                            prefix + "kda.o")),
+            state, gate, beta)
+
+
+def _decay_mean(gate):
+    """The mean decay ``exp(-exp(A_log) softplus(g + dt_bias))`` of a
+    delta-attention layer's ``(g, a_log, dt_bias)`` as a [1] float32
+    variable, off the gradient's path (the op makes the decays itself and
+    hands none out)."""
+    g, a_log, dt_bias = gate
+    g = layers.cast(g, "float32")
+    g.stop_gradient = True
+    return _mean(layers.exp(layers.elementwise_mul(
+        layers.softplus(layers.elementwise_add(g, dt_bias)),
+        layers.scale(layers.exp(a_log), scale=-1.0), axis=2)))
+
+
+def _sigmoid_experts(prefix, expert_share, expert_width, experts_per_token,
+                     expert_tile, route_scale, shared_width):
+    """``_expert_half``'s arguments after the prefix for the latent kinds'
+    router — sigmoid scores, a selection-only bias, the scale, a shared
+    expert — as a dict."""
+    return dict(
+        expert_share=expert_share, expert_width=expert_width,
+        experts_per_token=experts_per_token, expert_tile=expert_tile,
+        score_func="sigmoid", weight_scale=route_scale,
+        bias_attr=ParamAttr(name=prefix + "moe.bias"),
+        shared_width=shared_width,
+        shared_attrs=tuple(_attr(prefix + "moe.shared." + m)
+                           for m in ("gate", "up", "down")))
 
 
 def latent_block(x, prefix, sizes, rope_theta, rms_eps, dense_width=None,
@@ -320,14 +432,9 @@ def latent_decoder_lm(tokens, labels, labels2, vocab_size, n_layer, n_dense,
     tokens, and ``L_mtp`` — under ``LATENT_STEP_STATS``' names
     (``Program.step_stats``)."""
     def experts(prefix):
-        return dict(
-            expert_share=expert_share, expert_width=expert_width,
-            experts_per_token=experts_per_token, expert_tile=expert_tile,
-            score_func="sigmoid", weight_scale=route_scale,
-            bias_attr=ParamAttr(name=prefix + "moe.bias"),
-            shared_width=shared_width,
-            shared_attrs=tuple(_attr(prefix + "moe.shared." + m)
-                               for m in ("gate", "up", "down")))
+        return _sigmoid_experts(prefix, expert_share, expert_width,
+                                experts_per_token, expert_tile, route_scale,
+                                shared_width)
 
     def embed(ids):
         return layers.embedding(ids, size=[vocab_size, d_model],
@@ -359,3 +466,57 @@ def latent_decoder_lm(tokens, labels, labels2, vocab_size, n_layer, n_dense,
     return loss, _declare_step_stats(loss, _moe_step_stats(
         stats, lambda: layers.reshape(loss_mtp, shape=[1])),
         LATENT_STEP_STATS)
+
+
+def _mean(v):
+    """The mean of ``v`` as a [1] float32 variable, off the gradient's
+    path."""
+    out = layers.reshape(layers.reduce_mean(v, keep_dim=False), shape=[1])
+    out.stop_gradient = True
+    return out
+
+
+def linear_latent_decoder_lm(tokens, labels, vocab_size, d_model, mixers,
+                             n_dense, delta_sizes, latent_sizes, dense_width,
+                             expert_share, expert_width, experts_per_token,
+                             shared_width, route_scale=1.0, rms_eps=1e-5,
+                             expert_tile=256):
+    """The training graph over ``tokens`` / ``labels`` (the next token) [B,
+    T, 1] int64, every position real: one block a name of ``mixers`` —
+    ``"kda"`` (``_delta_attention`` with ``delta_sizes``) or ``"mla"``
+    (``_latent_attention`` with ``latent_sizes``, no rotation) — over a
+    dense FFN of ``dense_width`` in the first ``n_dense`` blocks and the
+    routed-expert half in the others (the router and the shared expert as
+    ``latent_decoder_lm``'s).  Returns ``(loss, stats, state)``: the mean
+    next-token cross entropy; a [6] float32 variable a caller fetches WITH
+    the loss, under ``LINEAR_STEP_STATS``' names (``Program.step_stats``) —
+    pairs routed to the held experts and pairs computed (summed over the
+    expert layers), the fullest held expert's tokens, and of the FIRST
+    ``"kda"`` block the RMS of its final state, its mean decay and its mean
+    ``beta`` —; and that block's final state [B, H, Dk, Dv]."""
+    x = layers.embedding(tokens, size=[vocab_size, d_model],
+                         param_attr=_attr("tok_emb"))
+    stats, first = [], None
+    for i, mixer in enumerate(mixers):
+        prefix = "l%d." % i
+        if mixer == "kda":
+            x, state, gate, beta = _delta_attention(x, prefix, delta_sizes,
+                                                    rms_eps)
+            first = first or (state, gate, beta)
+        elif mixer == "mla":
+            x = _latent_attention(x, prefix, latent_sizes, None, rms_eps)
+        else:
+            raise ValueError("a mixer is 'kda' or 'mla', got %r" % (mixer,))
+        if i < n_dense:
+            x = _dense_half(x, prefix, dense_width, rms_eps)
+            continue
+        x, st = _expert_half(x, prefix, rms_eps=rms_eps, **_sigmoid_experts(
+            prefix, expert_share, expert_width, experts_per_token,
+            expert_tile, route_scale, shared_width))
+        stats.append(st)
+    loss = _head_loss(x, labels, vocab_size, rms_eps, "ln_f.g")
+    state, gate, beta = first
+    return loss, _declare_step_stats(loss, _moe_step_stats(
+        stats, lambda: layers.concat([
+            layers.sqrt(_mean(layers.square(state))), _decay_mean(gate),
+            _mean(beta)], axis=0)), LINEAR_STEP_STATS), state

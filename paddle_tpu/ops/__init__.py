@@ -16,6 +16,7 @@ from . import (  # noqa: F401
     detection,
     elementwise,
     fused_conv_bn,
+    gated_delta_rule,
     kv_cache,
     loss,
     manipulation,

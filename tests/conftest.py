@@ -113,6 +113,18 @@ _RESTATED = {
         "the same reading of num_hidden_layers as a width; "
         "test_bm_phi4_cell.py::test_configuration_keeps_every_published_"
         "size holds the file to the rest of that test",
+    "test_bm_phi4_cell.py::test_benchmark_json_holds_the_six_cells_and_"
+    "five_configurations":
+        "pins BENCHMARK.json to PR 38's six cells, five configurations and "
+        "wants its three metrics last in per_layer; ISSUE 46 appends "
+        "kimi_linear_48b_a3b.train_doc_4k and its two metrics "
+        "(test_bm_kimi_cell.py::test_benchmark_json_holds_the_seven_cells_"
+        "and_six_configurations says what the pin meant)",
+    "test_bm_contract.py::test_configuration_entry_and_file"
+    "[kimi_linear_48b_a3b]":
+        "the same reading of num_hidden_layers as a width; "
+        "test_bm_kimi_cell.py::test_configuration_keeps_every_published_"
+        "size holds the file to the rest of that test",
 }
 
 

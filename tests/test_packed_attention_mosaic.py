@@ -614,3 +614,45 @@ def test_head_grad_kernel_compiles_for_v5e_at_the_cell_shape(one_chip, n):
     # x^T, w^T, the float32 dX before its rounding, the rows' columns
     assert mem.temp_size_in_bytes <= (n * d + d * v) * 2 + n * d * 4 \
         + 2 * 1024 * 1024
+
+
+def test_gated_delta_rule_compiles_for_v5e_inside_its_memory(one_chip):
+    """``gated_delta_rule``'s XLA body and its gradient op at ONE layer of
+    the delta-attention cell — 4096 steps of 32 heads of 128, the operands
+    bf16 as a mixed-precision step hands them over — for the described v5e:
+    no Pallas call (the op has none), and the temporaries stay what a
+    14.5 GB step has room for: the forward's chunk-local parts for all 64
+    chunks at once (0.55 GB), the backward's a group of 16 chunks at a
+    time (0.9 GB where all at once took 1.9)."""
+    from paddle_tpu.ops import gated_delta_rule as gdr
+
+    b, t, h, d = 1, 4096, 32, 128
+    attrs = {"chunk": 64, "scale": d ** -0.5, "epsilon": 1e-5}
+
+    def arg(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    ins = {"Q": [arg((b, t, h, d))], "K": [arg((b, t, h, d))],
+           "V": [arg((b, t, h, d))], "G": [arg((b, t, h, d))],
+           "Beta": [arg((b, t, h), jnp.float32)],
+           "ALog": [arg((h,), jnp.float32)],
+           "DtBias": [arg((h, d), jnp.float32)],
+           "OutGate": [arg((b, t, h, d))],
+           "OutNorm": [arg((d,), jnp.float32)]}
+    starts = arg((b, 4, h, d, d), jnp.float32)      # 64 chunks in 4 groups
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        fwd = jax.jit(lambda ins: gdr._compute(ins, attrs, None, 0)).lower(
+            ins).compile()
+        bwd = jax.jit(lambda ins, starts, dout: gdr._grad_compute(
+            dict(ins, **{"Out::Starts": [starts], "GRAD::Out": [dout]}),
+            attrs, None, 0)).lower(
+            ins, starts, arg((b, t, h, d), jnp.float32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert "tpu_custom_call" not in fwd.as_text()
+    out = jax.eval_shape(lambda ins: gdr._compute(ins, attrs, None, 0), ins)
+    assert out["Starts"].shape == starts.shape
+    gib = 1024 ** 3
+    assert fwd.memory_analysis().temp_size_in_bytes < 0.75 * gib
+    assert bwd.memory_analysis().temp_size_in_bytes < 1.25 * gib

@@ -594,6 +594,63 @@ def selective_scan_kernels(b, t, e, n, chunk=64):
     return errs
 
 
+def gated_delta_rule_kernels(b, t, h, d, chunk=64):
+    """The delta-attention recurrence at the delta-attention cell's heads —
+    ``[b, t, h, d]`` bf16 operands as a mixed-precision step hands them over
+    — through both ops' compute as a TPU trace lowers it (the kernels of
+    ``ops/pallas/gated_delta_rule.py``: the note says so) against the XLA
+    body on the same chip: ``Out``, ``State``, ``Starts`` and the nine
+    gradients by relative norm (the float32 ones to three bf16 passes'
+    rounding, the bf16 ones to their last bit)."""
+    from paddle_tpu.ops import gated_delta_rule as gdr
+    from paddle_tpu.registry import ComputeContext
+
+    bf = jnp.bfloat16
+    ins = {"Q": normal(1, (b, t, h, d), bf), "K": normal(2, (b, t, h, d), bf),
+           "V": normal(3, (b, t, h, d), bf), "G": normal(4, (b, t, h, d), bf),
+           "Beta": jax.nn.sigmoid(normal(5, (b, t, h), jnp.float32)),
+           "ALog": 0.5 * normal(6, (h,), jnp.float32),
+           "DtBias": 0.5 * normal(7, (h, d), jnp.float32),
+           "OutGate": normal(8, (b, t, h, d), bf),
+           "OutNorm": 1 + 0.1 * normal(9, (d,), jnp.float32)}
+    ins = {slot: [v] for slot, v in ins.items()}
+    dout = normal(10, (b, t, h, d), jnp.float32)
+    attrs = {"chunk": chunk, "scale": d ** -0.5, "epsilon": 1e-5}
+
+    def both(platform):
+        ctx = ComputeContext(key=jax.random.key(0), platform=platform)
+
+        def run(ins, dout):
+            out = gdr._compute(ins, attrs, ctx, 0)
+            return out, gdr._grad_compute(dict(
+                ins, **{"Out::Starts": [out["Starts"]],
+                        "GRAD::Out": [dout]}), attrs, ctx, 0)
+        return run
+    before = kernel_bodies("gated_delta_rule")
+    got, got_grad = mosaic_jit(both("tpu"), ins, dout)(ins, dout)
+    bodies = bodies_since(before, "gated_delta_rule")
+    if set(bodies) != {"gated_delta_rule:chunked",
+                       "gated_delta_rule_grad:chunked"}:    # no "xla"
+        raise AssertionError("the delta rule's ops lowered to %s" % bodies)
+    want, want_grad = jax.jit(both("cpu"))(ins, dout)   # the XLA body
+
+    def gap(a, w, bound, what):
+        a, w = (np.asarray(x, np.float32).ravel() for x in (a, w))
+        err = float(np.linalg.norm(a - w) / np.linalg.norm(w))
+        if not err <= bound:
+            raise AssertionError("gated_delta_rule %s: %g of the XLA body's "
+                                 "norm off, over %g" % (what, err, bound))
+        return err
+    errs = {s: gap(got[s], want[s], 1e-4, s)
+            for s in ("Out", "State", "Starts")}
+    errs.update({s: gap(got_grad[s][0], want_grad[s][0],
+                        2e-3 if want_grad[s][0].dtype == bf else 2e-4, s)
+                 for s in want_grad})
+    errs["bodies"] = bodies
+    log("kernel gated_delta_rule: %s" % errs)
+    return errs
+
+
 def phase_kernels():
     out = {}
 
@@ -681,6 +738,7 @@ def phase_kernels():
     streamed("streamed_attention_window", 20, 10, 4096, 64, None, 128, 512)
     streamed("streamed_attention_pairs", 20, 10, 4096, 64, None, 128)
     out["selective_scan"] = selective_scan_kernels(1, 4096, 5120, 16)
+    out["gated_delta_rule"] = gated_delta_rule_kernels(1, 2048, 8, 128)
     out["grouped_experts"] = grouped_experts_through_the_op(
         "grouped_experts", 128, 1)
     out["grouped_experts_chunks"] = grouped_experts_through_the_op(

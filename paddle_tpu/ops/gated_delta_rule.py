@@ -69,10 +69,19 @@ chunk-local parts, its states, its outputs — and pulled back by its
 behind an ``optimization_barrier``, or XLA merges the recomputation with the
 forward op's own and keeps that op's float32 operands alive in between.
 
-Bodies by the op's own rule: the XLA body everywhere
-(``gated_delta_rule:xla``, ``gated_delta_rule_grad:xla`` in
-``kernel_bodies``).  The chunked form is already MXU work; no Pallas body
-has been shown ahead of it inside a step, so none is here.
+Bodies by the op's own rule (``_kernels``): on a TPU, no mesh, ``Dk`` and
+``Dv`` whole lane tiles, ``T`` whole chunks of at most 128 steps, both ops
+lower to the kernels of ``ops/pallas/gated_delta_rule.py``
+(``gated_delta_rule:chunked``, ``gated_delta_rule_grad:chunked`` in
+``kernel_bodies``; ``kernel_traces["gated_delta_rule"]``), which run the SAME
+chunk algebra with a chunk's local parts and the running state in VMEM: the
+forward 2.2 ms a layer at the delta-attention cell's shape where this body
+takes 10.5, the backward 6.1 for 35.1 (the ops alone, my chip runs, PR 47).
+Everywhere
+else — the CPU, a mesh, ``FLAGS_pallas_kernels=False``, a ragged last chunk
+(this body pads it), narrower heads — the XLA body here
+(``gated_delta_rule:xla``, ``gated_delta_rule_grad:xla``), which is also the
+definition the kernels' tests hold them to.
 """
 
 import jax
@@ -289,12 +298,35 @@ def rule_xla(q, k, v, g, beta, chunk):
             jnp.moveaxis(starts[::_group(starts.shape[0])], 0, 1))
 
 
-def _compute(ins, attrs, ctx, op_index):
-    from ..compile_cache import note_kernel_body
+# the platforms the chunked kernels are the body on
+_KERNEL_PLATFORMS = ("tpu",)
 
-    note_kernel_body("gated_delta_rule", "xla")
+
+def _kernels(ctx, op_type, ins, chunk):
+    """Whether the kernels of ``ops/pallas/gated_delta_rule.py`` are the
+    body; notes which under ``op_type``."""
+    from ..compile_cache import note_kernel_body
+    from .pallas import kernel_allowed, gated_delta_rule as kernels
+
+    chunked = kernel_allowed(ctx, _KERNEL_PLATFORMS) \
+        and getattr(ctx, "mesh", None) is None \
+        and kernels.supported(ins["Q"][0].shape, ins["V"][0].shape, chunk)
+    note_kernel_body(op_type, "chunked" if chunked else "xla")
+    return chunked
+
+
+def _compute(ins, attrs, ctx, op_index):
+    chunk = int(attrs["chunk"])
+    if _kernels(ctx, "gated_delta_rule", ins, chunk):
+        from .pallas import interpret_mode, gated_delta_rule as kernels
+        out, state, starts = kernels.forward(
+            *_inputs(ins), chunk=chunk,
+            group=_group(ins["Q"][0].shape[1] // chunk),
+            scale=attrs["scale"], eps=attrs["epsilon"],
+            interpret=interpret_mode(ctx))
+        return {"Out": out, "State": state, "Starts": starts}
     *ops, gate, gain = _prelude(*_inputs(ins), attrs["scale"])
-    out, state, starts = rule_xla(*ops, int(attrs["chunk"]))
+    out, state, starts = rule_xla(*ops, chunk)
     return {"Out": _finish(out, gate, gain, attrs["epsilon"]),
             "State": state, "Starts": starts}
 
@@ -330,18 +362,21 @@ def rule_grad_xla(ops, gate, gain, eps, starts, dout):
 
 
 def _grad_compute(ins, attrs, ctx, op_index):
-    from ..compile_cache import note_kernel_body
-
     if not ins.get("GRAD::Out") or not ins.get("Out::Starts"):
         raise ValueError("gated_delta_rule_grad needs Out's gradient and "
                          "the forward op's Starts")
-    note_kernel_body("gated_delta_rule_grad", "xla")
     dout, starts = ins["GRAD::Out"][0], ins["Out::Starts"][0]
+    chunk = int(attrs["chunk"])
+    if _kernels(ctx, "gated_delta_rule_grad", ins, chunk):
+        from .pallas import interpret_mode, gated_delta_rule as kernels
+        grads = kernels.backward(
+            *_inputs(ins), dout, chunk=chunk, scale=attrs["scale"],
+            eps=attrs["epsilon"], interpret=interpret_mode(ctx))
+        return {"GRAD::" + slot: [d] for slot, d in zip(_SLOTS, grads)}
     # behind a barrier: XLA would otherwise merge this recomputation with
     # the forward op's own and keep ~0.4 GB of float32 operands and
     # chunk-local parts a layer alive from the forward to here
-    chunk, raw = int(attrs["chunk"]), jax.lax.optimization_barrier(
-        _inputs(ins))
+    raw = jax.lax.optimization_barrier(_inputs(ins))
     (*ops, gate, gain), pull = jax.vjp(
         lambda *raw: _prelude(*raw, attrs["scale"]), *raw)
     *grads, dgain = rule_grad_xla(

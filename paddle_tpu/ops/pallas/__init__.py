@@ -32,6 +32,9 @@ kernel ON.  The rules:
   (PERF.md 6.20): ``ops/manipulation.segment_body``.
 * ``selective_scan`` — the chunked scan and its backward:
   ``ops/state_space``'s rule.
+* ``gated_delta_rule`` — the delta-attention recurrence's chunk algebra and
+  its pull-back with a chunk's local parts and the running state in VMEM
+  (three kernels; PERF.md 6.24): ``ops/gated_delta_rule._kernels``.
 * ``head_grad`` — the one body of SEVERAL ops: where a program's backward
   holds ``softmax_with_cross_entropy_grad`` -> ``elementwise_add_grad`` (the
   bias; optional) -> ``mul_grad`` of the same logits, consecutive, hard

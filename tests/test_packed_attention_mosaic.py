@@ -616,16 +616,28 @@ def test_head_grad_kernel_compiles_for_v5e_at_the_cell_shape(one_chip, n):
         + 2 * 1024 * 1024
 
 
-def test_gated_delta_rule_compiles_for_v5e_inside_its_memory(one_chip):
-    """``gated_delta_rule``'s XLA body and its gradient op at ONE layer of
-    the delta-attention cell — 4096 steps of 32 heads of 128, the operands
-    bf16 as a mixed-precision step hands them over — for the described v5e:
-    no Pallas call (the op has none), and the temporaries stay what a
-    14.5 GB step has room for: the forward's chunk-local parts for all 64
-    chunks at once (0.55 GB), the backward's a group of 16 chunks at a
-    time (0.9 GB where all at once took 1.9)."""
+@pytest.mark.parametrize("body", ["chunked", "xla"])
+def test_gated_delta_rule_compiles_for_v5e_inside_its_memory(
+        one_chip, body, request):
+    """``gated_delta_rule`` and its gradient op at ONE layer of the
+    delta-attention cell — 4096 steps of 32 heads of 128, the operands bf16
+    as a mixed-precision step hands them over — for the described v5e.  By
+    the op's rule on a TPU both lower to the kernels of
+    ``ops/pallas/gated_delta_rule.py`` (one custom call forward, two
+    backward) and a chunk's local parts never reach HBM: what is left is the
+    inputs' relayout to ``[B, T, H * D]`` and, backward, the 128 MiB of
+    states the chunks start on.  With ``FLAGS_pallas_kernels`` off they keep
+    the XLA body and its temporaries: the forward's chunk-local parts for
+    all 64 chunks at once (0.55 GB), the backward's a group of 16 chunks at
+    a time (0.9 GB where all at once took 1.9).  ``Starts`` is the same
+    array either way."""
     from paddle_tpu.ops import gated_delta_rule as gdr
 
+    if body == "xla":
+        request.getfixturevalue("no_pallas")
+
+    class Ctx:
+        platform, mesh = "tpu", None
     b, t, h, d = 1, 4096, 32, 128
     attrs = {"chunk": 64, "scale": d ** -0.5, "epsilon": 1e-5}
 
@@ -641,18 +653,24 @@ def test_gated_delta_rule_compiles_for_v5e_inside_its_memory(one_chip):
     starts = arg((b, 4, h, d, d), jnp.float32)      # 64 chunks in 4 groups
     cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
+    pallas.traced.cache_clear()
     try:
-        fwd = jax.jit(lambda ins: gdr._compute(ins, attrs, None, 0)).lower(
+        fwd = jax.jit(lambda ins: gdr._compute(ins, attrs, Ctx, 0)).lower(
             ins).compile()
         bwd = jax.jit(lambda ins, starts, dout: gdr._grad_compute(
             dict(ins, **{"Out::Starts": [starts], "GRAD::Out": [dout]}),
-            attrs, None, 0)).lower(
+            attrs, Ctx, 0)).lower(
             ins, starts, arg((b, t, h, d), jnp.float32)).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
-    assert "tpu_custom_call" not in fwd.as_text()
-    out = jax.eval_shape(lambda ins: gdr._compute(ins, attrs, None, 0), ins)
+        pallas.traced.cache_clear()
+    calls = [text.count("tpu_custom_call") > 0
+             for text in (fwd.as_text(), bwd.as_text())]
+    assert calls == [body == "chunked"] * 2
+    out = jax.eval_shape(lambda ins: gdr._compute(ins, attrs, Ctx, 0), ins)
     assert out["Starts"].shape == starts.shape
+    assert out["Out"].shape == (b, t, h, d) and out["Out"].dtype == jnp.float32
     gib = 1024 ** 3
-    assert fwd.memory_analysis().temp_size_in_bytes < 0.75 * gib
-    assert bwd.memory_analysis().temp_size_in_bytes < 1.25 * gib
+    limits = {"chunked": (0.2, 0.4), "xla": (0.75, 1.25)}[body]
+    assert fwd.memory_analysis().temp_size_in_bytes < limits[0] * gib
+    assert bwd.memory_analysis().temp_size_in_bytes < limits[1] * gib

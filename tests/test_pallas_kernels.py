@@ -13,8 +13,8 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import compile_cache, flags
-from paddle_tpu.ops import attention, loss as loss_ops, manipulation, moe, \
-    pallas, sparse_select, state_space
+from paddle_tpu.ops import attention, gated_delta_rule, loss as loss_ops, \
+    manipulation, moe, pallas, sparse_select, state_space
 from paddle_tpu.ops.pallas import head_grad
 
 
@@ -89,6 +89,22 @@ def _scan(rng):
     return feed, [y, state]
 
 
+def _delta(rng):
+    t, h, d = 64, 2, 128
+    shapes = {"q": (1, t, h, d), "k": (1, t, h, d), "v": (1, t, h, d),
+              "g": (1, t, h, d), "beta": (1, t, h), "gate": (1, t, h, d)}
+    v = {name: _data(name, s) for name, s in shapes.items()}
+    a_log, dt_bias = (fluid.layers.create_parameter(
+        list(shape), "float32", attr=fluid.ParamAttr(name=n))
+        for n, shape in (("a_log", (h,)), ("dt_bias", (h, d))))
+    out, state = fluid.layers.gated_delta_rule(
+        v["q"], v["k"], v["v"], v["g"], v["beta"], a_log, dt_bias, v["gate"],
+        d ** -0.5, chunk=32)
+    feed = {name: _normal(rng, *s) for name, s in shapes.items()}
+    feed["beta"] = 1 / (1 + np.exp(-feed["beta"]))
+    return feed, [out, state]
+
+
 # rule -> (the module and platform tuple its rule reads, program, the note
 # with the kernel, the note without)
 RULES = {
@@ -108,6 +124,8 @@ RULES = {
                    "mul_grad:head_fused", "mul_grad:head_by_op"),
     "chunked": (state_space, "_KERNEL_PLATFORMS", _scan,
                 "selective_scan:chunked", "selective_scan:xla"),
+    "chunked_delta": (gated_delta_rule, "_KERNEL_PLATFORMS", _delta,
+                      "gated_delta_rule:chunked", "gated_delta_rule:xla"),
 }
 
 

@@ -46,7 +46,8 @@ from paddle_tpu.contrib import mixed_precision
 from paddle_tpu.models import transformer as tfm
 from paddle_tpu.monitor import program_profile
 from paddle_tpu.ops import loss as loss_ops
-from paddle_tpu.ops import attention_xla, moe, sparse_select, state_space
+from paddle_tpu.ops import attention, attention_xla, moe, sparse_select
+from paddle_tpu.ops import state_space
 from paddle_tpu.ops.activation import rotary_tables
 from paddle_tpu.ops.pallas import packed_attention as pa
 from paddle_tpu.ops.pallas import selective_scan
@@ -486,6 +487,64 @@ def plain_heads_through_the_op(h, t, dk, dv, rope_theta=None):
     return sorted(step)[0] + " " + sorted(grad_step)[0]
 
 
+def latent_projections_through_the_op(h, t, nope, rope, dv, rope_theta):
+    """A latent block's attention over its projections' own outputs — Q [1,
+    t, h * (nope + rope)], the key/value projection's [1, t, h * (nope +
+    dv)], the shared key part [1, t, rope] — through ``fused_attention`` and
+    its gradient op in a program: the trace takes the streamed kernels in
+    place, forward and the fused backward, several heads a grid step and not
+    all ``h``.  Out, dQ, dKV and dKShared against the op's XLA body, which
+    is the composition a model wrote before (split, rotate, broadcast, join,
+    transpose, the plain attention)."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        q, kv, kr = (fluid.layers.data(n, shape=[t, w], dtype="float32")
+                     for n, w in (("q", h * (nope + rope)),
+                                  ("kv", h * (nope + dv)), ("kr", rope)))
+        for x in (q, kv, kr):
+            x.stop_gradient = False
+        o = fluid.layers.fused_attention(
+            q, kv, causal=True, scale=(nope + rope) ** -0.5, n_head=h,
+            v_dim=dv, k_shared=kr, rope_theta=rope_theta)
+        fluid.append_backward(fluid.layers.reduce_sum(
+            fluid.layers.elementwise_mul(o, o)))
+    args = [normal(i, (1, t, w), jnp.float32) * 0.5
+            for i, w in enumerate((h * (nope + rope), h * (nope + dv), rope))]
+    noted = ("fused_attention", "streamed_step", "streamed_grad_step")
+    before = {p: kernel_bodies(p) for p in noted}
+    got = fluid.Executor(fluid.TPUPlace(0)).run(
+        main, feed=dict(zip(("q", "kv", "kr"), map(np.asarray, args))),
+        fetch_list=[o, "q@GRAD", "kv@GRAD", "kr@GRAD"])
+    bodies, step, grad_step = (bodies_since(before[p], p) for p in noted)
+    forward, backward = sa.in_place_step(args[0], args[1], h, dv)
+    if bodies != {"fused_attention:streamed_inplace": 1,
+                  "fused_attention_grad:streamed_fused_inplace": 1} \
+            or step != {"streamed_step:%dx1" % forward: 1} \
+            or grad_step != {"streamed_grad_step:%dx1" % backward: 1} \
+            or not 1 < forward < h:
+        raise AssertionError("latent projections through the op: bodies %s, "
+                             "step %s, backward %s"
+                             % (bodies, step, grad_step))
+    attrs = {"causal": True, "n_head": h, "v_dim": dv,
+             "scale": (nope + rope) ** -0.5}
+    if rope_theta is not None:
+        attrs["rope_theta"] = rope_theta
+
+    def reference(q, kv, kr):
+        return attention._in_place_reference(
+            {"Q": [q], "K": [kv], "KShared": [kr]}, attrs, None, None, True,
+            0.0, attrs["scale"])[0]
+    want = (jax.jit(reference)(*args),) + jax.jit(jax.grad(
+        lambda *a: jnp.sum(reference(*a) ** 2), (0, 1, 2)))(*args)
+    errs = [close(g, w, TOL_KERNEL["matmul"],
+                  "latent projections through the op")
+            for g, w in zip(got, want)]
+    log("latent projections through the op (%d x [%d | %d] / %d, T %d, "
+        "rotary %s): %s, backward %s, errors %s"
+        % (h, nope, rope, dv, t, rope_theta, step, grad_step, errs))
+    return sorted(step)[0] + " " + sorted(grad_step)[0]
+
+
 def normal(seed, shape, dtype):
     return jax.random.normal(jax.random.key(seed), shape,
                              jnp.float32).astype(dtype)
@@ -728,6 +787,13 @@ def phase_kernels():
     streamed("streamed_attention_latent", h, h, 2048, 192, None, 128)
     out["streamed_attention_latent"]["step"] = plain_heads_through_the_op(
         h, 2048, 192, 128)
+    # ... and as a latent block hands it over since PR 48: the projections'
+    # outputs where they lie, the rotation inside the op; and without one
+    out["streamed_attention_in_place"] = {
+        "step": latent_projections_through_the_op(h, 2048, 128, 64, 128,
+                                                  3.2e7),
+        "step_no_rotation": latent_projections_through_the_op(
+            h, 2048, 128, 64, 128, None)}
     # the looped decoder's geometry: 16 plain heads, keys and values both
     # 128 wide, rotary on the whole head, T = 4096
     out["streamed_attention_plain_128"] = {"step": plain_heads_through_the_op(

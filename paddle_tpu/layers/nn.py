@@ -270,10 +270,12 @@ def exit_gate_loss(hiddens, token_losses, beta=0.0, param_attr=None,
     return loss, stats
 
 
-def fused_attention(q, k, v, k_len=None, causal=False, dropout_rate=0.0,
+def fused_attention(q, k, v=None, k_len=None, causal=False, dropout_rate=0.0,
                     is_test=False, scale=None, selected=None, name=None,
-                    window=None):
-    """Flash attention over head-split tensors q/k/v [B, H, T, D].
+                    window=None, n_head=None, v_dim=None, k_shared=None,
+                    rope_theta=None):
+    """Flash attention over head-split tensors q/k/v [B, H, T, D], or —
+    ``n_head`` given — over the projections' own outputs [B, T, H * D].
 
     k and v may carry fewer heads than q (grouped-query attention: H a
     whole multiple of theirs), and v another width than q and k (``[B, H,
@@ -293,21 +295,59 @@ def fused_attention(q, k, v, k_len=None, causal=False, dropout_rate=0.0,
     body — streamed, ring, packed or XLA, each with its gradient — is
     chosen at trace time by the op's own rules over platform, mesh and
     shapes (``ops/attention.py`` says which and why);
-    ``FLAGS_pallas_kernels=False`` keeps every Pallas body off."""
+    ``FLAGS_pallas_kernels=False`` keeps every Pallas body off.
+
+    **The projections' layout** (latent attention; ``n_head`` and ``v_dim``
+    given, ``v`` None): ``q`` is the query projection's output ``[B, T,
+    n_head * (nope + rope)]``, a head's columns ``[q_nope | q_rope]``;
+    ``k`` the key/value projection's output WHOLE, ``[B, T, n_head * (nope
+    + v_dim)]``, a head's columns ``[k_nope | v]`` (one variable read once:
+    its gradient is one array); ``k_shared`` ``[B, T, rope]`` the ONE key
+    part every head reads beside its own (absent: ``rope`` is 0);
+    ``rope_theta`` the base by which the op rotates each head's ``q_rope``
+    and ``k_shared`` by their positions, neighbouring pairs
+    (``rotary_embedding(.., interleaved=True)``), None for no rotation.
+    The result is ``[B, T, n_head * v_dim]``, what the output projection
+    reads: no split, join, broadcast or transpose is asked for between the
+    projections and the op.  On a TPU the streamed kernels address these
+    arrays where they lie (``nope`` and ``v_dim`` whole lane tiles of 128,
+    ``rope`` whole tiles or one half-tile, T whole 128-key blocks, no
+    ``k_len``, no dropout, no mesh); anything else takes the XLA body,
+    which is that composition written out.  No ``selected``, no
+    ``window``."""
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(dtype=q.dtype)
-    inputs = {"Q": [q], "K": [k], "V": [v]}
+    in_place = n_head is not None
+    if in_place and (v is not None or v_dim is None or selected is not None
+                     or window is not None):
+        raise ValueError(
+            "fused_attention over the projections' outputs (n_head given) "
+            "takes k as the key/value projection's output whole with v_dim, "
+            "and no v, selected or window")
+    if not in_place and not (k_shared is None and rope_theta is None
+                             and v_dim is None):
+        raise ValueError("fused_attention: k_shared, rope_theta and v_dim "
+                         "belong to the projections' layout (n_head given)")
+    inputs = {"Q": [q], "K": [k]}
+    if not in_place:
+        inputs["V"] = [v]
     if k_len is not None:
         inputs["KLen"] = [k_len]
     if selected is not None:
         inputs["Selected"] = [selected]
+    if k_shared is not None:
+        inputs["KShared"] = [k_shared]
     attrs = {"causal": causal, "dropout_rate": float(dropout_rate),
              "is_test": is_test}
     if scale is not None:
         attrs["scale"] = float(scale)
+    if in_place:
+        attrs.update(n_head=int(n_head), v_dim=int(v_dim))
+    if rope_theta is not None:
+        attrs["rope_theta"] = float(rope_theta)
     outputs = {"Out": [out]}
     from ..ops.attention import streams_plain_heads
-    marked = selected is None and k.shape[1] == q.shape[1] \
+    marked = not in_place and selected is None and k.shape[1] == q.shape[1] \
         and streams_plain_heads(q.shape, k.shape, v.shape, k_len is not None,
                                 float(dropout_rate))
     if window is not None:
@@ -320,9 +360,11 @@ def fused_attention(q, k, v, k_len=None, causal=False, dropout_rate=0.0,
         marked = True
     if marked:
         attrs["keep_lse"] = True
-    if marked or selected is not None or k.shape[1] != q.shape[1]:
+    if marked or in_place or selected is not None \
+            or k.shape[1] != q.shape[1]:
         # the bodies of grouped-query / selected-key / long plain-head
-        # attention keep the rows' log-sum-exp for their gradient op
+        # attention, and every body over the projections' layout, keep the
+        # rows' log-sum-exp for their gradient op
         lse = helper.create_variable_for_type_inference(dtype="float32")
         lse.stop_gradient = True
         outputs["LSE"] = [lse]

@@ -42,7 +42,11 @@ sizes are a ``LatentSizes``):
    (interleaved pairs) on each head's ``rope`` part of the query and on
    ``kr``, ONE rotary key all heads share; ``k_i = [k_nope_i | rope(kr)]``.
    Causal attention with keys ``nope + rope`` wide over values ``v_dim``
-   wide; ``x += concat_i(o_i) Wo``.
+   wide; ``x += concat_i(o_i) Wo``.  Everything between the three products
+   and ``Wo`` is ONE op, ``layers.fused_attention`` over the products'
+   outputs where they lie (``n_head=``, ``v_dim=``, ``k_shared=``,
+   ``rope_theta=``): the program asks for no split of a head's parts, no
+   join, no broadcast and no transpose.
 2. ``h2 = rms_norm(x)``; the first ``n_dense`` layers: ``x += (silu(h2 Wg)
    * (h2 Wu)) Wd`` of ``dense_width``; the others: ``x +=
    routed_experts(h2)`` with a sigmoid router that selects on ``score +
@@ -297,38 +301,23 @@ def _latent_attention(x, prefix, sizes, rope_theta, rms_eps):
     def norm(v, name):
         return layers.rms_norm(v, rms_eps, ParamAttr(name=prefix + name))
 
-    def rotate(v):
-        if rope_theta is None:
-            return v
-        return layers.rotary_embedding(v, theta=rope_theta, interleaved=True)
-
-    def to_bhtd(t):
-        return layers.transpose(t, perm=[0, 2, 1, 3])
     h = norm(x, "ln1.g")
     if sizes.q_rank is None:
         q = _proj(h, n * (nope + rope), prefix + "attn.q")
     else:
         cq = norm(_proj(h, sizes.q_rank, prefix + "attn.q_a"), "attn.q_a_g")
         q = _proj(cq, n * (nope + rope), prefix + "attn.q_b")
-    q = layers.reshape(q, shape=[0, 0, n, nope + rope])
-    if rope_theta is not None:
-        q_nope, q_rope = layers.split(q, [nope, rope], dim=-1)
     ckv, kr = layers.split(_proj(h, sizes.kv_rank + rope,
                                  prefix + "attn.kv_a"),
                            [sizes.kv_rank, rope], dim=-1)
-    k_nope, v = layers.split(layers.reshape(
-        _proj(norm(ckv, "attn.kv_a_g"), n * (nope + dv),
-              prefix + "attn.kv_b"),
-        shape=[0, 0, n, nope + dv]), [nope, dv], dim=-1)
-    # ONE shared key part for all the heads, joined onto each head's own
-    kr = layers.expand(rotate(layers.reshape(kr, shape=[0, 0, 1, rope])),
-                       [1, 1, n, 1])
-    if rope_theta is not None:
-        q = layers.concat([q_nope, rotate(q_rope)], axis=3)
-    k = layers.concat([k_nope, kr], axis=3)
-    ctx = layers.fused_attention(to_bhtd(q), to_bhtd(k), to_bhtd(v),
-                                 causal=True, scale=(nope + rope) ** -0.5)
-    ctx = layers.reshape(to_bhtd(ctx), shape=[0, 0, n * dv])
+    kv = _proj(norm(ckv, "attn.kv_a_g"), n * (nope + dv),
+               prefix + "attn.kv_b")
+    # the op takes the three products where they lie: each head's [q_nope |
+    # q_rope] of q and [k_nope | v] of kv, the ONE shared key part kr, the
+    # rotation of q_rope and kr inside it
+    ctx = layers.fused_attention(q, kv, causal=True,
+                                 scale=(nope + rope) ** -0.5, n_head=n,
+                                 v_dim=dv, k_shared=kr, rope_theta=rope_theta)
     return layers.elementwise_add(x, _proj(ctx, x.shape[-1],
                                            prefix + "attn.o"))
 

@@ -20,7 +20,9 @@ Each body with its gradient, in the order the op asks:
   block of keys wholly outside the window is neither fetched nor computed.
   The forward hands the gradient op its output and the rows' log-sum-exp
   (the op's ``LSE`` output); the gradient is the kernels' own backward on
-  them.
+  them.  **In place** (``streamed_inplace``): the same kernels over the
+  projections' own layout — the operand form below — where the rule
+  ``_in_place_applicable`` takes it.
 * **ring** — the mesh has a populated ``sp`` axis the sequence dims divide:
   sequence-parallel ring attention (``parallel/ring_attention.py``).  The
   gradient differentiates the body (``jax.vjp``).
@@ -40,12 +42,15 @@ Each body with its gradient, in the order the op asks:
   log-sum-exp also falls back to.  The gradient differentiates the body.
 
 A kernel is chosen by its rule alone (``_streamed_applicable``,
-``_packed_applicable``, through ``ops.pallas.kernel_allowed``);
+``_in_place_applicable``, ``_packed_applicable``, through
+``ops.pallas.kernel_allowed``);
 ``FLAGS_pallas_kernels=False`` is the operator's one switch against all of
 them ("no Pallas").  Which body a trace took is counted in
 ``compile_cache.stats()["kernel_bodies"]`` (``fused_attention:<body>``; the
 streamed body's gradient ``fused_attention_grad:streamed_fused`` — one
-backward kernel — or ``:streamed`` — dQ and dK/dV —, and the heads a grid
+backward kernel — or ``:streamed`` — dQ and dK/dV —, in place
+``fused_attention:streamed_inplace`` and
+``fused_attention_grad:streamed_fused_inplace``; and the heads a grid
 step serves ``streamed_step:KxG`` forward, ``streamed_grad_step:KxG``
 backward).
 
@@ -59,7 +64,28 @@ K and V may carry fewer heads than Q (grouped-query attention): query head
 ``h`` reads K/V head ``h // (H / Hkv)``.  V may be narrower or wider than
 Q and K (``[B, Hkv, Tk, Dv]``; latent attention's 192-wide keys over
 128-wide values): ``Out`` is ``[B, H, Tq, Dv]``, and the bodies are the
-streamed kernel and the XLA one.  ``causal`` with
+streamed kernel and the XLA one.  **The projections' layout** is
+the op's second operand form, told from what it observes — rank-3 ``Q``
+with the attributes ``n_head`` and ``v_dim`` — for latent attention as its
+three projections write it: ``Q`` ``[B, T, H * (nope + rope)]``, a head's
+columns ``[q_nope | q_rope]``; ``K`` the key/value projection's output
+whole, ``[B, T, H * (nope + v_dim)]``, a head's columns ``[k_nope | v]``,
+and no ``V`` (ONE variable read once, so Fluid's backward makes one gradient
+array and no ``sum`` of two); ``KShared`` ``[B, T, rope]``, the one key
+part every head reads; ``rope_theta``, by which the OP rotates ``q_rope``
+and ``KShared`` (interleaved pairs; absent: no rotation).  ``Out`` is ``[B,
+T, H * v_dim]``, what the output projection reads, ``LSE`` ``[B, H, T, 1]``;
+the gradient op returns dQ, dK (keys' and values' columns together) and
+dKShared in the same layouts.  Its XLA body IS the definition: the
+composition a model wrote before — split, rotate, broadcast, join,
+transpose, ``reference_attention`` — and its gradient differentiates that.
+Its kernel body addresses the three arrays where they lie
+(``streamed_attention``'s section of that name): nothing is materialised
+between a projection and the kernel, the rotation of ``q_rope`` runs on the
+query block inside the kernel and that of ``KShared`` (1 MB) by XLA inside
+the op's scope.  The split score's second product saves no MXU pass; the
+form is there for the ~50 ms of copies a step it removes (PERF.md 6.25).
+``causal`` with
 ``Tq == Tk`` is aligned self-attention (query i sees keys <= i); with
 ``Tq < Tk`` the queries are the *suffix* of the valid keys — query i sits
 at global position ``klen - Tq + i`` — which is the single-token /
@@ -74,9 +100,44 @@ from ..registry import (register_op, set_output, in_var,
                         _generic_grad_infer)
 
 
+def _in_place_infer(op, block, q, kv):
+    """The projections' layout (the module docstring): Q ``[B, T, H * (nope
+    + rope)]``, K ``[B, T, H * (nope + v_dim)]`` with no V, KShared ``[B, T,
+    rope]`` or absent (``rope`` 0)."""
+    n, dv = op.attrs.get("n_head"), op.attrs.get("v_dim")
+    shared = in_var(op, block, "KShared")
+    rope = 0 if shared is None else shared.shape[-1]
+    if not n or not dv or in_var(op, block, "V") is not None \
+            or len(kv.shape) != 3 or not op.outputs.get("LSE"):
+        raise ValueError(
+            "fused_attention over [B, T, H * D] operands takes K as the "
+            "key/value projection's output whole, no V, the attributes "
+            "n_head and v_dim and keeps the rows' log-sum-exp: build the op "
+            "with layers.fused_attention(q, kv, n_head=, v_dim=)")
+    nope = kv.shape[2] // n - dv
+    if q.shape[2] % n or kv.shape[2] % n or nope < 1 \
+            or q.shape[2] // n != nope + rope \
+            or tuple(kv.shape[:2]) != tuple(q.shape[:2]) \
+            or (shared is not None
+                and tuple(shared.shape) != tuple(q.shape[:2]) + (rope,)):
+        raise ValueError(
+            "fused_attention: %d heads of [k_nope | v] in K %s with values "
+            "%d wide, [q_nope | q_rope] in Q %s and the shared key part %s "
+            "do not fit together" % (n, kv.shape, dv, q.shape,
+                                     None if shared is None else shared.shape))
+    if in_var(op, block, "Selected") is not None \
+            or op.attrs.get("window") is not None:
+        raise ValueError("fused_attention over [B, T, H * D] operands takes "
+                         "no Selected and no window")
+    set_output(op, block, "Out", tuple(q.shape[:2]) + (n * dv,), q.dtype)
+    set_output(op, block, "LSE", (q.shape[0], n, q.shape[1], 1), "float32")
+
+
 def _fused_attention_infer(op, block):
     q = in_var(op, block, "Q")
     k = in_var(op, block, "K")
+    if len(q.shape) == 3:
+        return _in_place_infer(op, block, q, k)
     v = in_var(op, block, "V")
     if len(q.shape) != 4 or len(k.shape) != 4 or len(v.shape) != 4:
         raise ValueError(
@@ -174,7 +235,7 @@ def _attention_args(ins, attrs, ctx, op_index):
     KLen, the masks' and dropout's attributes, the dropout hash's seed
     (from the FORWARD op's trace index), and the eval-time output scale
     (``downgrade_in_infer``: weights *= (1-p) == output *= (1-p))."""
-    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
+    q, k, v = ins["Q"][0], ins["K"][0], (ins.get("V") or [None])[0]
     k_len = ins.get("KLen", [None])[0]
     causal = attrs.get("causal", False)
     rate = float(attrs.get("dropout_rate", 0.0))
@@ -190,7 +251,133 @@ def _attention_args(ins, attrs, ctx, op_index):
     return q, k, v, k_len, seed, causal, rate, scale, post
 
 
+def _rotated(x, theta, back=False):
+    """``x`` ``[B, T, ..., rope]`` with neighbouring pairs rotated by their
+    position (``rotary_embedding``'s interleaved form: float32 inside,
+    ``x``'s dtype out), or — ``back`` — by the negative angle, which is the
+    rotation's gradient; ``theta`` None: ``x``."""
+    if theta is None:
+        return x
+    from .activation import _rotary_compute
+
+    def turn(x):
+        return _rotary_compute({"X": [x]}, {"theta": float(theta),
+                                            "interleaved": True}, None, 0)["Out"]
+    if not back:
+        return turn(x)
+    import jax
+    return jax.vjp(turn, x)[1](x)[0]
+
+
+def _in_place_parts(ins, attrs):
+    """(Q, K, KShared or None, heads, nope, rope, v_dim, rope_theta) of the
+    op over the projections' layout."""
+    q, kv = ins["Q"][0], ins["K"][0]
+    shared = (ins.get("KShared") or [None])[0]
+    n, dv = int(attrs["n_head"]), int(attrs["v_dim"])
+    nope = kv.shape[2] // n - dv
+    return (q, kv, shared, n, nope, q.shape[2] // n - nope, dv,
+            attrs.get("rope_theta"))
+
+
+def _in_place_reference(ins, attrs, k_len, seed, causal, rate, scale):
+    """The definition of the op over the projections' layout, as a model
+    composed it from Fluid ops before the op took it: split each head's
+    ``[nope | rope]`` and ``[k_nope | v]``, rotate the queries' ``rope``
+    columns and the shared key part, join that onto every head's keys,
+    heads to the front, the XLA body, heads back.  (Out, LSE)."""
+    from . import attention_xla
+
+    q, kv, shared, n, nope, rope, dv, theta = _in_place_parts(ins, attrs)
+    b, t = q.shape[:2]
+    q = q.reshape(b, t, n, nope + rope)
+    kv = kv.reshape(b, t, n, nope + dv)
+    k, v = kv[..., :nope], kv[..., nope:]
+    if shared is not None:
+        q = jnp.concatenate([q[..., :nope], _rotated(q[..., nope:], theta)],
+                            -1)
+        k = jnp.concatenate([k, jnp.broadcast_to(
+            _rotated(shared[:, :, None], theta), (b, t, n, rope))], -1)
+    out, lse = attention_xla.reference_attention(
+        *(x.transpose(0, 2, 1, 3) for x in (q, k, v)), k_len, seed, causal,
+        rate, scale, None, True)
+    return out.transpose(0, 2, 1, 3).reshape(b, t, n * dv), lse
+
+
+def _in_place_applicable(ctx, ins, attrs, has_klen, rate):
+    """The rule of the streamed kernels over the projections' layout: a TPU
+    trace on one device, no ``FLAGS_pallas_kernels=False``, a shared key
+    part, a call ``in_place_supported`` takes, and the fewest heads' resident
+    gradients inside the budget."""
+    from .pallas import kernel_allowed, streamed_attention as sa
+
+    q, kv, shared, n, nope, rope, dv, theta = _in_place_parts(ins, attrs)
+    return kernel_allowed(ctx, _STREAMED_PLATFORMS) \
+        and getattr(ctx, "mesh", None) is None and shared is not None \
+        and sa.in_place_supported(q.shape, kv.shape, rope, n, dv, has_klen,
+                                 rate) \
+        and sa.in_place_step(q, kv, n, dv)[1] is not None
+
+
+def _in_place_compute(ins, attrs, ctx, op_index):
+    from ..compile_cache import note_kernel_body
+
+    _, _, _, k_len, seed, causal, rate, scale, post = _attention_args(
+        ins, attrs, ctx, op_index)
+    if _in_place_applicable(ctx, ins, attrs, k_len is not None, rate):
+        from .pallas import interpret_mode
+        from .pallas import streamed_attention as sa
+
+        q, kv, shared, n, nope, rope, dv, theta = _in_place_parts(ins, attrs)
+        note_kernel_body("fused_attention", "streamed_inplace")
+        note_kernel_body("streamed_step",
+                         "%dx1" % sa.in_place_step(q, kv, n, dv)[0])
+        out, lse = sa.forward_in_place(
+            q, kv, _rotated(shared, theta), n, dv, theta, causal, scale,
+            interpret_mode(ctx))
+    else:
+        note_kernel_body("fused_attention", "xla")
+        out, lse = _in_place_reference(ins, attrs, k_len, seed, causal, rate,
+                                       scale)
+    if post is not None:
+        out = out * jnp.asarray(post, out.dtype)
+    return {"Out": out, "LSE": lse}
+
+
+def _in_place_grad_compute(ins, attrs, ctx, op_index):
+    """The streamed body's backward from the forward's own output and
+    log-sum-exp; the XLA body differentiates itself."""
+    from ..registry import _generic_grad_compute
+
+    _, _, _, k_len, seed, causal, rate, scale, post = _attention_args(
+        ins, attrs, ctx, attrs.get("__fwd_op_index__", op_index))
+    dout, out, lse = ((ins.get(slot) or [None])[0]
+                      for slot in ("GRAD::Out", "Out::Out", "Out::LSE"))
+    if dout is None or out is None or lse is None \
+            or not _in_place_applicable(ctx, ins, attrs, k_len is not None,
+                                        rate):
+        return _generic_grad_compute(ins, attrs, ctx, op_index)
+    from ..compile_cache import note_kernel_body
+    from .pallas import interpret_mode
+    from .pallas import streamed_attention as sa
+
+    q, kv, shared, n, nope, rope, dv, theta = _in_place_parts(ins, attrs)
+    note_kernel_body("fused_attention_grad", "streamed_fused_inplace")
+    note_kernel_body("streamed_grad_step",
+                     "%dx1" % sa.in_place_step(q, kv, n, dv)[1])
+    if post is not None:
+        dout = dout * jnp.asarray(post, dout.dtype)
+    dq, dkv, dshared = sa.backward_in_place(
+        q, kv, _rotated(shared, theta), n, dv, out, lse, dout, theta, causal,
+        scale, interpret_mode(ctx))
+    return {"GRAD::Q": [dq], "GRAD::K": [dkv],
+            "GRAD::KShared": [_rotated(dshared, theta, back=True).astype(
+                shared.dtype)]}
+
+
 def _fused_attention_compute(ins, attrs, ctx, op_index):
+    if ins["Q"][0].ndim == 3:
+        return _in_place_compute(ins, attrs, ctx, op_index)
     q, k, v, k_len, seed, causal, rate, scale, post = _attention_args(
         ins, attrs, ctx, op_index)
     selected = (ins.get("Selected") or [None])[0]
@@ -258,6 +445,8 @@ def _fused_attention_grad_compute(ins, attrs, ctx, op_index):
     streamed bodies run their own backward kernels."""
     from ..registry import _generic_grad_compute
 
+    if ins["Q"][0].ndim == 3:
+        return _in_place_grad_compute(ins, attrs, ctx, op_index)
     fwd_index = attrs.get("__fwd_op_index__", op_index)
     q, k, v, k_len, seed, causal, rate, scale, post = _attention_args(
         ins, attrs, ctx, fwd_index)
@@ -502,7 +691,8 @@ def _ring_attention(mesh, q, k, v, k_len, seed, causal, rate, scale):
 
 
 register_op(
-    "fused_attention", ["Q", "K", "V", "KLen", "Selected"], ["Out", "LSE"],
+    "fused_attention", ["Q", "K", "V", "KLen", "Selected", "KShared"],
+    ["Out", "LSE"],
     infer=_fused_attention_infer, compute=_fused_attention_compute,
     no_grad_inputs=("KLen", "Selected"), stateful_random=True,
 )

@@ -19,7 +19,9 @@ kernel ON.  The rules:
   ahead of the XLA body, PERF.md 6.6).
 * ``streamed_attention`` — grouped heads, selected keys, values narrower than
   the keys, or plain heads the layer marked ``keep_lse``; any length:
-  ``ops/attention._streamed_applicable``.
+  ``ops/attention._streamed_applicable``.  The same kernels over a latent
+  block's projections where they lie (``[B, T, H * D]`` operands, the shared
+  key part its own; PERF.md 6.25): ``ops/attention._in_place_applicable``.
 * ``topk_select`` — ``select_topk_keys`` with a query block's scores held in
   VMEM, one read of the scores where the XLA body makes 46 (PERF.md 6.8):
   ``ops/sparse_select._kernel_applicable``.

@@ -10,13 +10,21 @@ flight: any sequence length the HBM holds fits.
 
 * **Keys wider than values.** Q and K are ``Dk`` wide, V, O and dO ``Dv``
   (latent attention as training computes it: 192-wide keys — 128 from the
-  latent and a 64-wide rotary part the caller has already joined on — over
-  128-wide values); ``Dk == Dv`` is the common case.  The scores are ONE
+  latent and a 64-wide rotary part — over 128-wide values); ``Dk == Dv`` is
+  the common case.  Over ``[B, H, T, D]`` operands the scores are ONE
   contraction over ``Dk``: ``Dv`` is whole lane tiles, ``Dk`` whole
   half-tiles (a 192-wide block is the array's full last axis, which Mosaic
-  lays out in two lane tiles; the MXU's 128-deep passes make the 64 odd
-  columns cost a pass either way, so a second product for the rotary part
-  would save no pass and add a kernel operand).
+  lays out in two lane tiles).  That form wants the rotary part JOINED onto
+  every head's keys and the heads in front of the sequence, which a latent
+  block's three projections do not write: the split, the rotation, the
+  broadcast, the join and four transposes a block, and their gradients, were
+  ~50 ms of a 328 ms step (PERF.md 6.25).  So the kernels have a second way
+  to address their operands — **the projections' own layout**, the section
+  of that name below: Q ``[B, T, H * (nope + rope)]``, the key/value
+  projection's ``[B, T, H * (nope + dv)]`` and the shared key part ``[B, T,
+  rope]`` as three arrays, the score two products summed in float32.  The
+  second product saves no MXU pass (the 128-deep passes make the 64 odd
+  columns cost one either way); it is there for the copies it removes.
 * **One grid step serves several heads.** K/V carry ``H / g`` heads; the
   ``g`` query heads that read one of them are contiguous in ``[B, H, T,
   D]``.  A step serves ``kh`` K/V heads and ``g'`` query heads of each
@@ -424,6 +432,49 @@ def _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal,
         heads(None)
 
 
+def _softmax_pair(h, s, bias, values, m_s, l_s, acc_s, in_dtype):
+    """Head ``h``'s online-softmax state over one more block of keys, whose
+    scores are ``s`` ``[bq, bk]`` float32 and whose values ``values()``
+    hands over (``bias``: ``_each_head``'s)."""
+    def across(x, width):
+        # [bq, 128], every lane of a row equal, as [bq, width]: the same
+        # registers named width / 128 times
+        return jnp.tile(x, (1, width // LANES))
+
+    bk = s.shape[1]
+    if bias is not None:
+        s = s + bias[...]
+    # the block's maximum a row (its lane tiles by vector maxima, then
+    # ONE cross-lane step), held by every lane of the row as m is
+    m = m_s[h]
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    if bias is None:
+        p = jnp.exp(s - across(m_new, bk))
+    else:
+        # a row with no key yet keeps m = -1e30: subtract 0.0 there, so
+        # that its keys' exp(-1e30) is 0.0 and not exp(0)
+        p = jnp.exp(s - across(
+            jnp.where(m_new > _NEG_INF, m_new, 0.0), bk))
+    corr = jnp.exp(m - m_new)
+    # lane j sums the keys j mod 128; the lanes are added up at the end
+    l_s[h] = l_s[h] * corr + functools.reduce(jnp.add, _lane_tiles(p))
+    acc_s[h] = acc_s[h] * across(corr, acc_s.shape[-1]) + _dot(
+        p, values(), ((1,), (0,)), in_dtype)
+    m_s[h] = m_new
+
+
+def _softmax_rows(m_s, l_s, acc_s):
+    """(the step's heads' outputs ``[n, bq, Dv]`` float32, their rows'
+    log-sum-exp ``[n, bq, 1]``) after a row's last block of keys, each made
+    when it is called."""
+    l = jnp.sum(l_s[...], axis=-1, keepdims=True)
+    row = l > 0.0
+    return (lambda: acc_s[...] / jnp.where(row, l, 1.0),
+            lambda: jnp.where(
+                row, m_s[:, :, :1] + jnp.log(jnp.maximum(l, 1e-37)),
+                _POS_BIG))
+
+
 def _fwd_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nk, in_dtype,
                 window=None):
     sel_ref, (q_ref, k_ref, v_ref), \
@@ -436,43 +487,18 @@ def _fwd_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nk, in_dtype,
         l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
         acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
 
-    def across(x, width):
-        # [bq, 128], every lane of a row equal, as [bq, width]: the same
-        # registers named width / 128 times
-        return jnp.tile(x, (1, width // LANES))
-
     def head(h, kv, bias):
-        s = _scores(q_ref[0, h], k_ref[0, kv], scale, in_dtype)
-        if bias is not None:
-            s = s + bias[...]
-        # the block's maximum a row (its lane tiles by vector maxima, then
-        # ONE cross-lane step), held by every lane of the row as m is
-        m = m_s[h]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        if bias is None:
-            p = jnp.exp(s - across(m_new, bk))
-        else:
-            # a row with no key yet keeps m = -1e30: subtract 0.0 there, so
-            # that its keys' exp(-1e30) is 0.0 and not exp(0)
-            p = jnp.exp(s - across(
-                jnp.where(m_new > _NEG_INF, m_new, 0.0), bk))
-        corr = jnp.exp(m - m_new)
-        # lane j sums the keys j mod 128; the lanes are added up at the end
-        l_s[h] = l_s[h] * corr + functools.reduce(jnp.add, _lane_tiles(p))
-        acc_s[h] = acc_s[h] * across(corr, acc_s.shape[-1]) + _dot(
-            p, v_ref[0, kv], ((1,), (0,)), in_dtype)
-        m_s[h] = m_new
+        _softmax_pair(h, _scores(q_ref[0, h], k_ref[0, kv], scale, in_dtype),
+                      bias, lambda: v_ref[0, kv], m_s, l_s, acc_s, in_dtype)
 
     _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal,
                window)
 
     @pl.when(ki == nk - 1)
     def _():
-        l = jnp.sum(l_s[...], axis=-1, keepdims=True)
-        row = l > 0.0
-        o_ref[0] = (acc_s[...] / jnp.where(row, l, 1.0)).astype(o_ref.dtype)
-        lse_ref[0] = jnp.where(
-            row, m_s[:, :, :1] + jnp.log(jnp.maximum(l, 1e-37)), _POS_BIG)
+        out, lse = _softmax_rows(m_s, l_s, acc_s)
+        o_ref[0] = out().astype(o_ref.dtype)
+        lse_ref[0] = lse()
 
 
 def _probs(q, k, lse, bias, scale, in_dtype):
@@ -835,6 +861,470 @@ def _grad(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
     )(*_given(selected, q, k, v, dout, lse, delta))
 
 
+# ---------------------------------------------------------------------------
+# Latent attention over the projections' own layout
+# ---------------------------------------------------------------------------
+#
+# Q ``[B, T, H * (nope + rope)]`` as the query projection writes it, a head's
+# columns ``[q_nope | q_rope]``; KV ``[B, T, H * (nope + dv)]`` as the
+# key/value projection writes it, a head's columns ``[k_nope | v]``; the ONE
+# key part every head reads, ``[B, T, rope]``, an operand of its own.  A grid
+# step's heads are a COLUMN block — ``[1, bq, kh * (nope + rope)]``, ``[1,
+# bk, kh * (nope + dv)]`` — and the shared block is fetched once for all of
+# them, so nothing is split, joined, broadcast or transposed between a
+# projection and the kernel.  ``nope`` and ``dv`` are whole lane tiles, so a
+# head's keys and values start on a tile of the block (a dynamic index in
+# whole tiles: the head loop stays rolled); ``rope`` is whole tiles too, or
+# ONE half-tile: then a head is 1.5 tiles more than its neighbour, heads come
+# in pairs, and the odd head's ``nope`` columns straddle tiles.  That shift —
+# and the rotation of ``q_rope`` by its position, and the scale — is done
+# once a QUERY block, into VMEM scratch the key blocks' loop reads (``qn_s``
+# a head's scaled ``nope`` columns, ``qr_s`` its rotated ``rope`` columns in
+# a whole lane tile, zero outside them), never once a key block.  The score is
+# two products, ``qn k_nope^T + qr kr^T``, summed in float32: the MXU passes
+# of the joined 192-deep product.  A half-tile ``rope`` never leaves its
+# lanes: the shared block and the tables come with their 64 columns TWICE
+# across a tile (``_twice``), an even head's ``qr`` lives in the lower half
+# and an odd head's in the upper, and either picks its own copy in the
+# product — so ``ds kr`` lands in both halves alike, and ``ds^T qr`` lands in
+# the half of the head's parity: the shared part's gradient is a ``[T, 128]``
+# float32 block, RESIDENT across the head blocks as well (all but the batch
+# axis ``arbitrary``), whose halves XLA adds.  The rotation turns neighbours
+# ``(x[2i], x[2i + 1])`` (``rotary_embedding``'s interleaved form): the
+# partner by two lane rolls and a parity select, ``x cos + partner ssin`` with
+# ``ssin`` the sine signed by parity; dQ's rotary columns turn back by ``x
+# cos - partner ssin`` where dQ is written.
+
+_HALF = LANES // 2
+
+
+def in_place_supported(q_shape, kv_shape, rope, n_head, v_dim, has_klen, rate):
+    """Whether the kernels take the projections' layout: whole 128-key
+    slabs, ``nope`` and ``v_dim`` whole lane tiles, ``rope`` whole tiles or
+    one half-tile (then an even number of heads), no dropout and no padding
+    mask."""
+    if len(q_shape) != 3 or len(kv_shape) != 3 or has_klen or rate:
+        return False
+    b, t, w = q_shape
+    if tuple(kv_shape[:2]) != (b, t) or w % n_head or kv_shape[2] % n_head:
+        return False
+    nope = kv_shape[2] // n_head - v_dim
+    if nope < LANES or nope % LANES or v_dim % LANES \
+            or w // n_head != nope + rope:
+        return False
+    if rope % LANES and (rope != _HALF or n_head % 2):
+        return False
+    return rope > 0 and _pick_blocks(t) is not None
+
+
+def _twice(x):
+    """A half-tile's columns twice across a lane tile; whole tiles as they
+    are."""
+    return jnp.concatenate([x, x], -1) if x.shape[-1] == _HALF else x
+
+
+def rotation_tables(t, rope, theta):
+    """``[t, 2 * lanes(rope)]`` float32: the cosines of positions 0..t-1 for
+    neighbouring pairs, then the sines signed by parity (``-sin`` on the
+    even lane of a pair), each ``_twice``."""
+    from ..activation import rotary_tables
+
+    cos, sin = (jnp.repeat(x[:, :rope // 2], 2, -1)
+                for x in rotary_tables(t, rope, theta))
+    sin = sin * jnp.where(jnp.arange(rope) % 2 == 0, -1.0, 1.0)
+    return jnp.concatenate([_twice(cos), _twice(sin)], -1)
+
+
+def _lane(shape):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+
+def _lower_half(x):
+    return _lane(x.shape) < _HALF
+
+
+def _turned(x, rot_ref, sign):
+    """``x`` ``[bq, r]`` float32, every pair of neighbouring lanes rotated
+    by its row's position (``sign`` 1.0) or back (-1.0), a lane tile at a
+    time."""
+    r = x.shape[1]
+
+    def tile(i):
+        lanes = slice(i * LANES, (i + 1) * LANES)
+        y = x[:, lanes]
+        partner = jnp.where((_lane(y.shape) & 1) == 0,
+                            pltpu.roll(y, LANES - 1, 1), pltpu.roll(y, 1, 1))
+        return y * rot_ref[:, lanes] + partner * (
+            sign * rot_ref[:, r + i * LANES:r + (i + 1) * LANES])
+    return jnp.concatenate([tile(i) for i in range(r // LANES)], 1)
+
+
+class _Projections:
+    """A grid step's ``n`` heads where the projections wrote them."""
+
+    def __init__(self, q_ref, kv_ref, kr_ref, rot_ref, qn_s, qr_s, nope,
+                 rope, dv, n, scale, in_dtype):
+        self.q_ref, self.kv_ref, self.kr_ref = q_ref, kv_ref, kr_ref
+        self.rot_ref, self.qn_s, self.qr_s = rot_ref, qn_s, qr_s
+        self.nope, self.rope, self.dv, self.n = nope, rope, dv, n
+        self.scale, self.in_dtype = scale, in_dtype
+
+    def _tile(self, ref, i):
+        return ref[0, :, i * LANES:(i + 1) * LANES].astype(jnp.float32)
+
+    def _query(self, h):
+        """Head ``h``'s (static) ``nope`` columns, the lane tiles that hold
+        its ``rope`` columns, and which lanes of them are its own (None:
+        all), float32."""
+        nope, w = self.nope, self.nope + self.rope
+        if w % LANES == 0:
+            x = self.q_ref[0, :, h * w:(h + 1) * w].astype(jnp.float32)
+            return x[:, :nope], x[:, nope:], None
+        tiles, first = nope // LANES, h // 2 * (2 * w // LANES)
+        if h % 2 == 0:
+            own = self.q_ref[0, :, first * LANES:(first + tiles) * LANES]
+            last = self._tile(self.q_ref, first + tiles)
+            return own.astype(jnp.float32), last, _lower_half(last)
+        # the odd head starts in the upper half of the even head's last tile
+        swapped = [pltpu.roll(self._tile(self.q_ref, first + tiles + j),
+                              _HALF, 1) for j in range(tiles + 1)]
+        own = [jnp.where(_lower_half(a), a, b)
+               for a, b in zip(swapped, swapped[1:])]
+        last = self._tile(self.q_ref, first + 2 * tiles)
+        return (jnp.concatenate(own, 1), last,
+                jnp.logical_not(_lower_half(last)))
+
+    def load_queries(self):
+        """Once a query block: every head's scaled ``nope`` columns and its
+        rotated, scaled ``rope`` columns, each rounded where the Fluid ops
+        this replaces rounded it (the rotation's result, then the scaled
+        operand of the product)."""
+        def scaled(x):
+            return (x * self.scale).astype(self.in_dtype)
+        for h in range(self.n):
+            own, last, mine = self._query(h)
+            if self.rot_ref is not None:
+                last = _turned(last, self.rot_ref, 1.0).astype(
+                    self.in_dtype).astype(jnp.float32)
+            if mine is not None:
+                last = jnp.where(mine, last, 0.0)
+            self.qn_s[h], self.qr_s[h] = scaled(own), scaled(last)
+
+    def columns(self, ref, h, width, start=0):
+        """Head ``h``'s ``width`` columns at ``start`` of its part of a
+        block whose heads are ``ref.shape[2] / n`` wide: whole lane tiles,
+        ``h`` the head loop's own index."""
+        at = h * (ref.shape[2] // self.n) + start
+        if not isinstance(at, int):
+            at = pl.multiple_of(at, LANES)
+        return ref[0, :, pl.ds(at, width)]
+
+    def keys(self, kv):
+        return self.columns(self.kv_ref, kv, self.nope)
+
+    def values(self, kv):
+        return self.columns(self.kv_ref, kv, self.dv, self.nope)
+
+    def scores(self, h, kv):
+        return _dot(self.qn_s[h], self.keys(kv), ((1,), (1,)),
+                    self.in_dtype) + _dot(
+            self.qr_s[h], self.kr_ref[0], ((1,), (1,)), self.in_dtype)
+
+    def store_queries_gradient(self, dq_ref, acc_s, accr_s):
+        """dQ's block from the heads' float32 sums: the ``rope`` columns
+        turned back, the odd heads' columns shifted to where they lie."""
+        def rope_part(h):
+            x = accr_s[h] * self.scale
+            if self.rot_ref is not None:
+                x = _turned(x, self.rot_ref, -1.0)
+            return x
+        nope, w = self.nope, self.nope + self.rope
+        tiles = nope // LANES
+        for h in range(self.n):
+            own = acc_s[h] * self.scale
+            if w % LANES == 0:
+                dq_ref[0, :, h * w:(h + 1) * w] = jnp.concatenate(
+                    [own, rope_part(h)], 1).astype(dq_ref.dtype)
+                continue
+            if h % 2 == 0:
+                even, even_rope = own, rope_part(h)
+                continue
+            # the pair's 2 * tiles + 1 lane tiles: the even head's, its rope
+            # columns beside the odd head's first half-tile, the odd head's
+            # columns a half-tile on, its rope columns in the last upper half
+            swapped = [pltpu.roll(x, _HALF, 1) for x in _lane_tiles(own)]
+            joints = [even_rope] + swapped + [rope_part(h)]
+            first = h // 2 * (2 * w // LANES) * LANES
+            dq_ref[0, :, first:first + 2 * w] = jnp.concatenate(
+                [even] + [jnp.where(_lower_half(a), a, b)
+                          for a, b in zip(joints, joints[1:])],
+                1).astype(dq_ref.dtype)
+
+
+def _in_place(refs, n_in, rotated, **sizes):
+    """(``_Projections`` of a kernel's ``refs`` — Q, KV, the shared key
+    block and, where the queries are ``rotated``, the tables first; the two
+    query scratches last —, its other inputs, its outputs and scratch)."""
+    q_ref, kv_ref, kr_ref = refs[:3]
+    rot_ref = refs[3] if rotated else None
+    first = 4 if rotated else 3
+    return (_Projections(q_ref, kv_ref, kr_ref, rot_ref, *refs[-2:], **sizes),
+            refs[first:n_in], refs[n_in:-2])
+
+
+def _fwd_kernel_in_place(*refs, scale, causal, rotated, n, nope, rope, dv,
+                         bq, bk, nk, in_dtype):
+    src, _, (o_ref, lse_ref, m_s, l_s, acc_s, bias_s) = _in_place(
+        refs, 4 if rotated else 3, rotated, nope=nope, rope=rope, dv=dv,
+        n=n, scale=scale, in_dtype=in_dtype)
+    qi, ki = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _():
+        m_s[...] = jnp.full(m_s.shape, _NEG_INF, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+        src.load_queries()
+
+    def head(h, kv, bias):
+        _softmax_pair(h, src.scores(h, kv), bias, lambda: src.values(kv),
+                      m_s, l_s, acc_s, in_dtype)
+
+    _each_head(head, None, bias_s, qi, ki, n, 1, bq, bk, causal)
+
+    @pl.when(ki == nk - 1)
+    def _():
+        out, lse = _softmax_rows(m_s, l_s, acc_s)
+        out = out()
+        for h in range(n):
+            o_ref[0, :, h * dv:(h + 1) * dv] = out[h].astype(o_ref.dtype)
+        lse_ref[0] = lse()
+
+
+def _grad_kernel_in_place(*refs, scale, causal, rotated, n, nope, rope, dv,
+                          bq, bk, nq, nk, in_dtype):
+    """``_grad_kernel`` over the projections' layout: a head's float32 dK
+    and dV are ONE resident ``[T, nope + dv]`` block (``dkv_s``), written
+    back as the key/value projection's gradient; the shared key part's
+    gradient adds up over ALL heads in its float32 output block, resident
+    across the head blocks too."""
+    src, (do_ref, lse_ref, delta_ref), \
+        (dq_ref, dkv_ref, dkr_ref, acc_s, accr_s, dkv_s, bias_s) = _in_place(
+            refs, 7 if rotated else 6, rotated, nope=nope, rope=rope, dv=dv,
+            n=n, scale=scale, in_dtype=in_dtype)
+    hi, qi, ki = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    first, last = (qi == 0) & (ki == 0), (qi == nq - 1) & (ki == nk - 1)
+    t = nk * bk
+
+    def rows(kv, block):           # head kv's key block of dkv_s
+        return pl.ds(pl.multiple_of(kv * t + block * bk, bk), bk)
+
+    def shared(block):             # a key block of dkr_ref
+        return pl.ds(pl.multiple_of(block * bk, bk), bk)
+
+    def each_block(body):
+        def step(i, carry):
+            body(i)
+            return carry
+        jax.lax.fori_loop(0, nk, step, 0)
+
+    @pl.when(first)
+    def _():
+        def zero(i):
+            for kv in range(n):
+                dkv_s[rows(kv, i)] = jnp.zeros((bk, nope + dv), jnp.float32)
+        each_block(zero)
+
+    @pl.when(first & (hi == 0))
+    def _():
+        def zero(i):
+            dkr_ref[0, shared(i)] = jnp.zeros((bk, dkr_ref.shape[2]),
+                                              jnp.float32)
+        each_block(zero)
+
+    @pl.when(ki == 0)
+    def _():
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+        accr_s[...] = jnp.zeros(accr_s.shape, jnp.float32)
+        src.load_queries()
+
+    def head(h, kv, bias):
+        qn, qr, k, kr = src.qn_s[h], src.qr_s[h], src.keys(kv), src.kr_ref[0]
+        do = src.columns(do_ref, h, dv)
+        s = src.scores(h, kv)
+        if bias is not None:
+            s = s + bias[...]
+        p = jnp.exp(s - lse_ref[0, h])               # empty rows: lse = +BIG
+        dkv_s[rows(kv, ki), nope:] += _dot(p, do, ((0,), (0,)), in_dtype)
+        g = _dot(do, src.values(kv), ((1,), (1,)), in_dtype)
+        ds = (p * (g - delta_ref[0, h])).astype(in_dtype)
+        acc_s[h] += _dot(ds, k, ((1,), (0,)), in_dtype)
+        accr_s[h] += _dot(ds, kr, ((1,), (0,)), in_dtype)
+        dkv_s[rows(kv, ki), :nope] += _dot(ds, qn, ((0,), (0,)), in_dtype)
+        dkr_ref[0, shared(ki)] += _dot(ds, qr, ((0,), (0,)), in_dtype)
+
+    _each_head(head, None, bias_s, qi, ki, n, 1, bq, bk, causal)
+
+    @pl.when(ki == nk - 1)
+    def _():
+        src.store_queries_gradient(dq_ref, acc_s, accr_s)
+
+    @pl.when(last)
+    def _():
+        def write(i):
+            block = shared(i)
+            for kv in range(n):
+                dkv_ref[0, block, kv * (nope + dv):(kv + 1) * (nope + dv)] = \
+                    dkv_s[rows(kv, i)].astype(dkv_ref.dtype)
+        each_block(write)
+
+
+def _in_place_geometry(q, kv, n_head, v_dim):
+    """(B, T, nope, rope, dv, bq, bk, query blocks, key blocks)."""
+    b, t, w = q.shape
+    nope = kv.shape[2] // n_head - v_dim
+    bq = bk = _pick_blocks(t)
+    return b, t, nope, w // n_head - nope, v_dim, bq, bk, t // bq, t // bk
+
+
+def _in_place_bytes(n, bq, bk, t, nope, rope, dv, itemsize, grad):
+    """VMEM bytes of a grid step of ``n`` heads over the projections'
+    layout, forward or (``grad``) the fused backward: the column blocks of
+    Q, KV and O (dO; dQ), the shared key block and the tables, a head's two
+    columns and float32 sums, the queries' scratch, and — backward —
+    RESIDENT, a head's whole float32 dK and dV ``[t, nope + dv]`` with the
+    one-buffered output block they are cast into and the shared part's
+    float32 ``[t, lanes(rope)]``."""
+    r = _lanes(rope)
+    column = bq * LANES * 4
+    shared = 2 * bk * r * itemsize + 2 * bq * 2 * r * 4 + bq * bk * 4
+    blocks = 2 * (bq * (nope + rope) + bk * (nope + dv) + bq * dv) * itemsize
+    queries = bq * (nope + r) * itemsize
+    if not grad:
+        state = 4 * column + bq * dv * 4         # lse's block, m, l; acc
+        return n * (blocks + queries + state) + shared + 8 * bq * bk * 4
+    sums = 4 * column + bq * (nope + r) * 4      # lse, delta; dQ's sums
+    resident = t * (nope + dv) * (4 + itemsize)
+    return n * (blocks + 2 * bq * (nope + rope) * itemsize + queries + sums
+                + resident) + shared + t * r * 4 + 2 * bq * bk * 4
+
+
+def _in_place_heads(n_head, rope, fits):
+    """The most heads a step by ``fits(n)`` (an even number where a head's
+    ``rope`` columns are a half-tile); None where the fewest do not fit."""
+    return max((m for m in range(1, n_head + 1)
+                if n_head % m == 0 and not (rope % LANES and m % 2)
+                and fits(m)), default=None)
+
+
+def _in_place_heads_per_step(q, kv, n_head, v_dim):
+    """Heads a grid step of the forward serves: the most that fit the
+    budget, the fewest there are where none does."""
+    b, t, nope, rope, dv, bq, bk, nq, nk = _in_place_geometry(
+        q, kv, n_head, v_dim)
+    fewest = 2 if rope % LANES else 1
+    return _in_place_heads(n_head, rope, lambda n: n == fewest
+                           or _in_place_bytes(n, bq, bk, t, nope, rope, dv,
+                                              q.dtype.itemsize, False)
+                           <= _VMEM_BUDGET)
+
+
+def _in_place_fused_heads_per_step(q, kv, n_head, v_dim):
+    """The same for the fused backward; None where the fewest heads'
+    resident gradients do not fit."""
+    b, t, nope, rope, dv, bq, bk, nq, nk = _in_place_geometry(
+        q, kv, n_head, v_dim)
+    return _in_place_heads(n_head, rope, lambda n: _in_place_bytes(
+        n, bq, bk, t, nope, rope, dv, q.dtype.itemsize, True) <= _VMEM_BUDGET)
+
+
+def _in_place_specs(n, bq, bk, rope, causal):
+    """Block specs of the grid (batch, block of ``n`` heads, query block,
+    key block) over ``[B, T, heads * width]`` arrays: (a query-row block of
+    the step's heads at ``width`` columns a head, their ``[n, bq, 1]``
+    column, the key rows' block likewise, the shared key block, the
+    tables' block).  Under ``causal`` the key index clamps to the last
+    block the query block needs, so a skipped step fetches nothing."""
+    def key_block(qi, ki):
+        return jnp.minimum(ki, _div(qi * bq + bq - 1, bk)) if causal else ki
+    r = _lanes(rope)
+    return (lambda width: pl.BlockSpec(
+                (1, bq, n * width), lambda bi, hi, qi, ki: (bi, qi, hi)),
+            pl.BlockSpec((1, n, bq, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            lambda width: pl.BlockSpec(
+                (1, bk, n * width),
+                lambda bi, hi, qi, ki: (bi, key_block(qi, ki), hi)),
+            pl.BlockSpec((1, bk, r),
+                         lambda bi, hi, qi, ki: (bi, key_block(qi, ki), 0)),
+            pl.BlockSpec((bq, 2 * r), lambda bi, hi, qi, ki: (qi, 0)))
+
+
+def _forward_in_place(q, kv, kr, rot, *, n_head, v_dim, heads, vmem, causal,
+                      scale, interpret):
+    b, t, nope, rope, dv, bq, bk, nq, nk = _in_place_geometry(
+        q, kv, n_head, v_dim)
+    n, r = heads, _lanes(rope)
+    row, col, keys, shared, tables = _in_place_specs(n, bq, bk, rope, causal)
+    rotated = rot is not None
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel_in_place, scale=scale, causal=causal,
+                          rotated=rotated, n=n, nope=nope, rope=rope, dv=dv,
+                          bq=bq, bk=bk, nk=nk, in_dtype=q.dtype),
+        grid=(b, n_head // n, nq, nk),
+        in_specs=[row(nope + rope), keys(nope + dv), shared]
+        + ([tables] if rotated else []),
+        out_specs=[row(dv), col],
+        out_shape=[jax.ShapeDtypeStruct((b, t, n_head * dv), q.dtype),
+                   jax.ShapeDtypeStruct((b, n_head, t, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, bq, LANES), jnp.float32),
+                        pltpu.VMEM((n, bq, LANES), jnp.float32),
+                        pltpu.VMEM((n, bq, dv), jnp.float32),
+                        pltpu.VMEM((bq, bk), jnp.float32),
+                        pltpu.VMEM((n, bq, nope), q.dtype),
+                        pltpu.VMEM((n, bq, r), q.dtype)],
+        compiler_params=_params(vmem), interpret=interpret,
+    )(*_given(q, kv, kr, rot))
+
+
+def _grad_in_place(q, kv, kr, rot, dout, lse, delta, *, n_head, v_dim, heads,
+                   vmem, causal, scale, interpret):
+    """dQ, dKV and the shared key part's float32 gradient ``[B, T,
+    lanes(rope)]`` by the fused kernel.  dKV's block is the step's heads'
+    whole ``[T, n * (nope + dv)]``, resident while the two inner axes run;
+    the shared part's is resident across the head blocks too, so only the
+    batch axis is ``parallel``."""
+    b, t, nope, rope, dv, bq, bk, nq, nk = _in_place_geometry(
+        q, kv, n_head, v_dim)
+    n, r = heads, _lanes(rope)
+    row, col, keys, shared, tables = _in_place_specs(n, bq, bk, rope, causal)
+    rotated = rot is not None
+    return pl.pallas_call(
+        functools.partial(_grad_kernel_in_place, scale=scale, causal=causal,
+                          rotated=rotated, n=n, nope=nope, rope=rope, dv=dv,
+                          bq=bq, bk=bk, nq=nq, nk=nk, in_dtype=q.dtype),
+        grid=(b, n_head // n, nq, nk),
+        in_specs=[row(nope + rope), keys(nope + dv), shared]
+        + ([tables] if rotated else []) + [row(dv), col, col],
+        out_specs=[row(nope + rope),
+                   pl.BlockSpec((1, t, n * (nope + dv)),
+                                lambda bi, hi, qi, ki: (bi, 0, hi),
+                                pipeline_mode=pl.Buffered(1)),
+                   pl.BlockSpec((1, t, r), lambda bi, hi, qi, ki: (bi, 0, 0),
+                                pipeline_mode=pl.Buffered(1))],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(kv.shape, kv.dtype),
+                   jax.ShapeDtypeStruct((b, t, r), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, bq, nope), jnp.float32),
+                        pltpu.VMEM((n, bq, r), jnp.float32),
+                        pltpu.VMEM((n * t, nope + dv), jnp.float32),
+                        pltpu.VMEM((bq, bk), jnp.float32),
+                        pltpu.VMEM((n, bq, nope), q.dtype),
+                        pltpu.VMEM((n, bq, r), q.dtype)],
+        compiler_params=_params(vmem, "arbitrary", "arbitrary", "arbitrary"),
+        interpret=interpret,
+    )(*_given(q, kv, kr, rot, dout, lse, delta))
+
+
 _run = functools.partial(run_traced, "streamed_attention")
 
 
@@ -878,6 +1368,67 @@ def backward(q, k, v, selected, out, lse, dout, causal=False, scale=None,
     (dq,) = _run(_dq, operands, **statics)
     dk, dv = _run(_dkv, operands, **statics)
     return dq, dk, dv
+
+
+def in_place_step(q, kv, n_head, v_dim):
+    """(heads a grid step of the forward serves over the projections'
+    layout, heads a step of the fused backward or None where the fewest
+    heads' resident gradients do not fit the budget)."""
+    return (_in_place_heads_per_step(q, kv, n_head, v_dim),
+            _in_place_fused_heads_per_step(q, kv, n_head, v_dim))
+
+
+def _in_place_operands(q, kv, k_shared, n_head, v_dim, rope_theta, heads,
+                       causal, scale, interpret):
+    """(the kernels' first four operands — the shared part ``_twice``, the
+    rotation's tables or None —, a signature's statics)."""
+    rot = None if rope_theta is None else rotation_tables(
+        q.shape[1], k_shared.shape[2], float(rope_theta))
+    if scale is None:
+        scale = (q.shape[2] // n_head) ** -0.5
+    return (q, kv, _twice(k_shared), rot), dict(
+        n_head=int(n_head), v_dim=int(v_dim), heads=heads, vmem=_VMEM_BUDGET,
+        causal=bool(causal), scale=float(scale), interpret=bool(interpret))
+
+
+def forward_in_place(q, kv, k_shared, n_head, v_dim, rope_theta=None,
+                     causal=False, scale=None, interpret=False):
+    """q ``[B, T, H * (nope + rope)]``, kv ``[B, T, H * (nope + dv)]`` (a
+    head's columns ``[k_nope | v]``), ``k_shared`` ``[B, T, rope]`` the key
+    part every head reads, already rotated; ``rope_theta`` the base the
+    queries' ``rope`` columns are rotated by, None for none.  Returns the
+    output ``[B, T, H * dv]`` and the rows' log-sum-exp ``[B, H, T, 1]``
+    float32."""
+    operands, statics = _in_place_operands(
+        q, kv, k_shared, n_head, v_dim, rope_theta,
+        _in_place_heads_per_step(q, kv, n_head, v_dim), causal, scale,
+        interpret)
+    out, lse = _run(_forward_in_place, operands, **statics)
+    return out, lse
+
+
+def backward_in_place(q, kv, k_shared, n_head, v_dim, out, lse, dout,
+                      rope_theta=None, causal=False, scale=None,
+                      interpret=False):
+    """(dQ, dKV, the gradient of the ROTATED shared key part ``[B, T,
+    rope]`` in float32, all heads' sum) from ``forward_in_place``'s operands
+    and results."""
+    b, t, _ = q.shape
+    dout = dout.astype(q.dtype)
+    delta = jnp.sum((dout.astype(jnp.float32) * out.astype(jnp.float32))
+                    .reshape(b, t, n_head, v_dim), -1)
+    operands, statics = _in_place_operands(
+        q, kv, k_shared, n_head, v_dim, rope_theta,
+        _in_place_fused_heads_per_step(q, kv, n_head, v_dim), causal, scale,
+        interpret)
+    dq, dkv, dkr = _run(
+        _grad_in_place,
+        operands + (dout, lse, delta.transpose(0, 2, 1)[..., None]),
+        **statics)
+    if k_shared.shape[2] == _HALF:
+        # the even heads' sum and the odd heads'
+        dkr = dkr[..., :_HALF] + dkr[..., _HALF:]
+    return dq, dkv, dkr
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))

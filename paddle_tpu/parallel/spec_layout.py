@@ -65,8 +65,9 @@ _PASSTHROUGH_OPS = {
     "relu", "gelu", "tanh", "sigmoid", "dropout", "scale", "reshape",
     "transpose", "fused_attention", "softmax", "cast",
     # a gated FFN's product of its two column-parallel halves, and what a
-    # latent-attention block does between its up-projections and the
-    # attention: the head's parts split off, rotated, joined again
+    # block may do between its up-projections and the attention: the head's
+    # parts split off, rotated, joined again (a latent-attention block hands
+    # its projections to ``fused_attention`` as they are)
     "swiglu", "split", "concat", "expand", "rotary_embedding",
 }
 

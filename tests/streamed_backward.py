@@ -1,7 +1,9 @@
 """The streamed attention's two backward bodies on the same operands, for
 the decoder cells' tests: the fused kernel (one ``pallas_call``: dQ, dK and
 dV from one ``s``, ``p``, ``g`` and ``ds`` a block pair) against the dQ and
-dK/dV kernels and against ``jax.vjp`` of the XLA body, all interpreted."""
+dK/dV kernels and against ``jax.vjp`` of the XLA body, all interpreted; and a
+latent block's attention as two programs, composed of Fluid ops around the
+4-D op and as the ONE op over the projections' layout."""
 
 import numpy as np
 
@@ -57,3 +59,66 @@ def check_fused_backward(monkeypatch, q, k, v, ct, packed=None, causal=True,
         assert a.shape == b.shape and a.dtype == b.dtype
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
     return fused
+
+
+# ---- a latent block's attention, composed of Fluid ops and as ONE op -------------
+
+def latent_attention_programs(n, t, nope, rope, dv, theta, scale):
+    """Two programs over the same feeds — ``q`` [B, t, n * (nope + rope)],
+    ``kv`` [B, t, n * (nope + dv)], ``kr`` [B, t, rope] as a latent block's
+    three projections write them, ``ct`` the result's cotangent — each with
+    its backward: the block's attention as a model composed it before the op
+    took the projections' layout (reshape / split / rotate / expand / concat
+    / transpose around the 4-D ``fused_attention``), and the op over that
+    layout.  Returns ``[(main, fetches)] * 2``, the fetches ``Out``, ``LSE``,
+    dQ, dKV, dKShared and — the composition's, None the op's — the joined
+    keys' gradient [B, n, t, nope + rope]."""
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+
+    def composed(q, kv, kr):
+        def rotate(v):
+            if theta is None:
+                return v
+            return layers.rotary_embedding(v, theta=theta, interleaved=True)
+
+        def to_bhtd(x):
+            return layers.transpose(x, perm=[0, 2, 1, 3])
+        q = layers.reshape(q, shape=[0, 0, n, nope + rope])
+        if theta is not None:
+            q_nope, q_rope = layers.split(q, [nope, rope], dim=-1)
+            q = layers.concat([q_nope, rotate(q_rope)], axis=3)
+        k_nope, v = layers.split(layers.reshape(
+            kv, shape=[0, 0, n, nope + dv]), [nope, dv], dim=-1)
+        kr = layers.expand(rotate(layers.reshape(kr, shape=[0, 0, 1, rope])),
+                           [1, 1, n, 1])
+        k = to_bhtd(layers.concat([k_nope, kr], axis=3))
+        ctx = layers.fused_attention(to_bhtd(q), k, to_bhtd(v), causal=True,
+                                     scale=scale)
+        return layers.reshape(to_bhtd(ctx), shape=[0, 0, n * dv]), k
+
+    def in_place(q, kv, kr):
+        return layers.fused_attention(
+            q, kv, causal=True, scale=scale, n_head=n, v_dim=dv, k_shared=kr,
+            rope_theta=theta), None
+
+    programs = []
+    for build in (composed, in_place):
+        main = fluid.Program()
+        with fluid.program_guard(main, fluid.Program()), \
+                fluid.unique_name.guard():
+            feeds = [layers.data(name, shape=[t, width], dtype="float32")
+                     for name, width in (("q", n * (nope + rope)),
+                                         ("kv", n * (nope + dv)),
+                                         ("kr", rope), ("ct", n * dv))]
+            for x in feeds[:3]:
+                x.stop_gradient = False
+            out, joined = build(*feeds[:3])
+            fluid.append_backward(layers.reduce_sum(
+                layers.elementwise_mul(out, feeds[3])))
+        op = next(o for o in main.global_block().ops
+                  if o.type == "fused_attention")
+        programs.append((main, [
+            out, op.outputs["LSE"][0], "q@GRAD", "kv@GRAD", "kr@GRAD"]
+            + ([] if joined is None else [joined.name + "@GRAD"])))
+    return programs

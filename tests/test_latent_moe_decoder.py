@@ -436,6 +436,243 @@ def test_streamed_supported_takes_half_tile_keys_over_whole_tile_values():
         8, 512, 512, 128, 2, 128)
 
 
+# ---- the op over the projections' own layout ---------------------------------------
+
+def _projections(b, n, t, nope, rope, dv, seed=0, dtype="float32"):
+    """(q, kv, kr, ct) as a latent block's three products write them and
+    the result's cotangent."""
+    return tuple(jnp.asarray(_rand((b, t, w), seed + i, s)).astype(dtype)
+                 for i, (w, s) in enumerate((
+                     (n * (nope + rope), 0.5), (n * (nope + dv), 0.5),
+                     (rope, 0.5), (n * dv, 1.0))))
+
+
+def _bodies(*names):
+    got = compile_cache.stats()["kernel_bodies"]
+    return tuple(got.get(n, 0) for n in names)
+
+
+# (heads, T, nope, rope, dv, theta, heads a step forward, backward, batch)
+IN_PLACE_CASES = [
+    (2, 256, 128, 64, 128, 1e4, 2, 2, 1),
+    (4, 384, 128, 64, 128, 3.2e7, 4, 2, 1),       # three blocks a row
+    (8, 256, 128, 64, 128, 1e4, 8, 4, 2),         # two rows a batch
+    (8, 256, 128, 64, 128, None, 8, 8, 1),        # no rotation (NoPE)
+    (4, 256, 128, 64, 128, None, 2, 2, 2),
+    # an odd count of heads a step: rope of whole tiles, heads one by one
+    (3, 256, 128, 128, 128, 1e4, 3, 3, 1),
+    (6, 256, 128, 128, 128, None, 3, 1, 1),
+    # two tiles of nope: the odd head's columns shift across three
+    (2, 256, 256, 64, 128, 1e4, 2, 2, 1),
+]
+
+
+@pytest.mark.parametrize("case", IN_PLACE_CASES,
+                         ids=["-".join(map(str, c)) for c in IN_PLACE_CASES])
+def test_op_over_the_projections_layout_against_the_composed_block(
+        case, monkeypatch):
+    """``fused_attention`` over Q ``[B, T, H * (nope + rope)]``, KV ``[B, T,
+    H * (nope + dv)]`` and the shared key part, through the executor with
+    the streamed kernels interpreted, against the block as Fluid ops composed
+    it around the 4-D op (its streamed kernels too): ``Out``, ``LSE``, dQ,
+    dKV — keys' and values' columns —, dKShared; which is the heads' sum of
+    the joined keys' rotary gradient, turned back."""
+    from streamed_backward import latent_attention_programs
+
+    n, t, nope, rope, dv, theta, forward, backward, b = case
+    monkeypatch.setattr(att, "_STREAMED_PLATFORMS", ("tpu", "cpu"))
+    monkeypatch.setattr(sa, "_in_place_heads_per_step", lambda *a: forward)
+    monkeypatch.setattr(sa, "_in_place_fused_heads_per_step",
+                        lambda *a: backward)
+    compile_cache.clear()
+    composed, one = latent_attention_programs(n, t, nope, rope, dv, theta,
+                                              (nope + rope) ** -0.5)
+    types = [op.type for op in one[0].global_block().ops]
+    assert not {"transpose", "expand", "concat", "split", "reshape",
+                "rotary_embedding"} & set(types)
+    feed = dict(zip(("q", "kv", "kr", "ct"), map(np.asarray, _projections(
+        b, n, t, nope, rope, dv))))
+    names = ("fused_attention:streamed_inplace",
+             "fused_attention_grad:streamed_fused_inplace",
+             "streamed_step:%dx1" % forward,
+             "streamed_grad_step:%dx1" % backward, "fused_attention:xla")
+    exe = fluid.Executor(fluid.CPUPlace())
+    want = exe.run(composed[0], feed=feed, fetch_list=composed[1])
+    before = _bodies(*names)
+    got = exe.run(one[0], feed=feed, fetch_list=one[1])
+    assert tuple(x - y for x, y in zip(_bodies(*names), before)) == (
+        1, 1, 1, 1, 0)
+    assert got[0].shape == (b, t, n * dv) and got[1].shape == (b, n, t, 1)
+    for a, w, tol in zip(got, want, (1e-5, 1e-5, 1e-4, 1e-4, 1e-4)):
+        assert a.shape == w.shape and a.dtype == w.dtype
+        np.testing.assert_allclose(a, w, rtol=tol, atol=tol)
+    joined = want[5][..., nope:].sum(1)                     # [B, T, rope]
+    turned = got[4] if theta is None else _np_rotary_pairs(got[4], theta)
+    np.testing.assert_allclose(turned, joined, rtol=1e-4, atol=1e-4)
+    compile_cache.clear()
+
+
+def test_in_place_kernels_round_where_the_composed_block_rounded():
+    """In bf16: the queries' rotation rounded once, then the scaled operand
+    of the product, as ``rotary_embedding`` and the 4-D kernel round them —
+    the two forms' outputs differ by the order of float32 sums alone."""
+    n, t, nope, rope, dv, theta = 4, 256, 128, 64, 128, 3.2e7
+    q, kv, kr, ct = _projections(1, n, t, nope, rope, dv,
+                                 dtype=jnp.bfloat16)
+    scale = (nope + rope) ** -0.5
+    krr = att._rotated(kr, theta)
+    q4 = q.reshape(1, t, n, nope + rope)
+    q4 = jnp.concatenate([q4[..., :nope], att._rotated(q4[..., nope:],
+                                                       theta)], -1)
+    kv4 = kv.reshape(1, t, n, nope + dv)
+    k4 = jnp.concatenate([kv4[..., :nope], jnp.broadcast_to(
+        krr[:, :, None], (1, t, n, rope))], -1)
+    want, want_lse = sa.forward(*(x.transpose(0, 2, 1, 3) for x in
+                                  (q4, k4, kv4[..., nope:])), None, True,
+                                scale, True)
+    got, lse = sa.forward_in_place(q, kv, krr, n, dv, theta, True, scale,
+                                   True)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(lse, want_lse, rtol=1e-5, atol=1e-5)
+    a = np.asarray(got, "float32").reshape(1, t, n, dv)
+    w = np.asarray(want, "float32").transpose(0, 2, 1, 3)
+    assert (a != w).mean() < 0.01             # a last bit here and there
+    np.testing.assert_allclose(a, w, rtol=2 ** -7, atol=1e-4)
+
+
+def _in_place_ins(n=2, t=256, nope=128, rope=64, dv=128, b=1, **attrs):
+    q, kv, kr, _ = _projections(b, n, t, nope, rope, dv)
+    return ({"Q": [q], "K": [kv], "KShared": [kr]},
+            dict({"causal": True, "n_head": n, "v_dim": dv,
+                  "rope_theta": 1e4}, **attrs))
+
+
+def _tpu(**kw):
+    from paddle_tpu.registry import ComputeContext
+
+    return ComputeContext(key=jax.random.key(0), platform="tpu", **kw)
+
+
+IN_PLACE_DECLINES = {
+    "a_mesh": (dict(), dict(mesh=True), False, 0.0),
+    "a_key_length": (dict(), dict(), True, 0.0),
+    "dropout": (dict(), dict(), False, 0.1),
+    "rope_no_half_tile": (dict(rope=32), dict(), False, 0.0),
+    "rope_three_half_tiles": (dict(rope=192), dict(), False, 0.0),
+    "nope_no_tile": (dict(nope=64), dict(), False, 0.0),
+    "values_no_tile": (dict(dv=64), dict(), False, 0.0),
+    "a_ragged_block": (dict(t=200), dict(), False, 0.0),
+    "an_odd_head_of_half_tiles": (dict(n=3), dict(), False, 0.0),
+}
+
+
+@pytest.mark.parametrize("why", sorted(IN_PLACE_DECLINES))
+def test_in_place_rule_declines(why):
+    from paddle_tpu.parallel.mesh import make_mesh
+
+    sizes, ctx, has_klen, rate = IN_PLACE_DECLINES[why]
+    ins, attrs = _in_place_ins()
+    assert att._in_place_applicable(_tpu(), ins, attrs, False, 0.0)
+    if ctx:
+        ctx = dict(mesh=make_mesh((2,), ("dp",)))
+    ins, attrs = _in_place_ins(**sizes)
+    assert not att._in_place_applicable(_tpu(**ctx), ins, attrs, has_klen,
+                                        rate)
+
+
+def test_in_place_rule_wants_a_tpu_a_shared_part_and_the_operators_leave(
+        no_pallas):
+    from paddle_tpu import flags
+    from paddle_tpu.registry import ComputeContext
+
+    ins, attrs = _in_place_ins()
+    assert not att._in_place_applicable(_tpu(), ins, attrs, False, 0.0)
+    flags.set_flags({"FLAGS_pallas_kernels": True})
+    assert att._in_place_applicable(_tpu(), ins, attrs, False, 0.0)
+    assert not att._in_place_applicable(
+        ComputeContext(key=jax.random.key(0), platform="cpu"), ins, attrs,
+        False, 0.0)
+    ins.pop("KShared")
+    assert not att._in_place_applicable(
+        _tpu(), {"Q": [ins["Q"][0][..., :2 * 128]], "K": ins["K"]}, attrs,
+        False, 0.0)
+    # 32k tokens: not even two heads' resident gradients fit the budget
+    q, kv = (jax.ShapeDtypeStruct((1, 32768, 32 * w), jnp.bfloat16)
+             for w in (192, 256))
+    assert sa.in_place_supported(q.shape, kv.shape, 64, 32, 128, False, 0.0)
+    assert sa.in_place_step(q, kv, 32, 128) == (8, None)
+
+
+@pytest.mark.parametrize("declined", ["cpu", "key_length", "small_widths"])
+def test_declined_in_place_op_takes_the_xla_body_with_the_same_numbers(
+        declined):
+    """The op's definition: what the composed block computes, whatever made
+    the rule decline — the CPU, a ``k_len``, widths off the tiles."""
+    from streamed_backward import latent_attention_programs
+
+    n, t, nope, rope, dv, theta = (4, 48, 16, 8, 16, 1e4) \
+        if declined == "small_widths" else (2, 256, 128, 64, 128, 3.2e7)
+    compile_cache.clear()
+    q, kv, kr, ct = _projections(2, n, t, nope, rope, dv)
+    names = ("fused_attention:xla", "fused_attention:streamed_inplace")
+    before = _bodies(*names)
+    if declined == "key_length":
+        ctx = fluid.registry.ComputeContext(key=jax.random.key(0),
+                                            platform="cpu")
+        k_len = jnp.asarray([t, t - 50], jnp.int32)
+        ins, attrs = ({"Q": [q], "K": [kv], "KShared": [kr],
+                       "KLen": [k_len]},
+                      {"causal": True, "n_head": n, "v_dim": dv,
+                       "rope_theta": theta})
+        got = att._fused_attention_compute(ins, attrs, ctx, 0)
+        q4 = q.reshape(2, t, n, nope + rope)
+        q4 = jnp.concatenate([q4[..., :nope],
+                              att._rotated(q4[..., nope:], theta)], -1)
+        kv4 = kv.reshape(2, t, n, nope + dv)
+        k4 = jnp.concatenate([kv4[..., :nope], jnp.broadcast_to(
+            att._rotated(kr, theta)[:, :, None], (2, t, n, rope))], -1)
+        want, lse = fa.reference_attention(
+            *(x.transpose(0, 2, 1, 3) for x in (q4, k4, kv4[..., nope:])),
+            k_len, None, True, 0.0, None, None, True)
+        np.testing.assert_allclose(
+            got["Out"], want.transpose(0, 2, 1, 3).reshape(2, t, n * dv),
+            rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(got["LSE"], lse, rtol=1e-5, atol=1e-5)
+        assert tuple(x - y for x, y in zip(_bodies(*names), before)) == (1, 0)
+        return
+    composed, one = latent_attention_programs(n, t, nope, rope, dv, theta,
+                                              (nope + rope) ** -0.5)
+    feed = dict(zip(("q", "kv", "kr", "ct"), map(np.asarray,
+                                                 (q, kv, kr, ct))))
+    exe = fluid.Executor(fluid.CPUPlace())
+    got = exe.run(one[0], feed=feed, fetch_list=one[1])
+    # the forward, and the gradient op differentiating it
+    assert tuple(x - y for x, y in zip(_bodies(*names), before)) == (2, 0)
+    want = exe.run(composed[0], feed=feed, fetch_list=composed[1])
+    for a, w in zip(got, want):
+        np.testing.assert_allclose(a, w, rtol=1e-5, atol=1e-5)
+    compile_cache.clear()
+
+
+def test_the_op_refuses_a_layout_it_cannot_read():
+    def build(**kw):
+        with fluid.program_guard(fluid.Program(), fluid.Program()):
+            q = fluid.layers.data("q", shape=[16, 2 * 24], dtype="float32")
+            kv = fluid.layers.data("kv", shape=[16, 2 * 32], dtype="float32")
+            kr = fluid.layers.data("kr", shape=[16, 8], dtype="float32")
+            args = dict(dict(q=q, k=kv, n_head=2, v_dim=16, k_shared=kr),
+                        **kw)
+            return fluid.layers.fused_attention(**args)
+    assert tuple(build().shape[1:]) == (16, 32)
+    for kw, match in ((dict(v_dim=None), "v_dim"),
+                      (dict(v_dim=24), "do not fit together"),
+                      (dict(k_shared=None), "do not fit together"),
+                      (dict(window=4), "no v, selected or window"),
+                      (dict(n_head=None), "projections' layout")):
+        with pytest.raises(ValueError, match=match):
+            build(**kw)
+
+
 # ---- the layer's shares ------------------------------------------------------------
 
 def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_reference():
@@ -574,7 +811,6 @@ def test_program_holds_one_embedding_one_head_and_two_losses():
               "moe_router_grad", "moe_expert_ffn_grad"):
         assert types.count(t) == experts, t
     assert types.count("swiglu") == blocks                 # dense or shared
-    assert types.count("rotary_embedding") == 2 * blocks
     assert types.count("lookup_table") == 2
     assert types.count("softmax_with_cross_entropy") == 2
     tables = {op.inputs["W"][0] for op in block.ops
@@ -585,12 +821,35 @@ def test_program_holds_one_embedding_one_head_and_two_losses():
     assert len(heads) == 2
     for op in block.ops:
         if op.type == "fused_attention":
-            assert op.attrs["keep_lse"] and op.outputs["LSE"]
-        if op.type == "rotary_embedding":
-            assert op.attrs["interleaved"] and op.attrs["theta"] == 3.2e7
+            # the three projections' outputs where they lie, the rotation
+            # inside the op
+            assert op.outputs["LSE"] and not op.inputs.get("V")
+            assert op.inputs["KShared"] and op.attrs["rope_theta"] == 3.2e7
+            assert op.attrs["n_head"] == cfg["num_attention_heads"]
+            assert op.attrs["v_dim"] == cfg["v_head_dim"]
         if op.type == "moe_router":
             assert op.attrs["score_func"] == "sigmoid"
             assert op.attrs["scale"] == 2.5 and op.inputs["Bias"]
+    # a latent block asks for nothing between its projections and the op:
+    # from its first norm to its output projection, and back
+    assert not {"rotary_embedding", "transpose", "expand"} & set(types)
+    for prefix in ["l%d." % i for i in range(blocks - 1)] + ["mtp."]:
+        first = next(i for i, op in enumerate(block.ops)
+                     if op.inputs.get("Scale") == [prefix + "ln1.g"])
+        last = next(i for i, op in enumerate(block.ops)
+                    if op.inputs.get("Y") == [prefix + "attn.o"])
+        assert sorted(set(types[first:last + 1])) == [
+            "fused_attention", "mul", "rms_norm", "split"]
+        first = next(i for i, op in enumerate(block.ops)
+                     if op.type == "mul_grad"
+                     and op.inputs.get("Y") == [prefix + "attn.o"])
+        last = next(i for i, op in enumerate(block.ops)
+                    if op.type == "rms_norm_grad"
+                    and op.inputs.get("Scale") == [prefix + "ln1.g"])
+        assert set(types[first:last + 1]) <= {
+            "fused_attention_grad", "mul_grad", "rms_norm_grad",
+            "split_grad", "concat", "sum"}
+        assert types[first:last + 1].count("sum") <= 1    # the norm's input
     model.close()
 
 

@@ -326,7 +326,13 @@ def test_latent_mixer_without_rank_and_rotation_against_the_reference():
         fluid.backward.append_backward(fluid.layers.reduce_sum(
             fluid.layers.square(y)))
     types = [op.type for op in main.global_block().ops]
-    assert "rotary_embedding" not in types
+    # the three products go to the op as they are, and it rotates nothing
+    assert not {"rotary_embedding", "transpose", "expand", "concat",
+                "reshape"} & set(types)
+    op = next(o for o in main.global_block().ops
+              if o.type == "fused_attention")
+    assert "rope_theta" not in op.attrs and op.inputs["KShared"]
+    assert op.inputs["Q"] != op.inputs["K"] and not op.inputs.get("V")
     params = {p.name for p in main.global_block().all_parameters()}
     assert "l2.attn.q" in params and not {"l2.attn.q_a", "l2.attn.q_b",
                                           "l2.attn.q_a_g"} & params
@@ -457,6 +463,11 @@ def test_program_holds_a_mixer_a_layer_and_declares_its_counters():
     assert types.count("gated_delta_rule_grad") == 2
     assert types.count("fused_attention") == 1
     assert types.count("causal_conv1d") == 6 and "rotary_embedding" not in types
+    latent = next(op for op in model.main.global_block().ops
+                  if op.type == "fused_attention")
+    assert latent.attrs["n_head"] == cfg["num_attention_heads"]
+    assert "rope_theta" not in latent.attrs and latent.inputs["KShared"]
+    assert "transpose" not in types and "expand" not in types
     assert types.count("moe_expert_ffn") == 2           # one dense layer
     assert model.main.step_stats[1] == smd.LINEAR_STEP_STATS
     assert smd.LINEAR_STEP_STATS[:3] == smd.STEP_STATS[:3]

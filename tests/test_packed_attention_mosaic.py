@@ -351,6 +351,77 @@ def test_no_index_of_the_streamed_kernels_divides_through_sign(
     assert {"div", "rem"} & inside and not {"sign", "floor"} & inside
 
 
+def _in_place_step(sa, n, dv, theta, blocks=1):
+    """(q, kv, kr, dO) -> blocks x (out, dQ, dKV, dKShared) through the
+    streamed kernels over the projections' layout, each call under its own
+    Fluid scope."""
+    def step(q, kv, kr, ct):
+        got = []
+        for i in range(blocks):
+            with jax.named_scope("fluid[fused_attention]out_%d" % i):
+                out, lse = sa.forward_in_place(q, kv, kr, n, dv, theta, True)
+            with jax.named_scope("fluid[fused_attention_grad]q_%d.GRAD" % i):
+                got.append((out,) + sa.backward_in_place(
+                    q, kv, kr, n, dv, out, lse, ct, theta, True))
+            q = q + got[-1][1]
+        return got
+    return step
+
+
+@pytest.mark.parametrize("t,theta,heads", [(8192, 3.2e7, (8, 2)),
+                                           (4096, None, (8, 4))])
+def test_in_place_kernels_compile_for_v5e_at_the_latent_cells_shapes(
+        one_chip, t, theta, heads):
+    """The streamed kernels over the projections' layout at
+    ``joyai_llm_flash.train_mtp_8k``'s shape — 32 heads, ``[q_nope 128 |
+    q_rope 64]`` of Q ``[1, 8192, 6144]``, ``[k_nope 128 | v 128]`` of KV
+    ``[1, 8192, 8192]``, the shared ``[1, 8192, 64]``, rotation — and at
+    ``kimi_linear_48b_a3b.train_doc_4k``'s (4096 tokens, none): Mosaic has
+    to accept the column blocks, the head loop's index in whole lane tiles,
+    the lane rolls and parity selects of the rotation and of the odd heads'
+    half-tile shift, two heads' (four at 4096) float32 ``[T, 256]`` dK|dV
+    resident beside the shared part's ``[T, 128]``, inside the VMEM limit
+    the kernels state.  Three blocks: 6 call sites, 2 traces."""
+    from paddle_tpu.ops.pallas import streamed_attention as sa
+
+    b, n, nope, rope, dv, blocks = 1, 32, 128, 64, 128, 3
+
+    def arg(width, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((b, t, width), dtype, sharding=one_chip)
+    q, kv, kr, ct = (arg(n * (nope + rope)), arg(n * (nope + dv)), arg(rope),
+                     arg(n * dv))
+    assert sa.in_place_supported(q.shape, kv.shape, rope, n, dv, False, 0.0)
+    assert sa.in_place_step(q, kv, n, dv) == heads
+    pallas.traced.cache_clear()
+    sites, traces = _kernel_traces()
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(_in_place_step(sa, n, dv, theta, blocks)).lower(
+            q, kv, kr, ct).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert _kernel_traces() == (sites + 2 * blocks, traces + 2)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 2 * blocks
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024 * 1024 * 1024
+
+
+@pytest.mark.parametrize("theta", [1e4, None])
+def test_no_index_of_the_in_place_kernels_divides_through_sign(theta):
+    from paddle_tpu.ops.pallas import streamed_attention as sa
+
+    n, t, nope, rope, dv = 32, 8192, 128, 64, 128
+    q, kv, kr, ct = (jax.ShapeDtypeStruct((1, t, w), jnp.bfloat16)
+                     for w in (n * (nope + rope), n * (nope + dv), rope,
+                               n * dv))
+    jaxpr = jax.make_jaxpr(_in_place_step(sa, n, dv, theta))(q, kv, kr,
+                                                             ct).jaxpr
+    inside = list(_primitives(jaxpr))
+    assert inside.count("pallas_call") == 2
+    assert "div" in inside and not {"sign", "floor"} & set(inside)
+
+
 def _expert_operands(held, tile, sharding=None):
     """The expert ops' operands at the two expert cells' size — 8192 tokens
     of 2048, eight experts a token, ``held`` experts of width 768 at tiles of

@@ -347,6 +347,12 @@ def note_phase(field, since_ns):
     return now
 
 
+_TICKS = float(2 ** 30)
+_PHASE_SECONDS = ("analyze_s", "program_trace_s", "jax_trace_s",
+                  "kernel_trace_s", "lowering_s", "executable_s",
+                  "first_call_s")
+
+
 def close_record(call_ns):
     """Close the calling thread's record (None when there is none) and
     append it to the log: ``call_ns`` is when the cold call began
@@ -362,6 +368,11 @@ def close_record(call_ns):
     _open.record = None
     if call_ns is not None:
         rec["first_call_s"] = (time.perf_counter_ns() - call_ns) / 1e9
+    # every phase in whole 2^-30 s (~1 ns): a sum of them is then exact in
+    # whatever order a reader adds them
+    for field in _PHASE_SECONDS:
+        rec[field] = round(rec[field] * _TICKS) / _TICKS
+    if call_ns is not None:
         rec["unaccounted_s"] = rec["first_call_s"] - (
             rec["jax_trace_s"] + rec["lowering_s"] + rec["executable_s"])
     spans = rec.pop("_spans")

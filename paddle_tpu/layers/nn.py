@@ -485,17 +485,36 @@ def rms_norm(x, epsilon=1e-6, param_attr=None, name=None):
     return out
 
 
-def rotary_embedding(x, theta=10000.0, interleaved=False, name=None):
+def rotary_embedding(x, theta=10000.0, interleaved=False, freq_scaling=None,
+                     scale=1.0, name=None):
     """Rotary position embedding of ``x`` ``[B, T, ..., D]``: position =
     index along axis 1, all D dimensions rotated, frequencies
     ``theta^(-2i/D)``; rotate-half convention (dimension i pairs with i +
     D/2) or, ``interleaved``, neighbouring pairs (2i, 2i + 1).  A model
-    that rotates part of a head slices that part out and joins it back."""
+    that rotates part of a head slices that part out and joins it back.
+
+    ``freq_scaling`` — ``{"factor", "original_length", "beta_fast",
+    "beta_slow"}`` — takes the frequencies from YaRN's blend by parts
+    instead: a frequency that turns more than ``beta_fast`` times in
+    ``original_length`` positions stays, one that turns fewer than
+    ``beta_slow`` times is divided by ``factor``, those between are blended
+    linearly by index (``ops.activation.scaled_frequencies``: worked out in
+    float64 when the step is traced and bound as a constant; the blend is
+    static, whatever the length).  ``scale`` multiplies cos and sin (YaRN's
+    attention factor: a score of two rotated sides carries its square).
+    The gradient turns back by the same frequencies times the same scale.
+    An op without the two keeps its text."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_variable_for_type_inference(dtype=x.dtype)
     attrs = {"theta": float(theta)}
     if interleaved:
         attrs["interleaved"] = True
+    if freq_scaling is not None:
+        attrs["freq_scaling"] = {
+            k: float(freq_scaling[k]) for k in (
+                "factor", "original_length", "beta_fast", "beta_slow")}
+    if float(scale) != 1.0:
+        attrs["scale"] = float(scale)
     helper.append_op(type="rotary_embedding", inputs={"X": [x]},
                      outputs={"Out": [out]}, attrs=attrs)
     return out

@@ -1,8 +1,10 @@
 """Decoder-only language models parameterised by their sizes and built
 from ``layers`` functions into a Fluid ``Program``: one family, three kinds
 of block.  Two are here, pre-norm blocks with routed SiLU-gated experts —
-and a third decoder whose MIXER differs by layer (delta attention or latent
-attention) over the same two FFN halves; the third kind of block, the
+with a third decoder whose MIXER differs by layer (delta attention or latent
+attention) over the same two FFN halves, and a fourth whose ATTENTION half
+differs by layer (a window of the nearest keys or every causal key, each
+under its own rotation) over the experts; the third kind of block, the
 sandwich-norm block of a looped dense decoder, is in
 :mod:`.looped_decoder`.  What the kinds share exists once, here: the
 bias-free projection (``_proj``), the SiLU-gated products of a dense FFN
@@ -12,7 +14,10 @@ the looped kind), the untied head's per-token cross entropy
 kind weighs the passes' by an exit gate) and the declaration of the step's
 counters (``_declare_step_stats``).  The two expert kinds also share the
 routed-expert half of a block (``_expert_half``) and its counters
-(``_moe_step_stats``).
+(``_moe_step_stats``); the decoders over grouped heads share their attention
+half (``_grouped_attention``: the selected-key block's with a per-head norm
+and its indexer's selection, the window / full decoder's with a window or
+none).
 
 **Selected-key blocks** (``decoder_block`` / ``decoder_lm``), for ``x``
 [B, T, D]:
@@ -80,6 +85,17 @@ sizes are a ``LatentSizes``):
    ``_expert_half`` with the latent kind's router (sigmoid, a frozen bias, a
    scale, a shared expert).  No module; one loss.
 
+**Window / full attention blocks** (``window_moe_decoder_lm``), one kind
+a layer from a list:
+
+1. ``_grouped_attention`` without the per-head norm and without a
+   selection: ``q``, ``k`` rotated (rotate-half, all ``head_dim``
+   dimensions) by the layer kind's law ``ropes[kind] = (theta,
+   freq_scaling, scale)`` — ``"window"``: a query reads its nearest
+   ``window`` keys (``fused_attention(window=)``); ``"full"``: every causal
+   key.  The kind changes those two arguments and nothing else.
+2. ``_expert_half`` with the softmax router, no shared expert.
+
 After the last block a final RMSNorm, an untied head over ``vocab_size``
 rows and the mean next-token cross entropy in float32.
 """
@@ -91,7 +107,8 @@ from ..initializer import ConstantInitializer, NormalInitializer
 from ..param_attr import ParamAttr
 
 __all__ = ["decoder_block", "decoder_lm", "LatentSizes", "latent_block",
-           "latent_decoder_lm", "DeltaSizes", "linear_latent_decoder_lm"]
+           "latent_decoder_lm", "DeltaSizes", "linear_latent_decoder_lm",
+           "window_moe_decoder_lm"]
 
 _INIT_STD = 0.02
 # the fields of ``decoder_lm``'s step counters, as the executor names them in
@@ -103,6 +120,8 @@ LATENT_STEP_STATS = STEP_STATS[:3] + ("mtp_loss",)
 # ... and of ``linear_latent_decoder_lm``'s
 LINEAR_STEP_STATS = STEP_STATS[:3] + ("delta_state_rms", "decay_mean",
                                       "beta_mean")
+# ... and of ``window_moe_decoder_lm``'s
+WINDOW_STEP_STATS = STEP_STATS[:3] + ("window_pair_share",)
 
 # the sizes of a latent-attention block's attention half
 LatentSizes = collections.namedtuple(
@@ -190,6 +209,49 @@ def _moe_step_stats(stats, last):
         last()], axis=0)
 
 
+def _heads(v, n, width):
+    return layers.reshape(v, shape=[0, 0, n, width])
+
+
+def _grouped_attention(x, prefix, n_head, n_kv_head, head_dim, rope, rms_eps,
+                       window=None, qk_norm=False, select=None):
+    """The attention half of a block over grouped heads, whichever keys
+    count: ``x += concat_h(attention(q, k, v)) Wo`` with ``h = rms_norm(x)``,
+    ``q = h Wq`` (``n_head`` heads of ``head_dim``), ``k = h Wk``, ``v = h
+    Wv`` (``n_kv_head`` heads), no biases; ``q`` and ``k`` through a per-head
+    RMSNorm (``qk_norm``) and ``rope``, a function of a ``[B, T, H, D]``
+    variable (the rotation's law is the caller's).  Causal; of a query's keys
+    count the nearest ``window`` (an int), or those ``select(h)`` — called
+    between the rotations and the attention, returning ``(packed key mask,
+    share of the causal pairs selected)`` — selects, or all.  Returns ``(x,
+    ctx, mask, share)``: ``ctx`` [B, T, n_head * head_dim] is what ``Wo``
+    reads; ``mask`` and ``share`` are ``select``'s, None without one."""
+    h = layers.rms_norm(x, rms_eps, ParamAttr(name=prefix + "ln1.g"))
+    q = _heads(_proj(h, n_head * head_dim, prefix + "attn.q"), n_head,
+               head_dim)
+    k = _heads(_proj(h, n_kv_head * head_dim, prefix + "attn.k"), n_kv_head,
+               head_dim)
+    v = _heads(_proj(h, n_kv_head * head_dim, prefix + "attn.v"), n_kv_head,
+               head_dim)
+    if qk_norm:
+        q = layers.rms_norm(q, rms_eps, ParamAttr(name=prefix + "attn.q_g"))
+    q = rope(q)
+    if qk_norm:
+        k = layers.rms_norm(k, rms_eps, ParamAttr(name=prefix + "attn.k_g"))
+    k = rope(k)
+    mask, share = select(h) if select is not None else (None, None)
+
+    def to_bhtd(t):
+        return layers.transpose(t, perm=[0, 2, 1, 3])
+    ctx = layers.fused_attention(to_bhtd(q), to_bhtd(k), to_bhtd(v),
+                                 causal=True, scale=head_dim ** -0.5,
+                                 selected=mask, window=window)
+    ctx = layers.reshape(to_bhtd(ctx), shape=[0, 0, n_head * head_dim])
+    return (layers.elementwise_add(x, _proj(ctx, x.shape[-1],
+                                            prefix + "attn.o")),
+            ctx, mask, share)
+
+
 def decoder_block(x, prefix, n_head, n_kv_head, head_dim, expert_share,
                   expert_width, experts_per_token, index_heads, index_dim,
                   index_topk, rope_theta=1e7, rms_eps=1e-6, expert_tile=256):
@@ -201,46 +263,27 @@ def decoder_block(x, prefix, n_head, n_kv_head, head_dim, expert_share,
     fullest held expert's tokens) and ``selected_share`` (share of the
     causal pairs the indexer selected), and ``selected``, the packed key
     mask itself."""
-    d = x.shape[-1]
-
-    def heads(v, n, width):
-        return layers.reshape(v, shape=[0, 0, n, width])
-
     def rope(v):
         return layers.rotary_embedding(v, theta=rope_theta)
 
-    h = layers.rms_norm(x, rms_eps, ParamAttr(name=prefix + "ln1.g"))
-    q = heads(_proj(h, n_head * head_dim, prefix + "attn.q"), n_head,
-              head_dim)
-    k = heads(_proj(h, n_kv_head * head_dim, prefix + "attn.k"), n_kv_head,
-              head_dim)
-    v = heads(_proj(h, n_kv_head * head_dim, prefix + "attn.v"), n_kv_head,
-              head_dim)
-    q = rope(layers.rms_norm(q, rms_eps, ParamAttr(name=prefix + "attn.q_g")))
-    k = rope(layers.rms_norm(k, rms_eps, ParamAttr(name=prefix + "attn.k_g")))
+    def select(h):
+        # the frozen indexer: its inputs carry no gradient either (the
+        # selection is a set), so h's gradient comes from q, k, v alone
+        qi = rope(_heads(_proj(h, index_heads * index_dim, prefix + "idx.q",
+                               False), index_heads, index_dim))
+        ki = layers.layer_norm(
+            _proj(h, index_dim, prefix + "idx.k", False), begin_norm_axis=2,
+            param_attr=ParamAttr(name=prefix + "idx.k_g", trainable=False),
+            bias_attr=ParamAttr(name=prefix + "idx.k_b", trainable=False))
+        ki = rope(ki)
+        wi = _proj(h, index_heads, prefix + "idx.w", False)
+        return layers.select_keys(
+            qi, ki, wi, index_topk,
+            scale=index_heads ** -0.5 * index_dim ** -0.5)
 
-    # the frozen indexer: its inputs carry no gradient either (the
-    # selection is a set), so h's gradient comes from q, k, v alone
-    qi = rope(heads(_proj(h, index_heads * index_dim, prefix + "idx.q",
-                          False), index_heads, index_dim))
-    ki = layers.layer_norm(
-        _proj(h, index_dim, prefix + "idx.k", False), begin_norm_axis=2,
-        param_attr=ParamAttr(name=prefix + "idx.k_g", trainable=False),
-        bias_attr=ParamAttr(name=prefix + "idx.k_b", trainable=False))
-    ki = rope(ki)
-    wi = _proj(h, index_heads, prefix + "idx.w", False)
-    selected, share = layers.select_keys(
-        qi, ki, wi, index_topk,
-        scale=index_heads ** -0.5 * index_dim ** -0.5)
-
-    def to_bhtd(t):
-        return layers.transpose(t, perm=[0, 2, 1, 3])
-    ctx = layers.fused_attention(to_bhtd(q), to_bhtd(k), to_bhtd(v),
-                                 causal=True, scale=head_dim ** -0.5,
-                                 selected=selected)
-    ctx = layers.reshape(to_bhtd(ctx), shape=[0, 0, n_head * head_dim])
-    x = layers.elementwise_add(x, _proj(ctx, d, prefix + "attn.o"))
-
+    x, _, selected, share = _grouped_attention(
+        x, prefix, n_head, n_kv_head, head_dim, rope, rms_eps, qk_norm=True,
+        select=select)
     x, stats = _expert_half(x, prefix, expert_share, expert_width,
                             experts_per_token, rms_eps, expert_tile)
     stats.update(selected_share=share, selected=selected)
@@ -509,3 +552,61 @@ def linear_latent_decoder_lm(tokens, labels, vocab_size, d_model, mixers,
         stats, lambda: layers.concat([
             layers.sqrt(_mean(layers.square(state))), _decay_mean(gate),
             _mean(beta)], axis=0)), LINEAR_STEP_STATS), state
+
+
+def attention_pair_share(program):
+    """Of the causal (query, key) pairs of ``program``'s ``fused_attention``
+    ops, the share that counts under the ops' own masks — a ``window``
+    keeps ``t - window < s <= t`` — read off what each op is given: its
+    query's length and its attributes."""
+    counted = causal = 0
+    for op in program.global_block().ops:
+        if op.type != "fused_attention":
+            continue
+        t = program.global_block().var(op.input("Q")[0]).shape[2]
+        w = min(int(op.attr("window") or t), t)
+        causal += t * (t + 1) // 2
+        counted += w * (w + 1) // 2 + (t - w) * w
+    return counted / max(causal, 1)
+
+
+def window_moe_decoder_lm(tokens, labels, vocab_size, d_model, mixers, n_head,
+                          n_kv_head, head_dim, window, ropes, expert_share,
+                          expert_width, experts_per_token, rms_eps=1e-6,
+                          expert_tile=256):
+    """The training graph over ``tokens`` / ``labels`` (the next token) [B,
+    T, 1] int64, every position real: one block a name of ``mixers`` —
+    ``"window"`` (a query reads its nearest ``window`` keys) or ``"full"``
+    (every causal key) — whose attention half is ``_grouped_attention`` with
+    that kind's rotation, ``ropes[kind] = (theta, freq_scaling, scale)``
+    (``layers.rotary_embedding``), over the routed-expert half with the
+    softmax router.  Returns ``(loss, stats, contexts)``: the mean next-token
+    cross entropy; a [4] float32 variable a caller fetches WITH the loss,
+    under ``WINDOW_STEP_STATS``' names (``Program.step_stats``) — pairs
+    routed to the held experts and pairs computed (summed over the layers),
+    the fullest held expert's tokens, and the share of the layers' causal
+    pairs that count under their masks (``attention_pair_share``) —; and
+    each block's attention output before ``Wo`` [B, T, n_head * head_dim]."""
+    x = layers.embedding(tokens, size=[vocab_size, d_model],
+                         param_attr=_attr("tok_emb"))
+    stats, contexts = [], []
+    for i, mixer in enumerate(mixers):
+        if mixer not in ("window", "full"):
+            raise ValueError("a mixer is 'window' or 'full', got %r"
+                             % (mixer,))
+        prefix = "l%d." % i
+        theta, freq_scaling, scale = ropes[mixer]
+        x, ctx, _, _ = _grouped_attention(
+            x, prefix, n_head, n_kv_head, head_dim,
+            lambda v: layers.rotary_embedding(
+                v, theta=theta, freq_scaling=freq_scaling, scale=scale),
+            rms_eps, window=window if mixer == "window" else None)
+        contexts.append(ctx)
+        x, st = _expert_half(x, prefix, expert_share, expert_width,
+                             experts_per_token, rms_eps, expert_tile)
+        stats.append(st)
+    loss = _head_loss(x, labels, vocab_size, rms_eps, "ln_f.g")
+    return loss, _declare_step_stats(loss, _moe_step_stats(
+        stats, lambda: layers.fill_constant(
+            [1], "float32", attention_pair_share(loss.block.program))),
+        WINDOW_STEP_STATS), contexts

@@ -5,8 +5,11 @@ TPU-native: one-liner jnp/lax bodies; XLA fuses them into producers, and
 their vjp-derived gradients match the reference's analytic grad kernels.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..registry import register_op, same_shape_infer, set_output, in_var
 
@@ -147,24 +150,65 @@ register_op("swiglu", ["X", "Y"], ["Out"], infer=same_shape_infer("X", "Out"),
 
 # -- rotary position embedding ----------------------------------------------
 
-def rotary_tables(length, dim, theta):
+def scaled_frequencies(dim, theta, freq_scaling):
+    """The ``dim / 2`` frequencies of YaRN's blend by parts, float64 (numpy:
+    worked out when the op is traced, bound as a constant): ``w0_i =
+    theta^(-2i/dim)`` kept where a frequency turns more than ``beta_fast``
+    times in ``original_length`` positions, divided by ``factor`` where it
+    turns fewer than ``beta_slow`` times, and blended linearly over the
+    indices between — ``low = max(floor(c(beta_fast)), 0)``, ``high =
+    min(ceil(c(beta_slow)), dim - 1)`` with ``c(r) = dim ln(original_length /
+    (2 pi r)) / (2 ln theta)``; ``high`` + 0.001 where the two meet.  The
+    blend is static: it holds at every length."""
+    factor, length = (float(freq_scaling[k])
+                      for k in ("factor", "original_length"))
+
+    def turns(r):
+        return dim * math.log(length / (r * 2 * math.pi)) / (
+            2 * math.log(theta))
+    low = max(math.floor(turns(float(freq_scaling["beta_fast"]))), 0)
+    high = min(math.ceil(turns(float(freq_scaling["beta_slow"]))), dim - 1)
+    if low == high:
+        high += 0.001
+    base = float(theta) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return (1.0 - ramp) * base + ramp * base / factor
+
+
+def rotary_tables(length, dim, theta, freq_scaling=None, scale=1.0):
     """(cos, sin) ``[length, dim]`` of positions 0..length-1 in the
-    rotate-half convention: frequency i = theta^(-2i/dim) turns the pair
-    (x[i], x[i + dim/2])."""
-    inv = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    rotate-half convention: frequency i = theta^(-2i/dim) — or, with
+    ``freq_scaling``, ``scaled_frequencies``' — turns the pair (x[i], x[i +
+    dim/2]); both tables times ``scale``."""
+    if freq_scaling is None:
+        inv = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    else:
+        inv = jnp.asarray(scaled_frequencies(dim, theta, freq_scaling),
+                          jnp.float32)
     angle = jnp.arange(length, dtype=jnp.float32)[:, None] * inv[None, :]
     angle = jnp.concatenate([angle, angle], -1)
-    return jnp.cos(angle), jnp.sin(angle)
+    if scale == 1.0:
+        return jnp.cos(angle), jnp.sin(angle)
+    return scale * jnp.cos(angle), scale * jnp.sin(angle)
 
 
 def _rotary_compute(ins, attrs, ctx, op_index):
     """Rotate ``X`` ``[B, T, ..., D]`` by its position along axis 1 over all
     D dimensions, in float32; the output keeps X's dtype.  Frequency i
     turns the pair (x[i], x[i + D/2]) (rotate-half, the default) or, with
-    ``interleaved``, the neighbours (x[2i], x[2i + 1]), in place."""
+    ``interleaved``, the neighbours (x[2i], x[2i + 1]), in place.  The
+    frequencies are ``theta^(-2i/D)`` or, with the attribute
+    ``freq_scaling`` (``{factor, original_length, beta_fast, beta_slow}``),
+    YaRN's blend of them by parts (``scaled_frequencies``: float64 at trace
+    time, a constant of the step); ``scale`` multiplies cos and sin.  The
+    gradient (``jax.vjp`` of this) turns back by the same frequencies, times
+    the same scale."""
     x = ins["X"][0]
     d = x.shape[-1]
-    cos, sin = rotary_tables(x.shape[1], d, float(attrs.get("theta", 1e4)))
+    cos, sin = rotary_tables(x.shape[1], d, float(attrs.get("theta", 1e4)),
+                             attrs.get("freq_scaling"),
+                             float(attrs.get("scale", 1.0)))
     shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (d,)
     xf = x.astype(jnp.float32)
     if attrs.get("interleaved", False):

@@ -333,23 +333,46 @@ def test_build_passes_cost_the_program_they_rewrote_once(amp):
     assert main._build_s == {}
 
 
-def test_build_pass_spans_nest_and_count_their_own_seconds():
+def test_build_pass_spans_nest_and_count_their_own_seconds(monkeypatch):
+    """``outer``'s own seconds are its whole less ``inner``'s, on a clock
+    the test turns itself: a loaded host (six xdist workers) stretches a
+    ``sleep`` past any bound on the wall clock."""
+    import types
+
+    now = [0]
+    monkeypatch.setattr(profiler, "time", types.SimpleNamespace(
+        perf_counter_ns=lambda: now[0]))
+
+    def passes(ms):
+        now[0] += ms * 1_000_000
+
     main = fluid.Program()
     profiler.reset_profiler()
     profiler.start_profiler("CPU")
     try:
         with profiler.build_pass(main, "outer"):
-            time.sleep(0.02)
+            passes(20)
             with profiler.build_pass(main, "inner"):
-                time.sleep(0.03)
+                passes(30)
+            passes(5)
+        events = {e["name"]: e for e in profiler._events}
         names = [e["name"] for e in profiler._events]
     finally:
         profiler.stop_profiler(profile_path=None)
         profiler.reset_profiler()
+    # the inner span closes first and lies inside the outer one
     assert names == ["build/inner", "build/outer"]
-    assert main._build_s["inner"] >= 0.03
-    assert 0.02 <= main._build_s["outer"] < 0.03 + main._build_s["inner"]
-    assert main._build_s["outer"] < 0.03
+    inner, outer = events["build/inner"], events["build/outer"]
+    assert (inner["dur"], outer["dur"]) == (30e3, 55e3)        # microseconds
+    assert outer["ts"] <= inner["ts"] \
+        and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+    # own seconds: the whole less what a nested pass counted
+    assert main._build_s == {"inner": pytest.approx(0.030),
+                             "outer": pytest.approx(0.025)}
+    # a second pass of the same name adds to it
+    with profiler.build_pass(main, "inner"):
+        passes(10)
+    assert main._build_s["inner"] == pytest.approx(0.040)
 
 
 def test_a_closed_record_is_spans_under_a_profiler_session():

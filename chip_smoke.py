@@ -545,6 +545,58 @@ def latent_projections_through_the_op(h, t, nope, rope, dv, rope_theta):
     return sorted(step)[0] + " " + sorted(grad_step)[0]
 
 
+def grouped_projections_through_the_op(h, hk, t, d, window, **rope):
+    """A grouped-head block's attention over its three projections' own
+    outputs — Q [1, t, h * d], K and V [1, t, hk * d] — through
+    ``fused_attention`` and its gradient op in a program, the rotation
+    (``rope``: the layer's ``rope_*`` keywords) inside the op: the trace
+    takes the streamed kernels in place, forward and the fused backward.
+    Out, dQ, dK and dV against the op's XLA body, which is the composition
+    a model wrote before (view as heads, rotate, transpose, the plain
+    attention)."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        q, k, v = (fluid.layers.data(n, shape=[t, w], dtype="float32")
+                   for n, w in (("q", h * d), ("k", hk * d), ("v", hk * d)))
+        for x in (q, k, v):
+            x.stop_gradient = False
+        o = fluid.layers.fused_attention(
+            q, k, v, causal=True, scale=d ** -0.5, n_head=h, window=window,
+            **rope)
+        fluid.append_backward(fluid.layers.reduce_sum(
+            fluid.layers.elementwise_mul(o, o)))
+    args = [normal(i, (1, t, w), jnp.float32) * 0.5
+            for i, w in enumerate((h * d, hk * d, hk * d))]
+    before = kernel_bodies("fused_attention")
+    got = fluid.Executor(fluid.TPUPlace(0)).run(
+        main, feed=dict(zip("qkv", map(np.asarray, args))),
+        fetch_list=[o, "q@GRAD", "k@GRAD", "v@GRAD"])
+    bodies = bodies_since(before, "fused_attention")
+    if bodies != {"fused_attention:streamed_inplace": 1,
+                  "fused_attention_grad:streamed_fused_inplace": 1}:
+        raise AssertionError("grouped projections through the op: bodies %s"
+                             % bodies)
+    op = next(op for op in main.global_block().ops
+              if op.type == "fused_attention")
+    law = attention._grouped_parts(
+        {"Q": [args[0]], "K": [args[1]], "V": [args[2]]}, op.attrs)[-1]
+
+    def reference(q, k, v):
+        return attention._merge_heads(attention_xla.reference_attention(
+            *attention._split_heads(q, k, v, h, hk, law), None, None, True,
+            0.0, d ** -0.5, None, False, window))
+    want = (jax.jit(reference)(*args),) + jax.jit(jax.grad(
+        lambda *a: jnp.sum(reference(*a) ** 2), (0, 1, 2)))(*args)
+    errs = [close(g, w, TOL_KERNEL["matmul"],
+                  "grouped projections through the op")
+            for g, w in zip(got, want)]
+    step = "%dx%d %dx%d" % (sa.step_heads(*args, h)
+                            + sa.grad_step(*args, h)[1])
+    log("grouped projections through the op (%d / %d x %d, T %d, window %s, "
+        "%s): %s, errors %s" % (h, hk, d, t, window, rope, step, errs))
+    return step
+
+
 def normal(seed, shape, dtype):
     return jax.random.normal(jax.random.key(seed), shape,
                              jnp.float32).astype(dtype)
@@ -794,6 +846,16 @@ def phase_kernels():
                                                   3.2e7),
         "step_no_rotation": latent_projections_through_the_op(
             h, 2048, 128, 64, 128, None)}
+    # ... and a grouped-head block's three projections since PR 50: eight
+    # query heads a K/V head under a window that crosses three key blocks
+    # and YaRN's law with its factor; plain heads with the plain law
+    out["streamed_attention_grouped_in_place"] = {
+        "step_window": grouped_projections_through_the_op(
+            16, 2, 2048, 128, 700, rope_theta=5e5, rope_scale=1.2772588722,
+            rope_freq_scaling={"factor": 16.0, "original_length": 512.0,
+                               "beta_fast": 32.0, "beta_slow": 1.0}),
+        "step_plain": grouped_projections_through_the_op(
+            8, 8, 2048, 128, None, rope_theta=1e6)}
     # the looped decoder's geometry: 16 plain heads, keys and values both
     # 128 wide, rotary on the whole head, T = 4096
     out["streamed_attention_plain_128"] = {"step": plain_heads_through_the_op(

@@ -273,9 +273,11 @@ def exit_gate_loss(hiddens, token_losses, beta=0.0, param_attr=None,
 def fused_attention(q, k, v=None, k_len=None, causal=False, dropout_rate=0.0,
                     is_test=False, scale=None, selected=None, name=None,
                     window=None, n_head=None, v_dim=None, k_shared=None,
-                    rope_theta=None):
+                    rope_theta=None, rope_freq_scaling=None, rope_scale=1.0,
+                    rope_interleaved=False):
     """Flash attention over head-split tensors q/k/v [B, H, T, D], or —
-    ``n_head`` given — over the projections' own outputs [B, T, H * D].
+    ``n_head`` given — over the projections' own outputs [B, T, H * D]
+    (grouped heads with ``v``, latent attention without).
 
     k and v may carry fewer heads than q (grouped-query attention: H a
     whole multiple of theirs), and v another width than q and k (``[B, H,
@@ -297,7 +299,27 @@ def fused_attention(q, k, v=None, k_len=None, causal=False, dropout_rate=0.0,
     shapes (``ops/attention.py`` says which and why);
     ``FLAGS_pallas_kernels=False`` keeps every Pallas body off.
 
-    **The projections' layout** (latent attention; ``n_head`` and ``v_dim``
+    **Grouped heads where the projections wrote them** (``n_head`` and
+    ``v`` given): ``q`` ``[B, T, n_head * D]``, ``k`` ``[B, T, Hkv * D]``,
+    ``v`` ``[B, T, Hkv * Dv]`` — the three projections' outputs as they are,
+    ``Hkv`` a whole divisor of ``n_head`` read off ``k``'s width — and the
+    result ``[B, T, n_head * Dv]``, what the output projection reads: no
+    reshape, rotation or transpose is asked for between the projections and
+    the op.  ``causal``, ``scale``, ``window``, ``selected``, ``k_len`` and
+    dropout mean what they mean over ``[B, H, T, D]``.  ``rope_theta`` given,
+    the OP rotates every head of ``q`` and ``k`` by its position, as
+    ``rotary_embedding(x, theta=rope_theta, freq_scaling=rope_freq_scaling,
+    scale=rope_scale, interleaved=rope_interleaved)`` over the ``[B, T, H,
+    D]`` view would (a per-head norm of q or k comes BEFORE the op:
+    ``rms_norm(x, group=D)``, over the projection as it lies).  On a TPU the
+    streamed kernels address the three arrays where they lie and rotate q
+    and k as they load their blocks (``D`` and
+    ``Dv`` whole lane tiles of 128 — a rotation: rotate-half of 128-wide
+    heads —, T whole 128-key blocks, no ``k_len``, no dropout, no mesh);
+    anything else takes the 4-D kernels or the XLA body behind the op's own
+    rotation and transposes, which is the definition.
+
+    **The projections' layout of latent attention** (``n_head`` and ``v_dim``
     given, ``v`` None): ``q`` is the query projection's output ``[B, T,
     n_head * (nope + rope)]``, a head's columns ``[q_nope | q_rope]``;
     ``k`` the key/value projection's output WHOLE, ``[B, T, n_head * (nope
@@ -318,18 +340,20 @@ def fused_attention(q, k, v=None, k_len=None, causal=False, dropout_rate=0.0,
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(dtype=q.dtype)
     in_place = n_head is not None
-    if in_place and (v is not None or v_dim is None or selected is not None
-                     or window is not None):
+    latent = in_place and v is None
+    if latent and (v_dim is None or selected is not None
+                   or window is not None):
         raise ValueError(
             "fused_attention over the projections' outputs (n_head given) "
-            "takes k as the key/value projection's output whole with v_dim, "
-            "and no v, selected or window")
-    if not in_place and not (k_shared is None and rope_theta is None
-                             and v_dim is None):
-        raise ValueError("fused_attention: k_shared, rope_theta and v_dim "
-                         "belong to the projections' layout (n_head given)")
+            "without v takes k as the key/value projection's output whole "
+            "with v_dim, and no selected or window")
+    if not in_place and rope_theta is not None \
+            or not latent and not (k_shared is None and v_dim is None):
+        raise ValueError("fused_attention: rope_theta belongs to the "
+                         "projections' layout (n_head given), k_shared and "
+                         "v_dim to its latent form (no v)")
     inputs = {"Q": [q], "K": [k]}
-    if not in_place:
+    if not latent:
         inputs["V"] = [v]
     if k_len is not None:
         inputs["KLen"] = [k_len]
@@ -342,9 +366,21 @@ def fused_attention(q, k, v=None, k_len=None, causal=False, dropout_rate=0.0,
     if scale is not None:
         attrs["scale"] = float(scale)
     if in_place:
-        attrs.update(n_head=int(n_head), v_dim=int(v_dim))
+        attrs["n_head"] = int(n_head)
+    if latent:
+        attrs["v_dim"] = int(v_dim)
     if rope_theta is not None:
         attrs["rope_theta"] = float(rope_theta)
+    if not latent:
+        # rotary_embedding's own attributes, kept as that layer keeps them
+        if rope_freq_scaling is not None:
+            attrs["rope_freq_scaling"] = {
+                key: float(rope_freq_scaling[key]) for key in (
+                    "factor", "original_length", "beta_fast", "beta_slow")}
+        if float(rope_scale) != 1.0:
+            attrs["rope_scale"] = float(rope_scale)
+        if rope_interleaved:
+            attrs["rope_interleaved"] = True
     outputs = {"Out": [out]}
     from ..ops.attention import streams_plain_heads
     marked = not in_place and selected is None and k.shape[1] == q.shape[1] \
@@ -467,17 +503,20 @@ def causal_conv1d(x, width, act=None, param_attr=None, bias_attr=None,
     return out
 
 
-def rms_norm(x, epsilon=1e-6, param_attr=None, name=None):
+def rms_norm(x, epsilon=1e-6, param_attr=None, name=None, group=None):
     """``x * rsqrt(mean(x^2) + epsilon) * gain`` over the LAST axis, with a
     learned gain of that axis's size (initialised to 1) and no bias: the
     pre-norm of a decoder block over ``[B, T, D]``, and its per-head
-    QK-norm over ``[B, T, H, Dh]``.  Statistics in float32 whatever the
+    QK-norm over ``[B, T, H, Dh]``.  ``group`` (a whole divisor of the last
+    axis): one norm a ``group``-wide slice of it under ONE gain of that
+    width — the per-head norm over a projection's ``[B, T, H * Dh]`` output
+    as it lies, with no view as heads.  Statistics in float32 whatever the
     activations' dtype."""
     from ..initializer import ConstantInitializer
 
     helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
     gain = helper.create_parameter(
-        attr=helper.param_attr, shape=[x.shape[-1]], dtype="float32",
+        attr=helper.param_attr, shape=[group or x.shape[-1]], dtype="float32",
         default_initializer=ConstantInitializer(1.0))
     out = helper.create_variable_for_type_inference(dtype=x.dtype)
     helper.append_op(type="rms_norm", inputs={"X": [x], "Scale": [gain]},

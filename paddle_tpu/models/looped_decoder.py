@@ -54,18 +54,13 @@ def sandwich_block(x, prefix, n_head, head_dim, ffn_width, rope_theta=1e6,
     def norm(v, name):
         return layers.rms_norm(v, rms_eps, ParamAttr(name=prefix + name))
 
-    def heads(v):
-        return layers.reshape(v, shape=[0, 0, n_head, head_dim])
-
-    def to_bhtd(t):
-        return layers.transpose(t, perm=[0, 2, 1, 3])
     a = norm(x, "ln1.g")
-    q, k, v = (heads(_proj(a, n_head * head_dim, prefix + "attn." + m))
+    # the three projections go to the op as they lie, the rotation inside it
+    q, k, v = (_proj(a, n_head * head_dim, prefix + "attn." + m)
                for m in "qkv")
-    q, k = (layers.rotary_embedding(t, theta=rope_theta) for t in (q, k))
-    ctx = layers.fused_attention(to_bhtd(q), to_bhtd(k), to_bhtd(v),
-                                 causal=True, scale=head_dim ** -0.5)
-    ctx = layers.reshape(to_bhtd(ctx), shape=[0, 0, n_head * head_dim])
+    ctx = layers.fused_attention(q, k, v, causal=True,
+                                 scale=head_dim ** -0.5, n_head=n_head,
+                                 rope_theta=rope_theta)
     x = layers.elementwise_add(
         x, norm(_proj(ctx, x.shape[-1], prefix + "attn.o"), "ln2.g"))
     return layers.elementwise_add(

@@ -219,34 +219,32 @@ def _grouped_attention(x, prefix, n_head, n_kv_head, head_dim, rope, rms_eps,
     count: ``x += concat_h(attention(q, k, v)) Wo`` with ``h = rms_norm(x)``,
     ``q = h Wq`` (``n_head`` heads of ``head_dim``), ``k = h Wk``, ``v = h
     Wv`` (``n_kv_head`` heads), no biases; ``q`` and ``k`` through a per-head
-    RMSNorm (``qk_norm``) and ``rope``, a function of a ``[B, T, H, D]``
-    variable (the rotation's law is the caller's).  Causal; of a query's keys
-    count the nearest ``window`` (an int), or those ``select(h)`` — called
-    between the rotations and the attention, returning ``(packed key mask,
-    share of the causal pairs selected)`` — selects, or all.  Returns ``(x,
-    ctx, mask, share)``: ``ctx`` [B, T, n_head * head_dim] is what ``Wo``
-    reads; ``mask`` and ``share`` are ``select``'s, None without one."""
+    RMSNorm (``qk_norm``: ``rms_norm(group=head_dim)``, where they lie) and
+    rotated by
+    ``rope``, the law as ``(theta, freq_scaling, scale)``
+    (``layers.rotary_embedding``'s).  The three projections go to ONE op as
+    they lie — ``fused_attention`` over grouped heads in the projections'
+    layout, the rotation inside it — and its result is what ``Wo`` reads:
+    nothing is rotated or transposed between them.  Causal; of a query's
+    keys count the nearest ``window`` (an int), or those ``select(h)`` —
+    returning ``(packed key mask, share of the causal pairs selected)`` —
+    selects, or all.  Returns ``(x, ctx, mask, share)``: ``ctx`` [B, T,
+    n_head * head_dim] is what ``Wo`` reads; ``mask`` and ``share`` are
+    ``select``'s, None without one."""
     h = layers.rms_norm(x, rms_eps, ParamAttr(name=prefix + "ln1.g"))
-    q = _heads(_proj(h, n_head * head_dim, prefix + "attn.q"), n_head,
-               head_dim)
-    k = _heads(_proj(h, n_kv_head * head_dim, prefix + "attn.k"), n_kv_head,
-               head_dim)
-    v = _heads(_proj(h, n_kv_head * head_dim, prefix + "attn.v"), n_kv_head,
-               head_dim)
+    q = _proj(h, n_head * head_dim, prefix + "attn.q")
+    k = _proj(h, n_kv_head * head_dim, prefix + "attn.k")
+    v = _proj(h, n_kv_head * head_dim, prefix + "attn.v")
     if qk_norm:
-        q = layers.rms_norm(q, rms_eps, ParamAttr(name=prefix + "attn.q_g"))
-    q = rope(q)
-    if qk_norm:
-        k = layers.rms_norm(k, rms_eps, ParamAttr(name=prefix + "attn.k_g"))
-    k = rope(k)
+        q, k = (layers.rms_norm(t, rms_eps, ParamAttr(name=prefix + name),
+                                group=head_dim)
+                for t, name in ((q, "attn.q_g"), (k, "attn.k_g")))
     mask, share = select(h) if select is not None else (None, None)
-
-    def to_bhtd(t):
-        return layers.transpose(t, perm=[0, 2, 1, 3])
-    ctx = layers.fused_attention(to_bhtd(q), to_bhtd(k), to_bhtd(v),
-                                 causal=True, scale=head_dim ** -0.5,
-                                 selected=mask, window=window)
-    ctx = layers.reshape(to_bhtd(ctx), shape=[0, 0, n_head * head_dim])
+    theta, freq_scaling, scale = rope
+    ctx = layers.fused_attention(
+        q, k, v, causal=True, scale=head_dim ** -0.5, selected=mask,
+        window=window, n_head=n_head, rope_theta=theta,
+        rope_freq_scaling=freq_scaling, rope_scale=scale)
     return (layers.elementwise_add(x, _proj(ctx, x.shape[-1],
                                             prefix + "attn.o")),
             ctx, mask, share)
@@ -263,7 +261,7 @@ def decoder_block(x, prefix, n_head, n_kv_head, head_dim, expert_share,
     fullest held expert's tokens) and ``selected_share`` (share of the
     causal pairs the indexer selected), and ``selected``, the packed key
     mask itself."""
-    def rope(v):
+    def rope(v):             # the indexer's 64-wide heads: no op takes them
         return layers.rotary_embedding(v, theta=rope_theta)
 
     def select(h):
@@ -282,8 +280,8 @@ def decoder_block(x, prefix, n_head, n_kv_head, head_dim, expert_share,
             scale=index_heads ** -0.5 * index_dim ** -0.5)
 
     x, _, selected, share = _grouped_attention(
-        x, prefix, n_head, n_kv_head, head_dim, rope, rms_eps, qk_norm=True,
-        select=select)
+        x, prefix, n_head, n_kv_head, head_dim, (rope_theta, None, 1.0),
+        rms_eps, qk_norm=True, select=select)
     x, stats = _expert_half(x, prefix, expert_share, expert_width,
                             experts_per_token, rms_eps, expert_tile)
     stats.update(selected_share=share, selected=selected)
@@ -558,12 +556,14 @@ def attention_pair_share(program):
     """Of the causal (query, key) pairs of ``program``'s ``fused_attention``
     ops, the share that counts under the ops' own masks — a ``window``
     keeps ``t - window < s <= t`` — read off what each op is given: its
-    query's length and its attributes."""
+    query's length (``[B, H, T, D]`` or the projections' ``[B, T, H * D]``)
+    and its attributes."""
     counted = causal = 0
     for op in program.global_block().ops:
         if op.type != "fused_attention":
             continue
-        t = program.global_block().var(op.input("Q")[0]).shape[2]
+        shape = program.global_block().var(op.input("Q")[0]).shape
+        t = shape[2] if len(shape) == 4 else shape[1]
         w = min(int(op.attr("window") or t), t)
         causal += t * (t + 1) // 2
         counted += w * (w + 1) // 2 + (t - w) * w
@@ -595,12 +595,9 @@ def window_moe_decoder_lm(tokens, labels, vocab_size, d_model, mixers, n_head,
             raise ValueError("a mixer is 'window' or 'full', got %r"
                              % (mixer,))
         prefix = "l%d." % i
-        theta, freq_scaling, scale = ropes[mixer]
         x, ctx, _, _ = _grouped_attention(
-            x, prefix, n_head, n_kv_head, head_dim,
-            lambda v: layers.rotary_embedding(
-                v, theta=theta, freq_scaling=freq_scaling, scale=scale),
-            rms_eps, window=window if mixer == "window" else None)
+            x, prefix, n_head, n_kv_head, head_dim, ropes[mixer], rms_eps,
+            window=window if mixer == "window" else None)
         contexts.append(ctx)
         x, st = _expert_half(x, prefix, expert_share, expert_width,
                              experts_per_token, rms_eps, expert_tile)

@@ -21,8 +21,9 @@ Each body with its gradient, in the order the op asks:
   The forward hands the gradient op its output and the rows' log-sum-exp
   (the op's ``LSE`` output); the gradient is the kernels' own backward on
   them.  **In place** (``streamed_inplace``): the same kernels over the
-  projections' own layout — the operand form below — where the rule
-  ``_in_place_applicable`` takes it.
+  projections' own layout — the two operand forms below — where the rules
+  ``_in_place_applicable`` (latent attention) and ``_grouped_body``
+  (grouped heads) take it.
 * **ring** — the mesh has a populated ``sp`` axis the sequence dims divide:
   sequence-parallel ring attention (``parallel/ring_attention.py``).  The
   gradient differentiates the body (``jax.vjp``).
@@ -42,7 +43,7 @@ Each body with its gradient, in the order the op asks:
   log-sum-exp also falls back to.  The gradient differentiates the body.
 
 A kernel is chosen by its rule alone (``_streamed_applicable``,
-``_in_place_applicable``, ``_packed_applicable``, through
+``_in_place_applicable``, ``_grouped_body``, ``_packed_applicable``, through
 ``ops.pallas.kernel_allowed``);
 ``FLAGS_pallas_kernels=False`` is the operator's one switch against all of
 them ("no Pallas").  Which body a trace took is counted in
@@ -85,6 +86,32 @@ between a projection and the kernel, the rotation of ``q_rope`` runs on the
 query block inside the kernel and that of ``KShared`` (1 MB) by XLA inside
 the op's scope.  The split score's second product saves no MXU pass; the
 form is there for the ~50 ms of copies a step it removes (PERF.md 6.25).
+**Grouped heads where the projections wrote them** is the third form —
+rank-3 ``Q`` with ``n_head`` and a ``V`` input, no ``v_dim``, no
+``KShared``: ``Q`` ``[B, T, H * D]``, ``K`` ``[B, T, Hkv * D]``, ``V`` ``[B,
+T, Hkv * Dv]``, the three projections of a grouped-query (or plain-head)
+block as they are, ``Hkv`` read off ``K``'s width.  ``Out`` is ``[B, T, H *
+Dv]``, ``LSE`` ``[B, H, T, 1]``; the gradient op returns dQ, dK and dV in the
+operands' layouts.  ``causal``, ``scale``, ``window``, ``Selected``, ``KLen``
+and dropout mean what they mean over ``[B, H, T, D]``.  The rotation's law
+rides on the op as ``rotary_embedding``'s own attributes under a prefix —
+``rope_theta``, ``rope_freq_scaling``, ``rope_scale``, ``rope_interleaved``
+(rotate-half unless set) — and the tables come from
+``ops.activation.rotary_tables``, so the two ops cannot drift; without
+``rope_theta`` nothing is rotated.  Its XLA body IS the definition
+(``_split_heads``): view as heads, rotate q and k by ``_rotary_compute``
+with its rounding points, heads to the front, ``reference_attention``, heads
+back; the gradient differentiates that.  Its kernel body is the 4-D form's
+OWN kernels reached through column blocks of the three arrays
+(``streamed_attention``: ``_head``), q and k rotated INSIDE them
+(``_Rotation``: a query block once as it is loaded, a key block each time —
+an XLA pass over K where it lies was tried first and ran at a fifth of its
+bytes' floor), dQ and dK turned back where they are written.
+Where only the 4-D rule holds (64-wide keys, neighbouring pairs, a rotated
+256-wide head, 32k tokens) the op goes to the 4-D kernels through its own
+rotation and transposes.  The form is there for the rotations and
+transposes it removes between the projections and the kernels (PERF.md
+6.27).
 ``causal`` with
 ``Tq == Tk`` is aligned self-attention (query i sees keys <= i); with
 ``Tq < Tk`` the queries are the *suffix* of the valid keys — query i sits
@@ -133,12 +160,56 @@ def _in_place_infer(op, block, q, kv):
     set_output(op, block, "LSE", (q.shape[0], n, q.shape[1], 1), "float32")
 
 
+def _grouped_infer(op, block, q, k, v):
+    """Grouped heads where the projections wrote them (the module
+    docstring): Q ``[B, T, H * D]``, K ``[B, T, Hkv * D]``, V ``[B, T, Hkv *
+    Dv]``, the attribute ``n_head``."""
+    n = op.attrs.get("n_head")
+    d = q.shape[2] // n if n and q.shape[2] % n == 0 else 0
+    hk = k.shape[2] // d if d and k.shape[2] % d == 0 else 0
+    if not hk or n % hk or len(k.shape) != 3 or len(v.shape) != 3 \
+            or v.shape[2] % hk or op.attrs.get("v_dim") \
+            or in_var(op, block, "KShared") is not None \
+            or not op.outputs.get("LSE") \
+            or not tuple(q.shape[:2]) == tuple(k.shape[:2]) \
+            == tuple(v.shape[:2]):
+        raise ValueError(
+            "fused_attention over [B, T, H * D] operands with a V takes "
+            "n_head heads of Q %s, a whole divisor of them in K %s and V %s "
+            "over the same rows, no v_dim and no KShared, and keeps the "
+            "rows' log-sum-exp: build the op with layers.fused_attention(q, "
+            "k, v, n_head=)" % (q.shape, k.shape, v.shape))
+    sel = in_var(op, block, "Selected")
+    if sel is not None:
+        from .sparse_select import packed_width
+        want = tuple(q.shape[:2]) + (packed_width(q.shape[1]),)
+        if tuple(sel.shape) != want:
+            raise ValueError(
+                "fused_attention: Selected must be the packed key mask %s "
+                "(ops/sparse_select.py), got %s" % (want, sel.shape))
+    window = op.attrs.get("window")
+    if window is not None and (not op.attrs.get("causal", False)
+                               or int(window) < 1):
+        raise ValueError("fused_attention: a window (%r) is of causal "
+                         "self-attention, at least one key wide" % (window,))
+    if op.attrs.get("rope_theta") is None and (
+            op.attrs.get("rope_freq_scaling") is not None
+            or float(op.attrs.get("rope_scale", 1.0)) != 1.0):
+        raise ValueError("fused_attention: rope_freq_scaling and rope_scale "
+                         "belong to a rotation (rope_theta)")
+    set_output(op, block, "Out", tuple(q.shape[:2]) + (n * (v.shape[2] // hk),),
+               q.dtype)
+    set_output(op, block, "LSE", (q.shape[0], n, q.shape[1], 1), "float32")
+
+
 def _fused_attention_infer(op, block):
     q = in_var(op, block, "Q")
     k = in_var(op, block, "K")
-    if len(q.shape) == 3:
-        return _in_place_infer(op, block, q, k)
     v = in_var(op, block, "V")
+    if len(q.shape) == 3:
+        if v is not None:
+            return _grouped_infer(op, block, q, k, v)
+        return _in_place_infer(op, block, q, k)
     if len(q.shape) != 4 or len(k.shape) != 4 or len(v.shape) != 4:
         raise ValueError(
             "fused_attention expects [B, H, T, D] Q/K/V, got %s/%s/%s"
@@ -251,18 +322,20 @@ def _attention_args(ins, attrs, ctx, op_index):
     return q, k, v, k_len, seed, causal, rate, scale, post
 
 
-def _rotated(x, theta, back=False):
-    """``x`` ``[B, T, ..., rope]`` with neighbouring pairs rotated by their
-    position (``rotary_embedding``'s interleaved form: float32 inside,
+def _rotated(x, law, back=False):
+    """``x`` ``[B, T, ..., D]`` rotated by its positions as the op
+    ``rotary_embedding`` with the attributes ``law`` does (float32 inside,
     ``x``'s dtype out), or — ``back`` — by the negative angle, which is the
-    rotation's gradient; ``theta`` None: ``x``."""
-    if theta is None:
+    rotation's gradient; ``law`` a bare base: interleaved pairs by it (the
+    latent form's); None: ``x``."""
+    if law is None:
         return x
+    if not isinstance(law, dict):
+        law = {"theta": float(law), "interleaved": True}
     from .activation import _rotary_compute
 
     def turn(x):
-        return _rotary_compute({"X": [x]}, {"theta": float(theta),
-                                            "interleaved": True}, None, 0)["Out"]
+        return _rotary_compute({"X": [x]}, law, None, 0)["Out"]
     if not back:
         return turn(x)
     import jax
@@ -375,8 +448,165 @@ def _in_place_grad_compute(ins, attrs, ctx, op_index):
                 shared.dtype)]}
 
 
+# ---- grouped heads where the projections wrote them -----------------------
+
+def _grouped_parts(ins, attrs):
+    """(Q, K, V, heads, K/V heads, D, Dv, the rotation's law — the
+    attributes of the ``rotary_embedding`` op that would do it — or None)
+    of the op over ``[B, T, H * D]`` operands with a V."""
+    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
+    n = int(attrs["n_head"])
+    d = q.shape[2] // n
+    hk = k.shape[2] // d
+    law = None
+    if attrs.get("rope_theta") is not None:
+        law = {"theta": float(attrs["rope_theta"])}
+        if attrs.get("rope_freq_scaling") is not None:
+            law["freq_scaling"] = attrs["rope_freq_scaling"]
+        if float(attrs.get("rope_scale", 1.0)) != 1.0:
+            law["scale"] = float(attrs["rope_scale"])
+        if attrs.get("rope_interleaved", False):
+            law["interleaved"] = True
+    return q, k, v, n, hk, d, v.shape[2] // hk, law
+
+
+def _split_heads(q, k, v, n, hk, law):
+    """The op's definition up to the attention, as a model composed it from
+    Fluid ops before the op took the projections' outputs: each viewed as
+    heads, q and k rotated, heads to the front.  ``[B, H, T, D]`` each."""
+    def heads(x, m, rotate):
+        x = x.reshape(x.shape[:2] + (m, x.shape[2] // m))
+        return (_rotated(x, law) if rotate else x).transpose(0, 2, 1, 3)
+    return heads(q, n, True), heads(k, hk, True), heads(v, hk, False)
+
+
+def _merge_heads(x):
+    """``[B, H, T, D]`` as ``[B, T, H * D]``."""
+    b, h, t, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+
+
+def _grouped_body(ctx, ins, attrs, has_klen, rate):
+    """The body of the op over grouped heads in the projections' layout, by
+    its rule: ``streamed_inplace`` — the streamed kernels addressing the
+    operands where they lie — on a TPU trace on one device with no
+    ``FLAGS_pallas_kernels=False``, no ``KLen``, no dropout, keys and values
+    whole lane tiles wide (a rotation: rotate-half of 128-wide heads), T
+    whole key blocks and the fused backward's resident gradients inside the
+    budget; ``streamed`` — the 4-D kernels through the op's own rotation and
+    transposes — where only ``_streamed_applicable`` holds (64-wide keys,
+    32k tokens); ``xla`` otherwise."""
+    from .pallas import streamed_attention as sa
+    from .sparse_select import LANES
+
+    q, k, v, n, hk, d, dv, law = _grouped_parts(ins, attrs)
+    b, t = q.shape[:2]
+    if not _streamed_applicable(ctx, (b, n, t, d), (b, hk, t, d), q.dtype,
+                                attrs.get("causal", False), has_klen, rate,
+                                dv):
+        return "xla"
+    turns = law is None or (d == LANES and not law.get("interleaved"))
+    if d % LANES == 0 and turns \
+            and sa.grad_step(q, k, v, n)[0] == "streamed_fused":
+        return "streamed_inplace"
+    return "streamed"
+
+
+def _grouped_tables(q, d, law):
+    """``half_turn_tables`` of ``q``'s positions by ``law``; None without
+    one."""
+    from .pallas import streamed_attention as sa
+
+    return None if law is None else sa.half_turn_tables(
+        q.shape[1], d, law["theta"], law.get("freq_scaling"),
+        law.get("scale", 1.0))
+
+
+def _grouped_compute(ins, attrs, ctx, op_index):
+    from . import attention_xla
+    from ..compile_cache import note_kernel_body
+    from .pallas import interpret_mode
+    from .pallas import streamed_attention as sa
+
+    _, _, _, k_len, seed, causal, rate, scale, post = _attention_args(
+        ins, attrs, ctx, op_index)
+    q, k, v, n, hk, d, dv, law = _grouped_parts(ins, attrs)
+    selected = (ins.get("Selected") or [None])[0]
+    window = attrs.get("window")
+    body = _grouped_body(ctx, ins, attrs, k_len is not None, rate)
+    note_kernel_body("fused_attention", body)
+    if body == "streamed_inplace":
+        note_kernel_body("streamed_step", "%dx%d" % sa.step_heads(q, k, v, n))
+        out, lse = sa.forward(
+            q, k, v, selected, causal, scale, interpret_mode(ctx), window, n,
+            _grouped_tables(q, d, law))
+    else:
+        heads = _split_heads(q, k, v, n, hk, law)
+        if body == "streamed":
+            note_kernel_body("streamed_step", "%dx%d" % sa.step_heads(*heads))
+            out, lse = sa.forward(*heads, selected, causal, scale,
+                                  interpret_mode(ctx), window)
+        else:
+            out, lse = attention_xla.reference_attention(
+                *heads, k_len, seed, causal, rate, scale, selected, True,
+                window)
+        out = _merge_heads(out)
+    if post is not None:
+        out = out * jnp.asarray(post, out.dtype)
+    return {"Out": out, "LSE": lse}
+
+
+def _grouped_grad_compute(ins, attrs, ctx, op_index):
+    """A streamed body's backward from the forward's own output and
+    log-sum-exp — in place, or around the 4-D kernels through the pull-back
+    of the op's own rotation and transposes —; the XLA body differentiates
+    itself."""
+    from ..registry import _generic_grad_compute
+
+    _, _, _, k_len, seed, causal, rate, scale, post = _attention_args(
+        ins, attrs, ctx, attrs.get("__fwd_op_index__", op_index))
+    dout, out, lse = ((ins.get(slot) or [None])[0]
+                      for slot in ("GRAD::Out", "Out::Out", "Out::LSE"))
+    body = _grouped_body(ctx, ins, attrs, k_len is not None, rate)
+    if dout is None or out is None or lse is None or body == "xla":
+        return _generic_grad_compute(ins, attrs, ctx, op_index)
+    from ..compile_cache import note_kernel_body
+    from .pallas import interpret_mode
+    from .pallas import streamed_attention as sa
+
+    q, k, v, n, hk, d, dv, law = _grouped_parts(ins, attrs)
+    selected = (ins.get("Selected") or [None])[0]
+    window = attrs.get("window")
+    if post is not None:
+        dout = dout * jnp.asarray(post, dout.dtype)
+    if body == "streamed_inplace":
+        note_kernel_body("fused_attention_grad", "streamed_fused_inplace")
+        note_kernel_body("streamed_grad_step",
+                         "%dx%d" % sa.grad_step(q, k, v, n)[1])
+        dq, dk, dvalues = sa.backward(
+            q, k, v, selected, out, lse, dout, causal, scale,
+            interpret_mode(ctx), window, n, _grouped_tables(q, d, law))
+    else:
+        import jax
+
+        heads, pull = jax.vjp(
+            lambda q, k, v: _split_heads(q, k, v, n, hk, law), q, k, v)
+        grad_body, step = sa.grad_step(*heads)
+        note_kernel_body("fused_attention_grad", grad_body)
+        note_kernel_body("streamed_grad_step", "%dx%d" % step)
+
+        def split(x):
+            return x.reshape(x.shape[:2] + (n, dv)).transpose(0, 2, 1, 3)
+        dq, dk, dvalues = pull(sa.backward(
+            *heads, selected, split(out), lse, split(dout), causal, scale,
+            interpret_mode(ctx), window))
+    return {"GRAD::Q": [dq], "GRAD::K": [dk], "GRAD::V": [dvalues]}
+
+
 def _fused_attention_compute(ins, attrs, ctx, op_index):
     if ins["Q"][0].ndim == 3:
+        if ins.get("V"):
+            return _grouped_compute(ins, attrs, ctx, op_index)
         return _in_place_compute(ins, attrs, ctx, op_index)
     q, k, v, k_len, seed, causal, rate, scale, post = _attention_args(
         ins, attrs, ctx, op_index)
@@ -446,6 +676,8 @@ def _fused_attention_grad_compute(ins, attrs, ctx, op_index):
     from ..registry import _generic_grad_compute
 
     if ins["Q"][0].ndim == 3:
+        if ins.get("V"):
+            return _grouped_grad_compute(ins, attrs, ctx, op_index)
         return _in_place_grad_compute(ins, attrs, ctx, op_index)
     fwd_index = attrs.get("__fwd_op_index__", op_index)
     q, k, v, k_len, seed, causal, rate, scale, post = _attention_args(

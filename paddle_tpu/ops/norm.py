@@ -256,18 +256,46 @@ register_op(
 def _rms_compute(ins, attrs, ctx, op_index):
     """``x * rsqrt(mean(x^2) + eps) * scale`` over the LAST axis (a hidden
     vector, or one head's slice of it: the per-head QK-norm of a decoder
-    block is this op over ``[B, T, H, D]`` with a ``[D]`` gain).  Like
+    block is this op over ``[B, T, H, D]`` with a ``[D]`` gain, or over
+    ``[B, T, H * D]`` with that gain: ``_rms_by_group``).  Like
     layer_norm an AMP-gray op: the mean of squares is taken in float32
     whatever the activations' dtype, and the output keeps that dtype."""
     x = ins["X"][0]
     xf = x.astype(jnp.float32)
+    if ins["Scale"][0].shape[0] != x.shape[-1]:
+        return {"Y": _rms_by_group(xf, ins["Scale"][0], attrs).astype(
+            x.dtype)}
     y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True)
                        + attrs.get("epsilon", 1e-6))
     return {"Y": (y * ins["Scale"][0].astype(jnp.float32)).astype(x.dtype)}
 
 
+def _rms_by_group(xf, scale, attrs):
+    """A gain narrower than the last axis: one norm a ``d``-wide group of it
+    (a per-head norm over a projection's ``[B, T, H * D]`` output WHERE IT
+    LIES).  The groups' sums and their way back are products with the
+    groups' indicator, exact in float32 at the highest precision: a view as
+    ``[.., H, D]`` is a relayout on a TPU, and XLA laid the float32 gradient
+    of such a view transposed and copied it back (PERF.md 6.27)."""
+    d = scale.shape[0]
+    n = xf.shape[-1] // d
+    member = (jnp.arange(n * d)[:, None] // d
+              == jnp.arange(n)[None, :]).astype(jnp.float32)
+
+    def over(a, b):
+        return jnp.matmul(a, b, precision=lax.Precision.HIGHEST)
+    inv = lax.rsqrt(over(jnp.square(xf), member) / d
+                    + attrs.get("epsilon", 1e-6))
+    return xf * over(inv, member.T) * jnp.tile(scale.astype(jnp.float32), n)
+
+
 def _rms_infer(op, block):
     x = in_var(op, block, "X")
+    scale = in_var(op, block, "Scale")
+    if x.shape[-1] % scale.shape[0]:
+        raise ValueError("rms_norm: the gain %s is as wide as X's last axis "
+                         "%s or a whole divisor of it (one norm a group)"
+                         % (scale.shape, x.shape))
     set_output(op, block, "Y", x.shape, x.dtype)
 
 

@@ -21,7 +21,10 @@ kernel ON.  The rules:
   the keys, or plain heads the layer marked ``keep_lse``; any length:
   ``ops/attention._streamed_applicable``.  The same kernels over a latent
   block's projections where they lie (``[B, T, H * D]`` operands, the shared
-  key part its own; PERF.md 6.25): ``ops/attention._in_place_applicable``.
+  key part its own; PERF.md 6.25): ``ops/attention._in_place_applicable``;
+  and over a grouped-head block's three projections (``[B, T, H * D]`` with a
+  V, the rotation inside the op; PERF.md 6.27):
+  ``ops/attention._grouped_body``.
 * ``topk_select`` — ``select_topk_keys`` with a query block's scores held in
   VMEM, one read of the scores where the XLA body makes 46 (PERF.md 6.8):
   ``ops/sparse_select._kernel_applicable``.

@@ -25,6 +25,21 @@ flight: any sequence length the HBM holds fits.
   rope]`` as three arrays, the score two products summed in float32.  The
   second product saves no MXU pass (the 128-deep passes make the 64 odd
   columns cost one either way); it is there for the copies it removes.
+* **Grouped heads where the projections wrote them.** ``forward`` /
+  ``backward`` with ``n_head``: Q ``[B, T, H * D]``, K ``[B, T, H / g *
+  D]``, V ``[B, T, H / g * Dv]`` and the result ``[B, T, H * Dv]`` — the
+  SAME kernels (``_fwd_kernel``, ``_grad_kernel``) on the same grid with the
+  same heads a step, a step's heads a COLUMN block ``[1, bq, kh * g' * D]``
+  / ``[1, bk, kh * D]`` / the resident ``[1, T, kh * D]`` of dK and dV
+  (``_row_specs(flat=True)``) and a head read as whole lane tiles of it
+  (``_head``).  With ``rot`` (``half_turn_tables``, resident whole) the
+  kernels rotate q and k themselves (``_Rotation``): a query block's heads
+  ONCE, as the block's first step loads them, and a step's K/V heads each
+  time their block is loaded — rotate-half of a 128-wide head is one
+  64-lane roll a tile and a signed-sine table — into VMEM scratch the head
+  loop reads; dQ and dK are turned back from their float32 sums where they
+  are written.  ``D`` and ``Dv`` whole lane tiles; the dQ + dK/dV pair
+  stays 4-D.
 * **One grid step serves several heads.** K/V carry ``H / g`` heads; the
   ``g`` query heads that read one of them are contiguous in ``[B, H, T,
   D]``.  A step serves ``kh`` K/V heads and ``g'`` query heads of each
@@ -361,7 +376,7 @@ def _split(refs, has_sel, n_in):
 
 
 def _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal,
-               window=None):
+               window=None, first=None):
     """Block pair (qi, ki) for the step's ``kh * gh`` query heads:
     ``head(h, kv, bias)`` for each, where ``kv`` is the one of the step's
     ``kh`` K/V heads that query head ``h`` reads (``h // gh``) and ``bias``
@@ -370,11 +385,15 @@ def _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal,
     heads — or None when every pair counts.  Nothing runs for a pair wholly
     above the diagonal, nor, under ``window`` (key ``s`` counts for query
     ``t`` iff ``t - window < s <= t``), for one wholly below the window's
-    lower edge."""
+    lower edge.  ``first()``, where given, runs once before the heads of a
+    pair that runs."""
     n = kh * gh
     together = max(m for m in range(1, _HEADS_UNROLLED + 1) if n % m == 0)
 
     def heads(bias):
+        if first is not None:
+            first()
+
         def body(i, carry):
             for j in range(together):
                 h = i * together + j
@@ -475,29 +494,114 @@ def _softmax_rows(m_s, l_s, acc_s):
                 _POS_BIG))
 
 
+def _columns(ref, at, width):
+    """``width`` columns at ``at`` of a block ``[1, rows, columns]``: whole
+    lane tiles, ``at`` static or a multiple of 128 made of the head loop's
+    own index (the loop stays rolled)."""
+    if not isinstance(at, int):
+        at = pl.multiple_of(at, LANES)
+    return ref[0, :, pl.ds(at, width)]
+
+
+def _head(ref, h, n):
+    """Head ``h`` of a block that holds ``n``: ``ref[0, h]`` of ``[1, n,
+    rows, d]`` — heads in front of the sequence — or the head's columns of
+    ``[1, rows, n * d]``, where a projection wrote them."""
+    if len(ref.shape) == 4:
+        return ref[0, h]
+    d = ref.shape[2] // n
+    return _columns(ref, h * d, d)
+
+
+def _store_heads(ref, x, back=None):
+    """``x`` ``[n, rows, d]`` float32 into a block of ``n`` heads of either
+    layout; through ``back`` (the queries' gradients over ``[1, rows, n *
+    d]``: each head's rotation turned back) where given."""
+    if len(ref.shape) == 4:
+        ref[0] = x.astype(ref.dtype)
+        return
+    d = x.shape[2]
+    for h in range(x.shape[0]):
+        y = x[h] if back is None else back(x[h])
+        ref[0, :, h * d:(h + 1) * d] = y.astype(ref.dtype)
+
+
+class _Rotation:
+    """Q and K of a grid step, rotated by their rows' positions where the
+    kernel was handed the tables (``rot``: the resident ``[T, 2 * d]`` of
+    ``half_turn_tables``; ``scratch``: the rotated queries ``[n, bq, d]`` and
+    keys ``[kh, bk, d]``), as they lie otherwise.  A query block's heads are
+    rotated ONCE, at the block's first step; the step's K/V heads each time
+    their block is loaded (XLA's pass over K where it lies ran at a fifth of
+    its bytes' floor: PERF.md 6.27); both rounded where ``rotary_embedding``
+    rounded.  Gradients are turned back from their float32 sums."""
+
+    def __init__(self, q_ref, k_ref, rot, scratch, n, kh, qi, ki, bq, bk):
+        self.q_ref, self.k_ref, self.n, self.kh = q_ref, k_ref, n, kh
+        self.at = (qi, bq), (ki, bk)
+        self.on = bool(rot)
+        if self.on:
+            (self.rot_ref,), (self.q_s, self.k_s) = rot, scratch
+
+    def _rows(self, block, size):
+        return self.rot_ref[pl.ds(pl.multiple_of(block * size, size), size)]
+
+    def _load(self, ref, into, heads, at):
+        table = self._rows(*at)
+        for h in range(heads):
+            into[h] = _turned(_head(ref, h, heads).astype(jnp.float32),
+                              table, 1.0, True).astype(into.dtype)
+
+    def load_queries(self):
+        if self.on:
+            self._load(self.q_ref, self.q_s, self.n, self.at[0])
+
+    def load_keys(self):
+        """``_each_head``'s ``first`` as its keyword (a call without a
+        rotation binds no such argument)."""
+        return {"first": lambda: self._load(
+            self.k_ref, self.k_s, self.kh, self.at[1])} if self.on else {}
+
+    def query(self, h):
+        return self.q_s[h] if self.on else _head(self.q_ref, h, self.n)
+
+    def key(self, kv):
+        return self.k_s[kv] if self.on else _head(self.k_ref, kv, self.kh)
+
+    def back(self, block, size):
+        """The turn back of a ``[size, d]`` float32 gradient of the rows of
+        ``block``, or None."""
+        return (lambda x: _turned(x, self._rows(block, size), -1.0,
+                                  True)) if self.on else None
+
+
 def _fwd_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nk, in_dtype,
-                window=None):
-    sel_ref, (q_ref, k_ref, v_ref), \
-        (o_ref, lse_ref, m_s, l_s, acc_s, bias_s) = _split(refs, has_sel, 4)
+                window=None, rotated=False):
+    sel_ref, (q_ref, k_ref, v_ref, *rot), \
+        (o_ref, lse_ref, m_s, l_s, acc_s, bias_s, *turned) = _split(
+            refs, has_sel, 4 + rotated)
     qi, ki = pl.program_id(2), pl.program_id(3)
+    src = _Rotation(q_ref, k_ref, rot, turned, kh * gh, kh, qi, ki, bq, bk)
 
     @pl.when(ki == 0)
     def _():
         m_s[...] = jnp.full(m_s.shape, _NEG_INF, jnp.float32)
         l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
         acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+        src.load_queries()
 
     def head(h, kv, bias):
-        _softmax_pair(h, _scores(q_ref[0, h], k_ref[0, kv], scale, in_dtype),
-                      bias, lambda: v_ref[0, kv], m_s, l_s, acc_s, in_dtype)
+        _softmax_pair(h, _scores(src.query(h), src.key(kv), scale, in_dtype),
+                      bias, lambda: _head(v_ref, kv, kh), m_s, l_s, acc_s,
+                      in_dtype)
 
     _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal,
-               window)
+               window, **src.load_keys())
 
     @pl.when(ki == nk - 1)
     def _():
         out, lse = _softmax_rows(m_s, l_s, acc_s)
-        o_ref[0] = out().astype(o_ref.dtype)
+        _store_heads(o_ref, out())
         lse_ref[0] = lse()
 
 
@@ -568,17 +672,19 @@ def _dkv_kernel(*refs, scale, causal, has_sel, kh, gh, bq, bk, nq, nr,
 
 
 def _grad_kernel(*refs, scale, causal, has_sel, kh, gh, parts, bq, bk, nq,
-                 nk, in_dtype, window=None):
+                 nk, in_dtype, window=None, rotated=False):
     """dQ, dK and dV of a block pair from ONE ``s``, ``p``, ``g`` and ``ds``
     (five products a pair).  dQ adds up across the key blocks in ``acc_s``;
     the step's K/V heads' float32 dK and dV stay in ``dk_s`` and ``dv_s``
     (K/V head ``kv``'s rows at ``kv * T``) across the query blocks, the key
     blocks and the ``parts`` head blocks of a group, a pair adding into its
     key block's rows."""
-    sel_ref, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), \
-        (dq_ref, dk_ref, dv_ref, acc_s, dk_s, dv_s, bias_s) = _split(
-            refs, has_sel, 7)
+    sel_ref, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rot), \
+        (dq_ref, dk_ref, dv_ref, acc_s, dk_s, dv_s, bias_s, *turned) = _split(
+            refs, has_sel, 7 + rotated)
     hi, qi, ki = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    n = kh * gh
+    src = _Rotation(q_ref, k_ref, rot, turned, n, kh, qi, ki, bq, bk)
     first, last = (qi == 0) & (ki == 0), (qi == nq - 1) & (ki == nk - 1)
     if parts > 1:
         first = first & (_rem(hi, parts) == 0)
@@ -605,31 +711,39 @@ def _grad_kernel(*refs, scale, causal, has_sel, kh, gh, parts, bq, bk, nq,
     @pl.when(ki == 0)
     def _():
         acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+        src.load_queries()
 
     def head(h, kv, bias):
-        q, k, do = q_ref[0, h], k_ref[0, kv], do_ref[0, h]
+        q, k, do = src.query(h), src.key(kv), _head(do_ref, h, n)
         p = _probs(q, k, lse_ref[0, h], bias, scale, in_dtype)
         dv_s[rows(kv, ki)] += _dot(p, do, ((0,), (0,)), in_dtype)
-        g = _dot(do, v_ref[0, kv], ((1,), (1,)), in_dtype)
+        g = _dot(do, _head(v_ref, kv, kh), ((1,), (1,)), in_dtype)
         ds = (p * (g - delta_ref[0, h])).astype(in_dtype)
         acc_s[h] += _dot(ds, k, ((1,), (0,)), in_dtype)
         dk_s[rows(kv, ki)] += _dot(ds, q.astype(jnp.float32) * scale,
                                    ((0,), (0,)), in_dtype)
 
     _each_head(head, sel_ref, bias_s, qi, ki, kh, gh, bq, bk, causal,
-               window)
+               window, **src.load_keys())
 
     @pl.when(ki == nk - 1)
     def _():
-        dq_ref[0] = (acc_s[...] * scale).astype(dq_ref.dtype)
+        _store_heads(dq_ref, acc_s[...] * scale, src.back(qi, bq))
 
     @pl.when(last)
     def _():
         def write(i):
             block = pl.ds(pl.multiple_of(i * bk, bk), bk)
             for kv in range(kh):
-                dk_ref[0, kv, block] = dk_s[rows(kv, i)].astype(dk_ref.dtype)
-                dv_ref[0, kv, block] = dv_s[rows(kv, i)].astype(dv_ref.dtype)
+                for ref, sums, back in ((dk_ref, dk_s, src.back(i, bk)),
+                                        (dv_ref, dv_s, None)):
+                    x = sums[rows(kv, i)]
+                    x = (x if back is None else back(x)).astype(ref.dtype)
+                    if len(ref.shape) == 4:
+                        ref[0, kv, block] = x
+                    else:
+                        d = sums.shape[1]
+                        ref[0, block, kv * d:(kv + 1) * d] = x
         each_block(write)
 
 
@@ -643,43 +757,54 @@ def _params(vmem, *inner):
         vmem_limit_bytes=vmem)
 
 
-def _geometry(q, k, v):
+def _geometry(q, k, v, n_head=None):
     """(B, H, T, Dk, Dv, query heads a K/V head, bq, bk, query blocks, key
-    blocks)."""
-    b, h, t, dk = q.shape
+    blocks) of ``[B, H, T, D]`` operands or — ``n_head`` given — of the
+    projections' ``[B, T, H * D]``."""
+    if n_head is None:
+        b, h, t, dk = q.shape
+        hk, dv = k.shape[1], v.shape[3]
+    else:
+        b, t, h = q.shape[0], q.shape[1], n_head
+        dk = q.shape[2] // h
+        hk = k.shape[2] // dk
+        dv = v.shape[2] // hk
     bq = bk = _pick_blocks(t)
-    return b, h, t, dk, v.shape[3], h // k.shape[1], bq, bk, t // bq, t // bk
+    return b, h, t, dk, dv, h // hk, bq, bk, t // bq, t // bk
 
 
-def step_heads(q, k, v):
+def step_heads(q, k, v, n_head=None):
     """(K/V heads, query heads of each) that one grid step of the forward
     serves for these operands."""
-    b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v)
-    return _heads_per_step(g, k.shape[1], bq, bk, dk, q.dtype.itemsize, dv)
+    b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v, n_head)
+    return _heads_per_step(g, h // g, bq, bk, dk, q.dtype.itemsize, dv)
 
 
-def grad_step(q, k, v):
+def grad_step(q, k, v, n_head=None):
     """(body, (K/V heads, query heads of each) a grid step) of the backward
     for these operands: ``streamed_fused``, the one kernel, where a K/V
     head's float32 dK and dV fit the budget beside a step's blocks;
     ``streamed``, the dQ and dK/dV kernels with the forward's heads a step,
     where they do not."""
-    b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v)
-    heads = _fused_heads_per_step(g, k.shape[1], bq, bk, t, dk,
+    b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v, n_head)
+    heads = _fused_heads_per_step(g, h // g, bq, bk, t, dk,
                                   q.dtype.itemsize, dv)
     if heads is None:
-        return "streamed", step_heads(q, k, v)
+        return "streamed", step_heads(q, k, v, n_head)
     return "streamed_fused", heads
 
 
-def _row_specs(g, kh, gh, bq, bk, causal, window=None):
+def _row_specs(g, kh, gh, bq, bk, causal, window=None, flat=False, t=None):
     """Block specs of a grid (batch, block of ``kh * gh`` query heads, query
     block, key block): (the heads' query-row blocks ``[kh * gh, bq, d]`` for
     a width ``d``, a ``[kh * gh, bq, 1]`` column of them, the ``d``-wide K/V
     blocks ``[kh, bk, d]`` of the heads' K/V heads, the selection's word
-    tile).  Under ``causal`` the key index clamps to the last block the
-    query block needs — and under ``window`` to the first, from below —
-    so a skipped step fetches nothing."""
+    tile, the K/V heads' whole one-buffered ``[kh, t, d]``).  ``flat``: the
+    operands are the projections' ``[B, T, heads * d]`` and a step's heads a
+    COLUMN block, ``[bq, kh * gh * d]`` / ``[bk, kh * d]`` / ``[t, kh * d]``
+    (the column stays ``[B, H, T, 1]``'s).  Under ``causal`` the key index
+    clamps to the last block the query block needs — and under ``window``
+    to the first, from below — so a skipped step fetches nothing."""
     per_tile = KEYS_PER_TILE // bk
 
     def key_block(qi, ki):
@@ -699,10 +824,24 @@ def _row_specs(g, kh, gh, bq, bk, causal, window=None):
 
     def sel_map(bi, hi, qi, ki):
         return (bi, qi, _div(key_block(qi, ki), per_tile))
-    return (lambda d: pl.BlockSpec((1, kh * gh, bq, d), q_map),
+
+    def whole_map(bi, hi, qi, ki):
+        return (bi, _div(hi * gh, g), 0, 0)
+
+    def spec(heads, rows, index, **mode):
+        # a block of ``heads`` heads' ``rows`` rows, by width
+        if not flat:
+            return lambda d: pl.BlockSpec((1, heads, rows, d), index, **mode)
+
+        def columns(*at):
+            bi, hi, ri, _ = index(*at)
+            return (bi, ri, hi)
+        return lambda d: pl.BlockSpec((1, rows, heads * d), columns, **mode)
+    return (spec(kh * gh, bq, q_map),
             pl.BlockSpec((1, kh * gh, bq, 1), q_map),
-            lambda d: pl.BlockSpec((1, kh, bk, d), kv_map),
-            pl.BlockSpec((1, bq, LANES), sel_map))
+            spec(kh, bk, kv_map),
+            pl.BlockSpec((1, bq, LANES), sel_map),
+            spec(kh, t, whole_map, pipeline_mode=pl.Buffered(1)))
 
 
 def _given(*operands):
@@ -717,28 +856,46 @@ def _windowed(window):
     return {} if window is None else {"window": window}
 
 
-def _forward(selected, q, k, v, *, heads, vmem, causal, scale, interpret,
-             window=None):
-    b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v)
+def _rotating(rot, n, kh, bq, bk, dk, dtype):
+    """What a kernel over the projections' layout gains where q and k are
+    rotated (``rot`` the tables ``[T, 2 * dk]``, ``half_turn_tables``): (its
+    keyword, the tables' spec — the whole array, resident, one buffer —, the
+    rotated queries' and keys' scratch), each empty without."""
+    if rot is None:
+        return {}, [], []
+    return ({"rotated": True},
+            [pl.BlockSpec(rot.shape, lambda bi, hi, qi, ki: (0, 0),
+                          pipeline_mode=pl.Buffered(1))],
+            [pltpu.VMEM((n, bq, dk), dtype), pltpu.VMEM((kh, bk, dk), dtype)])
+
+
+def _forward(selected, q, k, v, rot=None, *, heads, vmem, causal, scale,
+             interpret, window=None, n_head=None):
+    b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v, n_head)
     kh, gh = heads
-    row, col, kv, sel = _row_specs(g, kh, gh, bq, bk, causal, window)
+    flat = n_head is not None
+    row, col, kv, sel, _ = _row_specs(g, kh, gh, bq, bk, causal, window,
+                                      flat)
     has_sel = selected is not None
     n = kh * gh
+    rotated, tables, queries = _rotating(rot, n, kh, bq, bk, dk, q.dtype)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           has_sel=has_sel, kh=kh, gh=gh, bq=bq, bk=bk, nk=nk,
-                          in_dtype=q.dtype, **_windowed(window)),
+                          in_dtype=q.dtype, **_windowed(window), **rotated),
         grid=(b, h // n, nq, nk),
-        in_specs=([sel] if has_sel else []) + [row(dk), kv(dk), kv(dv)],
+        in_specs=([sel] if has_sel else []) + [row(dk), kv(dk), kv(dv)]
+        + tables,
         out_specs=[row(dv), col],
-        out_shape=[jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct(
+            (b, t, h * dv) if flat else (b, h, t, dv), q.dtype),
                    jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((n, bq, LANES), jnp.float32),
                         pltpu.VMEM((n, bq, LANES), jnp.float32),
                         pltpu.VMEM((n, bq, dv), jnp.float32),
-                        pltpu.VMEM((bq, bk), jnp.float32)],
+                        pltpu.VMEM((bq, bk), jnp.float32)] + queries,
         compiler_params=_params(vmem), interpret=interpret,
-    )(*_given(selected, q, k, v))
+    )(*_given(selected, q, k, v, rot))
 
 
 def _dq(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
@@ -748,7 +905,7 @@ def _dq(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
     kh, gh = heads
     n = kh * gh
     has_sel = selected is not None
-    row, col, kv, sel = _row_specs(g, kh, gh, bq, bk, causal, window)
+    row, col, kv, sel, _ = _row_specs(g, kh, gh, bq, bk, causal, window)
     return pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           has_sel=has_sel, kh=kh, gh=gh, bq=bq, bk=bk, nk=nk,
@@ -820,32 +977,29 @@ def _dkv(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
     )(*_given(selected, q, k, v, dout, lse, delta))
 
 
-def _grad(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
-          scale, interpret, window=None):
+def _grad(selected, q, k, v, dout, lse, delta, rot=None, *, heads, vmem,
+          causal, scale, interpret, window=None, n_head=None):
     """dQ, dK, dV by the fused kernel, on the forward's grid: (B, blocks of
     kh x gh heads, query blocks, key blocks).  The dK and dV blocks are a
     K/V head's whole ``[T, d]``, their index a function of the batch and
     the head block alone, so they stay in VMEM — one buffer, written back
     once — while the two inner axes (and a group's head blocks, where gh <
     g) run, none of which is ``parallel`` therefore."""
-    b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v)
+    b, h, t, dk, dv, g, bq, bk, nq, nk = _geometry(q, k, v, n_head)
     kh, gh = heads
     n = kh * gh
     has_sel = selected is not None
-    row, col, kv, sel = _row_specs(g, kh, gh, bq, bk, causal, window)
-
-    def whole(d):
-        return pl.BlockSpec(
-            (1, kh, t, d), lambda bi, hi, qi, ki: (bi, _div(hi * gh, g), 0, 0),
-            pipeline_mode=pl.Buffered(1))
+    row, col, kv, sel, whole = _row_specs(g, kh, gh, bq, bk, causal, window,
+                                          n_head is not None, t)
+    rotated, tables, queries = _rotating(rot, n, kh, bq, bk, dk, q.dtype)
     return pl.pallas_call(
         functools.partial(_grad_kernel, scale=scale, causal=causal,
                           has_sel=has_sel, kh=kh, gh=gh, parts=g // gh, bq=bq,
                           bk=bk, nq=nq, nk=nk, in_dtype=q.dtype,
-                          **_windowed(window)),
+                          **_windowed(window), **rotated),
         grid=(b, h // n, nq, nk),
         in_specs=([sel] if has_sel else [])
-        + [row(dk), kv(dk), kv(dv), row(dv), col, col],
+        + [row(dk), kv(dk), kv(dv), row(dv), col, col] + tables,
         out_specs=[row(dk), whole(dk), whole(dv)],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -853,12 +1007,12 @@ def _grad(selected, q, k, v, dout, lse, delta, *, heads, vmem, causal,
         scratch_shapes=[pltpu.VMEM((n, bq, dk), jnp.float32),
                         pltpu.VMEM((kh * t, dk), jnp.float32),
                         pltpu.VMEM((kh * t, dv), jnp.float32),
-                        pltpu.VMEM((bq, bk), jnp.float32)],
+                        pltpu.VMEM((bq, bk), jnp.float32)] + queries,
         compiler_params=_params(
             vmem, "parallel" if gh == g else "arbitrary", "arbitrary",
             "arbitrary"),
         interpret=interpret,
-    )(*_given(selected, q, k, v, dout, lse, delta))
+    )(*_given(selected, q, k, v, dout, lse, delta, rot))
 
 
 # ---------------------------------------------------------------------------
@@ -943,17 +1097,20 @@ def _lower_half(x):
     return _lane(x.shape) < _HALF
 
 
-def _turned(x, rot_ref, sign):
+def _turned(x, rot_ref, sign, halves=False):
     """``x`` ``[bq, r]`` float32, every pair of neighbouring lanes rotated
     by its row's position (``sign`` 1.0) or back (-1.0), a lane tile at a
-    time."""
+    time; ``halves``: the pairs are lane ``i`` and ``i + 64`` of the ONE tile
+    (``rotary_embedding``'s rotate-half form of a 128-wide head), the
+    partner one roll."""
     r = x.shape[1]
 
     def tile(i):
         lanes = slice(i * LANES, (i + 1) * LANES)
         y = x[:, lanes]
-        partner = jnp.where((_lane(y.shape) & 1) == 0,
-                            pltpu.roll(y, LANES - 1, 1), pltpu.roll(y, 1, 1))
+        partner = pltpu.roll(y, _HALF, 1) if halves else jnp.where(
+            (_lane(y.shape) & 1) == 0,
+            pltpu.roll(y, LANES - 1, 1), pltpu.roll(y, 1, 1))
         return y * rot_ref[:, lanes] + partner * (
             sign * rot_ref[:, r + i * LANES:r + (i + 1) * LANES])
     return jnp.concatenate([tile(i) for i in range(r // LANES)], 1)
@@ -1014,10 +1171,7 @@ class _Projections:
         """Head ``h``'s ``width`` columns at ``start`` of its part of a
         block whose heads are ``ref.shape[2] / n`` wide: whole lane tiles,
         ``h`` the head loop's own index."""
-        at = h * (ref.shape[2] // self.n) + start
-        if not isinstance(at, int):
-            at = pl.multiple_of(at, LANES)
-        return ref[0, :, pl.ds(at, width)]
+        return _columns(ref, h * (ref.shape[2] // self.n) + start, width)
 
     def keys(self, kv):
         return self.columns(self.kv_ref, kv, self.nope)
@@ -1095,9 +1249,7 @@ def _fwd_kernel_in_place(*refs, scale, causal, rotated, n, nope, rope, dv,
     @pl.when(ki == nk - 1)
     def _():
         out, lse = _softmax_rows(m_s, l_s, acc_s)
-        out = out()
-        for h in range(n):
-            o_ref[0, :, h * dv:(h + 1) * dv] = out[h].astype(o_ref.dtype)
+        _store_heads(o_ref, out())
         lse_ref[0] = lse()
 
 
@@ -1328,43 +1480,75 @@ def _grad_in_place(q, kv, kr, rot, dout, lse, delta, *, n_head, v_dim, heads,
 _run = functools.partial(run_traced, "streamed_attention")
 
 
-def _statics(q, k, v, causal, scale, interpret, window=None, heads=None):
+def _statics(q, k, v, causal, scale, interpret, window=None, heads=None,
+             n_head=None):
     """A signature's statics (``heads`` the forward's unless given);
-    ``window`` is among them only where a call has one, so that a call
-    without keeps the signature — and the one trace — it had."""
+    ``window`` and ``n_head`` are among them only where a call has one, so
+    that a call without keeps the signature — and the one trace — it had."""
     if window is not None and (not causal or int(window) < 1):
         raise ValueError("a window (%r) is of causal attention, at least "
                          "one key wide" % (window,))
-    return dict(heads=heads or step_heads(q, k, v), vmem=_VMEM_BUDGET,
-                causal=bool(causal),
-                scale=float(q.shape[-1] ** -0.5 if scale is None else scale),
+    if scale is None:
+        scale = _geometry(q, k, v, n_head)[3] ** -0.5
+    return dict(heads=heads or step_heads(q, k, v, n_head),
+                vmem=_VMEM_BUDGET, causal=bool(causal), scale=float(scale),
                 interpret=bool(interpret),
-                **_windowed(None if window is None else int(window)))
+                **_windowed(None if window is None else int(window)),
+                **({} if n_head is None else {"n_head": int(n_head)}))
+
+
+def half_turn_tables(t, d, theta, freq_scaling=None, scale=1.0):
+    """``[t, 2 * d]`` float32: ``rotary_embedding``'s cosines of positions
+    0..t-1 in its rotate-half form, then its sines signed by half (``-sin``
+    on the lower half of a head, whose partner is subtracted)."""
+    from ..activation import rotary_tables
+
+    cos, sin = rotary_tables(t, d, theta, freq_scaling, scale)
+    return jnp.concatenate(
+        [cos, sin * jnp.where(jnp.arange(d) < d // 2, -1.0, 1.0)], -1)
 
 
 def forward(q, k, v, selected, causal=False, scale=None, interpret=False,
-            window=None):
+            window=None, n_head=None, rot=None):
     """q ``[B, H, T, Dk]``; k ``[B, H / g, T, Dk]``, v ``[B, H / g, T,
     Dv]``; ``selected`` the packed key mask ``[B, T, W]`` int32 or None;
     ``window`` (with ``causal``) the keys a query reads back from its own.
     Returns the output ``[B, H, T, Dv]`` in q's dtype and the rows'
-    log-sum-exp ``[B, H, T, 1]`` float32, which ``backward`` wants back."""
-    out, lse = _run(_forward, (selected, q, k, v),
-                    **_statics(q, k, v, causal, scale, interpret, window))
+    log-sum-exp ``[B, H, T, 1]`` float32, which ``backward`` wants back.
+    ``n_head`` given: the operands are grouped heads where the projections
+    wrote them — q ``[B, T, H * Dk]``, k ``[B, T, H / g * Dk]``, v ``[B, T,
+    H / g * Dv]``, the output ``[B, T, H * Dv]`` — and ``rot``
+    (``half_turn_tables``; 128-wide heads) rotates every head of q and k by
+    its rows' positions inside the kernel."""
+    out, lse = _run(_forward, (selected, q, k, v, rot), **_statics(
+        q, k, v, causal, scale, interpret, window, n_head=n_head))
     return out, lse
 
 
 def backward(q, k, v, selected, out, lse, dout, causal=False, scale=None,
-             interpret=False, window=None):
-    """(dQ, dK, dV) from the forward's operands and results."""
+             interpret=False, window=None, n_head=None, rot=None):
+    """(dQ, dK, dV) from the forward's operands and results, in the
+    operands' layout (under ``rot``: of the unrotated q and k)."""
     dout = dout.astype(q.dtype)
-    delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), -1,
-                    keepdims=True)
-    operands = (selected, q, k, v, dout, lse, delta)
-    body, heads = grad_step(q, k, v)
-    statics = _statics(q, k, v, causal, scale, interpret, window, heads)
+    delta = dout.astype(jnp.float32) * out.astype(jnp.float32)
+    if n_head is None:
+        delta = jnp.sum(delta, -1, keepdims=True)
+    else:
+        # each head's columns added up by a product with the heads'
+        # indicator (exact in float32 at the highest precision): a view as
+        # [B, T, H, Dv] is a 128 MB relayout here
+        member = (jnp.arange(delta.shape[2])[:, None]
+                  // (delta.shape[2] // n_head)
+                  == jnp.arange(n_head)[None, :]).astype(jnp.float32)
+        delta = jnp.matmul(delta, member, precision=jax.lax.Precision.HIGHEST
+                           ).transpose(0, 2, 1)[..., None]
+    operands = (selected, q, k, v, dout, lse, delta, rot)
+    body, heads = grad_step(q, k, v, n_head)
+    statics = _statics(q, k, v, causal, scale, interpret, window, heads,
+                       n_head)
     if body == "streamed_fused":
         return tuple(_run(_grad, operands, **statics))
+    operands = operands[:-1]
     (dq,) = _run(_dq, operands, **statics)
     dk, dv = _run(_dkv, operands, **statics)
     return dq, dk, dv

@@ -2,8 +2,9 @@
 the decoder cells' tests: the fused kernel (one ``pallas_call``: dQ, dK and
 dV from one ``s``, ``p``, ``g`` and ``ds`` a block pair) against the dQ and
 dK/dV kernels and against ``jax.vjp`` of the XLA body, all interpreted; and a
-latent block's attention as two programs, composed of Fluid ops around the
-4-D op and as the ONE op over the projections' layout."""
+latent block's attention, and a grouped-head block's, each as two programs:
+composed of Fluid ops around the 4-D op and as the ONE op over the
+projections' layout."""
 
 import numpy as np
 
@@ -121,4 +122,78 @@ def latent_attention_programs(n, t, nope, rope, dv, theta, scale):
         programs.append((main, [
             out, op.outputs["LSE"][0], "q@GRAD", "kv@GRAD", "kr@GRAD"]
             + ([] if joined is None else [joined.name + "@GRAD"])))
+    return programs
+
+
+# ---- grouped heads, composed of Fluid ops and as ONE op ---------------------------
+
+def grouped_attention_programs(n, hk, d, t, window=None, law=None,
+                               selected=False, head_norm=False, dv=None):
+    """Two programs over the same feeds — ``q`` [B, t, n * d], ``k`` [B, t,
+    hk * d], ``v`` [B, t, hk * dv] as a block's three projections write
+    them, ``ct`` the result's cotangent and, ``selected``, the packed key
+    mask ``sel`` — each with its backward: the block's attention as the
+    decoders composed it before the op took the projections' layout (view
+    as heads, a per-head RMSNorm where ``head_norm``, ``rotary_embedding``
+    by ``law`` — that layer's keywords —, transposes around the 4-D
+    ``fused_attention``), and the op over that layout, the rotation inside
+    it and the per-head norm an ``rms_norm(group=d)`` before it.  Returns ``[(main, fetches)] * 2``, the fetches ``Out``, ``LSE``,
+    dQ, dK, dV."""
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+    from paddle_tpu.ops import sparse_select as ss
+    from paddle_tpu.param_attr import ParamAttr
+
+    dv = d if dv is None else dv
+    law = law or {}
+
+    def heads(x, m, width=d):
+        return layers.reshape(x, shape=[0, 0, m, width])
+
+    def normed(x, name):
+        return layers.rms_norm(x, 1e-6, ParamAttr(name=name)) \
+            if head_norm else x
+
+    def composed(q, k, v, sel):
+        def rotate(x):
+            return layers.rotary_embedding(x, **law) if law else x
+
+        def to_bhtd(x):
+            return layers.transpose(x, perm=[0, 2, 1, 3])
+        q = rotate(normed(heads(q, n), "q_g"))
+        k = rotate(normed(heads(k, hk), "k_g"))
+        ctx = layers.fused_attention(
+            to_bhtd(q), to_bhtd(k), to_bhtd(heads(v, hk, dv)), causal=True,
+            scale=d ** -0.5, window=window, selected=sel)
+        return layers.reshape(to_bhtd(ctx), shape=[0, 0, n * dv])
+
+    def one(q, k, v, sel):
+        if head_norm:
+            q, k = (layers.rms_norm(x, 1e-6, ParamAttr(name=name), group=d)
+                    for x, name in ((q, "q_g"), (k, "k_g")))
+        return layers.fused_attention(
+            q, k, v, causal=True, scale=d ** -0.5, window=window,
+            selected=sel, n_head=n,
+            **{"rope_" + key: value for key, value in law.items()})
+
+    programs = []
+    for build in (composed, one):
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup), fluid.unique_name.guard():
+            feeds = [layers.data(name, shape=[t, width], dtype="float32")
+                     for name, width in (("q", n * d), ("k", hk * d),
+                                         ("v", hk * dv), ("ct", n * dv))]
+            for x in feeds[:3]:
+                x.stop_gradient = False
+            sel = layers.data("sel", shape=[t, ss.packed_width(t)],
+                              dtype="int32") if selected else None
+            out = build(*feeds[:3], sel)
+            fluid.append_backward(layers.reduce_sum(
+                layers.elementwise_mul(out, feeds[3])))
+        op = next(o for o in main.global_block().ops
+                  if o.type == "fused_attention")
+        # plain heads short enough for the 4-D op's other bodies keep no
+        # log-sum-exp there: the output stands in and nobody compares it
+        programs.append((main, [out, op.outputs.get("LSE", [out])[0],
+                                "q@GRAD", "k@GRAD", "v@GRAD"], startup))
     return programs

@@ -667,7 +667,7 @@ def test_the_op_refuses_a_layout_it_cannot_read():
     for kw, match in ((dict(v_dim=None), "v_dim"),
                       (dict(v_dim=24), "do not fit together"),
                       (dict(k_shared=None), "do not fit together"),
-                      (dict(window=4), "no v, selected or window"),
+                      (dict(window=4), "no selected or window"),
                       (dict(n_head=None), "projections' layout")):
         with pytest.raises(ValueError, match=match):
             build(**kw)

@@ -411,7 +411,8 @@ def test_program_runs_one_stack_over_the_same_weights():
     assert types.count("fused_attention") == apps
     assert types.count("fused_attention_grad") == apps
     assert types.count("swiglu") == apps
-    assert types.count("rotary_embedding") == 2 * apps
+    # the op takes the projections as they lie and rotates q and k itself
+    assert not {"rotary_embedding", "transpose", "reshape"} & set(types)
     assert types.count("rms_norm") == 4 * apps + 3        # + a pass's final
     assert types.count("mul") == 7 * apps + 3             # + a pass's head
     assert types.count("softmax_with_cross_entropy") == 3
@@ -419,12 +420,14 @@ def test_program_runs_one_stack_over_the_same_weights():
     assert types.count("adam") == len(harness.load_reference(
         cfg["reference"]).param_spec(cfg))
     for op in block.ops:
-        if op.type == "rotary_embedding":
-            assert op.attrs["theta"] == 1e6 and not op.attrs.get(
-                "interleaved")
         if op.type == "fused_attention":
             assert op.attrs["causal"] and op.attrs["scale"] == \
                 cfg["head_dim"] ** -0.5
+            assert op.attrs["rope_theta"] == 1e6 and not op.attrs.get(
+                "rope_interleaved")
+            assert op.attrs["n_head"] == cfg["num_attention_heads"]
+            assert [len(block.var(op.input(s)[0]).shape)
+                    for s in "QKV"] == [3, 3, 3]
     # the same parameter under every pass: l0.attn.q is read by 3 products
     readers = [op for op in block.ops if op.type == "mul"
                and op.inputs["Y"] == ["l0.attn.q"]]
@@ -462,7 +465,7 @@ def test_every_op_of_a_pass_and_block_runs_under_a_scope_naming_both():
         for i in (0, 1):
             for kind in ("fused_attention", "fused_attention_grad", "mul",
                          "mul_grad", "rms_norm", "rms_norm_grad", "swiglu",
-                         "rotary_embedding_grad"):
+                         "swiglu_grad"):
                 assert (t, i, kind) in seen, (t, i, kind)
     model.close()
 

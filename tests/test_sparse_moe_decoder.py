@@ -1070,7 +1070,9 @@ def test_program_lists_every_new_op_under_its_own_type():
               "moe_expert_ffn_grad"):
         assert types.count(t) == n, t
     assert types.count("rms_norm") == 4 * n + 1
-    assert types.count("rotary_embedding") == 4 * n
+    # the indexer's q and k; the attention's ride on its op (ISSUE 50)
+    assert types.count("rotary_embedding") == 2 * n
+    assert "transpose" not in types
     for t in ("indexer_score_grad", "select_topk_keys_grad",
               "moe_dispatch_grad"):
         assert t not in types

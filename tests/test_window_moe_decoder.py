@@ -372,20 +372,26 @@ def test_program_chooses_its_attention_half_by_layer():
     assert all(op.outputs.get("LSE") for op in attention)       # grouped heads
     assert [op.attr("window") for op in ops
             if op.type == "fused_attention_grad"] == [None, 24, 24, 24]
-    # q and k of each layer: the plain law on the window layers, YaRN's
-    # blend and the attention factor on the full one
+    # the rotation of q and k rides on each layer's op (ISSUE 50): the plain
+    # law on the window layers, YaRN's blend and the attention factor on
+    # the full one; the op takes the three projections as they lie
     rope = cfg["rope_parameters"]["full_attention"]
     want = {"factor": 16.0, "beta_fast": 32.0, "beta_slow": 1.0,
             "original_length": float(rope["original_max_position_embeddings"])}
-    rotary = [op for op in ops if op.type == "rotary_embedding"]
-    assert len(rotary) == 8
-    for op in rotary[:6]:
-        assert "freq_scaling" not in op.attrs and "scale" not in op.attrs
-    for op in rotary[6:]:
-        assert op.attr("freq_scaling") == want
-        assert op.attr("scale") == rope["attention_factor"]
-    assert all(op.attr("theta") == rope["rope_theta"] for op in rotary)
+    for op in attention[:3]:
+        assert "rope_freq_scaling" not in op.attrs \
+            and "rope_scale" not in op.attrs
+    assert attention[3].attr("rope_freq_scaling") == want
+    assert attention[3].attr("rope_scale") == rope["attention_factor"]
+    assert all(op.attr("rope_theta") == rope["rope_theta"]
+               and op.attr("n_head") == cfg["num_attention_heads"]
+               and not op.attr("rope_interleaved") for op in attention)
+    block = model.main.global_block()
+    for op in attention:
+        assert [len(block.var(op.input(s)[0]).shape) for s in "QKV"] \
+            == [3, 3, 3]
     types = [op.type for op in ops]
+    assert not {"rotary_embedding", "transpose"} & set(types)
     assert types.count("moe_expert_ffn") == 4 and "select_keys" not in types
     assert types.count("rms_norm") == 9             # no per-head norm
     params = {p.name for p in model.main.global_block().all_parameters()}

@@ -53,7 +53,8 @@ __all__ = [
     "program_fingerprint", "program_label", "name_step", "trace_key",
     "trace_flag_values", "lookup",
     "store", "stats", "reset_stats", "clear", "note_kernel_body",
-    "note_kernel_trace", "open_record", "note_phase", "close_record",
+    "note_kernel_trace", "note_op_work", "open_record", "note_phase",
+    "close_record",
     "compile_log", "outside_compiles",
     "count_compiles", "persistent_cache_dir", "enable_persistent_cache",
     "rescope_persistent_cache", "CHECKOUT_CACHE_DIR",
@@ -272,6 +273,23 @@ def note_kernel_trace(kernel, counter, seconds=0.0):
             rec["kernel_trace_s"] += seconds
 
 
+def note_op_work(scope_name, op_type, part, flops, least_bytes, shape):
+    """Note, while a step is traced for lowering, what one part of an op's
+    dense products must do (an op definition's ``work`` rule, asked by
+    ``registry.compute_op`` where the op's scope is opened): ``part`` is
+    ``fwd``, ``dx`` or ``dw``, ``flops`` 2·M·K·N of the flattened product
+    ``shape`` = (M, K, N), ``least_bytes`` each operand and the result once,
+    in the dtypes the body was handed — GLOBAL shapes under a mesh (the
+    record's ``batch_shards`` says over how many devices the batch axis is
+    split).  One plain tuple a part in the open record's ``op_work``; with
+    no record open (eager programs, the reference) nothing is kept."""
+    rec = getattr(_open, "record", None)
+    if rec is not None:
+        rec["op_work"].append((
+            scope_name, op_type, part, int(flops), int(least_bytes),
+            tuple(int(d) for d in shape)))
+
+
 def clear():
     """Drop every cached trace (tests; frees the traced programs)."""
     with _mu:
@@ -304,7 +322,7 @@ def lowered_before(program):
         return program_fingerprint(program)[:12] in _LOWERINGS_BY_FP
 
 
-def open_record(executor, kind, program, cause):
+def open_record(executor, kind, program, cause, batch_shards=1):
     """Open the calling thread's compile record: ``executor`` is the
     step path's name (``executor`` / ``parallel_executor``), ``kind`` its
     label in the module name, ``cause`` one of ``first`` (fingerprint never
@@ -312,8 +330,10 @@ def open_record(executor, kind, program, cause):
     program object, new ``_version``), ``other_key`` (flags, placement,
     scope, fetch list).  The program's ``build_s`` — what the whole-program
     passes of build spent on it (``profiler.build_pass``) — moves into its
-    first record.  A record still open on the thread (its cold call never
-    came) is closed first, with ``first_call_s`` 0."""
+    first record.  ``op_work`` fills while the step is traced
+    (``note_op_work``); ``batch_shards`` is over how many devices the
+    placement splits the batch axis.  A record still open on the thread
+    (its cold call never came) is closed first, with ``first_call_s`` 0."""
     assert cause in _CAUSES, cause
     _listen()
     if getattr(_open, "record", None) is not None:
@@ -331,6 +351,7 @@ def open_record(executor, kind, program, cause):
         "jax_trace_s": 0.0, "kernel_trace_s": 0.0, "kernel_traces": 0,
         "lowering_s": 0.0, "executable_s": 0.0, "executable": "none",
         "first_call_s": 0.0, "unaccounted_s": 0.0,
+        "op_work": [], "batch_shards": int(batch_shards),
         # (phase, end on the profiler's clock, seconds) as jax reported
         # them, and whether jax is inside the step function's own trace
         "_spans": [], "_tracing": False}
@@ -353,12 +374,14 @@ _PHASE_SECONDS = ("analyze_s", "program_trace_s", "jax_trace_s",
                   "first_call_s")
 
 
-def close_record(call_ns):
+def close_record(call_ns, op_work=None):
     """Close the calling thread's record (None when there is none) and
     append it to the log: ``call_ns`` is when the cold call began
     (``time.perf_counter_ns``; None = there was no cold call),
-    ``unaccounted_s`` what of the call jax reported no phase for.  Under a
-    profiler session the phases jax reported are appended to its events as
+    ``unaccounted_s`` what of the call jax reported no phase for;
+    ``op_work`` is the trace-cache entry's list, for a record under which
+    nothing was traced again.  Under a profiler session
+    the phases jax reported are appended to its events as
     ``<executor>/jax_trace``, ``/mlir_lowering`` and ``/executable`` spans,
     back-dated from the moment each duration arrived; with the monitor's
     JSONL log on, the record is one ``compile_record`` event there."""
@@ -366,6 +389,8 @@ def close_record(call_ns):
     if rec is None:
         return None
     _open.record = None
+    if op_work and not rec["op_work"]:
+        rec["op_work"] = list(op_work)
     if call_ns is not None:
         rec["first_call_s"] = (time.perf_counter_ns() - call_ns) / 1e9
     # every phase in whole 2^-30 s (~1 ns): a sum of them is then exact in
@@ -392,7 +417,8 @@ def close_record(call_ns):
 def compile_log():
     """The closed records, oldest first, as plain dicts (copies)."""
     with _mu:
-        return [dict(rec, build=dict(rec["build"])) for rec in _LOG]
+        return [dict(rec, build=dict(rec["build"]),
+                     op_work=list(rec["op_work"])) for rec in _LOG]
 
 
 def outside_compiles():

@@ -468,6 +468,10 @@ class CompiledStep:
         # schedule accounting for the program's pipeline regions on its
         # mesh (None = nothing runs pipelined)
         self.pipeline_stats = pipeline_stats
+        # {feed signature: the ``op_work`` its trace noted} (the products'
+        # required work, compile_cache.note_op_work): a later record that
+        # finds this entry traced already carries the list, not an empty one
+        self.op_work = {}
 
 
 def _names(fetch_list):
@@ -481,15 +485,18 @@ def _feed_sig(feed_names, feed_vals):
 
 
 @contextlib.contextmanager
-def _cold_call(name, span_args):
+def _cold_call(name, span_args, compiled, feed_sig):
     """The cold call's ``<name>/compile`` span, which also closes the
-    program's compile record with the call's wall time."""
+    program's compile record with the call's wall time and keeps what the
+    trace noted of its products' work on the entry."""
     t0 = time.perf_counter_ns()
     try:
         with RecordEvent(name + "/compile", args=span_args):
             yield
     finally:
-        compile_cache.close_record(t0)
+        rec = compile_cache.close_record(t0)
+        if rec is not None and rec["op_work"]:
+            compiled.op_work[feed_sig] = rec["op_work"]
 
 
 class StepPath:
@@ -583,6 +590,11 @@ class StepPath:
                 [jax.device_put(scope.var(n), dev)
                  for n in compiled.state_in])
 
+    def _batch_shards(self):
+        """Over how many devices the placement splits the batch axis (the
+        compile record's ``batch_shards``)."""
+        return 1
+
     def _auto_seed(self):
         """The seed of a program that declares none."""
         return np.random.randint(0, 2**31 - 1)
@@ -616,7 +628,8 @@ class StepPath:
             # one compile record a lowering: opened here, closed when the
             # cold call has returned (_cold_call)
             compile_cache.open_record(self._name, self._label, program,
-                                      self._cause(program, key))
+                                      self._cause(program, key),
+                                      batch_shards=self._batch_shards())
             # the reference wraps op instantiation in RecordBlock
             # (executor.cc Prepare); here the analog is the trace+jit
             # (_lower consults the process-global trace cache first)
@@ -626,8 +639,10 @@ class StepPath:
                     fetch_names, dev)
             if feed_sig in compiled.seen_sigs:
                 # the process has run this entry at this signature (the
-                # trace cache's hit): no cold call follows
-                compile_cache.close_record(None)
+                # trace cache's hit): no cold call follows, nothing is
+                # traced again
+                compile_cache.close_record(
+                    None, op_work=compiled.op_work.get(feed_sig))
         return feed_sig, compiled
 
     def _cause(self, program, key):
@@ -779,7 +794,7 @@ class StepPath:
         if fault.active():
             fault.fire("executor/dispatch", step_idx)
         with RecordEvent(name + "/run"):
-            with (_cold_call(name, span_args) if cold else
+            with (_cold_call(name, span_args, compiled, feed_sig) if cold else
                   RecordEvent(name + "/dispatch", args=span_args)):
                 with self._dispatch_scope(dev):
                     fn = compiled.fn

@@ -457,13 +457,22 @@ def summary_for(fingerprint):
 def _lowering_columns(records):
     """{fp12: the report's columns from that program's compile records}:
     how often it was lowered and why (``first,feed_signature*2`` — a
-    retrace storm reads as its cause), and what set-up paid for it, phase
-    by phase, summed over the records."""
+    retrace storm reads as its cause), what set-up paid for it, phase
+    by phase, summed over the records, and what its dense products must do
+    a step (``op_work`` of its newest record that holds one: ``{"<op
+    type>:<part>": [flops, least bytes]}``)."""
     out = {}
     for r in records:
         c = out.setdefault(r["fingerprint"], {
             "lowerings": 0, "causes": {}, "build_s": 0.0, "trace_s": 0.0,
-            "lowering_s": 0.0, "executable_s": 0.0})
+            "lowering_s": 0.0, "executable_s": 0.0, "op_work": {}})
+        if r.get("op_work"):
+            c["op_work"] = {}
+            for row in r["op_work"]:
+                total = c["op_work"].setdefault("%s:%s" % (row[1], row[2]),
+                                                [0, 0])
+                total[0] += row[3]
+                total[1] += row[4]
         c["lowerings"] += 1
         c["causes"][r["cause"]] = c["causes"].get(r["cause"], 0) + 1
         c["build_s"] += r["build_s"]
@@ -530,7 +539,7 @@ def report_rows(peak_tflops=None, profiles_by_fp=None, acct_by_fp=None,
                if p is not None else None}
         row.update(lowered.get(fp[:12]) or {
             "lowerings": 0, "cause": "", "build_s": None, "trace_s": None,
-            "lowering_s": None, "executable_s": None})
+            "lowering_s": None, "executable_s": None, "op_work": {}})
         if probe:
             row["probe"] = True
             row["mfu"] = None
@@ -557,6 +566,7 @@ def render_table(rows):
               "program", "executor", "steps", "wall(s)", "share",
               "GFLOP/step", "GB/step", "peakHBM", "MFU",
               "build(s)", "trace(s)", "lower(s)", "exec(s)", "lowered")
+    hdr += "  products' work a step (TFLOP/least GB by op:part)"
     lines = [hdr, "-" * len(hdr)]
 
     def secs(v):
@@ -565,7 +575,7 @@ def render_table(rows):
         kind = ("probe:" + (r["kind"] or "?")) if r.get("probe") \
             else (r["kind"] or "?")
         lines.append("%-12s %-10s %8d %10.3f %6.1f%% %12s %12s %10s %7s"
-                     " %8s %8s %8s %8s  %s" % (
+                     " %8s %8s %8s %8s  %-7s  %s" % (
             r["fp12"], kind[:10], r["steps"], r["wall_s"],
             100.0 * r["wall_share"],
             "%.3f" % (r["flops_per_step"] / 1e9)
@@ -577,7 +587,9 @@ def render_table(rows):
             "%.3f" % r["mfu"] if r["mfu"] is not None else "-",
             secs(r.get("build_s")), secs(r.get("trace_s")),
             secs(r.get("lowering_s")), secs(r.get("executable_s")),
-            r.get("cause") or "-"))
+            r.get("cause") or "-",
+            " ".join("%s %.4g/%.4g" % (k, f / 1e12, b / 1e9) for k, (f, b)
+                     in sorted((r.get("op_work") or {}).items())) or "-"))
     return "\n".join(lines)
 
 

@@ -26,8 +26,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..registry import (fluid_scope_name, in_var, register_chain,
-                        register_op, same_shape_infer, set_output)
+from ..registry import (fluid_scope_name, get_op_def, in_var, note_work,
+                        register_chain, register_op, same_shape_infer,
+                        set_output)
 
 
 def _rowwise_out_infer(op, block, x_slot="X"):
@@ -303,6 +304,10 @@ def _head_chain_rule(ops, i, env, ctx, kept):
             out_specs=(rows, P(), P()))
     x, w, z, bias, logits, label, ct = args
     with jax.named_scope(fluid_scope_name(mul)):
+        # the kernel stands for ``mul_grad``'s two products (and the bias's
+        # sum): the same parts the op-by-op spelling notes
+        note_work(mul, get_op_def("mul").work(
+            {"X": [x], "Y": [w]}, {}, ("X", "Y")))
         dx, dw, db = run(x, w, z, bias, _row_lse(logits, eps), label, ct)
     env[_only(mul.outputs["GRAD::X"])] = dx.reshape(
         env[_only(mul.inputs["X"])].shape)

@@ -49,6 +49,40 @@ def _mm_accum_dtype(a, b, ctx=None):
     return None
 
 
+# -- what a dense product must do (an op definition's ``work`` rule) ---------
+
+def _size(shape):
+    return int(np.prod(shape, dtype=np.int64))
+
+
+def product_work(m, k, n, lhs, rhs, out, grad, weight_rhs=True):
+    """The parts of the flattened product ``[m, k] x [k, n]`` as a ``work``
+    rule returns them — ``(part, flops, least_bytes, (M, K, N))``, flops
+    2·M·K·N of the product the part IS, least bytes each of its operands
+    and its result once.  ``lhs``, ``rhs``, ``out`` are the three arrays'
+    dtypes (the cotangent arrives in the output's, a gradient leaves in its
+    input's).  ``grad`` () is the product itself, ``fwd``; else the slots
+    that get a gradient, ``X`` -> ``dx`` = ``[m, n] x [n, k]`` and ``Y`` ->
+    ``dw`` = ``[k, m] x [m, n]`` (``dx`` too where the right operand is an
+    activation: ``weight_rhs`` False)."""
+    nbytes = (m * k * np.dtype(lhs).itemsize + k * n * np.dtype(rhs).itemsize
+              + m * n * np.dtype(out).itemsize)
+    flops = 2 * m * k * n
+    if not grad:
+        return [("fwd", flops, nbytes, (m, k, n))]
+    by_slot = {"X": ("dx", flops, nbytes, (m, n, k)),
+               "Y": ("dw" if weight_rhs else "dx", flops, nbytes, (k, m, n))}
+    return [by_slot[slot] for slot in grad]
+
+
+def _mul_work(ins, attrs, grad):
+    x, y = ins["X"][0], ins["Y"][0]
+    xnc = attrs.get("x_num_col_dims", 1)
+    ync = attrs.get("y_num_col_dims", 1)
+    return product_work(_size(x.shape[:xnc]), _size(x.shape[xnc:]),
+                        _size(y.shape[ync:]), x.dtype, y.dtype, x.dtype, grad)
+
+
 # -- mul (fc's matmul: flatten then 2-D gemm; mul_op.cc) --------------------
 
 def _mul_infer(op, block):
@@ -72,7 +106,8 @@ def _mul_compute(ins, attrs, ctx, op_index):
     return {"Out": out.reshape(tuple(x.shape[:xnc]) + tuple(y.shape[ync:]))}
 
 
-register_op("mul", ["X", "Y"], ["Out"], infer=_mul_infer, compute=_mul_compute)
+register_op("mul", ["X", "Y"], ["Out"], infer=_mul_infer, compute=_mul_compute,
+            work=_mul_work)
 
 
 # -- matmul (batched, with transpose flags; matmul_op.cc) -------------------
@@ -121,8 +156,25 @@ def _matmul_compute(ins, attrs, ctx, op_index):
     return {"Out": out}
 
 
+def _matmul_work(ins, attrs, grad):
+    """Batch dimensions multiplied into M.  A right operand without batch
+    dimensions is a weight (its gradient contracts over every row: ``dw``);
+    with them it is an activation, and both gradients are ``dx``."""
+    xs, ys = list(ins["X"][0].shape), list(ins["Y"][0].shape)
+    xs = [1] + xs if len(xs) == 1 else xs
+    ys = ys + [1] if len(ys) == 1 else ys
+    if attrs.get("transpose_X", False):
+        xs[-1], xs[-2] = xs[-2], xs[-1]
+    if attrs.get("transpose_Y", False):
+        ys[-1], ys[-2] = ys[-2], ys[-1]
+    batch = xs[:-2] if len(xs) >= len(ys) else ys[:-2]
+    x = ins["X"][0].dtype
+    return product_work(_size(batch) * xs[-2], xs[-1], ys[-1], x,
+                        ins["Y"][0].dtype, x, grad, weight_rhs=len(ys) == 2)
+
+
 register_op("matmul", ["X", "Y"], ["Out"], infer=_matmul_infer,
-            compute=_matmul_compute)
+            compute=_matmul_compute, work=_matmul_work)
 
 
 # -- sum (variadic add; sum_op.cc) ------------------------------------------

@@ -34,7 +34,7 @@ from jax import lax
 
 from ..registry import register_op, set_output, in_var
 from ..framework import grad_var_name
-from .math import _flatten_to_2d
+from .math import _flatten_to_2d, _size, product_work
 
 __all__ = []
 
@@ -223,8 +223,16 @@ def _dequant_matmul_compute(ins, attrs, ctx, op_index):
     return {"Out": out}
 
 
+def _dequant_matmul_work(ins, attrs, grad):
+    x, qw = ins["X"][0], ins["QWeight"][0]
+    xnc = attrs.get("x_num_col_dims", 1)
+    return product_work(_size(x.shape[:xnc]), _size(x.shape[xnc:]),
+                        qw.shape[-1], x.dtype, qw.dtype, x.dtype, grad)
+
+
 register_op(
     "dequant_matmul", ["X", "QWeight", "Scale", "XScale"], ["Out"],
     infer=_dequant_matmul_infer, compute=_dequant_matmul_compute,
     grad=None, no_grad_inputs=("QWeight", "Scale", "XScale"),
+    work=_dequant_matmul_work,
 )

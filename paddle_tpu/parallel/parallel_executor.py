@@ -221,6 +221,9 @@ class ParallelExecutor(StepPath):
                 bs.donate_state, jax.process_count(),
                 bs.pipeline_schedule, bs.pipeline_microbatches)
 
+    def _batch_shards(self):
+        return self._dp_size()
+
     def _trace_sigs(self, feed_names, feed_sig, state_names, scope):
         # shapes decide the shardings below, so they key the entry
         return feed_sig, tuple(

@@ -91,6 +91,7 @@ class OpDef:
         no_grad_inputs=(),
         stateful_random=False,
         doc="",
+        work=None,
     ):
         self.type = type
         self.input_slots = tuple(inputs)
@@ -103,6 +104,13 @@ class OpDef:
         self.no_grad_inputs = frozenset(no_grad_inputs)
         self.stateful_random = stateful_random
         self.doc = doc
+        # work(ins, attrs, grad) -> [(part, flops, least_bytes, (M, K, N))]:
+        # what the op's dense products must do, from the traced inputs the
+        # body is handed (after AMP's casts).  ``grad`` is () for the op
+        # itself (one part, ``fwd``) and, asked by the generic gradient of
+        # THIS definition, the input slots that get a gradient: one part a
+        # slot, in their order, named for the scope its operations run under
+        self.work = work
 
 
 def register_op(
@@ -115,12 +123,13 @@ def register_op(
     no_grad_inputs=(),
     stateful_random=False,
     doc="",
+    work=None,
 ):
     if type in OPS:
         raise ValueError("op type %r already registered" % type)
     OPS[type] = OpDef(
         type, inputs, outputs, infer, compute, grad, no_grad_inputs,
-        stateful_random, doc,
+        stateful_random, doc, work,
     )
     return OPS[type]
 
@@ -163,6 +172,19 @@ def fluid_scope_name(op):
     return "fluid[%s]%s" % (op.type, _SCOPE_UNSAFE.sub(".", out))
 
 
+def note_work(op, parts):
+    """Note what a ``work`` rule answered for ``op`` into the compile record
+    open on this thread, under the op's own scope name
+    (``compile_cache.note_op_work``; nothing without a record: eager
+    programs, the reference).  Called where the scope is opened, while the
+    step is traced for lowering, never per step."""
+    from .compile_cache import note_op_work
+
+    scope = fluid_scope_name(op)
+    for part, flops, least_bytes, shape in parts:
+        note_op_work(scope, op.type, part, flops, least_bytes, shape)
+
+
 def compute_op(op, env, ctx, op_index=0):
     """Execute one op inside a trace: read inputs from env, write outputs."""
     d = get_op_def(op.type)
@@ -196,6 +218,8 @@ def compute_op(op, env, ctx, op_index=0):
         with jax.named_scope(fluid_scope_name(op)):
             if ctx.amp is not None:
                 ins = ctx.amp.cast_inputs(op.type, ins)
+            if d.work is not None:
+                note_work(op, d.work(ins, op.attrs, ()))
             outs = d.compute(ins, op.attrs, ctx, op_index)
     finally:
         ctx.op = prev_op
@@ -358,9 +382,6 @@ def _generic_grad_compute(ins, attrs, ctx, op_index):
             canon[slot] = list(v) if isinstance(v, (list, tuple)) else [v]
         return canon
 
-    diff_vals = {slot: primal_ins[slot] for slot in diff_slots}
-    outs, vjp_fn = jax.vjp(fwd_fn, diff_vals)
-
     # build cotangents: use provided GRAD:: slots, zeros elsewhere (an
     # integer output, e.g. a router's expert ids, takes jax's float0)
     def zero_ct(v):
@@ -368,28 +389,48 @@ def _generic_grad_compute(ins, attrs, ctx, op_index):
             return jnp.zeros_like(v)
         return np.zeros(v.shape, jax.dtypes.float0)
 
-    cts = {}
-    for slot, vals in outs.items():
-        gslot = "GRAD::" + slot
-        if gslot in ins and ins[gslot]:
-            gvals = ins[gslot]
-            # cotangents must match the recomputed forward's output dtype:
-            # under the AMP policy a white-listed forward yields bf16 while
-            # the incoming cotangent may be fp32 (or vice versa)
-            cts[slot] = [
-                g.astype(v.dtype)
-                if g is not None and dtype_is_floating(v.dtype)
-                else zero_ct(v)
-                for g, v in zip(gvals, vals)
-            ]
-        else:
-            cts[slot] = [zero_ct(v) for v in vals]
+    def pull_back(slots):
+        outs, vjp_fn = jax.vjp(
+            fwd_fn, {slot: primal_ins[slot] for slot in slots})
+        cts = {}
+        for slot, vals in outs.items():
+            gslot = "GRAD::" + slot
+            if gslot in ins and ins[gslot]:
+                gvals = ins[gslot]
+                # cotangents must match the recomputed forward's output
+                # dtype: under the AMP policy a white-listed forward yields
+                # bf16 while the incoming cotangent may be fp32 (or vice
+                # versa)
+                cts[slot] = [
+                    g.astype(v.dtype)
+                    if g is not None and dtype_is_floating(v.dtype)
+                    else zero_ct(v)
+                    for g, v in zip(gvals, vals)
+                ]
+            else:
+                cts[slot] = [zero_ct(v) for v in vals]
+        (grads,) = vjp_fn(cts)
+        return {"GRAD::" + slot: grads[slot] for slot in slots}
 
-    (grads,) = vjp_fn(cts)
-
+    if fwd_def.work is None:
+        return pull_back(diff_slots)
+    # a definition that counts its products: each wanted gradient is pulled
+    # back alone, under the part's own plain scope (``dx`` / ``dw``), so a
+    # trace tells the two products of one ``fluid[mul_grad]..`` apart.  The
+    # one-sided pull-backs' dead forwards and their second cast of the
+    # cotangent fold away: the compiled step is the one-vjp spelling's
+    # instruction for instruction (compiled for a described v5e, PR 51).
+    held = getattr(ctx.op, "outputs", None)
+    wanted = tuple(
+        slot for slot in diff_slots
+        if held is None or any(held.get("GRAD::" + slot, ())))
+    parts = fwd_def.work(primal_ins, fwd_attrs, wanted)
+    if ctx.op is not None:
+        note_work(ctx.op, parts)
     result = {}
-    for slot in diff_slots:
-        result["GRAD::" + slot] = grads[slot]
+    for slot, part in zip(wanted, parts):
+        with jax.named_scope(part[0]):
+            result.update(pull_back([slot]))
     return result
 
 

@@ -137,6 +137,12 @@ _RESTATED = {
         "the same reading of num_hidden_layers as a width; "
         "test_bm_mellum_cell.py::test_configuration_keeps_every_published_"
         "size holds the file to the rest of that test",
+    "test_bm_mellum_cell.py::test_benchmark_json_holds_the_eight_cells_and_"
+    "seven_configurations":
+        "wants PR 49's two metrics last in per_layer and pins what its cell "
+        "reports; ISSUE 51 appends the dense products' four, in every cell "
+        "(test_bm_products.py::test_the_benchmark_lists_the_four_metrics_"
+        "last_for_every_cell says what the pin meant)",
 }
 
 

@@ -26,7 +26,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FIELDS = {"name", "executor", "fingerprint", "ops", "cause", "trace_cache",
           "start_us", "build_s", "build", "analyze_s", "program_trace_s",
           "jax_trace_s", "kernel_trace_s", "kernel_traces", "lowering_s",
-          "executable_s", "executable", "first_call_s", "unaccounted_s"}
+          "executable_s", "executable", "first_call_s", "unaccounted_s",
+          "op_work", "batch_shards"}
 SECONDS = ("build_s", "analyze_s", "program_trace_s", "jax_trace_s",
            "kernel_trace_s", "lowering_s", "executable_s", "first_call_s")
 
@@ -491,5 +492,15 @@ def test_the_monitor_log_and_the_program_report_carry_the_record(tmp_path):
     (offline,) = [r for r in program_report.rows_from_records(records)
                   if r["fingerprint"] == fp]
     for col in ("lowerings", "cause", "build_s", "trace_s", "lowering_s",
-                "executable_s"):
+                "executable_s", "op_work"):
         assert offline[col] == row[col]
+    # the products' work, counted at lowering: two fc layers, each forward
+    # and dW, the second's dX too (the newest record's: 10 rows)
+    assert offline["op_work"] == {
+        "mul:fwd": [2 * 10 * 8 * 28 + 2 * 10 * 28, 4 * (
+            10 * 8 + 8 * 28 + 10 * 28 + 10 * 28 + 28 + 10)],
+        "mul_grad:dw": [2 * 10 * 8 * 28 + 2 * 10 * 28, 4 * (
+            10 * 8 + 8 * 28 + 10 * 28 + 10 * 28 + 28 + 10)],
+        "mul_grad:dx": [2 * 10 * 28, 4 * (10 * 28 + 28 + 10)]}
+    assert "mul_grad:dx 5.6e-10/1.272e-06" in program_profile.render_table(
+        [offline])

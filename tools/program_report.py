@@ -6,7 +6,9 @@ Renders the table the program-profile registry maintains in-process
 bytes/step, estimated peak HBM, ground-truth MFU from the compiler's
 own flop accounting, and from the compile records what set-up paid to
 lower it — build, trace, lowering, executable seconds — and why: a retrace
-storm reads ``first,feed_signature*40``) from a monitor JSONL log — the offline twin of
+storm reads ``first,feed_signature*40`` — and what its dense products must
+do a step, by op type and part: the ``op_work`` its ``work`` rules counted
+at lowering, no benchmark run needed) from a monitor JSONL log — the offline twin of
 calling ``paddle_tpu.monitor.program_profile.report_rows()`` /
 ``render_table()`` on a live registry.
 
@@ -161,7 +163,9 @@ def render_device_table(devices):
 def main(argv=None):
     p = argparse.ArgumentParser(
         description="per-program cost/memory/step report from a monitor "
-                    "JSONL log")
+                    "JSONL log; the last column is the dense products' "
+                    "required TFLOP and least GB a step by op type and "
+                    "part (fwd / dx / dw), as counted at lowering")
     p.add_argument("log", help="monitor JSONL file, or a "
                                "FLAGS_monitor_log_dir directory")
     p.add_argument("--peak_tflops", type=float, default=None,

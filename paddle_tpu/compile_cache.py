@@ -246,7 +246,9 @@ def note_kernel_body(op_type, body):
     ring) alternative lowered to.  A requested kernel that its
     ``supported()`` gate rejects gives way to the XLA reference; this
     counter (``stats()["kernel_bodies"]``) is what tells the two apart
-    afterwards."""
+    afterwards.  ``<type>:stored`` / ``:inline`` count the sites whose
+    outputs the step keeps behind a barrier, or not, by the definition's
+    ``stored`` rule (``registry.compute_op``)."""
     key = "%s:%s" % (op_type, body)
     with _mu:
         _KERNEL_BODIES[key] = _KERNEL_BODIES.get(key, 0) + 1

@@ -144,8 +144,22 @@ def _swiglu_compute(ins, attrs, ctx, op_index):
     return {"Out": out.astype(x.dtype)}
 
 
+# every ``swiglu`` feeds a down projection: stored, the product's forward and
+# its weight gradient read ONE ``[T, F]`` array; fused into them its float32
+# sigmoid ran once per output tile of each (a ``10240 x 4096 x 2560`` dW at
+# 66 TFLOP/s beside siblings at 128-138: ledger, PR 51).  A narrow output
+# stays inline: the shared experts' 768- and 1024-wide rows gain 0.07-0.4 ms
+# a step stored, and the five more arrays held to the backward cost
+# ``train_mtp_8k``'s head a prefetched operand, 2.6 ms (PERF.md 6.29)
+_STORED_WIDTH = 2048
+
+
+def _swiglu_stored(ins, attrs):
+    return ("Out",) if ins["X"][0].shape[-1] >= _STORED_WIDTH else ()
+
+
 register_op("swiglu", ["X", "Y"], ["Out"], infer=same_shape_infer("X", "Out"),
-            compute=_swiglu_compute)
+            compute=_swiglu_compute, stored=_swiglu_stored)
 
 
 # -- rotary position embedding ----------------------------------------------

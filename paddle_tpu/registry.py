@@ -92,6 +92,7 @@ class OpDef:
         stateful_random=False,
         doc="",
         work=None,
+        stored=None,
     ):
         self.type = type
         self.input_slots = tuple(inputs)
@@ -111,6 +112,16 @@ class OpDef:
         # THIS definition, the input slots that get a gradient: one part a
         # slot, in their order, named for the scope its operations run under
         self.work = work
+        # stored(ins, attrs) -> the output slots the compiled step keeps as
+        # arrays of their own, from the traced inputs the body is handed: the
+        # FORWARD op's values go into the environment behind
+        # ``lax.optimization_barrier`` (``compute_op``), so XLA writes them
+        # once and every later reader — a product's forward, its weight
+        # gradient — reads the stored array instead of carrying the op's
+        # body inside its own fusion, evaluated again for every output tile.
+        # The generic gradient differentiates ``compute`` alone: no barrier
+        # lands on a cotangent
+        self.stored = stored
 
 
 def register_op(
@@ -124,12 +135,13 @@ def register_op(
     stateful_random=False,
     doc="",
     work=None,
+    stored=None,
 ):
     if type in OPS:
         raise ValueError("op type %r already registered" % type)
     OPS[type] = OpDef(
         type, inputs, outputs, infer, compute, grad, no_grad_inputs,
-        stateful_random, doc, work,
+        stateful_random, doc, work, stored,
     )
     return OPS[type]
 
@@ -185,6 +197,19 @@ def note_work(op, parts):
         note_op_work(scope, op.type, part, flops, least_bytes, shape)
 
 
+def _store(op_type, slots, outs):
+    """``outs`` with ``slots`` — what the definition's ``stored`` rule
+    answered — behind a barrier; ``kernel_bodies`` counts the sites
+    (``<type>:stored``, or ``<type>:inline`` where the rule kept nothing)."""
+    from .compile_cache import note_kernel_body
+
+    note_kernel_body(op_type, "stored" if slots else "inline")
+    outs = dict(outs)
+    for slot in slots:
+        outs[slot] = jax.lax.optimization_barrier(outs[slot])
+    return outs
+
+
 def compute_op(op, env, ctx, op_index=0):
     """Execute one op inside a trace: read inputs from env, write outputs."""
     d = get_op_def(op.type)
@@ -221,6 +246,8 @@ def compute_op(op, env, ctx, op_index=0):
             if d.work is not None:
                 note_work(op, d.work(ins, op.attrs, ()))
             outs = d.compute(ins, op.attrs, ctx, op_index)
+            if d.stored is not None:
+                outs = _store(op.type, d.stored(ins, op.attrs), outs)
     finally:
         ctx.op = prev_op
     for slot, names in op.outputs.items():
